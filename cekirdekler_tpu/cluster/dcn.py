@@ -116,24 +116,12 @@ def initialize(
     and every cross-process collective silently degenerates."""
     import jax
 
-    try:
-        already = jax.distributed.is_initialized()
-    except AttributeError:
-        # pre-0.5 jax has no is_initialized(); the client handle on the
-        # internal global state is the same signal (same convention as
-        # the other pre-0.6 compat shims in parallel/)
-        from jax._src import distributed as _dist
-
-        already = getattr(_dist.global_state, "client", None) is not None
-    if already:
+    if jax.distributed.is_initialized():
         return  # already joined
     if cpu_collectives:
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", cpu_collectives
-            )
-        except Exception:
-            pass  # flag absent on this jax version; TPU pods don't need it
+        jax.config.update(
+            "jax_cpu_collectives_implementation", cpu_collectives
+        )
     jax.distributed.initialize(
         coordinator_address, num_processes=num_processes,
         process_id=process_id,

@@ -62,12 +62,17 @@ __all__ = [
     "TUNER",
 ]
 
-#: Candidate per-axis tile sizes: powers of two spanning the measured
-#: useful range (the auto_block sweep: 128² tiles leave the MXU ~6%
-#: utilized, 256-1024 blocks are 1.5-3x faster; beyond 2048 the VMEM
-#: working set of a (bq, bk) score block stops fitting next to the
-#: double-buffered K/V blocks).
-BLOCK_CANDIDATES = (128, 256, 512, 1024, 2048)
+#: Candidate per-axis tile sizes: powers of two spanning the useful range
+#: (128² tiles leave the MXU mostly idle; larger blocks amortize the
+#: per-block softmax work).  The top is what Mosaic ACCEPTS: compiled on a
+#: v5e (16 MiB scoped VMEM, D=64 — which pads to the same 128-lane tiles
+#: as D=128), every fwd and bwd kernel of every pair up to 1024/1024
+#: builds in both precision modes, while most pairs with a 2048 side are
+#: refused (f32 streams: 512/2048, 1024/2048, 2048/256 and up; bf16
+#: streams: 1024/2048, 2048/1024, 2048/2048 — PR 21 chip run).  A
+#: candidate the chip refuses is not in the grid; it is never offered
+#: and then caught at run time.
+BLOCK_CANDIDATES = (128, 256, 512, 1024)
 
 #: Smallest legal block per axis — mirrors ``ops.flash_attention``'s
 #: ``_DENSE_FLOOR``: below one full 128-lane MXU tile the per-block

@@ -7,12 +7,15 @@ shard and every elastic rejoin re-paid the ladder compiles (measured at
 ~19x a timed wall when one lands inside a window).  This module is the
 cross-process half:
 
-- **XLA executable bytes** ride JAX's own persistent compilation cache:
-  arming ``CK_COMPILE_CACHE=<dir>`` points ``jax_compilation_cache_dir``
-  at ``<dir>/xla`` (with the min-compile-time / min-entry-size floors
-  dropped to 0 so small CPU-rig ladders persist too), so a process that
-  re-traces a ladder executable LOADS its XLA binary from disk instead of
-  recompiling.
+- **XLA executable bytes** ride JAX's own persistent compilation cache,
+  which this module never places: ``JAX_COMPILATION_CACHE_DIR`` decides
+  where it lives, and when that is unset the package points it once, at
+  import, at the fixed ``<checkout>/.jax_cache``.  The same place
+  (``cekirdekler_tpu/__init__.py``) makes every executable persist (the
+  ladder's rungs compile in well under jax's 1 s default floor).  A
+  process that re-traces a ladder executable LOADS its XLA binary from
+  there instead of recompiling.  :func:`trim_placed_jax_cache` bounds
+  the directory the package placed.
 - **Ladder-level manifest**: XLA's cache can only answer "have I compiled
   this exact computation" — it cannot tell a joining shard *what to
   trace*.  ``<dir>/entries/<key>.json`` persists one :class:`WarmupSpec`
@@ -29,14 +32,16 @@ processes racing one key both rename identical content — last one wins,
 harmlessly), manifest rows are single-line appends, and EVERY read path
 tolerates torn/corrupt state: a truncated manifest row or an unparsable
 payload is a *named miss* (``miss_reasons``), never an exception.  An
-unset ``CK_COMPILE_CACHE`` disables the disk layer entirely — warmup
+unset ``CK_COMPILE_CACHE`` disables the manifest layer entirely — warmup
 still precompiles in-process, results are bit-identical either way.
 
-The LRU size cap (``CK_COMPILE_CACHE_MAX_MB``, default 512) bounds
-``entries/`` + ``xla/`` bytes; :meth:`CompileCache.prune` evicts
-oldest-mtime files first (hits refresh an entry's mtime) and appends an
-``evict`` row per removal.  ``tools/ckcache.py`` is the operator CLI
-(``ls`` / ``stats`` / ``prune`` / ``--verify``).
+``CK_COMPILE_CACHE_MAX_MB`` (default 512) caps each layer separately:
+the package-placed XLA directory is trimmed to it whenever a ``Cores`` is
+built (:func:`trim_placed_jax_cache`), and
+:meth:`CompileCache.prune` holds ``entries/`` under it — oldest-mtime
+files first (hits refresh an entry's mtime), one ``evict`` row per
+removal.  ``tools/ckcache.py`` is the operator CLI (``ls`` / ``stats`` /
+``prune`` / ``--verify``) over the manifest layer.
 
 Cache I/O happens only on COLD paths — warmup, window engagement, the
 CLI — never on the fused-defer hot path (the ckcheck contract); metric
@@ -64,12 +69,13 @@ __all__ = [
     "CACHE",
     "warm_from_disk",
     "probe_counts",
+    "trim_placed_jax_cache",
 ]
 
 CACHE_ENV = "CK_COMPILE_CACHE"
 CACHE_MAX_MB_ENV = "CK_COMPILE_CACHE_MAX_MB"
 
-#: Default LRU byte cap over ``entries/`` + ``xla/``.
+#: Default LRU byte cap over ``entries/``.
 DEFAULT_MAX_MB = 512
 
 #: Manifest format tag (first line of every manifest).
@@ -89,6 +95,45 @@ _M_WRITE = REGISTRY.counter(
 _M_EVICT = REGISTRY.counter(
     "ck_compile_cache_evict_total",
     "files evicted by the persistent cache's LRU size cap")
+
+
+def trim_placed_jax_cache(max_bytes: int | None = None) -> int:
+    """Hold the jax cache directory THE PACKAGE PLACED
+    (``cekirdekler_tpu.PLACED_CACHE_DIR``) under the cap: delete
+    oldest-written executables until it fits, return how many went.
+    Called wherever a ``Cores`` is built (process start, a fabric join):
+    between calls the directory grows by what the process compiles, which
+    its in-memory executable cache holds as well.  A directory placed from
+    outside belongs to whoever placed it — nothing in it is deleted.
+
+    Not jax's own eviction (``jax_compilation_cache_max_size``): that
+    re-reads one stamp file per entry on EVERY write, under a file lock —
+    with a few hundred ladder executables on disk it doubled the cold
+    first-call cost on the chip (PERF.md, PR 21)."""
+    from .. import PLACED_CACHE_DIR
+
+    if PLACED_CACHE_DIR is None:
+        return 0
+    cap = CACHE.max_bytes() if max_bytes is None else int(max_bytes)
+    try:
+        with os.scandir(PLACED_CACHE_DIR) as entries:
+            files = sorted(
+                (e.stat().st_mtime, e.stat().st_size, e.path)
+                for e in entries if e.name.endswith("-cache"))
+    except OSError:  # not there yet: nothing to trim
+        return 0
+    total = sum(size for _t, size, _p in files)
+    evicted = 0
+    for _t, size, path in files:
+        if total <= cap:
+            break
+        try:
+            os.remove(path)
+            evicted += 1
+        except OSError:  # another process trimmed it first
+            pass
+        total -= size
+    return evicted
 
 
 def probe_counts() -> tuple[int, int]:
@@ -260,44 +305,22 @@ class CompileCache:
     def _entries_dir(self) -> str:
         return os.path.join(self.root, "entries")
 
-    def _xla_dir(self) -> str:
-        return os.path.join(self.root, "xla")
-
     def _manifest(self) -> str:
         return os.path.join(self.root, "manifest.jsonl")
 
     # -- arming --------------------------------------------------------------
     def arm(self) -> bool:
-        """Point JAX's persistent compilation cache at ``<root>/xla``
-        (idempotent; survives missing knobs on older jax — any config
-        seam that doesn't exist is skipped, the manifest layer still
-        works).  Returns True when the XLA seam engaged."""
+        """Create the manifest layout under the root.  Idempotent.  Where
+        the XLA bytes land, which of them persist and how many are kept
+        is not decided here (module docstring)."""
         root = self.root
         if root is None:
             return False
         if self._armed_dir == root:
             return True
         os.makedirs(self._entries_dir(), exist_ok=True)
-        xla = self._xla_dir()
-        os.makedirs(xla, exist_ok=True)
-        ok = False
-        try:
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", xla)
-            ok = True
-            for knob, val in (
-                ("jax_persistent_cache_min_compile_time_secs", 0),
-                ("jax_persistent_cache_min_entry_size_bytes", 0),
-            ):
-                try:
-                    jax.config.update(knob, val)
-                except Exception:  # noqa: BLE001 - older jax: keep floors
-                    pass
-        except Exception:  # noqa: BLE001 - no jax config seam: manifest-only
-            ok = False
         self._armed_dir = root
-        return ok
+        return True
 
     # -- keys ----------------------------------------------------------------
     def ladder_key(self, program, spec: WarmupSpec, platform: str | None,
@@ -477,9 +500,10 @@ class CompileCache:
     # -- size cap ------------------------------------------------------------
     def _lru_files(self) -> list[tuple[float, int, str]]:
         """(mtime, bytes, path) of every cap-governed file (entry
-        payloads + XLA executables; never the manifest)."""
+        payloads; never the manifest, and never jax's own cache
+        directory — module docstring)."""
         out = []
-        for d in (self._entries_dir(), self._xla_dir()):
+        for d in (self._entries_dir(),):
             try:
                 names = os.listdir(d)
             except OSError:
@@ -592,8 +616,8 @@ def warm_from_disk(cores, cache: CompileCache | None = None) -> dict:
     """Warm a :class:`~cekirdekler_tpu.core.cores.Cores` from the
     persisted fleet signature mix: load every well-formed spec whose
     kernels the cores' program actually contains, and run
-    ``Cores.warmup`` over them (each XLA compile is then served from the
-    armed disk cache).  A disabled cache, an empty cache, and corrupt
+    ``Cores.warmup`` over them (each XLA compile is then served from
+    jax's persistent compilation cache).  A disabled cache, an empty cache, and corrupt
     entries all degrade to ``{"warmed": 0, ...}`` — never an
     exception."""
     cache = CACHE if cache is None else cache

@@ -62,6 +62,7 @@ from .balance import (
     prior_split,
 )
 from .compilecache import CACHE as COMPILE_CACHE
+from .compilecache import trim_placed_jax_cache
 from .stream import TransferTuner, chunk_plan
 from .worker import Worker
 
@@ -146,13 +147,14 @@ class Cores:
         devices.require_nonempty("Cores device selection")
         self.devices = devices
         self.program = program
-        # persistent executable cache (core/compilecache.py): arming at
-        # construction — not lazily at first engage — means EVERY compile
-        # in an armed process lands in the XLA disk cache, including the
-        # per-call launchers a window's first 1-2 iterations ride before
-        # fused engagement.  No-op unless CK_COMPILE_CACHE is set.
+        # ladder manifest (core/compilecache.py): laid out at
+        # construction, before the first engage records into it.  No-op
+        # unless CK_COMPILE_CACHE is set.  jax's own executable cache is
+        # configured once, at package import; a directory the package
+        # placed is held under its size cap here.
         if COMPILE_CACHE.enabled:
             COMPILE_CACHE.arm()
+        trim_placed_jax_cache()
         self.workers = [Worker(d.jax_device, i) for i, d in enumerate(devices)]
         # heterogeneous lanes (ISSUE 20): each lane's device KIND and
         # its table-derived relative-rate prior (hardware.rate_prior).
@@ -348,7 +350,7 @@ class Cores:
         # feeds the balancer MARGINAL per-cid times instead of charging
         # the whole-window fence time to every id dispatched in a mixed
         # window (trace/attribution.split_fence_benches).  Off by
-        # default: the split costs one extra ~RTT probe per cid in the
+        # default: the split costs one extra completion wait per cid in the
         # window (plus workers pinning the probe buffers), and
         # homogeneous windows (one kernel per window) are measured
         # exactly either way.
@@ -1350,14 +1352,13 @@ class Cores:
     def _warm_targets(self) -> list:
         """Distinct (platform, donate, device_kind, device) combinations
         across this scheduler's lanes — the set of fused-launcher key
-        variants the live path can request.  ``donate`` is computed
-        EXACTLY as ``Worker.launch_fused`` computes it: a warmed key
-        that differs in any component is a silent no-op (the satellite-1
-        bug this method exists to prevent)."""
+        variants the live path can request.  ``donate`` is the lane's
+        own ``Worker.fused_donate``: a warmed key that differs in any
+        component is a silent no-op."""
         seen: dict = {}
         for w in self.workers:
             platform = w.device.platform
-            donate = platform == "tpu" and not w.track_cid_outputs
+            donate = w.fused_donate
             kind = str(getattr(w.device, "device_kind", platform))
             seen.setdefault((platform, donate, kind), w.device)
         return [(p, d, k, dev) for (p, d, k), dev in seen.items()]
@@ -1457,7 +1458,7 @@ class Cores:
                     keys.append(key)
                     hit = CACHE.lookup(key)
                 bufs = tuple(
-                    jax.device_put(jnp.zeros(n, dtype=np.dtype(d)), device)
+                    jnp.zeros(n, dtype=np.dtype(d), device=device)
                     for n, d in spec.params
                 )
                 # the fused predicated ladder, under the live path's key
@@ -2509,12 +2510,9 @@ class Cores:
         reading results back (enqueue-mode sync point; the reference's
         finish() on the used queues, Worker.cs:364-423).
 
-        Each chip is fenced by ONE fused probe (one tiny dispatch + one
-        4-byte D2H covering every cached buffer — see Worker.fence), and
-        the chips are fenced concurrently: total cost is one round trip,
-        not O(buffers × workers).  On tunneled backends a single RTT is
-        ~100 ms, so this is the difference between a usable and an unusable
-        sync point.
+        Each chip is fenced by ONE ``block_until_ready`` over its cached
+        buffers (see Worker.fence), and the chips are fenced concurrently
+        so each lane's retire time is measured from the same window start.
 
         A device/kernel failure surfacing at the fence is REAL — it is
         collected per worker and the first one re-raised after all workers
@@ -2540,7 +2538,7 @@ class Cores:
         feeds the balancer MARGINAL per-cid times
         (trace/attribution.split_fence_benches): batched mixed windows
         (all of id A, then all of id B) are then measured exactly per
-        id, at the cost of one extra ~RTT completion probe per id in
+        id, at the cost of one extra completion wait per id in
         the window; interleaved windows remain bounded by stream order
         (a cid's marginal includes earlier-dispatched work of
         later-completing ids).
@@ -2553,7 +2551,7 @@ class Cores:
         of different sizes feed the balancer one scale."""
         self._fused_close()
         # cached handle (constructor): the barrier is every window's
-        # fence — a registry get-or-create per window is window_rtt
+        # fence — a registry get-or-create per window is window_fence
         # residue (r7 attribution)
         self._m_barriers.inc()
         _mt0 = time.perf_counter()

@@ -122,7 +122,7 @@ class NumberCruncher:
         """Per-compute-id fence splitting at enqueue-mode barriers
         (VERDICT r5 #8): marginal per-cid benches from completion-order
         probes instead of one whole-window fence time charged to every
-        id in a mixed window.  Costs ~1 extra RTT probe per id per
+        id in a mixed window.  Costs one extra completion wait per id per
         barrier; off by default."""
         return self.cores.fence_split
 

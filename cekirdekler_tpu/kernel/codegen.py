@@ -1392,6 +1392,12 @@ class KernelBuildInfo:
     value_params: list[str]
     array_ctypes: dict[str, str]
     stored_params: list[str]  # params the kernel writes (discovered at trace)
+    # which lowering this launcher was built with: "pallas" (Mosaic tile
+    # path), "xla" (vectorized lowering) or "python" (a PythonKernel) —
+    # and, when a TPU launch was routed AWAY from Pallas, why
+    # (kernel/registry.py records both so a run can assert its routing)
+    lowering: str = "xla"
+    veto: str | None = None
 
 
 def build_kernel_fn(

@@ -461,6 +461,7 @@ def build_kernel_fn_pallas(
         value_params=[p.name for p in value_params],
         array_ctypes={p.name: p.ctype for p in array_params},
         stored_params=list(stored),
+        lowering="pallas",
     )
     grid = rows_total // rows
     scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
@@ -497,9 +498,16 @@ def build_kernel_fn_pallas(
         # time — PAST the registry's build-time PallasUnsupported
         # fallback — so the dtype check must live here at trace time
         # (probed on-device, r4; bf16/f32/ints all compile fine).
-        if (any(arrays[i].dtype == jnp.float16 for i in range(len(arrays)))
-                or sum(arrays[name_ix[n]].size * arrays[name_ix[n]].dtype.itemsize
-                       for n in smem_names) > SMEM_UNIFORM_LIMIT):
+        delegate = None
+        if any(a.dtype == jnp.float16 for a in arrays):
+            delegate = "float16 operand (Mosaic rejects f16 tiles)"
+        elif sum(arrays[name_ix[n]].size * arrays[name_ix[n]].dtype.itemsize
+                 for n in smem_names) > SMEM_UNIFORM_LIMIT:
+            delegate = (f"uniform-read buffers exceed the "
+                        f"{SMEM_UNIFORM_LIMIT}-byte SMEM budget")
+        if delegate is not None:
+            # recorded, not silent: the launcher's info names what ran
+            info.lowering, info.veto = "xla", delegate
             return xla_fn()(offset, arrays, values)
         off = jnp.asarray(offset, jnp.int32)
         # window [offset, offset+chunk) of every elementwise/stored param

@@ -180,6 +180,18 @@ class KernelProgram:
             return [p.name for p in self._c_kernels[name].params if not p.is_pointer]
         return list(self._py_kernels[name].value_params)
 
+    def lowerings(self, name: str, platform: str | None) -> set[tuple]:
+        """``{(lowering, veto), ...}`` over every launcher built so far
+        for kernel ``name`` on ``platform`` — how a run asserts its
+        routing (``{("pallas", None)}`` = every rung of the ladder went
+        through Mosaic)."""
+        with self._lock:
+            infos = [
+                info for key, (_fn, info) in self._cache.items()
+                if len(key) == 5 and key[0] == name and key[4] == platform
+            ]
+        return {(i.lowering, i.veto) for i in infos}
+
     # -- partition-safety verification (analysis/) ---------------------------
     def summaries(self) -> dict:
         """Per-kernel access summaries, built once per program (one
@@ -236,10 +248,12 @@ class KernelProgram:
         updated arrays tuple``.
 
         ``platform`` is the dispatch target's PJRT platform name
-        (``"tpu"``/``"cpu"``): on TPU, C-subset kernels in the elementwise
-        subset lower to Pallas tiles (kernel/pallas_backend.py — VMEM-
-        resident loop state, per-tile early exit) and fall back to the
-        vectorized XLA lowering otherwise."""
+        (``"tpu"``/``"cpu"``): on TPU, C-subset kernels in the tile
+        subset lower to Pallas (kernel/pallas_backend.py — VMEM-resident
+        loop state, per-tile early exit); kernels outside it, or routed
+        away by the measured policy, take the vectorized XLA lowering.
+        ``info.lowering`` / ``info.veto`` record which was built and why
+        — see :meth:`lowerings`."""
         key = (name, chunk, local_size, global_size, platform)
         with self._lock:
             hit = self._cache.get(key)
@@ -247,7 +261,7 @@ class KernelProgram:
             return hit
 
         if name in self._c_kernels:
-            raw_fn = info = None
+            raw_fn = info = veto = None
             if platform == "tpu":
                 from . import pallas_backend
 
@@ -255,12 +269,13 @@ class KernelProgram:
                     raw_fn, info = pallas_backend.build_kernel_fn_pallas(
                         self._c_kernels[name], chunk, local_size, global_size
                     )
-                except pallas_backend.PallasUnsupported:
-                    raw_fn = None
+                except pallas_backend.PallasUnsupported as e:
+                    veto = str(e)
             if raw_fn is None:
                 raw_fn, info = codegen.build_kernel_fn(
                     self._c_kernels[name], chunk, local_size, global_size
                 )
+                info.veto = veto
         elif name in self._py_kernels:
             pk = self._py_kernels[name]
 
@@ -282,6 +297,7 @@ class KernelProgram:
                 value_params=list(pk.value_params),
                 array_ctypes={},
                 stored_params=list(pk.array_params),
+                lowering="python",
             )
         else:
             raise KernelCompileError(
@@ -364,7 +380,7 @@ class KernelProgram:
         jitted = jax.jit(raw)
         info = codegen.KernelBuildInfo(
             name="+".join(names), array_params=[], value_params=[],
-            array_ctypes={}, stored_params=[],
+            array_ctypes={}, stored_params=[], lowering="ladder",
         )
         with self._lock:
             self._cache[key] = (jitted, info)
@@ -462,6 +478,7 @@ class KernelProgram:
         info = codegen.KernelBuildInfo(
             name="fused:" + "+".join(names), array_params=[],
             value_params=[], array_ctypes={}, stored_params=[],
+            lowering="ladder",
         )
         with self._lock:
             self._cache[key] = (jitted, info)

@@ -1,3 +1,3 @@
-from .build import available, load
+from .build import available, load, runtime
 
-__all__ = ["available", "load"]
+__all__ = ["available", "load", "runtime"]

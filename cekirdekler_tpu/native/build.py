@@ -1,29 +1,41 @@
-"""Lazy builder + ctypes loader for the native host runtime library.
+"""Builder + ctypes loader for the native host runtime library.
 
-The reference ships its native layer as a prebuilt DLL; we build ours from
-source on first use with the system toolchain and cache the shared object
-next to the source.  Thread-safe; failures degrade gracefully (callers fall
-back to pure-numpy host arrays).
+The reference ships its native layer as a prebuilt DLL; ours is built
+from ``kutuphane_tpu.cpp`` — the only tracked artifact — with the system
+toolchain on first use.  The shared object is named after a hash of the
+source, so "is the build current?" is answered by content, never by file
+mtimes (a copied or freshly checked-out tree has arbitrary ones).
+Thread-safe.  If the build or load fails, :func:`load` returns ``None``
+and callers use their pure-Python host paths — with one warning, and
+:func:`runtime` naming which runtime is active and why.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
+import hashlib
+import re
 import subprocess
 import threading
+import warnings
 from pathlib import Path
 
 _HERE = Path(__file__).resolve().parent
 _SRC = _HERE / "kutuphane_tpu.cpp"
-_LIB = _HERE / "libkutuphane_tpu.so"
+_ABI = 2
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
-_load_failed = False
+_load_error: str | None = None
 
 
-def _compile() -> bool:
+def _lib_path() -> Path:
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:12]
+    return _HERE / f"libkutuphane_tpu.{digest}.so"
+
+
+def _compile(out: Path) -> None:
+    tmp = out.with_suffix(f".tmp{threading.get_ident()}.so")
     cmd = [
         "g++",
         "-O2",
@@ -33,13 +45,18 @@ def _compile() -> bool:
         "-fvisibility=hidden",
         str(_SRC),
         "-o",
-        str(_LIB),
+        str(tmp),
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
+        tmp.replace(out)  # atomic: a racing process never loads a half file
+    finally:
+        tmp.unlink(missing_ok=True)
+    # builds of OLDER sources only — never a racing process's tmp file
+    for stale in _HERE.glob("libkutuphane_tpu.*.so"):
+        if stale != out and re.fullmatch(
+                r"libkutuphane_tpu\.[0-9a-f]{12}\.so", stale.name):
+            stale.unlink(missing_ok=True)
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -100,31 +117,40 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def load() -> ctypes.CDLL | None:
-    """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _load_failed
-    if _lib is not None:
+    """Load (building if needed) the native library; None if unavailable
+    — the failure is warned once and kept for :func:`runtime`."""
+    global _lib, _load_error
+    if _lib is not None or _load_error is not None:
         return _lib
-    if _load_failed:
-        return None
     with _lock:
-        if _lib is not None:
+        if _lib is not None or _load_error is not None:
             return _lib
-        if _load_failed:
-            return None
         try:
-            if not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
-                if not _compile():
-                    _load_failed = True
-                    return None
-            lib = ctypes.CDLL(str(_LIB))
-            if lib.ck_abiVersion() != 2:
-                raise OSError("ABI mismatch")
+            path = _lib_path()
+            if not path.exists():
+                _compile(path)
+            lib = ctypes.CDLL(str(path))
+            if lib.ck_abiVersion() != _ABI:
+                raise OSError(f"ABI {lib.ck_abiVersion()} != {_ABI}")
             _lib = _bind(lib)
-            return _lib
-        except Exception:
-            _load_failed = True
-            return None
+        except (OSError, subprocess.SubprocessError) as e:
+            detail = getattr(e, "stderr", b"") or b""
+            _load_error = (f"{type(e).__name__}: {e} "
+                           f"{detail.decode(errors='replace')[-300:]}").strip()
+            warnings.warn(
+                "cekirdekler_tpu native host runtime unavailable — using "
+                f"the pure-Python host paths ({_load_error})",
+                RuntimeWarning, stacklevel=2)
+        return _lib
 
 
 def available() -> bool:
     return load() is not None
+
+
+def runtime() -> str:
+    """Which host runtime is active: ``"native (<file>, abi N)"`` or
+    ``"python (<why the native build is unavailable>)"``."""
+    if load() is not None:
+        return f"native ({_lib_path().name}, abi {_ABI})"
+    return f"python ({_load_error})"

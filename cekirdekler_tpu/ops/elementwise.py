@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import call_by_platform
+
 __all__ = ["map_blocks", "saxpy"]
 
 _LANES = 128
@@ -31,7 +33,9 @@ def map_blocks(
     interpret: bool | None = None,
 ):
     """Apply elementwise ``fn(*blocks) -> block`` over 1-D arrays of equal
-    length (multiple of 128)."""
+    length (multiple of 128).  ``interpret=None`` lowers per dispatch
+    platform when called under ``jit`` (as :func:`saxpy` does); called
+    eagerly it follows the default backend (ops/platform.py)."""
     n = arrays[0].shape[0]
     if any(a.shape != (n,) for a in arrays):
         raise ValueError("map_blocks needs equal-length 1-D arrays")
@@ -42,21 +46,26 @@ def map_blocks(
     while rows_total % rows != 0:
         rows //= 2
     rows = max(rows, 1)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     def kernel(*refs):
         out_ref = refs[-1]
         out_ref[:] = fn(*(r[:] for r in refs[:-1]))
 
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((rows_total, _LANES), arrays[0].dtype),
-        grid=(rows_total // rows,),
-        in_specs=[pl.BlockSpec((rows, _LANES), lambda i: (i, 0)) for _ in arrays],
-        out_specs=pl.BlockSpec((rows, _LANES), lambda i: (i, 0)),
-        interpret=interpret,
-    )(*(a.reshape(rows_total, _LANES) for a in arrays))
+    def make_call(interp: bool):
+        return pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct(
+                (rows_total, _LANES), arrays[0].dtype),
+            grid=(rows_total // rows,),
+            in_specs=[pl.BlockSpec((rows, _LANES), lambda i: (i, 0))
+                      for _ in arrays],
+            out_specs=pl.BlockSpec((rows, _LANES), lambda i: (i, 0)),
+            interpret=interp,
+        )
+
+    out = call_by_platform(
+        interpret, make_call,
+        *(a.reshape(rows_total, _LANES) for a in arrays))
     return out.reshape(n)
 
 

@@ -52,6 +52,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from .platform import call_by_platform
+
 __all__ = ["flash_attention", "flash_attention_parts",
            "flash_attention_bwd_parts", "auto_block", "default_blocks",
            "fused_qkv", "fused_qkv_attention"]
@@ -220,11 +222,11 @@ def _fa_kernel(*refs, scale, block_q, block_k, n_kb, causal, precision,
 
 
 def _resolve(interpret, precision):
-    """One place for the interpret default (Pallas interpreter off-TPU)
-    and the precision-string -> lax.Precision mapping — used by the
-    primal, parts, fwd, and bwd paths so they can never diverge."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    """One place for the precision-string -> lax.Precision mapping — used
+    by the primal, parts, fwd, and bwd paths so they can never diverge.
+    ``interpret`` passes through untouched: ``None`` means "lower per
+    dispatch platform" and is resolved at each ``pallas_call``
+    (ops/platform.py), never from the process's default backend."""
     precision = _precision_str(precision)  # validate enum/string spellings
     prec = (
         lax.Precision.HIGHEST if precision == "highest"
@@ -271,14 +273,10 @@ def _mosaic_params(interpret, pltpu):
     """Megacore partitioning hint: the (bh, major) grid axes are
     embarrassingly parallel, only the minor streaming axis is a
     sequential reduction.  Without the hint Mosaic serializes the whole
-    grid on one core (half the chip idle on v5e)."""
+    grid on one core of a multi-core (megacore) chip."""
     if interpret:
         return {}
-    CP = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-    if CP is None:  # pragma: no cover - very old pallas
-        return {}
-    return {"compiler_params": CP(
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
@@ -336,11 +334,8 @@ def _vma_sds(*operands):
     varying-axes sets — under shard_map every pallas_call output must
     declare how it varies over mesh axes (a replicated q attending
     sharded k/v still produces per-shard-varying output)."""
-    try:
-        vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
-        return functools.partial(jax.ShapeDtypeStruct, vma=vma)
-    except (TypeError, AttributeError):
-        return jax.ShapeDtypeStruct
+    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    return functools.partial(jax.ShapeDtypeStruct, vma=vma)
 
 
 @functools.partial(
@@ -385,24 +380,28 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret, precision,
         out_specs = [out_specs,
                      pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))]
         out_shape = [out_shape, sds((B * H, Tq, 1), jnp.float32)]
-    res = pl.pallas_call(
-        kernel,
-        grid=(B * H, Tq // bq, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), kv_idx),
-            pl.BlockSpec((1, bk, D), kv_idx),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),    # running max
-            pltpu.VMEM((bq, 1), jnp.float32),    # running denominator
-            pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
-        ],
-        interpret=interpret,
-        **_mosaic_params(interpret, pltpu),
-    )(q3, k3, v3)
+
+    def make_call(interp: bool):
+        return pl.pallas_call(
+            kernel,
+            grid=(B * H, Tq // bq, n_kb),
+            in_specs=[
+                pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, bk, D), kv_idx),
+                pl.BlockSpec((1, bk, D), kv_idx),
+            ],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),    # running max
+                pltpu.VMEM((bq, 1), jnp.float32),    # running denominator
+                pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
+            ],
+            interpret=interp,
+            **_mosaic_params(interp, pltpu),
+        )
+
+    res = call_by_platform(interpret, make_call, q3, k3, v3)
     if with_lse:
         out, lse = res
         return (
@@ -453,31 +452,30 @@ def flash_attention_parts(
     tile_q = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
     tile_k = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0))
     tile_ml = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
-    try:
-        vma = frozenset(
-            jax.typeof(q3).vma | jax.typeof(k3).vma | jax.typeof(v3).vma
+    sds = _vma_sds(q3, k3, v3)
+
+    def make_call(interp: bool):
+        return pl.pallas_call(
+            kernel,
+            grid=(B * H, Tq // bq, n_kb),
+            in_specs=[scalar_spec, scalar_spec, tile_q, tile_k, tile_k],
+            out_specs=[tile_q, tile_ml, tile_ml],
+            out_shape=[
+                sds((B * H, Tq, D), jnp.float32),
+                sds((B * H, Tq, 1), jnp.float32),
+                sds((B * H, Tq, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, D), jnp.float32),
+            ],
+            interpret=interp,
+            **_mosaic_params(interp, pltpu),
         )
-        sds = functools.partial(jax.ShapeDtypeStruct, vma=vma)
-    except (TypeError, AttributeError):
-        sds = jax.ShapeDtypeStruct
-    acc, m, l = pl.pallas_call(
-        kernel,
-        grid=(B * H, Tq // bq, n_kb),
-        in_specs=[scalar_spec, scalar_spec, tile_q, tile_k, tile_k],
-        out_specs=[tile_q, tile_ml, tile_ml],
-        out_shape=[
-            sds((B * H, Tq, D), jnp.float32),
-            sds((B * H, Tq, 1), jnp.float32),
-            sds((B * H, Tq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
-        interpret=interpret,
-        **_mosaic_params(interpret, pltpu),
-    )(
+
+    acc, m, l = call_by_platform(
+        interpret, make_call,
         jnp.asarray(q_pos0, jnp.int32).reshape(1, 1),
         jnp.asarray(k_pos0, jnp.int32).reshape(1, 1),
         q3, k3, v3,
@@ -691,49 +689,57 @@ def _flash_backward(q, k, v, out, lse3, do, causal, block_q, block_k,
     do3 = do.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
     sds = _vma_sds(q3, k3, v3, do3)
     n_qb, n_kb = Tq // bq, Tk // bk
-    mosaic = _mosaic_params(interpret, pltpu)
     tile_q = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
     tile_ml = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
     tile_k_minor = pl.BlockSpec((1, bk, D), _stream_idx(bq, bk, causal, "k"))
-    dq = pl.pallas_call(
-        functools.partial(
-            _fa_bwd_dq_kernel, scale=scale, block_q=bq, block_k=bk,
-            n_kb=n_kb, causal=causal, precision=prec,
-        ),
-        grid=(B * H, n_qb, n_kb),
-        in_specs=[tile_q, tile_k_minor, tile_k_minor, tile_q, tile_ml,
-                  tile_ml],
-        out_specs=tile_q,
-        out_shape=sds((B * H, Tq, D), dq_dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
-        **mosaic,
-    )(q3, k3, v3, do3, lse3, dlt3)
+
+    def make_dq(interp: bool):
+        return pl.pallas_call(
+            functools.partial(
+                _fa_bwd_dq_kernel, scale=scale, block_q=bq, block_k=bk,
+                n_kb=n_kb, causal=causal, precision=prec,
+            ),
+            grid=(B * H, n_qb, n_kb),
+            in_specs=[tile_q, tile_k_minor, tile_k_minor, tile_q, tile_ml,
+                      tile_ml],
+            out_specs=tile_q,
+            out_shape=sds((B * H, Tq, D), dq_dtype),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            interpret=interp,
+            **_mosaic_params(interp, pltpu),
+        )
+
+    operands = (q3, k3, v3, do3, lse3, dlt3)
+    dq = call_by_platform(interpret, make_dq, *operands)
     # dk/dv: k-block is the 2nd grid axis, q streams as the minor axis
     q_idx = _stream_idx(bq, bk, causal, "q")
     tile_q_minor = pl.BlockSpec((1, bq, D), q_idx)
     tile_ml_minor = pl.BlockSpec((1, bq, 1), q_idx)
     tile_k = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _fa_bwd_dkv_kernel, scale=scale, block_q=bq, block_k=bk,
-            n_qb=n_qb, causal=causal, precision=prec,
-        ),
-        grid=(B * H, n_kb, n_qb),
-        in_specs=[tile_q_minor, tile_k, tile_k, tile_q_minor, tile_ml_minor,
-                  tile_ml_minor],
-        out_specs=[tile_k, tile_k],
-        out_shape=[
-            sds((B * H, Tk, D), dk_dtype),
-            sds((B * H, Tk, D), dv_dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
-        interpret=interpret,
-        **mosaic,
-    )(q3, k3, v3, do3, lse3, dlt3)
+
+    def make_dkv(interp: bool):
+        return pl.pallas_call(
+            functools.partial(
+                _fa_bwd_dkv_kernel, scale=scale, block_q=bq, block_k=bk,
+                n_qb=n_qb, causal=causal, precision=prec,
+            ),
+            grid=(B * H, n_kb, n_qb),
+            in_specs=[tile_q_minor, tile_k, tile_k, tile_q_minor,
+                      tile_ml_minor, tile_ml_minor],
+            out_specs=[tile_k, tile_k],
+            out_shape=[
+                sds((B * H, Tk, D), dk_dtype),
+                sds((B * H, Tk, D), dv_dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+            ],
+            interpret=interp,
+            **_mosaic_params(interp, pltpu),
+        )
+
+    dk, dv = call_by_platform(interpret, make_dkv, *operands)
     reshape = lambda a, T: a.reshape(B, H, T, D).transpose(0, 2, 1, 3)
     return reshape(dq, Tq), reshape(dk, Tk), reshape(dv, Tk)
 
@@ -789,51 +795,59 @@ def flash_attention_bwd_parts(
     )
     sds = _vma_sds(q3, k3, v3, do3)
     n_qb, n_kb = Tq // bq, Tk // bk
-    mosaic = _mosaic_params(interpret, pltpu)
     scalar_spec = pl.BlockSpec((1, 1), lambda b, i, j: (0, 0),
                                memory_space=pltpu.SMEM)
     tile_q = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
     tile_ml = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
     tile_k_minor = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0))
-    dq = pl.pallas_call(
-        functools.partial(
-            _fa_bwd_dq_kernel, scale=scale, block_q=bq, block_k=bk,
-            n_kb=n_kb, causal=causal, precision=prec, parts=True,
-        ),
-        grid=(B * H, n_qb, n_kb),
-        in_specs=[scalar_spec, scalar_spec, tile_q, tile_k_minor,
-                  tile_k_minor, tile_q, tile_ml, tile_ml],
-        out_specs=tile_q,
-        out_shape=sds((B * H, Tq, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
-        **mosaic,
-    )(*offs, q3, k3, v3, do3, lse3, dlt3)
+
+    def make_dq(interp: bool):
+        return pl.pallas_call(
+            functools.partial(
+                _fa_bwd_dq_kernel, scale=scale, block_q=bq, block_k=bk,
+                n_kb=n_kb, causal=causal, precision=prec, parts=True,
+            ),
+            grid=(B * H, n_qb, n_kb),
+            in_specs=[scalar_spec, scalar_spec, tile_q, tile_k_minor,
+                      tile_k_minor, tile_q, tile_ml, tile_ml],
+            out_specs=tile_q,
+            out_shape=sds((B * H, Tq, D), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            interpret=interp,
+            **_mosaic_params(interp, pltpu),
+        )
+
+    operands = (*offs, q3, k3, v3, do3, lse3, dlt3)
+    dq = call_by_platform(interpret, make_dq, *operands)
     tile_q_minor = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0))
     tile_ml_minor = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0))
     tile_k = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
     scalar_spec_m = pl.BlockSpec((1, 1), lambda b, j, i: (0, 0),
                                  memory_space=pltpu.SMEM)
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _fa_bwd_dkv_kernel, scale=scale, block_q=bq, block_k=bk,
-            n_qb=n_qb, causal=causal, precision=prec, parts=True,
-        ),
-        grid=(B * H, n_kb, n_qb),
-        in_specs=[scalar_spec_m, scalar_spec_m, tile_q_minor, tile_k,
-                  tile_k, tile_q_minor, tile_ml_minor, tile_ml_minor],
-        out_specs=[tile_k, tile_k],
-        out_shape=[
-            sds((B * H, Tk, D), jnp.float32),
-            sds((B * H, Tk, D), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
-        interpret=interpret,
-        **mosaic,
-    )(*offs, q3, k3, v3, do3, lse3, dlt3)
+
+    def make_dkv(interp: bool):
+        return pl.pallas_call(
+            functools.partial(
+                _fa_bwd_dkv_kernel, scale=scale, block_q=bq, block_k=bk,
+                n_qb=n_qb, causal=causal, precision=prec, parts=True,
+            ),
+            grid=(B * H, n_kb, n_qb),
+            in_specs=[scalar_spec_m, scalar_spec_m, tile_q_minor, tile_k,
+                      tile_k, tile_q_minor, tile_ml_minor, tile_ml_minor],
+            out_specs=[tile_k, tile_k],
+            out_shape=[
+                sds((B * H, Tk, D), jnp.float32),
+                sds((B * H, Tk, D), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+            ],
+            interpret=interp,
+            **_mosaic_params(interp, pltpu),
+        )
+
+    dk, dv = call_by_platform(interpret, make_dkv, *operands)
     reshape = lambda a, T: a.reshape(B, H, T, D).transpose(0, 2, 1, 3)
     return reshape(dq, Tq), reshape(dk, Tk), reshape(dv, Tk)
 
@@ -898,7 +912,8 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
 
     Shapes match :func:`parallel.attention.attention_reference`:
     q [B, Tq, H, D], k/v [B, Tk, H, D] → [B, Tq, H, D].
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU.
+    ``interpret=None`` lowers per dispatch platform (Mosaic on a TPU,
+    the Pallas interpreter elsewhere — ops/platform.py).
     ``precision``: "highest" (true-f32 MXU passes, matches the dense
     reference bit-for-bit-ish) or "default" (bf16 end-to-end: f32 inputs
     are cast to bf16 once at the XLA level, the kernels stream and

@@ -19,6 +19,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import call_by_platform
+
 __all__ = ["mandelbrot_pallas", "MANDEL_LANES", "MANDEL_SUBLANES"]
 
 MANDEL_LANES = 128      # TPU lane width
@@ -94,7 +96,8 @@ def mandelbrot_pallas(
 
     ``n`` must be a multiple of 128; blocks are (block_rows, 128);
     ``offset`` may be a traced scalar (no retrace per chunk).
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU.
+    ``interpret=None`` lowers per dispatch platform (Mosaic on a TPU,
+    the Pallas interpreter elsewhere — ops/platform.py).
     """
     if n % MANDEL_LANES != 0:
         raise ValueError(f"n ({n}) must be a multiple of {MANDEL_LANES}")
@@ -103,8 +106,6 @@ def mandelbrot_pallas(
     while rows_total % rows != 0:
         rows //= 2
     rows = max(rows, 1)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     # python-float scalars fold into the kernel trace (array constants are
     # rejected by pallas_call); f32 rounding of the coefficients matches the
     # kernel-language path
@@ -115,16 +116,22 @@ def mandelbrot_pallas(
         width=width, max_iter=max_iter, rows=rows,
     )
     grid = rows_total // rows
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((rows_total, MANDEL_LANES), jnp.float32),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM
-            )
-        ],
-        out_specs=pl.BlockSpec((rows, MANDEL_LANES), lambda i: (i, 0)),
-        interpret=interpret,
-    )(jnp.asarray(offset, jnp.int32).reshape(1, 1))
+
+    def make_call(interp: bool):
+        return pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct(
+                (rows_total, MANDEL_LANES), jnp.float32),
+            grid=(grid,),
+            in_specs=[
+                pl.BlockSpec(
+                    (1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM
+                )
+            ],
+            out_specs=pl.BlockSpec((rows, MANDEL_LANES), lambda i: (i, 0)),
+            interpret=interp,
+        )
+
+    out = call_by_platform(
+        interpret, make_call, jnp.asarray(offset, jnp.int32).reshape(1, 1))
     return out.reshape(n)

@@ -14,7 +14,6 @@ bound by the enclosing mesh.
 
 from __future__ import annotations
 
-import jax
 from jax import lax
 
 __all__ = [
@@ -57,16 +56,9 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str):
-    """Static size of a bound mesh axis.  lax.axis_size on current jax;
-    on pre-0.6 jax (CPU-only rigs) jax.core.axis_frame(name) already IS
-    the static int size inside shard_map — one compat point for every
-    ring/pipeline/MoE caller that needs a python int (perm tables,
-    capacity math, unrolled schedules)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    import jax
-
-    return jax.core.axis_frame(axis)  # older jax (0.4.x rigs)
+    """Static size of a bound mesh axis — a python int inside shard_map
+    (perm tables, capacity math, unrolled schedules need one)."""
+    return lax.axis_size(axis)
 
 
 def _ring_perm(n: int, shift: int):

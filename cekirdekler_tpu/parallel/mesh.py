@@ -40,43 +40,8 @@ __all__ = [
 AXIS_NAMES = ("dp", "fsdp", "pp", "tp", "sp", "ep")
 
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # older jax (e.g. the 0.4.x CPU-only rigs)
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, **kw):
-        """Compat: pre-0.6 jax ships shard_map under jax.experimental
-        with ``check_rep`` instead of ``check_vma`` and ``auto`` (the
-        complement set) instead of ``axis_names``.  One shim here so
-        every caller (attention/moe/pipeline/transformer) stays written
-        against the current API."""
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        if "axis_names" in kw:
-            manual = set(kw.pop("axis_names"))
-            mesh_ = kw["mesh"]
-            # manualizing a size-1 axis is a no-op, and the old
-            # shard_map's auto support is partial (eager `if auto:
-            # raise NotImplementedError`; PartitionId failures under
-            # jit) — only axes that actually span devices go auto
-            auto = frozenset(
-                a for a in mesh_.axis_names
-                if a not in manual and mesh_.shape[a] > 1
-            )
-            if auto:
-                kw["auto"] = auto
-                # the old rep checker predates auto axes; it false-alarms
-                # on psum-into-auto patterns the new checker accepts
-                kw.setdefault("check_rep", False)
-        return _shard_map_exp(f, **kw)
-
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-else:  # older jax: Mesh is itself the context manager
-    def set_mesh(mesh: Mesh) -> Mesh:
-        return mesh
+shard_map = jax.shard_map
+set_mesh = jax.set_mesh
 
 
 def make_mesh(

@@ -109,11 +109,6 @@ STORE_SCHEMA = "ck-kernel-profile-v1"
 #: Environment variable naming the persistent profile-store directory.
 PROFILE_STORE_ENV = "CK_PROFILE_STORE"
 
-#: Default machine roofline (TPU v5e public spec) — callers with a
-#: different rig pass their own peaks to :func:`roofline_row`.
-V5E_PEAK_BF16_TFLOPS = 197.0
-V5E_HBM_GBPS = 819.0
-
 
 # ---------------------------------------------------------------------------
 # marks: the launch-side half of the correlation
@@ -729,9 +724,9 @@ def roofline_row(
     ``flops``/``bytes_moved`` are the workload's analytic counts (the
     same numbers the bench's MFU rows use), ``device_ms`` the measured
     device-busy time.  Peaks default from :func:`hardware.device_peaks`
-    for the current rig's device kind (``device_kind`` names one
-    explicitly; ``peak_tflops``/``peak_gbps`` override outright) — an
-    MFU printed on a non-v5e rig is no longer silently scaled to v5e.
+    for the running device's kind (``device_kind`` names one
+    explicitly; ``peak_tflops``/``peak_gbps`` override outright); a
+    kind the peak table does not list raises — no assumed roof.
     Returns intensity (flop/byte), attained Tflop/s and GB/s, the roof
     at this intensity, MFU vs the compute peak, the fraction of the
     (possibly memory-slanted) roof attained, and which side of the

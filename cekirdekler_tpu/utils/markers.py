@@ -133,13 +133,12 @@ class MarkerCounter:
         # joined with ONE jax.block_until_ready over the whole batch (NOT
         # only the newest item — transfer and compute streams of one
         # device can retire out of order, so a single-item join would
-        # under-prove the batch).  Without batching, on a tunneled backend
-        # where every join costs ~1 RTT (~100 ms), the thread lags minutes
-        # behind a burst of light dispatches, remaining() wildly
-        # overestimates in-flight depth, and close()'s bounded join leaves
-        # an orphan thread to die inside PJRT teardown at interpreter exit
-        # (native terminate).  The whole batch retires as ONE weighted
-        # rate sample (see below).
+        # under-prove the batch).  Without batching, a burst of light
+        # dispatches pays one join per item, the thread lags behind,
+        # remaining() overestimates in-flight depth, and close()'s
+        # bounded join can leave an orphan thread to die inside PJRT
+        # teardown at interpreter exit (native terminate).  The whole
+        # batch retires as ONE weighted rate sample (see below).
         while True:
             # ckcheck: ok sentinel-terminated daemon loop — close()
             # always enqueues the None sentinel; the unbounded get is

@@ -7,12 +7,13 @@ the TPU-native upgrade: capture an Xprof trace around any region, then
 answer "how busy was the chip, and how much of the wall time did compute
 cover?" from the DEVICE-side event stream instead of host stopwatches.
 
-Backend caveat, stated honestly: tunneled/remote PJRT backends expose XLA
-module/op execution events but not DMA-engine events, so transfer busy time
-cannot be read off the device timeline there — compute busy/span can, and is
-exactly the evidence needed for overlap claims ("during the pipelined run
-the compute stream was busy X% of the makespan; transfers supplied it
-without starving it").
+What is reduced here is the device planes' "XLA Ops" tracks: compute
+busy time and span — the evidence overlap claims need ("during the
+pipelined run the compute stream was busy X% of the makespan; transfers
+supplied it without starving it").  Transfer (DMA) tracks are not reduced.
+Checked against the installed profiler on a v5e (jax 0.9.0): the dump is
+``plugins/profile/<ts>/<host>.trace.json.gz`` beside the ``.xplane.pb``,
+device processes are named ``/device:TPU:<n>``, their op track ``XLA Ops``.
 """
 
 from __future__ import annotations

@@ -1,0 +1,999 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof the system still starts on the chip.
+
+Drives the main path once, through the entry points a user calls
+(``all_devices().tpus()``, ``NumberCruncher``, ``ClArray.compute``, enqueue
+windows, ``DevicePipeline``/``ClPipeline``, ``ServeFrontend.submit``,
+``flash_attention``, ``__graft_entry__.entry``), at the sizes upstream and
+this repo document for real use, and checks every result against the plain
+host reference the repo already has.  One process, no children; data from
+``--seed``.
+
+Fails (non-zero exit, reason named) when: no TPU is found; a stage raises;
+a result misses its tolerance; a Pallas kernel ran interpreted; a
+kernel-language kernel the routing policy sends to Pallas ran on the XLA
+lowering; a lane's buffers sit on a device other than its own chip.
+
+Pallas kernels are called the way users call them — ``interpret`` left
+out, so the lowering follows the lane (``ops/platform.py``) — and every such
+row checks the lowering it got: on a TPU lane a Mosaic custom call must be
+in it.  The rows ISSUE 21 names with a forced lowering say so in their name.
+
+Each stage is a function of ``(devices, sizes)`` returning result rows —
+``tests/test_chip_smoke.py`` calls them on the CPU rig at toy sizes, where
+the same rule interprets the kernels.  The "no chip -> fail" rule lives in
+:func:`main` only.
+
+Last line of stdout on success::
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import sys
+import tempfile
+import threading
+import time
+
+import numpy as np
+
+# ---------------------------------------------------------------------------
+# sizes: FULL is what main() runs on the chip; the CPU-rig test passes its own
+# ---------------------------------------------------------------------------
+
+FULL = {
+    "seed": 0,
+    # stage 1 — upstream's mandelbrot demo frame and Tester.nBody's scale
+    "mandel_wh": 2048, "mandel_max_iter": 256, "local_range": 256,
+    "mandel_per_call": 4, "mandel_window": 32, "mandel_marker_window": 8,
+    "nbody_n": 8192, "nbody_iters": 150, "nbody_window": 50,
+    # stage 2 — 256 MiB per array (bench.py's HBM-stream size): not a cache
+    "stream_n": 1 << 26, "stream_tuner_runs": 3,
+    # stage 3 — the examples/wave_equation.py stage
+    "wave_pushes": 40,
+    # stage 4 — 4 tenants x 2 signatures x 16 requests, 16 MiB arrays
+    "serve_tenants": 4, "serve_sigs": 2, "serve_reqs": 16,
+    "serve_n": 1 << 22, "serve_local": 256,
+    # stage 5 — the flash bench shape and the tiled / dense-fallback lengths
+    "flash_bhd": (2, 8, 64),
+    "flash_T": (4096, 8192), "flash_tiled_T": 640, "flash_dense_T": 96,
+    "flash_oneshot_T": 512, "qkv_T": 1024,
+    "saxpy_n": 1 << 22, "backend_n": 1 << 20, "backend_nbody_n": 4096,
+    # stage 6
+    "trace_iters": 8,
+}
+
+
+class SmokeFailure(Exception):
+    """A check of the smoke did not hold (the message names it)."""
+
+
+def _require(cond: bool, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+def _timed(fn):
+    t0 = time.perf_counter()
+    out = fn()
+    return out, time.perf_counter() - t0
+
+
+def _row(name: str, lowering: str, cold_s: float, run_s: float,
+         max_err: float, **extra) -> dict:
+    return {"name": name, "lowering": lowering, "cold_s": round(cold_s, 3),
+            "run_s": round(run_s, 4), "max_err": float(max_err), **extra}
+
+
+def _lane_platforms(devices) -> list[str]:
+    return [d.jax_device.platform for d in devices]
+
+
+def _check_placement(cr) -> None:
+    """Every lane's cached buffers live on that lane's own chip."""
+    for w in cr.cores.workers:
+        for buf in list(w._buffers.values()):
+            _require(
+                buf.devices() == {w.device},
+                f"lane {w.index} buffer on {buf.devices()}, "
+                f"not its own {w.device}")
+
+
+def _check_routing(cr, kernel: str, devices, expect: str) -> str:
+    """On TPU lanes every launcher rung of ``kernel`` must have been built
+    with the lowering the routing policy promises; returns what ran."""
+    seen: set = set()
+    for plat in set(_lane_platforms(devices)):
+        got = cr.cores.program.lowerings(kernel, plat)
+        seen |= got
+        if plat == "tpu":
+            _require(
+                got == {(expect, None)},
+                f"kernel {kernel!r} on tpu lanes: expected every rung on "
+                f"the {expect} lowering, got {sorted(map(str, got))}")
+    return "+".join(sorted({lo for lo, _v in seen})) or "none"
+
+
+def _mosaic_calls(jitted, *args) -> int:
+    """Mosaic custom calls in a jitted function's lowering for the platform
+    ``args`` live on — 0 means every Pallas kernel inside lowered to the
+    interpreter (or there is none)."""
+    return jitted.lower(*args).as_text().count("tpu_custom_call")
+
+
+def _check_compiled(platform: str, n_calls: int, what: str) -> None:
+    """A Pallas kernel dispatched to a TPU must have lowered to Mosaic."""
+    if platform == "tpu":
+        _require(n_calls > 0, f"{what}: Pallas kernel ran interpreted "
+                              "(no Mosaic call in the lowering)")
+
+
+def _launcher_mosaic_calls(cr, kernel: str, n_arrays: int, values, lr: int,
+                           n: int) -> dict:
+    """``{lane platform: Mosaic calls}`` in the lowering of ``kernel``'s
+    largest ladder rung — the jitted launcher ``compute()`` dispatched and
+    the fused ladder nests under ``lax.cond`` — lowered for a lane of each
+    platform in the cruncher."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    chunk = lr << ((n // lr).bit_length() - 1)
+    out = {}
+    for w in cr.cores.workers:
+        plat = w.device.platform
+        if plat in out:
+            continue
+        fn, _info = cr.cores.program.launcher(kernel, chunk, lr, n, plat)
+        arrays = tuple(
+            jax.ShapeDtypeStruct((n,), np.float32,
+                                 sharding=SingleDeviceSharding(w.device))
+            for _ in range(n_arrays))
+        out[plat] = fn.lower(0, arrays, tuple(values)).as_text().count(
+            "tpu_custom_call")
+    return out
+
+
+# ---------------------------------------------------------------------------
+# stage 1: compute() per call and in an enqueue window
+# ---------------------------------------------------------------------------
+
+def _marker_window(cr, call, out, want, iters: int, label: str) -> dict:
+    """One enqueue window with fine-grained queue control on.  The marker
+    thread holds each launch's output until it retires, so a lane must NOT
+    donate those buffers to the next fused launch while markers are on
+    (``Worker.fused_donate``) — and the window must still be exact."""
+    out.host()[:] = -1.0
+    cr.fine_grained_queue_control = True
+    try:
+        cr.enqueue_mode = True
+        for _ in range(iters):
+            call()
+        cr.barrier()
+        donate = [w.fused_donate for w in cr.cores.workers]
+        cr.enqueue_mode = False  # flush
+        deadline = time.time() + 10.0
+        while cr.count_markers_remaining() and time.time() < deadline:
+            time.sleep(0.01)
+        reached, left = cr.count_markers_reached(), cr.count_markers_remaining()
+    finally:
+        if cr.enqueue_mode:
+            cr.enqueue_mode = False
+        cr.fine_grained_queue_control = False
+    err = float(np.abs(out.host() - want).max())
+    _require(not any(donate),
+             f"mandelbrot[{label}]: a lane donates its fused buffers while "
+             f"the marker thread holds them ({donate})")
+    _require(err == 0.0,
+             f"mandelbrot[{label}]: marker window differs from the host "
+             f"by {err}")
+    _require(reached > 0 and left == 0,
+             f"mandelbrot[{label}]: markers reached {reached}, still in "
+             f"flight {left} after the flush")
+    return {"donate": donate, "reached": reached}
+
+
+def _mandelbrot_through_compute(devices, sizes, source, label, want, cid,
+                                markers: bool = False):
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+
+    wh = sizes["mandel_wh"]
+    n, lr = wh * wh, sizes["local_range"]
+    vals = (-2.0, -1.25, 2.5 / wh, 2.5 / wh, wh, sizes["mandel_max_iter"])
+    cr = NumberCruncher(devices, source)
+    out = ClArray(n, np.float32, name=f"mandel_{cid}", read=False,
+                  write=True)
+    try:
+        call = lambda: out.compute(cr, cid, "mandelbrot", n, lr, values=vals)
+        _, cold_s = _timed(call)
+        lanes_first_ms = cr.benchmarks_of(cid)
+        ranges_first = cr.ranges_of(cid)
+        err_call = float(np.abs(out.host() - want).max())
+        run_s = cold_s
+        for _ in range(sizes["mandel_per_call"] - 1):
+            _, run_s = _timed(call)
+        lanes_steady_ms = cr.benchmarks_of(cid)
+        # the enqueue window: fused dispatch is the default path
+        out.host()[:] = -1.0
+        w0 = cr.fused_stats["windows"]
+        cr.enqueue_mode = True
+        t0 = time.perf_counter()
+        for _ in range(sizes["mandel_window"]):
+            call()
+        cr.barrier()
+        window_s = time.perf_counter() - t0
+        _check_placement(cr)
+        donate = [w.fused_donate for w in cr.cores.workers]
+        cr.enqueue_mode = False  # flush
+        ranges_last = cr.ranges_of(cid)
+        fused = cr.fused_stats
+        err_win = float(np.abs(out.host() - want).max())
+        lowering = _check_routing(
+            cr, "mandelbrot", devices,
+            "python" if not isinstance(source, str) else "pallas")
+        mosaic = _launcher_mosaic_calls(cr, "mandelbrot", 1, vals, lr, n)
+        for plat, calls in mosaic.items():
+            _check_compiled(plat, calls, f"mandelbrot[{label}] on {plat}")
+        _require(fused["windows"] > w0,
+                 f"mandelbrot[{label}]: fused windows never engaged "
+                 f"(disengaged: {fused['disengaged']})")
+        _require(err_call == 0.0 and err_win == 0.0,
+                 f"mandelbrot[{label}]: image differs from mandelbrot_host "
+                 f"(per-call {err_call}, window {err_win})")
+        _require(all(r > 0 for r in ranges_last) and sum(ranges_last) == n,
+                 f"mandelbrot[{label}]: lane shares {ranges_last}")
+        if len({d.jax_device for d in devices if d.is_tpu}) > 1:
+            # real chips see the frame's row skew as real time differences
+            # (virtual CPU lanes share cores, so there it is not asserted)
+            _require(ranges_first != ranges_last,
+                     f"mandelbrot[{label}]: the balancer never moved the "
+                     f"ranges off {ranges_first}")
+        extra = {}
+        if markers:
+            extra["marker_window"] = _marker_window(
+                cr, call, out, want, sizes["mandel_marker_window"], label)
+        return _row(
+            f"mandelbrot[{label}] compute()", lowering, cold_s, run_s,
+            max(err_call, err_win), window_s=round(window_s, 3),
+            fused_windows=fused["windows"] - w0,
+            fused_iters=fused["fused_iters"], donate=donate,
+            mosaic_calls=mosaic,
+            ranges_first=ranges_first, ranges_last=ranges_last,
+            lane_first_call_s=[round(m / 1e3, 3) for m in lanes_first_ms],
+            lane_steady_call_s=[round(m / 1e3, 4) for m in lanes_steady_ms],
+            **extra)
+    finally:
+        cr.dispose()
+
+
+def stage_compute(devices, sizes) -> list[dict]:
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+    from cekirdekler_tpu.workloads import (
+        MANDELBROT_SRC, NBODY_SRC, mandelbrot_host, mandelbrot_pallas_kernel,
+        nbody_host_step)
+
+    wh = sizes["mandel_wh"]
+    want = mandelbrot_host(wh, wh, -2.0, -1.25, 2.5 / wh, 2.5 / wh,
+                           sizes["mandel_max_iter"])
+    # the hand-tiled kernel twice: as users build it (the lowering follows
+    # each lane), and with the lowering forced the way ISSUE 21 names it —
+    # forced to what the lanes are, so the CPU rig can run the row too
+    on_chip = all(p == "tpu" for p in _lane_platforms(devices))
+    rows = [
+        _mandelbrot_through_compute(
+            devices, sizes, MANDELBROT_SRC, "kernel-language", want, 7101,
+            markers=True),
+        _mandelbrot_through_compute(
+            devices, sizes, mandelbrot_pallas_kernel(), "hand Pallas", want,
+            7102),
+        _mandelbrot_through_compute(
+            devices, sizes, mandelbrot_pallas_kernel(interpret=not on_chip),
+            f"hand Pallas interpret={not on_chip}", want, 7104),
+    ]
+
+    # n-body: the workloads.nbody_e2e shape — balanced over >= 2 lanes
+    # (two partition lanes of the chip when there is only one)
+    lanes = devices if len(devices) > 1 else devices[0].as_partitions(2)
+    n, lr, dt = sizes["nbody_n"], sizes["local_range"], 1e-4
+    rng = np.random.default_rng(sizes["seed"])
+    pos = (rng.random((3, n), dtype=np.float32) - 0.5) * 2.0
+    x, y, z = (ClArray(pos[i].copy(), name=c, read_only=True)
+               for i, c in enumerate("xyz"))
+    vel = [ClArray(n, np.float32, name=f"v{c}", partial_read=True)
+           for c in "xyz"]
+    zero = np.zeros(n, np.float32)
+    v1 = nbody_host_step(pos[0], pos[1], pos[2], zero, zero, zero, dt)
+    cr = NumberCruncher(lanes, NBODY_SRC)
+    group = x.next_param(y, z, *vel)
+    cid = 7103
+    try:
+        step = lambda: group.compute(cr, cid, "nBody", n, lr, values=(n, dt))
+        _, cold_s = _timed(step)  # synchronous first step: the +-0.01 check
+        err1 = max(float(np.abs(g.host() - w).max())
+                   for g, w in zip(vel, v1))
+        _require(err1 <= 0.01, f"nBody first step: max err {err1} > 0.01")
+        cr.enqueue_mode = True
+        t0 = time.perf_counter()
+        for k in range(sizes["nbody_iters"]):
+            step()
+            if (k + 1) % sizes["nbody_window"] == 0:
+                cr.barrier()
+        _check_placement(cr)
+        cr.enqueue_mode = False  # flush
+        run_s = time.perf_counter() - t0
+        # positions are read-only, so every step adds the same v1: the
+        # final velocities are (1 + iters) x the host step
+        total = 1 + sizes["nbody_iters"]
+        errN = max(float(np.abs(g.host() - total * w).max())
+                   for g, w in zip(vel, v1))
+        tol = 0.01 + 1e-5 * total * max(float(np.abs(w).max()) for w in v1)
+        _require(errN <= tol,
+                 f"nBody after {total} steps: max err {errN} > {tol:.4f}")
+        ranges = cr.ranges_of(cid)
+        _require(all(r > 0 for r in ranges), f"nBody lane shares {ranges}")
+        lowering = _check_routing(cr, "nBody", lanes, "pallas")
+        rows.append(_row(
+            "nBody compute() balanced", lowering, cold_s, run_s, err1,
+            err_final=errN, lanes=len(lanes), ranges=ranges,
+            fused_windows=cr.fused_stats["windows"]))
+    finally:
+        cr.dispose()
+    return rows
+
+
+# ---------------------------------------------------------------------------
+# stage 2: transfers at a size that is not a cache
+# ---------------------------------------------------------------------------
+
+# STREAM triad.  s = 2 makes s*b exact, so a fused multiply-add and a
+# separate multiply + add round identically and numpy is a bit-exact oracle.
+TRIAD_SRC = """
+__kernel void triad(__global float* a, __global float* b, __global float* c,
+                    float s) {
+    int i = get_global_id(0);
+    c[i] = a[i] + s * b[i];
+}
+"""
+
+
+def stage_transfers(devices, sizes) -> list[dict]:
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.core.cores import PIPELINE_DRIVER, PIPELINE_EVENT
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+
+    n, lr, s = sizes["stream_n"], sizes["local_range"], 2.0
+    rng = np.random.default_rng(sizes["seed"] + 1)
+    a_h = rng.random(n, dtype=np.float32)
+    b_h = rng.random(n, dtype=np.float32)
+    want = a_h + np.float32(s) * b_h
+    a = ClArray(a_h, name="a", partial_read=True, read_only=True)
+    b = ClArray(b_h, name="b", partial_read=True, read_only=True)
+    c = ClArray(n, np.float32, name="c", write_only=True)
+    group = a.next_param(b, c)
+    cr = NumberCruncher(devices, TRIAD_SRC)
+    rows = []
+
+    def run(label, cid, repeats=1, **kw):
+        c.host()[:] = 0.0
+        call = lambda: group.compute(cr, cid, "triad", n, lr, values=(s,),
+                                     **kw)
+        _, cold_s = _timed(call)
+        run_s = cold_s
+        for _ in range(repeats - 1):
+            _, run_s = _timed(call)
+        _require(np.array_equal(c.host(), want),
+                 f"triad[{label}] differs from numpy")
+        return cold_s, run_s
+
+    try:
+        cr.streamed_transfers = False
+        cold_s, run_s = run("monolithic", 7201, repeats=2)
+        rows.append(_row("triad monolithic", "", cold_s, run_s, 0.0))
+        cr.streamed_transfers = True  # the default
+        # the tuner's first contact is a fenced measuring run; the later
+        # calls are where it is free to pick its chunk count
+        cold_s, run_s = run("streamed", 7202,
+                            repeats=sizes["stream_tuner_runs"])
+        chunks = dict(cr.cores.last_stream_chunks)
+        rows.append(_row("triad streamed", "", cold_s, run_s, 0.0,
+                         tuner_chunks=chunks))
+        cold_s, run_s = run("EVENT x8", 7203, pipeline=True,
+                            pipeline_blobs=8, pipeline_type=PIPELINE_EVENT)
+        rows.append(_row("triad pipeline EVENT x8", "", cold_s, run_s, 0.0))
+        cold_s, run_s = run("DRIVER x16", 7204, pipeline=True,
+                            pipeline_blobs=16, pipeline_type=PIPELINE_DRIVER)
+        rows.append(_row("triad pipeline DRIVER x16", "", cold_s, run_s, 0.0))
+        _check_placement(cr)
+        lowering = _check_routing(cr, "triad", devices, "pallas")
+        for r in rows:
+            r["lowering"] = lowering
+    finally:
+        cr.dispose()
+    return rows
+
+
+# ---------------------------------------------------------------------------
+# stage 3: stage pipeline
+# ---------------------------------------------------------------------------
+
+def _wave_example():
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "examples", "wave_equation.py")
+    spec = importlib.util.spec_from_file_location("ck_wave_example", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# one more kernel for the one-stage-per-chip chain: hand (u1, frame) on as
+# the next stage's (u0, u1) through TRANSITION arrays
+_FORWARD_SRC = """
+__kernel void forward(__global float* u0, __global float* u1,
+                      __global float* frame,
+                      __global float* n0, __global float* n1,
+                      int width, int height, float c2) {
+    int i = get_global_id(0);
+    n0[i] = u1[i];
+    n1[i] = frame[i];
+}
+"""
+
+
+def stage_pipeline(devices, sizes) -> list[dict]:
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.pipeline.device_pipeline import (
+        ClPipeline, DevicePipeline, PipelineStage)
+
+    ex = _wave_example()
+    W, H = ex.W, ex.H
+    yy, xx = np.mgrid[0:H, 0:W]
+    bump = np.exp(-(((xx - W // 3) ** 2) / 18.0
+                    + ((yy - H // 2) ** 2) / 18.0))
+    u1_init = (0.6 * bump).reshape(-1).astype(np.float32)
+    u0_init = u1_init.copy()
+    vals = (W, H, ex.C2)
+    rows = []
+
+    # the example's own stage: state device-resident, live readback per push
+    stage = PipelineStage(ex.WAVE_SRC, "waveStep rotate", global_range=W * H,
+                          local_range=ex.LOCAL, values=vals)
+    stage.add_hidden(ClArray(u0_init.copy(), name="u0"))
+    stage.add_hidden(ClArray(u1_init.copy(), name="u1"))
+    stage.add_output(ClArray(W * H, np.float32, name="frame"))
+    pipe = DevicePipeline.make([stage], devices[0])
+    out = np.zeros(W * H, np.float32)
+    try:
+        _, cold_s = _timed(lambda: pipe.push(None, out))
+        t0 = time.perf_counter()
+        for _ in range(sizes["wave_pushes"] - 1):
+            pipe.push(None, out)
+        run_s = time.perf_counter() - t0
+        for s in stage._slots():
+            _require(s.value.devices() == {devices[0].jax_device},
+                     f"pipeline slot {s.arr.name} on {s.value.devices()}")
+        plat = devices[0].jax_device.platform
+        routed = {k: sorted(stage.program.lowerings(k, plat), key=str)
+                  for k in ("waveStep", "rotate")}
+        lowering = "+".join(sorted(
+            {lo for got in routed.values() for lo, _v in got}))
+        # waveStep's shifts are runtime values (u1[i - width]): outside the
+        # tile subset, so the XLA lowering is the POLICY, with its reason
+        vetoes = {k: v for k, got in routed.items() for _lo, v in got if v}
+    finally:
+        pipe.dispose()
+    err = float(np.abs(
+        out - ex.host_reference(u0_init, u1_init, sizes["wave_pushes"])
+    ).max())
+    _require(err < 1e-3, f"wave DevicePipeline: max err {err} >= 1e-3")
+    rows.append(_row("wave DevicePipeline", lowering, cold_s, run_s, err,
+                     pushes=sizes["wave_pushes"], vetoes=vetoes))
+
+    if len(devices) > 1:
+        # one stage per chip, each advancing the field one step; the same
+        # initial state is fed every push, so once the chain is full the
+        # last stage emits K steps of it — host_reference(.., K)
+        k = len(devices)
+        stages = []
+        ins = (ClArray(W * H, np.float32, name="p0_u0"),
+               ClArray(W * H, np.float32, name="p0_u1"))
+        for i in range(k):
+            last = i == k - 1
+            st = PipelineStage(
+                ex.WAVE_SRC + _FORWARD_SRC,
+                "waveStep" if last else "waveStep forward",
+                global_range=W * H, local_range=ex.LOCAL, values=vals)
+            st.add_input(*ins)
+            if last:
+                st.add_output(ClArray(W * H, np.float32, name=f"p{i}_frame"))
+            else:
+                st.add_hidden(ClArray(W * H, np.float32, name=f"p{i}_frame"))
+                ins = (ClArray(W * H, np.float32, name=f"p{i + 1}_u0"),
+                       ClArray(W * H, np.float32, name=f"p{i + 1}_u1"))
+                st.add_transition(*ins)
+            stages.append(st)
+        pipe = ClPipeline.make(stages, list(devices))
+        out = np.zeros(W * H, np.float32)
+        try:
+            _, cold_s = _timed(
+                lambda: pipe.push([u0_init, u1_init], out))
+            t0 = time.perf_counter()
+            for _ in range(k + 2):
+                valid = pipe.push([u0_init, u1_init], out)
+            run_s = time.perf_counter() - t0
+            _require(valid, "ClPipeline never reported valid results")
+            for st, d in zip(stages, devices):
+                for s in st._slots():
+                    _require(s.value.devices() == {d.jax_device},
+                             f"ClPipeline slot {s.arr.name} on "
+                             f"{s.value.devices()}, not {d.jax_device}")
+        finally:
+            pipe.dispose()
+        err = float(np.abs(
+            out - ex.host_reference(u0_init, u1_init, k)).max())
+        _require(err < 1e-3, f"wave ClPipeline x{k}: max err {err} >= 1e-3")
+        rows.append(_row(f"wave ClPipeline x{k} chips", lowering, cold_s,
+                         run_s, err))
+    return rows
+
+
+# ---------------------------------------------------------------------------
+# stage 4: serving
+# ---------------------------------------------------------------------------
+
+def stage_serving(devices, sizes) -> list[dict]:
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+    from cekirdekler_tpu.serve import ServeFrontend, ServeJob
+    from tools.loadgen import LOADGEN_SRC
+
+    n, lr = sizes["serve_n"], sizes["serve_local"]
+    tenants, sigs, reqs = (sizes["serve_tenants"], sizes["serve_sigs"],
+                           sizes["serve_reqs"])
+    cr = NumberCruncher(devices, LOADGEN_SRC)
+    arrays, jobs = [], []
+    for s in range(sigs):
+        arr = ClArray(np.zeros(n, np.float32), name=f"serve{s}")
+        arr.partial_read = True
+        arrays.append(arr)
+        jobs.append(ServeJob(params=[arr], kernels=["lg_inc"],
+                             compute_id=7400 + s, global_range=n,
+                             local_range=lr))
+    fe = ServeFrontend(cr, name="chip-smoke")
+    results: list = []
+    errors: list = []
+    mu = threading.Lock()
+
+    def client(tenant: str, job) -> None:
+        try:
+            futs = [fe.submit(tenant, job) for _ in range(reqs)]
+            got = [f.result(timeout=300.0) for f in futs]
+            with mu:
+                results.extend(got)
+        except Exception as e:  # noqa: BLE001 - reported by the stage
+            with mu:
+                errors.append(f"{tenant}: {type(e).__name__}: {e}")
+
+    try:
+        warm, warm_s = _timed(lambda: fe.warmup(jobs))  # set-up
+        w0 = cr.fused_stats["windows"]
+        i0 = cr.fused_stats["fused_iters"]
+        threads = [
+            threading.Thread(target=client, args=(f"tenant{t}", jobs[s]))
+            for t in range(tenants) for s in range(sigs)]
+        t0 = time.perf_counter()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=600.0)
+        run_s = time.perf_counter() - t0
+        _require(not any(t.is_alive() for t in threads),
+                 "serving: client threads still running")
+        _require(not errors, f"serving: {errors[:3]}")
+        fe.close()
+        total = tenants * sigs * reqs
+        _require(len(results) == total,
+                 f"serving: {len(results)} of {total} futures resolved")
+        per_sig = float(tenants * reqs)
+        err = max(float(np.abs(np.asarray(arr) - per_sig).max())
+                  for arr in arrays)
+        _require(err == 0.0,
+                 f"serving: arrays differ from {per_sig} by up to {err}")
+        windows = cr.fused_stats["windows"] - w0
+        per_call = total - (cr.fused_stats["fused_iters"] - i0)
+        launches = windows + per_call
+        _require(0 < launches < total,
+                 f"serving: {launches} launches for {total} requests — "
+                 "coalescing never engaged")
+        _check_placement(cr)
+        lowering = _check_routing(cr, "lg_inc", devices, "pallas")
+        return [_row(f"ServeFrontend {tenants}x{sigs}x{reqs}", lowering,
+                     warm_s, run_s, err, requests=total, launches=launches,
+                     coalesce_ratio=round(total / launches, 2),
+                     warmup=warm.get("warmed"))]
+    finally:
+        if not fe._halt:
+            fe.close(drain=False)
+        cr.dispose()
+
+
+# ---------------------------------------------------------------------------
+# stage 5: every Pallas kernel the repo ships compiles under Mosaic
+# ---------------------------------------------------------------------------
+
+def _rel(a, b) -> float:
+    import jax.numpy as jnp
+
+    return float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-9))
+
+
+def _dense_grads(q, k, v, heads_per_chunk: int = 2):
+    """Gradients of ``_dense_attention(..).sum()`` — the reference — taken
+    per (batch, head-chunk) so the [T, T] scores of the long shapes fit."""
+    import jax
+    import jax.numpy as jnp
+
+    from cekirdekler_tpu.ops.flash_attention import _dense_attention
+
+    g = jax.jit(jax.grad(
+        lambda q, k, v: _dense_attention(q, k, v, True, "highest").sum(),
+        argnums=(0, 1, 2)))
+    B, _T, H, _D = q.shape
+    hc = min(heads_per_chunk, H)
+    outs = [[], [], []]
+    for b in range(B):
+        parts = [g(*(a[b:b + 1, :, h:h + hc] for a in (q, k, v)))
+                 for h in range(0, H, hc)]
+        for i in range(3):
+            outs[i].append(jnp.concatenate([p[i] for p in parts], axis=2))
+    return tuple(jnp.concatenate(o, axis=0) for o in outs)
+
+
+def stage_kernels(devices, sizes) -> list[dict]:
+    import jax
+    import jax.numpy as jnp
+
+    from cekirdekler_tpu.kernel import codegen, lang
+    from cekirdekler_tpu.kernel.pallas_backend import build_kernel_fn_pallas
+    from cekirdekler_tpu.ops.elementwise import saxpy
+    from cekirdekler_tpu.ops.flash_attention import (
+        _dense_attention, flash_attention, fused_qkv, fused_qkv_attention)
+    from cekirdekler_tpu.workloads import (
+        MANDELBROT_SRC, NBODY_SRC, WAVE_SRC, mandelbrot_host,
+        nbody_host_step)
+
+    dev = devices[0].jax_device
+    plat = dev.platform
+    # hand kernels are called with `interpret` left out: the lowering
+    # follows the device, and each row states (and checks) what it got
+    pallas_ran = "mosaic" if plat == "tpu" else "interpret"
+    rng = np.random.default_rng(sizes["seed"] + 2)
+    B, H, D = sizes["flash_bhd"]
+    rows = []
+
+    def qkv(T):
+        return tuple(
+            jax.device_put(
+                (rng.standard_normal((B, T, H, D)) * 0.3).astype(np.float32),
+                dev)
+            for _ in range(3))
+
+    def first_and_repeat(fn, *args):
+        out, cold_s = _timed(lambda: jax.block_until_ready(fn(*args)))
+        _, run_s = _timed(lambda: jax.block_until_ready(fn(*args)))
+        return out, cold_s, run_s
+
+    # flash fwd+bwd, default arguments (the BlockTuner path), both precisions
+    for T in sizes["flash_T"]:
+        q, k, v = qkv(T)
+        want = _dense_grads(q, k, v)
+        for precision, tol in (("highest", 5e-4), ("default", 2e-2)):
+            loss = lambda q, k, v: flash_attention(
+                q, k, v, causal=True, precision=precision).sum()
+            g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+            got, cold_s, run_s = first_and_repeat(g, q, k, v)
+            n_calls = _mosaic_calls(g, q, k, v)
+            _check_compiled(plat, n_calls, f"flash T={T} {precision}")
+            err = max(_rel(a, b) for a, b in zip(got, want))
+            _require(
+                err < tol and all(bool(jnp.isfinite(a).all()) for a in got),
+                f"flash grads T={T} {precision}: rel err {err} >= {tol}")
+            rows.append(_row(f"flash fwd+bwd T={T} {precision}", pallas_ran,
+                             cold_s, run_s, err, mosaic_calls=n_calls))
+        del want
+
+    # T=640 stays tiled (128-wide blocks); T=96 takes the dense fallback
+    for T, tiled in ((sizes["flash_tiled_T"], True),
+                     (sizes["flash_dense_T"], False)):
+        q, k, v = qkv(T)
+        f = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
+        got, cold_s, run_s = first_and_repeat(f, q, k, v)
+        n_calls = _mosaic_calls(f, q, k, v)
+        if tiled:
+            _check_compiled(plat, n_calls, f"flash T={T}")
+        else:
+            _require(n_calls == 0, f"flash T={T} did not take dense")
+        err = _rel(got, _dense_attention(q, k, v, True, "highest"))
+        _require(err < 5e-4, f"flash fwd T={T}: rel err {err}")
+        rows.append(_row(f"flash fwd T={T}",
+                         pallas_ran if tiled else "dense",
+                         cold_s, run_s, err))
+
+    # fused QKV projection + tuned flash; the one-shot softmax (block_k == T)
+    T, E = sizes["qkv_T"], H * D
+    x = jax.device_put(
+        (rng.standard_normal((B, T, E)) * 0.3).astype(np.float32), dev)
+    wq, wk, wv = (jax.device_put(
+        (rng.standard_normal((E, E)) / np.sqrt(E)).astype(np.float32), dev)
+        for _ in range(3))
+    f = jax.jit(lambda x, wq, wk, wv: fused_qkv_attention(
+        x, wq, wk, wv, H, causal=True))
+    got, cold_s, run_s = first_and_repeat(f, x, wq, wk, wv)
+    _check_compiled(plat, _mosaic_calls(f, x, wq, wk, wv), "fused_qkv")
+    q, k, v = (a.reshape(B, T, H, D) for a in fused_qkv(x, wq, wk, wv))
+    err = _rel(got, _dense_attention(q, k, v, True, "highest"))
+    _require(err < 2e-3, f"fused_qkv_attention: rel err {err}")
+    rows.append(_row("fused_qkv_attention", pallas_ran, cold_s, run_s, err))
+    T = sizes["flash_oneshot_T"]
+    q, k, v = qkv(T)
+    f = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=False, block_q=128, block_k=T))
+    got, cold_s, run_s = first_and_repeat(f, q, k, v)
+    _check_compiled(plat, _mosaic_calls(f, q, k, v), "one-shot softmax")
+    err = _rel(got, _dense_attention(q, k, v, False, "highest"))
+    _require(err < 5e-4, f"flash one-shot softmax: rel err {err}")
+    rows.append(_row("flash one-shot softmax", pallas_ran, cold_s, run_s,
+                     err))
+
+    # ops/elementwise.saxpy
+    n = sizes["saxpy_n"]
+    xs = jax.device_put(rng.random(n, dtype=np.float32), dev)
+    ys = jax.device_put(rng.random(n, dtype=np.float32), dev)
+    f = jax.jit(lambda x, y: saxpy(2.0, x, y))
+    got, cold_s, run_s = first_and_repeat(f, xs, ys)
+    _check_compiled(plat, _mosaic_calls(f, xs, ys), "saxpy")
+    err = float(np.abs(np.asarray(got)
+                       - (np.asarray(ys) + np.float32(2.0) * np.asarray(xs))
+                       ).max())
+    _require(err == 0.0, f"saxpy: max err {err}")
+    rows.append(_row("ops.saxpy", pallas_ran, cold_s, run_s, err))
+
+    # one kernel of each kernel/pallas_backend.py access class, built as
+    # the registry builds them for a TPU lane (it compiles under Mosaic;
+    # only a rig without a chip asks the builder to interpret), against the
+    # XLA lowering of the same source
+    interp = plat != "tpu"
+
+    def backend(src, name, n, arrays, values, label, want=None, tol=0.0):
+        kdef = {k.name: k for k in lang.parse_kernels(src)}[name]
+        pl_fn, info = build_kernel_fn_pallas(kdef, n, 256, n,
+                                             interpret=interp, force=True)
+        arrays = tuple(jax.device_put(a, dev) for a in arrays)
+        f = jax.jit(lambda *arrs: pl_fn(0, arrs, values))
+        got, cold_s, run_s = first_and_repeat(f, *arrays)
+        _check_compiled(plat, _mosaic_calls(f, *arrays), label)
+        _require(info.lowering == "pallas",
+                 f"{label}: delegated to {info.lowering} ({info.veto})")
+        if want is None:
+            xla_fn, _ = codegen.build_kernel_fn(kdef, n, 256, n)
+            want = jax.jit(lambda *arrs: xla_fn(0, arrs, values))(*arrays)
+        err = max(float(np.abs(np.asarray(g) - np.asarray(w)).max())
+                  for g, w in zip(got, want))
+        _require(err <= tol, f"{label}: max err {err} > {tol}")
+        rows.append(_row(f"pallas_backend {label}", pallas_ran, cold_s,
+                         run_s, err))
+
+    n = sizes["backend_n"]
+    wh = int(np.sqrt(n))
+    mvals = (np.float32(-2.0), np.float32(-1.25), np.float32(2.5 / wh),
+             np.float32(2.5 / wh), np.int32(wh), np.int32(64))
+    # exact on the chip; the CPU backend contracts the orbit's multiply-adds
+    # differently under the interpreter, moving a boundary pixel by one
+    backend(MANDELBROT_SRC, "mandelbrot", wh * wh,
+            (np.zeros(wh * wh, np.float32),), mvals, "elementwise",
+            want=(mandelbrot_host(wh, wh, -2.0, -1.25, 2.5 / wh, 2.5 / wh,
+                                  64),), tol=1.0 if interp else 0.0)
+    wave = tuple((rng.standard_normal(n) * 0.5).astype(np.float32)
+                 for _ in range(3))
+    backend(WAVE_SRC, "wave", n, wave, (), "halo (pl.Element)", tol=1e-4)
+    nb = sizes["backend_nbody_n"]
+    pos = (rng.random((3, nb), dtype=np.float32) - 0.5) * 2.0
+    zero = np.zeros(nb, np.float32)
+    v1 = nbody_host_step(pos[0], pos[1], pos[2], zero, zero, zero, 1e-4)
+    backend(NBODY_SRC, "nBody", nb, (*pos, zero, zero, zero),
+            (np.int32(nb), np.float32(1e-4)), "SMEM uniform gather",
+            want=(*pos, *v1), tol=0.01)
+
+    # the driver's own entry point (beside this script)
+    import __graft_entry__ as graft
+
+    fn, args = graft.entry()
+    args = jax.device_put(args, dev)
+    got, cold_s, run_s = first_and_repeat(jax.jit(fn), *args)
+    _require(bool(jnp.isfinite(got).all()), "entry(): non-finite logits")
+    with jax.default_matmul_precision("highest"):
+        ref = fn(*args)
+    err = _rel(got, ref)
+    _require(err < 5e-2, f"entry(): jit vs eager-highest rel err {err}")
+    rows.append(_row("jit(__graft_entry__.entry)", "xla", cold_s, run_s, err,
+                     shape=list(got.shape)))
+    return rows
+
+
+# ---------------------------------------------------------------------------
+# stage 6: device trace
+# ---------------------------------------------------------------------------
+
+def stage_trace(devices, sizes) -> list[dict]:
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+    from cekirdekler_tpu.trace.device import DeviceCapture
+    from cekirdekler_tpu.utils import timeline
+    from cekirdekler_tpu.workloads import MANDELBROT_SRC
+
+    wh, lr = sizes["mandel_wh"], sizes["local_range"]
+    n = wh * wh
+    vals = (-2.0, -1.25, 2.5 / wh, 2.5 / wh, wh, sizes["mandel_max_iter"])
+    cr = NumberCruncher(devices, MANDELBROT_SRC)
+    out = ClArray(n, np.float32, name="trace_out", read=False, write=True)
+    root = tempfile.mkdtemp(prefix="ck_smoke_trace_")
+
+    def window():
+        for _ in range(sizes["trace_iters"]):
+            out.compute(cr, 7601, "mandelbrot", n, lr, values=vals)
+        cr.barrier()
+
+    try:
+        cr.enqueue_mode = True
+        _, cold_s = _timed(window)  # compiled and fused outside the traces
+        with timeline.capture(os.path.join(root, "timeline")) as result:
+            window()
+        tl = result()
+        cap = DeviceCapture(os.path.join(root, "device"))
+        with cap:
+            _, run_s = _timed(window)
+        cr.enqueue_mode = False
+        rep = cap.report
+        expect_device_events = all(
+            p == "tpu" for p in _lane_platforms(devices))
+        if expect_device_events:
+            chips = len({d.jax_device for d in devices})
+            _require(tl.n_events > 0,
+                     "timeline.capture parsed zero device events "
+                     f"(dump: {tl.trace_path})")
+            _require(tl.n_devices >= chips,
+                     f"timeline: {tl.n_devices} device planes < {chips}")
+            _require(rep.absent is None, f"DeviceCapture: {rep.absent}")
+            _require(rep.n_ops > 0 and rep.n_marks > 0,
+                     f"DeviceCapture: {rep.n_ops} ops, {rep.n_marks} marks")
+            _require(rep.attributed_ms > 0,
+                     "DeviceCapture: no device op correlated to a launch "
+                     f"mark (matched_by {rep.matched_by})")
+        return [_row(
+            "device trace", "", cold_s, run_s, 0.0, n_events=tl.n_events,
+            n_devices=tl.n_devices,
+            busy_fraction=round(tl.compute_busy_fraction, 4),
+            capture=rep.absent or {
+                "n_ops": rep.n_ops, "n_marks": rep.n_marks,
+                "n_dump_marks": rep.n_dump_marks, "anchor": rep.anchor,
+                "matched_by": rep.matched_by,
+                "coverage_frac": round(rep.coverage_frac, 4),
+                "devices": rep.devices})]
+    finally:
+        if cr.enqueue_mode:
+            cr.enqueue_mode = False
+        cr.dispose()
+        shutil.rmtree(root, ignore_errors=True)
+
+
+STAGES = (
+    ("1 compute()", stage_compute),
+    ("2 transfers", stage_transfers),
+    ("3 pipeline", stage_pipeline),
+    ("4 serving", stage_serving),
+    ("5 kernels", stage_kernels),
+    ("6 device trace", stage_trace),
+)
+
+
+def _cache_files(path: str | None) -> int:
+    """Executables in jax's persistent cache (its ``<key>-cache`` files;
+    the eviction's ``-atime`` stamps and lock file are not entries)."""
+    if not path or not os.path.isdir(path):
+        return 0
+    return sum(name.endswith("-cache") for name in os.listdir(path))
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    try:
+        import jax
+
+        import cekirdekler_tpu as ct
+        from cekirdekler_tpu import native
+    except ImportError as e:
+        print(f"chip_smoke: FAIL — the program is not here ({e}); run it "
+              "from the root of a checkout", file=sys.stderr)
+        return 2
+
+    tpus = ct.all_devices().tpus()
+    if not len(tpus):
+        print(f"chip_smoke: FAIL — no TPU found (jax sees "
+              f"{[str(d) for d in jax.devices()]}); this check does not "
+              "run on CPU devices", file=sys.stderr)
+        return 2
+    d0 = jax.devices()[0]
+    device = {"platform": d0.platform, "kind": d0.device_kind,
+              "count": len(jax.devices())}
+    try:
+        import libtpu
+
+        libtpu_v = getattr(libtpu, "__version__", "?")
+    except ImportError:
+        libtpu_v = "absent"
+    import jaxlib
+
+    cache_dir = jax.config.jax_compilation_cache_dir
+    files0 = _cache_files(cache_dir)
+    print(f"chip_smoke: {device['count']} x {device['kind']} "
+          f"({device['platform']}); jax {jax.__version__} jaxlib "
+          f"{jaxlib.__version__} libtpu {libtpu_v}")
+    print(f"  lanes: {[d.name for d in tpus]}")
+    print(f"  host runtime: {native.runtime()}")
+    placed_by = ("JAX_COMPILATION_CACHE_DIR"
+                 if os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                 else "package default")
+    print(f"  compile cache: {cache_dir} ({files0} executables; {placed_by})")
+
+    sizes = dict(FULL, seed=args.seed)
+    failures: list[str] = []
+    t_all = time.perf_counter()
+    compile_s = 0.0
+    for name, fn in STAGES:
+        t0 = time.perf_counter()
+        try:
+            rows = fn(tpus, sizes)
+        except Exception as e:  # noqa: BLE001 - a raising stage fails the run
+            import traceback
+
+            traceback.print_exc()
+            failures.append(f"stage {name}: {type(e).__name__}: {e}")
+            print(f"[stage {name}] FAIL after "
+                  f"{time.perf_counter() - t0:.1f}s: {type(e).__name__}: "
+                  f"{str(e)[:600]}", flush=True)
+            continue
+        print(f"[stage {name}] ok in {time.perf_counter() - t0:.1f}s",
+              flush=True)
+        for r in rows:
+            compile_s += max(r["cold_s"] - r["run_s"], 0.0)
+            extra = {k: v for k, v in r.items() if k not in (
+                "name", "lowering", "cold_s", "run_s", "max_err")}
+            print(f"  {r['name']:<34} lowering={r['lowering'] or '-':<10} "
+                  f"cold={r['cold_s']:8.3f}s run={r['run_s']:9.4f}s "
+                  f"max_err={r['max_err']:.3g}"
+                  + (f"  {json.dumps(extra)}" if extra else ""), flush=True)
+    print(f"chip_smoke: {time.perf_counter() - t_all:.1f}s total, "
+          f"~{compile_s:.1f}s of it first-call (compile) cost; compile "
+          f"cache now {_cache_files(cache_dir)} executables (was {files0})")
+    if failures:
+        for f in failures:
+            print(f"chip_smoke: FAIL — {f}", file=sys.stderr)
+        print(json.dumps({"ok": False, "device": device,
+                          "failures": [f[:300] for f in failures]}))
+        return 1
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -15,9 +15,10 @@ path (SMEM operand; kernel/pallas_backend.py) — ~25× the vectorized XLA
 lowering of the same source, and faster than the hand-written jnp
 formulation (ops/nbody.py).
 
-Run it anywhere:
+Run it on the chip, or on the host CPU when you say so (with no TPU and
+no ``JAX_PLATFORMS=cpu`` it fails instead of quietly switching):
 
-    python examples/nbody.py                       # real TPU chip (if any)
+    python examples/nbody.py                       # TPU chip
     JAX_PLATFORMS=cpu python examples/nbody.py     # host CPU
 """
 
@@ -43,10 +44,7 @@ LOCAL = 256
 
 
 def main() -> int:
-    devs = ct.all_devices()
-    tpus = devs.tpus()
-    if len(tpus):
-        devs = tpus
+    devs = ct.chip_devices()
     print(f"devices: {[str(d) for d in devs]}")
 
     rng = np.random.default_rng(0)

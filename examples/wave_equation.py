@@ -8,9 +8,10 @@ arrays keep the field state device-resident across generations, with live
 readback of every frame (the OUTPUT array), an ASCII render, and a numpy
 reference check.
 
-Run it anywhere:
+Run it on the chip, or on the host CPU when you say so (with no TPU and
+no ``JAX_PLATFORMS=cpu`` it fails instead of quietly switching):
 
-    python examples/wave_equation.py              # real TPU chip (if any)
+    python examples/wave_equation.py                     # TPU chip
     JAX_PLATFORMS=cpu python examples/wave_equation.py   # host CPU
 
 The kernel uses shifted neighbor loads (``u[i-1]``, ``u[i+W]``) — outside
@@ -97,9 +98,7 @@ def ascii_frame(field: np.ndarray) -> str:
 
 
 def main() -> None:
-    devs = ct.all_devices()
-    tpus = devs.tpus()
-    dev = (tpus if len(tpus) else devs.cpus())[0]
+    dev = ct.chip_devices()[0]
     print(f"wave_equation: {W}x{H} membrane, {STEPS} steps on {dev.name}")
 
     # initial condition: a gaussian pluck off-center

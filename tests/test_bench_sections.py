@@ -53,7 +53,7 @@ def test_section_exception_recorded_not_raised():
     s = bench.SectionScheduler(100.0, {})
 
     def boom():
-        raise RuntimeError("tunnel died")
+        raise RuntimeError("link died")
 
     assert s.run("overlap", boom, default="dflt") == "dflt"
     assert s.errors["overlap"].startswith("RuntimeError")
@@ -151,7 +151,7 @@ def test_starvation_history_reads_budget_skips_only(tmp_path):
             "null_sections": {
                 "ov": {"null_reason": "skipped: 1500s bench budget spent",
                         "budget_spent_s": 1430.0},
-                "boom": {"null_reason": "RuntimeError: tunnel died",
+                "boom": {"null_reason": "RuntimeError: link died",
                           "budget_spent_s": 100.0},
             },
             "headline": {"mandelbrot_mpix": 1.0},

@@ -511,40 +511,42 @@ def test_one_shot_agrees_with_two_step_geometry():
 # ---------------------------------------------------------------------------
 
 def test_device_peak_table_pins_v5e_numbers():
-    from cekirdekler_tpu.hardware import (
-        DEVICE_PEAKS, device_peaks)
-    from cekirdekler_tpu.trace.device import (
-        V5E_HBM_GBPS, V5E_PEAK_BF16_TFLOPS)
+    from cekirdekler_tpu.errors import DeviceSelectionError
+    from cekirdekler_tpu.hardware import DEVICE_PEAKS, device_peaks
 
     assert DEVICE_PEAKS["TPU v5e"] == (197.0, 819.0)
     assert DEVICE_PEAKS["TPU v5 lite"] == (197.0, 819.0)
-    # the historical module constants still pin the same numbers
-    assert (V5E_PEAK_BF16_TFLOPS, V5E_HBM_GBPS) == (197.0, 819.0)
     tf, gb, kind = device_peaks("TPU v4")
     assert (tf, gb, kind) == (275.0, 1228.0, "TPU v4")
-    # unknown kinds (CPU containers) fall back to v5e, NAMED as such
-    tf, gb, kind = device_peaks("cpu")
-    assert (tf, gb) == (197.0, 819.0)
-    assert kind == "TPU v5e (fallback for cpu)"
+    # a kind the table does not list (the CPU rig, a typo) is an error,
+    # never an assumed v5e roof
+    for unknown in ("cpu", "nonsense"):
+        with pytest.raises(DeviceSelectionError, match=unknown):
+            device_peaks(unknown)
+    with pytest.raises(DeviceSelectionError, match="cpu"):
+        device_peaks()  # this rig's first device is a CPU
 
 
-def test_roofline_row_defaults_unchanged_vs_explicit_v5e():
-    """Satellite pin: sourcing peaks from the device table leaves the
-    default (v5e-on-this-container) roofline numbers bit-unchanged vs
-    the old hardcoded constants."""
+def test_roofline_row_peaks_come_from_the_named_kind():
+    """Peaks resolve by device kind: the v5e row equals the explicit
+    public-spec numbers, another kind is judged against its own roof,
+    and the CPU rig (no table entry) gets no row at all."""
+    from cekirdekler_tpu.errors import DeviceSelectionError
     from cekirdekler_tpu.trace.device import roofline_row
 
-    auto = roofline_row(1e12, 1e9, 5.0)
+    auto = roofline_row(1e12, 1e9, 5.0, device_kind="TPU v5 lite")
     pinned = roofline_row(1e12, 1e9, 5.0, peak_tflops=197.0,
                           peak_gbps=819.0)
     assert pinned["peak_kind"] == "override"
-    assert auto["peak_kind"].startswith("TPU v5e")
+    assert auto["peak_kind"] == "TPU v5 lite"
     for key in ("attained_tflops", "mfu", "bound", "frac_of_roof",
                 "intensity_flop_per_byte"):
         assert auto[key] == pinned[key], key
     v4 = roofline_row(1e12, 1e9, 5.0, device_kind="TPU v4")
     assert v4["peak_kind"] == "TPU v4"
     assert v4["mfu"] < auto["mfu"]  # judged against a taller roof
+    with pytest.raises(DeviceSelectionError):
+        roofline_row(1e12, 1e9, 5.0)
 
 
 # ---------------------------------------------------------------------------

@@ -35,9 +35,11 @@ from cekirdekler_tpu.core import NumberCruncher
 from cekirdekler_tpu.core.compilecache import (
     CACHE,
     CACHE_ENV,
+    CACHE_MAX_MB_ENV,
     CompileCache,
     WarmupSpec,
     program_fingerprint,
+    trim_placed_jax_cache,
     warm_from_disk,
 )
 from cekirdekler_tpu.hardware import platforms
@@ -76,20 +78,94 @@ def devs():
 @pytest.fixture()
 def cache_root(tmp_path, monkeypatch):
     """Arm the process-wide CACHE singleton at a fresh root; disarm on
-    teardown so the suite's other tests never write XLA cache files."""
+    teardown."""
     root = str(tmp_path / "cache")
     monkeypatch.setenv(CACHE_ENV, root)
     CACHE._seen.clear()
     CACHE.miss_reasons.clear()
     yield root
+    _disarm()
+
+
+def _disarm():
     CACHE._seen.clear()
     CACHE._armed_dir = None
-    try:
-        import jax
 
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:  # noqa: BLE001 - knob absent on this jax
-        pass
+
+# ---------------------------------------------------------------------------
+# jax's own cache: place, floor and bound are configured once, at import
+# ---------------------------------------------------------------------------
+
+def test_jax_cache_placed_at_import_and_never_jax_evicted():
+    import jax
+
+    import cekirdekler_tpu
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        assert cekirdekler_tpu.PLACED_CACHE_DIR == os.path.join(
+            ROOT, ".jax_cache")
+        assert (jax.config.jax_compilation_cache_dir
+                == cekirdekler_tpu.PLACED_CACHE_DIR)
+    # jax's own eviction stays off: it re-reads a stamp per entry on every
+    # write (core/compilecache.trim_placed_jax_cache bounds the directory)
+    if "JAX_COMPILATION_CACHE_MAX_SIZE" not in os.environ:
+        assert jax.config.jax_compilation_cache_max_size == -1
+    # the suite itself runs without the cache (tests/conftest.py)
+    assert not jax.config.jax_enable_compilation_cache
+
+
+def test_placed_cache_is_trimmed_to_the_cap_oldest_written_first(
+        tmp_path, monkeypatch):
+    import cekirdekler_tpu
+
+    d = tmp_path / "placed"
+    d.mkdir()
+    for i in range(5):  # 5 x 1000 bytes, oldest first
+        f = d / f"jit_f-{i}-cache"
+        f.write_bytes(b"x" * 1000)
+        os.utime(f, (1_000_000 + i, 1_000_000 + i))
+    (d / "notes.txt").write_bytes(b"y" * 5000)  # not an executable: kept
+    monkeypatch.setattr(cekirdekler_tpu, "PLACED_CACHE_DIR", str(d))
+    assert trim_placed_jax_cache(2500) == 3
+    assert sorted(os.listdir(d)) == [
+        "jit_f-3-cache", "jit_f-4-cache", "notes.txt"]
+    assert trim_placed_jax_cache(2500) == 0  # under the cap: no-op
+    # the default cap is the one the manifest's entries/ obey
+    monkeypatch.setenv(CACHE_MAX_MB_ENV, "0")
+    assert trim_placed_jax_cache() == 2
+    # a directory that is not there yet, and one placed from outside
+    monkeypatch.setattr(cekirdekler_tpu, "PLACED_CACHE_DIR",
+                        str(tmp_path / "absent"))
+    assert trim_placed_jax_cache(0) == 0
+    monkeypatch.setattr(cekirdekler_tpu, "PLACED_CACHE_DIR", None)
+    assert trim_placed_jax_cache(0) == 0
+
+
+def test_jax_cache_placed_from_outside_in_a_fresh_process(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR decides the place: the package sets no
+    other and deletes nothing in a directory it does not own; unpinned,
+    every executable persists — a compile lands there."""
+    place = str(tmp_path / "placed")
+    env = os.environ.copy()
+    env.update({"JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": place})
+    for k in ("JAX_ENABLE_COMPILATION_CACHE",
+              "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+              "JAX_COMPILATION_CACHE_MAX_SIZE", CACHE_ENV):
+        env.pop(k, None)
+    code = (
+        "import json, jax, cekirdekler_tpu\n"
+        "import jax.numpy as jnp\n"
+        "jax.jit(lambda x: x * 3 + 1)(jnp.arange(8.0)).block_until_ready()\n"
+        "c = jax.config\n"
+        "print(json.dumps([c.jax_compilation_cache_dir,"
+        " c.jax_persistent_cache_min_compile_time_secs,"
+        " c.jax_compilation_cache_max_size]))\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got_dir, floor, cap = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got_dir == place and floor == 0 and cap == -1
+    assert any(n.endswith("-cache") for n in os.listdir(place))
 
 
 def _fused_batch(cr, arr, cid, iters, kernel="inc"):
@@ -420,14 +496,7 @@ def test_cache_is_bit_invisible_fused_on_and_off(devs, tmp_path,
     ref = images[("env-off", True)]
     for key, img in images.items():
         np.testing.assert_array_equal(img, ref, err_msg=str(key))
-    CACHE._seen.clear()
-    CACHE._armed_dir = None
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:  # noqa: BLE001 - knob absent on this jax
-        pass
+    _disarm()
 
 
 # ---------------------------------------------------------------------------

@@ -578,6 +578,11 @@ def test_nbody_e2e_embeds_kernel_profile_block(monkeypatch, cpu_devices):
             return None
 
     monkeypatch.setattr(dvmod, "DeviceCapture", FakeCap)
+    # the faked device ops "ran" on CPU lanes, which have no roof: name
+    # the one this contrived capture is judged against
+    from cekirdekler_tpu import hardware
+
+    monkeypatch.setitem(hardware.DEVICE_PEAKS, "cpu", (197.0, 819.0))
     out = workloads.nbody_e2e(
         ct.all_devices().cpus().subset(2), n=2048, iters=4, window=2,
         attribution=True, device_timeline_dir="/tmp/ck_faked")

@@ -20,19 +20,6 @@ from cekirdekler_tpu.kernel.pallas_backend import (  # noqa: E402
     build_kernel_fn_pallas,
 )
 
-import jax.experimental.pallas as _pl  # noqa: E402
-
-# env capability, not a code property: every fuzz case drives the
-# round-4 widened Pallas tile lowering, which needs pl.Element
-# (pallas_backend.py:469) — absent from the jax this CPU container
-# ships, so the whole file failed identically every run.  On capable
-# rigs the condition is False and the fuzz runs unchanged.
-pytestmark = pytest.mark.skipif(
-    not hasattr(_pl, "Element"),
-    reason="jax.experimental.pallas lacks pl.Element in this environment "
-           "(pre-0.5-era pallas) — the widened tile lowering cannot build",
-)
-
 N = 256
 
 

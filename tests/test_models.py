@@ -10,16 +10,6 @@ import pytest
 from cekirdekler_tpu import parallel as par
 from cekirdekler_tpu.parallel.mesh import set_mesh
 
-# pre-0.6 jax (the 0.4.x CPU rigs) routes shard_map(axis_names=...) through
-# experimental shard_map's PARTIAL auto-axes support — multi-device auto
-# axes die under jit with "PartitionId ... UNIMPLEMENTED".  The paths are
-# supported (and these tests run) on current jax; on old rigs they are
-# declared unsupported rather than shipped red.
-requires_full_auto_axes = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="pre-0.6 jax: shard_map auto-axes support is partial "
-           "(PartitionId UNIMPLEMENTED under jit)",
-)
 from cekirdekler_tpu.models import Transformer, TransformerConfig
 
 
@@ -121,7 +111,6 @@ def test_moe_forward_and_training():
     assert losses[-1] < losses[0] * 0.8, losses
 
 
-@requires_full_auto_axes
 def test_moe_sharded_matches_single_device():
     devs = jax.devices("cpu")[:8]
     mesh = par.make_mesh(devs, dp=2, tp=2, ep=2)
@@ -137,7 +126,6 @@ def test_moe_sharded_matches_single_device():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 
 
-@requires_full_auto_axes
 def test_pp_pipelined_matches_sequential():
     devs = jax.devices("cpu")[:8]
     mesh = par.make_mesh(devs, dp=2, pp=2, tp=2)
@@ -153,7 +141,6 @@ def test_pp_pipelined_matches_sequential():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 
 
-@requires_full_auto_axes
 def test_pp_training_reduces_loss():
     devs = jax.devices("cpu")[:4]
     mesh = par.make_mesh(devs, pp=2, tp=2)

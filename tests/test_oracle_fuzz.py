@@ -22,20 +22,6 @@ jnp = pytest.importorskip("jax.numpy")
 from cekirdekler_tpu.kernel import codegen, lang  # noqa: E402
 from tests.kernel_oracle import Oracle  # noqa: E402
 
-import jax.experimental.pallas as _pl  # noqa: E402
-
-# env capability, not a code property (same guard as
-# tests/test_lowering_fuzz.py): cases whose kernels fall inside the
-# widened Pallas subset fuzz the tile lowering three-way, and that
-# lowering needs pl.Element — absent from this container's jax.  Only
-# the in-subset cases are marked; per-lane-gather/private-array cases
-# never touch Pallas and run everywhere.
-requires_pl_element = pytest.mark.skipif(
-    not hasattr(_pl, "Element"),
-    reason="jax.experimental.pallas lacks pl.Element in this environment "
-           "(pre-0.5-era pallas) — the widened tile lowering cannot build",
-)
-
 N = 128
 
 
@@ -75,7 +61,6 @@ def _run_both(src: str, arrays: dict, values: dict, atol=1e-4):
         )
 
 
-@requires_pl_element
 def test_oracle_uniform_gather_loop():
     src = """
     __kernel void k(__global float* w, __global float* x, __global float* out, int m) {
@@ -151,7 +136,6 @@ def test_oracle_private_array_histogramish():
     }, {})
 
 
-@requires_pl_element
 def test_oracle_integer_division_semantics():
     """C truncating division/remainder with mixed signs."""
     src = """
@@ -170,7 +154,6 @@ def test_oracle_integer_division_semantics():
     }, {})
 
 
-@requires_pl_element
 def test_oracle_divergent_while_with_builtins():
     src = """
     __kernel void k(__global float* x, __global float* out) {
@@ -216,7 +199,6 @@ def test_oracle_random_gather_kernels(seed):
     }, {})
 
 
-@requires_pl_element
 def test_oracle_break_in_divergent_loop():
     src = """
     __kernel void k(__global float* x, __global float* out) {
@@ -238,7 +220,6 @@ def test_oracle_break_in_divergent_loop():
     }, {})
 
 
-@requires_pl_element
 def test_oracle_continue_skips_rest_but_runs_step():
     src = """
     __kernel void k(__global float* out) {
@@ -255,7 +236,6 @@ def test_oracle_continue_skips_rest_but_runs_step():
     _run_both(src, {"out": np.zeros(N, np.float32)}, {})
 
 
-@requires_pl_element
 def test_oracle_break_continue_mixed_while():
     src = """
     __kernel void k(__global float* x, __global float* out) {
@@ -282,7 +262,6 @@ def test_oracle_break_continue_mixed_while():
     }, {})
 
 
-@requires_pl_element
 def test_oracle_break_in_do_while_first_pass():
     src = """
     __kernel void k(__global float* x, __global float* out) {
@@ -330,7 +309,6 @@ def test_oracle_divergent_break_poisons_uniform_gather():
     }, {})
 
 
-@requires_pl_element
 def test_oracle_helper_functions():
     """Non-kernel helper functions inline at call sites: scalar params,
     locals, loops inside the helper, nested helper calls."""
@@ -358,7 +336,6 @@ def test_oracle_helper_functions():
     }, {})
 
 
-@requires_pl_element
 def test_oracle_helper_under_divergent_branch():
     src = """
     float pick(float a, float b) {

@@ -15,19 +15,6 @@ from cekirdekler_tpu.kernel.pallas_backend import (
     build_kernel_fn_pallas,
 )
 
-import jax.experimental.pallas as _pl
-
-# env capability, not a code property: these cases build real Pallas
-# tile programs, which need pl.Element (pallas_backend.py:469) — absent
-# from this container's jax, so they failed identically every run.  The
-# subset-REJECTION tests (PallasUnsupported raised before any tile
-# program is built) run everywhere.
-requires_pl_element = pytest.mark.skipif(
-    not hasattr(_pl, "Element"),
-    reason="jax.experimental.pallas lacks pl.Element in this environment "
-           "(pre-0.5-era pallas) — the widened tile lowering cannot build",
-)
-
 SAXPY = """
 __kernel void saxpy(__global float* x, __global float* y, float a) {
     int i = get_global_id(0);
@@ -126,7 +113,6 @@ def _both(src: str, arrays, values=(), chunk=None, offset=0, global_size=None):
     return out_x, out_p
 
 
-@requires_pl_element
 def test_saxpy_matches_xla():
     n = 1024
     x = np.linspace(-2, 2, n).astype(np.float32)
@@ -138,7 +124,6 @@ def test_saxpy_matches_xla():
     np.testing.assert_allclose(np.asarray(out_p[1]), 3.0 * x + 1.0, rtol=1e-6, atol=1e-6)
 
 
-@requires_pl_element
 def test_while_loop_kernel_matches_xla():
     n = 512
     out = np.zeros(n, np.float32)
@@ -148,7 +133,6 @@ def test_while_loop_kernel_matches_xla():
     assert got.min() >= 0 and got.max() <= 64 and len(np.unique(got)) > 3
 
 
-@requires_pl_element
 def test_masked_branch_matches_xla():
     n = 256
     rng = np.random.default_rng(7)
@@ -160,7 +144,6 @@ def test_masked_branch_matches_xla():
     np.testing.assert_allclose(np.asarray(out_p[0]), want, rtol=1e-6)
 
 
-@requires_pl_element
 def test_offset_window_into_larger_buffer():
     """chunk < buffer: the Pallas path slices the window at a runtime
     offset and update-slices the result back (multi-chip range slices)."""
@@ -187,7 +170,6 @@ def test_store_plus_shift_read_rejected():
         build_kernel_fn_pallas(_kdef(STORE_SHIFT_MIX), 256, 64, 256, interpret=True)
 
 
-@requires_pl_element
 def test_shifted_window_matches_xla():
     """a[i+1] now lowers to a halo block + lane roll (widened subset)."""
     n = 1024
@@ -202,7 +184,6 @@ def test_shifted_window_matches_xla():
     np.testing.assert_array_equal(got[:-1], x[1:])
 
 
-@requires_pl_element
 def test_stencil_multi_tap_matches_xla_across_offsets():
     """8-tap wave stencil: row- and lane-crossing shifts, offset launches
     into a larger buffer, edge-clamp agreement at both ends."""
@@ -216,7 +197,6 @@ def test_stencil_multi_tap_matches_xla_across_offsets():
             np.asarray(out_x[2]), np.asarray(out_p[2]), rtol=1e-5, atol=1e-5)
 
 
-@requires_pl_element
 def test_uniform_gather_loop_matches_xla():
     """The n-body shape: a lane-uniform loop index streaming a second
     buffer (SMEM operand) plus a constant-index broadcast w[0]."""
@@ -230,7 +210,6 @@ def test_uniform_gather_loop_matches_xla():
         np.asarray(out_x[2]), np.asarray(out_p[2]), rtol=1e-5, atol=1e-5)
 
 
-@requires_pl_element
 def test_nbody_kernel_matches_xla():
     """The full NBODY_SRC kernel (uniform x[j]/y[j]/z[j] loads + elementwise
     velocity updates) through both lowerings."""
@@ -253,7 +232,6 @@ def test_nbody_kernel_matches_xla():
                                    rtol=2e-5, atol=2e-5)
 
 
-@requires_pl_element
 def test_smem_limit_falls_back_inside_fn(monkeypatch):
     """Uniform-read buffers beyond the SMEM budget delegate to the XLA
     lowering at trace time — same results, no failure."""
@@ -288,7 +266,6 @@ def test_registry_falls_back_off_tpu():
     assert fn_gather is not None  # fell back to the XLA lowering
 
 
-@requires_pl_element
 def test_shift_only_routing_veto():
     """Measured routing policy: shift-only kernels prefer the XLA lowering
     (faster on HBM-bound single-pass stencils); force=True overrides for
@@ -300,7 +277,6 @@ def test_shift_only_routing_veto():
     assert fn is not None
 
 
-@requires_pl_element
 def test_multi_tile_grid_halo_and_smem():
     """grid > 1 coverage for the widened paths: small block_rows force
     multiple tiles, so the pl.Element halo index map, the 8-row alignment
@@ -336,7 +312,6 @@ def test_multi_tile_grid_halo_and_smem():
             err_msg=f"grid>1 divergence at offset {o}")
 
 
-@requires_pl_element
 def test_f16_arrays_delegate_to_xla_inside_fn():
     """float16 tiles fail the Mosaic compile on the real chip AFTER the
     registry's build-time fallback window, so the launch fn itself must
@@ -368,7 +343,6 @@ def test_f16_arrays_delegate_to_xla_inside_fn():
                                rtol=1e-2, atol=1e-2)
 
 
-@requires_pl_element
 def test_half_declared_kernel_vetoed_for_mosaic():
     """A kernel that DECLARES half (param/local/cast) creates f16 tiles
     internally regardless of the caller's array dtypes — vetoed at build
@@ -387,7 +361,6 @@ def test_half_declared_kernel_vetoed_for_mosaic():
     assert fn is not None
 
 
-@requires_pl_element
 def test_bf16_arrays_through_real_pallas_path():
     """bfloat16 arrays against a float-declared kernel exercise the
     actual-dtype out_shape + load/store casts on the PALLAS path (bf16 is

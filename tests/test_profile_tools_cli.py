@@ -77,8 +77,11 @@ def test_kernel_profile_cli_trace_dir_roofline_and_store(tmp_path):
     fix = str(tmp_path / "fix")
     store = str(tmp_path / "store")
     _fixture_dump(fix)
+    # an offline dump on the CPU rig: the roof is the dump's chip's, and
+    # the caller names it — no peak is assumed for an unknown device
     r = _run("kernel_profile.py", "--trace-dir", fix, "--store", store,
-             "--flops", "1e9", "--bytes", "1e8")
+             "--flops", "1e9", "--bytes", "1e8",
+             "--peak-tflops", "197", "--peak-gbps", "819")
     assert r.returncode == 0, r.stdout + r.stderr
     assert "mandelbrot" in r.stdout and "device ms" in r.stdout
     assert "5.700" in r.stdout          # 5.0 + 0.7 ms attributed

@@ -265,27 +265,43 @@ def test_artifact_round_ordering_is_numeric(tmp_path):
     assert "BENCH_r100" in r.stdout or "mandelbrot" in r.stdout
 
 
-def test_load_headline_real_r5_artifact():
-    art = regress.load_headline(os.path.join(ROOT, "BENCH_r05.json"))
+def _driver_artifact(tmp_path, name="BENCH_r05.json"):
+    """A synthetic driver-format artifact: the wrapper the driver wrote
+    around a bench run, whose ``tail`` is the LAST 2000 characters of
+    the one JSON line (front cut mid-object, headline block last)."""
+    doc = {
+        "metric": "mandelbrot_throughput", "value": 240.0,
+        "lowering_faceoff": {"wave_stencil": {"x": list(range(400))}},
+        "errors": {"dtype_matrix": "skipped: 1500s bench budget spent"},
+        "headline": dict(HEADLINE),
+    }
+    p = tmp_path / name
+    p.write_text(json.dumps({
+        "n": 5, "cmd": "python bench.py", "rc": 0,
+        "tail": json.dumps(doc)[-2000:], "parsed": None}))
+    return str(p)
+
+
+def test_load_headline_driver_format_artifact(tmp_path):
+    art = regress.load_headline(_driver_artifact(tmp_path))
     assert isinstance(art["headline"], dict)
     assert "mandelbrot_mpix" in art["headline"]
     assert isinstance(art["errors"], dict)
 
 
 def test_cli_acceptance_pair(tmp_path):
-    """The acceptance criterion end-to-end through the CLI: r5 baseline
-    vs (a) itself → 0, (b) 20% injected regression → nonzero, (c) a
-    bare-null section → nonzero."""
-    r5 = regress.load_headline(os.path.join(ROOT, "BENCH_r05.json"))
-    h = dict(r5["headline"])
+    """The acceptance criterion end-to-end through the CLI: a driver-
+    format baseline vs (a) itself → 0, (b) 20% injected regression →
+    nonzero, (c) a bare-null section → nonzero."""
+    base = _driver_artifact(tmp_path)
+    h = dict(regress.load_headline(base)["headline"])
 
     def run(candidate_doc):
         p = tmp_path / "cand.json"
         p.write_text(json.dumps(candidate_doc))
         return subprocess.run(
             [sys.executable, os.path.join(ROOT, "tools", "regress.py"),
-             "--against", os.path.join(ROOT, "BENCH_r05.json"),
-             "--candidate", str(p)],
+             "--against", base, "--candidate", str(p)],
             capture_output=True, text=True,
         )
 
@@ -435,7 +451,7 @@ def test_scheduler_records_structured_exception_reason():
     s = bench.SectionScheduler(100.0, {})
 
     def boom():
-        raise RuntimeError("tunnel died")
+        raise RuntimeError("link died")
 
     assert s.run("flash_train", boom, default=None) is None
     rec = s.skips["flash_train"]
@@ -490,13 +506,13 @@ def test_failed_ratio_sections_surface_as_starved_not_improvement():
     v = regress.diff_headlines(
         _art(HEADLINE),
         _art(cand, errors={
-            "tuned_loop": "RuntimeError: tunnel died",
+            "tuned_loop": "RuntimeError: link died",
             "repeat_mode": "skipped: budget spent",
         }),
     )
     assert v["exit_code"] == 3
     reasons = {f["key"]: f["reason"] for f in v["findings"]}
-    assert "tunnel died" in reasons["vs_tuned_loop"]
+    assert "link died" in reasons["vs_tuned_loop"]
     assert "budget spent" in reasons["repeat_mode_mpix"]
 
 
@@ -508,7 +524,7 @@ def test_critical_failure_artifact_still_finalized():
     bench = _bench()
     s = bench.SectionScheduler(100.0, {})
     full = s.run("framework", lambda: (_ for _ in ()).throw(
-        RuntimeError("tunnel died")), default=None, critical=True)
+        RuntimeError("link died")), default=None, critical=True)
     assert full is None
     result = {
         "metric": "mandelbrot_throughput", "value": 0.0,
@@ -537,7 +553,7 @@ def test_critical_failure_artifact_still_finalized():
     )
     assert v["exit_code"] == 3
     by_key = {f["key"]: f for f in v["findings"]}
-    assert "tunnel died" in by_key["mandelbrot_mpix"]["reason"]
+    assert "link died" in by_key["mandelbrot_mpix"]["reason"]
 
 
 def test_annotate_nulls_replaces_bare_nulls_only():

@@ -423,13 +423,13 @@ def test_nbody_e2e_attribution_names_the_factors():
     assert out["checked"]
     att = out["attribution"]
     f = att["factors"]
-    for name in ("window_rtt", "ladder_launch", "upload",
+    for name in ("window_fence", "ladder_launch", "upload",
                  "download_flush", "scheduler_dispatch", "host_gap"):
         assert name in f, f.keys()
         assert f[name]["ms"] >= 0.0
         assert f[name]["frac"] is None or f[name]["frac"] >= 0.0
     # 12 iters / window 4 → 3 barriers
-    assert f["window_rtt"]["count"] == 3
+    assert f["window_fence"]["count"] == 3
     assert f["ladder_launch"]["count"] >= 12  # ≥1 dispatch span per iter
     li = att["lane_interference"]
     assert "factor" in li, li

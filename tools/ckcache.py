@@ -10,9 +10,12 @@ Subcommands over a cache root (``--root`` or ``CK_COMPILE_CACHE``):
   hit/miss/write/evict totals read back from ``manifest.jsonl`` (the
   in-process ``ck_compile_cache_*`` counters only see one interpreter;
   the manifest sees the fleet).
-- ``prune`` — LRU-evict ``entries/`` + ``xla/`` files to the size cap
-  (``--max-mb`` or ``CK_COMPILE_CACHE_MAX_MB``), oldest mtime first
-  (hits refresh mtime), one ``evict`` manifest row per removal.
+- ``prune`` — evict ``entries/`` files to the size cap (``--max-mb`` or
+  ``CK_COMPILE_CACHE_MAX_MB``), oldest mtime first (hits refresh mtime),
+  one ``evict`` manifest row per removal.  jax's own cache directory is
+  not this tool's: where the package placed it (``<checkout>/.jax_cache``)
+  building a ``Cores`` trims it to the same cap; one placed from outside
+  belongs to whoever placed it.
 - ``--verify`` (flag on any subcommand, or alone) — re-hash every entry
   payload against its newest ``write`` manifest row: ``corrupt``
   entries fail the exit code; ``unindexed`` ones (payload present, its

@@ -5,16 +5,26 @@
 Three SUBPROCESS incarnations per workload, each a fresh interpreter
 (process-cold is a process property — it cannot be measured in-process):
 
-- **cold** — no ``CK_COMPILE_CACHE``: the autoscale worst case.  Times
-  the first fused batch (compile + execute) and a steady-state batch.
-- **populate** — same run with the cache armed: the engage-time
-  recorder (``core/cores._cache_record_engaged``) persists the window
-  spec and jax's persistent cache captures the XLA executables.  This
-  is the PRODUCTION population flow, not a synthetic writer.
-- **warm** — cache armed, ``warm_from_disk`` precompiles the full
+- **cold** — no ``CK_COMPILE_CACHE`` and jax's persistent compilation
+  cache switched OFF explicitly (``JAX_ENABLE_COMPILATION_CACHE=0`` — the
+  package otherwise places one in the checkout): the autoscale worst
+  case.  Times the first fused batch (compile + execute) and a
+  steady-state batch.
+- **populate** — same run with the manifest armed and jax's cache placed
+  from outside (``JAX_COMPILATION_CACHE_DIR=<root>/xla``): the
+  engage-time recorder (``core/cores._cache_record_engaged``) persists
+  the window spec and jax's persistent cache captures the XLA
+  executables.  This is the PRODUCTION population flow, not a synthetic
+  writer.
+- **warm** — same placement, ``warm_from_disk`` precompiles the full
   predicated launch ladder BEFORE traffic, then times the same first
   batch.  ``cold_start_warm_speedup = cold.first / warm.first`` is the
   regression-watched headline (higher is better).
+
+Every incarnation is a child PINNED to the CPU backend
+(``JAX_PLATFORMS=cpu``) and says so in its row: a chip belongs to one
+process, and the usual parent (bench.py) holds it.  The numbers are
+host-CPU compile walls, never device metrics.
 
 Exactness gate: all three incarnations hash their result arrays —
 the cache must be bit-invisible (``exact`` is False otherwise, and the
@@ -155,7 +165,8 @@ def _child(args) -> int:
     else:
         os.environ.pop(CACHE_ENV, None)
     out: dict = {"mode": args.child, "workload": args.workload,
-                 "cache": bool(args.cache), "pid": os.getpid()}
+                 "cache": bool(args.cache), "pid": os.getpid(),
+                 "platform": os.environ.get("JAX_PLATFORMS", "")}
     try:
         if args.workload == "flash":
             out = _child_flash(args, out)
@@ -176,6 +187,17 @@ def _spawn(mode: str, workload: str, cache: str, n: int, local_range: int,
            iters: int, seq: int, timeout: float = CHILD_TIMEOUT_S) -> dict:
     env = os.environ.copy()
     env.pop(CACHE_ENV, None)  # the child's --cache flag is authoritative
+    # one process per chip: the parent may hold it, so every incarnation
+    # is pinned to the CPU backend (and reports that as its platform)
+    env["JAX_PLATFORMS"] = "cpu"
+    if cache:
+        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(cache, "xla")
+        env.pop("JAX_ENABLE_COMPILATION_CACHE", None)
+    else:
+        # process-cold means NO persistent cache, stated — not the
+        # accident of nobody having placed one
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "0"
     cmd = [sys.executable, os.path.abspath(__file__),
            "--child", mode, "--workload", workload, "--cache", cache,
            "--n", str(n), "--local-range", str(local_range),
@@ -242,6 +264,7 @@ def coldstart_section(devices=None, resilience=None, n: int = 4096,
         if own_root:
             shutil.rmtree(root, ignore_errors=True)
     out = {
+        "platform": "cpu (pinned children; host compile walls)",
         # the watched key: n-body only — the flash path's speedup is
         # tuner/interpret-mode dependent and reported, not watched
         "cold_start_warm_speedup": nbody.get("warm_speedup"),

@@ -79,20 +79,14 @@ def run_workload(args) -> int:
     from cekirdekler_tpu.trace.device import DeviceCapture
     from cekirdekler_tpu.workloads import mandelbrot_pallas_kernel
 
-    import jax
-
-    devs = ct.all_devices()
-    tpus = devs.tpus()
-    devs = (tpus if len(tpus) else devs).subset(1)
-    print("device:", devs[0].jax_device)
+    devs = ct.chip_devices().subset(1)
+    print("device:", devs[0].jax_device,
+          f"(platform {devs[0].platform}; Pallas lowers for it)")
 
     n = args.size * args.size
     local = 256
     vals = (-2.0, -1.25, 2.5 / args.size, 2.5 / args.size, args.size, 64)
-    cr = NumberCruncher(
-        devs,
-        mandelbrot_pallas_kernel(interpret=jax.default_backend() != "tpu"),
-    )
+    cr = NumberCruncher(devs, mandelbrot_pallas_kernel())
     out = ClArray(n, np.float32, name="kp_out", read=False, write=True)
     try:
         out.compute(cr, 7100, "mandelbrot", n, local, values=vals)  # warm
@@ -241,18 +235,12 @@ def main(argv=None) -> int:
     ap.add_argument("--bytes", type=float, default=None,
                     help="analytic byte count for the roofline row")
     ap.add_argument("--peak-tflops", type=float, default=None,
-                    help="machine compute peak (default: v5e bf16)")
+                    help="machine compute peak (default: the running "
+                         "device's, by kind — hardware.DEVICE_PEAKS)")
     ap.add_argument("--peak-gbps", type=float, default=None,
-                    help="machine HBM bandwidth (default: v5e)")
+                    help="machine HBM bandwidth (default: same table)")
     args = ap.parse_args(argv)
 
-    from cekirdekler_tpu.trace.device import (
-        V5E_HBM_GBPS, V5E_PEAK_BF16_TFLOPS)
-
-    if args.peak_tflops is None:
-        args.peak_tflops = V5E_PEAK_BF16_TFLOPS
-    if args.peak_gbps is None:
-        args.peak_gbps = V5E_HBM_GBPS
     if args.show_store:
         return show_store(args)
     if args.trace_dir:

@@ -318,6 +318,7 @@ def run_loadgen(
     lat_ms = sorted(v * 1000.0 for v in latencies)
     return {
         "mode": mode,
+        "platform": devs[0].platform,  # where the lanes ran (cpu default)
         "clients": clients,
         "tenants": tenants,
         "signatures": signatures,
@@ -616,6 +617,10 @@ def run_fabric(
     }
 
 
+#: What every multi-process fabric shard runs on, and reports.
+FABRIC_WORKER_PLATFORM = "cpu"
+
+
 def _spawn_fabric_worker(member: str, n: int, local_range: int,
                          max_queue_depth: int = 0,
                          gather_window_ms: float = 4.0,
@@ -626,7 +631,10 @@ def _spawn_fabric_worker(member: str, n: int, local_range: int,
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # one process per chip: shard workers are children of a process that
+    # may hold it, so they are PINNED to the CPU backend — and the merged
+    # result says so (``platform``)
+    env["JAX_PLATFORMS"] = FABRIC_WORKER_PLATFORM
     proc = subprocess.Popen(
         [sys.executable, os.path.join(repo, "tests", "_fabric_worker.py"),
          str(member), str(int(n)), str(int(local_range)),
@@ -787,6 +795,7 @@ def run_fabric_mp(
     merged["goodput_rps"] = (round(merged["completed"] / wall_s, 2)
                              if wall_s > 0 else None)
     merged["failure_causes"] = dict(sorted(merged["failure_causes"].items()))
+    merged["platform"] = f"{FABRIC_WORKER_PLATFORM} (pinned shard workers)"
     return merged
 
 

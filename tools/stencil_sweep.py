@@ -3,8 +3,8 @@ the fused XLA lowering? (VERDICT r4 #6)
 
 Generates a K-tap 1-D stencil kernel (K shifted loads per store, one halo
 fetch amortized across all K), lowers it both ways, and measures with the
-faceoff chain methodology (dependent fori_loop steps, one sync, RTT
-subtracted).  The answer feeds docs/KERNEL_LANGUAGE.md's routing section.
+faceoff chain methodology (dependent fori_loop steps, one device fence
+— workloads.fori_chain_bench; needs the chip).  The answer feeds docs/KERNEL_LANGUAGE.md's routing section.
 
 Usage: python tools/stencil_sweep.py [K ...]
 """
@@ -29,7 +29,7 @@ def stencil_src(taps: list[int]) -> str:
     )
 
 
-def bench(fn, arrs, reps, rtt):
+def bench(fn, arrs, reps):
     """Shared harness, structural carry: the stencil output feeds back as
     the next input (q becomes p) — see fori_chain_bench's carry arg."""
     from cekirdekler_tpu.workloads import fori_chain_bench
@@ -38,7 +38,6 @@ def bench(fn, arrs, reps, rtt):
         lambda *c: fn(0, c, ()),
         arrs,
         reps,
-        rtt=rtt,
         carry=lambda c, out: (out[1], c[0]),
     )
 
@@ -46,10 +45,9 @@ def bench(fn, arrs, reps, rtt):
 def main(Ks=(2, 4, 8, 16, 24), n=1 << 24, reps=192):
     from cekirdekler_tpu.kernel import codegen, lang
     from cekirdekler_tpu.kernel.pallas_backend import build_kernel_fn_pallas
-    from cekirdekler_tpu.workloads import measure_rtt
+    from cekirdekler_tpu.hardware import chip_devices
 
-    rtt = measure_rtt()
-    print(f"rtt_ms={rtt*1e3:.1f} n={n} reps={reps}")
+    print(f"device={chip_devices()[0].name} n={n} reps={reps}")
     rng = np.random.default_rng(0)
     base = (
         jnp.asarray(rng.standard_normal(n).astype(np.float32)),
@@ -70,8 +68,8 @@ def main(Ks=(2, 4, 8, 16, 24), n=1 << 24, reps=192):
         except Exception as e:
             print(f"K={K}: pallas build failed: {e}"[:120])
             continue
-        tx = bench(xla_fn, base, reps, rtt)
-        tp = bench(pl_fn, base, reps, rtt)
+        tx = bench(xla_fn, base, reps)
+        tp = bench(pl_fn, base, reps)
         gbps = 3 * 4 * n / tx / 1e9
         print(f"K={len(taps)} taps={taps[:6]}...: xla {tx*1e3:7.3f} ms "
               f"({gbps:5.0f} GB/s)  pallas {tp*1e3:7.3f} ms  "
