@@ -186,7 +186,7 @@ class DistributedAccelerator(IComputeNode):
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("dcn-exchange")
         _t0 = time.perf_counter()
         value = np.ascontiguousarray(value)
         raw = value.view(np.uint8)
@@ -228,7 +228,7 @@ class DistributedAccelerator(IComputeNode):
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("dcn-exchange")
         _t0 = time.perf_counter()
         value = np.ascontiguousarray(value)
         raw = value.view(np.uint8)
@@ -332,7 +332,7 @@ class DistributedAccelerator(IComputeNode):
 
         my_share = shares[self.pid]
         my_off = int(refs[self.pid])
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("enqueue")
         t0 = time.perf_counter()
         if my_share > 0:
             group = ParameterGroup(params)

@@ -292,14 +292,11 @@ class Cores:
             "windows": 0, "fused_iters": 0, "deferred_iters": 0,
             "disengaged": {},
         }
-        # cached metric handles for the fused hot/warm paths: the
-        # deferral IS the dispatch-floor collapse ("a counter
-        # increment"), so it must not pay a registry get-or-create per
-        # call (label-less here; the per-reason disengage counter stays
-        # get-or-create — disengages are cold)
-        self._m_fused_deferred = REGISTRY.counter(
-            "ck_fused_deferred_iters_total",
-            "enqueue calls deferred into fused windows")
+        # cached metric handles for the fused warm path (one dispatch
+        # per batch; the deferral itself counts into fused_stats alone —
+        # it IS the dispatch-floor collapse, "a counter increment"); the
+        # per-reason disengage counter stays get-or-create — disengages
+        # are cold
         self._m_fused_windows = REGISTRY.counter(
             "ck_fused_windows_total", "fused ladder dispatch batches")
         self._m_fused_iters = REGISTRY.counter(
@@ -579,6 +576,15 @@ class Cores:
                 f"global_range ({global_range}) must be divisible by step ({step})"
             )
         t_start = time.perf_counter()
+        # the call's "enqueue" span (ring and, in a profiler session, the
+        # ck/enqueue annotation): closed by _fused_defer for a deferred
+        # call and before the engage walk for a per-call one.  The first
+        # call of an enqueue window, and every non-windowed call, opens
+        # the next ``win``: the identifier its spans share across threads
+        _tt = TRACER.t0("enqueue")
+        # ckcheck: ok racy read — a window id is an observation aid
+        if _tt and (not self.enqueue_mode or self._enqueue_t0 is None):
+            TRACER.next_window()
         # Enqueue mode cannot rebalance on per-call host benches (they only
         # measure async dispatch time), so ranges hold still BETWEEN syncs
         # and move AT them: barrier() times each chip's retirement fence and
@@ -639,7 +645,7 @@ class Cores:
                     # reset upload coverage mid-window: operands are no
                     # longer guaranteed HBM-resident for these rows
                     self._fused_break("non-resident")
-                elif self._fused_defer(t_start, kernel_names):
+                elif self._fused_defer(t_start, kernel_names, _tt):
                     return
             else:
                 self._fused_break("signature-change")
@@ -674,6 +680,7 @@ class Cores:
             with self._lock:
                 self._note_enqueue_call(compute_id, t_start)
         old_ranges = list(self.global_ranges.get(compute_id, ()))
+        _ts = TRACER.t0("schedule")
         ranges, refs = self._ranges_for(
             compute_id,
             global_range,
@@ -682,6 +689,7 @@ class Cores:
             # ckcheck: ok one-shot arm — same contract as the check above
             or compute_id in self._enqueue_rebalance,
         )
+        TRACER.record("schedule", _ts, cid=compute_id)
         with self._lock:
             # same lock as barrier's |= : a discard interleaved into the
             # set union would un-arm a rebalance the barrier just armed
@@ -696,19 +704,12 @@ class Cores:
                 old=list(old_ranges),
             )
             # balancer health (metrics registry): per-cid per-device share
-            # gauges set on CHANGE only (steady state costs nothing), the
-            # re-split count, and how many work items the move shifted
+            # gauges set on CHANGE only (steady state costs nothing) and the
+            # re-split count
             REGISTRY.counter(
                 "ck_rebalance_total", "range-table changes",
                 cid=compute_id,
             ).inc()
-            if old_ranges and len(old_ranges) == len(ranges):
-                moved = sum(
-                    abs(a - b) for a, b in zip(ranges, old_ranges)) // 2
-                REGISTRY.counter(
-                    "ck_rebalance_moved_items_total",
-                    "work items shifted between chips by rebalances",
-                ).inc(moved)
             for i, r in enumerate(ranges):
                 REGISTRY.gauge(
                     "ck_balance_share", "per-chip work-item share",
@@ -748,7 +749,9 @@ class Cores:
             # Worker.coverage_epoch; fused windows check it per deferral,
             # per-call windows re-upload from a host made current inside
             # the same atomic step).
+            _tr = TRACER.t0("resync")
             self._flush_and_reset_coverage()
+            TRACER.record("resync", _tr, cid=compute_id, tag="range-move")
         # a chip whose share was quantized to zero never re-runs its bench;
         # decay its stale measurement so a one-off slow call (e.g. first-call
         # compile) cannot starve it permanently.  The transfer floor decays
@@ -789,7 +792,7 @@ class Cores:
                 continue
             futures.append(
                 self.pool.submit(
-                    self._run_worker,
+                    TRACER.bind(self._run_worker, w.index),
                     w,
                     kernel_names,
                     params,
@@ -818,20 +821,22 @@ class Cores:
             record_crash("cores.compute", errs[0], lanes=self._lane_config())
             raise errs[0]
 
-        TRACER.record(
-            "enqueue", t_start, cid=compute_id,
-            tag="+".join(kernel_names),
-        )
+        if _tt:
+            TRACER.record(
+                "enqueue", _tt, cid=compute_id, tag="+".join(kernel_names),
+            )
         self._record_perf(compute_id, t_start, ranges)
         # fused-window engagement: a successfully dispatched enqueue call
         # whose next identical call would be a pure launch (operands
         # resident, ranges pinned) establishes the window this call's
         # geometry defines — subsequent matching calls defer
         if self.enqueue_mode and self.fused_dispatch and not pipeline:
+            _te = TRACER.t0("engage")
             self._fused_try_engage(
                 kernel_names, params, compute_id, global_range,
                 local_range, global_offset, value_args, ranges, refs, step,
             )
+            TRACER.record("engage", _te, cid=compute_id)
 
     def _note_kernel_verdict(self, verdict, kernel_names) -> None:
         """Advisory-mode surfacing of an unsafe launch shape: one
@@ -1014,10 +1019,11 @@ class Cores:
                 "lanes": [w.index for w, _off, _size in rows],
             }, {"engaged": True, "rows": len(rows)})
 
-    def _fused_defer(self, t_start: float, kernel_names) -> bool:
+    def _fused_defer(self, t_start: float, kernel_names, span=0.0) -> bool:
         """Count this call into the active fused window.  Returns False
         when the window was concurrently closed (caller falls through to
-        the per-call path)."""
+        the per-call path).  ``span`` is the caller's open "enqueue" span
+        (falsy while the tracer is inactive)."""
         with self._lock:
             run = self._fused_run
             if run is None or self._fused_sig is None:
@@ -1027,16 +1033,15 @@ class Cores:
             self._fused_pending += 1
             pending = self._fused_pending
             self.fused_stats["deferred_iters"] += 1
-        self._m_fused_deferred.inc()
         if pending >= max(1, int(self.fused_batch)):
             self._fused_flush()
-        if TRACER.enabled:
+        if TRACER.active():
             # guard the WHOLE call: the tag concatenation allocates per
             # deferral even when the tracer is off, and the deferral is
             # the path whose cost budget is "a counter increment"
             # (ckcheck hotpath finding, PR 7)
             TRACER.record(
-                "enqueue", t_start, cid=cid,
+                "enqueue", span, cid=cid,
                 tag="+".join(kernel_names) + " fused-defer",
             )
         if self.performance_feed:
@@ -1057,7 +1062,8 @@ class Cores:
         """Submit one K-iteration ladder dispatch per active device to the
         per-device driver queues (host-side dispatch of device B's ladder
         overlaps device A's execution; FIFO per device)."""
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("fused")
+        _t_pass = time.perf_counter()
         try:
             # PREFLIGHT every lane before queuing ANY lane's closure:
             # pending driver errors and the armed driver-submit fault
@@ -1117,7 +1123,7 @@ class Cores:
         # one ComputePerf per dispatched window (total_ms = this
         # dispatch pass) — the per-window row the per-deferral fast
         # path above stopped paying for
-        self._record_perf(run.compute_id, _tt,
+        self._record_perf(run.compute_id, _t_pass,
                           self.global_ranges.get(run.compute_id, []))
         FLIGHT.event("fused-window", cid=run.compute_id, iters=iters)
         TRACER.record("fused", _tt, cid=run.compute_id, tag=f"x{iters}")
@@ -1147,7 +1153,11 @@ class Cores:
                 self._fused_run = None
             if run is not None and k > 0:
                 self._dispatch_fused(run, k)
-        self._fused_drain()
+        _td = TRACER.t0("drain")
+        try:
+            self._fused_drain()
+        finally:
+            TRACER.record("drain", _td)
 
     def _note_disengage(self, reason: str, cid: int | None) -> None:
         """The one disengage-accounting path: fused_stats dict bump +
@@ -1214,7 +1224,6 @@ class Cores:
                 self._flush_iters[cid] += k - 1
             self._fused_pending += k
             self.fused_stats["deferred_iters"] += k
-        self._m_fused_deferred.inc(k)
         self._fused_flush()
         return True
 
@@ -1840,10 +1849,14 @@ class Cores:
         if tuner_key is not None and nbytes > 0 and (
                 tune_u_ms > 0.0 or d_ms > 0.0):
             c_ms = max(wall_s * 1000.0 - tune_u_ms - d_ms, 0.0)
+            _tu = TRACER.t0("tune")
             self.transfer_tuner.observe(
                 w.index, tuner_key, nbytes, tune_u_ms, c_ms, d_ms,
                 chunks=chunks, wall_ms=wall_s * 1000.0, fenced=fenced,
             )
+            if _tu:
+                TRACER.record("tune", _tu, cid=compute_id, lane=w.index,
+                              tag=f"observe:{chunks}")
 
     def _run_streamed(
         self,
@@ -1934,24 +1947,34 @@ class Cores:
             return False, None
         nbytes = self._stream_key_bytes(w, params, offset, size, single)
         tuner_key = self._tuner_kernel_key(kernel_names, value_args)
-        chunks = self.stream_chunks or self.transfer_tuner.choose(
-            w.index, tuner_key, nbytes, max_chunks
-        )
+        chunks = self.stream_chunks
+        if not chunks:
+            _tu = TRACER.t0("tune")
+            chunks = self.transfer_tuner.choose(
+                w.index, tuner_key, nbytes, max_chunks
+            )
+            if _tu:
+                TRACER.record("tune", _tu, cid=compute_id, lane=w.index,
+                              tag=f"choose:{chunks}")
         chunks = min(max(int(chunks), 1), max_chunks)
         # record the live choice even when it is "monolithic" — an
         # artifact saying chunks=1 ("the autotuner judged chunk overhead
         # to outweigh overlap on this lane") beats a stale count
-        if self.last_stream_chunks.get(w.index) != chunks:
+        was = self.last_stream_chunks.get(w.index)
+        if was != chunks:
             # flight-record the DECISION, not the steady state: only a
             # changed chunk count is an autotuner move worth a ring slot
             FLIGHT.event("stream-choice", lane=w.index, chunks=chunks,
                          nbytes=nbytes)
+            if TRACER.active():
+                TRACER.instant("tune", cid=compute_id, lane=w.index,
+                               tag=f"chunks:{was}->{chunks}")
         self.last_stream_chunks[w.index] = chunks
         w.m_chunk_count.set(chunks)
         if chunks <= 1:
             return False, nbytes
         plan = chunk_plan(size, step, chunks)
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("pipeline-stage")
         t_phase0 = time.perf_counter()
         for p in up_full:
             w.upload(p, 0, p.size, True)
@@ -2170,7 +2193,7 @@ class Cores:
         R+C+W with no events, Cores.cs:1371-1858).  XLA's async dispatch
         streams play the role of the 16 in-order queues: the transfer
         engine runs blob k+1's DMA while the compute stream runs blob k."""
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("pipeline-stage")
         blob = size // blobs
         if blob <= 0:
             blob, blobs = size, 1
@@ -2240,7 +2263,7 @@ class Cores:
         single blob's transfer outlasts one compute step (the r3 overlap
         shortfall), at the cost of up to L+1 simultaneously staged blobs
         of host/HBM footprint (blob j is staged before blob j-L pops)."""
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("pipeline-stage")
         blob = size // blobs
         if blob <= 0:
             blob, blobs = size, 1
@@ -2408,6 +2431,7 @@ class Cores:
         open fused window is dispatched and drained first — the download
         slices must see the post-ladder buffers."""
         self._fused_close()
+        _tr = TRACER.t0("resync")
         with self._lock:
             pending, self._enqueued = self._enqueued, []
             flush_iters, self._flush_iters = self._flush_iters, {}
@@ -2415,6 +2439,7 @@ class Cores:
             self._start_deferred_downloads(pending, lock_each=True),
             flush_iters,
         )
+        TRACER.record("resync", _tr, tag="flush")
 
     def _flush_and_reset_coverage(self) -> None:
         """The sync-point-rebalance flush: read back every deferred record
@@ -2555,7 +2580,7 @@ class Cores:
         # residue (r7 attribution)
         self._m_barriers.inc()
         _mt0 = time.perf_counter()
-        t_b = TRACER.t0()
+        t_b = TRACER.t0("fence")
         # ONE consistent snapshot of the window state under the lock:
         # another host thread's compute() mutates t0 / the cid order /
         # the iteration counts mid-barrier, and the previous unlocked
@@ -2576,7 +2601,6 @@ class Cores:
         try:
             if len(self.workers) == 1:
                 self.workers[0].fence()
-                TRACER.record("fence", t_b, tag="barrier")
                 return
             done_at: dict[int, float] = {}
             comp_at: dict[int, list[tuple[int, float]]] = {}
@@ -2602,7 +2626,8 @@ class Cores:
                 comp_at[w.index] = comps
 
             errs: list[Exception] = []
-            futs = [self.pool.submit(fence_timed, w) for w in self.workers]
+            futs = [self.pool.submit(TRACER.bind(fence_timed, w.index), w)
+                    for w in self.workers]
             for f in futs:
                 try:
                     f.result()
@@ -2666,7 +2691,6 @@ class Cores:
                 # into it (ckcheck lockset finding)
                 with self._lock:
                     self._enqueue_rebalance |= window_cids
-            TRACER.record("fence", t_b, tag="barrier")
         finally:
             REGISTRY.histogram(
                 "ck_barrier_seconds", "barrier wall time",
@@ -2687,6 +2711,9 @@ class Cores:
             # always close the window — a fence failure must not leave a
             # stale t0/cid set to corrupt the NEXT window's benches
             self._enqueue_window_closed()
+            # the span covers the barrier's bookkeeping too: a chip that
+            # has retired its work waits for all of it
+            TRACER.record("fence", t_b, tag="barrier")
 
     def _drain_evaluate(self) -> None:
         """Run one DrainController transition (barrier tail).  Guarded:

@@ -179,7 +179,7 @@ class _DriverQueue:
             self._pending += 1
             if self._depth_gauge is not None:
                 self._depth_gauge.set(self._pending)
-        self._q.put(fn)
+        self._q.put(TRACER.bind(fn, self.lane))
 
     def _run(self) -> None:
         while True:
@@ -454,7 +454,7 @@ class Worker:
     def upload(self, arr: ClArray, offset_elems: int, size_elems: int, full: bool) -> None:
         """H2D: full array or only this chip's range slice (reference:
         writeToBuffer / writeToBufferRanged, Worker.cs:821-885)."""
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("upload")
         key = id(arr)
         host = arr.host()
         if full:
@@ -465,18 +465,21 @@ class Worker:
             if self.markers is not None:
                 self.markers.add()
                 self.markers.reach_when_ready(buf)
-            TRACER.record("upload", _tt, lane=self.index, tag=arr.name)
+            TRACER.record("upload", _tt, lane=self.index, tag=arr.name,
+                          bytes=host.nbytes)
             return
         buf = self._buffer_for(arr)
         if self.markers is not None:
             self.markers.add()
-        sl = self._h2d(host[offset_elems : offset_elems + size_elems], arr.flags.zero_copy)
+        part = host[offset_elems : offset_elems + size_elems]
+        sl = self._h2d(part, arr.flags.zero_copy)
         out = _update_slice(buf, sl, offset_elems)
         self._buffers[key] = out
         self._record_upload(arr, offset_elems, size_elems)
         if self.markers is not None:
             self.markers.reach_when_ready(out)
-        TRACER.record("upload", _tt, lane=self.index, tag=arr.name)
+        TRACER.record("upload", _tt, lane=self.index, tag=arr.name,
+                      bytes=part.nbytes)
 
     def stage_upload(self, arr: ClArray, offset_elems: int, size_elems: int,
                      kind: str = "upload"):
@@ -487,16 +490,19 @@ class Worker:
         :meth:`commit_upload`.  ``kind`` names the span recorded
         (``upload-chunk`` for one ladder-aligned chunk of a streamed
         partition upload — same split as :meth:`download_async`)."""
-        _tt = TRACER.t0()
+        _tt = TRACER.t0(kind)
         host = arr.host()
         if self.markers is not None:
             self.markers.add()
-        sl = self._h2d(host[offset_elems : offset_elems + size_elems], arr.flags.zero_copy)
+        part = host[offset_elems : offset_elems + size_elems]
+        sl = self._h2d(part, arr.flags.zero_copy)
         if self.markers is not None:
             self.markers.reach_when_ready(sl)
-        tag = (f"{arr.name}@{offset_elems}+{size_elems}"
-               if kind == "upload-chunk" else f"stage:{arr.name}")
-        TRACER.record(kind, _tt, lane=self.index, tag=tag)
+        if _tt:
+            tag = (f"{arr.name}@{offset_elems}+{size_elems}"
+                   if kind == "upload-chunk" else f"stage:{arr.name}")
+            TRACER.record(kind, _tt, lane=self.index, tag=tag,
+                          bytes=part.nbytes)
         return (arr, sl, offset_elems)
 
     def stage_upload_chunk(self, arr: ClArray, offset_elems: int, size_elems: int):
@@ -646,14 +652,15 @@ class Worker:
         kernel between repeats (computeRepeatedWithSyncKernel).
         ``compute_id`` tags the launch span and the per-cid completion
         probe used by the fence split — optional, purely observability."""
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("launch")
         bufs = tuple(self._buffers[id(p)] for p in params)
         names = list(kernel_names)
         dispatched = 0
         # device-timeline mark around the dispatch (trace/device.py):
         # disabled is one attribute read + falsy check, the tracer
-        # discipline — the annotation correlates this launch's device
-        # ops back to (cid, lane, kernel, seq)
+        # discipline — the host-clock half of the launch's mark; its
+        # sequence number rides the ``ck/launch`` annotation below, which
+        # correlates this launch's device ops back to (cid, lane, kernel)
         _dm = MARKS.begin(names, compute_id, self.index) \
             if MARKS.enabled else None
         try:
@@ -716,10 +723,12 @@ class Worker:
                 self._cid_last_out[compute_id] = bufs[0]
                 if len(self._cid_last_out) > 64:
                     self._cid_last_out.pop(next(iter(self._cid_last_out)))
-            TRACER.record(
-                "launch", _tt, cid=compute_id, lane=self.index,
-                tag=f"{'+'.join(names)} x{dispatched}",
-            )
+            if _tt:
+                TRACER.record(
+                    "launch", _tt, cid=compute_id, lane=self.index,
+                    tag=f"{'+'.join(names)} x{dispatched}",
+                    **MARKS.meta(_dm),
+                )
         if self.markers is not None and bufs:
             # one marker per actual dispatch, reached when the sequence's
             # final output retires on the chip (real in-flight depth, not
@@ -766,7 +775,6 @@ class Worker:
         (``KernelProgram.fused_launcher``), so the balancer re-splitting
         or the window size changing never recompiles.  Buffers are
         donated per :attr:`fused_donate`."""
-        _tt = TRACER.t0()
         donate = self.fused_donate
         fn = program.fused_launcher(
             tuple(kernel_names), step, global_size, local_range,
@@ -781,6 +789,7 @@ class Worker:
                     compute_id=compute_id,
                 )
             return
+        _tt = TRACER.t0("launch")
         bufs = tuple(self._buffers[id(p)] for p in params)
         # device-timeline mark (trace/device.py): the fused ladder is ONE
         # dispatch, so one mark covers all `iters` iterations; the
@@ -800,10 +809,12 @@ class Worker:
                 self._cid_last_out[compute_id] = bufs[0]
                 if len(self._cid_last_out) > 64:
                     self._cid_last_out.pop(next(iter(self._cid_last_out)))
-            TRACER.record(
-                "launch", _tt, cid=compute_id, lane=self.index,
-                tag=f"fused:{'+'.join(kernel_names)} x{iters}",
-            )
+            if _tt:
+                TRACER.record(
+                    "launch", _tt, cid=compute_id, lane=self.index,
+                    tag=f"fused:{'+'.join(kernel_names)} x{iters}",
+                    **MARKS.meta(_dm),
+                )
             if self.markers is not None:
                 # add AFTER the dispatch succeeded (launch()'s ordering):
                 # a failed dispatch must not leak an added-never-reached
@@ -848,7 +859,7 @@ class Worker:
     @staticmethod
     def finish_download(handle) -> None:
         arr, out, off, markers, lane, byte_counter, kind = handle
-        _tt = TRACER.t0()
+        _tt = TRACER.t0(kind)
         # capture the fault-plane state ONCE: a plane armed mid-call
         # would otherwise pair delay_s with the 0.0 sentinel t0 and
         # scale the injected sleep by absolute process uptime
@@ -885,7 +896,7 @@ class Worker:
                                base_s=time.perf_counter() - _ft0)
             if d > 0.0:
                 time.sleep(d)
-        TRACER.record(kind, _tt, lane=lane, tag=arr.name)
+        TRACER.record(kind, _tt, lane=lane, tag=arr.name, bytes=data.nbytes)
         if markers is not None:
             markers.reach()
 
@@ -923,7 +934,7 @@ class Worker:
         buf = self._cid_last_out.get(compute_id)
         if buf is None:
             return False
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("fence")
         buf.block_until_ready()
         TRACER.record(
             "fence", _tt, cid=compute_id, lane=self.index, tag="cid-split"

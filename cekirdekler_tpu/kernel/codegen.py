@@ -35,6 +35,7 @@ Cores.cs:607-613, preserved under jit).
 from __future__ import annotations
 
 import math
+import re
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
@@ -1398,6 +1399,15 @@ class KernelBuildInfo:
     # (kernel/registry.py records both so a run can assert its routing)
     lowering: str = "xla"
     veto: str | None = None
+
+
+def hlo_name(*kernel_names: str) -> str:
+    """The kernel's name(s) made safe for an HLO identifier
+    (``[A-Za-z0-9_.]``; a sequence joined with ``.``): what the jitted
+    launchers and the Mosaic call are named after, so that a profile's
+    device operations and modules read ``nBody.6/custom-call`` /
+    ``jit_nBody`` and not the name of a closure."""
+    return ".".join(re.sub(r"[^A-Za-z0-9_.]", "_", n) for n in kernel_names)
 
 
 def build_kernel_fn(

@@ -557,6 +557,8 @@ def build_kernel_fn_pallas(
                 for n in stored
             ],
             interpret=interpret,
+            # the device operation carries the user's kernel's name
+            name=codegen.hlo_name(kernel.name),
         )(*scalar_ops, *windows, *halos, *smem_bufs)
         if not isinstance(outs, (list, tuple)):
             outs = [outs]

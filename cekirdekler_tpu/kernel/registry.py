@@ -26,9 +26,38 @@ import jax
 import jax.numpy as jnp
 
 from ..errors import KernelCompileError
+from ..trace.spans import TRACER
 from . import codegen, lang
 
 __all__ = ["KernelProgram", "kernel", "PythonKernel"]
+
+
+class _Launcher:
+    """A jitted launch function as the launcher cache holds it: calls go
+    straight through once it has run, and until then sit under a
+    ``compile`` span tagged ``<kernel> <shape key>`` — ``jax.jit`` traces
+    and compiles at the first call, not when the launcher is built, and
+    a rung built while a fused ladder was traced is first compiled on
+    its own whenever a per-call launch asks for it.  A call from inside
+    another function's trace does not count as having run.  Everything
+    else (``.lower``, ``.trace``) is the jitted function's own."""
+
+    __slots__ = ("_fn", "_tag", "_warm")
+
+    def __init__(self, fn, tag: str):
+        self._fn, self._tag, self._warm = fn, tag, False
+
+    def __call__(self, *args):
+        if self._warm:
+            return self._fn(*args)
+        with TRACER.span("compile", tag=self._tag):
+            out = self._fn(*args)
+        self._warm = not any(
+            isinstance(x, jax.core.Tracer) for x in jax.tree_util.tree_leaves(out))
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
 
 
 @dataclass
@@ -305,7 +334,12 @@ class KernelProgram:
             )
 
         static = name in self._py_kernels and self._py_kernels[name].static_values
-        jitted = jax.jit(raw_fn, static_argnums=(2,) if static else ())
+        # whichever lowering built it: the XLA module reads jit_<kernel>
+        raw_fn.__name__ = codegen.hlo_name(name)
+        jitted = _Launcher(
+            jax.jit(raw_fn, static_argnums=(2,) if static else ()),
+            f"{name} chunk={chunk} lr={local_size} g={global_size} "
+            f"{platform}")
         with self._lock:
             self._cache[key] = (jitted, info)
         return jitted, info
@@ -377,6 +411,7 @@ class KernelProgram:
                 0, repeats, lambda _, b: run_names(names, offset, b), bufs
             )
 
+        raw.__name__ = "seq_" + codegen.hlo_name(*names)
         jitted = jax.jit(raw)
         info = codegen.KernelBuildInfo(
             name="+".join(names), array_params=[], value_params=[],
@@ -474,7 +509,11 @@ class KernelProgram:
                 0, iters, lambda _, b: run_ladder(offset, units, b), bufs
             )
 
-        jitted = jax.jit(raw, donate_argnums=(3,) if donate else ())
+        raw.__name__ = "fused_" + codegen.hlo_name(*names)
+        jitted = _Launcher(
+            jax.jit(raw, donate_argnums=(3,) if donate else ()),
+            f"fused:{'+'.join(names)} step={step} g={global_size} "
+            f"{platform}")
         info = codegen.KernelBuildInfo(
             name="fused:" + "+".join(names), array_params=[],
             value_params=[], array_ctypes={}, stored_params=[],

@@ -168,7 +168,7 @@ class PipelineStage:
         if self._cores is not None:
             self._run_multi(kernel_names)
             return
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("pipeline-stage")
         t0 = time.perf_counter()
         slots = self._slots()
         # placement ownership: every producer of a single-chip stage's slot
@@ -214,7 +214,7 @@ class PipelineStage:
         through host arrays, ClPipeline.cs:287-603,624-1580)."""
         import time
 
-        _tt = TRACER.t0()
+        _tt = TRACER.t0("pipeline-stage")
         t0 = time.perf_counter()
         slots = self._slots()
         for s in slots:

@@ -229,7 +229,7 @@ class _Consumer(threading.Thread):
             for task in batch:
                 try:
                     self._throttle()
-                    _tt = TRACER.t0()
+                    _tt = TRACER.t0("pool-task")
                     task.compute(self.cruncher)
                     TRACER.record(
                         "pool-task", _tt, cid=task.compute_id,

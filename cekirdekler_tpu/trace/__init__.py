@@ -5,9 +5,10 @@ Five pieces (see ``docs/OBSERVABILITY.md`` for the guided tour):
 
 - :mod:`.spans` — the process-global :data:`TRACER`: a lock-free-ish
   ring buffer of typed spans (enqueue, split, rebalance, launch, fence,
-  upload, download, pipeline-stage, pool-task, dcn-exchange) recorded by
-  every runtime layer; a no-op when disabled (<1 µs/span, pinned by
-  test).
+  upload, download, pipeline-stage, pool-task, dcn-exchange, ...)
+  recorded by every runtime layer, and the same spans as ``ck/<kind>``
+  annotations in any running ``jax.profiler`` session; a no-op when
+  neither is on (<1 µs/span, pinned by test).
 - :mod:`.attribution` — per-window "where did the time go" reports
   reconciling host wall time against span totals and device-busy time,
   plus the per-compute-id fence split that fixes the one-fence-time-

@@ -6,14 +6,16 @@ names every lost *host* millisecond; device time was a black box
 inferred from fences.  This module closes that gap on the
 ``jax.profiler`` capture seam (``utils/timeline.py``):
 
-1. **Marks.**  Every ladder/chunk launch is tagged with a
-   ``jax.profiler.TraceAnnotation`` named
-   ``ck|k=<kernel>|c=<cid>|l=<lane>|s=<seq>`` (:data:`MARKS`,
-   :meth:`DeviceMarks.begin` / :meth:`DeviceMarks.end` — the worker
-   launch paths call them behind a plain ``.enabled`` check, the same
-   disabled-is-free discipline as the tracer; the pair is a declared
-   ckcheck hot root).  The same mark is recorded HOST-side with
-   ``perf_counter`` timestamps, so every mark exists on both clocks.
+1. **Marks.**  Every ladder/chunk launch is one ``ck/launch`` span of
+   the tracer (``trace/spans.py``): in a profiler session that span IS
+   the launch's ``jax.profiler.TraceAnnotation``, the only one.
+   :data:`MARKS` (:meth:`DeviceMarks.begin` / :meth:`DeviceMarks.end` —
+   the worker launch paths call them behind a plain ``.enabled`` check,
+   the same disabled-is-free discipline as the tracer; the pair is a
+   declared ckcheck hot root) records the same launch HOST-side with
+   ``perf_counter`` timestamps and hands the span its sequence number
+   and kernel (``seq`` / ``kernel`` in the annotation's metadata,
+   :meth:`DeviceMarks.meta`), so every mark exists on both clocks.
 
 2. **Capture.**  :class:`DeviceCapture` wraps a traced window: start
    the profiler (``timeline.start_profiler``), enable marks, run the
@@ -25,10 +27,9 @@ inferred from fences.  This module closes that gap on the
 3. **Correlation contract** (:func:`correlate`), three tiers, each
    counted in the report so coverage is explicit:
 
-   - *explicit*: a device op that carries the mark (``args`` with
-     ``ck-seq``, or a ``ck|`` mark string in its name/args) attaches
-     directly — the synthetic-Xprof fixture format, and what rigs with
-     annotation propagation produce;
+   - *explicit*: a device op that carries the mark's sequence number
+     (``args`` with ``ck-seq``) attaches directly — the synthetic-Xprof
+     fixture format, and what rigs with annotation propagation produce;
    - *kernel-name*: a device op whose name mentions a marked kernel
      attaches to the nearest preceding mark for that kernel (XLA
      module/op names usually embed the jitted function name);
@@ -99,9 +100,10 @@ __all__ = [
 #: process so the dispatch edge is visible next to the ops it explains.
 DEVICE_SPAN_KINDS = ("device-op", "device-mark")
 
-#: Mark-name prefix in the Xprof dump.  Format:
-#: ``ck|k=<kernel>|c=<cid>|l=<lane>|s=<seq>`` (``c=-`` when no cid).
-MARK_PREFIX = "ck|"
+#: The dump-side mark: the tracer's launch annotation, whose metadata
+#: (``args``: seq, kernel, cid, lane — strings in the trace-viewer JSON)
+#: names the launch.
+MARK_EVENT = "ck/launch"
 
 #: Store schema tag — bump on incompatible row changes.
 STORE_SCHEMA = "ck-kernel-profile-v1"
@@ -127,30 +129,20 @@ class Mark(NamedTuple):
     t1: float = 0.0
 
 
-def _mark_name(kernel: str, cid: int | None, lane: int | None,
-               seq: int) -> str:
-    return (f"{MARK_PREFIX}k={kernel}"
-            f"|c={'-' if cid is None else cid}"
-            f"|l={'-' if lane is None else lane}|s={seq}")
-
-
-def parse_mark_name(name: str) -> dict | None:
-    """``ck|k=...|c=...|l=...|s=...`` → field dict, or None when the
-    name is not a mark."""
-    if not name.startswith(MARK_PREFIX):
+def mark_fields(event: dict) -> dict | None:
+    """A trace event → the launch mark it is (kernel, cid, lane, seq), or
+    None: a ``ck/launch`` annotation recorded while :data:`MARKS` was on
+    carries ``seq`` and ``kernel`` in its args."""
+    if event.get("name") != MARK_EVENT:
         return None
-    out: dict = {"kernel": "?", "cid": None, "lane": None, "seq": None}
-    for part in name[len(MARK_PREFIX):].split("|"):
-        if "=" not in part:
-            continue
-        k, v = part.split("=", 1)
-        if k == "k":
-            out["kernel"] = v
-        elif k in ("c", "l", "s") and v not in ("-", ""):
-            try:
-                out[{"c": "cid", "l": "lane", "s": "seq"}[k]] = int(v)
-            except ValueError:
-                pass
+    args = event.get("args") or {}
+    out: dict = {"kernel": str(args.get("kernel", "?")), "cid": None,
+                 "lane": None, "seq": None}
+    for k in ("cid", "lane", "seq"):
+        try:
+            out[k] = int(args[k])
+        except (KeyError, TypeError, ValueError):
+            pass
     return out if out["seq"] is not None else None
 
 
@@ -160,18 +152,19 @@ class DeviceMarks:
     ``enabled`` is a plain attribute — the tracer convention: the
     disabled fast path at a launch site is one attribute read plus a
     falsy check, nothing allocated, no clock read.  Enabled, each
-    ``begin``/``end`` pair opens/closes a ``jax.profiler.TraceAnnotation``
-    around the dispatch AND records the host-clock :class:`Mark` — the
-    same (seq, kernel, cid, lane) on both clocks is what anchors the
-    unified timeline.  Recording is one GIL-atomic ``deque.append``
-    (the flight-recorder ring discipline); no lock is ever taken on the
+    ``begin``/``end`` pair records the host-clock :class:`Mark` of the
+    dispatch and opens NO annotation of its own: the launch's one
+    annotation is the tracer's ``ck/launch`` span, which takes the
+    mark's ``seq`` / ``kernel`` as metadata (:meth:`meta`) — the same
+    (seq, kernel, cid, lane) on both clocks is what anchors the unified
+    timeline.  Recording is one GIL-atomic ``deque.append`` (the
+    flight-recorder ring discipline); no lock is ever taken on the
     launch path."""
 
     def __init__(self, capacity: int = 65536):
         self.enabled = False
         self._ring: deque[Mark] = deque(maxlen=max(16, int(capacity)))
         self._seq = itertools.count(1)
-        self._ann_cls = None  # jax.profiler.TraceAnnotation, cached on enable
 
     # -- hot path (declared ckcheck hot root) --------------------------------
     def begin(self, kernel_names, cid: int | None, lane: int | None):
@@ -183,39 +176,27 @@ class DeviceMarks:
         seq = next(self._seq)
         kernel = "+".join(kernel_names) if not isinstance(kernel_names, str) \
             else kernel_names
-        ann = None
-        if self._ann_cls is not None:
-            try:
-                ann = self._ann_cls(_mark_name(kernel, cid, lane, seq))
-                ann.__enter__()
-            except Exception:  # noqa: BLE001 - marking must never sink a launch
-                ann = None
-        return (ann, seq, kernel, cid, lane, time.perf_counter())
+        return (seq, kernel, cid, lane, time.perf_counter())
 
     def end(self, token) -> None:
         """Close a mark opened by :meth:`begin` (no-op on None)."""
         if token is None:
             return
-        ann, seq, kernel, cid, lane, t0 = token
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:  # noqa: BLE001
-                pass
+        seq, kernel, cid, lane, t0 = token
         self._ring.append(
             Mark(seq, kernel, cid, lane, t0, time.perf_counter()))
+
+    @staticmethod
+    def meta(token) -> dict:
+        """What the launch's ``ck/launch`` span carries of this mark."""
+        if token is None:
+            return {}
+        return {"seq": token[0], "kernel": token[1]}
 
     # -- control / inspection (cold) -----------------------------------------
     def enable(self, clear: bool = True) -> None:
         if clear:
             self._ring.clear()
-        if self._ann_cls is None:
-            try:
-                import jax.profiler as _prof
-
-                self._ann_cls = _prof.TraceAnnotation
-            except Exception:  # noqa: BLE001 - host marks still work
-                self._ann_cls = None
         self.enabled = True
 
     def disable(self) -> None:
@@ -280,8 +261,8 @@ def parse_trace_dump(trace_dir: str) -> TraceDump:
     and dump-side marks.  Real dumps and the synthetic-Xprof fixture
     format share the schema: ``M`` metadata events name device
     processes (``/device:...``) and their op tracks; ``X`` events on
-    those tracks are device ops; ``X`` events named ``ck|...``
-    (anywhere — host thread or device track) are marks."""
+    those tracks are device ops; ``ck/launch`` ``X`` events whose args
+    carry a ``seq`` (anywhere — host thread or device track) are marks."""
     from ..utils.timeline import load_trace_events
 
     path, events = load_trace_events(trace_dir)
@@ -317,8 +298,8 @@ def parse_trace_dump(trace_dir: str) -> TraceDump:
             continue
         name = str(e.get("name", ""))
         args = e.get("args", {}) or {}
-        if name.startswith(MARK_PREFIX):
-            fields = parse_mark_name(name)
+        if name == MARK_EVENT:
+            fields = mark_fields(e)
             if fields is not None:
                 dump.dump_marks[fields["seq"]] = {
                     "ts": float(e.get("ts", 0.0)),
@@ -479,21 +460,11 @@ class DeviceWindowReport:
 
 
 def _explicit_seq(op: DeviceOp) -> int | None:
-    """Tier-1 evidence on the op itself: a ``ck-seq`` arg, or a mark
-    string embedded in the op name or any string arg."""
-    v = op.args.get("ck-seq")
-    if v is not None:
-        try:
-            return int(v)
-        except (TypeError, ValueError):
-            pass
-    for s in (op.name, *[a for a in op.args.values() if isinstance(a, str)]):
-        i = s.find(MARK_PREFIX)
-        if i >= 0:
-            fields = parse_mark_name(s[i:].split()[0])
-            if fields is not None:
-                return fields["seq"]
-    return None
+    """Tier-1 evidence on the op itself: a ``ck-seq`` arg."""
+    try:
+        return int(op.args["ck-seq"])
+    except (KeyError, TypeError, ValueError):
+        return None
 
 
 def correlate(

@@ -12,15 +12,19 @@ stack has a name.
 
 Design constraints, in order:
 
-1. **Disabled is free.**  The tracer ships enabled on no hot path by
-   default; instrumentation sites pay two attribute reads and a falsy
-   check (<1 µs per would-be span, measured by
+1. **Inactive is free.**  The tracer ships enabled on no hot path by
+   default; instrumentation sites pay one ``is_enabled()`` of the
+   profiler, one attribute read and a falsy check (<1 µs per would-be
+   span, measured by
    ``tests/test_trace.py::test_disabled_tracer_overhead``).  The
    convention at hot sites is the ``t0()``/``record()`` pair::
 
-       t0 = TRACER.t0()          # 0.0 when disabled — no clock read
+       t0 = TRACER.t0("launch")  # 0.0 when inactive — no clock read
        ...work...
        TRACER.record("launch", t0, cid=cid, lane=self.index)
+
+   ``t0`` is an opaque token: falsy when the tracer is inactive, and
+   whatever :meth:`Tracer.record` needs otherwise.
 
 2. **Lock-free-ish.**  Recording is one ``itertools.count`` increment
    (atomic under the GIL) plus one list-slot store — concurrent worker
@@ -28,10 +32,26 @@ Design constraints, in order:
    oldest spans when full; ``total_recorded`` keeps the true count so a
    wrapped buffer is detectable, never silent.
 
-3. **Monotonic clocks.**  All timestamps are ``time.perf_counter()``
-   seconds, comparable across threads within the process (the exchange
-   rate to device-side Xprof events is handled by
-   ``trace/attribution.py``, which reconciles totals, not timestamps).
+3. **Two sinks, each on its own clock.**  The tracer is ACTIVE when
+   ``TRACER.enabled`` (the ring: ``time.perf_counter()`` seconds,
+   comparable across threads within the process) **or** while a
+   ``jax.profiler`` session runs.  In a session every site opens a
+   ``jax.profiler.TraceAnnotation`` named ``ck/<kind>`` at its start and
+   closes it at its end on the same thread, so the program's spans land
+   in ``/host:CPU`` of the same ``.xplane.pb`` as the device's ``XLA
+   Ops`` lines, on the profiler's clock: an idle gap of a chip can be
+   put down to the span that covers it (``benchmark/host_phases.py``).
+   Nothing has to switch this on — ``jax.profiler.start_trace`` is the
+   switch, and the test for it is ``TraceAnnotation.is_enabled()``
+   (bound lazily: this module imports no jax).  Each annotation carries
+   as METADATA (xplane stats; ``args`` in the trace-viewer JSON): ``cid``
+   and ``lane`` where the site knows them, ``tag``, ``win`` (the
+   sequence number of the enqueue window or non-windowed call the span
+   belongs to, shared by the caller's thread and the threads that work
+   for it), on a driver or pool thread the lane it works for (where the
+   site names none) and ``queued_us`` (how long the closure waited
+   between its submission and its start), and whatever else the site
+   passes by keyword (``bytes``, ``seq``, ``kernel``).
 
 Span kinds used by the built-in instrumentation (callers may add more):
 ``enqueue`` (a compute() dispatch), ``split`` (first range table),
@@ -49,12 +69,21 @@ the fused path falls back to per-iteration dispatch, so a silent perf
 regression to the slow path is attributable), ``driver-error`` (a
 dispatch-driver closure failed — the instant is recorded at failure
 time, before the error surfaces at the caller's sync point, so a
-postmortem's span ring names the failing dispatch).
+postmortem's span ring names the failing dispatch).  The stretches of
+``compute()`` / ``barrier()`` that had no span before ISSUE 24:
+``schedule`` (the range table and the balancer behind it), ``resync``
+(the flush of deferred results and the coverage reset after a range
+move, and ``flush()`` itself), ``engage`` (opening a fused window),
+``drain`` (the caller waiting for the per-lane driver queues), ``tune``
+(the transfer autotuner's ``choose`` / ``observe``; an instant
+``chunks:<old>-><new>`` when a lane's chunk count changes) and
+``compile`` (the first trace-and-compile of a launcher for a new shape).
 """
 
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -67,7 +96,24 @@ SPAN_KINDS = (
     "upload", "download", "upload-chunk", "download-chunk",
     "pipeline-stage", "pool-task", "dcn-exchange",
     "fused", "driver-error",
+    "schedule", "resync", "engage", "drain", "tune", "compile",
 )
+
+#: annotation names, built once: a span site must not concatenate per span
+_NAMES = {k: "ck/" + k for k in SPAN_KINDS}
+
+
+class _Context(threading.local):
+    """What a span inherits from the thread it closes on: the window it
+    belongs to, and on a driver or pool thread the lane the closure works
+    for and how long it was queued (:meth:`Tracer.bind`)."""
+
+    win: int | None = None
+    lane: int | None = None
+    queued_us: float | None = None
+
+
+_CTX = _Context()
 
 
 class Span(NamedTuple):
@@ -90,8 +136,10 @@ class Span(NamedTuple):
 class Tracer:
     """Process-global span recorder (one instance: :data:`TRACER`).
 
-    ``enabled`` is a plain attribute on purpose: the disabled fast path
-    must be an attribute read, not a property call."""
+    ``enabled`` (the ring) is a plain attribute on purpose: the disabled
+    fast path must be an attribute read, not a property call.  The other
+    sink, a running ``jax.profiler`` session, is asked for by
+    :meth:`_session_on`."""
 
     def __init__(self, capacity: int = 65536):
         self.enabled = False
@@ -104,25 +152,94 @@ class Tracer:
         # (ck_trace_dropped_spans_total) — the delta tracking that keeps
         # the counter monotonic across snapshots within one ring epoch
         self._dropped_reported = 0
+        # the profiler bridge: ``_session_on`` is this resolver until jax
+        # is there to ask, then ``TraceAnnotation.is_enabled`` itself
+        self._ann = None
+        self._session_on = self._bind_session
+        self._wins = itertools.count(1)
+
+    def _bind_session(self) -> bool:
+        """First asks only: without jax imported no session can run, and
+        this module must not be what imports it."""
+        if "jax" not in sys.modules:
+            return False
+        try:
+            from jax.profiler import TraceAnnotation
+
+            probe = TraceAnnotation.is_enabled
+            on = bool(probe())
+        except Exception:  # noqa: BLE001 - no profiler: the ring still works
+            self._session_on = lambda: False
+            return False
+        self._ann = TraceAnnotation
+        self._session_on = probe
+        return on
 
     # -- recording (hot path) ------------------------------------------------
-    def t0(self) -> float:
-        """Span-open timestamp, or 0.0 when disabled (no clock read)."""
+    def active(self) -> bool:
+        """True while either sink takes spans: the guard for work a site
+        does only to describe a span (building a tag)."""
+        return self.enabled or self._session_on()
+
+    def t0(self, kind: str | None = None):
+        """Span-open token: 0.0 when inactive (no clock read), a
+        ``perf_counter`` float when only the ring is on, and with a
+        profiler session running the opened ``ck/<kind>`` annotation
+        beside it.  Pass it to :meth:`record` on the same thread."""
+        if kind is not None and self._session_on():
+            ann = self._ann(_NAMES.get(kind) or "ck/" + kind)
+            ann.__enter__()
+            return (ann, time.perf_counter() if self.enabled else 0.0)
         return time.perf_counter() if self.enabled else 0.0
+
+    @staticmethod
+    def _close(token, cid, lane, tag, meta: dict) -> float:
+        """Close the annotation half of a token with its metadata;
+        returns the ring half (0.0 when the ring was off at open)."""
+        ann, t0 = token
+        ctx = _CTX.__dict__  # ONE thread-local resolution, then dict reads
+        if cid is not None:
+            meta["cid"] = cid
+        if lane is None:
+            lane = ctx.get("lane")  # a site that knows no lane (compile)
+        if lane is not None:        # takes the one its thread works for
+            meta["lane"] = lane
+        if tag is not None:
+            # '#' ends the metadata block of a TraceMe name
+            meta["tag"] = tag.replace("#", "") if "#" in tag else tag
+        if ctx:
+            win, queued = ctx.get("win"), ctx.get("queued_us")
+            if win is not None:
+                meta["win"] = win
+            if queued is not None:
+                meta["queued_us"] = queued
+        try:
+            if meta:
+                ann.set_metadata(**meta)
+            ann.__exit__(None, None, None)
+        except Exception:  # noqa: BLE001
+            pass
+        return t0
 
     def record(
         self,
         kind: str,
-        t0: float,
+        t0,
         cid: int | None = None,
         lane: int | None = None,
         tag: str | None = None,
         t1: float | None = None,
+        **meta,
     ) -> None:
-        """Close and store a span opened at ``t0``.  No-op when disabled
-        or when ``t0`` is the disabled sentinel (0.0) — a site that
+        """Close and store a span opened at ``t0``.  No-op when inactive
+        or when ``t0`` is the inactive sentinel (0.0) — a site that
         opened its span while the tracer was off records nothing even if
-        the tracer was enabled mid-span."""
+        the tracer was enabled mid-span.  ``meta`` goes to the profiler
+        annotation only (the ring's :class:`Span` has no room for it)."""
+        if not t0:
+            return
+        if t0.__class__ is tuple:
+            t0 = self._close(t0, cid, lane, tag, meta)
         if not self.enabled or not t0:
             return
         i = next(self._count)  # GIL-atomic slot claim — no lock
@@ -144,11 +261,13 @@ class Tracer:
         lane: int | None = None,
         tag: str | None = None,
     ) -> None:
-        """Zero-duration marker (e.g. a rebalance decision)."""
-        if not self.enabled:
-            return
-        t = time.perf_counter()
-        self.record(kind, t, cid=cid, lane=lane, tag=tag, t1=t)
+        """Zero-duration marker (e.g. a rebalance decision): a
+        zero-length annotation in a profiler session."""
+        tok = self.t0(kind)
+        if tok.__class__ is tuple:  # an open annotation: close it at once
+            tok = self._close(tok, cid, lane, tag, {})
+        if tok:  # the ring's half: one instant, both ends at the open
+            self.record(kind, tok, cid=cid, lane=lane, tag=tag, t1=tok)
 
     @contextmanager
     def span(
@@ -157,15 +276,45 @@ class Tracer:
         cid: int | None = None,
         lane: int | None = None,
         tag: str | None = None,
+        **meta,
     ):
         """Context-manager convenience for non-hot sites; records even
         when the body raises (the failing span is usually the one you
         want to see)."""
-        t0 = self.t0()
+        t0 = self.t0(kind)
         try:
             yield
         finally:
-            self.record(kind, t0, cid=cid, lane=lane, tag=tag)
+            self.record(kind, t0, cid=cid, lane=lane, tag=tag, **meta)
+
+    # -- the window a span belongs to, across threads ------------------------
+    def next_window(self) -> int:
+        """Open the next enqueue window / non-windowed call on the calling
+        thread: every span closed on it from now on carries this ``win``,
+        and so do the closures it hands to other threads (:meth:`bind`)."""
+        _CTX.win = win = next(self._wins)
+        return win
+
+    def bind(self, fn, lane: int | None = None):
+        """``fn`` itself when inactive.  Active: a wrapper that runs
+        ``fn`` on whatever thread picks it up with the submitting
+        thread's ``win``, the ``lane`` it works for, and ``queued_us``,
+        the time between this call and its start — the queue wait no
+        annotation pair can span across threads."""
+        if not self.active():
+            return fn
+        win, t_sub = _CTX.win, time.perf_counter()
+
+        def bound(*args, **kwargs):
+            prev = (_CTX.win, _CTX.lane, _CTX.queued_us)
+            _CTX.win, _CTX.lane = win, lane
+            _CTX.queued_us = round((time.perf_counter() - t_sub) * 1e6, 1)
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                _CTX.win, _CTX.lane, _CTX.queued_us = prev
+
+        return bound
 
     # -- control -------------------------------------------------------------
     def enable(self, capacity: int | None = None, clear: bool = True) -> None:

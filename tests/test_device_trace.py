@@ -34,7 +34,7 @@ from cekirdekler_tpu.trace.device import (
     Mark,
     ProfileStore,
     correlate,
-    parse_mark_name,
+    mark_fields,
     parse_trace_dump,
     roofline_row,
     split_unified_trace,
@@ -57,10 +57,15 @@ def _device_meta(pid=7, name="/device:TPU:0", tid=2, track="XLA Ops"):
 
 
 def _mark_event(seq, kernel, cid=None, lane=None, ts=0.0, dur=50.0, pid=1):
-    name = (f"ck|k={kernel}|c={'-' if cid is None else cid}"
-            f"|l={'-' if lane is None else lane}|s={seq}")
+    # the launch's one annotation: the tracer's ``ck/launch`` span, its
+    # metadata as the trace-viewer JSON renders it (strings)
+    args = {"seq": str(seq), "kernel": kernel}
+    if cid is not None:
+        args["cid"] = str(cid)
+    if lane is not None:
+        args["lane"] = str(lane)
     return {"ph": "X", "pid": pid, "tid": 0, "ts": ts, "dur": dur,
-            "name": name}
+            "name": "ck/launch", "args": args}
 
 
 def _op(ts, dur, name="fusion.1", pid=7, tid=2, args=None):
@@ -89,14 +94,21 @@ def _write_dump(dirpath, events, gz=True):
 # ---------------------------------------------------------------------------
 
 def test_mark_name_round_trip():
-    name = dv._mark_name("nBody", 7, 3, 42)
-    f = parse_mark_name(name)
+    f = mark_fields(_mark_event(42, "nBody", cid=7, lane=3))
     assert f == {"kernel": "nBody", "cid": 7, "lane": 3, "seq": 42}
-    # None cid/lane render as '-' and parse back to None
-    f2 = parse_mark_name(dv._mark_name("k", None, None, 1))
+    # a launch span without cid/lane parses them back to None
+    f2 = mark_fields(_mark_event(1, "k"))
     assert f2["cid"] is None and f2["lane"] is None and f2["seq"] == 1
-    assert parse_mark_name("not a mark") is None
-    assert parse_mark_name("ck|k=x") is None  # no seq: not a usable mark
+    assert mark_fields({"name": "not a mark"}) is None
+    # no seq (MARKS was off when the span closed): not a usable mark
+    assert mark_fields({"name": "ck/launch",
+                        "args": {"kernel": "x"}}) is None
+    # what DeviceMarks hands the span is what mark_fields reads back
+    m = DeviceMarks()
+    m.enable()
+    tok = m.begin(("nBody",), 7, 3)
+    assert DeviceMarks.meta(tok) == {"seq": 1, "kernel": "nBody"}
+    assert DeviceMarks.meta(None) == {}
 
 
 # ---------------------------------------------------------------------------
@@ -356,8 +368,7 @@ def test_disabled_marks_overhead_under_budget():
 
 def test_enabled_marks_record_host_side_without_jax_annotation():
     m = DeviceMarks()
-    m.enable()
-    m._ann_cls = None  # simulate a rig with no jax profiler at all
+    m.enable()  # MARKS opens no annotation of its own: nothing to lack
     tok = m.begin(["a", "b"], cid=9, lane=2)
     assert tok is not None
     m.end(tok)

@@ -212,7 +212,8 @@ def test_real_profiler_capture_reduces(tmp_path):
     d = str(tmp_path / "real")
     try:
         with jax.profiler.trace(d):
-            with jax.profiler.TraceAnnotation("ck|k=mm|c=1|l=0|s=1"):
+            with jax.profiler.TraceAnnotation(
+                    "ck/launch", kernel="mm", cid=1, lane=0, seq=1):
                 x = jnp.ones((128, 128))
                 for _ in range(2):
                     x = (x @ x).block_until_ready()

@@ -63,7 +63,9 @@ def _fixture_dump(dirpath):
         {"ph": "M", "name": "thread_name", "pid": 7, "tid": 2,
          "args": {"name": "XLA Ops"}},
         {"ph": "X", "pid": 1, "tid": 0, "ts": 0.0, "dur": 40.0,
-         "name": "ck|k=mandelbrot|c=7|l=0|s=1"},
+         "name": "ck/launch",
+         "args": {"kernel": "mandelbrot", "cid": "7", "lane": "0",
+                  "seq": "1"}},
         {"ph": "X", "pid": 7, "tid": 2, "ts": 100.0, "dur": 5000.0,
          "name": "fusion.1", "args": {"ck-seq": 1}},
         {"ph": "X", "pid": 7, "tid": 2, "ts": 5300.0, "dur": 700.0,

@@ -5,7 +5,9 @@ schema round-trip, and the per-rep overlap ceiling's structural bounds.
 """
 
 import json
+import os
 import time
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -66,21 +68,30 @@ def _tracer_off():
 # -- overhead budget ---------------------------------------------------------
 
 def test_disabled_tracer_overhead_under_budget():
-    """The ISSUE's stated budget: a disabled tracer's would-be span costs
-    < 1 µs.  Measured over 50k t0()/record() pairs (the hot-site
+    """The ISSUE's stated budget: an inactive tracer's would-be span costs
+    < 1 µs — the two-way test (ring off, no profiler session running)
+    included.  Measured over 50k t0()/record() pairs (the hot-site
     convention), best of 3 runs to shrug off scheduler noise."""
+    import jax
+
     tr = Tracer()
-    assert not tr.enabled
+    assert not tr.enabled and not tr.active()
+    assert not jax.profiler.TraceAnnotation.is_enabled()
     n = 50_000
     best = float("inf")
     for _ in range(3):
         t0 = time.perf_counter()
         for _ in range(n):
-            t = tr.t0()
+            t = tr.t0("launch")
             tr.record("launch", t, cid=1, lane=0)
         best = min(best, (time.perf_counter() - t0) / n)
     assert best < 1e-6, f"disabled span cost {best*1e9:.0f} ns >= 1 µs"
     assert tr.total_recorded == 0  # truly a no-op: nothing stored
+    # ... and no annotation opened: the token is the inactive sentinel,
+    # and the probe is the profiler's own static method, bound on first use
+    assert tr.t0("launch") == 0.0
+    assert tr._session_on is jax.profiler.TraceAnnotation.is_enabled
+    assert tr.bind(len) is len  # nothing wraps a closure either
 
 
 def test_enabled_tracer_records_and_costs_sanely():
@@ -461,3 +472,213 @@ def test_fori_chain_bench_fallback_refuses_dceable_feedback():
 
     dt = fori_chain_bench(ok_step, (a, b), reps=2, trials=1)
     assert dt > 0
+
+
+# -- the profiler bridge: the program's spans on the profiler's clock --------
+
+INC = """
+__kernel void inc(__global float* x) {
+    int i = get_global_id(0);
+    x[i] = x[i] + 1.0f;
+}
+"""
+
+
+def _laggy(orig, secs=0.2):
+    def f():
+        time.sleep(secs)
+        orig()
+
+    return f
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """ONE ``jax.profiler`` session on the CPU backend with the ring OFF:
+    enqueue windows of a fresh kernel on two virtual lanes (the first cold,
+    one after a lagging lane so that the ranges move), MARKS on.  Returns
+    the ``ck/`` / ``test/`` events of the dump's host plane."""
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    from cekirdekler_tpu.trace.device import MARKS
+
+    n, calls = 4096, 4
+    cr = NumberCruncher(_cpus(2), INC)
+    x = ClArray(np.zeros(n, np.float32), name="bridge_x", partial_read=True)
+    trace_dir = str(tmp_path_factory.mktemp("bridge"))
+
+    def window():
+        for _ in range(calls):
+            x.compute(cr, 77, "inc", n, 64)
+        cr.barrier()
+
+    TRACER.disable()
+    MARKS.enable()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        assert TRACER.active() and not TRACER.enabled
+        cr.enqueue_mode = True
+        with TraceAnnotation("test/cold"):
+            window()
+        with TraceAnnotation("test/warm"):
+            window()
+        slow = cr.cores.workers[0]
+        fence = slow.fence
+        slow.fence = _laggy(fence)
+        window()  # lane 0 lags at the barrier: the next call re-splits
+        slow.fence = fence
+        before = cr.ranges_of(77)
+        with TraceAnnotation("test/moved"):
+            window()
+        moved = cr.ranges_of(77) != before
+        cr.enqueue_mode = False
+    finally:
+        jax.profiler.stop_trace()
+        marks = MARKS.snapshot()
+        MARKS.disable()
+    np.testing.assert_array_equal(np.asarray(x), 4.0 * calls)
+    ring = TRACER.total_recorded
+    cr.dispose()
+    path = [os.path.join(r, f) for r, _d, fs in os.walk(trace_dir)
+            for f in fs if f.endswith(".xplane.pb")][0]
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for li, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith(("ck/", "test/", "ck|")):
+                    events.append(SimpleNamespace(
+                        name=ev.name, t0=ev.start_ns,
+                        t1=ev.start_ns + ev.duration_ns, line=li,
+                        stats=dict(ev.stats)))
+    return SimpleNamespace(events=events, marks=marks, ring=ring,
+                           moved=moved, calls=calls)
+
+
+def _inside(p, outer_name, kind):
+    (outer,) = [e for e in p.events if e.name == outer_name]
+    return [e for e in p.events if e.name == "ck/" + kind
+            and outer.t0 <= e.t0 and e.t1 <= outer.t1], outer
+
+
+def test_profiler_session_alone_activates_the_tracer(profiled):
+    assert profiled.ring == 0  # the ring stayed off: nothing recorded there
+    assert any(e.name == "ck/enqueue" for e in profiled.events)
+    # a closed session switches the sites off again
+    assert not TRACER.active()
+
+
+@pytest.mark.parametrize("kind", [
+    "enqueue", "schedule", "engage", "fused", "drain", "launch", "fence"])
+def test_window_yields_span_on_the_profilers_clock(profiled, kind):
+    """One enqueue window under a profiler session: every stretch of
+    compute() / barrier() has its ``ck/<kind>`` annotation in the dump's
+    host plane, nested inside the test's own, carrying win (and cid)."""
+    spans, outer = _inside(profiled, "test/warm", kind)
+    assert spans, f"no ck/{kind} inside the test's own annotation"
+    caller = [e for e in spans if e.line == outer.line]
+    wins = {e.stats.get("win") for e in spans}
+    assert len(wins) == 1 and None not in wins  # one window, one id
+    if kind not in ("drain", "fence"):
+        assert all(e.stats.get("cid") == 77 for e in spans)
+    if kind == "launch":
+        # the fused ladder launches run on the per-lane driver threads
+        driver = [e for e in spans if e.line != outer.line]
+        assert {e.stats["lane"] for e in driver} == {0, 1}
+        assert all(e.stats["queued_us"] >= 0.0 for e in driver)
+        assert any(str(e.stats["tag"]).startswith("fused:inc x")
+                   for e in driver)
+    else:
+        assert caller and "queued_us" not in caller[0].stats
+    if kind == "enqueue":
+        # the per-call path once, then deferrals into the fused window
+        tags = [str(e.stats["tag"]) for e in caller]
+        assert sum(not t.endswith("fused-defer") for t in tags) >= 1
+        assert sum(t.endswith("fused-defer") for t in tags) >= 1
+        assert len(tags) == profiled.calls
+
+
+def test_window_ids_differ_between_windows(profiled):
+    cold, _ = _inside(profiled, "test/cold", "fence")
+    warm, _ = _inside(profiled, "test/warm", "fence")
+    assert cold[0].stats["win"] != warm[0].stats["win"]
+
+
+def test_forced_range_move_yields_resync(profiled):
+    assert profiled.moved, "the lagging lane did not move the ranges"
+    resync, _ = _inside(profiled, "test/moved", "resync")
+    assert [str(e.stats["tag"]) for e in resync] == ["range-move"]
+    rebalance, _ = _inside(profiled, "test/moved", "rebalance")
+    assert len(rebalance) == 1 and rebalance[0].t1 - rebalance[0].t0 < 1e6
+    # the deferred results come back inside it, with the bytes staged
+    down, _ = _inside(profiled, "test/moved", "download")
+    assert down and all(resync[0].t0 <= e.t0 and e.t1 <= resync[0].t1
+                        for e in down)
+    assert sum(e.stats["bytes"] for e in down) == 4096 * 4
+    # a window whose ranges held still has none
+    assert _inside(profiled, "test/warm", "resync")[0] == []
+
+
+def test_first_launch_yields_compile_and_second_none(profiled):
+    cold, _ = _inside(profiled, "test/cold", "compile")
+    assert cold and all("inc" in str(e.stats["tag"]) for e in cold)
+    assert any(str(e.stats["tag"]).startswith("fused:inc") for e in cold)
+    # spans of the pool / driver threads name the lane they worked for
+    assert all(e.stats.get("lane") in (0, 1) for e in cold)
+    assert _inside(profiled, "test/warm", "compile")[0] == []
+
+
+def test_one_annotation_per_launch_marks_emit_none(profiled):
+    """MARKS opens no annotation of its own: every launch is ONE
+    ``ck/launch`` in the dump, which carries the mark's seq and kernel."""
+    assert not [e for e in profiled.events if e.name.startswith("ck|")]
+    launches = [e for e in profiled.events if e.name == "ck/launch"]
+    assert len(launches) == len(profiled.marks)
+    by_seq = {m.seq: m for m in profiled.marks}
+    assert {e.stats["seq"] for e in launches} == set(by_seq)
+    assert all(by_seq[e.stats["seq"]].kernel == e.stats["kernel"] == "inc"
+               for e in launches)
+
+
+def test_mark_pairs_anchor_trace_time_onto_perf_counter(profiled):
+    """The launch span seen host-side (the Mark) and in the dump (the
+    ``ck/launch`` annotation) is one event on two clocks: every pair gives
+    the same offset, to well under a launch's own length."""
+    by_seq = {m.seq: m for m in profiled.marks}
+    offs = [by_seq[e.stats["seq"]].t0 - e.t0 * 1e-9
+            for e in profiled.events if e.name == "ck/launch"]
+    assert len(offs) >= 4 and max(offs) - min(offs) < 5e-3
+
+
+def test_mosaic_launch_carries_the_kernels_name():
+    """The device operation and the XLA module are named after the user's
+    kernel: the lowering for a TPU names the Mosaic call ``inc`` and the
+    module ``jit_inc``; the fused ladder's is ``jit_fused_inc``."""
+    import jax
+    import jax.numpy as jnp
+
+    from cekirdekler_tpu.kernel.codegen import hlo_name
+    from cekirdekler_tpu.kernel.registry import KernelProgram
+
+    prog = KernelProgram(INC)
+    fn, info = prog.launcher("inc", 4096, 64, 8192, platform="tpu")
+    assert info.lowering == "pallas"
+    buf = jax.ShapeDtypeStruct((8192,), jnp.float32)
+    off = jax.ShapeDtypeStruct((), jnp.int32)
+    text = fn.trace(off, (buf,), ()).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and 'kernel_name = "inc"' in text
+    assert "module @jit_inc " in text
+    fused = prog.fused_launcher(("inc",), 64, 8192, 64, 8192, (),
+                                platform="cpu")
+    text = fused.trace(off, off, off, (buf,)).lower().as_text()
+    assert "module @jit_fused_inc " in text
+    # the XLA-lowered path shows the kernel in its module name too
+    fn_cpu, _ = prog.launcher("inc", 4096, 64, 8192, platform="cpu")
+    assert "module @jit_inc " in fn_cpu.trace(
+        off, (buf,), ()).lower().as_text()
+    assert hlo_name("a-b c", "k2") == "a_b_c.k2"

@@ -177,7 +177,7 @@ class TelemetryCall:
     kind: str | None      # literal first arg
     line: int
     computed_args: bool   # any argument allocates (f-string/concat/call)
-    enabled_guarded: bool # lexically inside an `if X.enabled:` branch
+    enabled_guarded: bool # lexically inside `if X.enabled:` / `if X.active():`
 
 
 @dataclass
@@ -927,8 +927,13 @@ class _FuncWalker:
 
     @staticmethod
     def _is_enabled_test(test: ast.expr) -> bool:
+        """``X.enabled``, or ``X.active()`` — the tracer's two-sink test
+        (ring or profiler session, trace/spans.py)."""
         for n in ast.walk(test):
             if isinstance(n, ast.Attribute) and n.attr == "enabled":
+                return True
+            if isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute) \
+                    and n.func.attr == "active" and not n.args:
                 return True
         return False
 

@@ -53,7 +53,7 @@ def _print_report(rep, as_json: bool) -> None:
 
 def analyze_dir(args) -> int:
     """--trace-dir mode: reduce an existing dump (no host marks — the
-    dump's own ``ck|`` mark events drive the correlation)."""
+    dump's own ``ck/launch`` mark events drive the correlation)."""
     from cekirdekler_tpu.trace.device import correlate, parse_trace_dump
 
     dump = parse_trace_dump(args.trace_dir)
