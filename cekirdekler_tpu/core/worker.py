@@ -76,7 +76,10 @@ def _ladder(size: int, step: int) -> list[int]:
 
 def launch_ladder(size: int, step: int) -> list[int]:
     """The ladder-build seam: the ONE decomposition every launch-geometry
-    consumer shares — per-call dispatch (:meth:`Worker.launch`), the
+    consumer shares — per-call dispatch (:meth:`Worker.launch`, which
+    walks it rung by rung, or hands a ladder of more than one rung to
+    the fused executable that predicates the same rungs on the bits of
+    ``size // step``: ``KernelProgram.fused_launcher``), the
     streamed chunk planner (``core/stream.chunk_plan``), and the
     persistent executable cache's key/warmup geometry
     (``core/compilecache``).  A second decomposition would silently warm
@@ -651,10 +654,22 @@ class Worker:
         Worker.cs:1051-1069); ``sync_kernel`` interleaves a synchronization
         kernel between repeats (computeRepeatedWithSyncKernel).
         ``compute_id`` tags the launch span and the per-cid completion
-        probe used by the fence split — optional, purely observability."""
+        probe used by the fence split — optional, purely observability.
+
+        The launch ladder (:func:`launch_ladder`) has two lowerings and
+        this is where a per-call launch picks one: a host loop over the
+        rungs, one dispatch each, values passed at run time; or, when the
+        ladder has MORE THAN ONE rung and a fused window (or
+        ``Cores.warmup``) has already built the predicated-ladder
+        executable of exactly this key, ONE dispatch of that executable
+        with ``iters=1`` — the same rung functions in the same order,
+        bit-identical (``KernelProgram.fused_launcher``).  The per-call
+        path only peeks: it never builds that executable (values are baked
+        into it, and per-call values may change every call)."""
         _tt = TRACER.t0("launch")
         bufs = tuple(self._buffers[id(p)] for p in params)
         names = list(kernel_names)
+        units = size // step
         dispatched = 0
         # device-timeline mark around the dispatch (trace/device.py):
         # disabled is one attribute read + falsy check, the tracer
@@ -664,21 +679,32 @@ class Worker:
         _dm = MARKS.begin(names, compute_id, self.index) \
             if MARKS.enabled else None
         try:
-            seq_fn = None
+            one_fn, one_args = None, ()
             if repeats > 1:
                 # on-device repeat: the whole sequence × repeats is ONE
                 # fused dispatch (lax.fori_loop inside jit) — no host
                 # round-trips (reference: computeRepeated, Worker.cs:36-46)
-                seq_fn = program.sequence_launcher(
+                one_fn = program.sequence_launcher(
                     tuple(names), tuple(_ladder(size, step)), local_range,
                     global_size, repeats, sync_kernel, value_args,
                     platform=self.device.platform,
                 )
-            if seq_fn is not None:
-                bufs = tuple(seq_fn(offset, bufs))
+                one_args = (offset, bufs)
+            elif not sync_kernel and units & (units - 1):
+                # more than one rung: ride the fused ladder executable IF
+                # a window or warmup has built this very key (a peek)
+                one_fn = program.fused_launcher(
+                    tuple(names), step, global_size, local_range,
+                    global_size, value_args, platform=self.device.platform,
+                    donate=self.fused_donate, build=False,
+                )
+                one_args = (offset, units, 1, bufs)
+            if one_fn is not None:
+                bufs = tuple(one_fn(*one_args))
                 dispatched = 1
             else:
-                # host-loop fallback (unhashable values): interleave the
+                # the host loop over the rungs (one rung, no executable
+                # built for this key, unhashable values): interleave the
                 # sync kernel between repeats like
                 # computeRepeatedWithSyncKernel
                 if repeats > 1 and sync_kernel:
@@ -708,31 +734,43 @@ class Worker:
         finally:
             if _dm is not None:  # close even on a failed dispatch
                 MARKS.end(_dm)
+        self._launched(params, bufs, compute_id, _tt, _dm,
+                       _tt and f"{'+'.join(names)} x{dispatched}", dispatched)
+
+    def _launched(self, params, bufs: tuple, compute_id, _tt, _dm,
+                  tag: str, dispatched: int) -> None:
+        """The tail every launch shares once its dispatches are out: the
+        buffer cache is REPLACED from the outputs (a donating executable
+        has deleted the inputs), then the per-cid probe, the ``launch``
+        span and the markers."""
         for p, b in zip(params, bufs):
             self._buffers[id(p)] = b
-        if bufs:
-            if compute_id is not None and self.track_cid_outputs:
-                # last output value of this cid's latest launch: the
-                # fence-split completion probe (stream order means
-                # materializing it waits for exactly this work).
-                # Re-insert to refresh recency, bound to the 64 most
-                # recent cids (the perf_log convention) — unbounded, a
-                # fresh-cid-per-job caller would pin one stale device
-                # buffer per cid forever
-                self._cid_last_out.pop(compute_id, None)
-                self._cid_last_out[compute_id] = bufs[0]
-                if len(self._cid_last_out) > 64:
-                    self._cid_last_out.pop(next(iter(self._cid_last_out)))
-            if _tt:
-                TRACER.record(
-                    "launch", _tt, cid=compute_id, lane=self.index,
-                    tag=f"{'+'.join(names)} x{dispatched}",
-                    **MARKS.meta(_dm),
-                )
-        if self.markers is not None and bufs:
-            # one marker per actual dispatch, reached when the sequence's
+        if not bufs:
+            return
+        if compute_id is not None and self.track_cid_outputs:
+            # last output value of this cid's latest launch: the
+            # fence-split completion probe (stream order means
+            # materializing it waits for exactly this work).
+            # Re-insert to refresh recency, bound to the 64 most
+            # recent cids (the perf_log convention) — unbounded, a
+            # fresh-cid-per-job caller would pin one stale device
+            # buffer per cid forever
+            self._cid_last_out.pop(compute_id, None)
+            self._cid_last_out[compute_id] = bufs[0]
+            if len(self._cid_last_out) > 64:
+                self._cid_last_out.pop(next(iter(self._cid_last_out)))
+        if _tt:
+            TRACER.record(
+                "launch", _tt, cid=compute_id, lane=self.index, tag=tag,
+                **MARKS.meta(_dm),
+            )
+        if self.markers is not None:
+            # one marker per actual dispatch, added AFTER the dispatch
+            # succeeded (a failed one must not leak an added-never-reached
+            # marker into the in-flight accounting) and reached when the
             # final output retires on the chip (real in-flight depth, not
-            # host-dispatch counting) — repeat mode shows O(1) dispatches
+            # host-dispatch counting) — repeat mode, a fused ladder and a
+            # per-call launch riding it all show O(1) dispatches
             self.markers.add(dispatched)
             self.markers.reach_when_ready(bufs[0], dispatched)
 
@@ -801,26 +839,8 @@ class Worker:
         finally:
             if _dm is not None:
                 MARKS.end(_dm)
-        for p, b in zip(params, bufs):
-            self._buffers[id(p)] = b
-        if bufs:
-            if compute_id is not None and self.track_cid_outputs:
-                self._cid_last_out.pop(compute_id, None)
-                self._cid_last_out[compute_id] = bufs[0]
-                if len(self._cid_last_out) > 64:
-                    self._cid_last_out.pop(next(iter(self._cid_last_out)))
-            if _tt:
-                TRACER.record(
-                    "launch", _tt, cid=compute_id, lane=self.index,
-                    tag=f"fused:{'+'.join(kernel_names)} x{iters}",
-                    **MARKS.meta(_dm),
-                )
-            if self.markers is not None:
-                # add AFTER the dispatch succeeded (launch()'s ordering):
-                # a failed dispatch must not leak an added-never-reached
-                # marker into the in-flight accounting
-                self.markers.add()
-                self.markers.reach_when_ready(bufs[0])
+        self._launched(params, bufs, compute_id, _tt, _dm,
+                       _tt and f"fused:{'+'.join(kernel_names)} x{iters}", 1)
 
     # -- readback ------------------------------------------------------------
     def download_async(

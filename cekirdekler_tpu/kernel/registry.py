@@ -431,6 +431,7 @@ class KernelProgram:
         value_args,
         platform: str | None = None,
         donate: bool = False,
+        build: bool = True,
     ) -> Callable | None:
         """ONE executable for the fused-iteration dispatch path
         (core/cores.py): ``fn(offset, units, iters, bufs) -> bufs`` runs
@@ -460,7 +461,14 @@ class KernelProgram:
 
         Scalar values are baked as compile-time constants, like
         :meth:`sequence_launcher`; returns ``None`` when they are
-        unhashable (the caller falls back to per-iteration dispatch)."""
+        unhashable (the caller falls back to per-iteration dispatch).
+
+        ``build=False`` only PEEKS: the cached executable of exactly this
+        key, or ``None`` when no fused window (or ``Cores.warmup``) has
+        built it.  A multi-rung per-call launch (``Worker.launch``) rides
+        the executable that way with ``iters=1``; it must never build
+        one, because its values change freely from call to call and each
+        new value would compile."""
         from jax import lax
 
         def vals_for(name: str) -> tuple:
@@ -478,6 +486,8 @@ class KernelProgram:
             return None  # unhashable values (e.g. traced arrays)
         if hit is not None:
             return hit[0]
+        if not build:
+            return None
 
         nbits = max(1, (total_range // step).bit_length())
 
