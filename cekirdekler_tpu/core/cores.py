@@ -43,7 +43,7 @@ from ..errors import (
     KernelVerifyError,
 )
 from ..hardware import Devices, rate_prior
-from ..kernel.registry import KernelProgram
+from ..kernel.registry import KernelProgram, lowering_meta
 from ..metrics.registry import REGISTRY
 from ..obs.debugserver import DEBUG_PORT_ENV
 from ..obs.decisions import DECISIONS
@@ -1126,7 +1126,20 @@ class Cores:
         self._record_perf(run.compute_id, _t_pass,
                           self.global_ranges.get(run.compute_id, []))
         FLIGHT.event("fused-window", cid=run.compute_id, iters=iters)
-        TRACER.record("fused", _tt, cid=run.compute_id, tag=f"x{iters}")
+        if _tt:
+            # the lowering of the rungs in the lanes' fused executables: a
+            # peek, so a process's FIRST window, whose executables the
+            # closures above are still to trace, has none to name (its
+            # lanes' ``launch`` spans do)
+            fns = [self.program.fused_launcher(
+                tuple(run.kernel_names), run.step, run.global_range,
+                run.local_range, run.global_range, run.value_args,
+                platform=w.device.platform, donate=w.fused_donate,
+                build=False) for w, _off, _size in run.rows]
+            infos = [fn.info for fn in fns if fn is not None and fn.info.rungs]
+            TRACER.record(
+                "fused", _tt, cid=run.compute_id, tag=f"x{iters}",
+                **(lowering_meta(infos) if infos else {}))
 
     # ckcheck: cold window boundary — runs once per fused_batch deferrals
     def _fused_flush(self) -> None:

@@ -33,7 +33,7 @@ import numpy as np
 from jax import lax
 
 from ..arrays.clarray import ClArray
-from ..kernel.registry import KernelProgram
+from ..kernel.registry import KernelProgram, lowering_meta
 from ..metrics.registry import REGISTRY
 from ..obs.flight import FLIGHT
 from ..trace.device import MARKS
@@ -671,6 +671,7 @@ class Worker:
         names = list(kernel_names)
         units = size // step
         dispatched = 0
+        infos: list = []  # of the launchers run, for the span's lowering
         # device-timeline mark around the dispatch (trace/device.py):
         # disabled is one attribute read + falsy check, the tracer
         # discipline — the host-clock half of the launch's mark; its
@@ -702,6 +703,7 @@ class Worker:
             if one_fn is not None:
                 bufs = tuple(one_fn(*one_args))
                 dispatched = 1
+                infos.append(one_fn.info)
             else:
                 # the host loop over the rungs (one rung, no executable
                 # built for this key, unhashable values): interleave the
@@ -730,19 +732,23 @@ class Worker:
                                 bufs = tuple(out) + bufs[n_arr:]
                                 offset += chunk
                                 dispatched += 1
+                                if _tt:
+                                    infos.append(info)
                             offset -= size  # rewind for next kernel/repeat
         finally:
             if _dm is not None:  # close even on a failed dispatch
                 MARKS.end(_dm)
         self._launched(params, bufs, compute_id, _tt, _dm,
-                       _tt and f"{'+'.join(names)} x{dispatched}", dispatched)
+                       _tt and f"{'+'.join(names)} x{dispatched}", dispatched,
+                       infos)
 
     def _launched(self, params, bufs: tuple, compute_id, _tt, _dm,
-                  tag: str, dispatched: int) -> None:
+                  tag: str, dispatched: int, infos) -> None:
         """The tail every launch shares once its dispatches are out: the
         buffer cache is REPLACED from the outputs (a donating executable
         has deleted the inputs), then the per-cid probe, the ``launch``
-        span and the markers."""
+        span (with the lowering ``infos``' launchers were built with) and
+        the markers."""
         for p, b in zip(params, bufs):
             self._buffers[id(p)] = b
         if not bufs:
@@ -762,7 +768,7 @@ class Worker:
         if _tt:
             TRACER.record(
                 "launch", _tt, cid=compute_id, lane=self.index, tag=tag,
-                **MARKS.meta(_dm),
+                **MARKS.meta(_dm), **lowering_meta(infos),
             )
         if self.markers is not None:
             # one marker per actual dispatch, added AFTER the dispatch
@@ -840,7 +846,8 @@ class Worker:
             if _dm is not None:
                 MARKS.end(_dm)
         self._launched(params, bufs, compute_id, _tt, _dm,
-                       _tt and f"fused:{'+'.join(kernel_names)} x{iters}", 1)
+                       _tt and f"fused:{'+'.join(kernel_names)} x{iters}", 1,
+                       (fn.info,))
 
     # -- readback ------------------------------------------------------------
     def download_async(

@@ -25,6 +25,15 @@ Key mechanisms:
   carries are shape/dtype-stable and nothing recompiles when trip counts
   change at runtime.
 
+- **Run windows and row gathers** — a ``for`` whose variable goes up by
+  one a pass and indexes buffers it does not store to as ``T[j]`` (a CSR
+  row loop) reads, in every lane, a run of consecutive elements: the runs
+  are fetched once for 32 passes as two 128-wide rows a lane
+  (``_run_window``) where a pass would gather a chunk-wide element each;
+  on a TPU lane any other per-lane gather fetches the element's row and
+  picks its lane (``_take_rows``).  The chip gathers rows several times
+  faster than it gathers elements (PERF.md, PR 26).
+
 The launch boundary: ``build_kernel_fn`` returns ``fn(offset, *buffers,
 value_args) -> updated buffers``, where ``offset`` is a *runtime* scalar —
 the load balancer can re-partition the global range every call without
@@ -199,6 +208,14 @@ class _Ctx:
         self.uniform_vars: set[str] = set()
         # helper functions (lang.FuncDef by name) inlined at call sites
         self.helpers: dict = {}
+        # the innermost run-window loop's tables: buffer name -> (loop
+        # variable, this pass's row of the window) — see _exec_loop
+        self.runs: dict[str, tuple[str, Any]] = {}
+        # a TPU launcher reads per-lane gathers through 128-wide rows
+        # (build_kernel_fn sets it; _take_rows)
+        self.row_gathers = False
+        # row views of buffers: name -> (buffer, its [rows, 128] view)
+        self._rows_cache: dict[str, tuple[Any, Any]] = {}
 
     def broadcast_scalar(self, val, dtype):
         """Materialize a scalar as a full work-item vector of this ctx's
@@ -228,6 +245,23 @@ class _Ctx:
 
     def invalidate_padded(self, name: str) -> None:
         self._pad_cache.pop(name, None)
+
+    def rows_view(self, name: str):
+        """The buffer as ``[rows, 128]`` for the row gathers of
+        :func:`_take_rows` and :func:`_run_window`: element ``i`` sits at
+        ``i + 128`` of a copy with one row of the first element before it
+        and the last element repeated to the end of a row and one row
+        more, so that a run reaching over either end reads what a gather's
+        clamp reads.  One copy, kept for as long as the buffer is the
+        same (``_exec_loop`` asks for a run table's before it enters)."""
+        buf = self.bufs[name]
+        hit = self._rows_cache.get(name)
+        if hit is not None and hit[0] is buf:
+            return hit[1]
+        rows = jnp.pad(buf, (_ROW, -buf.shape[0] % _ROW + _ROW), mode="edge")
+        rows = rows.reshape(-1, _ROW)
+        self._rows_cache[name] = (buf, rows)
+        return rows
 
     def active_mask(self):
         """Combined current mask (branch mask minus returned / broken /
@@ -658,6 +692,86 @@ def _loaded(value, ctype: str) -> KVal:
     return KVal(value, ctype)
 
 
+# ---------------------------------------------------------------------------
+# per-lane reads through 128-wide rows.  On the chip a gather of single
+# elements costs 9-20 ns an element whatever the pattern, and a gather of
+# whole rows of 128 costs 3-12 ns a ROW (PERF.md, PR 26): so an element is
+# read by fetching its row and picking its lane, and a loop that walks a
+# per-lane run ``T[j], T[j + 1], ...`` fetches the run's rows once for many
+# passes (_exec_loop).
+# ---------------------------------------------------------------------------
+
+_ROW = 128             # elements of a row of ``_Ctx.rows_view``
+_RUN_WINDOW = 32       # passes one refill of a loop's run windows serves
+_LANE_CHUNK = 1 << 18  # work items whose rows are materialized at once
+
+
+def _by_lane_chunks(fn, ix, lead: tuple, dtype):
+    """``fn(int32[C]) -> dtype[*lead, C]`` over ``ix`` in chunks of
+    ``_LANE_CHUNK`` lanes, joined along the last axis: a row gather holds
+    128 elements a lane, which for a whole rung would be gigabytes."""
+    B = ix.shape[0]
+    C = _LANE_CHUNK
+    if B <= C:
+        return fn(ix)
+    n = -(-B // C)
+    if n * C != B:
+        ix = jnp.pad(ix, (0, n * C - B))
+    at = (0,) * len(lead)
+
+    def body(c, out):
+        part = fn(lax.dynamic_slice(ix, (c * C,), (C,)))
+        return lax.dynamic_update_slice(out, part, at + (c * C,))
+
+    out = lax.fori_loop(0, n, body, jnp.zeros(lead + (n * C,), dtype))
+    return out if n * C == B else out[..., :B]
+
+
+def _take_rows(ctx: _Ctx, name: str, iv):
+    """``buf[clip(iv)]`` for every lane: the element's row fetched whole,
+    its lane picked by a compare and a sum (on the value's bits, so that
+    every float comes back as it was stored)."""
+    rows, n = ctx.rows_view(name), ctx.bufs[name].shape[0]
+    bits = lax.bitcast_convert_type(rows, jnp.int32)
+
+    def pick(ic):
+        ic = jnp.clip(ic, 0, n - 1) + _ROW
+        g = bits.at[ic >> 7].get(mode="promise_in_bounds")
+        lane = lax.broadcasted_iota(jnp.int32, g.shape, 1)
+        hit = lane == (ic & (_ROW - 1))[:, None]
+        return jnp.sum(jnp.where(hit, g, 0), axis=1, dtype=jnp.int32)
+
+    out = _by_lane_chunks(pick, iv.astype(jnp.int32), (), jnp.int32)
+    return lax.bitcast_convert_type(out, rows.dtype)
+
+
+def _run_window(ctx: _Ctx, name: str, j0):
+    """``out[r, lane] = buf[clip(j0[lane] + r)]`` for ``r`` in
+    ``[0, _RUN_WINDOW)``: a lane's run lies in two neighbouring rows of the
+    row view; both are fetched, laid side by side with the lanes last, and
+    moved up by the run's offset in its row one bit of the offset at a
+    time (a select between two row-slices a bit)."""
+    rows, n = ctx.rows_view(name), ctx.bufs[name].shape[0]
+    W, last = _RUN_WINDOW, rows.shape[0] - 1
+
+    def window(jc):
+        start = jnp.clip(jc, -_ROW, n) + _ROW
+        r0, off = start >> 7, start & (_ROW - 1)
+        both = jnp.concatenate(
+            [rows.at[r0].get(mode="promise_in_bounds"),
+             rows.at[jnp.minimum(r0 + 1, last)].get(mode="promise_in_bounds")],
+            axis=1)
+        t = both.T[:W + _ROW - 1]
+        bit = _ROW // 2
+        while bit:
+            keep = W + bit - 1
+            t = jnp.where(((off & bit) != 0)[None, :], t[bit:bit + keep], t[:keep])
+            bit //= 2
+        return t
+
+    return _by_lane_chunks(window, j0.astype(jnp.int32), (W,), rows.dtype)
+
+
 def _load(ctx: _Ctx, node: Index) -> KVal:
     if node.base in ctx.private:
         return _private_load(ctx, node)
@@ -671,6 +785,9 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
     if ctx.pallas:
         kv = ctx.pallas_load(node, buf, ctype, idx)  # type: ignore[attr-defined]
         return _loaded(kv.value, ctype)
+    run = ctx.runs.get(node.base)
+    if run is not None and isinstance(node.index, Var) and node.index.name == run[0]:
+        return _loaded(run[1], ctype)  # this pass's row of the run window
     if idx.affine is not None and idx.affine[0] == 1 and isinstance(idx.affine[1], int):
         c = idx.affine[1]
         if c == 0:
@@ -692,6 +809,8 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
     iv = _num(_as_dtype(idx, "int"))
     if not hasattr(iv, "ndim") or iv.ndim == 0:
         iv = jnp.full((ctx.B,), iv, dtype=jnp.int32)
+    if ctx.row_gathers and buf.dtype.itemsize == 4:
+        return _loaded(_take_rows(ctx, node.base, iv), ctype)
     return _loaded(jnp.take(buf, iv, mode="clip"), ctype)
 
 
@@ -948,6 +1067,51 @@ def _exec_if(ctx: _Ctx, node: If) -> None:
     ctx.mask = outer_mask
 
 
+def _index_reads(node, var: str, out: set[str]) -> set[str]:
+    """Bases of every ``base[var]`` under ``node`` (index exactly the
+    variable)."""
+    if isinstance(node, Index):
+        if isinstance(node.index, Var) and node.index.name == var:
+            out.add(node.base)
+        _index_reads(node.index, var, out)
+    elif isinstance(node, (list, tuple)):
+        for x in node:
+            _index_reads(x, var, out)
+    elif hasattr(node, "__dict__") and not isinstance(node, _Lit):
+        for v in vars(node).values():
+            if isinstance(v, (list, tuple)) or hasattr(v, "__dict__"):
+                _index_reads(v, var, out)
+    return out
+
+
+def _run_reads(ctx: _Ctx, node, cond_expr, carried_bufs) -> tuple:
+    """``(j, tables)`` when ``node`` is a ``for`` whose variable ``j`` goes
+    up by exactly one a pass (``j++`` / ``j += 1`` as the step, no other
+    assignment in the body), differs from lane to lane, and indexes
+    buffers the loop does not store to as ``T[j]``: each lane then reads a
+    run of consecutive elements of every such ``T``.  ``(None, [])``
+    otherwise."""
+    none = (None, [])
+    if not isinstance(node, For) or node.step is None:
+        return none
+    step = node.step
+    if isinstance(step, CrementStmt):
+        up = step.op == "++"
+    else:
+        up = (isinstance(step, Assign) and step.op == "+="
+              and isinstance(step.value, Num) and step.value.value == 1)
+    if not up or not isinstance(step.target, Var):
+        return none
+    j = step.target.name
+    if (j not in ctx.env or ctx.env[j].ctype not in _INT_TYPES
+            or j in ctx.uniform_vars or j in _assigned_vars(node.body)):
+        return none
+    tables = _index_reads([node.body, cond_expr], j, set())
+    return j, sorted(t for t in tables
+                     if t in ctx.bufs and t not in ctx.private
+                     and t not in carried_bufs)
+
+
 def _exec_loop(ctx: _Ctx, node) -> None:
     """Lower for/while to a vectorized lax.while_loop with a per-item active
     mask (see module docstring)."""
@@ -964,6 +1128,13 @@ def _exec_loop(ctx: _Ctx, node) -> None:
 
     carried_vars = sorted(_assigned_vars(body) & set(ctx.env.keys()))
     carried_bufs = sorted(_stored_bufs(body) & set(ctx.bufs.keys()))
+    run_var, run_tables = None, []
+    if not ctx.pallas:
+        run_var, run_tables = _run_reads(ctx, node, cond_expr, carried_bufs)
+        # their row views are made here, where the buffers are defined,
+        # and not anew at every refill
+        for t in run_tables:
+            ctx.rows_view(t)
 
     outer_mask = ctx.active_mask()
 
@@ -1041,10 +1212,13 @@ def _exec_loop(ctx: _Ctx, node) -> None:
             return jnp.sum(prev) > 0.0
         return jnp.any(prev)
 
-    def body_fun(carry):
+    def body_fun(carry, rows=None):
         prev, env_vals, buf_vals = carry
         prev = from_carry_mask(prev)
         saved_env, saved_bufs, saved_mask = dict(ctx.env), dict(ctx.bufs), ctx.mask
+        saved_runs, saved_views = ctx.runs, dict(ctx._rows_cache)
+        if rows:  # this pass's rows of the loop's run windows
+            ctx.runs = {**ctx.runs, **rows}
         saved_stored = set(ctx.stored)
         saved_rm = ctx.return_mask
         saved_fr = ctx._freerun
@@ -1099,10 +1273,33 @@ def _exec_loop(ctx: _Ctx, node) -> None:
             ctx.return_mask = saved_rm
             ctx._freerun = saved_fr
             ctx.break_mask, ctx.continue_mask = saved_bk, saved_cn
+            # row views made inside the body belong to its trace
+            ctx.runs, ctx._rows_cache = saved_runs, saved_views
 
-    active_f, env_f, bufs_f = lax.while_loop(
-        cond_fun, body_fun, (to_carry_mask(prev0), init_env, init_bufs)
-    )
+    carry0 = (to_carry_mask(prev0), init_env, init_bufs)
+    if run_var is None:
+        active_f, env_f, bufs_f = lax.while_loop(cond_fun, body_fun, carry0)
+    else:
+        # RUN WINDOWS: the loop variable goes up by one a pass, so the
+        # reads ``T[j]`` of a lane are a run ``T[j0], T[j0 + 1], ...``.
+        # The runs of all lanes are fetched once for _RUN_WINDOW passes
+        # (_run_window: two row gathers a lane) and a pass reads row r of
+        # the window where it gathered a chunk-wide element each.
+        def refill_and_run(carry):
+            wins = {t: _run_window(ctx, t, carry[1][run_var]) for t in run_tables}
+
+            def more(c):
+                return jnp.logical_and(c[0] < _RUN_WINDOW, cond_fun(c[1]))
+
+            def one_pass(c):
+                r, inner = c
+                rows = {t: (run_var, lax.dynamic_index_in_dim(w, r, 0, keepdims=False))
+                        for t, w in wins.items()}
+                return r + 1, body_fun(inner, rows)
+
+            return lax.while_loop(more, one_pass, (jnp.int32(0), carry))[1]
+
+        active_f, env_f, bufs_f = lax.while_loop(cond_fun, refill_and_run, carry0)
     ctx._pad_cache.clear()
     for k in carried_vars:
         ctx.env[k] = KVal(env_f[k], var_ctypes[k], None)
@@ -1399,6 +1596,9 @@ class KernelBuildInfo:
     # (kernel/registry.py records both so a run can assert its routing)
     lowering: str = "xla"
     veto: str | None = None
+    # a ladder executable (``lowering="ladder"``: the fused window's, repeat
+    # mode's) names the build infos of the rung launchers it runs
+    rungs: tuple = ()
 
 
 def hlo_name(*kernel_names: str) -> str:
@@ -1415,6 +1615,7 @@ def build_kernel_fn(
     chunk: int,
     local_size: int,
     global_size: int,
+    platform: str | None = None,
 ) -> tuple[Callable, KernelBuildInfo]:
     """Build the vectorized launch function for one kernel.
 
@@ -1422,6 +1623,8 @@ def build_kernel_fn(
     processes work items ``[offset, offset+chunk)`` and returns the tuple of
     updated arrays (all array params, in declaration order).  ``offset`` is a
     runtime scalar — re-balancing never recompiles.  ``chunk`` is static.
+    ``platform`` is the lane's: on ``"tpu"`` a per-lane gather reads whole
+    rows (:func:`_take_rows`).
     """
     array_params = [p for p in kernel.params if p.is_pointer]
     value_params = [p for p in kernel.params if not p.is_pointer]
@@ -1438,6 +1641,7 @@ def build_kernel_fn(
     def fn(offset, arrays: tuple, values: tuple = ()):
         ctx = _Ctx(chunk, jnp.asarray(offset, jnp.int32), global_size, local_size, {})
         ctx.uniform_vars = uniform
+        ctx.row_gathers = platform == "tpu"
         ctx.helpers = getattr(kernel, "helpers", {}) or {}
         for p, arr in zip(array_params, arrays):
             ctx.bufs[p.name] = arr

@@ -481,7 +481,8 @@ def build_kernel_fn_pallas(
 
     def xla_fn():
         if not _xla_fallback:
-            f, _ = codegen.build_kernel_fn(kernel, chunk, local_size, global_size)
+            f, _ = codegen.build_kernel_fn(
+                kernel, chunk, local_size, global_size, "tpu")
             _xla_fallback.append(f)
         return _xla_fallback[0]
 

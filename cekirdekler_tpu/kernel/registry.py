@@ -29,7 +29,7 @@ from ..errors import KernelCompileError
 from ..trace.spans import TRACER
 from . import codegen, lang
 
-__all__ = ["KernelProgram", "kernel", "PythonKernel"]
+__all__ = ["KernelProgram", "kernel", "PythonKernel", "lowering_meta"]
 
 
 class _Launcher:
@@ -39,25 +39,86 @@ class _Launcher:
     and compiles at the first call, not when the launcher is built, and
     a rung built while a fused ladder was traced is first compiled on
     its own whenever a per-call launch asks for it.  A call from inside
-    another function's trace does not count as having run.  Everything
-    else (``.lower``, ``.trace``) is the jitted function's own."""
+    another function's trace does not count as having run.  ``info`` is
+    the build's :class:`~.codegen.KernelBuildInfo`; the span carries the
+    lowering it names (:func:`lowering_meta`), read when the span closes
+    because a build may still change its mind while it is traced.
+    Everything else (``.lower``, ``.trace``) is the jitted function's
+    own."""
 
-    __slots__ = ("_fn", "_tag", "_warm")
+    __slots__ = ("_fn", "_tag", "_warm", "info")
 
-    def __init__(self, fn, tag: str):
-        self._fn, self._tag, self._warm = fn, tag, False
+    def __init__(self, fn, tag: str, info):
+        self._fn, self._tag, self._warm, self.info = fn, tag, False, info
 
     def __call__(self, *args):
         if self._warm:
             return self._fn(*args)
-        with TRACER.span("compile", tag=self._tag):
+        _tt = TRACER.t0("compile")
+        try:
             out = self._fn(*args)
+        finally:
+            TRACER.record("compile", _tt, tag=self._tag,
+                          **(lowering_meta((self.info,)) if _tt else {}))
         self._warm = not any(
             isinstance(x, jax.core.Tracer) for x in jax.tree_util.tree_leaves(out))
         return out
 
     def __getattr__(self, name):
         return getattr(self._fn, name)
+
+
+class _KernelLauncher(_Launcher):
+    """The launcher of ONE kernel over one chunk: ``fn(offset, arrays,
+    values) -> arrays``.  The executable returns only the arrays the kernel
+    may REPLACE (``kept``: the positions of the array parameters it stores
+    to, known when the launcher is built); the others are handed back as
+    they came.  An executable that returns an argument copies it, so a
+    kernel that writes one small array beside gigabytes it only reads
+    would copy those on every launch and hold them twice.  The trace
+    checks that nothing outside ``kept`` was replaced.  ``.lower`` and
+    ``.trace`` are the executable's own: their outputs are the ``kept``
+    arrays alone."""
+
+    __slots__ = ("_kept",)
+
+    def __init__(self, raw_fn, tag: str, info, static: bool, kept: tuple):
+        self._kept = kept
+
+        def replaced(offset, arrays: tuple, values: tuple = ()):
+            out = raw_fn(offset, arrays, values)
+            stray = [i for i, (a, o) in enumerate(zip(arrays, out))
+                     if o is not a and i not in kept]
+            assert not stray, (
+                f"{tag}: array parameter(s) {stray} replaced by a kernel "
+                f"whose stores name only {kept}")
+            return tuple(out[i] for i in kept)
+
+        replaced.__name__ = raw_fn.__name__
+        super().__init__(
+            jax.jit(replaced, static_argnums=(2,) if static else ()),
+            tag, info)
+
+    def __call__(self, offset, arrays, values=()):
+        new = super().__call__(offset, tuple(arrays), values)
+        out = list(arrays)
+        for i, buf in zip(self._kept, new):
+            out[i] = buf
+        return tuple(out)
+
+
+def lowering_meta(infos) -> dict:
+    """Span metadata naming what was built for the launchers a span ran:
+    ``lowering`` (``pallas``, ``xla``, ``python``; several joined by ``+``
+    where a ladder's rungs differ) and, where a TPU build was routed away
+    from Pallas, ``veto`` with the reason.  A ladder executable stands for
+    its rungs."""
+    leaves = [r for i in infos for r in (i.rungs or (i,))]
+    meta = {"lowering": "+".join(sorted({i.lowering for i in leaves}))}
+    vetoes = sorted({i.veto for i in leaves if i.veto})
+    if vetoes:
+        meta["veto"] = "; ".join(vetoes)
+    return meta
 
 
 @dataclass
@@ -302,7 +363,8 @@ class KernelProgram:
                     veto = str(e)
             if raw_fn is None:
                 raw_fn, info = codegen.build_kernel_fn(
-                    self._c_kernels[name], chunk, local_size, global_size
+                    self._c_kernels[name], chunk, local_size, global_size,
+                    platform,
                 )
                 info.veto = veto
         elif name in self._py_kernels:
@@ -334,12 +396,17 @@ class KernelProgram:
             )
 
         static = name in self._py_kernels and self._py_kernels[name].static_values
+        # the array parameters a launch may replace: those a C kernel's
+        # statements store to, every one of a Python kernel's
+        stores = (codegen._stored_bufs(self._c_kernels[name].body)
+                  if name in self._c_kernels else info.array_params)
+        kept = tuple(i for i, p in enumerate(info.array_params) if p in stores)
         # whichever lowering built it: the XLA module reads jit_<kernel>
         raw_fn.__name__ = codegen.hlo_name(name)
-        jitted = _Launcher(
-            jax.jit(raw_fn, static_argnums=(2,) if static else ()),
+        jitted = _KernelLauncher(
+            raw_fn,
             f"{name} chunk={chunk} lr={local_size} g={global_size} "
-            f"{platform}")
+            f"{platform}", info, static, kept)
         with self._lock:
             self._cache[key] = (jitted, info)
         return jitted, info
@@ -385,15 +452,23 @@ class KernelProgram:
         if hit is not None:
             return hit[0]
 
+        info = codegen.KernelBuildInfo(
+            name="+".join(names), array_params=[], value_params=[],
+            array_ctypes={}, stored_params=[], lowering="ladder",
+        )
+        rungs: dict = {}  # the rung launchers' infos, seen where traced
+
         def run_names(names_seq, offset0, bufs):
             for name in names_seq:
                 off = offset0
                 n_arr = self.array_param_count(name)
                 for chunk in chunks:
-                    fn, _ = self.launcher(name, chunk, local_size, global_size, platform)
+                    fn, rungs[name, chunk] = self.launcher(
+                        name, chunk, local_size, global_size, platform)
                     out = fn(off, bufs[:n_arr], vals_for(name))
                     bufs = tuple(out) + bufs[n_arr:]
                     off = off + chunk
+            info.rungs = tuple(rungs.values())
             return bufs
 
         def raw(offset, bufs: tuple):
@@ -412,11 +487,10 @@ class KernelProgram:
             )
 
         raw.__name__ = "seq_" + codegen.hlo_name(*names)
-        jitted = jax.jit(raw)
-        info = codegen.KernelBuildInfo(
-            name="+".join(names), array_params=[], value_params=[],
-            array_ctypes={}, stored_params=[], lowering="ladder",
-        )
+        jitted = _Launcher(
+            jax.jit(raw),
+            f"seq:{'+'.join(names)} x{repeats} g={global_size} {platform}",
+            info)
         with self._lock:
             self._cache[key] = (jitted, info)
         return jitted
@@ -490,6 +564,12 @@ class KernelProgram:
             return None
 
         nbits = max(1, (total_range // step).bit_length())
+        info = codegen.KernelBuildInfo(
+            name="fused:" + "+".join(names), array_params=[],
+            value_params=[], array_ctypes={}, stored_params=[],
+            lowering="ladder",
+        )
+        rungs: dict = {}  # the rung launchers' infos, seen where traced
 
         def run_ladder(offset, units, bufs):
             for name in names:
@@ -498,7 +578,7 @@ class KernelProgram:
                 off = jnp.asarray(offset, jnp.int32)
                 for k in reversed(range(nbits)):
                     chunk = step << k
-                    fn, _ = self.launcher(
+                    fn, rungs[name, chunk] = self.launcher(
                         name, chunk, local_size, global_size, platform
                     )
                     bit = (jnp.asarray(units, jnp.int32) >> k) & 1
@@ -511,6 +591,7 @@ class KernelProgram:
                         bit != 0, hit_branch, lambda b: tuple(b), tuple(bufs)
                     )
                     off = off + bit * chunk
+            info.rungs = tuple(rungs.values())
             return bufs
 
         def raw(offset, units, iters, bufs: tuple):
@@ -523,12 +604,7 @@ class KernelProgram:
         jitted = _Launcher(
             jax.jit(raw, donate_argnums=(3,) if donate else ()),
             f"fused:{'+'.join(names)} step={step} g={global_size} "
-            f"{platform}")
-        info = codegen.KernelBuildInfo(
-            name="fused:" + "+".join(names), array_params=[],
-            value_params=[], array_ctypes={}, stored_params=[],
-            lowering="ladder",
-        )
+            f"{platform}", info)
         with self._lock:
             self._cache[key] = (jitted, info)
         return jitted

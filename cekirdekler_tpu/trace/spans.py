@@ -205,8 +205,12 @@ class Tracer:
         if lane is not None:        # takes the one its thread works for
             meta["lane"] = lane
         if tag is not None:
-            # '#' ends the metadata block of a TraceMe name
-            meta["tag"] = tag.replace("#", "") if "#" in tag else tag
+            meta["tag"] = tag
+        for k, v in meta.items():
+            # '#' ends the metadata block of a TraceMe name and ',' a
+            # value in it: a tag ``[2048, 2048]`` would arrive as ``[2048``
+            if v.__class__ is str and ("," in v or "#" in v):
+                meta[k] = v.replace("#", "").replace(",", ";")
         if ctx:
             win, queued = ctx.get("win"), ctx.get("queued_us")
             if win is not None:
