@@ -28,11 +28,16 @@ Key mechanisms:
 - **Run windows and row gathers** — a ``for`` whose variable goes up by
   one a pass and indexes buffers it does not store to as ``T[j]`` (a CSR
   row loop) reads, in every lane, a run of consecutive elements: the runs
-  are fetched once for 32 passes as two 128-wide rows a lane
-  (``_run_window``) where a pass would gather a chunk-wide element each;
-  on a TPU lane any other per-lane gather fetches the element's row and
-  picks its lane (``_take_rows``).  The chip gathers rows several times
-  faster than it gathers elements (PERF.md, PR 26).
+  are fetched once for 32 passes as ONE 128-wide row a lane of a
+  half-overlapping view of the table (row ``r`` holds elements
+  ``[64 r, 64 r + 128)``: a run of 32 lies whole in one row) and moved up
+  by the run's offset in six select stages over whole lines
+  (``_run_window``), where a pass would gather a chunk-wide element each.
+  The view costs twice the table's bytes of device memory for as long as
+  the launch runs.  On a TPU lane any other per-lane gather fetches the
+  element's row of the plain view and picks its lane (``_take_rows``).
+  The chip gathers rows several times faster than it gathers elements
+  (PERF.md, PR 26, PR 27).
 
 The launch boundary: ``build_kernel_fn`` returns ``fn(offset, *buffers,
 value_args) -> updated buffers``, where ``offset`` is a *runtime* scalar —
@@ -214,8 +219,9 @@ class _Ctx:
         # a TPU launcher reads per-lane gathers through 128-wide rows
         # (build_kernel_fn sets it; _take_rows)
         self.row_gathers = False
-        # row views of buffers: name -> (buffer, its [rows, 128] view)
-        self._rows_cache: dict[str, tuple[Any, Any]] = {}
+        # row views of buffers: (name, overlapping) -> (buffer, its
+        # [rows, 128] view)
+        self._rows_cache: dict[tuple[str, bool], tuple[Any, Any]] = {}
 
     def broadcast_scalar(self, val, dtype):
         """Materialize a scalar as a full work-item vector of this ctx's
@@ -246,21 +252,38 @@ class _Ctx:
     def invalidate_padded(self, name: str) -> None:
         self._pad_cache.pop(name, None)
 
-    def rows_view(self, name: str):
-        """The buffer as ``[rows, 128]`` for the row gathers of
-        :func:`_take_rows` and :func:`_run_window`: element ``i`` sits at
-        ``i + 128`` of a copy with one row of the first element before it
-        and the last element repeated to the end of a row and one row
-        more, so that a run reaching over either end reads what a gather's
-        clamp reads.  One copy, kept for as long as the buffer is the
-        same (``_exec_loop`` asks for a run table's before it enters)."""
+    def rows_view(self, name: str, overlapping: bool = False):
+        """The buffer as ``[rows, 128]`` for the row gathers.  Element
+        ``i`` sits at ``i + 128`` of a padded copy: one row of the first
+        element before it, the last element repeated after it, so that a
+        read reaching over either end reads what a gather's clamp reads.
+
+        The plain view (:func:`_take_rows`) is that copy cut into rows.
+        The OVERLAPPING view (:func:`_run_window`) has a row every 64
+        elements: row ``k`` holds ``[128 k, 128 k + 128)`` of the copy
+        and row ``K + k`` holds ``[128 k + 64, 128 k + 192)``, ``K`` rows
+        a half, so that a run of ``_RUN_WINDOW`` elements lies whole in
+        one row wherever it starts.  It is twice the buffer's bytes (one
+        concatenate: the halves are the copy, and the copy 64 elements
+        on).  A view is kept for as long as the buffer is the same
+        (``_exec_loop`` asks for a run table's before it enters)."""
         buf = self.bufs[name]
-        hit = self._rows_cache.get(name)
+        hit = self._rows_cache.get((name, overlapping))
         if hit is not None and hit[0] is buf:
             return hit[1]
-        rows = jnp.pad(buf, (_ROW, -buf.shape[0] % _ROW + _ROW), mode="edge")
+        n = buf.shape[0]
+        if overlapping:
+            half, tail = _ROW // 2, -n % _ROW
+            lo, hi = buf[:1], buf[-1:]
+            rows = jnp.concatenate([
+                jnp.broadcast_to(lo, (_ROW,)), buf,
+                jnp.broadcast_to(hi, (tail,)),
+                jnp.broadcast_to(lo, (half,)), buf,
+                jnp.broadcast_to(hi, (tail + half,))])
+        else:
+            rows = jnp.pad(buf, (_ROW, -n % _ROW + _ROW), mode="edge")
         rows = rows.reshape(-1, _ROW)
-        self._rows_cache[name] = (buf, rows)
+        self._rows_cache[(name, overlapping)] = (buf, rows)
         return rows
 
     def active_mask(self):
@@ -697,8 +720,10 @@ def _loaded(value, ctype: str) -> KVal:
 # elements costs 9-20 ns an element whatever the pattern, and a gather of
 # whole rows of 128 costs 3-12 ns a ROW (PERF.md, PR 26): so an element is
 # read by fetching its row and picking its lane, and a loop that walks a
-# per-lane run ``T[j], T[j + 1], ...`` fetches the run's rows once for many
-# passes (_exec_loop).
+# per-lane run ``T[j], T[j + 1], ...`` fetches the run's row once for many
+# passes (_exec_loop).  What a row costs is the fetch, not its bytes (11.8
+# ns a row, 22.3 for two neighbours in one gather: PERF.md, PR 27), so a
+# run is laid out to need one.
 # ---------------------------------------------------------------------------
 
 _ROW = 128             # elements of a row of ``_Ctx.rows_view``
@@ -747,27 +772,39 @@ def _take_rows(ctx: _Ctx, name: str, iv):
 
 def _run_window(ctx: _Ctx, name: str, j0):
     """``out[r, lane] = buf[clip(j0[lane] + r)]`` for ``r`` in
-    ``[0, _RUN_WINDOW)``: a lane's run lies in two neighbouring rows of the
-    row view; both are fetched, laid side by side with the lanes last, and
-    moved up by the run's offset in its row one bit of the offset at a
-    time (a select between two row-slices a bit)."""
-    rows, n = ctx.rows_view(name), ctx.bufs[name].shape[0]
-    W, last = _RUN_WINDOW, rows.shape[0] - 1
+    ``[0, _RUN_WINDOW)``.  A lane's run lies whole in ONE row of the
+    overlapping row view, at an offset under 64: the rows are fetched,
+    transposed for real (lanes last, in tiles of 128: line ``e * tiles +
+    k`` is element ``e`` of tile ``k``'s rows) and moved up by the offset
+    one bit at a time, a select between two slices of whole lines a bit:
+    six stages over 63, 47, 39, 35, 33, 32 rows."""
+    rows, n = ctx.rows_view(name, overlapping=True), ctx.bufs[name].shape[0]
+    W, half = _RUN_WINDOW, _ROW // 2
+    width = -(-(W + half - 1) // 8) * 8  # of a fetched row, what a run can reach
 
     def window(jc):
-        start = jnp.clip(jc, -_ROW, n) + _ROW
-        r0, off = start >> 7, start & (_ROW - 1)
-        both = jnp.concatenate(
-            [rows.at[r0].get(mode="promise_in_bounds"),
-             rows.at[jnp.minimum(r0 + 1, last)].get(mode="promise_in_bounds")],
-            axis=1)
-        t = both.T[:W + _ROW - 1]
-        bit = _ROW // 2
+        c = jc.shape[0]
+        tiles = -(-c // _ROW)
+        if c % _ROW:
+            jc = jnp.pad(jc, (0, -c % _ROW))
+        start = jnp.clip(jc, -_ROW, n - 1) + _ROW
+        r0 = (start >> 7) + ((start >> 6) & 1) * (rows.shape[0] // 2)
+        off = (start & (half - 1)).reshape(tiles, _ROW)
+        g = rows.at[r0].get(mode="promise_in_bounds")
+        # Flattened to 2-D the transposition has to be made (as a 3-D
+        # ``[width, tiles, 128]`` XLA keeps the gather's layout, elements
+        # minor, and every stage slices the minor dimension); behind the
+        # barrier the first stage's slices stay slices of it
+        t = g[:, :width].reshape(tiles, _ROW, width).transpose(2, 0, 1)
+        t = lax.optimization_barrier(t.reshape(width * tiles, _ROW))
+        bit = half // 2
         while bit:
             keep = W + bit - 1
-            t = jnp.where(((off & bit) != 0)[None, :], t[bit:bit + keep], t[:keep])
+            up = jnp.broadcast_to(((off & bit) != 0)[None], (keep, tiles, _ROW))
+            t = jnp.where(up.reshape(keep * tiles, _ROW),
+                          t[bit * tiles:(bit + keep) * tiles], t[:keep * tiles])
             bit //= 2
-        return t
+        return t.reshape(W, tiles * _ROW)[:, :c]
 
     return _by_lane_chunks(window, j0.astype(jnp.int32), (W,), rows.dtype)
 
@@ -1134,7 +1171,7 @@ def _exec_loop(ctx: _Ctx, node) -> None:
         # their row views are made here, where the buffers are defined,
         # and not anew at every refill
         for t in run_tables:
-            ctx.rows_view(t)
+            ctx.rows_view(t, overlapping=True)
 
     outer_mask = ctx.active_mask()
 
@@ -1283,7 +1320,7 @@ def _exec_loop(ctx: _Ctx, node) -> None:
         # RUN WINDOWS: the loop variable goes up by one a pass, so the
         # reads ``T[j]`` of a lane are a run ``T[j0], T[j0 + 1], ...``.
         # The runs of all lanes are fetched once for _RUN_WINDOW passes
-        # (_run_window: two row gathers a lane) and a pass reads row r of
+        # (_run_window: one row gather a lane) and a pass reads row r of
         # the window where it gathered a chunk-wide element each.
         def refill_and_run(carry):
             wins = {t: _run_window(ctx, t, carry[1][run_var]) for t in run_tables}
