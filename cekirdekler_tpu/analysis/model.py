@@ -2,7 +2,7 @@
 state machines, against the invariants each machine declares.
 
 Every controller bug found so far (the PR 12 probation↔quarantine
-flapping, the r10 SectionScheduler starvation violation, the r8
+flapping, the r10 fairness-rotation starvation violation, the r8
 fused-window mode-change break) was found BY HAND from a specific
 reproduction, after it shipped.  The controllers are now pure,
 deterministic, replay-verified functions — exactly the shape
@@ -33,8 +33,7 @@ Design rules:
    trajectories (deterministic per rate/knob config) explore a
    quantized rate alphabet × knob grid to an exact fixpoint, limit
    cycle, or horizon.  Tier-1 bounds finish in seconds; the
-   :data:`DEPTH_ENV` (``CK_MODEL_DEPTH``) knob deepens on the bench
-   rig.
+   :data:`DEPTH_ENV` (``CK_MODEL_DEPTH``) knob deepens them.
 4. **Violations are decision-log traces.**  A counterexample is a
    minimal (BFS-shortest) sequence of records in the
    ``obs/decisions.py`` row schema — balance/membership steps are the
@@ -46,8 +45,7 @@ Design rules:
 Exploration runs with the decision log captured into a scratch ring
 and the flight recorder disabled (the replay "quiesced" discipline):
 like replay-verify, it re-executes emission sites that also touch
-``ck_balance_*``/``ck_member_*`` counters, so run it at sync points —
-bench runs it in ``finalize_result`` after the metrics snapshot.
+``ck_balance_*``/``ck_member_*`` counters, so run it at sync points.
 """
 
 from __future__ import annotations
@@ -83,7 +81,7 @@ __all__ = [
     "DEPTH_ENV",
 ]
 
-#: CLI/bench machine vocabulary: ``serve`` groups the admission and
+#: CLI machine vocabulary: ``serve`` groups the admission and
 #: coalesce sub-machines (one serving tier, two pure planners);
 #: ``resilience`` groups the breaker, brownout-shed and retry-budget
 #: machines (``serve/resilience.py``); ``block`` explores the tile
@@ -93,7 +91,7 @@ __all__ = [
 MACHINE_NAMES = ("drain", "elastic", "serve", "balance", "resilience",
                  "block", "router")
 
-#: Deepen-on-the-bench-rig knob: a positive integer scales the bounds
+#: Deepening knob: a positive integer scales the bounds
 #: (balancer horizon, starvation caps, rate alphabet) beyond tier-1.
 DEPTH_ENV = "CK_MODEL_DEPTH"
 
@@ -2122,8 +2120,7 @@ def build_machines(name: str, quick: bool = False,
                    scale: int | None = None) -> list:
     """The sub-machine list for one CLI machine name, at tier-1 bounds
     scaled by ``CK_MODEL_DEPTH`` (or ``scale``).  ``quick`` is the
-    bench-epilogue profile: the same machines under the smallest
-    honest bounds, sub-second."""
+    same machines under the smallest honest bounds, sub-second."""
     scale = _depth_scale() if scale is None else max(1, int(scale))
     if name == "drain":
         if quick:
@@ -2217,8 +2214,7 @@ def check_machine(name: str, quick: bool = False,
 
 def check_all(names=None, quick: bool = False,
               scale: int | None = None) -> dict:
-    """The full report over every machine: the CLI gate's engine and
-    the bench artifact's ``model`` block."""
+    """The full report over every machine: the CLI gate's engine."""
     names = tuple(names) if names else MACHINE_NAMES
     per = {n: check_machine(n, quick=quick, scale=scale) for n in names}
     violations = [v for r in per.values() for v in r["violations"]]
@@ -2233,7 +2229,7 @@ def check_all(names=None, quick: bool = False,
 
 
 def tier1_check(quick: bool = True) -> dict:
-    """The bench-epilogue view: jsonable, violation rows not objects."""
+    """The quick-profile report, jsonable: violation rows not objects."""
     rep = check_all(quick=quick)
     return {
         "ok": rep["ok"],

@@ -1,95 +1,15 @@
-"""Balancer demonstration on the 8-device virtual CPU rig (VERDICT r2 #4).
+"""The compute path's multi-lane proof on the 8-device virtual CPU rig.
 
-Run as ``python -m cekirdekler_tpu.benchrig`` in a process whose env forces
-``JAX_PLATFORMS=cpu`` + ``--xla_force_host_platform_device_count=8`` (bench.py
-sets this up).  Prints ONE JSON line with:
-
-- the classic per-call rebalance: mandelbrot over 8 devices, whose
-  contiguous row split is NATURALLY skewed (rows crossing the set run the
-  full escape loop; rows far from it exit immediately), so the first equal
-  split is wrong and the balancer must move shares — the range trajectory
-  and convergence iteration are the north-star metric (BASELINE.md);
-- the enqueue-mode sync-point rebalance: ranges pinned between barriers,
-  moved at them from fence-retire benches (core/cores.py barrier()).
+:func:`compute_path_proof` runs in a process whose environment forces
+``JAX_PLATFORMS=cpu`` + ``--xla_force_host_platform_device_count=8``
+(``__graft_entry__.dryrun_multichip`` starts such a child) and returns the
+four facts the "N devices as ONE device" claim rests on.  They are counts,
+trajectories and exactness on CPU lanes, never a time of the device.
 """
 
 from __future__ import annotations
 
-import json
-
 import numpy as np
-
-
-def convergence_sim(ndev: int = 8, step: int = 256) -> dict:
-    """Deterministic convergence of the REAL ``load_balance`` implementation
-    against the actual mandelbrot cost field.
-
-    The live rig below shares ONE host core across 8 virtual devices, so
-    its wall-time benches are scheduler-contention noise — fine for showing
-    direction of movement, useless for a crisp convergence count.  Here the
-    per-chip bench is the exact work in its contiguous slice (host-computed
-    escape counts), which is what a chip's wall time measures on real
-    isolated hardware.  Same code path as production: equal_split →
-    load_balance with history smoothing + continuous carry."""
-    from .core.balance import BalanceHistory, BalanceState, equal_split, load_balance
-    from .workloads import _converged_at, mandelbrot_host
-
-    w = h = 512
-    max_iter = 128
-    img = mandelbrot_host(w, h, -2.0, -1.25, 2.5 / w, 2.5 / h, max_iter)
-    cost = img.astype(np.float64) + 2.0  # per-pixel work ∝ escape iters
-    cum = np.concatenate([[0.0], np.cumsum(cost)])
-    n = w * h
-
-    def run(smooth: bool, adaptive: bool = True, cid: int = 0):
-        """Same config Cores._ranges_for uses: adaptive BalanceState +
-        recency-weighted history by default; adaptive=False is the
-        reference-parity fixed-damping mode.  ``cid`` keys the decision
-        provenance: the four configs are four INDEPENDENT chains (each
-        resets to the equal split), and replay/what-if tooling chains
-        records per cid — one shared id would splice them into a
-        meaningless merged trajectory."""
-        ranges = equal_split(n, ndev, step)
-        hist = BalanceHistory(weighted=adaptive) if smooth else None
-        state = BalanceState() if adaptive else None
-        carry: list[float] | None = None if adaptive else []
-        traj = [list(ranges)]
-        for _ in range(48):
-            offs = np.concatenate([[0], np.cumsum(ranges)]).astype(int)
-            bench = [float(cum[offs[i + 1]] - cum[offs[i]]) for i in range(ndev)]
-            ranges = load_balance(bench, ranges, n, step, hist,
-                                  carry=carry, state=state, cid=cid)
-            traj.append(list(ranges))
-        return traj
-
-    traj = run(smooth=True, cid=0)
-    traj_ns = run(smooth=False, cid=1)
-    traj_parity = run(smooth=True, adaptive=False, cid=2)
-    traj_parity_ns = run(smooth=False, adaptive=False, cid=3)
-
-    # balanced quality: max per-chip work / mean, at first vs final split
-    def imbalance(r):
-        offs = np.concatenate([[0], np.cumsum(r)]).astype(int)
-        work = [cum[offs[i + 1]] - cum[offs[i]] for i in range(ndev)]
-        return float(max(work) / (sum(work) / ndev))
-
-    return {
-        "n_devices": ndev,
-        "iterations_run": len(traj) - 1,
-        # smoothed/unsmoothed run the PRODUCTION config (adaptive damping);
-        # the *_reference_parity keys rerun both with the fixed-0.3-damping
-        # parity mode so cross-round comparisons against r3 numbers (which
-        # predate adaptive damping) have a like-for-like column
-        "convergence_iters_smoothed": _converged_at(traj, step),
-        "convergence_iters_unsmoothed": _converged_at(traj_ns, step),
-        "convergence_iters_smoothed_reference_parity": _converged_at(traj_parity, step),
-        "convergence_iters_unsmoothed_reference_parity": _converged_at(traj_parity_ns, step),
-        "imbalance_first": round(imbalance(traj[0]), 3),
-        "imbalance_final": round(imbalance(traj[-1]), 3),
-        "imbalance_final_unsmoothed": round(imbalance(traj_ns[-1]), 3),
-        "ranges_first": traj[0],
-        "ranges_final": traj[-1],
-    }
 
 
 def compute_path_proof(ndev: int = 8, iters: int = 49) -> dict:
@@ -286,99 +206,3 @@ def compute_path_proof(ndev: int = 8, iters: int = 49) -> dict:
     finally:
         cores.trace_lanes = False
         cr.dispose()
-
-
-def _guard(fn) -> dict:
-    """Artifact resilience: a section failure reports as that section's
-    error, never an empty artifact."""
-    try:
-        return fn()
-    except Exception as e:  # noqa: BLE001 - resilience boundary
-        return {"ok": False, "error": f"{type(e).__name__}: {e}"[:500]}
-
-
-def main() -> None:
-    import jax
-
-    from .utils.jsonsafe import dumps_safe
-
-    if jax.default_backend() != "cpu" or len(jax.devices()) < 8:
-        print(dumps_safe({
-            "ok": False,
-            "error": f"rig not available: backend={jax.default_backend()} "
-                     f"n={len(jax.devices())}",
-        }))
-        return
-
-    from .hardware import platforms
-    from .workloads import run_mandelbrot
-
-    devs = platforms().cpus().subset(8)
-
-    # -- classic path: rebalance every call on measured per-chip times -----
-    res = run_mandelbrot(
-        devs, width=1024, height=1024, max_iter=128,
-        iters=16, warmup=0, local_range=256,
-    )
-    traj = res.ranges_per_iter
-    # sparse trajectory for the artifact: first 4 + last
-    shown = {str(i): traj[i] for i in (0, 1, 2, 3, len(traj) - 1) if i < len(traj)}
-    spread0 = max(traj[0]) - min(traj[0])
-    spreadN = max(traj[-1]) - min(traj[-1])
-
-    # -- enqueue mode: ranges move only at barriers -------------------------
-    from .arrays.clarray import ClArray
-    from .core.cruncher import NumberCruncher
-    from .workloads import mandelbrot_pallas_kernel
-
-    cr = NumberCruncher(devs, mandelbrot_pallas_kernel(interpret=True))
-    n = 1024 * 1024
-    out = ClArray(n, np.float32, name="rig_out", read=False, write=True)
-    vals = (-2.0, -1.25, 2.5 / 1024, 2.5 / 1024, 1024, 128)
-    cr.enqueue_mode = True
-    enq_traj: list[list[int]] = []
-    try:
-        for k in range(12):
-            out.compute(cr, 7101, "mandelbrot", n, 256, values=vals)
-            enq_traj.append(cr.ranges_of(7101))
-            if (k + 1) % 4 == 0:
-                cr.barrier()  # measures per-chip retirement, arms rebalance
-        cr.enqueue_mode = False  # flush
-    finally:
-        if cr.enqueue_mode:
-            cr.enqueue_mode = False
-        cr.dispose()
-    # within a window ranges must hold still; across barriers they may move
-    pinned_within = all(
-        enq_traj[i] == enq_traj[i - 1]
-        for i in range(1, 12)
-        if i % 4 != 0
-    )
-    moved_at_sync = any(
-        enq_traj[i] != enq_traj[i - 1] for i in (4, 8)
-    )
-
-    print(dumps_safe({
-        "ok": True,
-        "n_devices": len(devs),
-        "live_convergence_iters": res.convergence_iters,
-        "live_note": (
-            "live rig shares 1 host core across 8 virtual devices — benches "
-            "are contention-noisy; see convergence_sim for the deterministic "
-            "measurement through the same load_balance code"
-        ),
-        "range_trajectory": shown,
-        "range_spread_first": spread0,
-        "range_spread_last": spreadN,
-        "mpixels_per_sec_rig": round(res.mpixels_per_sec, 2),
-        "convergence_sim": convergence_sim(),
-        "compute_path": _guard(compute_path_proof),
-        "enqueue_pinned_within_window": pinned_within,
-        "enqueue_moved_at_sync": moved_at_sync,
-        "enqueue_ranges_first": enq_traj[0],
-        "enqueue_ranges_last": enq_traj[-1],
-    }))
-
-
-if __name__ == "__main__":
-    main()

@@ -281,11 +281,11 @@ class Cores:
         # observability: windows dispatched, iterations fused, and every
         # disengage with its named reason — a perf regression to the
         # per-iteration path must be attributable, never silent.  The
-        # dict stays as the per-cruncher API (tests and nbody_e2e read
+        # dict stays as the per-cruncher API (tests and /statusz read
         # it); the metrics registry carries the same counts process-wide
-        # (ck_fused_* series) for the uniform Prometheus/artifact export.
-        # Writes hold the scheduler lock / fused mutex; READERS (bench
-        # delta snapshots, /statusz) are reporting-only and tolerate a
+        # (ck_fused_* series) for the uniform Prometheus export.
+        # Writes hold the scheduler lock / fused mutex; READERS (delta
+        # snapshots, /statusz) are reporting-only and tolerate a
         # mid-window value by design — the counters only ever grow.
         # ckcheck: ok reporting-only reads; monotone counters, snapshot semantics
         self.fused_stats: dict[str, Any] = {

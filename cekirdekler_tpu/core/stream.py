@@ -62,11 +62,9 @@ Two kinds of keys, two first-contact rules:
 
 * **No-compute keys** (``has_compute=False`` — the flush drain's pure
   D2H records): nothing to measure serially, so the duplex-probe seed
-  (:meth:`seed_link`, ms/MiB each direction — what
-  ``workloads.measure_stream_overlap(duplex_probe=True)`` measures
-  anyway) drives the model directly; with no seed either, transfers of
-  at least :data:`BOOTSTRAP_BYTES` get :data:`BOOTSTRAP_CHUNKS` chunks
-  and smaller ones stay monolithic.
+  (:meth:`seed_link`, ms/MiB each direction) drives the model directly;
+  with no seed either, transfers of at least :data:`BOOTSTRAP_BYTES` get
+  :data:`BOOTSTRAP_CHUNKS` chunks and smaller ones stay monolithic.
 """
 
 from __future__ import annotations
@@ -214,8 +212,6 @@ class TransferTuner:
         self._last_choice: dict[tuple, int] = {}
         # on_repartition() count — a superset of ck_stream_retune_total,
         # which only the balancer's re-partition path increments
-        # (measure_stream_overlap's deliberate warmup drop rides this
-        # counter too, and subtracts its own baseline when reporting)
         self.retunes = 0
         self._mu = threading.Lock()
 

@@ -5,7 +5,7 @@ chunk: every local variable becomes a ``(B,)`` array, and a ``while`` loop's
 state streams through HBM on EVERY iteration — for iteration-heavy kernels
 (mandelbrot's escape loop) that is HBM-bound and ~4-5x off the pace of a
 hand-tiled Pallas kernel whose state lives in VMEM (ops/mandelbrot.py;
-measured in BENCH_r03's ``codegen_vs_pallas``).
+measured before the chip moved into the sandbox, its records deleted).
 
 This backend closes that gap for kernels whose buffer accesses fall in
 three statically-recognizable classes (discovered by a shape-only probe,
@@ -287,7 +287,8 @@ def _mentions_half(kernel: lang.KernelDef) -> bool:
 
 
 def _routing_veto(acc: _Accesses) -> None:
-    """Measured routing policy (BENCH r4 ``lowering_faceoff``): kernels
+    """Measured routing policy (numbers from before the chip moved into the
+    sandbox; their records are deleted and no ledger cell repeats them): kernels
     whose only non-elementwise accesses are shifted windows run FASTER
     through the XLA lowering (single-pass stencils are HBM-bound; XLA
     fuses the shifts into the consumer loop and across chained dispatches,
@@ -299,7 +300,7 @@ def _routing_veto(acc: _Accesses) -> None:
     if acc.shifts and not acc.uniform:
         raise PallasUnsupported(
             "shift-only kernel routed to the XLA lowering "
-            "(measured faster; see lowering_faceoff)"
+            "(measured faster for single-pass stencils)"
         )
 
 
@@ -418,7 +419,7 @@ def build_kernel_fn_pallas(
     Raises :class:`PallasUnsupported` if the kernel is outside the tile
     subset, the chunk doesn't tile, or the measured routing policy prefers
     the XLA lowering for this access mix (``force=True`` skips the policy
-    veto — used by tests and the faceoff bench to exercise the halo path
+    veto — used by tests and ``chip_smoke.py`` to exercise the halo path
     directly)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu

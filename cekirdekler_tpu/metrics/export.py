@@ -8,7 +8,7 @@ Three consumers, three formats, one source of truth (the registry):
   one line per series, histograms as cumulative ``_bucket{le=...}``
   lines plus ``_sum``/``_count`` (standard ``le`` semantics).
 - ``json_snapshot()`` — the deterministic dict `MetricsRegistry.snapshot`
-  produces, ready to embed in bench artifacts (bench.py does).
+  produces.
 - ``chrome_counter_events()`` — Chrome-trace ``ph: "C"`` counter events
   from sampled series, merged into the span export by
   ``trace.export.to_chrome_trace(..., counters=...)`` so balancer
@@ -62,10 +62,9 @@ def _with_labels(name: str, labels: str, extra: str = "") -> str:
 def prometheus_from_snapshot(snapshot: dict,
                              help_map: dict | None = None) -> str:
     """A :meth:`MetricsRegistry.snapshot` dict in Prometheus exposition
-    format — THE renderer (``prometheus_text`` and the artifact replay
-    in tools/metrics_dump.py both use it, so a live scrape and an
-    artifact re-render are label-for-label identical).  Sorted, so
-    equal snapshots produce byte-equal output."""
+    format — THE renderer (``prometheus_text`` rides it, so a live scrape
+    and a re-rendered stored snapshot are label-for-label identical).
+    Sorted, so equal snapshots produce byte-equal output."""
     help_map = help_map or {}
     lines: list[str] = []
     seen: set[str] = set()
@@ -163,7 +162,7 @@ def prometheus_text(registry: MetricsRegistry | None = None) -> str:
 
 
 def json_snapshot(registry: MetricsRegistry | None = None) -> dict:
-    """Deterministic JSON-able snapshot (bench artifacts embed this)."""
+    """Deterministic JSON-able snapshot."""
     reg = registry if registry is not None else REGISTRY
     return reg.snapshot()
 

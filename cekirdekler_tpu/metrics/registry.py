@@ -7,8 +7,8 @@ counters — balancer shares, driver-queue occupancy, fused
 engage/disengage, transfer bytes, DCN exchange traffic — used to live as
 ad-hoc dicts (``Cores.fused_stats``, ``Worker.benchmarks``) with no
 uniform export.  This registry gives every such number ONE home with
-three exports (``metrics/export.py``): Prometheus text, a JSON snapshot
-(embedded in bench artifacts), and Perfetto counter tracks merged into
+three exports (``metrics/export.py``): Prometheus text, a JSON snapshot,
+and Perfetto counter tracks merged into
 the Chrome-trace export so metrics ride the same timeline as spans.
 
 Design constraints, same discipline as the tracer:
@@ -25,8 +25,8 @@ Design constraints, same discipline as the tracer:
    ~100 ns — fine for per-dispatch/per-transfer granularity; truly hot
    inner loops should aggregate locally and ``inc()`` once per batch.
 3. **Snapshots are deterministic.**  ``snapshot()`` sorts series keys,
-   so two snapshots of the same state serialize identically — the bench
-   artifact diffing in ``tools/regress.py`` depends on it.
+   so two snapshots of the same state serialize identically and can be
+   diffed.
 
 Label model: labels are fixed at metric creation
 (``REGISTRY.counter("ck_upload_bytes_total", lane=0)``) and become part
@@ -218,8 +218,8 @@ class MetricsRegistry:
 
     ``enabled`` ships True — the registry is ALWAYS-ON by design (the
     whole point is noticing regressions nobody was watching for); the
-    off switch exists for overhead-sensitive measurement windows (the
-    marker-overhead bench) and the budget test.  ``sampling`` (off by
+    off switch exists for overhead-sensitive measurement windows and the
+    budget test.  ``sampling`` (off by
     default) additionally records bounded per-metric time series for
     Perfetto counter tracks."""
 
@@ -323,7 +323,7 @@ class MetricsRegistry:
     def snapshot(self) -> dict:
         """Deterministic JSON-able state: series name → value, grouped by
         metric kind, keys sorted.  Two snapshots of identical state
-        serialize identically (regress.py diffs depend on it)."""
+        serialize identically."""
         out: dict = {"counters": {}, "gauges": {}, "histograms": {}}
         for m in self:
             if isinstance(m, Counter):

@@ -9,8 +9,7 @@ transfer floors and history rows it chose it FROM.  This module is that
 record.  Every controller decision in the runtime — ``load_balance``
 (core/balance.py), ``TransferTuner.choose``/``observe``
 (core/stream.py), fused-window engage/disengage (core/cores.py), lane
-health verdict flips and drain advisories (obs/health.py), and the
-bench's scheduler fairness rotation (bench.py) — appends one typed
+health verdict flips and drain advisories (obs/health.py) — appends one typed
 :class:`DecisionRecord` carrying the decision's **complete inputs and
 outputs**, a process-monotone ``seq``, and both clock stamps
 (``perf_counter`` for ordering against the span ring, epoch for
@@ -61,7 +60,7 @@ Design constraints, the flight recorder's exactly:
    path stay full atomic tmp+rename dumps.  A path naming a DIRECTORY
    (or ending in a path separator) resolves to a per-process
    ``ck_decisions_<pid>.jsonl`` inside it — multi-process rigs (DCN
-   jobs, bench's benchrig subprocess) must not last-writer-win one
+   jobs, a launcher's CPU-pinned child) must not last-writer-win one
    file.  Unarmed (unset OR empty), nothing touches disk.
 
 The kind vocabulary is :data:`DECISION_KINDS`; ``tools/ckcheck``'s
@@ -104,7 +103,6 @@ DECISION_KINDS = (
     "fused-disengage",     # core/cores — window refusal/break, named reason
     "health-verdict",      # obs/health — a (lane, signal) verdict flipped
     "drain-advisory",      # obs/health.suggest_drain — lanes named for eviction
-    "scheduler-rotation",  # bench.SectionScheduler — fairness promotion
     "admission",           # serve/admission — one request admitted/rejected
     "coalesce",            # serve/coalescer — one dispatch cycle's batch plan
     "breaker",             # serve/resilience — a circuit breaker transitioned
@@ -145,7 +143,6 @@ CONTEXT_KINDS = (
     "fused-engage",        # depends on live device residency
     "fused-disengage",     # depends on live device residency
     "drain-advisory",      # derived view of the monitor's verdicts
-    "scheduler-rotation",  # derived from on-disk artifact history
     "checkpoint-restore",  # reads the filesystem: provenance, not oracle
     "cache-warmup",        # reads the cache manifest: provenance, not oracle
 )
@@ -274,9 +271,8 @@ class DecisionLog:
         ring, spill buffer, watermark and ``total_recorded`` are saved
         and restored; ``seq`` keeps advancing globally (captured rows
         are renumbered by their consumer).  Process-global like
-        :func:`~.replay._quiesced` — run captures at sync points
-        (bench runs the model check in ``finalize_result``, after
-        every section's workload has completed)."""
+        :func:`~.replay._quiesced` — run captures at sync points, after
+        the workload's last barrier."""
         saved = (self._ring, self._spill, self._spill_seen, self._total,
                  self.enabled)
         scratch: deque[DecisionRecord] = deque(maxlen=self._cap)
@@ -305,7 +301,7 @@ class DecisionLog:
         empty = unarmed).  A DIRECTORY (existing, or a value ending in
         a path separator) resolves to ``ck_decisions_<pid>.jsonl``
         inside it — the postmortem pattern: N processes sharing one
-        armed environment (a DCN job, bench's benchrig subprocess)
+        armed environment (a DCN job, a launcher's CPU-pinned child)
         must each keep their own log, not last-writer-win one file."""
         path = os.environ.get(DECISION_LOG_ENV)
         if not path:

@@ -275,9 +275,9 @@ class HealthMonitor:
         g, peak = pair
         score = float(verdict_score(self._lane_verdict_locked(lane)[0]))
         g.set(score)
-        # the high-water mark never decreases: later monitors (a fresh
-        # Cores per bench section) must not erase an earlier section's
-        # degradation from the process-wide artifact view
+        # the high-water mark never decreases: a later monitor (a fresh
+        # Cores in the same process) must not erase an earlier one's
+        # degradation from the process-wide view
         if score > peak.value:
             peak.set(score)
 
@@ -397,11 +397,10 @@ def registry_health_summary(snapshot: dict | None = None) -> dict:
     """Per-lane verdicts recovered from the ``ck_lane_health`` (current)
     and ``ck_lane_health_peak`` (process-lifetime high-water) gauges in
     a registry snapshot (live registry when None) — the process-wide
-    view that survives individual ``Cores`` disposal.  ``bench.py``
-    embeds this as the artifact ``health`` block: ``worst``/``healthy``
+    view that survives individual ``Cores`` disposal.  ``worst``/``healthy``
     describe the run's END state, ``worst_seen`` whether ANY lane
     degraded at any point during the whole run (the peak gauge is
-    monotone, so a later section's fresh monitor cannot erase it)."""
+    monotone, so a later ``Cores``' fresh monitor cannot erase it)."""
     if snapshot is None:
         snapshot = REGISTRY.snapshot()
     lanes: dict = {}

@@ -42,7 +42,7 @@ rig):
 
 Replays run "quiesced": the global DECISIONS/FLIGHT recorders are
 disabled around re-execution so replaying a log never re-records it
-(and an in-process bench verify cannot pollute the artifact's rings).
+(and an in-process verify cannot pollute the live rings).
 """
 
 from __future__ import annotations
@@ -61,7 +61,6 @@ __all__ = [
     "explain_latest",
     "explain_rid",
     "convergence_summary",
-    "bench_decisions_summary",
     "decisionz_payload",
     "verify_counterexample",
     "save_counterexample",
@@ -124,7 +123,7 @@ _quiesce_saved: tuple | None = None
 def _quiesced():
     """Disable the global recorders around a replay: re-executing
     recorded decisions must not re-record them (or emit flight events
-    into a live ring mid-bench).
+    into a live ring mid-run).
 
     Depth-counted under a lock so OVERLAPPING replays (two threads, or
     whatif nesting simulate_balance) restore the flags only at the
@@ -133,8 +132,7 @@ def _quiesced():
     process-GLOBAL by design (the enabled flags are the hot-path
     attribute reads and must stay lock-free): decisions other live
     threads make DURING a replay window are not recorded, so run
-    verify at sync points — bench runs it in ``finalize_result``,
-    after every section's workload has completed."""
+    verify at sync points, after the workload's last barrier."""
     global _quiesce_depth, _quiesce_saved
     from .flight import FLIGHT
 
@@ -571,8 +569,7 @@ def replay_record(row) -> dict:
 
 
 def verify_records(records, max_divergences: int = 8) -> dict:
-    """Replay-verify a whole log (the ``ckreplay verify`` engine and
-    bench.py's in-process epilogue pass).
+    """Replay-verify a whole log (the ``ckreplay verify`` engine).
 
     Returns ``{"ok", "records", "replayed", "skipped", "per_kind",
     "first_divergence", "divergences"}``.  ``ok`` is True when every
@@ -1004,7 +1001,7 @@ def explain_rid(records, rid: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# summaries (bench artifact + /decisionz)
+# summaries (/decisionz)
 # ---------------------------------------------------------------------------
 
 def convergence_summary(records) -> dict:
@@ -1040,31 +1037,6 @@ def convergence_summary(records) -> dict:
             "final_ranges": list(last.get("ranges", ())),
         }
     return out
-
-
-def bench_decisions_summary(records=None) -> dict:
-    """The bench artifact's ``decisions`` block: per-kind counts, the
-    per-cid convergence view, and the in-process replay-verify verdict
-    (``replay_ok`` — ``tools/regress.py`` hard-fails an artifact that
-    carries ``false``: behavior drift in the balancer becomes a
-    sentinel failure, not a silent perf mystery)."""
-    rows = _rows(records if records is not None else DECISIONS.snapshot())
-    counts: dict = {}
-    for r in rows:
-        counts[r["kind"]] = counts.get(r["kind"], 0) + 1
-    verdict = verify_records(rows)
-    return {
-        "counts": counts,
-        "total_recorded": DECISIONS.total_recorded,
-        "rebalances": counts.get("load-balance", 0),
-        "convergence": convergence_summary(rows),
-        "replay_ok": verdict["ok"],
-        "replay": {
-            "replayed": verdict["replayed"],
-            "skipped": verdict["skipped"],
-            "first_divergence": verdict["first_divergence"],
-        },
-    }
 
 
 def decisionz_payload(recent: int = 64) -> dict:

@@ -30,8 +30,8 @@ Everything below the recorder is PURE (ckmodel purity-linted):
 :func:`fold_phases` folds an event list into per-request records,
 :func:`tail_anatomy` decomposes p50/p95/p99 into per-phase
 milliseconds with the explicit coverage fraction,
-:func:`phase_fracs` derives the regress-watched
-``serve_p99_queue_frac`` / ``serve_p99_device_frac``,
+:func:`phase_fracs` derives a record's queue and device wall
+fractions (``tools/loadgen.py``'s ``p99_queue_frac`` / ``p99_device_frac``),
 :func:`request_chrome_events` renders per-request Perfetto tracks
 (merged into ``unified_chrome_trace`` / ``gather_cluster``), and
 :func:`anatomy_table` renders the table ``tools/loadgen.py`` prints
@@ -88,8 +88,8 @@ REQ_EVENT_KINDS = (
 #: ``rerouted`` hop is NOT terminal — the chain continues elsewhere).
 TERMINAL_KINDS = ("resolved", "failed")
 
-#: The phases that count as "time spent waiting to run" for the
-#: regress-watched ``serve_p99_queue_frac`` (see :func:`phase_fracs`).
+#: The phases that count as "time spent waiting to run" in
+#: :func:`phase_fracs`' ``queue_frac``.
 QUEUE_PHASES = ("admitted", "queued", "coalesce-wait")
 
 
@@ -277,9 +277,7 @@ def tail_anatomy(records, pcts=(50, 95, 99)) -> dict:
 
 
 def phase_fracs(record) -> dict:
-    """PURE: one record's queue/device wall fractions — the
-    regress-watched ``serve_p99_queue_frac`` /
-    ``serve_p99_device_frac`` oracles (queue = the
+    """PURE: one record's queue/device wall fractions (queue = the
     :data:`QUEUE_PHASES` sum; device = the ``device`` phase)."""
     rec = record or {}
     wall = float(rec.get("wall_s") or 0.0)

@@ -5,8 +5,8 @@ to a vectorized ``lax.while_loop`` over the whole launch chunk — every
 iteration streams the full chunk's state. This Pallas version tiles the
 flat pixel range into VMEM blocks on a 1-D grid: each program holds one
 (rows, 128) block in registers/VMEM for its entire ``fori_loop``, so orbit
-state never round-trips HBM and the VPU runs at full tilt.  This is the
-hot op behind bench.py (BASELINE.md: Mpixels/sec is the headline metric).
+state never round-trips HBM and the VPU runs at full tilt.  The
+benchmark's ``mandelbrot_balance_4chip`` cell runs it through ``compute()``.
 """
 
 from __future__ import annotations

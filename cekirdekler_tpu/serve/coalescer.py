@@ -14,8 +14,7 @@ by ``ckreplay verify``.  Ordering rules, pinned by test:
 
 1. **Fairness promotions first.**  A group that lost the pick
    :data:`STARVE_ROUNDS` (2) consecutive planning rounds is promoted to
-   the FRONT of the order — the SectionScheduler starvation rule
-   (bench.py, r10) generalized from bench sections to request groups.
+   the FRONT of the order.
    Promotion order is LONGEST-starved first; only equal-streak ties
    share the head slot by round-count rotation.  (The r10-era
    whole-list rotation anchored on ``round % len(streak)`` let
@@ -39,8 +38,7 @@ from __future__ import annotations
 __all__ = ["plan_coalesce", "STARVE_ROUNDS", "MODEL_INVARIANTS"]
 
 #: Consecutive lost rounds that promote a group to the front of the
-#: plan (the SectionScheduler's "no section starves more than 2
-#: consecutive rounds" guarantee, applied to request groups).
+#: plan ("no group starves more than 2 consecutive rounds").
 STARVE_ROUNDS = 2
 
 #: Machine-checked temporal invariants of the coalescing plan (the
@@ -51,8 +49,8 @@ STARVE_ROUNDS = 2
 #: group leaves the table) and proves each of these over every
 #: reachable state.  The starvation bound is capacity-aware: with
 #: ``max_picks`` ≥ the promotion streak size every promoted group
-#: dispatches immediately (the r10 SectionScheduler guarantee,
-#: STARVE_ROUNDS consecutive losses at most); under a tighter
+#: dispatches immediately (STARVE_ROUNDS consecutive losses at
+#: most); under a tighter
 #: ``max_picks`` the rotation shares the head slot, so a group waits
 #: at most the streak it shares — STARVE_ROUNDS + (groups − 1) total.
 MODEL_INVARIANTS = (

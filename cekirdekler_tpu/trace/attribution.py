@@ -61,8 +61,7 @@ def split_fence_benches(
 
 def union_ms(intervals: list[tuple[float, float]]) -> float:
     """Length of the union of (start, end) second-intervals, in ms —
-    the wall-coverage reduction shared by the report below and external
-    residue accounting (workloads._nbody_attribution)."""
+    the wall-coverage reduction of the report below."""
     if not intervals:
         return 0.0
     intervals.sort()

@@ -52,7 +52,7 @@ FULL = {
     "mandel_wh": 2048, "mandel_max_iter": 256, "local_range": 256,
     "mandel_per_call": 4, "mandel_window": 32, "mandel_marker_window": 8,
     "nbody_n": 8192, "nbody_iters": 150, "nbody_window": 50,
-    # stage 2 — 256 MiB per array (bench.py's HBM-stream size): not a cache
+    # stage 2 — 256 MiB per array: not a cache
     "stream_n": 1 << 26, "stream_tuner_runs": 3,
     # stage 3 — the examples/wave_equation.py stage
     "wave_pushes": 40,
@@ -297,7 +297,7 @@ def stage_compute(devices, sizes) -> list[dict]:
             f"hand Pallas interpret={not on_chip}", want, 7104),
     ]
 
-    # n-body: the workloads.nbody_e2e shape — balanced over >= 2 lanes
+    # n-body: the benchmark cells' shape — balanced over >= 2 lanes
     # (two partition lanes of the chip when there is only one)
     lanes = devices if len(devices) > 1 else devices[0].as_partitions(2)
     n, lr, dt = sizes["nbody_n"], sizes["local_range"], 1e-4

@@ -67,7 +67,7 @@ def _by_rule(findings, rule):
 
 def test_live_tree_is_clean_against_baseline(capsys):
     """THE gate: ckcheck exits 0 on HEAD.  A new concurrency/hot-path/
-    invariant finding anywhere in cekirdekler_tpu/, bench.py, or
+    invariant finding anywhere in cekirdekler_tpu/ or
     tools/ fails tier-1 right here with the finding printed."""
     rc = ckcheck_main([])
     out = capsys.readouterr().out
@@ -498,28 +498,3 @@ def test_json_safe_sanitizes_everything():
     # finite floats pass through untouched
     assert json_safe({"x": 1.5}) == {"x": 1.5}
     assert math.isfinite(json.loads(dumps_safe({"v": 2.25}))["v"])
-
-
-def test_bench_artifact_print_is_strict(capsys):
-    """bench.py's one-JSON-line contract survives an inf/numpy payload
-    (pre-fix: TypeError killed the artifact or `Infinity` corrupted
-    it)."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "ck_bench_jsontest", os.path.join(ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    bench._print_artifact({
-        "value": float("inf"),
-        "np": np.float64("nan"),
-        "headline": {"k": np.int64(3)},
-    })
-    out = capsys.readouterr().out.strip()
-
-    def reject(_):
-        raise AssertionError("artifact line is not strict JSON")
-
-    doc = json.loads(out, parse_constant=reject)
-    assert doc["value"] is None and doc["np"] is None
-    assert doc["headline"]["k"] == 3

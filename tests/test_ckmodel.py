@@ -116,7 +116,7 @@ def test_quick_profile_is_subsecond_and_jsonable():
     assert time.perf_counter() - t0 < 2.0
     assert doc["ok"] is True
     assert doc["states_explored"] > 0
-    json.dumps(doc, allow_nan=False)  # the bench-artifact contract
+    json.dumps(doc, allow_nan=False)  # strict JSON
 
 
 def test_machines_declare_exactly_their_checks():
@@ -1124,59 +1124,6 @@ def test_purity_constants_and_helpers_allowed():
         "    return max(v, _FLOOR_S)\n"
     )
     assert purity.scan_module(src, "m.py", ("trans",), ()) == []
-
-
-# ---------------------------------------------------------------------------
-# 7. bench + regress wiring
-# ---------------------------------------------------------------------------
-
-def _bench():
-    sys.path.insert(0, ROOT)
-    import bench
-
-    return bench
-
-
-def test_bench_artifact_embeds_model_block():
-    bench = _bench()
-    sched = bench.SectionScheduler(100.0, {})
-    result = {"headline": {"mandelbrot_mpix": 1.0}}
-    out = bench.finalize_result(result, sched)
-    assert out["model"]["ok"] is True
-    assert out["model"]["states_explored"] > 0
-    assert set(out["model"]["machines"]) == set(M.MACHINE_NAMES)
-    assert out["headline"]["model_ok"] is True
-    assert out["headline"]["model_states_explored"] == \
-        out["model"]["states_explored"]
-    # tail-order contract intact: model slots in before the
-    # tail-critical block
-    keys = list(out)
-    assert keys[-4:] == ["metrics", "regression",
-                         "null_sections", "headline"]
-    assert keys.index("model") < keys.index("metrics")
-
-
-def test_regress_hard_fails_model_false():
-    import tools.regress as regress
-
-    base = {"path": "b", "headline": {"mandelbrot_mpix": 10.0},
-            "errors": None, "null_sections": None, "sections": None}
-    good = {"path": "c", "headline": {"mandelbrot_mpix": 10.0,
-                                      "model_ok": True},
-            "errors": None, "null_sections": None, "sections": None}
-    assert regress.diff_headlines(base, good)["exit_code"] == 0
-    bad = {"path": "c", "headline": {"mandelbrot_mpix": 10.0,
-                                     "model_ok": False},
-           "errors": None, "null_sections": None, "sections": None}
-    v = regress.diff_headlines(base, bad)
-    assert v["exit_code"] == 3 and not v["ok"]
-    finding = next(f for f in v["findings"]
-                   if f["kind"] == "model-drift")
-    assert "ckmodel" in finding["reason"]
-    # absent (pre-model artifact) passes
-    legacy = {"path": "c", "headline": {"mandelbrot_mpix": 10.0},
-              "errors": None, "null_sections": None, "sections": None}
-    assert regress.diff_headlines(base, legacy)["exit_code"] == 0
 
 
 # ---------------------------------------------------------------------------

@@ -245,7 +245,7 @@ def test_real_split_anchors_the_simulator(devs):
 
 
 def test_partial_read_fix_is_bit_identical(devs):
-    """Satellite pin (workloads.marker_overhead flag fix): the saxpy
+    """Satellite pin (the partial_read flag fix): the saxpy
     input under partial_read produces bit-identical results to the
     over-broad full read on a real 2-chip split — the H2D saving is
     free."""

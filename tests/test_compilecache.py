@@ -551,7 +551,7 @@ def test_coldstart_trio_smoke(tmp_path):
 
 
 def test_coldstart_section_shape(tmp_path):
-    """coldstart_section carries the watched key + the resilience
+    """coldstart_section carries the headline key + the resilience
     rider without re-running anything resilience-shaped."""
     sec = coldstart.coldstart_section(
         None, resilience={"rejoin_converge_iters": 3, "exact": True},

@@ -12,7 +12,6 @@ must never block an append (maxlen eviction, no lock)."""
 import importlib.util
 import json
 import os
-import sys
 import threading
 import time
 import urllib.request
@@ -266,7 +265,7 @@ def test_armed_spills_append_incrementally_and_keep_evicted_rows(
 
 def test_spill_path_directory_is_per_process(tmp_path, monkeypatch):
     """Review finding: N processes sharing one armed env (a DCN job,
-    bench's benchrig child) must not last-writer-win one file — a
+    a launcher's CPU-pinned child) must not last-writer-win one file — a
     directory value resolves to ck_decisions_<pid>.jsonl inside it."""
     d = str(tmp_path / "logs")
     os.makedirs(d)
@@ -770,65 +769,3 @@ def test_postmortem_v1_files_still_load(tmp_path):
     assert pm["decisions"] == []
     assert pm["spans"][0].kind == "launch"
     assert ckreplay.load_records(p) == []
-
-
-# ---------------------------------------------------------------------------
-# bench artifact + regress gate
-# ---------------------------------------------------------------------------
-
-def _bench():
-    sys.path.insert(0, ROOT)
-    import bench
-
-    return bench
-
-
-def test_bench_artifact_embeds_decisions_and_replay_ok():
-    bench = _bench()
-    if not any(r.kind == "load-balance" for r in DECISIONS.snapshot()):
-        _run_chain(steps=3, cid=77)
-    sched = bench.SectionScheduler(100.0, {})
-    result = {"headline": {"mandelbrot_mpix": 1.0}}
-    out = bench.finalize_result(result, sched)
-    dec = out["decisions"]
-    assert dec["replay_ok"] is True
-    assert dec["rebalances"] >= 1
-    assert dec["counts"].get("load-balance", 0) >= 1
-    assert isinstance(dec["convergence"], dict) and dec["convergence"]
-    cid_rec = next(iter(dec["convergence"].values()))
-    assert {"rebalances", "iterations_to_converge", "settled",
-            "jumped", "final_ranges"} <= set(cid_rec)
-    # the verdict rides the tail-surviving headline
-    assert out["headline"]["replay_ok"] is True
-    # tail order is preserved (decisions slots in BEFORE metrics, the
-    # tail-critical block still closes the artifact)
-    keys = list(out)
-    assert keys[-4:] == ["metrics", "regression",
-                         "null_sections", "headline"]
-    assert keys.index("decisions") < keys.index("metrics")
-    # the in-process scheduler-rotation decision is declared vocabulary
-    assert all(r.kind in DECISION_KINDS for r in DECISIONS.snapshot())
-
-
-def test_regress_hard_fails_replay_false():
-    regress = _load_tool("ck_regress_dec", "tools/regress.py")
-    base = {"path": "b", "headline": {"mandelbrot_mpix": 10.0},
-            "errors": None, "null_sections": None, "sections": None}
-    good = {"path": "c", "headline": {"mandelbrot_mpix": 10.0,
-                                      "replay_ok": True},
-            "errors": None, "null_sections": None, "sections": None}
-    assert regress.diff_headlines(base, good)["exit_code"] == 0
-    bad = {"path": "c", "headline": {"mandelbrot_mpix": 10.0,
-                                     "replay_ok": False},
-           "errors": None, "null_sections": None, "sections": {
-               "decisions": {"replay": {"first_divergence": {
-                   "seq": 12, "kind": "load-balance"}}}}}
-    v = regress.diff_headlines(base, bad)
-    assert v["exit_code"] == 3 and not v["ok"]
-    finding = next(f for f in v["findings"]
-                   if f["kind"] == "replay-drift")
-    assert "seq" in str(finding["reason"])
-    # absent (pre-provenance artifact) and None both pass
-    legacy = {"path": "c", "headline": {"mandelbrot_mpix": 10.0},
-              "errors": None, "null_sections": None, "sections": None}
-    assert regress.diff_headlines(base, legacy)["exit_code"] == 0

@@ -564,51 +564,6 @@ def test_profilez_endpoint_serves_last_report_and_store(tmp_path):
         srv.close()
 
 
-def test_nbody_e2e_embeds_kernel_profile_block(monkeypatch, cpu_devices):
-    """The bench-artifact contract: with a device capture that produced
-    ops, the nbody attribution carries the per-kernel report AND the
-    roofline/MFU row (faked capture — the CPU rig has no device
-    tracks; the absent path is covered by the CLI/absence tests)."""
-    import cekirdekler_tpu as ct
-    from cekirdekler_tpu import workloads
-    from cekirdekler_tpu.trace import device as dvmod
-
-    rep = DeviceWindowReport(
-        wall_ms=100.0, device_busy_ms=50.0, attributed_ms=50.0)
-    rep.kernels = [dv.KernelDeviceProfile(
-        "nBody", device_ms=50.0, op_count=5, launches=5)]
-
-    class FakeCap:
-        def __init__(self, trace_dir):
-            self.report = rep
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return None
-
-    monkeypatch.setattr(dvmod, "DeviceCapture", FakeCap)
-    # the faked device ops "ran" on CPU lanes, which have no roof: name
-    # the one this contrived capture is judged against
-    from cekirdekler_tpu import hardware
-
-    monkeypatch.setitem(hardware.DEVICE_PEAKS, "cpu", (197.0, 819.0))
-    out = workloads.nbody_e2e(
-        ct.all_devices().cpus().subset(2), n=2048, iters=4, window=2,
-        attribution=True, device_timeline_dir="/tmp/ck_faked")
-    kp = out["attribution"]["kernel_profile"]
-    assert kp["kernels"][0]["kernel"] == "nBody"
-    assert kp["coverage_frac"] == pytest.approx(1.0)
-    rl = kp["roofline"]
-    # n-body is heavily compute-slanted: ~20n/36 flop per byte
-    assert rl["bound"] == "compute"
-    assert rl["intensity_flop_per_byte"] == pytest.approx(
-        20.0 * 2048 / 36.0, rel=1e-3)
-    assert rl["device_ms"] == pytest.approx(50.0)
-    assert out["attribution"]["device_busy_ms"] == pytest.approx(50.0)
-
-
 def test_plan_signature_blocks_component():
     from cekirdekler_tpu.core.stream import chunk_plan, plan_signature
     from cekirdekler_tpu.core.worker import _ladder

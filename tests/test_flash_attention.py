@@ -1,6 +1,7 @@
 """Flash attention (ops/flash_attention.py) vs the dense reference: values
 and gradients must agree; causal masking and uneven Tq/Tk supported.
-Runs in Pallas interpret mode on the rig; compiled on TPU via bench/tools."""
+Runs in Pallas interpret mode on the rig; compiled on the TPU by
+``chip_smoke.py``'s kernel stage."""
 
 import numpy as np
 import pytest
@@ -250,8 +251,8 @@ def test_flash_bf16_default_grad_agreement(T):
         float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-9))
         for a, b in zip(gf, gd)
     )
-    # 2e-2: the SAME regression gate bench.py applies (measured ~3e-3
-    # here; the documented trade is ~1e-2, the gate leaves rig headroom)
+    # 2e-2: measured ~3e-3 here; the documented trade is ~1e-2, the gate
+    # leaves rig headroom
     assert rel < 2e-2, f"bf16 default-path grads diverged: rel={rel:.2e}"
 
 

@@ -316,29 +316,6 @@ def test_window_report_rolls_up_per_lane_kind():
     assert window_report(spans, t0, t0 + 0.25).per_lane_kind == {}
 
 
-def test_nbody_attribution_accepts_lane_kinds():
-    """workloads._nbody_attribution forwards Cores.lane_kinds into the
-    report: the per_lane_kind_ms block names each kind.  probe_devs is
-    None on purpose — the single-lane interference probe fails closed
-    and must not block the per-kind rollup."""
-    from cekirdekler_tpu.workloads import _nbody_attribution
-
-    t0 = 50.0
-    spans = [
-        Span(kind="launch", t0=t0, t1=t0 + 0.010, cid=5, lane=0, tag=None),
-        Span(kind="launch", t0=t0, t1=t0 + 0.002, cid=5, lane=1, tag=None),
-    ]
-    out = _nbody_attribution(
-        spans, t0, t0 + 0.02, wall=0.02, iters=4, lanes=2,
-        probe_devs=None, n=64, dt=0.01, local_range=32, window=2,
-        probe_iters=1, lane_kinds=["tpu-emu", "cpu"])
-    blk = out["per_lane_kind_ms"]
-    assert set(blk) == {"tpu-emu", "cpu"}
-    assert blk["tpu-emu"]["ms"] >= blk["cpu"]["ms"]
-    assert blk["tpu-emu"]["lanes"] == [0]
-    assert "error" in out["lane_interference"]
-
-
 # ---------------------------------------------------------------------------
 # the hetero_sweep bench section (small-n smoke)
 # ---------------------------------------------------------------------------

@@ -4,7 +4,7 @@ XLA lowering (codegen.py), and kernels outside the subset must be rejected
 with PallasUnsupported so the registry falls back.
 
 Runs in Pallas interpret mode on the CPU rig; the compiled-Mosaic path is
-exercised on the real chip by bench.py (codegen_mpix)."""
+exercised on the real chip by ``chip_smoke.py`` and the benchmark's cells."""
 
 import numpy as np
 import pytest

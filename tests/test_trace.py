@@ -401,79 +401,6 @@ def test_ceiling_report_medians_and_spread():
     assert 0.9 <= rep["achieved_vs_ceiling"] <= 1.0
 
 
-def test_measure_stream_overlap_per_rep_ceiling_keys():
-    """Live rig smoke: the overlap measurement carries the per-rep
-    ceiling keys with their structural bounds (the rig's memcpy
-    'transfers' make the absolute numbers meaningless — the BOUNDS and
-    the schema are what the artifact contract pins)."""
-    from cekirdekler_tpu.workloads import measure_stream_overlap
-
-    ov = measure_stream_overlap(
-        _cpus(1), n=1 << 14, blobs=4, reps=2, heavy_iters=2000,
-        duplex_probe=True,
-    )
-    assert ov["n_reps"] == 2
-    assert len(ov["per_rep_achieved_vs_ceiling"]) <= 2
-    avc = ov["achieved_vs_ceiling"]
-    if avc is not None:
-        assert avc <= 1.0 + 1e-9  # the ruler bounds from above, always
-        assert ov["achieved_vs_ceiling_spread"] is not None
-    assert 0.0 <= ov["duplex_capacity"] <= 1.0
-    assert 0.0 <= ov["overlap_ceiling"] <= 1.0
-
-
-# -- nbody e2e attribution ---------------------------------------------------
-
-def test_nbody_e2e_attribution_names_the_factors():
-    from cekirdekler_tpu.workloads import nbody_e2e
-
-    out = nbody_e2e(
-        _cpus(2), n=512, iters=12, window=4, attribution=True,
-        probe_iters=4,
-    )
-    assert out["checked"]
-    att = out["attribution"]
-    f = att["factors"]
-    for name in ("window_fence", "ladder_launch", "upload",
-                 "download_flush", "scheduler_dispatch", "host_gap"):
-        assert name in f, f.keys()
-        assert f[name]["ms"] >= 0.0
-        assert f[name]["frac"] is None or f[name]["frac"] >= 0.0
-    # 12 iters / window 4 → 3 barriers
-    assert f["window_fence"]["count"] == 3
-    assert f["ladder_launch"]["count"] >= 12  # ≥1 dispatch span per iter
-    li = att["lane_interference"]
-    assert "factor" in li, li
-    assert li["factor"] > 0
-    assert li["lanes"] == 2
-    # the attribution run must not leave the global tracer enabled
-    assert not TRACER.enabled
-
-
-def test_fori_chain_bench_fallback_refuses_dceable_feedback():
-    import jax.numpy as jnp
-
-    from cekirdekler_tpu.workloads import fori_chain_bench
-
-    a = jnp.ones((8, 8), jnp.float32)
-    b = jnp.ones((4, 4), jnp.float32)
-
-    # two output leaves that do not pair with the carries: leaves[1:]
-    # would silently DCE out of the loop — must refuse
-    def bad_step(x, y):
-        return x * 1.0001, jnp.sum(y, keepdims=True)
-
-    with pytest.raises(ValueError, match="DCE-able"):
-        fori_chain_bench(bad_step, (a, b), reps=2, trials=1)
-
-    # single output leaf matching a carry: the documented fallback works
-    def ok_step(x, y):
-        return x * 1.0001 + y[:1, :1].sum()
-
-    dt = fori_chain_bench(ok_step, (a, b), reps=2, trials=1)
-    assert dt > 0
-
-
 # -- the profiler bridge: the program's spans on the profiler's clock --------
 
 INC = """

@@ -139,31 +139,11 @@ def test_partial_range_readback_preserves_host_outside_range():
         cr.dispose()
 
 
-def test_measure_stream_overlap_shape():
-    """Overlap instrumentation runs end-to-end and returns a sane record;
-    the >=0.9 target is asserted on real TPU hardware only (bench.py) —
-    on the CPU rig 'transfers' are memcpys and overlap is meaningless."""
-    from cekirdekler_tpu.workloads import measure_stream_overlap
-
-    ov = measure_stream_overlap(_cpus(), n=1 << 14, blobs=4, reps=1)
-    assert set(ov) >= {
-        "t_read_ms", "t_compute_ms", "t_write_ms", "t_pipelined_ms",
-        "t_serial_ms", "overlap_fraction", "sample_spread",
-    }
-    # the ratio is RAW (unclipped, VERDICT r2 #3) — on the CPU rig where
-    # "transfers" are memcpys it can be far outside [0, 1]; only finiteness
-    # and the serial-sum identity are backend-independent
-    assert np.isfinite(ov["overlap_fraction"])
-    assert ov["t_serial_ms"] >= max(
-        ov["t_read_ms"], ov["t_compute_ms"], ov["t_write_ms"]
-    )
-
-
 def test_pipelined_not_catastrophically_slower_than_plain():
     """Correctness + sanity wall-clock on the CPU rig: the pipelined path
     must stay within 3x of the plain path (the strict 'pipelined beats
-    plain' claim is a device-DMA property, asserted on TPU in bench.py's
-    overlap_fraction)."""
+    plain' claim is a device-DMA property; no ledger cell measures it
+    yet)."""
     import time as _t
 
     from cekirdekler_tpu.arrays.clarray import ClArray

@@ -1,8 +1,7 @@
 """Flash-attention tile sweep CLI: brute-force every LEGAL
 (block_q, block_k) pair at the requested geometry, then pin the block
-autotuner's pick against the sweep optimum (the ``choice_vs_optimum``
-honesty check ``tools/overlap_sweep.py`` established for transfer
-chunks, applied to Pallas tiles — ISSUE 16).
+autotuner's pick against the sweep optimum (``choice_vs_optimum`` —
+ISSUE 16).
 
 Run on the target chip from the repo root:
 

@@ -148,10 +148,10 @@ def repo_config() -> AnalyzerConfig:
 
 
 def _repo_extra_paths() -> list:
-    """bench.py + the standalone tools (invariant-pass coverage); the
+    """The standalone tools (invariant-pass coverage); the
     analyzer's own package is excluded — it lints itself via the
     package scan only when listed here, which it is."""
-    out = [os.path.join(REPO, "bench.py")]
+    out = []
     tools_dir = os.path.join(REPO, "tools")
     for fn in sorted(os.listdir(tools_dir)):
         if fn.endswith(".py"):
@@ -218,10 +218,9 @@ RULE_DOCS = {
         "telemetry still allocates per call.  Guard the site with "
         "`if TRACER.enabled:` / `if FLIGHT.enabled:`."),
     "headline-last": (
-        "Artifact dicts must keep 'headline' as the final key: the bench "
-        "driver records only the last 2000 chars of output and regress.py "
-        "recovers the trailing objects from that tail (the "
-        "finalize_result contract)."),
+        "Artifact dicts must keep 'headline' as the final key: a reader "
+        "that keeps only the tail of a tool's output recovers the "
+        "headline from that tail."),
     "undeclared-kind": (
         "A span/flight-event/decision/request-lifecycle kind is "
         "emitted that is not declared in SPAN_KINDS / EVENT_KINDS / "

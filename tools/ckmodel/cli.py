@@ -27,8 +27,8 @@ Usage::
     python -m tools.ckmodel --save-trace DIR      # spill traces
     python -m tools.ckmodel --update-baseline [--allow-grow]
 
-``CK_MODEL_DEPTH`` is the environment form of ``--depth`` (the bench
-rig exports it to deepen tier-1 bounds without editing CI).
+``CK_MODEL_DEPTH`` is the environment form of ``--depth`` (deepens
+tier-1 bounds without editing CI).
 """
 
 from __future__ import annotations

@@ -51,7 +51,7 @@ DEFAULT_BASELINE = os.path.join(
 
 #: What the corpus scan covers.  tests/ is deliberately EXCLUDED: the
 #: differential-oracle corpus there plants unsafe kernels on purpose.
-SCAN_ROOTS = ("cekirdekler_tpu", "examples", "bench.py")
+SCAN_ROOTS = ("cekirdekler_tpu", "examples")
 
 if REPO not in sys.path:  # direct-script invocation
     sys.path.insert(0, REPO)

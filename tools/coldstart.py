@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""Cold-start vs cache-warm first-call latency: the bench's
-``cold_start`` section and a standalone CLI (ISSUE 18).
+"""Cold-start vs cache-warm first-call latency: a standalone CLI over
+:func:`coldstart_section` (ISSUE 18).
 
 Three SUBPROCESS incarnations per workload, each a fresh interpreter
 (process-cold is a process property — it cannot be measured in-process):
@@ -19,19 +19,18 @@ Three SUBPROCESS incarnations per workload, each a fresh interpreter
 - **warm** — same placement, ``warm_from_disk`` precompiles the full
   predicated launch ladder BEFORE traffic, then times the same first
   batch.  ``cold_start_warm_speedup = cold.first / warm.first`` is the
-  regression-watched headline (higher is better).
+  headline (higher is better).
 
 Every incarnation is a child PINNED to the CPU backend
 (``JAX_PLATFORMS=cpu``) and says so in its row: a chip belongs to one
-process, and the usual parent (bench.py) holds it.  The numbers are
+process, and a parent that has touched jax holds it.  The numbers are
 host-CPU compile walls, never device metrics.
 
 Exactness gate: all three incarnations hash their result arrays —
 the cache must be bit-invisible (``exact`` is False otherwise, and the
-speedup is withheld from the watched key).  ``rejoin_converge_iters``
-from the resilience section rides along in the same artifact so the
-two autoscale numbers (rejoin convergence, rejoin compile cost) are
-read side by side.
+speedup is withheld).  A ``resilience_section`` result handed in
+contributes its ``rejoin_converge_iters``, so the two autoscale numbers
+(rejoin convergence, rejoin compile cost) are read side by side.
 
 Workloads: the n-body ladder (``workloads.NBODY_SRC`` through
 ``compute_fused_batch`` — the serving tier's coalesced entry) is the
@@ -245,14 +244,14 @@ def coldstart_section(devices=None, resilience=None, n: int = 4096,
                       local_range: int = 256, iters: int = 4,
                       seq: int = 256, include_flash: bool = True,
                       cache_root: str | None = None) -> dict:
-    """bench.py's ``cold_start`` section: process-cold vs cache-warm
-    first-call latency for the n-body (headline) and flash ladders.
+    """Process-cold vs cache-warm first-call latency for the n-body
+    (headline) and flash ladders.
 
-    ``devices`` is accepted for section-signature uniformity but the
-    measurements are subprocess-scoped — a fresh interpreter per
-    incarnation is the point.  ``resilience`` (the resilience section's
-    result dict, when the bench already ran it) contributes
-    ``rejoin_converge_iters`` to the same artifact."""
+    ``devices`` is accepted and ignored: the measurements are
+    subprocess-scoped — a fresh interpreter per incarnation is the
+    point.  ``resilience`` (``tools/resilience.resilience_section``'s
+    result dict) contributes ``rejoin_converge_iters`` to the same
+    report."""
     del devices  # children own their device discovery
     root = cache_root or tempfile.mkdtemp(prefix="ck_coldstart_")
     own_root = cache_root is None
@@ -265,8 +264,8 @@ def coldstart_section(devices=None, resilience=None, n: int = 4096,
             shutil.rmtree(root, ignore_errors=True)
     out = {
         "platform": "cpu (pinned children; host compile walls)",
-        # the watched key: n-body only — the flash path's speedup is
-        # tuner/interpret-mode dependent and reported, not watched
+        # the headline: n-body only — the flash path's speedup is
+        # tuner/interpret-mode dependent and reported beside it
         "cold_start_warm_speedup": nbody.get("warm_speedup"),
         "rejoin_converge_iters": (
             resilience.get("rejoin_converge_iters")

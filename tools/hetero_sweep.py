@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Heterogeneous-lane sweep: {fast-only, slow-only, mixed} at equal
-total range — the bench's ``hetero`` section and a standalone CLI
+total range — a standalone CLI over :func:`hetero_section`
 (ISSUE 20).
 
 The paper's headline feature is treating N *unequal* devices as ONE
@@ -31,12 +31,11 @@ fault (transfers run ``skew``× slower, proportional to measured wall,
 so the balancer holds the skewed split), and the headline walls come
 from the rate MODEL applied to each arm's actual converged split:
 ``wall_model = max_i(range_i / rate_i)``.  That model is deterministic
-— same split, same number — which is what a regression-watched key
-needs.  Measured walls ride along for reference.  On a rig with real
-accelerators the same arms run un-emulated and the measured walls are
-the artifact of record.
+— same split, same number.  Measured walls ride along for reference.
+On a rig with real accelerators the same arms run un-emulated and the
+measured walls are the result of record.
 
-Headline (watched by tools/regress.py, exactness-gated)::
+Headline (exactness-gated)::
 
     hetero_speedup_vs_best_homog = best_homog_wall / mixed_wall
 
@@ -196,7 +195,7 @@ def _model_wall(split, rates) -> float:
 def hetero_section(devices=None, n: int = 262144, local_range: int = 256,
                    iters: int = 6, skew: float = 8.0,
                    spill: str | None = None) -> dict:
-    """bench.py's ``hetero`` section: the four-arm sweep + the pinned
+    """The four-arm sweep + the pinned
     model comparison + the per-lane-kind attribution rollup."""
     from cekirdekler_tpu.hardware import platforms, rate_prior
     from cekirdekler_tpu.obs.decisions import DECISIONS
@@ -276,8 +275,7 @@ def hetero_section(devices=None, n: int = 262144, local_range: int = 256,
                              else "slow_only")
     speedup = (round(best_homog / walls["mixed"], 3)
                if walls["mixed"] > 0 else None)
-    # the watched key: minted ONLY under the exactness gate — a digest
-    # mismatch starves the regress trajectory instead of feeding it a
+    # the headline: minted ONLY under the exactness gate — never a
     # number whose results differ
     out["hetero_speedup_vs_best_homog"] = speedup if exact else None
 

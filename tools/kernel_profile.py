@@ -162,7 +162,7 @@ def _maybe_store(rep, args, shape, blocks) -> None:
 
 
 def _tuner_vs_best(store, best) -> str:
-    """The per-key honesty column (the overlap_sweep
+    """The per-key honesty column (``tools/block_sweep.py``'s
     ``choice_vs_optimum`` idiom): what the block tuner would ENGAGE for
     this key — store-seeded, clamped to the legal tile grid — next to
     the store's own best row, so a tuner that cannot cash in a

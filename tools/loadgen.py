@@ -24,15 +24,16 @@ acceptance names — and verifies the workload bit-exactly (every
 signature's array must equal its completed-request count; the inc
 kernel makes lost/duplicated requests integer-visible).
 
-``bench.py``'s ``serving`` section runs :func:`loadgen_section` (closed
-+ open) and mints the four headline keys ``tools/regress.py`` watches.
+:func:`loadgen_section` (``--mode both``) is one closed run, one open run
+and the chaos sub-run with the headline floats hoisted; no ledger cell
+drives it yet.
 
 ``--fabric N`` shards the front-end: the same closed-loop workload runs
 against a :class:`~cekirdekler_tpu.serve.ServeFabric` of N member
 shards (docs/SERVING.md, "Cluster fabric") and, for ``--mode chaos``,
 a seeded mid-run member kill whose in-flight requests must re-route
-onto the survivors bit-exactly (:func:`run_fabric_chaos`).  bench.py's
-``serving_fabric`` section runs :func:`fabric_section`.
+onto the survivors bit-exactly (:func:`run_fabric_chaos`);
+:func:`fabric_section` is both beside the single-frontend baseline.
 """
 
 from __future__ import annotations
@@ -90,8 +91,8 @@ def _percentile(sorted_vals: list, q: float) -> float:
 def _run_anatomy(t_wall0: float) -> dict:
     """Fold this run's request-lifecycle events (obs/reqtrace.py) into
     the tail-anatomy block every run result carries: the p50/p95/p99
-    per-phase decomposition plus the p99 queue/device fractions the
-    bench headline keys hoist.  The recorder ring is process-global, so
+    per-phase decomposition plus the p99 queue/device fractions.
+    The recorder ring is process-global, so
     the fold is WALL-clock-bounded to ``t_wall0`` — earlier runs'
     events (warmups, the chaos control) must not blend in."""
     from cekirdekler_tpu.obs.reqtrace import (
@@ -367,9 +368,8 @@ def run_chaos(devices=None, clients: int = 32, tenants: int = 4,
     - **goodput retained** — chaos goodput / control goodput clears
       ``goodput_floor``.
 
-    ``checked`` is the conjunction; the bench's ``serving`` section
-    mints ``serve_chaos_goodput_frac`` / ``serve_chaos_p99_ms`` from
-    this (tools/regress.py watches both)."""
+    ``checked`` is the conjunction; :func:`loadgen_section` reports
+    ``chaos_goodput_frac`` / ``chaos_p99_ms`` only while it holds."""
     from cekirdekler_tpu.utils.faultinject import FAULTS
 
     # untimed warmup: the ladder compiles are process-global, so
@@ -818,10 +818,9 @@ def run_fabric_chaos(devices=None, fabric: int = 3, clients: int = 64,
     - **goodput retained** — killed-run goodput / control goodput
       clears ``goodput_floor``.
 
-    ``checked`` is the conjunction; bench.py's ``serving_fabric``
-    section mints ``fabric_chaos_goodput_frac`` from this
-    (exactness-gated to None on any violation — tools/regress.py
-    reads that as STARVED, never as a pass)."""
+    ``checked`` is the conjunction; :func:`fabric_section` reports
+    ``fabric_chaos_goodput_frac`` from this, exactness-gated to None on
+    any violation (a None is never a pass)."""
     # untimed warmup: ladder compiles are process-global; without it
     # the control run pays them and the killed run does not
     run_fabric(devices, fabric=fabric, clients=4, tenants=tenants,
@@ -864,7 +863,7 @@ def fabric_section(devices=None, fabric: int = 3, clients: int = 128,
                    requests_per_client: int = 8, n: int = 1 << 13,
                    max_queue_depth: int = 32,
                    gather_window_ms: float = 1.0) -> dict:
-    """bench.py's ``serving_fabric`` section: the SAME pinned-signature
+    """The fabric beside its baseline: the SAME pinned-signature
     closed-loop workload against one frontend (the single-process
     baseline) and against an N-process fabric
     (:func:`run_fabric_mp`), plus the in-process kill-and-reroute
@@ -933,13 +932,12 @@ def fabric_section(devices=None, fabric: int = 3, clients: int = 128,
 def loadgen_section(devices=None, clients: int = 32, tenants: int = 4,
                     signatures: int = 4, requests_per_client: int = 8,
                     rate_rps: float = 400.0) -> dict:
-    """bench.py's ``serving`` section: one closed-loop run (the latency
+    """The serving summary: one closed-loop run (the latency
     keys) + one open-loop run (the goodput key) + one chaos sub-run
     (the resilience keys), with the headline floats hoisted to the top
     level.  The chaos keys are exactness-gated: any chaos-contract
     violation (hang, inexact array, unnamed failure, goodput below the
-    floor) makes them None — the regression sentinel reads that as
-    STARVED, never as a pass."""
+    floor) makes them None, never a pass."""
     closed = run_loadgen(
         devices, clients=clients, tenants=tenants, signatures=signatures,
         requests_per_client=requests_per_client, mode="closed")
@@ -960,8 +958,7 @@ def loadgen_section(devices=None, clients: int = 32, tenants: int = 4,
         "chaos_p99_ms": (chaos["chaos_p99_ms"]
                          if chaos["checked"] else None),
         # the closed run's tail decomposition (obs/reqtrace.py): the
-        # p99 queue/device fractions bench.py hoists to headline keys,
-        # plus the full per-phase anatomy block embedded verbatim
+        # p99 queue/device fractions plus the full per-phase anatomy
         "p99_queue_frac": closed["p99_queue_frac"],
         "p99_device_frac": closed["p99_device_frac"],
         "anatomy": closed["anatomy"],

@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Pretty-print a metrics-registry snapshot: live (drive a small
-instrumented workload in this process), from a bench artifact's
-embedded ``metrics`` block, or polled over HTTP from another process's
-debug server (``--url`` + ``--watch``).
+instrumented workload in this process), or polled over HTTP from another
+process's debug server (``--url`` + ``--watch``).
 
 The registry is process-local, so "live" means THIS process: with
 ``--demo`` the tool runs a short enqueue-window workload on the virtual
@@ -13,8 +12,8 @@ process registered (empty unless you import this from instrumented
 code).
 
 ``--url http://host:port/metrics`` switches the source to a LIVE debug
-server (``Cores.serve_debug`` / ``CK_DEBUG_PORT``) in another process —
-the bench rig's.  With ``--watch N`` the view re-renders every N
+server (``Cores.serve_debug`` / ``CK_DEBUG_PORT``) in another process.
+With ``--watch N`` the view re-renders every N
 seconds as a top-like per-lane table: bytes moved (with per-interval
 rates), fence waits, driver/stream queue depths, the autotuner's chunk
 choice, and the lane-health verdict.
@@ -24,7 +23,6 @@ Usage::
     python tools/metrics_dump.py --demo            # table
     python tools/metrics_dump.py --demo --prom     # Prometheus text
     python tools/metrics_dump.py --demo --json     # JSON snapshot
-    python tools/metrics_dump.py --from-artifact BENCH_r06.json
     python tools/metrics_dump.py --url http://127.0.0.1:8421/metrics \\
         --watch 2                                  # live lane top
 """
@@ -197,7 +195,7 @@ def _lane_view(series: dict, prev: dict | None, dt: float) -> str:
 
 def _watch(url: str, interval: float, count: int, prom: bool) -> int:
     """Poll a live debug-server /metrics endpoint over HTTP (NOT
-    in-process — the whole point is watching the bench rig's process
+    in-process — the whole point is watching another process
     from outside) and re-render.  ``count`` 0 = until interrupted."""
     from cekirdekler_tpu.metrics import parse_prometheus_text
 
@@ -239,9 +237,6 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true", help="JSON snapshot")
     ap.add_argument("--demo", action="store_true",
                     help="run a short instrumented rig workload first")
-    ap.add_argument("--from-artifact", default=None,
-                    help="print the metrics block embedded in a bench "
-                         "artifact instead of the live registry")
     ap.add_argument("--url", default=None,
                     help="poll a live debug-server /metrics endpoint over "
                          "HTTP instead of reading in-process")
@@ -257,28 +252,6 @@ def main(argv=None) -> int:
         ap.error("--watch requires --url (it polls a live debug server)")
     if args.url:
         return _watch(args.url, args.watch or 0.0, args.count, args.prom)
-
-    if args.from_artifact:
-        with open(args.from_artifact) as f:
-            doc = json.load(f)
-        snap = doc.get("metrics")
-        if snap is None and isinstance(doc.get("parsed"), dict):
-            snap = doc["parsed"].get("metrics")
-        if snap is None:
-            print("no metrics block in artifact", file=sys.stderr)
-            return 1
-        if args.prom:
-            # the SAME renderer as the live path, so an artifact
-            # re-render is label-for-label comparable to a scrape
-            from cekirdekler_tpu.metrics import prometheus_from_snapshot
-
-            sys.stdout.write(prometheus_from_snapshot(snap))
-        elif args.json:
-            print(json.dumps(_json_safe(snap), indent=2, sort_keys=True,
-                  allow_nan=False))
-        else:
-            print(_table(snap))
-        return 0
 
     if args.demo:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")

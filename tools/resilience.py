@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""Resilience scenario runner: the bench's ``resilience`` section and a
-standalone CLI (ISSUE 13).
+"""Resilience scenario runner: a standalone CLI over
+:func:`resilience_section` (ISSUE 13).
 
 Three seeded scenarios, all exactness-checked (recovery that corrupts
 results is not recovery):
@@ -104,7 +104,7 @@ def drain_readmit_scenario(devices=None, stall_ms: float = 400.0,
     # windows) — with the detector's deliberately tight 2-sample
     # windows, the healthy lane's baseline can land in the fast regime
     # and spuriously flag, tripping the availability floor (the
-    # balancer's own behavior is covered by its own tests/bench rows)
+    # balancer's own behavior is covered by its own tests)
     cores.fixed_compute_powers = [0.5, 0.5]
     x = ClArray(np.zeros(N_ITEMS, np.float32), name="x")
     x.partial_read = True
@@ -331,9 +331,8 @@ def rejoin_scenario(devices=None, windows: int = 8, kill_after: int = 4,
 
 def resilience_section(devices=None, stall_ms: float = 400.0,
                        windows: int = 8) -> dict:
-    """bench.py's ``resilience`` section: both scenarios, headline
-    floats hoisted to the top level (``drain_recover_ms``,
-    ``rejoin_converge_iters`` — the regression-watched keys)."""
+    """Both scenarios, headline floats hoisted to the top level
+    (``drain_recover_ms``, ``rejoin_converge_iters``)."""
     drain = drain_readmit_scenario(devices, stall_ms=stall_ms)
     rejoin = rejoin_scenario(devices, windows=windows)
     mixed = mixed_drain_scenario(devices, stall_ms=stall_ms)
