@@ -48,6 +48,7 @@ Cores.cs:607-613, preserved under jit).
 
 from __future__ import annotations
 
+import itertools
 import math
 import re
 from dataclasses import dataclass
@@ -187,6 +188,12 @@ class _Ctx:
         self.buf_ctypes: dict[str, str] = {}
         self.stored: set[str] = set()
         self.mask: Any = None  # None == all-active; else bool of self.shape
+        # the lane-UNIFORM conditions we are under (a 0-d bool, None == true):
+        # an ``if`` whose condition is proved the same in every lane, the
+        # break / continue flags of a counted loop.  Kept apart from the lane
+        # mask because a lane-uniform local is merged under this part alone
+        # (_assign): no lane outside the lane mask ever observes it
+        self.umask: Any = None
         self.return_mask: Any = None  # items that already returned
         self.global_size = global_size
         self.local_size = local_size
@@ -205,12 +212,18 @@ class _Ctx:
         self.private: dict[str, int] = {}
         # per-innermost-loop masks: lanes that executed `break` (persist
         # for the loop's remaining iterations) / `continue` (reset per
-        # iteration) — saved and restored by _exec_loop
+        # iteration) — saved and restored by _exec_loop.  In a counted
+        # loop (``counted``) every lane takes an exit together and the two
+        # are 0-d flags
         self.break_mask: Any = None
         self.continue_mask: Any = None
-        # statically-proven lane-uniform locals (set by build_kernel_fn
-        # from _uniform_vars) — drives scalarized uniform-index loads
+        self.counted = False
+        # statically-proven lane-uniform locals (adopt() takes them from
+        # _uniform_vars) — drives scalarized uniform-index loads, scalar
+        # predicates and counted loops
         self.uniform_vars: set[str] = set()
+        # the kernel has a ``return``: no proof, every loop is masked
+        self.returns = False
         # helper functions (lang.FuncDef by name) inlined at call sites
         self.helpers: dict = {}
         # the innermost run-window loop's tables: buffer name -> (loop
@@ -223,10 +236,21 @@ class _Ctx:
         # [rows, 128] view)
         self._rows_cache: dict[tuple[str, bool], tuple[Any, Any]] = {}
 
+    def adopt(self, kernel: KernelDef, uniform_vars: set[str]) -> None:
+        """Take what a build knows of ``kernel`` before its body runs."""
+        self.uniform_vars = uniform_vars
+        self.returns = _contains_return(kernel.body)
+        self.helpers = getattr(kernel, "helpers", {}) or {}
+
     def broadcast_scalar(self, val, dtype):
         """Materialize a scalar as a full work-item vector of this ctx's
         shape (subclasses may force a computed layout)."""
         return jnp.full(self.shape, val, dtype=dtype)
+
+    def any_lane(self, mask):
+        """0-d bool: is any lane of ``mask`` set (the Pallas subclass
+        reduces a float tile: Mosaic reduces no bools)."""
+        return jnp.any(mask)
 
     def force_computed(self, vec):
         """Hook for the Pallas subclass: rewrite a (possibly constant)
@@ -286,15 +310,27 @@ class _Ctx:
         self._rows_cache[(name, overlapping)] = (buf, rows)
         return rows
 
-    def active_mask(self):
-        """Combined current mask (branch mask minus returned / broken /
-        continued items)."""
-        m = self.mask
+    def masks(self) -> tuple:
+        """``(lane, uniform)``: the current mask in its two parts, either
+        None when all-true.  ``lane`` is the branch mask minus returned /
+        broken / continued items; ``uniform`` the 0-d conditions (``umask``
+        and a counted loop's break / continue flags)."""
+        lane, uni = self.mask, self.umask
         for excl in (self.return_mask, self.break_mask, self.continue_mask):
             if excl is not None:
                 inv = jnp.logical_not(excl)
-                m = inv if m is None else jnp.logical_and(m, inv)
-        return m
+                if inv.ndim == 0:
+                    uni = inv if uni is None else jnp.logical_and(uni, inv)
+                else:
+                    lane = inv if lane is None else jnp.logical_and(lane, inv)
+        return lane, uni
+
+    def active_mask(self):
+        """Combined current mask (both parts of :meth:`masks`)."""
+        lane, uni = self.masks()
+        if uni is None:
+            return lane
+        return uni if lane is None else jnp.logical_and(lane, uni)
 
 
 # ---------------------------------------------------------------------------
@@ -967,26 +1003,29 @@ def _exec(ctx: _Ctx, node) -> None:
         # body re-runs via the While, so an inner loop's free-run liveness
         # cannot be derived from the remainder stack alone.  break/continue
         # in the first pass bind to THIS do-while: continue skips the rest
-        # of the pass, break also excludes the lane from the While.
-        saved_bk, saved_cn = ctx.break_mask, ctx.continue_mask
+        # of the pass, break also excludes the lane from the While (all
+        # lanes, by a 0-d flag, where the loop is a counted one).
+        saved = ctx.break_mask, ctx.continue_mask, ctx.counted
         ctx.break_mask = None
         ctx.continue_mask = None
+        ctx.counted = _loop_counted(ctx, node)
         ctx.info["in_loop"] = ctx.info.get("in_loop", 0) + 1
         try:
             _exec_block(ctx, node.body)
         finally:
             ctx.info["in_loop"] -= 1
             first_broke = ctx.break_mask
-            ctx.break_mask, ctx.continue_mask = saved_bk, saved_cn
+            ctx.break_mask, ctx.continue_mask, ctx.counted = saved
         loop = While(cond=node.cond, body=node.body, line=node.line)
         if first_broke is not None:
-            outer = ctx.mask
+            part = "umask" if first_broke.ndim == 0 else "mask"
+            outer = getattr(ctx, part)
             nb = jnp.logical_not(first_broke)
-            ctx.mask = nb if outer is None else jnp.logical_and(outer, nb)
+            setattr(ctx, part, nb if outer is None else jnp.logical_and(outer, nb))
             try:
                 _exec_loop(ctx, loop)
             finally:
-                ctx.mask = outer
+                setattr(ctx, part, outer)
         else:
             _exec_loop(ctx, loop)
         return
@@ -996,9 +1035,17 @@ def _exec(ctx: _Ctx, node) -> None:
                 f"'{'break' if isinstance(node, Break) else 'continue'}' "
                 "outside a loop", line=node.line,
             )
-        m = ctx.active_mask()
-        if m is None:
-            m = jnp.ones(ctx.shape, jnp.bool_)
+        if ctx.counted:
+            # proved to be taken by every lane together: a 0-d flag
+            m = ctx.masks()[1]
+            if m is None:
+                m = jnp.asarray(True)
+        else:
+            m = ctx.active_mask()
+            if m is None:
+                m = jnp.ones(ctx.shape, jnp.bool_)
+            elif m.ndim == 0:
+                m = jnp.broadcast_to(m, ctx.shape)
         if isinstance(node, Break):
             ctx.break_mask = (
                 m if ctx.break_mask is None else jnp.logical_or(ctx.break_mask, m)
@@ -1034,8 +1081,15 @@ def _assign(ctx: _Ctx, target, op: str, value_expr) -> None:
         if name in ctx.env:
             old = ctx.env[name]
             new = _as_dtype(rhs, old.ctype)  # assignment keeps the declared C type
-            m = ctx.active_mask()
             fr = ctx._freerun
+            if name in ctx.uniform_vars:
+                # proved the same in every lane that can observe it, and
+                # assigned only where it was declared (_uniform_vars): the
+                # lanes that the lane mask holds back never read it, and it
+                # stays a 0-d value merged under the uniform conditions
+                m = ctx.masks()[1]
+            else:
+                m = ctx.active_mask()
             if (
                 m is not None
                 and fr is not None
@@ -1082,14 +1136,22 @@ def _exec_if(ctx: _Ctx, node: If) -> None:
         _exec_block(ctx, node.then)  # bare { } block
         return
 
-    outer_mask = ctx.mask
-    cvec = jnp.broadcast_to(cond, ctx.shape) if (not hasattr(cond, "ndim") or cond.ndim == 0) else cond
+    # a condition proved the same in every lane joins the uniform part of
+    # the mask as a 0-d value, any other the lane mask
+    part = "mask"
+    if _expr_uniform(node.cond, ctx.uniform_vars, frozenset(ctx.private)):
+        part, cvec = "umask", _lane0(cond)
+    elif not hasattr(cond, "ndim") or cond.ndim == 0:
+        cvec = jnp.broadcast_to(cond, ctx.shape)
+    else:
+        cvec = cond
+    outer_mask = getattr(ctx, part)
 
     # early-return pattern: if (cond) return;
     then_mask = cvec if outer_mask is None else jnp.logical_and(outer_mask, cvec)
     else_mask = jnp.logical_not(cvec) if outer_mask is None else jnp.logical_and(outer_mask, jnp.logical_not(cvec))
 
-    ctx.mask = then_mask
+    setattr(ctx, part, then_mask)
     # the else branch runs AFTER the then branch in trace order: for a loop
     # inside `then`, reads in `other` are still pending — they must count
     # as "read after the loop" for free-run liveness
@@ -1099,9 +1161,16 @@ def _exec_if(ctx: _Ctx, node: If) -> None:
     finally:
         ctx._after_stack.pop()
     if node.other:
-        ctx.mask = else_mask
+        setattr(ctx, part, else_mask)
         _exec_block(ctx, node.other)
-    ctx.mask = outer_mask
+    setattr(ctx, part, outer_mask)
+
+
+def _lane0(v):
+    """A value proved the same in every lane as a 0-d array (lane 0 of one
+    that still rides as a vector)."""
+    v = jnp.asarray(v)
+    return v[(0,) * v.ndim] if v.ndim else v
 
 
 def _index_reads(node, var: str, out: set[str]) -> set[str]:
@@ -1149,9 +1218,213 @@ def _run_reads(ctx: _Ctx, node, cond_expr, carried_bufs) -> tuple:
                      and t not in carried_bufs)
 
 
+def _exec_pass(ctx: _Ctx, node, body_core: list, step_stmt) -> None:
+    """One pass of a loop's body and step under the masks in place."""
+    _exec_block(ctx, body_core)
+    # C semantics: `continue` jumps to the for-step (which still runs for
+    # continued lanes); `break` skips it too
+    ctx.continue_mask = None
+    if step_stmt is not None:
+        _exec(ctx, step_stmt)
+    if ctx.return_mask is not None:
+        raise KernelLanguageError(
+            "'return' inside a loop is not supported; use the loop condition",
+            line=getattr(node, "line", 0),
+        )
+
+
+# passes of a counted loop's body in one pass of its main loop (chosen on
+# the chip at the n-body cells' two sizes: PERF.md, PR 29)
+_UNROLL = 8
+
+
+def _loop_counted(ctx: _Ctx, node) -> bool:
+    """Does this loop lower to a counted loop on scalars
+    (:func:`_exec_counted`)?  The same test that :func:`_uniform_vars`
+    made of it, on the set it ended with."""
+    return not ctx.returns and not _loop_diverges(
+        node, ctx.uniform_vars, frozenset(ctx.private))
+
+
+def _trip_count(ctx: _Ctx, node):
+    """The passes a counted loop will make, as a 0-d int32, where its
+    syntax gives them: ``for (...; j < B; j += c)`` (also ``<=``, and
+    ``>`` / ``>=`` with ``-=``; ``B`` on either side) with ``j`` an ``int``
+    that only the step assigns, ``c`` a literal, ``B`` an ``int`` nothing
+    in the loop changes, and no ``break``.  None otherwise."""
+    if not isinstance(node, For) or node.step is None or node.cond is None:
+        return None
+    step, cond = node.step, node.cond
+    if isinstance(step, CrementStmt):
+        c = 1 if step.op == "++" else -1
+    elif (isinstance(step, Assign) and step.op in ("+=", "-=")
+          and isinstance(step.value, Num) and step.value.ctype == "int"
+          and step.value.value > 0):
+        c = step.value.value if step.op == "+=" else -step.value.value
+    else:
+        return None
+    if not isinstance(step.target, Var) or not isinstance(cond, BinOp):
+        return None
+    j, body = step.target.name, node.body + [step]
+    flip = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}
+    if isinstance(cond.left, Var) and cond.left.name == j:
+        op, bound = cond.op, cond.right
+    elif isinstance(cond.right, Var) and cond.right.name == j:
+        op, bound = flip.get(cond.op), cond.left
+    else:
+        return None
+    if op not in (("<", "<=") if c > 0 else (">", ">=")):
+        return None
+    if (j not in ctx.uniform_vars or j in _assigned_vars(node.body)
+            or _has_break(node.body)
+            or _vars_read(bound) & (_assigned_vars(body) | _stored_bufs(body))):
+        return None
+    jv, bv = ctx.env[j], _eval(ctx, bound)
+    if jv.ctype != "int" or bv.ctype not in _INT_TYPES or _promote("int", bv.ctype) != "int":
+        return None
+    jv, bv = (jnp.asarray(_num(_as_dtype(v, "int"))) for v in (jv, bv))
+    d = (bv - jv) if c > 0 else (jv - bv)  # the distance left to go
+    c = jnp.int32(abs(c))
+    if op in ("<", ">"):
+        return jnp.where(d > 0, lax.div(d - 1, c) + 1, 0)
+    return jnp.where(d >= 0, lax.div(d, c) + 1, 0)
+
+
+def _exec_counted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
+                  carried_vars: list, carried_bufs: list) -> None:
+    """A loop that every lane leaves on the same pass (:func:`_loop_diverges`
+    says when) as a counted loop on scalars: no active mask in the carry, no
+    reduction of one a pass.  The carried locals of ``ctx.uniform_vars`` ride
+    as 0-d values, so the condition is one and a load at such an index reads
+    its element with it.  The body runs under the mask the loop was ENTERED
+    with: the other carried locals are assigned without a ``where`` a pass,
+    and the lanes outside that mask get their values back by one after the
+    loop (stores keep their mask).  With no lane inside it, or under a false
+    uniform condition, the loop makes no pass at all.
+
+    Where the syntax gives the passes (:func:`_trip_count`) they run
+    ``_UNROLL`` to a pass of the main loop, the rest one by one: the order of
+    every lane's operations is the masked form's, the results are its
+    results to the last bit.  Otherwise the condition is evaluated, on
+    scalars, after every pass.  Run windows (:func:`_run_reads`) are for
+    runs that differ from lane to lane and never meet this path."""
+    lane0, enter = ctx.masks()
+    if lane0 is not None:
+        some = ctx.any_lane(lane0)
+        enter = some if enter is None else jnp.logical_and(enter, some)
+    lane_vars = [k for k in carried_vars if k not in ctx.uniform_vars]
+    var_ctypes = {k: ctx.env[k].ctype for k in carried_vars}
+
+    def carried(k):
+        """A carried local in the form the carry holds it: 0-d if proved
+        uniform, else of the work-item shape (broadcast_scalar: see the
+        masked form)."""
+        val = _num(ctx.env[k])
+        if k not in lane_vars:
+            return _lane0(val)
+        if not hasattr(val, "ndim") or val.ndim == 0:
+            return ctx.broadcast_scalar(val, ctype_to_dtype(var_ctypes[k]))
+        return val
+
+    init_env = {k: carried(k) for k in carried_vars}
+    for k in carried_vars:
+        ctx.env[k] = KVal(init_env[k], var_ctypes[k], None)
+    init_bufs = {k: ctx.bufs[k] for k in carried_bufs}
+    trips = _trip_count(ctx, node)
+
+    def in_loop_state(env_vals, buf_vals, fn):
+        """``fn()`` with the carried state in place of the context's."""
+        saved = (ctx.env, ctx.bufs, ctx.mask, ctx.umask, ctx.return_mask,
+                 ctx.break_mask, ctx.continue_mask, ctx.counted, ctx._freerun,
+                 ctx._rows_cache)
+        ctx.env, ctx.bufs = dict(ctx.env), dict(ctx.bufs)
+        ctx._rows_cache = dict(ctx._rows_cache)
+        saved_stored = set(ctx.stored)
+        ctx.info["in_loop"] = ctx.info.get("in_loop", 0) + 1
+        try:
+            for k in carried_vars:
+                ctx.env[k] = KVal(env_vals[k], var_ctypes[k], None)
+            ctx.bufs.update(buf_vals)
+            ctx._pad_cache.clear()  # buffers swapped to loop tracers
+            # the entered mask's two parts hold for the whole loop: the
+            # lane part is the body's mask, the uniform part (and whether
+            # any lane is inside) is decided once, before the first pass
+            ctx.mask, ctx.umask, ctx.return_mask = lane0, None, None
+            ctx.break_mask = ctx.continue_mask = None  # bind to THIS loop
+            ctx.counted = True
+            ctx._freerun = (lane0, set(lane_vars)) if lane0 is not None else None
+            return fn()
+        finally:
+            ctx.info["in_loop"] -= 1
+            ctx.stored = saved_stored | ctx.stored
+            (ctx.env, ctx.bufs, ctx.mask, ctx.umask, ctx.return_mask,
+             ctx.break_mask, ctx.continue_mask, ctx.counted, ctx._freerun,
+             ctx._rows_cache) = saved
+
+    def cond_of(env_vals, buf_vals):
+        return in_loop_state(
+            env_vals, buf_vals, lambda: _lane0(_truthy(_eval(ctx, cond_expr))))
+
+    def one_pass(env_vals, buf_vals):
+        """``(env, bufs, broke)``: one pass, and its 0-d break flag (None
+        where nothing broke)."""
+        def run():
+            env_keys_before = set(ctx.env.keys())
+            _exec_pass(ctx, node, body_core, step_stmt)
+            out = ({k: carried(k) for k in carried_vars},
+                   {k: ctx.bufs[k] for k in carried_bufs}, ctx.break_mask)
+            for k in set(ctx.env.keys()) - env_keys_before:
+                ctx.private.pop(k, None)  # loop-local declarations scope out
+            return out
+        return in_loop_state(env_vals, buf_vals, run)
+
+    state = (init_env, init_bufs)
+    if trips is not None:
+        if enter is not None:
+            trips = jnp.where(enter, trips, 0)
+
+        def group(_, st):
+            for _ in range(_UNROLL):
+                st = one_pass(*st)[:2]
+            return st
+
+        state = lax.fori_loop(0, lax.div(trips, jnp.int32(_UNROLL)), group, state)
+        state = lax.fori_loop(0, lax.rem(trips, jnp.int32(_UNROLL)),
+                              lambda _, st: one_pass(*st)[:2], state)
+    else:
+        # the flag rides as an int32: Mosaic carries no bool
+        def flag(env_vals, buf_vals, broke=None):
+            go = cond_of(env_vals, buf_vals)
+            if broke is not None:
+                go = jnp.logical_and(go, jnp.logical_not(broke))
+            return go
+
+        def step(st):
+            env_vals, buf_vals, broke = one_pass(*st[1:])
+            return (flag(env_vals, buf_vals, broke).astype(jnp.int32),
+                    env_vals, buf_vals)
+
+        go0 = flag(*state)
+        if enter is not None:
+            go0 = jnp.logical_and(go0, enter)
+        state = lax.while_loop(lambda st: st[0] != 0, step,
+                               (go0.astype(jnp.int32), *state))[1:]
+    env_f, bufs_f = state
+    ctx._pad_cache.clear()
+    for k in carried_vars:
+        val = env_f[k]
+        if lane0 is not None and k in lane_vars:
+            val = jnp.where(lane0, val, init_env[k])
+        ctx.env[k] = KVal(val, var_ctypes[k], None)
+    for k in carried_bufs:
+        ctx.bufs[k] = bufs_f[k]
+        ctx.stored.add(k)
+
+
 def _exec_loop(ctx: _Ctx, node) -> None:
-    """Lower for/while to a vectorized lax.while_loop with a per-item active
-    mask (see module docstring)."""
+    """Lower for/while to a counted loop on scalars where every lane leaves
+    it together (:func:`_exec_counted`), and otherwise to a vectorized
+    lax.while_loop with a per-item active mask (see module docstring)."""
     if isinstance(node, For):
         if node.init is not None:
             _exec(ctx, node.init)
@@ -1165,6 +1438,10 @@ def _exec_loop(ctx: _Ctx, node) -> None:
 
     carried_vars = sorted(_assigned_vars(body) & set(ctx.env.keys()))
     carried_bufs = sorted(_stored_bufs(body) & set(ctx.bufs.keys()))
+    if _loop_counted(ctx, node):
+        _exec_counted(ctx, node, cond_expr, body_core, step_stmt,
+                      carried_vars, carried_bufs)
+        return
     run_var, run_tables = None, []
     if not ctx.pallas:
         run_var, run_tables = _run_reads(ctx, node, cond_expr, carried_bufs)
@@ -1241,7 +1518,8 @@ def _exec_loop(ctx: _Ctx, node) -> None:
     # per-iteration work).  Price: one trailing fully-masked pass before
     # cond_fun sees an all-false mask (and one masked pass for loops never
     # entered) — masked execution has no observable effects.
-    prev0 = outer_mask if outer_mask is not None else jnp.ones(ctx.shape, jnp.bool_)
+    prev0 = (jnp.ones(ctx.shape, jnp.bool_) if outer_mask is None
+             else jnp.broadcast_to(outer_mask, ctx.shape))
 
     def cond_fun(carry):
         prev, _, _ = carry
@@ -1253,6 +1531,7 @@ def _exec_loop(ctx: _Ctx, node) -> None:
         prev, env_vals, buf_vals = carry
         prev = from_carry_mask(prev)
         saved_env, saved_bufs, saved_mask = dict(ctx.env), dict(ctx.bufs), ctx.mask
+        saved_umask, saved_counted = ctx.umask, ctx.counted
         saved_runs, saved_views = ctx.runs, dict(ctx._rows_cache)
         if rows:  # this pass's rows of the loop's run windows
             ctx.runs = {**ctx.runs, **rows}
@@ -1268,7 +1547,8 @@ def _exec_loop(ctx: _Ctx, node) -> None:
                 ctx.bufs[k] = buf_vals[k]
             ctx._pad_cache.clear()  # buffers swapped to loop tracers
             active = jnp.logical_and(prev, eval_cond(env_vals, buf_vals))
-            ctx.mask = active
+            ctx.mask, ctx.umask = active, None  # ``active`` holds both parts
+            ctx.counted = False
             ctx.return_mask = None
             ctx.break_mask = None      # break binds to THIS loop
             ctx.continue_mask = None
@@ -1276,17 +1556,7 @@ def _exec_loop(ctx: _Ctx, node) -> None:
             # skip the where-merge for free-run variables (see above)
             ctx._freerun = (active, freerun) if freerun else None
             env_keys_before = set(ctx.env.keys())
-            _exec_block(ctx, body_core)
-            # C semantics: `continue` jumps to the for-step (which still
-            # runs for continued lanes); `break` skips it too
-            ctx.continue_mask = None
-            if step_stmt is not None:
-                _exec(ctx, step_stmt)
-            if ctx.return_mask is not None:
-                raise KernelLanguageError(
-                    "'return' inside a loop is not supported; use the loop condition",
-                    line=getattr(node, "line", 0),
-                )
+            _exec_pass(ctx, node, body_core, step_stmt)
             new_env = {k: _num(ctx.env[k]) for k in carried_vars}
             new_bufs = {k: ctx.bufs[k] for k in carried_bufs}
             # drop loop-local declarations so carry structure stays stable
@@ -1306,6 +1576,7 @@ def _exec_loop(ctx: _Ctx, node) -> None:
         finally:
             ctx.info["in_loop"] -= 1
             ctx.env, ctx.bufs, ctx.mask = saved_env, saved_bufs, saved_mask
+            ctx.umask, ctx.counted = saved_umask, saved_counted
             ctx.stored = saved_stored | ctx.stored
             ctx.return_mask = saved_rm
             ctx._freerun = saved_fr
@@ -1421,6 +1692,29 @@ def _has_divergent_exit(stmts: list, divergent: bool, uset, private) -> bool:
     return False
 
 
+def _has_break(stmts: list) -> bool:
+    """True if a ``break`` of THIS loop is anywhere in its body."""
+    return any(isinstance(s, Break)
+               or (isinstance(s, If) and (_has_break(s.then)
+                                          or _has_break(s.other)))
+               for s in stmts)
+
+
+def _loop_diverges(node, uset, private) -> bool:
+    """False iff every lane that enters this loop (For, While, DoWhile)
+    provably leaves it on the same pass: its condition is lane-uniform and
+    reads no buffer the loop stores to (a masked store is per lane), and no
+    ``break`` / ``continue`` sits under a divergent condition.  Such a loop
+    lowers to a counted loop on scalars (:func:`_exec_counted`); its body
+    adds no divergence to what surrounds it."""
+    body = node.body + ([node.step] if getattr(node, "step", None) is not None else [])
+    if node.cond is not None and (
+            not _expr_uniform(node.cond, uset, private)
+            or _vars_read(node.cond) & _stored_bufs(body)):
+        return True
+    return _has_divergent_exit(node.body, False, uset, private)
+
+
 def _contains_return(stmts: list) -> bool:
     for s in stmts:
         if isinstance(s, Return):
@@ -1457,10 +1751,20 @@ def _private_array_names(stmts: list, out: set[str] | None = None) -> set[str]:
 
 
 def _uniform_vars(body: list, value_params: set[str]) -> set[str]:
-    """Monotone-poisoning fixed point: start assuming every local is
-    uniform; poison any variable assigned a non-uniform value or assigned
-    under a non-uniform condition (divergent masks make merged values
-    differ per lane); repeat until stable."""
+    """The locals that provably hold the SAME value in every lane that can
+    observe them.  Monotone-poisoning fixed point: start assuming every
+    local is; poison any variable assigned a non-uniform value, or assigned
+    in another REGION than the one it was declared in; repeat until stable.
+
+    A region is a stretch of the kernel that the same lanes execute: the
+    top level, and one more for each branch of an ``if`` under a divergent
+    condition and for the body of a loop that lanes leave on different
+    passes (:func:`_loop_diverges`).  A local declared in a region lives and
+    dies in it (a loop's pass drops its declarations), so only that
+    region's lanes ever read it: assigned there alone, from uniform values,
+    it is the same in all of them, whatever the lanes outside would have
+    made of it.  The lowering relies on exactly this: such a local stays a
+    0-d value that no lane mask is merged into (:func:`_assign`)."""
     # an early `return` folds into a persistent per-lane return-mask that
     # divergently suppresses EVERY later assignment — modeling which
     # suffixes that poisons is subtle, and kernels with early returns are
@@ -1476,6 +1780,8 @@ def _uniform_vars(body: list, value_params: set[str]) -> set[str]:
     changed = True
     while changed:
         changed = False
+        home = dict.fromkeys(value_params, 0)  # name -> region declared in
+        new_region = itertools.count(1).__next__
 
         def poison(name: str) -> None:
             nonlocal changed
@@ -1483,45 +1789,70 @@ def _uniform_vars(body: list, value_params: set[str]) -> set[str]:
                 uset.discard(name)
                 changed = True
 
-        def walk(stmts, divergent: bool) -> None:
+        def walk(stmts, region: int) -> None:
             for s in stmts:
                 if isinstance(s, Decl):
                     for name, init in s.names:
+                        if home.setdefault(name, region) != region:
+                            poison(name)  # one name, two regions' lanes
                         if name in s.arrays:
                             poison(name)  # per-lane stores make stacks diverge
                         elif init is not None and not _expr_uniform(init, uset, private):
                             poison(name)
-                        elif divergent and init is not None:
-                            poison(name)
-                elif isinstance(s, Assign) and isinstance(s.target, Var):
-                    if divergent or not _expr_uniform(s.value, uset, private):
-                        poison(s.target.name)
-                elif isinstance(s, CrementStmt) and isinstance(s.target, Var):
-                    if divergent:
+                elif isinstance(s, (Assign, CrementStmt)) and isinstance(s.target, Var):
+                    if home.get(s.target.name) != region or not (
+                            isinstance(s, CrementStmt)
+                            or _expr_uniform(s.value, uset, private)):
                         poison(s.target.name)
                 elif isinstance(s, If):
-                    d = divergent or not _expr_uniform(s.cond, uset, private)
-                    walk(s.then, d)
-                    walk(s.other, d)
-                elif isinstance(s, For):
-                    d = divergent
-                    if s.init is not None:
-                        walk([s.init], d)
-                    cond_u = s.cond is None or _expr_uniform(s.cond, uset, private)
-                    d = d or not cond_u
-                    inner = s.body + ([s.step] if s.step is not None else [])
-                    # a break/continue under a divergent condition makes
-                    # per-lane trip counts differ: every assignment in the
-                    # loop diverges
-                    d = d or _has_divergent_exit(s.body, d, uset, private)
-                    walk(inner, d)
-                elif isinstance(s, (While, DoWhile)):
-                    d = divergent or not _expr_uniform(s.cond, uset, private)
-                    d = d or _has_divergent_exit(s.body, d, uset, private)
-                    walk(s.body, d)
+                    if _expr_uniform(s.cond, uset, private):
+                        walk(s.then, region)
+                        walk(s.other, region)
+                    else:
+                        walk(s.then, new_region())
+                        walk(s.other, new_region())
+                elif isinstance(s, (For, While, DoWhile)):
+                    inner = s.body
+                    if isinstance(s, For):
+                        if s.init is not None:
+                            walk([s.init], region)
+                        inner = s.body + ([s.step] if s.step is not None else [])
+                    # lanes that leave on different passes make every
+                    # assignment to an outer local in the loop diverge
+                    walk(inner, new_region()
+                         if _loop_diverges(s, uset, private) else region)
 
-        walk(body, False)
+        walk(body, 0)
     return uset
+
+
+def _loop_counts(kernel: KernelDef, uset: set[str]) -> tuple[int, int]:
+    """``(counted, masked)``: how many loops of the kernel, and of the
+    helper functions it can reach, lower each way (a helper's body is
+    inlined with no uniformity facts of its own)."""
+    counted = masked = 0
+    helpers = getattr(kernel, "helpers", {}) or {}
+    returns = _contains_return(kernel.body)
+    seen: set[str] = set()
+    todo = [(kernel.body, uset, frozenset(_private_array_names(kernel.body)))]
+    while todo:
+        node, facts, private = todo.pop()
+        if isinstance(node, (list, tuple)):
+            todo.extend((x, facts, private) for x in node)
+            continue
+        if isinstance(node, (For, While, DoWhile)):
+            if returns or _loop_diverges(node, facts, private):
+                masked += 1
+            else:
+                counted += 1
+        if isinstance(node, Call) and node.name in helpers and node.name not in seen:
+            seen.add(node.name)
+            hb = helpers[node.name].body
+            todo.append((hb, set(), frozenset(_private_array_names(hb))))
+        if hasattr(node, "__dict__"):
+            todo.extend((v, facts, private) for v in vars(node).values()
+                        if isinstance(v, (list, tuple)) or hasattr(v, "__dict__"))
+    return counted, masked
 
 
 def _vars_read(node, out: set[str] | None = None) -> set[str]:
@@ -1636,6 +1967,11 @@ class KernelBuildInfo:
     # a ladder executable (``lowering="ladder"``: the fused window's, repeat
     # mode's) names the build infos of the rung launchers it runs
     rungs: tuple = ()
+    # how the kernel's loops were lowered: as counted loops on scalars
+    # (every lane proved to leave together: _exec_counted) or under a
+    # per-lane active mask
+    loops_counted: int = 0
+    loops_masked: int = 0
 
 
 def hlo_name(*kernel_names: str) -> str:
@@ -1674,12 +2010,12 @@ def build_kernel_fn(
     )
 
     uniform = _uniform_vars(kernel.body, {p.name for p in value_params})
+    info.loops_counted, info.loops_masked = _loop_counts(kernel, uniform)
 
     def fn(offset, arrays: tuple, values: tuple = ()):
         ctx = _Ctx(chunk, jnp.asarray(offset, jnp.int32), global_size, local_size, {})
-        ctx.uniform_vars = uniform
+        ctx.adopt(kernel, uniform)
         ctx.row_gathers = platform == "tpu"
-        ctx.helpers = getattr(kernel, "helpers", {}) or {}
         for p, arr in zip(array_params, arrays):
             ctx.bufs[p.name] = arr
             ctx.buf_ctypes[p.name] = p.ctype

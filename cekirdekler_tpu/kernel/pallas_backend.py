@@ -130,6 +130,9 @@ class _PallasCtx(_Ctx):
     def force_computed(self, vec):
         return self._zero_f32.astype(vec.dtype) + vec
 
+    def any_lane(self, mask):
+        return jnp.sum(mask.astype(jnp.float32)) > 0.0
+
     # -- load/store classification ---------------------------------------
 
     def _uniform_index(self, node: lang.Index) -> bool:
@@ -224,8 +227,7 @@ def _probe(kernel: lang.KernelDef, rows: int, local_size: int, global_size: int,
 
     def run(offset, arrays, values):
         ctx = _PallasCtx(rows, offset, global_size, local_size, {}, record=acc)
-        ctx.uniform_vars = uniform_vars
-        ctx.helpers = getattr(kernel, "helpers", {}) or {}
+        ctx.adopt(kernel, uniform_vars)
         for p, arr in zip(array_params, arrays):
             ctx.bufs[p.name] = arr
             ctx.buf_ctypes[p.name] = p.ctype
@@ -351,8 +353,7 @@ def _tile_kernel(kernel: lang.KernelDef, rows: int, local_size: int,
         out_refs = refs[k + n_tiles + n_halos + n_smem:]
         base = offset_ref[0, 0] + pl_program_id() * rows * LANES
         ctx = _PallasCtx(rows, base, global_size, local_size, {}, halo_h=halo_h)
-        ctx.uniform_vars = uniform_vars
-        ctx.helpers = getattr(kernel, "helpers", {}) or {}
+        ctx.adopt(kernel, uniform_vars)
         for p in array_params:
             ctx.bufs[p.name] = None  # placeholder; real values set below
             ctx.buf_ctypes[p.name] = p.ctype
@@ -464,6 +465,8 @@ def build_kernel_fn_pallas(
         stored_params=list(stored),
         lowering="pallas",
     )
+    info.loops_counted, info.loops_masked = codegen._loop_counts(
+        kernel, uniform_vars)
     grid = rows_total // rows
     scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
     tile_spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))

@@ -110,11 +110,17 @@ class _KernelLauncher(_Launcher):
 def lowering_meta(infos) -> dict:
     """Span metadata naming what was built for the launchers a span ran:
     ``lowering`` (``pallas``, ``xla``, ``python``; several joined by ``+``
-    where a ladder's rungs differ) and, where a TPU build was routed away
-    from Pallas, ``veto`` with the reason.  A ladder executable stands for
-    its rungs."""
+    where a ladder's rungs differ), ``loops`` (``counted:N;masked:M``: how
+    many of the kernel's loops run on a scalar counter and how many under a
+    per-lane mask; joined the same way; no comma, which would end the
+    value in a profiler annotation) and, where a TPU build was routed
+    away from Pallas, ``veto`` with the reason.  A ladder executable stands
+    for its rungs."""
     leaves = [r for i in infos for r in (i.rungs or (i,))]
-    meta = {"lowering": "+".join(sorted({i.lowering for i in leaves}))}
+    meta = {"lowering": "+".join(sorted({i.lowering for i in leaves})),
+            "loops": "+".join(sorted(
+                {f"counted:{i.loops_counted};masked:{i.loops_masked}"
+                 for i in leaves}))}
     vetoes = sorted({i.veto for i in leaves if i.veto})
     if vetoes:
         meta["veto"] = "; ".join(vetoes)

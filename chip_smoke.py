@@ -771,10 +771,16 @@ def stage_kernels(devices, sizes) -> list[dict]:
     # XLA lowering of the same source
     interp = plat != "tpu"
 
-    def backend(src, name, n, arrays, values, label, want=None, tol=0.0):
+    def backend(src, name, n, arrays, values, label, want=None, tol=0.0,
+                counted=0):
         kdef = {k.name: k for k in lang.parse_kernels(src)}[name]
         pl_fn, info = build_kernel_fn_pallas(kdef, n, 256, n,
                                              interpret=interp, force=True)
+        # a uniform loop whose proof fails without a word would still be
+        # right, and three times slower (PERF.md, PR 29)
+        _require(info.loops_counted >= counted,
+                 f"{label}: {info.loops_counted} counted loop(s), "
+                 f"{info.loops_masked} masked; expected {counted} counted")
         arrays = tuple(jax.device_put(a, dev) for a in arrays)
         f = jax.jit(lambda *arrs: pl_fn(0, arrs, values))
         got, cold_s, run_s = first_and_repeat(f, *arrays)
@@ -809,7 +815,7 @@ def stage_kernels(devices, sizes) -> list[dict]:
     v1 = nbody_host_step(pos[0], pos[1], pos[2], zero, zero, zero, 1e-4)
     backend(NBODY_SRC, "nBody", nb, (*pos, zero, zero, zero),
             (np.int32(nb), np.float32(1e-4)), "SMEM uniform gather",
-            want=(*pos, *v1), tol=0.01)
+            want=(*pos, *v1), tol=0.01, counted=1)
 
     # the driver's own entry point (beside this script)
     import __graft_entry__ as graft

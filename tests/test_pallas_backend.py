@@ -380,3 +380,173 @@ def test_bf16_arrays_through_real_pallas_path():
     np.testing.assert_allclose(
         np.asarray(gx[1], dtype=np.float32), np.asarray(gp[1], dtype=np.float32),
         rtol=2e-2, atol=2e-2)
+
+
+# ---------------------------------------------------------------------------
+# counted loops (codegen._exec_counted): a loop every lane provably leaves on
+# the same pass runs on a scalar counter, in both lowerings, against the
+# scalar oracle
+# ---------------------------------------------------------------------------
+
+_LOOP_HEAD = ("__kernel void k(__global float* x, __global float* y, "
+              "__global float* o, int n) {\n    int i = get_global_id(0);\n    ")
+
+# name -> (body, (loops_counted, loops_masked))
+LOOP_CASES = {
+    # the passes come from the syntax: unrolled, with a remainder loop
+    "for_up": ("float a = 0.0f; for (int j = 0; j < n; j++) "
+               "{ a += x[j] * y[i]; } o[i] = a;", (1, 0)),
+    "for_le_step3": ("float a = 0.0f; for (int j = 1; j <= n; j += 3) "
+                     "{ a += x[j] * y[i]; } o[i] = a;", (1, 0)),
+    "for_down_bound_left": ("float a = 0.0f; for (int j = n; 0 < j; j -= 2) "
+                            "{ a += x[j] - y[i]; } o[i] = a;", (1, 0)),
+    "uniform_continue": ("float a = 0.0f; for (int j = 0; j < n; j++) { "
+                         "if (j % 3 == 1) continue; a += x[j] * y[i]; } "
+                         "o[i] = a;", (1, 0)),
+    # the condition is evaluated, on scalars, after every pass
+    "while": ("float a = 0.0f; int k = 0; while (k * k < n) "
+              "{ a += x[k] + y[i]; k += 2; } o[i] = a + (float)k;", (1, 0)),
+    "do_while": ("float a = 0.0f; int k = 0; do { a += x[k] + y[i]; k++; } "
+                 "while (k < n); o[i] = a + (float)k;", (1, 0)),
+    "do_while_breaks_in_first_pass": (
+        "float a = 0.0f; int k = 0; do { a += x[k] + y[i]; k++; "
+        "if (k > 2) break; } while (k < n); o[i] = a + (float)k;", (1, 0)),
+    "only_exit_is_a_uniform_break": (
+        "float a = 0.0f; int k = 0; for (;;) { if (k >= n) break; "
+        "a += x[k] * y[i]; k++; } o[i] = a + (float)k;", (1, 0)),
+    "uniform_float_local_rides_as_a_scalar": (
+        "float s = 0.0f; for (int j = 0; j < n; j++) { s += x[j]; } "
+        "o[i] = s * y[i];", (1, 0)),
+    "uniform_if_in_the_body": (
+        "float a = 0.0f; int m = 0; for (int j = 0; j < n; j++) { "
+        "if (j % 2 == 0) { m = m + j; a += x[m]; } else { a -= y[i]; } } "
+        "o[i] = a + (float)m;", (1, 0)),
+    # under a divergent `if`: the lanes outside keep `a`, the store in the
+    # body stays masked, `j` is the same in every lane inside
+    "under_a_divergent_if": (
+        "float a = y[i]; if (y[i] > 0.0f) { for (int j = 0; j < n; j++) "
+        "{ a += x[j]; o[i] = a; } } y[i] = a;", (1, 0)),
+    "counted_in_a_masked_loop": (
+        "float a = 0.0f; int c = (int)(fabs(y[i]) * 3.0f); int t = 0; "
+        "while (t < c) { for (int j = 0; j < n; j++) { a += x[j] * 0.5f; } "
+        "t++; } o[i] = a;", (1, 1)),
+    "masked_in_a_counted_loop": (
+        "float a = 0.0f; for (int j = 0; j < n; j++) { float z = y[i]; "
+        "int t = 0; while (z < 2.0f && t < 5) { z = z * 1.5f + 0.3f; t++; } "
+        "a += z * x[j]; } o[i] = a;", (1, 1)),
+    "counted_in_a_counted_loop": (
+        "float a = 0.0f; for (int j = 0; j < n; j++) { for (int k = 0; "
+        "k < 3; k++) { a += x[j + k] * y[i]; } } o[i] = a;", (2, 0)),
+    # lanes leave on different passes: the masked form, as before
+    "divergent_break": ("float a = 0.0f; for (int j = 0; j < n; j++) { "
+                        "a += x[i] * y[i]; if (a > 3.0f) break; } o[i] = a;",
+                        (0, 1)),
+    "condition_reads_a_buffer_the_loop_stores_to": (
+        "int k = 0; while (o[0] < 1.0f && k < n) { o[i] = 0.5f; k++; } "
+        "y[i] = (float)k;", (0, 1)),
+}
+
+
+def _nbody_case():
+    from cekirdekler_tpu.workloads import NBODY_SRC
+
+    return {k.name: k for k in lang.parse_kernels(NBODY_SRC)}["nBody"]
+
+
+@pytest.mark.parametrize("lowering", ["xla", "pallas"])
+@pytest.mark.parametrize("case", sorted(LOOP_CASES) + ["nbody"])
+def test_counted_loop_matches_the_oracle(case, lowering):
+    """Each loop form through one lowering against the scalar oracle, at
+    trip counts 0, 1, one that the unroll does not divide and one it does;
+    the build says how it lowered each loop."""
+    import jax
+    import jax.numpy as jnp
+
+    from tests.kernel_oracle import Oracle
+
+    if case == "nbody":
+        kdef, want = _nbody_case(), (1, 0)
+    else:
+        body, want = LOOP_CASES[case]
+        kdef = _kdef(_LOOP_HEAD + body + "\n}\n")
+    N = 256
+    names = [p.name for p in kdef.params if p.is_pointer]
+    if lowering == "pallas":
+        if case == "condition_reads_a_buffer_the_loop_stores_to":
+            with pytest.raises(PallasUnsupported):  # stored AND uniform-read
+                build_kernel_fn_pallas(kdef, N, 64, N, interpret=True)
+            return
+        fn, info = build_kernel_fn_pallas(kdef, N, 64, N, interpret=True,
+                                          force=True)
+    else:
+        fn, info = codegen.build_kernel_fn(kdef, N, 64, N)
+    assert (info.loops_counted, info.loops_masked) == want
+    assert codegen._UNROLL > 1 and codegen._UNROLL % 2 == 0
+    run = jax.jit(fn)
+    rng = np.random.default_rng(29)
+    for n in (0, 1, codegen._UNROLL + 3, 2 * codegen._UNROLL):
+        arrays = {k: rng.standard_normal(N).astype(np.float32) for k in names}
+        values = {"n": np.int32(n)}
+        if case == "nbody":
+            values["dt"] = np.float32(1e-3)
+        if case == "condition_reads_a_buffer_the_loop_stores_to":
+            arrays["o"][:] = 0.0  # every item sees o[0] < 1 until it stores
+        want_arrays = {k: v.copy() for k, v in arrays.items()}
+        Oracle(kdef).run(want_arrays, values, N)
+        got = run(0, tuple(jnp.asarray(arrays[k]) for k in names),
+                  tuple(values.values()))
+        for k, g in zip(names, got):
+            np.testing.assert_allclose(
+                np.asarray(g), want_arrays[k], rtol=1e-4, atol=1e-4,
+                err_msg=f"{case} ({lowering}), n={n}, array {k!r}")
+
+
+@pytest.mark.parametrize("case", ["for_up", "under_a_divergent_if", "nbody"])
+def test_counted_and_masked_forms_agree_to_the_last_bit(case):
+    """Bit-identity without a switch: the condition written ``j < n + (i -
+    i)`` defeats the proof, so the same kernel builds masked (and gathers
+    ``x[j]`` per lane: XLA lowering only).  Same operations in the same
+    order: the two builds agree exactly."""
+    import jax
+    import jax.numpy as jnp
+
+    if case == "nbody":
+        from cekirdekler_tpu.workloads import NBODY_SRC as src
+
+        name, values = "nBody", (np.int32(203), np.float32(1e-3))
+    else:
+        src = _LOOP_HEAD + LOOP_CASES[case][0] + "\n}\n"
+        name, values = "k", (np.int32(203),)
+    assert "j < n;" in src
+    defeated = src.replace("j < n;", "j < n + (i - i);")
+    N = 256
+    outs = []
+    for text, want in ((src, (1, 0)), (defeated, (0, 1))):
+        kdef = {k.name: k for k in lang.parse_kernels(text)}[name]
+        fn, info = codegen.build_kernel_fn(kdef, N, 64, N)
+        assert (info.loops_counted, info.loops_masked) == want
+        rng = np.random.default_rng(31)
+        arrays = tuple(jnp.asarray(rng.standard_normal(N).astype(np.float32))
+                       for p in kdef.params if p.is_pointer)
+        outs.append(jax.jit(fn)(0, arrays, values))
+    for a, b in zip(*outs):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_return_switches_the_proof_off_and_spans_name_the_loops():
+    """A kernel with a ``return`` builds every loop masked; ``lowering_meta``
+    carries the counts onto the launch / fused / compile spans."""
+    from cekirdekler_tpu.kernel.registry import KernelProgram, lowering_meta
+
+    body, _ = LOOP_CASES["for_up"]
+    src = _LOOP_HEAD + body + "\n}\n"
+    returning = src.replace("float a = 0.0f;",
+                            "if (i < 0) { return; } float a = 0.0f;")
+    for text, loops in ((src, "counted:1;masked:0"),
+                        (returning, "counted:0;masked:1")):
+        _fn, info = KernelProgram(text).launcher("k", 256, 64, 256,
+                                                 platform="cpu")
+        assert lowering_meta((info,)) == {"lowering": "xla", "loops": loops}
+    _fn, info = KernelProgram(MANDEL).launcher("mandel", 256, 64, 256,
+                                               platform="cpu")
+    assert lowering_meta((info,))["loops"] == "counted:0;masked:1"

@@ -161,9 +161,12 @@ def test_a_tpu_build_is_vetoed_and_says_why():
     prog = KernelProgram(SRC)
     _fn, info = prog.launcher("spmv", 4096, LOCAL_RANGE, 4096, platform="tpu")
     assert info.lowering == "xla" and "lane-uniform" in info.veto
-    assert lowering_meta((info,)) == {"lowering": "xla", "veto": info.veto}
+    # rows of unequal length: lanes leave the loop on different passes
+    loops = "counted:0;masked:1"
+    assert lowering_meta((info,)) == {"lowering": "xla", "loops": loops,
+                                      "veto": info.veto}
     _fn, info = prog.launcher("spmv", 4096, LOCAL_RANGE, 4096, platform="cpu")
-    assert lowering_meta((info,)) == {"lowering": "xla"}
+    assert lowering_meta((info,)) == {"lowering": "xla", "loops": loops}
 
 
 def test_launcher_hands_back_what_the_kernel_did_not_replace():
@@ -240,6 +243,7 @@ def test_span_carries_the_lowering(profiled, kind):
         assert len(spans) == 2 and "lowering" not in spans[0].stats
         spans = spans[1:]
     assert all(e.stats.get("lowering") == "xla" for e in spans)
+    assert all(e.stats.get("loops") == "counted:0;masked:1" for e in spans)
     if kind == "launch":
         tags = [str(e.stats["tag"]) for e in spans]
         assert any(t.startswith("fused:spmv x") for t in tags)
