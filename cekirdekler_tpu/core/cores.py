@@ -1135,7 +1135,9 @@ class Cores:
                 tuple(run.kernel_names), run.step, run.global_range,
                 run.local_range, run.global_range, run.value_args,
                 platform=w.device.platform, donate=w.fused_donate,
-                build=False) for w, _off, _size in run.rows]
+                build=False,
+                in_range=0 <= off and off + size <= run.global_range)
+                for w, off, size in run.rows]
             infos = [fn.info for fn in fns if fn is not None and fn.info.rungs]
             TRACER.record(
                 "fused", _tt, cid=run.compute_id, tag=f"x{iters}",

@@ -670,6 +670,9 @@ class Worker:
         bufs = tuple(self._buffers[id(p)] for p in params)
         names = list(kernel_names)
         units = size // step
+        # a compute with a global offset runs items beyond the global
+        # range: its launchers are built without the proofs drawn from it
+        in_range = 0 <= offset and offset + size <= global_size
         dispatched = 0
         infos: list = []  # of the launchers run, for the span's lowering
         # device-timeline mark around the dispatch (trace/device.py):
@@ -688,7 +691,7 @@ class Worker:
                 one_fn = program.sequence_launcher(
                     tuple(names), tuple(_ladder(size, step)), local_range,
                     global_size, repeats, sync_kernel, value_args,
-                    platform=self.device.platform,
+                    platform=self.device.platform, in_range=in_range,
                 )
                 one_args = (offset, bufs)
             elif not sync_kernel and units & (units - 1):
@@ -697,7 +700,7 @@ class Worker:
                 one_fn = program.fused_launcher(
                     tuple(names), step, global_size, local_range,
                     global_size, value_args, platform=self.device.platform,
-                    donate=self.fused_donate, build=False,
+                    donate=self.fused_donate, build=False, in_range=in_range,
                 )
                 one_args = (offset, units, 1, bufs)
             if one_fn is not None:
@@ -726,6 +729,7 @@ class Worker:
                                 fn, info = program.launcher(
                                     name, chunk, local_range, global_size,
                                     platform=self.device.platform,
+                                    in_range=in_range,
                                 )
                                 n_arr = program.array_param_count(name)
                                 out = fn(offset, bufs[:n_arr], tuple(va))
@@ -824,6 +828,7 @@ class Worker:
             tuple(kernel_names), step, global_size, local_range,
             global_size, value_args, platform=self.device.platform,
             donate=donate,
+            in_range=0 <= offset and offset + size <= global_size,
         )
         if fn is None:  # unhashable values — caller gates on this
             for _ in range(iters):

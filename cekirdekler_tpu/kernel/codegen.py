@@ -11,10 +11,20 @@ and lets XLA fuse the whole body.
 Key mechanisms:
 
 - **Affine index tracking** — every integer value carries an optional
-  ``(stride, offset)`` annotation meaning ``value == stride*gid + offset``.
-  Loads/stores with stride-1 indices lower to
-  ``lax.dynamic_slice`` / ``lax.dynamic_update_slice`` (contiguous DMA-
-  friendly vector ops); anything else falls back to gather/scatter.
+  ``(stride, offset)`` annotation meaning ``value == stride*gid + offset``,
+  with ``offset`` a literal or a runtime value that is the same in every
+  lane, and where the build can tell, the bounds of ``offset``.
+  Loads/stores with stride-1 indices lower to ``lax.dynamic_slice`` /
+  ``lax.dynamic_update_slice`` wherever the offset points (``a[j*n + i]``
+  is a contiguous window; a masked store a select into it); a stride that
+  is a build-time integer (a literal, or a value parameter the kernel
+  multiplies with inside an index: :func:`pitch_params`) reads a column of
+  the buffer seen as ``[rows, stride]``, and the columns a counted loop's
+  passes walk (``a[i*n + j]``) one 2-D slice and a transposition; a buffer
+  a loop touches at the lane's own element only (``x[i] += ...``) rides
+  the loop as a local.  An index that is neither affine nor lane-uniform,
+  or a strided one that cannot be proved inside its row, is a per-lane
+  gather / scatter (docs/KERNEL_LANGUAGE.md, *Affine accesses*).
 - **Masked control flow** — ``if``/``else`` run both branches under
   disjoint masks (stores become masked read-modify-writes, locals merge via
   ``where``); an early ``return`` folds into a cumulative return-mask.
@@ -51,8 +61,8 @@ from __future__ import annotations
 import itertools
 import math
 import re
-from dataclasses import dataclass
-from typing import Any, Callable, Optional
+from dataclasses import dataclass, field
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -82,7 +92,7 @@ from .lang import (
     While,
 )
 
-__all__ = ["build_kernel_fn", "KernelBuildInfo", "ctype_to_dtype"]
+__all__ = ["build_kernel_fn", "KernelBuildInfo", "ctype_to_dtype", "pitch_params"]
 
 
 # ---------------------------------------------------------------------------
@@ -157,12 +167,20 @@ class KVal:
     traced scalar.  Drives the contiguous slice fast path: stride-1 indices
     with an int ``const`` lower to dynamic_slice/dynamic_update_slice over a
     ``const``-padded buffer (padding makes tail chunks exact — a clamped
-    slice would silently shift the window).
+    slice would silently shift the window).  A value that is the same in
+    every lane has stride 0 and is its own ``const``.
+
+    ``span`` — ``(lo, hi)`` in Python ints with ``lo <= const <= hi`` on
+    every pass, where the build can tell (literals, a value parameter taken
+    as a launcher key, a counted loop's variable, sums and products of
+    these); None where it cannot.  With the launch's global range it is
+    what proves an affine access in bounds (:func:`_in_bounds`).
     """
 
     value: Any
     ctype: str
     affine: Optional[tuple[int, Any]] = None
+    span: Optional[tuple[int, int]] = None
 
     @property
     def is_vector(self) -> bool:
@@ -179,7 +197,8 @@ class _Ctx:
 
     pallas = False  # the Pallas tile subclass flips this
 
-    def __init__(self, B: int, offset, global_size, local_size: int, ctx_info: dict):
+    def __init__(self, B: int, offset, global_size, local_size: int, ctx_info: dict,
+                 in_range: bool = True):
         self.B = B
         self.shape: tuple[int, ...] = (B,)
         self.offset = offset  # scalar int32 (traced)
@@ -196,10 +215,14 @@ class _Ctx:
         self.umask: Any = None
         self.return_mask: Any = None  # items that already returned
         self.global_size = global_size
+        # the launch vouches that its work items lie in [0, global_size): a
+        # compute with a global offset hands out items beyond it, and then
+        # nothing is proved from the range (_in_bounds, _strided_rows)
+        self.in_range = in_range
         self.local_size = local_size
         self.info = ctx_info
         idx = jnp.arange(B, dtype=jnp.int32)
-        self.gid = KVal(offset + idx, "int", affine=(1, 0))
+        self.gid = KVal(offset + idx, "int", affine=(1, 0), span=(0, 0))
         # padded-view cache for shifted slice loads: name -> {const: padded}
         self._pad_cache: dict[str, dict[int, Any]] = {}
         # remainder stack (statements that can still run after the current
@@ -235,6 +258,17 @@ class _Ctx:
         # row views of buffers: (name, overlapping) -> (buffer, its
         # [rows, 128] view)
         self._rows_cache: dict[tuple[str, bool], tuple[Any, Any]] = {}
+        # the innermost strided-window loop's reads: id of the Index node
+        # -> this pass's row of its window (_exec_counted)
+        self.windows: dict[int, Any] = {}
+        # buffers riding a loop as a local because the loop touches them at
+        # the lane's own element only: name -> (the local's name in ``env``,
+        # the index expression) — see _own_element_bufs
+        self.own: dict[str, tuple[str, Any]] = {}
+        # how each buffer access was lowered: (id of the Index node, is it
+        # the store) -> kind; and the (loop, buffer) pairs carried as locals
+        self.access: dict[tuple[int, bool], str] = {}
+        self.carried: set[tuple[int, str]] = set()
 
     def adopt(self, kernel: KernelDef, uniform_vars: set[str]) -> None:
         """Take what a build knows of ``kernel`` before its body runs."""
@@ -347,8 +381,14 @@ def _as_dtype(v: KVal, ctype: str) -> KVal:
         val = val.astype(dt)
     else:
         val = jnp.asarray(val, dtype=dt) if not isinstance(val, (int, float, bool)) else dt.type(val)
-    affine = v.affine if (v.ctype in _INT_TYPES and ctype in _INT_TYPES) else None
-    return KVal(val, ctype, affine)
+    if v.ctype in _INT_TYPES and ctype in _INT_TYPES:
+        return KVal(val, ctype, v.affine, v.span)
+    return KVal(val, ctype)
+
+
+def _int_const(value: int, ctype: str = "int") -> KVal:
+    """A compile-time integer: stride 0, its own bounds."""
+    return KVal(value, ctype, (0, value), (value, value))
 
 
 def _const_int(v: KVal) -> Optional[int]:
@@ -362,7 +402,9 @@ def _const_int(v: KVal) -> Optional[int]:
 
 def _eval(ctx: _Ctx, node) -> KVal:
     if isinstance(node, Num):
-        return KVal(node.value, node.ctype, affine=(0, node.value) if node.ctype in _INT_TYPES else None)
+        if node.ctype in _INT_TYPES:
+            return KVal(node.value, node.ctype, (0, node.value), (node.value,) * 2)
+        return KVal(node.value, node.ctype)
     if isinstance(node, Var):
         if node.name in ctx.private:
             raise KernelLanguageError(
@@ -381,11 +423,12 @@ def _eval(ctx: _Ctx, node) -> KVal:
         if node.op == "+":
             return v
         if node.op == "-":
-            aff = None
+            aff = span = None
             if v.affine is not None:
                 s, o = v.affine
-                aff = (-s, -o if isinstance(o, int) else -o)
-            return KVal(-_num(v), v.ctype if v.ctype in _FLOAT_TYPES else _promote(v.ctype, "int"), aff)
+                aff = (-s, -o)
+                span = v.span and (-v.span[1], -v.span[0])
+            return KVal(-_num(v), v.ctype if v.ctype in _FLOAT_TYPES else _promote(v.ctype, "int"), aff, span)
         if node.op == "!":
             return KVal(jnp.logical_not(_truthy(v)), "bool")
         if node.op == "~":
@@ -443,25 +486,43 @@ def _binop(ctx: _Ctx, node: BinOp) -> KVal:
     ac, bc = _as_dtype(a, t), _as_dtype(b, t)
     av, bv = _num(ac), _num(bc)
 
-    affine = None
-    if t in _INT_TYPES:
+    affine = span = None
+    if t in _INT_TYPES and op in ("+", "-", "*"):
         ka, kb = ac.affine, bc.affine
         ca, cb = _const_int(ac), _const_int(bc)
+        sa, sb = ac.span, bc.span
+        both = sa is not None and sb is not None
         if op == "+" and ka is not None and kb is not None:
-            affine = (ka[0] + kb[0], _add_off(ka[1], kb[1]))
+            affine = (ka[0] + kb[0], (ka[1], kb[1]))
+            span = both and (sa[0] + sb[0], sa[1] + sb[1])
         elif op == "-" and ka is not None and kb is not None:
-            affine = (ka[0] - kb[0], _sub_off(ka[1], kb[1]))
+            affine = (ka[0] - kb[0], (ka[1], kb[1]))
+            span = both and (sa[0] - sb[1], sa[1] - sb[0])
         elif op == "*" and ka is not None and cb is not None:
-            affine = (ka[0] * cb, _mul_off(ka[1], cb))
+            affine = (ka[0] * cb, (ka[1], cb))
+            span = sa and tuple(sorted((sa[0] * cb, sa[1] * cb)))
         elif op == "*" and kb is not None and ca is not None:
-            affine = (kb[0] * ca, _mul_off(kb[1], ca))
+            affine = (kb[0] * ca, (kb[1], ca))
+            span = sb and tuple(sorted((sb[0] * ca, sb[1] * ca)))
+        elif op == "*" and ka is not None and kb is not None and ka[0] == kb[0] == 0:
+            # two values that are the same in every lane: so is the product
+            affine = (0, (ka[1], kb[1]))
+            if both:
+                ends = [x * y for x in sa for y in sb]
+                span = (min(ends), max(ends))
+        span = span or None
 
-    if op == "+":
-        return KVal(av + bv, t, affine)
-    if op == "-":
-        return KVal(av - bv, t, affine)
-    if op == "*":
-        return KVal(av * bv, t, affine)
+    if op in ("+", "-", "*"):
+        out = av + bv if op == "+" else av - bv if op == "-" else av * bv
+        if affine is not None:
+            # the ``const`` part by the same rule; of a value that is the
+            # same in every lane it is the value itself, computed once
+            parts = affine[1]
+            if affine[0] == 0 and not all(type(x) is int for x in parts):
+                affine = (0, out)
+            else:
+                affine = (affine[0], _OFF[op](*parts))
+        return KVal(out, t, affine, span)
     if op == "/":
         if t in _FLOAT_TYPES:
             return KVal(av / bv, t)
@@ -484,16 +545,26 @@ def _binop(ctx: _Ctx, node: BinOp) -> KVal:
     raise KernelCompileError(f"unknown operator {op}", line=node.line)
 
 
+# arithmetic on the ``const`` part of an affine value: a Python int or a
+# traced scalar; the trivial cases add no operation to the trace
+
 def _add_off(a, b):
-    return a + b
+    if type(a) is int and a == 0:
+        return b
+    return a if type(b) is int and b == 0 else a + b
 
 
 def _sub_off(a, b):
-    return a - b
+    return a if type(b) is int and b == 0 else a - b
 
 
 def _mul_off(a, c):
-    return a * c
+    if type(c) is int and c == 1:
+        return a
+    return c if type(a) is int and a == 1 else a * c
+
+
+_OFF = {"+": _add_off, "-": _sub_off, "*": _mul_off}
 
 
 # ---------------------------------------------------------------------------
@@ -603,9 +674,11 @@ def _call(ctx: _Ctx, node: Call) -> KVal:
         if name == "get_global_id":
             return ctx.gid
         if name == "get_global_size":
-            return KVal(ctx.global_size, "int", affine=(0, ctx.global_size) if isinstance(ctx.global_size, int) else None)
+            if isinstance(ctx.global_size, int):
+                return _int_const(ctx.global_size)
+            return KVal(ctx.global_size, "int")
         if name == "get_local_size":
-            return KVal(ctx.local_size, "int", affine=(0, ctx.local_size))
+            return _int_const(ctx.local_size)
         if name == "get_local_id":
             g = _num(ctx.gid)
             return KVal(lax.rem(g, jnp.int32(ctx.local_size)), "int")
@@ -616,8 +689,8 @@ def _call(ctx: _Ctx, node: Call) -> KVal:
             gs = ctx.global_size
             return KVal(gs // ctx.local_size if isinstance(gs, int) else lax.div(gs, ctx.local_size), "int")
         if name == "get_global_offset":
-            return KVal(0, "int", affine=(0, 0))
-        return KVal(1, "int", affine=(0, 1))  # get_work_dim
+            return _int_const(0)
+        return _int_const(1)  # get_work_dim
 
     if name in _UNARY_FLOAT:
         a = args[0]
@@ -845,6 +918,141 @@ def _run_window(ctx: _Ctx, name: str, j0):
     return _by_lane_chunks(window, j0.astype(jnp.int32), (W,), rows.dtype)
 
 
+# ---------------------------------------------------------------------------
+# affine accesses: an index ``s * gid + u`` with ``u`` the same in every
+# lane.  Stride 1 is a contiguous window of the buffer wherever ``u`` points
+# (a slice, never a gather); a stride that is a build-time integer makes the
+# lanes' elements a COLUMN of the buffer seen as ``[rows, s]``, and the
+# columns that a counted loop's passes walk one after the other a 2-D slice
+# of that view (_exec_counted).  A slice does not clamp element by element
+# as a gather does, so each path needs the access proved in bounds from the
+# launch's global range and the bounds of ``u`` (``KVal.span``), or puts the
+# clamped elements in itself (_shift_fill).
+# ---------------------------------------------------------------------------
+
+_INT32_MAX = (1 << 31) - 1
+# passes one strided window serves, at most: one block of the blocked view
+# (on the chip 128 passes a window ran the row walk of a 1 GiB matrix in
+# 21.8 ms a launch, 1024 passes in 25.4: PERF.md, PR 30)
+_STRIDE_WINDOW = _ROW
+_WINDOW_ELEMS = 1 << 24     # elements of one, at most (lanes x passes)
+
+
+def _in_bounds(ctx: _Ctx, idx: KVal, n: int) -> bool:
+    """Is ``idx`` (affine, stride >= 0) provably inside ``[0, n)`` in every
+    lane of every launch of this build?  Only a build whose launches keep
+    their work items in ``[0, global_size)`` (``in_range``) can tell: a
+    compute with a global offset runs items ``offset + global_size`` and
+    beyond through the same geometry."""
+    g = ctx.global_size
+    if (idx.affine is None or idx.span is None or not isinstance(g, int)
+            or not ctx.in_range):
+        return False
+    stride, (lo, hi) = idx.affine[0], idx.span
+    top = hi + stride * (g - 1)
+    return stride >= 0 and lo >= 0 and top < n and top <= _INT32_MAX
+
+
+def _shift_fill(w, d, lo, hi):
+    """``out[..., k] = w[..., k + d]`` where that exists, ``lo`` before it
+    and ``hi`` behind (``d`` a traced scalar): what a window that had to be
+    moved back inside its buffer holds once it is moved out again."""
+    b = w.shape[-1]
+    fill = w.shape[:-1] + (b,)
+    ext = jnp.concatenate([jnp.broadcast_to(lo, fill), w,
+                           jnp.broadcast_to(hi, fill)], axis=-1)
+    return lax.dynamic_slice_in_dim(ext, b + jnp.clip(d, -b, b), b, axis=-1)
+
+
+def _slice_clamped(buf, start, b: int):
+    """``buf[clip(start + k)]`` for ``k`` in ``[0, b)`` without a gather:
+    the window at the nearest start that lies inside the buffer, moved to
+    where it was asked for, the first or last element beyond the ends."""
+    n = buf.shape[0]
+    first, last = buf[:1], buf[-1:]
+    if n < b:
+        buf = jnp.pad(buf, (0, b - n), mode="edge")
+    s0 = jnp.clip(start, 0, buf.shape[0] - b)
+    w = lax.dynamic_slice(buf, (s0,), (b,))
+    return _shift_fill(w, start - s0, first, last)
+
+
+def _strided_rows(ctx: _Ctx, buf, stride: int, blocked: bool):
+    """``(view, row0, moved)``: the buffer as rows of ``stride`` elements and
+    where the chunk's block of rows starts in it; ``moved`` is None when the
+    global range proves every lane's row inside the view, else how far the
+    block had to be moved back (:func:`_shift_fill` moves its lanes out
+    again).  The view is ``[rows, stride]``, or ``blocked`` ``[rows, stride
+    / 128, 128]``: on the chip a 1-D buffer lies in memory as its ``[n /
+    128, 128]`` view does, so the blocked view of a stride of whole tiles
+    costs nothing where the 2-D one is a copy of the buffer a launch (the
+    compiler's choice, not this code's: PERF.md, PR 30).  None when the
+    buffer is no whole number of rows, or has fewer rows than the chunk has
+    lanes."""
+    n = buf.shape[0]
+    rows = n // stride
+    if n % stride or rows < ctx.B:
+        return None
+    view = buf.reshape((rows, stride // _ROW, _ROW) if blocked
+                       else (rows, stride))
+    g = ctx.global_size
+    if ctx.in_range and isinstance(g, int) and g <= rows:
+        return view, ctx.offset, None
+    row0 = jnp.clip(ctx.offset, 0, rows - ctx.B)
+    return view, row0, ctx.offset - row0
+
+
+def _strided_load(ctx: _Ctx, buf, idx: KVal):
+    """``buf[s * gid + u]`` for a build-time ``s >= 2`` and ``u`` proved in
+    ``[0, s)``: column ``u`` of the lanes' rows.  None where that is not
+    proved."""
+    stride, u = idx.affine
+    if (not isinstance(stride, int) or stride < 2 or idx.span is None
+            or idx.span[0] < 0 or idx.span[1] >= stride):
+        return None
+    at = _strided_rows(ctx, buf, stride, stride % _ROW == 0)
+    if at is None:
+        return None
+    view, row0, moved = at
+    u = jnp.asarray(u, jnp.int32)
+    if view.ndim == 3:
+        col = lax.dynamic_slice(view, (row0, u >> 7, u & (_ROW - 1)),
+                                (ctx.B, 1, 1))[:, 0, 0]
+    else:
+        col = lax.dynamic_slice(view, (row0, u), (ctx.B, 1))[:, 0]
+    # a lane whose row lies behind the buffer reads the last element
+    return col if moved is None else _shift_fill(col, moved, buf[:1], buf[-1:])
+
+
+def _strided_window(ctx: _Ctx, site, c, width: int):
+    """``(win, d)``: the columns that ``width`` passes from column ``c`` on
+    read, one row of ``win`` a column (``[columns, lanes]``: a 2-D slice of
+    the view, transposed), pass ``r`` reading row ``r + d``.  A window that
+    would reach over the end of the rows starts ``d`` columns early: the
+    passes that would leave the row never run (``u + j < s`` is what made
+    the read a site).  The blocked view is cut at whole blocks of 128: the
+    one the walk starts in, and the next unless it is known to start on
+    one."""
+    view, row0, moved = site.at
+    if view.ndim == 3:
+        blocks = 1 + (not site.aligned)
+        k0 = jnp.clip(c >> 7, 0, view.shape[1] - blocks)
+        win = lax.dynamic_slice(view, (row0, k0, jnp.int32(0)),
+                                (ctx.B, blocks, _ROW))
+        win, d = win.reshape(ctx.B, blocks * _ROW).T, c - (k0 << 7)
+    else:
+        c0 = jnp.clip(c, 0, site.stride - width)
+        win, d = lax.dynamic_slice(view, (row0, c0), (ctx.B, width)).T, c - c0
+    if moved is not None:
+        ends = ctx.bufs[site.node.base]
+        win = _shift_fill(win, moved, ends[:1], ends[-1:])
+    return win, d
+
+
+def _note(ctx: _Ctx, node: Index, store: bool, kind: str) -> None:
+    ctx.access[id(node), store] = kind
+
+
 def _load(ctx: _Ctx, node: Index) -> KVal:
     if node.base in ctx.private:
         return _private_load(ctx, node)
@@ -860,18 +1068,38 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
         return _loaded(kv.value, ctype)
     run = ctx.runs.get(node.base)
     if run is not None and isinstance(node.index, Var) and node.index.name == run[0]:
+        _note(ctx, node, False, "gather")  # a row gather a refill
         return _loaded(run[1], ctype)  # this pass's row of the run window
-    if idx.affine is not None and idx.affine[0] == 1 and isinstance(idx.affine[1], int):
+    own = ctx.own.get(node.base)
+    if own is not None and _same_expr(node.index, own[1]):
+        return _loaded(ctx.env[own[0]].value, ctype)  # rides the loop
+    if id(node) in ctx.windows:
+        _note(ctx, node, False, "strided")
+        return _loaded(ctx.windows[id(node)], ctype)  # this pass's column
+    if idx.affine is not None and idx.affine[0] == 1:
+        _note(ctx, node, False, "slice")
         c = idx.affine[1]
+        if not isinstance(c, int):
+            # a runtime offset, the same in every lane: still contiguous
+            start = jnp.asarray(ctx.offset + c, jnp.int32)
+            if _in_bounds(ctx, idx, buf.shape[0]):
+                return _loaded(lax.dynamic_slice(buf, (start,), (ctx.B,)), ctype)
+            return _loaded(_slice_clamped(buf, start, ctx.B), ctype)
         if c == 0:
             start = jnp.asarray(ctx.offset, jnp.int32)
             return _loaded(lax.dynamic_slice(buf, (start,), (ctx.B,)), ctype)
         padded, lo = ctx.padded_view(node.base, c)
         start = jnp.asarray(ctx.offset + c + lo, jnp.int32)
         return _loaded(lax.dynamic_slice(padded, (start,), (ctx.B,)), ctype)
+    if idx.affine is not None and idx.affine[0] != 0:
+        col = _strided_load(ctx, buf, idx)
+        if col is not None:
+            _note(ctx, node, False, "strided")
+            return _loaded(col, ctype)
     if ctx.uniform_vars and _expr_uniform(
         node.index, ctx.uniform_vars, frozenset(ctx.private)
     ):
+        _note(ctx, node, False, "uniform")
         # lane-uniform index (the n-body ``x[j]`` pattern): ONE element
         # load broadcast to the chunk instead of a (B,)-wide gather per
         # loop iteration — the dominant cost of gather-loop kernels
@@ -879,6 +1107,7 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
         sidx = iv if (not hasattr(iv, "ndim") or iv.ndim == 0) else iv.reshape(-1)[0]
         sidx = jnp.clip(jnp.asarray(sidx, jnp.int32), 0, buf.shape[0] - 1)
         return _loaded(lax.dynamic_slice(buf, (sidx,), (1,))[0], ctype)
+    _note(ctx, node, False, "gather")
     iv = _num(_as_dtype(idx, "int"))
     if not hasattr(iv, "ndim") or iv.ndim == 0:
         iv = jnp.full((ctx.B,), iv, dtype=jnp.int32)
@@ -909,9 +1138,29 @@ def _store(ctx: _Ctx, node: Index, val: KVal) -> None:
     if ctx.pallas:
         ctx.pallas_store(node, buf, ctype, idx, v)  # type: ignore[attr-defined]
         return
+    own = ctx.own.get(node.base)
+    if own is not None and _same_expr(node.index, own[1]):
+        # rides the loop as a local: merged as an assignment is (_assign)
+        m, fr = ctx.active_mask(), ctx._freerun
+        if m is not None and not (fr is not None and m is fr[0] and own[0] in fr[1]):
+            v = jnp.where(m, v, ctx.env[own[0]].value)
+        ctx.env[own[0]] = KVal(v, ctx.env[own[0]].ctype)
+        return
     m = ctx.active_mask()
     if (idx.affine is not None and idx.affine[0] == 1
+            and (m is not None or not isinstance(idx.affine[1], int))
+            and _in_bounds(ctx, idx, buf.shape[0])):
+        # every lane owns its element and none lies outside the buffer: a
+        # masked store is a select into the window, at a runtime offset too
+        _note(ctx, node, True, "slice")
+        start = jnp.asarray(ctx.offset + idx.affine[1], jnp.int32)
+        if m is not None:
+            v = jnp.where(m, v, lax.dynamic_slice(buf, (start,), (ctx.B,)))
+        ctx.bufs[node.base] = lax.dynamic_update_slice(buf, v, (start,))
+        ctx.invalidate_padded(node.base)
+    elif (idx.affine is not None and idx.affine[0] == 1
             and isinstance(idx.affine[1], int) and m is None):
+        _note(ctx, node, True, "slice")
         c = idx.affine[1]
         if c == 0:
             start = jnp.asarray(ctx.offset, jnp.int32)
@@ -925,6 +1174,7 @@ def _store(ctx: _Ctx, node: Index, val: KVal) -> None:
             ctx.bufs[node.base] = lax.slice(updated, (lo,), (lo + n,))
         ctx.invalidate_padded(node.base)
     else:
+        _note(ctx, node, True, "scatter")
         iv = _num(_as_dtype(idx, "int"))
         if not hasattr(iv, "ndim") or iv.ndim == 0:
             iv = jnp.full((ctx.B,), iv, dtype=jnp.int32)
@@ -977,8 +1227,9 @@ def _exec(ctx: _Ctx, node) -> None:
             if init is not None:
                 v = _as_dtype(_eval(ctx, init), node.ctype)
             else:
-                v = KVal(ctype_to_dtype(node.ctype).type(0), node.ctype,
-                         affine=(0, 0) if node.ctype in _INT_TYPES else None)
+                zero = ctype_to_dtype(node.ctype).type(0)
+                v = (KVal(zero, node.ctype, (0, 0), (0, 0))
+                     if node.ctype in _INT_TYPES else KVal(zero, node.ctype))
             ctx.env[name] = v
         return
     if isinstance(node, Assign):
@@ -1218,6 +1469,158 @@ def _run_reads(ctx: _Ctx, node, cond_expr, carried_bufs) -> tuple:
                      and t not in carried_bufs)
 
 
+def _walk(node):
+    """Every syntax-tree node under ``node`` (statements, expressions,
+    lists of either), ``node`` first."""
+    if isinstance(node, (list, tuple)):
+        for x in node:
+            yield from _walk(x)
+    elif hasattr(node, "__dict__") and not isinstance(node, _Lit):
+        yield node
+        for v in vars(node).values():
+            if isinstance(v, (list, tuple)) or hasattr(v, "__dict__"):
+                yield from _walk(v)
+
+
+def _index_nodes(node) -> list:
+    """Every ``Index`` node under ``node`` (a store's target too)."""
+    return [x for x in _walk(node) if isinstance(x, Index)]
+
+
+def _same_expr(a, b) -> bool:
+    """Are two expressions the same tree (whatever lines they stand on)?"""
+    if a is b:
+        return True
+    if type(a) is not type(b):
+        return False
+    if isinstance(a, (list, tuple)):
+        return len(a) == len(b) and all(map(_same_expr, a, b))
+    if not hasattr(a, "__dict__"):
+        return a == b
+    return all(_same_expr(v, vars(b)[k]) for k, v in vars(a).items()
+               if k != "line")
+
+
+def _affine_expr(node) -> bool:
+    """Is ``node`` made of what the affine tracker follows and nothing that
+    reads memory: literals, variables, ``+ - *``, casts, the ids?"""
+    if isinstance(node, (Num, Var)):
+        return True
+    if isinstance(node, BinOp):
+        return (node.op in ("+", "-", "*") and _affine_expr(node.left)
+                and _affine_expr(node.right))
+    if isinstance(node, UnOp):
+        return node.op in ("+", "-") and _affine_expr(node.operand)
+    if isinstance(node, Cast):
+        return node.ctype in _INT_TYPES and _affine_expr(node.operand)
+    if isinstance(node, Call):
+        return (node.name == "get_global_id" or node.name in _UNIFORM_CALLS
+                ) and all(isinstance(a, Num) for a in node.args)
+    return False
+
+
+def _terms(node, sign: int, out: list) -> list:
+    """``node`` as a sum: its ``(sign, term)`` leaves over ``+`` and ``-``."""
+    if isinstance(node, BinOp) and node.op in ("+", "-"):
+        _terms(node.left, sign, out)
+        _terms(node.right, sign if node.op == "+" else -sign, out)
+    elif isinstance(node, UnOp) and node.op in ("+", "-"):
+        _terms(node.operand, sign if node.op == "+" else -sign, out)
+    else:
+        out.append((sign, node))
+    return out
+
+
+class _Site(NamedTuple):
+    """One read ``T[s * gid + rest + j]`` of a strided-window loop."""
+
+    node: Index
+    stride: int
+    rest: Any      # what the index is with the loop's variable at 0
+    aligned: bool  # is every window known to start at a multiple of 128
+    at: tuple = ()  # _strided_rows: the view, the chunk's first row, moved
+
+
+def _window_sites(ctx: _Ctx, node, counted: _Trips) -> tuple:
+    """``(sites, width)``: the reads of a counted loop that walk a strided
+    window, and the passes one window serves.  A site: the loop's
+    variable ``j`` goes up by one a pass, and the index is ``j`` plus terms
+    that nothing in the loop changes, affine in the work-item id with a
+    build-time stride ``s >= 2``, with ``rest + j`` proved inside ``[0, s)``
+    on every pass; the buffer is one the loop does not store to and a whole
+    number of rows of ``s``."""
+    if ctx.pallas or counted.step != 1 or counted.span is None:
+        return [], 0
+    j, body = counted.var, node.body + [node.step]
+    changed = _assigned_vars(body) | _stored_bufs(body)
+    sites = []
+    for ix in _index_nodes(node.body):
+        if (ix.base not in ctx.bufs or ix.base in ctx.private
+                or ix.base in changed or not _affine_expr(ix.index)):
+            continue
+        terms = _terms(ix.index, 1, [])
+        bare = [sg for sg, t in terms if isinstance(t, Var) and t.name == j]
+        if bare != [1] or any(
+                _vars_read(t) & changed for _, t in terms
+                if not (isinstance(t, Var) and t.name == j)):
+            continue
+        saved = ctx.env[j]
+        ctx.env[j] = _int_const(0)
+        try:
+            rest = _eval(ctx, ix.index)
+        finally:
+            ctx.env[j] = saved
+        if (rest.affine is None or rest.span is None
+                or not isinstance(rest.affine[0], int) or rest.affine[0] < 2
+                or rest.span[0] + counted.span[0] < 0
+                or rest.span[1] + counted.span[1] >= rest.affine[0]):
+            continue
+        start = ctx.env[j].span  # where the loop's variable sets out
+        aligned = (type(rest.affine[1]) is int and start[0] == start[1]
+                   and (rest.affine[1] + start[0]) % _ROW == 0)
+        sites.append(_Site(ix, rest.affine[0], rest.affine[1], aligned))
+    if not sites:
+        return [], 0
+    width = min([_STRIDE_WINDOW, max(_UNROLL, _WINDOW_ELEMS // ctx.B)]
+                + [t.stride for t in sites])
+    placed = []
+    for t in sites:
+        # whole blocks of the blocked view, where the rows hold them
+        blocked = (width == _ROW and t.stride % _ROW == 0
+                   and t.stride >= _ROW * (2 - t.aligned))
+        at = _strided_rows(ctx, ctx.bufs[t.node.base], t.stride, blocked)
+        if at is not None:
+            placed.append(t._replace(at=at))
+    return placed, width
+
+
+def _own_element_bufs(ctx: _Ctx, body: list, cond_expr, stored: list) -> dict:
+    """``{buffer: (index expression, where its window starts)}`` for the buffers a loop
+    stores to that it touches ONLY at the lane's own element: every access
+    in the body and the condition has the same index, made of values the
+    loop does not change, with stride 1 and proved in bounds.  No other
+    lane's pass can observe such an element, so the loop may hold it in a
+    local: loaded before, stored after (:func:`_exec_loop`)."""
+    out: dict = {}
+    if ctx.pallas:
+        return out
+    changed = _assigned_vars(body)
+    sites = _index_nodes([body, cond_expr])
+    for k in stored:
+        mine = [ix.index for ix in sites if ix.base == k]
+        buf = ctx.bufs[k]
+        if (k in ctx.private or k in ctx.own or not mine
+                or not all(_same_expr(e, mine[0]) for e in mine)
+                or not _affine_expr(mine[0]) or _vars_read(mine[0]) & changed
+                or buf.dtype != ctype_to_dtype(ctx.buf_ctypes[k])):
+            continue
+        idx = _eval(ctx, mine[0])
+        if (idx.affine is not None and idx.affine[0] == 1
+                and _in_bounds(ctx, idx, buf.shape[0])):
+            out[k] = (mine[0], jnp.asarray(ctx.offset + idx.affine[1], jnp.int32))
+    return out
+
+
 def _exec_pass(ctx: _Ctx, node, body_core: list, step_stmt) -> None:
     """One pass of a loop's body and step under the masks in place."""
     _exec_block(ctx, body_core)
@@ -1246,7 +1649,16 @@ def _loop_counted(ctx: _Ctx, node) -> bool:
         node, ctx.uniform_vars, frozenset(ctx.private))
 
 
-def _trip_count(ctx: _Ctx, node):
+class _Trips(NamedTuple):
+    """What :func:`_trip_count` reads off a loop's syntax."""
+
+    passes: Any                 # 0-d int32
+    var: str                    # the loop's variable
+    step: int                   # what a pass adds to it
+    span: Optional[tuple]       # its bounds inside a pass, where known
+
+
+def _trip_count(ctx: _Ctx, node) -> Optional[_Trips]:
     """The passes a counted loop will make, as a 0-d int32, where its
     syntax gives them: ``for (...; j < B; j += c)`` (also ``<=``, and
     ``>`` / ``>=`` with ``-=``; ``B`` on either side) with ``j`` an ``int``
@@ -1282,12 +1694,21 @@ def _trip_count(ctx: _Ctx, node):
     jv, bv = ctx.env[j], _eval(ctx, bound)
     if jv.ctype != "int" or bv.ctype not in _INT_TYPES or _promote("int", bv.ctype) != "int":
         return None
+    span = None
+    if jv.span is not None and bv.span is not None:
+        # inside a pass the variable lies between where it started and the
+        # last value the condition lets through
+        (jlo, jhi), (blo, bhi), strict = jv.span, bv.span, op in ("<", ">")
+        span = ((jlo, max(jhi, bhi - strict)) if c > 0
+                else (min(jlo, blo + strict), jhi))
     jv, bv = (jnp.asarray(_num(_as_dtype(v, "int"))) for v in (jv, bv))
     d = (bv - jv) if c > 0 else (jv - bv)  # the distance left to go
-    c = jnp.int32(abs(c))
+    by = jnp.int32(abs(c))
     if op in ("<", ">"):
-        return jnp.where(d > 0, lax.div(d - 1, c) + 1, 0)
-    return jnp.where(d >= 0, lax.div(d, c) + 1, 0)
+        passes = jnp.where(d > 0, lax.div(d - 1, by) + 1, 0)
+    else:
+        passes = jnp.where(d >= 0, lax.div(d, by) + 1, 0)
+    return _Trips(passes, j, c, span)
 
 
 def _exec_counted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
@@ -1326,24 +1747,37 @@ def _exec_counted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
             return ctx.broadcast_scalar(val, ctype_to_dtype(var_ctypes[k]))
         return val
 
-    init_env = {k: carried(k) for k in carried_vars}
-    for k in carried_vars:
-        ctx.env[k] = KVal(init_env[k], var_ctypes[k], None)
-    init_bufs = {k: ctx.bufs[k] for k in carried_bufs}
-    trips = _trip_count(ctx, node)
+    def in_loop(k, val, span=None) -> KVal:
+        """A carried local in the carry's form: an integer proved the same
+        in every lane is affine with stride 0, within ``span``."""
+        if k in lane_vars or var_ctypes[k] not in _INT_TYPES:
+            return KVal(val, var_ctypes[k])
+        return KVal(val, var_ctypes[k], (0, val), span)
 
-    def in_loop_state(env_vals, buf_vals, fn):
-        """``fn()`` with the carried state in place of the context's."""
+    init_env = {k: carried(k) for k in carried_vars}
+    for k in carried_vars:  # as the loop is entered: with the bounds so far
+        ctx.env[k] = in_loop(k, init_env[k], ctx.env[k].span)
+    init_bufs = {k: ctx.bufs[k] for k in carried_bufs}
+    counted = _trip_count(ctx, node)
+    trips = counted and counted.passes
+    sites, width = _window_sites(ctx, node, counted) if counted else ([], 0)
+
+    def in_loop_state(env_vals, buf_vals, fn, rows=None):
+        """``fn()`` with the carried state in place of the context's, and
+        this pass's columns of the loop's strided windows."""
         saved = (ctx.env, ctx.bufs, ctx.mask, ctx.umask, ctx.return_mask,
                  ctx.break_mask, ctx.continue_mask, ctx.counted, ctx._freerun,
-                 ctx._rows_cache)
+                 ctx._rows_cache, ctx.windows)
         ctx.env, ctx.bufs = dict(ctx.env), dict(ctx.bufs)
         ctx._rows_cache = dict(ctx._rows_cache)
+        if rows:
+            ctx.windows = {**ctx.windows, **rows}
         saved_stored = set(ctx.stored)
         ctx.info["in_loop"] = ctx.info.get("in_loop", 0) + 1
         try:
-            for k in carried_vars:
-                ctx.env[k] = KVal(env_vals[k], var_ctypes[k], None)
+            for k in carried_vars:  # the loop's variable within its bounds
+                ctx.env[k] = in_loop(k, env_vals[k], counted.span if (
+                    counted and k == counted.var) else None)
             ctx.bufs.update(buf_vals)
             ctx._pad_cache.clear()  # buffers swapped to loop tracers
             # the entered mask's two parts hold for the whole loop: the
@@ -1359,13 +1793,13 @@ def _exec_counted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
             ctx.stored = saved_stored | ctx.stored
             (ctx.env, ctx.bufs, ctx.mask, ctx.umask, ctx.return_mask,
              ctx.break_mask, ctx.continue_mask, ctx.counted, ctx._freerun,
-             ctx._rows_cache) = saved
+             ctx._rows_cache, ctx.windows) = saved
 
     def cond_of(env_vals, buf_vals):
         return in_loop_state(
             env_vals, buf_vals, lambda: _lane0(_truthy(_eval(ctx, cond_expr))))
 
-    def one_pass(env_vals, buf_vals):
+    def one_pass(env_vals, buf_vals, rows=None):
         """``(env, bufs, broke)``: one pass, and its 0-d break flag (None
         where nothing broke)."""
         def run():
@@ -1376,13 +1810,48 @@ def _exec_counted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
             for k in set(ctx.env.keys()) - env_keys_before:
                 ctx.private.pop(k, None)  # loop-local declarations scope out
             return out
-        return in_loop_state(env_vals, buf_vals, run)
+        return in_loop_state(env_vals, buf_vals, run, rows)
 
     state = (init_env, init_bufs)
-    if trips is not None:
-        if enter is not None:
-            trips = jnp.where(enter, trips, 0)
+    if trips is not None and enter is not None:
+        trips = jnp.where(enter, trips, 0)
+    if sites:
+        # STRIDED WINDOWS: the loop's variable goes up by one a pass, so the
+        # elements ``T[s * gid + u + j]`` that the lanes read in ``width``
+        # passes are ``width`` neighbouring columns of their rows of ``T``
+        # seen as rows of ``s``: ONE 2-D slice, transposed so that a pass
+        # reads a row of it (_strided_window).
+        def fetch(st):
+            jv = st[0][counted.var]
+            return {id(t.node): _strided_window(
+                ctx, t, jnp.asarray(t.rest + jv, jnp.int32), width)
+                for t in sites}
 
+        def window_pass(st, wins, r):
+            rows = {k: lax.dynamic_index_in_dim(w, r + d, 0, keepdims=False)
+                    for k, (w, d) in wins.items()}
+            return one_pass(*st, rows)[:2]
+
+        def groups(st, wins, n_groups, n_singles):
+            """``n_groups`` times ``_UNROLL`` passes, then ``n_singles``."""
+            def group(g, s2):
+                for t in range(_UNROLL):
+                    s2 = window_pass(s2, wins, g * _UNROLL + t)
+                return s2
+
+            st = lax.fori_loop(0, n_groups, group, st)
+            return lax.fori_loop(
+                0, n_singles,
+                lambda i, s2: window_pass(s2, wins, n_groups * _UNROLL + i), st)
+
+        state = lax.fori_loop(
+            0, lax.div(trips, jnp.int32(width)),
+            lambda _, st: groups(st, fetch(st), width // _UNROLL,
+                                 width % _UNROLL), state)
+        rest = lax.rem(trips, jnp.int32(width))
+        state = groups(state, fetch(state), lax.div(rest, jnp.int32(_UNROLL)),
+                       lax.rem(rest, jnp.int32(_UNROLL)))
+    elif trips is not None:
         def group(_, st):
             for _ in range(_UNROLL):
                 st = one_pass(*st)[:2]
@@ -1438,10 +1907,35 @@ def _exec_loop(ctx: _Ctx, node) -> None:
 
     carried_vars = sorted(_assigned_vars(body) & set(ctx.env.keys()))
     carried_bufs = sorted(_stored_bufs(body) & set(ctx.bufs.keys()))
+    # a buffer the loop touches at the lane's own element only rides it as a
+    # local, loaded here and stored behind the loop; one that an enclosing
+    # loop already holds so stays that loop's local
+    own = _own_element_bufs(ctx, body, cond_expr, carried_bufs)
+    for k, (expr, start) in own.items():
+        ctx.env[k + "[]"] = KVal(
+            lax.dynamic_slice(ctx.bufs[k], (start,), (ctx.B,)), ctx.buf_ctypes[k])
+        ctx.own[k] = (k + "[]", expr)
+        ctx.carried.add((id(node), k))
+    held = [k for k in carried_bufs if k in ctx.own]
+    carried_vars = sorted(carried_vars + [ctx.own[k][0] for k in held])
+    carried_bufs = [k for k in carried_bufs if k not in held]
     if _loop_counted(ctx, node):
         _exec_counted(ctx, node, cond_expr, body_core, step_stmt,
                       carried_vars, carried_bufs)
-        return
+    else:
+        _exec_masked(ctx, node, cond_expr, body_core, step_stmt,
+                     carried_vars, carried_bufs)
+    for k, (_expr, start) in own.items():
+        local = ctx.env.pop(ctx.own.pop(k)[0])
+        ctx.bufs[k] = lax.dynamic_update_slice(ctx.bufs[k], local.value, (start,))
+        ctx.invalidate_padded(k)
+        ctx.stored.add(k)
+
+
+def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
+                 carried_vars: list, carried_bufs: list) -> None:
+    """A loop that lanes may leave on different passes: a vectorized
+    lax.while_loop with a per-item active mask (see module docstring)."""
     run_var, run_tables = None, []
     if not ctx.pallas:
         run_var, run_tables = _run_reads(ctx, node, cond_expr, carried_bufs)
@@ -1468,6 +1962,7 @@ def _exec_loop(ctx: _Ctx, node) -> None:
         for rest in ctx._after_stack:
             _vars_read(rest, read_later)
         freerun = {v for v in carried_vars if v not in read_later}
+        freerun -= {local for local, _ in ctx.own.values()}  # stored behind
 
     # broadcast carried locals to the work-item shape so loop-carry shapes
     # are stable (broadcast_scalar: the Pallas subclass forces a computed
@@ -1949,6 +2444,9 @@ def _stored_bufs(stmts: list) -> set[str]:
 # ---------------------------------------------------------------------------
 
 
+ACCESS_KINDS = ("slice", "strided", "uniform", "gather", "scatter", "carried")
+
+
 @dataclass
 class KernelBuildInfo:
     """Static description of one compiled kernel function."""
@@ -1972,6 +2470,16 @@ class KernelBuildInfo:
     # per-lane active mask
     loops_counted: int = 0
     loops_masked: int = 0
+    # how the kernel's buffer accesses were lowered, counted over its
+    # access sites by the walk that lowers them (filled at trace): loads
+    # and stores by ``slice`` (contiguous), loads by ``strided`` window or
+    # column, by ``uniform`` scalar, by per-lane ``gather``, stores by
+    # ``scatter``, and ``carried``: buffers riding a loop as a local
+    access: dict = field(default_factory=dict)
+    # the value parameters taken as keys of the launcher cache because the
+    # kernel multiplies with them inside an index (:func:`pitch_params`),
+    # with the values of the newest build
+    keyed: dict = field(default_factory=dict)
 
 
 def hlo_name(*kernel_names: str) -> str:
@@ -1989,6 +2497,7 @@ def build_kernel_fn(
     local_size: int,
     global_size: int,
     platform: str | None = None,
+    in_range: bool = True,
 ) -> tuple[Callable, KernelBuildInfo]:
     """Build the vectorized launch function for one kernel.
 
@@ -1997,7 +2506,11 @@ def build_kernel_fn(
     updated arrays (all array params, in declaration order).  ``offset`` is a
     runtime scalar — re-balancing never recompiles.  ``chunk`` is static.
     ``platform`` is the lane's: on ``"tpu"`` a per-lane gather reads whole
-    rows (:func:`_take_rows`).
+    rows (:func:`_take_rows`).  ``in_range``: every launch of this build
+    keeps ``[offset, offset+chunk)`` inside ``[0, global_size)``, which is
+    what proves an affine access in bounds; a launch that cannot promise it
+    (a compute with a global offset) takes the build without the proof,
+    whose windows clamp and whose masked stores scatter.
     """
     array_params = [p for p in kernel.params if p.is_pointer]
     value_params = [p for p in kernel.params if not p.is_pointer]
@@ -2011,18 +2524,70 @@ def build_kernel_fn(
 
     uniform = _uniform_vars(kernel.body, {p.name for p in value_params})
     info.loops_counted, info.loops_masked = _loop_counts(kernel, uniform)
+    pitches = pitch_params(kernel)
 
-    def fn(offset, arrays: tuple, values: tuple = ()):
-        ctx = _Ctx(chunk, jnp.asarray(offset, jnp.int32), global_size, local_size, {})
+    def fn(offset, arrays: tuple, values: tuple = (), keys: tuple | None = None):
+        ctx = _Ctx(chunk, jnp.asarray(offset, jnp.int32), global_size, local_size, {},
+                   in_range)
         ctx.adopt(kernel, uniform)
         ctx.row_gathers = platform == "tpu"
         for p, arr in zip(array_params, arrays):
             ctx.bufs[p.name] = arr
             ctx.buf_ctypes[p.name] = p.ctype
         for p, v in zip(value_params, values):
-            ctx.env[p.name] = KVal(jnp.asarray(v, ctype_to_dtype(p.ctype)), p.ctype)
+            v = jnp.asarray(v, ctype_to_dtype(p.ctype))
+            # an integer argument is the same in every lane: stride 0
+            uniform_int = p.ctype in _INT_TYPES and v.ndim == 0
+            ctx.env[p.name] = KVal(v, p.ctype, (0, v) if uniform_int else None)
+        # the launcher's keys: build-time integers in the arguments' place
+        info.keyed = dict(zip((value_params[i].name for i in pitches), keys or ()))
+        for name, v in info.keyed.items():
+            ctx.env[name] = _int_const(v, ctx.env[name].ctype)
         _exec_block(ctx, kernel.body)
         info.stored_params = [n for n in info.array_params if n in ctx.stored]
+        info.access = dict.fromkeys(ACCESS_KINDS, 0)
+        for kind in ctx.access.values():
+            info.access[kind] += 1
+        info.access["carried"] = len(ctx.carried)
         return tuple(ctx.bufs[p.name] for p in array_params)
 
     return fn, info
+
+
+def pitch_params(kernel: KernelDef) -> tuple:
+    """Positions, among the kernel's value parameters, of those it
+    multiplies with inside an array index: ``a[i * n + j]``, ``a[j * n +
+    i]``, or through a local that an index reads (``int row = i * n``).  Such
+    an argument is a shape in disguise, the pitch of a 2-D array, and a
+    launcher is built for each value of it as one is for each shape
+    (kernel/registry.py): only with the pitch known is the row walk a
+    window of a 2-D view and the access provably in bounds.  Decided from
+    the syntax tree: an ``int`` parameter nothing assigns to, a bare factor
+    of a ``*`` in an expression that feeds an index."""
+    names = [p.name for p in kernel.params if not p.is_pointer]
+    cands = {p.name for p in kernel.params if not p.is_pointer
+             and p.ctype in _INT_TYPES} - _assigned_vars(kernel.body)
+    if not cands:
+        return ()
+    sources: dict[str, list] = {}   # local -> the expressions assigned to it
+    for node in _walk(kernel.body):
+        if isinstance(node, Decl):
+            for name, init in node.names:
+                if init is not None:
+                    sources.setdefault(name, []).append(init)
+        elif isinstance(node, Assign) and isinstance(node.target, Var):
+            sources.setdefault(node.target.name, []).append(node.value)
+    # every expression that feeds an index: the indices, and what is assigned
+    # to the locals they read
+    feeding = [ix.index for ix in _index_nodes(kernel.body)]
+    seen: set[str] = set()
+    found: set[str] = set()
+    while feeding:
+        for node in _walk(feeding.pop()):
+            if isinstance(node, BinOp) and node.op == "*":
+                found.update(x.name for x in (node.left, node.right)
+                             if isinstance(x, Var) and x.name in cands)
+            if isinstance(node, Var) and node.name not in seen:
+                seen.add(node.name)
+                feeding.extend(sources.get(node.name, ()))
+    return tuple(i for i, name in enumerate(names) if name in found)

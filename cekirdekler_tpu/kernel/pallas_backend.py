@@ -411,6 +411,7 @@ def build_kernel_fn_pallas(
     block_rows: int = DEFAULT_ROWS,
     interpret: bool = False,
     force: bool = False,
+    in_range: bool = True,
 ) -> tuple[Callable, KernelBuildInfo]:
     """Build the Pallas tile launch function for one kernel geometry.
 
@@ -421,7 +422,7 @@ def build_kernel_fn_pallas(
     subset, the chunk doesn't tile, or the measured routing policy prefers
     the XLA lowering for this access mix (``force=True`` skips the policy
     veto — used by tests and ``chip_smoke.py`` to exercise the halo path
-    directly)."""
+    directly).  ``in_range`` is the XLA fallback's (codegen.build_kernel_fn)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -486,7 +487,7 @@ def build_kernel_fn_pallas(
     def xla_fn():
         if not _xla_fallback:
             f, _ = codegen.build_kernel_fn(
-                kernel, chunk, local_size, global_size, "tpu")
+                kernel, chunk, local_size, global_size, "tpu", in_range)
             _xla_fallback.append(f)
         return _xla_fallback[0]
 

@@ -24,6 +24,7 @@ from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..errors import KernelCompileError
 from ..trace.spans import TRACER
@@ -78,15 +79,29 @@ class _KernelLauncher(_Launcher):
     would copy those on every launch and hold them twice.  The trace
     checks that nothing outside ``kept`` was replaced.  ``.lower`` and
     ``.trace`` are the executable's own: their outputs are the ``kept``
-    arrays alone."""
+    arrays alone.
 
-    __slots__ = ("_kept",)
+    ``pitches`` are the positions of the value arguments that the kernel
+    multiplies with inside an index (``codegen.pitch_params``: the pitch of
+    a 2-D array, a shape in disguise).  Where a call gives them as plain
+    integers they are KEYS of the executable, as a shape is: one build a
+    value, in which the lowering sees the integer.  A traced or array-valued
+    pitch stays a runtime argument (the build without keys), and so does
+    every value after the first ``KEYED_BUILDS``: a factor that changes from
+    call to call (a reduction's or an FFT's stride) is no shape, and must
+    not compile a launcher a value."""
 
-    def __init__(self, raw_fn, tag: str, info, static: bool, kept: tuple):
-        self._kept = kept
+    __slots__ = ("_kept", "_pitches", "_keyed")
+    KEYED_BUILDS = 4
 
-        def replaced(offset, arrays: tuple, values: tuple = ()):
-            out = raw_fn(offset, arrays, values)
+    def __init__(self, raw_fn, tag: str, info, static: bool, kept: tuple,
+                 pitches: tuple = ()):
+        self._kept, self._pitches = kept, pitches
+        self._keyed: set = set()  # the key tuples that have a build
+
+        def replaced(offset, arrays: tuple, values: tuple = (), keys=None):
+            out = (raw_fn(offset, arrays, values, keys) if pitches
+                   else raw_fn(offset, arrays, values))
             stray = [i for i, (a, o) in enumerate(zip(arrays, out))
                      if o is not a and i not in kept]
             assert not stray, (
@@ -96,15 +111,31 @@ class _KernelLauncher(_Launcher):
 
         replaced.__name__ = raw_fn.__name__
         super().__init__(
-            jax.jit(replaced, static_argnums=(2,) if static else ()),
+            jax.jit(replaced, static_argnums=(2, 3) if static else (3,)),
             tag, info)
 
     def __call__(self, offset, arrays, values=()):
-        new = super().__call__(offset, tuple(arrays), values)
+        keys = None
+        if self._pitches and all(
+                isinstance(values[i], (int, np.integer)) for i in self._pitches):
+            keys = tuple(int(values[i]) for i in self._pitches)
+            if keys not in self._keyed:
+                if len(self._keyed) >= self.KEYED_BUILDS:
+                    keys = None
+                else:
+                    self._keyed.add(keys)
+        new = super().__call__(offset, tuple(arrays), values, keys)
         out = list(arrays)
         for i, buf in zip(self._kept, new):
             out[i] = buf
         return tuple(out)
+
+
+def _beyond(in_range: bool) -> tuple:
+    """The tail of a launcher-cache key: nothing for a build whose launches
+    stay inside the global range (every key's shape before PR 30), one
+    marker for the build of launches that reach beyond it."""
+    return () if in_range else ("beyond-range",)
 
 
 def lowering_meta(infos) -> dict:
@@ -124,6 +155,21 @@ def lowering_meta(infos) -> dict:
     vetoes = sorted({i.veto for i in leaves if i.veto})
     if vetoes:
         meta["veto"] = "; ".join(vetoes)
+    # the access sites by how they were lowered, summed over the KERNELS
+    # (the rungs of one kernel are builds of the same sites: the most of
+    # each kind), and the value arguments that were launcher keys
+    per_kernel: dict = {}
+    for i in leaves:
+        mine = per_kernel.setdefault(i.name, {})
+        for kind, n in i.access.items():
+            mine[kind] = max(mine.get(kind, 0), n)
+    if any(per_kernel.values()):
+        meta["access"] = ";".join(
+            f"{kind}:{sum(k.get(kind, 0) for k in per_kernel.values())}"
+            for kind in codegen.ACCESS_KINDS)
+    keyed = sorted({f"{k}={v}" for i in leaves for k, v in i.keyed.items()})
+    if keyed:
+        meta["keys"] = ";".join(keyed)
     return meta
 
 
@@ -235,11 +281,12 @@ class KernelProgram:
         operand shapes/dtypes via XLA's own per-signature cache, or the
         baked value constants)."""
         with self._lock:
-            # fused keys are the 9-tuples built below; a plain launcher
-            # key for a user kernel literally named "fused" is a 5-tuple
-            # and must not count
+            # fused keys are the 9-tuples built below (10 with the
+            # beyond-range marker); a plain launcher key for a user kernel
+            # literally named "fused" is a 5-tuple (6) and must not count
             return sum(
-                1 for k in self._cache if k and k[0] == "fused" and len(k) == 9
+                1 for k in self._cache
+                if k and k[0] == "fused" and len(k) in (9, 10)
             )
 
     def compiled_counts_by_platform(self) -> dict[str, int]:
@@ -252,11 +299,11 @@ class KernelProgram:
         with self._lock:
             out: dict[str, int] = {}
             for k in self._cache:
-                if k and k[0] == "fused" and len(k) == 9:
+                if k and k[0] == "fused" and len(k) in (9, 10):
                     p = k[7]
-                elif k and k[0] == "seq" and len(k) == 9:
+                elif k and k[0] == "seq" and len(k) in (9, 10):
                     p = k[8]
-                elif len(k) == 5:
+                elif len(k) in (5, 6):
                     p = k[4]
                 else:  # future key shape: never miscount, bucket as ?
                     p = "?"
@@ -284,7 +331,7 @@ class KernelProgram:
         with self._lock:
             infos = [
                 info for key, (_fn, info) in self._cache.items()
-                if len(key) == 5 and key[0] == name and key[4] == platform
+                if len(key) in (5, 6) and key[0] == name and key[4] == platform
             ]
         return {(i.lowering, i.veto) for i in infos}
 
@@ -338,10 +385,18 @@ class KernelProgram:
         local_size: int,
         global_size: int,
         platform: str | None = None,
+        in_range: bool = True,
     ) -> tuple[Callable, Any]:
         """Get (building if needed) the jitted launch function for one
         geometry.  Signature: ``fn(offset, arrays_tuple, values_tuple) ->
         updated arrays tuple``.
+
+        ``in_range``: the caller's word that every launch of this function
+        keeps its work items inside ``[0, global_size)``, from which the
+        vectorized lowering proves affine accesses in bounds.  A launch
+        that reaches beyond (a compute with a global offset:
+        ``Worker.launch`` decides it from its own offset and size) gets the
+        build without the proof, a key of its own.
 
         ``platform`` is the dispatch target's PJRT platform name
         (``"tpu"``/``"cpu"``): on TPU, C-subset kernels in the tile
@@ -350,7 +405,7 @@ class KernelProgram:
         away by the measured policy, take the vectorized XLA lowering.
         ``info.lowering`` / ``info.veto`` record which was built and why
         — see :meth:`lowerings`."""
-        key = (name, chunk, local_size, global_size, platform)
+        key = (name, chunk, local_size, global_size, platform) + _beyond(in_range)
         with self._lock:
             hit = self._cache.get(key)
         if hit is not None:
@@ -363,14 +418,15 @@ class KernelProgram:
 
                 try:
                     raw_fn, info = pallas_backend.build_kernel_fn_pallas(
-                        self._c_kernels[name], chunk, local_size, global_size
+                        self._c_kernels[name], chunk, local_size, global_size,
+                        in_range=in_range,
                     )
                 except pallas_backend.PallasUnsupported as e:
                     veto = str(e)
             if raw_fn is None:
                 raw_fn, info = codegen.build_kernel_fn(
                     self._c_kernels[name], chunk, local_size, global_size,
-                    platform,
+                    platform, in_range,
                 )
                 info.veto = veto
         elif name in self._py_kernels:
@@ -407,12 +463,17 @@ class KernelProgram:
         stores = (codegen._stored_bufs(self._c_kernels[name].body)
                   if name in self._c_kernels else info.array_params)
         kept = tuple(i for i, p in enumerate(info.array_params) if p in stores)
+        # the value arguments that key the executable: a C kernel's pitches,
+        # where the vectorized lowering built it (which can use them)
+        pitches = (codegen.pitch_params(self._c_kernels[name])
+                   if name in self._c_kernels and info.lowering == "xla" else ())
         # whichever lowering built it: the XLA module reads jit_<kernel>
         raw_fn.__name__ = codegen.hlo_name(name)
         jitted = _KernelLauncher(
             raw_fn,
             f"{name} chunk={chunk} lr={local_size} g={global_size} "
-            f"{platform}", info, static, kept)
+            f"{platform}" + ("" if in_range else " beyond-range"),
+            info, static, kept, pitches)
         with self._lock:
             self._cache[key] = (jitted, info)
         return jitted, info
@@ -427,6 +488,7 @@ class KernelProgram:
         sync_kernel: str | None,
         value_args,
         platform: str | None = None,
+        in_range: bool = True,
     ) -> Callable | None:
         """One jitted function running the whole kernel sequence over the
         launch ladder ``repeats`` times as an on-device ``lax.fori_loop`` —
@@ -437,7 +499,7 @@ class KernelProgram:
         Scalar values are baked as compile-time constants (part of the
         cache key) — repeat mode recompiles when they change.  Returns
         ``None`` when the values are unhashable (caller falls back to the
-        host loop).
+        host loop).  ``in_range`` is the rung launchers' (:meth:`launcher`).
         """
         from jax import lax
 
@@ -450,7 +512,7 @@ class KernelProgram:
         try:
             sig = tuple(sorted((n, vals_for(n)) for n in all_names))
             key = ("seq", names, chunks, local_size, global_size, repeats,
-                   sync_kernel, sig, platform)
+                   sync_kernel, sig, platform) + _beyond(in_range)
             with self._lock:
                 hit = self._cache.get(key)
         except TypeError:
@@ -470,7 +532,8 @@ class KernelProgram:
                 n_arr = self.array_param_count(name)
                 for chunk in chunks:
                     fn, rungs[name, chunk] = self.launcher(
-                        name, chunk, local_size, global_size, platform)
+                        name, chunk, local_size, global_size, platform,
+                        in_range)
                     out = fn(off, bufs[:n_arr], vals_for(name))
                     bufs = tuple(out) + bufs[n_arr:]
                     off = off + chunk
@@ -512,6 +575,7 @@ class KernelProgram:
         platform: str | None = None,
         donate: bool = False,
         build: bool = True,
+        in_range: bool = True,
     ) -> Callable | None:
         """ONE executable for the fused-iteration dispatch path
         (core/cores.py): ``fn(offset, units, iters, bufs) -> bufs`` runs
@@ -548,7 +612,11 @@ class KernelProgram:
         built it.  A multi-rung per-call launch (``Worker.launch``) rides
         the executable that way with ``iters=1``; it must never build
         one, because its values change freely from call to call and each
-        new value would compile."""
+        new value would compile.
+
+        ``in_range`` is the rung launchers' (:meth:`launcher`): whether the
+        window's ``[offset, offset + units·step)`` stays inside the global
+        range, as every window of a compute without a global offset does."""
         from jax import lax
 
         def vals_for(name: str) -> tuple:
@@ -559,7 +627,7 @@ class KernelProgram:
         try:
             sig = tuple(sorted((n, vals_for(n)) for n in set(names)))
             key = ("fused", names, step, total_range, local_size,
-                   global_size, sig, platform, donate)
+                   global_size, sig, platform, donate) + _beyond(in_range)
             with self._lock:
                 hit = self._cache.get(key)
         except TypeError:
@@ -585,7 +653,8 @@ class KernelProgram:
                 for k in reversed(range(nbits)):
                     chunk = step << k
                     fn, rungs[name, chunk] = self.launcher(
-                        name, chunk, local_size, global_size, platform
+                        name, chunk, local_size, global_size, platform,
+                        in_range,
                     )
                     bit = (jnp.asarray(units, jnp.int32) >> k) & 1
 

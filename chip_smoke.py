@@ -64,6 +64,7 @@ FULL = {
     "flash_T": (4096, 8192), "flash_tiled_T": 640, "flash_dense_T": 96,
     "flash_oneshot_T": 512, "qkv_T": 1024,
     "saxpy_n": 1 << 22, "backend_n": 1 << 20, "backend_nbody_n": 4096,
+    "affine_n": 4096,
     # stage 6
     "trace_iters": 8,
 }
@@ -816,6 +817,37 @@ def stage_kernels(devices, sizes) -> list[dict]:
     backend(NBODY_SRC, "nBody", nb, (*pos, zero, zero, zero),
             (np.int32(nb), np.float32(1e-4)), "SMEM uniform gather",
             want=(*pos, *v1), tol=0.01, counted=1)
+
+    # affine accesses on the vectorized-XLA lowering (Pallas vetoes them):
+    # PolyBench/GPU's MVT, a matrix walked by rows and by columns with the
+    # sums in global memory, built as the registry builds it for this lane.
+    # A walk that fell back to a per-lane gather or a scatter a pass would
+    # still be right, and ten thousand times slower (PERF.md, PR 30)
+    from cekirdekler_tpu.kernel.registry import KernelProgram
+
+    n = sizes["affine_n"]
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs", "polybench_mvt.cl")) as f:
+        mvt = KernelProgram(f.read())
+    a = rng.standard_normal(n * n, dtype=np.float32)
+    y1, y2 = (rng.standard_normal(n, dtype=np.float32) for _ in range(2))
+    a64 = a.reshape(n, n).astype(np.float64)
+    arrays = tuple(jax.device_put(h, dev) for h in (
+        a, np.zeros(n, np.float32), np.zeros(n, np.float32), y1, y2))
+    for name, at, want in (("mvt_kernel1", 1, a64 @ y1),
+                           ("mvt_kernel2", 2, a64.T @ y2)):
+        fn, info = mvt.launcher(name, n, 256, n, platform=plat)
+        got, cold_s, run_s = first_and_repeat(
+            lambda *arrs: fn(0, arrs, (n,)), *arrays)
+        _require(info.access["gather"] == 0 and info.access["scatter"] == 0
+                 and info.access["carried"] == 1 and info.keyed == {"n": n},
+                 f"{name}: access {info.access}, keys {info.keyed}")
+        err = float(np.abs(np.asarray(got[at]) - want).max()
+                    / np.abs(want).max())
+        _require(err < 1e-5, f"{name}: rel err {err}")
+        rows.append(_row(f"affine {name}", info.lowering, cold_s, run_s, err,
+                         access=";".join(f"{k}:{v}"
+                                         for k, v in info.access.items())))
 
     # the driver's own entry point (beside this script)
     import __graft_entry__ as graft

@@ -28,6 +28,7 @@ TOY = dict(
     flash_bhd=(1, 2, 16), flash_T=(256,), flash_tiled_T=128,
     flash_dense_T=96, flash_oneshot_T=128, qkv_T=128,
     saxpy_n=1 << 10, backend_n=1 << 12, backend_nbody_n=256,
+    affine_n=256,
     trace_iters=3,
 )
 

@@ -48,6 +48,18 @@ def _run_both(src: str, arrays: dict, values: dict, atol=1e-4):
             err_msg=f"compiled vs oracle divergence in array {n!r}:\n{src}",
         )
 
+    # a kernel that multiplies with an argument inside an index is ALSO built
+    # with that argument as a key, the way a launcher builds it for a call
+    # that gives a plain integer (affine accesses: slices, strided windows)
+    pitches = codegen.pitch_params(kdef)
+    if pitches:
+        keyed = fn(0, jarrs, vals, tuple(int(vals[i]) for i in pitches))
+        for n, a in zip(order, keyed):
+            np.testing.assert_allclose(
+                np.asarray(a), oracle_arrays[n], rtol=1e-4, atol=atol,
+                err_msg=f"keyed build vs oracle divergence in {n!r}:\n{src}",
+            )
+
     # three-way: the Pallas tile lowering, when the kernel is in-subset
     try:
         pl_fn, _ = build_kernel_fn_pallas(kdef, N, 64, N, interpret=True,
@@ -197,6 +209,52 @@ def test_oracle_random_gather_kernels(seed):
         "x": rng.standard_normal(N).astype(np.float32),
         "out": np.zeros(N, np.float32),
     }, {})
+
+
+AFFINE_FORMS = {
+    # the column walk, the row walk, an array of structures' field, a
+    # structure of arrays' field: ``s * gid + u`` with ``u`` lane-uniform
+    "col": ("a[j * p + i + {c}]", "p"),
+    "row": ("a[i * p + j + {c}]", "p"),
+    "aos": ("a[{s} * i + j + {c}]", "{s}"),
+    "soa": ("a[i + j * p + {c}]", "t"),
+}
+
+
+@pytest.mark.parametrize("seed", range(16))
+def test_oracle_random_affine_kernels(seed):
+    """Randomized affine index forms vs the oracle, in a counted loop and
+    (odd seeds) with a divergent loop inside it; guarded and not; an offset
+    that can push the walk past either end of the buffer (loads clamp); the
+    lane's own element accumulated in global memory."""
+    rng = np.random.default_rng(500 + seed)
+    form = sorted(AFFINE_FORMS)[seed % 4]
+    index, bound = AFFINE_FORMS[form]
+    stride = int(rng.integers(2, 5))
+    pitch = int(rng.choice([8, 24, N]))
+    index = index.format(c=int(rng.integers(-3, 4)), s=stride)
+    trips = int(rng.integers(1, 9))
+    guard = int(rng.choice([N, N - 37]))
+    body = f"x[i] += {index} * y[j];"
+    if seed % 2:
+        body = f"""int u = 0;
+            while (u < (i & 3)) {{ {body} u++; }}"""
+    src = f"""
+    __kernel void k(__global float* a, __global float* x, __global float* y,
+                    int p, int t, int g) {{
+        int i = get_global_id(0);
+        if (i < g) {{
+            for (int j = 0; j < {bound.format(s=stride)}; j++) {{
+                {body}
+            }}
+        }}
+    }}"""
+    size = {"aos": stride * N, "row": pitch * N}.get(form, pitch * 8 + N)
+    _run_both(src, {
+        "a": rng.standard_normal(size).astype(np.float32),
+        "x": rng.standard_normal(N).astype(np.float32),
+        "y": rng.standard_normal(N).astype(np.float32),
+    }, {"p": pitch, "t": trips, "g": guard})
 
 
 def test_oracle_break_in_divergent_loop():
