@@ -721,6 +721,11 @@ class Worker:
                     plan = [(seq, 1)]
                 else:
                     plan = [(names, repeats)]
+                # a rung keeps views only of what NO kernel of this launch
+                # stores to: an array that comes back replaced call after
+                # call would have its views built again every time
+                frozen = program.frozen(
+                    tuple(names) + ((sync_kernel,) if sync_kernel else ()))
                 for names_seq, reps in plan:
                     for _ in range(reps):
                         for name in names_seq:
@@ -732,7 +737,8 @@ class Worker:
                                     in_range=in_range,
                                 )
                                 n_arr = program.array_param_count(name)
-                                out = fn(offset, bufs[:n_arr], tuple(va))
+                                out = fn(offset, bufs[:n_arr], tuple(va),
+                                         frozen=frozen)
                                 bufs = tuple(out) + bufs[n_arr:]
                                 offset += chunk
                                 dispatched += 1

@@ -43,11 +43,16 @@ Key mechanisms:
   ``[64 r, 64 r + 128)``: a run of 32 lies whole in one row) and moved up
   by the run's offset in six select stages over whole lines
   (``_run_window``), where a pass would gather a chunk-wide element each.
-  The view costs twice the table's bytes of device memory for as long as
-  the launch runs.  On a TPU lane any other per-lane gather fetches the
-  element's row of the plain view and picks its lane (``_take_rows``).
-  The chip gathers rows several times faster than it gathers elements
-  (PERF.md, PR 26, PR 27).
+  The view costs twice the table's bytes of device memory.  On a TPU lane
+  any other per-lane gather fetches the element's row of the plain view
+  and picks its lane (``_take_rows``).  The chip gathers rows several
+  times faster than it gathers elements (PERF.md, PR 26, PR 27).
+- **Kept views** — such a view, and the ``[rows, s]`` view a strided
+  window is cut from, is computed from ONE array and nothing else; of an
+  array the kernel never stores to it is the same launch after launch.  A
+  build reports the views it asks for and takes them as an argument
+  (:class:`ViewSpec`); the launcher builds each once an upload and keeps it
+  (kernel/registry.py).  Whatever a launch is not handed it builds itself.
 
 The launch boundary: ``build_kernel_fn`` returns ``fn(offset, *buffers,
 value_args) -> updated buffers``, where ``offset`` is a *runtime* scalar —
@@ -92,7 +97,8 @@ from .lang import (
     While,
 )
 
-__all__ = ["build_kernel_fn", "KernelBuildInfo", "ctype_to_dtype", "pitch_params"]
+__all__ = ["build_kernel_fn", "KernelBuildInfo", "ViewSpec", "ctype_to_dtype",
+           "pitch_params"]
 
 
 # ---------------------------------------------------------------------------
@@ -258,6 +264,13 @@ class _Ctx:
         # row views of buffers: (name, overlapping) -> (buffer, its
         # [rows, 128] view)
         self._rows_cache: dict[tuple[str, bool], tuple[Any, Any]] = {}
+        # KEPT VIEWS (see ViewSpec): the array parameters the kernel never
+        # stores to; the views of them that the launch was handed as
+        # arguments, (name, kind) -> view; and every (name, kind) the build
+        # asked for, handed or not (build_kernel_fn reports them)
+        self.readonly: frozenset[str] = frozenset()
+        self.kept: dict[tuple[str, str], Any] = {}
+        self.asked: set[tuple[str, str]] = set()
         # the innermost strided-window loop's reads: id of the Index node
         # -> this pass's row of its window (_exec_counted)
         self.windows: dict[int, Any] = {}
@@ -310,37 +323,32 @@ class _Ctx:
     def invalidate_padded(self, name: str) -> None:
         self._pad_cache.pop(name, None)
 
-    def rows_view(self, name: str, overlapping: bool = False):
-        """The buffer as ``[rows, 128]`` for the row gathers.  Element
-        ``i`` sits at ``i + 128`` of a padded copy: one row of the first
-        element before it, the last element repeated after it, so that a
-        read reaching over either end reads what a gather's clamp reads.
+    def kept_view(self, name: str, kind: str):
+        """The view ``kind`` of buffer ``name`` that the launch was handed,
+        or None: the build then makes it itself.  Only a parameter the
+        kernel never stores to can have one (its buffer is the launch's
+        argument wherever the body reads it); asking is what reports the
+        view to the launcher (:class:`ViewSpec`)."""
+        if name not in self.readonly:
+            return None
+        self.asked.add((name, kind))
+        return self.kept.get((name, kind))
 
-        The plain view (:func:`_take_rows`) is that copy cut into rows.
-        The OVERLAPPING view (:func:`_run_window`) has a row every 64
-        elements: row ``k`` holds ``[128 k, 128 k + 128)`` of the copy
-        and row ``K + k`` holds ``[128 k + 64, 128 k + 192)``, ``K`` rows
-        a half, so that a run of ``_RUN_WINDOW`` elements lies whole in
-        one row wherever it starts.  It is twice the buffer's bytes (one
-        concatenate: the halves are the copy, and the copy 64 elements
-        on).  A view is kept for as long as the buffer is the same
-        (``_exec_loop`` asks for a run table's before it enters)."""
+    def rows_view(self, name: str, overlapping: bool = False):
+        """The buffer as ``[rows, 128]`` for the row gathers: the plain
+        view (:func:`_rows_of`, for :func:`_take_rows`) or the OVERLAPPING
+        one (:func:`_runs_of`, for :func:`_run_window`).  A kept view where
+        the launch was handed one; else built here, once for as long as the
+        buffer is the same (``_exec_loop`` asks for a run table's before it
+        enters)."""
+        kept = self.kept_view(name, "runs" if overlapping else "rows")
+        if kept is not None:
+            return kept
         buf = self.bufs[name]
         hit = self._rows_cache.get((name, overlapping))
         if hit is not None and hit[0] is buf:
             return hit[1]
-        n = buf.shape[0]
-        if overlapping:
-            half, tail = _ROW // 2, -n % _ROW
-            lo, hi = buf[:1], buf[-1:]
-            rows = jnp.concatenate([
-                jnp.broadcast_to(lo, (_ROW,)), buf,
-                jnp.broadcast_to(hi, (tail,)),
-                jnp.broadcast_to(lo, (half,)), buf,
-                jnp.broadcast_to(hi, (tail + half,))])
-        else:
-            rows = jnp.pad(buf, (_ROW, -n % _ROW + _ROW), mode="edge")
-        rows = rows.reshape(-1, _ROW)
+        rows = (_runs_of if overlapping else _rows_of)(buf)
         self._rows_cache[(name, overlapping)] = (buf, rows)
         return rows
 
@@ -840,6 +848,82 @@ _RUN_WINDOW = 32       # passes one refill of a loop's run windows serves
 _LANE_CHUNK = 1 << 18  # work items whose rows are materialized at once
 
 
+def _rows_of(buf):
+    """``buf`` as ``[rows, 128]``.  Element ``i`` sits at ``i + 128`` of a
+    padded copy: one row of the first element before it, the last element
+    repeated after it, so that a read reaching over either end reads what a
+    gather's clamp reads.  The plain view is that copy cut into rows."""
+    n = buf.shape[0]
+    return jnp.pad(buf, (_ROW, -n % _ROW + _ROW), mode="edge").reshape(-1, _ROW)
+
+
+def _runs_of(buf):
+    """The OVERLAPPING ``[rows, 128]`` view, a row every 64 elements: row
+    ``k`` holds ``[128 k, 128 k + 128)`` of :func:`_rows_of`'s padded copy
+    and row ``K + k`` holds ``[128 k + 64, 128 k + 192)``, ``K`` rows a
+    half, so that a run of ``_RUN_WINDOW`` elements lies whole in one row
+    wherever it starts.  It is twice the buffer's bytes (one concatenate:
+    the halves are the copy, and the copy 64 elements on)."""
+    n = buf.shape[0]
+    half, tail = _ROW // 2, -n % _ROW
+    lo, hi = buf[:1], buf[-1:]
+    return jnp.concatenate([
+        jnp.broadcast_to(lo, (_ROW,)), buf,
+        jnp.broadcast_to(hi, (tail,)),
+        jnp.broadcast_to(lo, (half,)), buf,
+        jnp.broadcast_to(hi, (tail + half,))]).reshape(-1, _ROW)
+
+
+class ViewSpec(NamedTuple):
+    """A launch-invariant VIEW a build asks for: a value computed from ONE
+    array parameter and nothing else (no offset, no value argument but a
+    launcher key, no other array), of a parameter the kernel never stores
+    to.  What launch k + 1 would build is bit for bit what launch k built
+    for as long as the array is the same object, so a launcher may build it
+    once, when it first meets the array, and hand it to every launch as an
+    argument (kernel/registry.py keeps them).  ``build_kernel_fn`` reports
+    the specs a trace asked for in ``KernelBuildInfo.views`` and its ``fn``
+    takes the built views as ``views={(param, kind): view}``; whatever it is
+    not handed it builds in the launch.
+
+    ``kind``: ``rows`` (:func:`_rows_of`), ``runs`` (:func:`_runs_of`),
+    ``pitch:<s>`` (the buffer as ``[rows, s]``: a strided window's view)."""
+
+    param: int  # position among the kernel's array parameters
+    kind: str
+
+    @property
+    def pitch(self) -> int | None:
+        """``s`` of a ``pitch:<s>`` view, None for the row views."""
+        return int(self.kind[6:]) if self.kind.startswith("pitch:") else None
+
+    @property
+    def build(self) -> Callable:
+        """``array -> view``: what the launch itself would compute, under a
+        name of its own (a jitted builder reads ``jit_view_<kind>``)."""
+        pitch = self.pitch
+        of = ((lambda buf: buf.reshape(-1, pitch)) if pitch else
+              {"rows": _rows_of, "runs": _runs_of}[self.kind])
+
+        def view(buf):
+            return of(buf)
+
+        view.__name__ = "view_" + hlo_name(self.kind)
+        return view
+
+    def nbytes(self, shape: tuple, itemsize: int) -> int:
+        """What the view of a buffer of ``shape`` holds on a device, where
+        a 2-D array lies in tiles of ``(8, 128)`` elements."""
+        n, pitch = shape[0], self.pitch
+        if pitch:
+            rows, cols = n // pitch, pitch
+        else:
+            blocks = -(-n // _ROW)
+            rows, cols = (2 * blocks + 2 if self.kind == "runs"
+                          else blocks + 2), _ROW
+        return (-(-rows // 8) * 8) * (-(-cols // _ROW) * _ROW) * itemsize
+
+
 def _by_lane_chunks(fn, ix, lead: tuple, dtype):
     """``fn(int32[C]) -> dtype[*lead, C]`` over ``ix`` in chunks of
     ``_LANE_CHUNK`` lanes, joined along the last axis: a row gather holds
@@ -977,24 +1061,32 @@ def _slice_clamped(buf, start, b: int):
     return _shift_fill(w, start - s0, first, last)
 
 
-def _strided_rows(ctx: _Ctx, buf, stride: int, blocked: bool):
-    """``(view, row0, moved)``: the buffer as rows of ``stride`` elements and
-    where the chunk's block of rows starts in it; ``moved`` is None when the
-    global range proves every lane's row inside the view, else how far the
-    block had to be moved back (:func:`_shift_fill` moves its lanes out
-    again).  The view is ``[rows, stride]``, or ``blocked`` ``[rows, stride
-    / 128, 128]``: on the chip a 1-D buffer lies in memory as its ``[n /
-    128, 128]`` view does, so the blocked view of a stride of whole tiles
-    costs nothing where the 2-D one is a copy of the buffer a launch (the
-    compiler's choice, not this code's: PERF.md, PR 30).  None when the
-    buffer is no whole number of rows, or has fewer rows than the chunk has
-    lanes."""
+def _strided_rows(ctx: _Ctx, name: str, stride: int, blocked: bool,
+                  window: bool = False):
+    """``(view, row0, moved)``: buffer ``name`` as rows of ``stride``
+    elements and where the chunk's block of rows starts in it; ``moved`` is
+    None when the global range proves every lane's row inside the view, else
+    how far the block had to be moved back (:func:`_shift_fill` moves its
+    lanes out again).  The view is ``[rows, stride]``, or ``blocked``
+    ``[rows, stride / 128, 128]``: on the chip a 1-D buffer lies in memory
+    as its ``[n / 128, 128]`` view does, so the blocked view of a stride of
+    whole tiles costs nothing where the 2-D one is a copy of the buffer (the
+    compiler's choice, not this code's: PERF.md, PR 30).  A ``window`` reads
+    whole ``(8, 128)`` tiles of the 2-D view and 512-byte pieces of the
+    blocked one (3.3 against 11.5 ms over 1 GiB: PERF.md, PR 30), so it asks
+    for the 2-D view as a KEPT one (:class:`ViewSpec`), whose copy is made
+    once an upload, and takes ``blocked`` only where the launch was not
+    handed it.  None when the buffer is no whole number of rows, or has
+    fewer rows than the chunk has lanes."""
+    buf = ctx.bufs[name]
     n = buf.shape[0]
     rows = n // stride
     if n % stride or rows < ctx.B:
         return None
-    view = buf.reshape((rows, stride // _ROW, _ROW) if blocked
-                       else (rows, stride))
+    view = ctx.kept_view(name, f"pitch:{stride}") if window else None
+    if view is None:
+        view = buf.reshape((rows, stride // _ROW, _ROW) if blocked
+                           else (rows, stride))
     g = ctx.global_size
     if ctx.in_range and isinstance(g, int) and g <= rows:
         return view, ctx.offset, None
@@ -1002,19 +1094,19 @@ def _strided_rows(ctx: _Ctx, buf, stride: int, blocked: bool):
     return view, row0, ctx.offset - row0
 
 
-def _strided_load(ctx: _Ctx, buf, idx: KVal):
-    """``buf[s * gid + u]`` for a build-time ``s >= 2`` and ``u`` proved in
-    ``[0, s)``: column ``u`` of the lanes' rows.  None where that is not
-    proved."""
+def _strided_load(ctx: _Ctx, name: str, idx: KVal):
+    """``buf[s * gid + u]`` of buffer ``name`` for a build-time ``s >= 2``
+    and ``u`` proved in ``[0, s)``: column ``u`` of the lanes' rows.  None
+    where that is not proved."""
     stride, u = idx.affine
     if (not isinstance(stride, int) or stride < 2 or idx.span is None
             or idx.span[0] < 0 or idx.span[1] >= stride):
         return None
-    at = _strided_rows(ctx, buf, stride, stride % _ROW == 0)
+    at = _strided_rows(ctx, name, stride, stride % _ROW == 0)
     if at is None:
         return None
     view, row0, moved = at
-    u = jnp.asarray(u, jnp.int32)
+    buf, u = ctx.bufs[name], jnp.asarray(u, jnp.int32)
     if view.ndim == 3:
         col = lax.dynamic_slice(view, (row0, u >> 7, u & (_ROW - 1)),
                                 (ctx.B, 1, 1))[:, 0, 0]
@@ -1092,7 +1184,7 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
         start = jnp.asarray(ctx.offset + c + lo, jnp.int32)
         return _loaded(lax.dynamic_slice(padded, (start,), (ctx.B,)), ctype)
     if idx.affine is not None and idx.affine[0] != 0:
-        col = _strided_load(ctx, buf, idx)
+        col = _strided_load(ctx, node.base, idx)
         if col is not None:
             _note(ctx, node, False, "strided")
             return _loaded(col, ctype)
@@ -1585,10 +1677,11 @@ def _window_sites(ctx: _Ctx, node, counted: _Trips) -> tuple:
                 + [t.stride for t in sites])
     placed = []
     for t in sites:
-        # whole blocks of the blocked view, where the rows hold them
+        # the kept 2-D view; without one, whole blocks of the blocked view
+        # where the rows hold them
         blocked = (width == _ROW and t.stride % _ROW == 0
                    and t.stride >= _ROW * (2 - t.aligned))
-        at = _strided_rows(ctx, ctx.bufs[t.node.base], t.stride, blocked)
+        at = _strided_rows(ctx, t.node.base, t.stride, blocked, window=True)
         if at is not None:
             placed.append(t._replace(at=at))
     return placed, width
@@ -2480,6 +2573,14 @@ class KernelBuildInfo:
     # kernel multiplies with them inside an index (:func:`pitch_params`),
     # with the values of the newest build
     keyed: dict = field(default_factory=dict)
+    # the launch-invariant views the newest trace asked for
+    # (:class:`ViewSpec`), and what the launcher made of them on its newest
+    # call: views handed to the launch as arguments, and how many of those
+    # had to be built on that call (kernel/registry.py; 0 and 0 for a build
+    # that asks for none, and in a warm window ``views_built`` is 0)
+    views: tuple = ()
+    views_kept: int = 0
+    views_built: int = 0
 
 
 def hlo_name(*kernel_names: str) -> str:
@@ -2505,6 +2606,10 @@ def build_kernel_fn(
     processes work items ``[offset, offset+chunk)`` and returns the tuple of
     updated arrays (all array params, in declaration order).  ``offset`` is a
     runtime scalar — re-balancing never recompiles.  ``chunk`` is static.
+    After them ``fn`` takes the launcher's ``keys`` (:func:`pitch_params`)
+    and ``views``, ``{(param, kind): view}``: the kept views it is handed
+    (:class:`ViewSpec`; every trace leaves what it asked for in
+    ``info.views``, so a trace without them is how a launcher learns them).
     ``platform`` is the lane's: on ``"tpu"`` a per-lane gather reads whole
     rows (:func:`_take_rows`).  ``in_range``: every launch of this build
     keeps ``[offset, offset+chunk)`` inside ``[0, global_size)``, which is
@@ -2525,12 +2630,17 @@ def build_kernel_fn(
     uniform = _uniform_vars(kernel.body, {p.name for p in value_params})
     info.loops_counted, info.loops_masked = _loop_counts(kernel, uniform)
     pitches = pitch_params(kernel)
+    readonly = frozenset(info.array_params) - _stored_bufs(kernel.body)
 
-    def fn(offset, arrays: tuple, values: tuple = (), keys: tuple | None = None):
+    def fn(offset, arrays: tuple, values: tuple = (), keys: tuple | None = None,
+           views: dict | None = None):
         ctx = _Ctx(chunk, jnp.asarray(offset, jnp.int32), global_size, local_size, {},
                    in_range)
         ctx.adopt(kernel, uniform)
         ctx.row_gathers = platform == "tpu"
+        ctx.readonly = readonly
+        ctx.kept = {(info.array_params[p], kind): v
+                    for (p, kind), v in (views or {}).items()}
         for p, arr in zip(array_params, arrays):
             ctx.bufs[p.name] = arr
             ctx.buf_ctypes[p.name] = p.ctype
@@ -2549,6 +2659,9 @@ def build_kernel_fn(
         for kind in ctx.access.values():
             info.access[kind] += 1
         info.access["carried"] = len(ctx.carried)
+        info.views = tuple(sorted(
+            ViewSpec(info.array_params.index(name), kind)
+            for name, kind in ctx.asked))
         return tuple(ctx.bufs[p.name] for p in array_params)
 
     return fn, info

@@ -19,6 +19,7 @@ Pallas kernels plug in the same way via ops/).
 from __future__ import annotations
 
 import threading
+import weakref
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
 
@@ -69,6 +70,103 @@ class _Launcher:
         return getattr(self._fn, name)
 
 
+class _KeptViews:
+    """The launch-invariant views (``codegen.ViewSpec``) of one program's
+    launchers: what a launch derives from a read-only array ALONE is built
+    when that array first meets a launcher and handed to every later launch
+    as an argument.
+
+    A view is built by a small jitted program of its own, kept under the
+    array OBJECT's identity and dropped with the object: a ``jax.Array``
+    cannot change, an upload makes a new one, and there is no other
+    invalidation.  The launchers of one program share them (a product's
+    four rungs are four launchers over the same two tables).
+
+    The rule that bounds them comes from the device: views are kept while
+    the bytes this program keeps on a device stay under ``SHARE`` of what
+    its ``memory_stats()`` reports as the limit; a view that would pass it
+    is left to the launch, which builds it as a temporary.  A device that
+    reports no limit (the CPU rig) bounds nothing."""
+
+    SHARE = 0.25
+
+    def __init__(self):
+        # callbacks of dying arrays re-enter on whichever thread drops the
+        # last reference, this one while it holds the lock included
+        self._lock = threading.RLock()
+        # (id of the array, kind) -> (weak reference, view, device, bytes)
+        self._kept: dict[tuple, tuple] = {}
+        self._bytes: dict[Any, int] = {}    # device -> bytes kept there
+        self._builders: dict[str, Callable] = {}
+        # what a kernel's builds ask for: launcher signature -> specs
+        self.asked: dict[tuple, tuple] = {}
+
+    @staticmethod
+    def limit(device) -> int | None:
+        """The device's memory limit in bytes, where it reports one."""
+        stats = device.memory_stats()
+        return stats.get("bytes_limit") if stats else None
+
+    def bytes_kept(self, device=None) -> int:
+        with self._lock:
+            return (sum(self._bytes.values()) if device is None
+                    else self._bytes.get(device, 0))
+
+    def _drop(self, key: tuple, ref) -> None:
+        with self._lock:
+            hit = self._kept.get(key)
+            if hit is not None and hit[0] is ref:  # not a later object's
+                del self._kept[key]
+                self._bytes[hit[2]] -= hit[3]
+
+    def views(self, arrays: tuple, specs) -> tuple[dict, int]:
+        """``({(param, kind): view}, built)``: the kept views of ``arrays``
+        among ``specs``, built now where an array is met for the first time
+        (``built`` counts those); a spec whose view the memory rule refuses
+        is left out."""
+        out, built = {}, 0
+        for spec in specs:
+            arr = arrays[spec.param]
+            key = (id(arr), spec.kind)
+            hit = self._kept.get(key)
+            if hit is None or hit[0]() is not arr:
+                hit = self._build(key, arr, spec)
+                if hit is None:
+                    continue
+                built += 1
+            out[spec] = hit[1]
+        return out, built
+
+    def _build(self, key: tuple, arr, spec):
+        devices = arr.devices()
+        if len(devices) != 1:
+            return None  # a sharded array: the launch builds its views
+        (device,) = devices
+        nbytes = spec.nbytes(arr.shape, arr.dtype.itemsize)
+        with self._lock:
+            hit = self._kept.get(key)
+            if hit is not None and hit[0]() is arr:
+                return hit  # another lane's thread was first
+            limit = self.limit(device)
+            if (limit is not None
+                    and self._bytes.get(device, 0) + nbytes > self.SHARE * limit):
+                return None
+            build = self._builders.get(spec.kind)
+            if build is None:
+                build = self._builders[spec.kind] = jax.jit(spec.build)
+            ref = weakref.ref(arr, lambda r, key=key: self._drop(key, r))
+            hit = self._kept[key] = (ref, build(arr), device, nbytes)
+            self._bytes[device] = self._bytes.get(device, 0) + nbytes
+            return hit
+
+
+def _concrete(arrays) -> bool:
+    """Are these device arrays (and not the tracers of an enclosing trace,
+    or host arrays), so that a view can be kept under their identity?"""
+    return all(isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer)
+               for a in arrays)
+
+
 class _KernelLauncher(_Launcher):
     """The launcher of ONE kernel over one chunk: ``fn(offset, arrays,
     values) -> arrays``.  The executable returns only the arrays the kernel
@@ -89,18 +187,31 @@ class _KernelLauncher(_Launcher):
     pitch stays a runtime argument (the build without keys), and so does
     every value after the first ``KEYED_BUILDS``: a factor that changes from
     call to call (a reduction's or an FFT's stride) is no shape, and must
-    not compile a launcher a value."""
+    not compile a launcher a value.
 
-    __slots__ = ("_kept", "_pitches", "_keyed")
+    ``views``: what the vectorized lowering derives from a read-only array
+    alone rides as one more argument (:class:`_KeptViews`).  A call on
+    device arrays looks the views up under the arrays' identity (``frozen``:
+    the positions no kernel of the caller's LAUNCH stores to; left out, this
+    kernel's own); a call from inside a ladder's trace is handed them."""
+
+    __slots__ = ("_kept", "_pitches", "_keyed", "_raw", "_views", "_sig")
     KEYED_BUILDS = 4
 
     def __init__(self, raw_fn, tag: str, info, static: bool, kept: tuple,
-                 pitches: tuple = ()):
+                 pitches: tuple = (), views: _KeptViews | None = None,
+                 sig: tuple = ()):
         self._kept, self._pitches = kept, pitches
         self._keyed: set = set()  # the key tuples that have a build
+        # ``views``: the program's, for a build of the vectorized lowering
+        # (the one that asks for any); ``sig``: what its builds share with
+        # the kernel's other chunks
+        self._raw, self._views, self._sig = raw_fn, views, sig
 
-        def replaced(offset, arrays: tuple, values: tuple = (), keys=None):
-            out = (raw_fn(offset, arrays, values, keys) if pitches
+        def replaced(offset, arrays: tuple, values: tuple = (), keys=None,
+                     views=None):
+            out = (raw_fn(offset, arrays, values, keys, views)
+                   if self._views is not None
                    else raw_fn(offset, arrays, values))
             stray = [i for i, (a, o) in enumerate(zip(arrays, out))
                      if o is not a and i not in kept]
@@ -114,21 +225,111 @@ class _KernelLauncher(_Launcher):
             jax.jit(replaced, static_argnums=(2, 3) if static else (3,)),
             tag, info)
 
-    def __call__(self, offset, arrays, values=()):
-        keys = None
-        if self._pitches and all(
+    def keys_of(self, values) -> tuple | None:
+        """The launcher keys among ``values``, where this call has any."""
+        if not self._pitches or not all(
                 isinstance(values[i], (int, np.integer)) for i in self._pitches):
-            keys = tuple(int(values[i]) for i in self._pitches)
-            if keys not in self._keyed:
-                if len(self._keyed) >= self.KEYED_BUILDS:
-                    keys = None
-                else:
-                    self._keyed.add(keys)
-        new = super().__call__(offset, tuple(arrays), values, keys)
+            return None
+        keys = tuple(int(values[i]) for i in self._pitches)
+        if keys not in self._keyed:
+            if len(self._keyed) >= self.KEYED_BUILDS:
+                return None
+            self._keyed.add(keys)
+        return keys
+
+    def wants(self, arrays, values, keys) -> tuple:
+        """The views (``codegen.ViewSpec``) a build of this kernel over
+        ``arrays`` (anything with ``shape`` and ``dtype``) asks for, of the
+        parameters the kernel itself never stores to.  Learnt from one
+        abstract trace (nothing compiles, nothing runs) a signature, which
+        the kernel's launchers of every chunk size share: what a kernel asks
+        for is a matter of its accesses, not of the lanes."""
+        if self._views is None:
+            return ()
+        sig = self._sig + (keys, tuple((a.shape, a.dtype) for a in arrays))
+        specs = self._views.asked.get(sig)
+        if specs is None:
+            jax.eval_shape(
+                lambda o, a, v: self._raw(o, a, v, keys),
+                jax.ShapeDtypeStruct((), jnp.int32),
+                tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrays),
+                tuple(values))
+            specs = self._views.asked[sig] = self.info.views
+        return specs
+
+    def __call__(self, offset, arrays, values=(), views=None, frozen=None):
+        arrays, keys = tuple(arrays), self.keys_of(values)
+        if views is None:
+            views, specs = {}, self.wants(arrays, values, keys)
+            if specs:
+                built = 0
+                if _concrete(arrays):
+                    views, built = self._views.views(
+                        arrays, specs if frozen is None else
+                        [s for s in specs if s.param in frozen])
+                self.info.views_kept, self.info.views_built = len(views), built
+        new = super().__call__(offset, arrays, values, keys, views)
         out = list(arrays)
         for i, buf in zip(self._kept, new):
             out[i] = buf
         return tuple(out)
+
+
+def _merged(moving, held: dict, n: int) -> tuple:
+    """The ``n`` buffers of a ladder in their positions again: ``held``
+    (position -> array) put back among the ``moving`` ones."""
+    rest = iter(moving)
+    return tuple(held[i] if i in held else next(rest) for i in range(n))
+
+
+def _moving(bufs, held: dict) -> tuple:
+    return tuple(b for i, b in enumerate(bufs) if i not in held)
+
+
+class _LadderLauncher(_Launcher):
+    """A ladder executable (repeat mode's, the fused window's):
+    ``fn(*scalars, bufs) -> bufs``.  Its rungs' kept views
+    (:class:`_KeptViews`) are looked up here, under the buffers' identity,
+    and ride the executable as arguments that its loops close over: loop
+    invariants, not carried.  A buffer that has a kept view is HELD: handed
+    to the executable beside the others, neither donated nor returned, and
+    put back as the object it was.  An array that comes back from an
+    executable is a new object, read-only or not, and its views would be
+    built again at every dispatch.  A ladder whose rungs ask for no view
+    holds nothing and is the program it was before there were views.
+
+    ``ask(bufs) -> specs``: the views the ladder's rungs ask for over
+    ``bufs``, of the positions no kernel of the ladder stores to."""
+
+    __slots__ = ("_ask", "_views", "_specs")
+
+    def __init__(self, fn, tag: str, info, ask, views: _KeptViews):
+        super().__init__(fn, tag, info)
+        self._ask, self._views = ask, views
+        self._specs: dict[tuple, tuple] = {}  # buffer signature -> specs
+
+    def __call__(self, *args):
+        bufs = tuple(args[-1])
+        views, held = {}, {}
+        if _concrete(bufs):
+            sig = tuple((b.shape, b.dtype) for b in bufs)
+            specs = self._specs.get(sig)
+            if specs is None:
+                specs = self._specs[sig] = self._ask(bufs)
+            if specs:
+                views, built = self._views.views(bufs, specs)
+                held = {s.param: bufs[s.param] for s in views}
+                self.info.views_kept, self.info.views_built = len(views), built
+        out = super().__call__(*args[:-1], _moving(bufs, held), held, views)
+        return _merged(out, held, len(bufs))
+
+
+def _views_of(fn, arrays, values, views: dict) -> dict:
+    """Of a ladder's kept ``views``, those its rung ``fn`` asks for."""
+    if not views:
+        return views
+    return {s: views[s] for s in fn.wants(arrays, values, fn.keys_of(values))
+            if s in views}
 
 
 def _beyond(in_range: bool) -> tuple:
@@ -146,12 +347,18 @@ def lowering_meta(infos) -> dict:
     per-lane mask; joined the same way; no comma, which would end the
     value in a profiler annotation) and, where a TPU build was routed
     away from Pallas, ``veto`` with the reason.  A ladder executable stands
-    for its rungs."""
+    for its rungs.  ``views`` (``kept:K;built:B``) is of the DISPATCHES the
+    span ran, one of ``infos`` each: the kept views they took as arguments
+    (:class:`_KeptViews`) and how many of those were built on these calls;
+    in a warm window ``built`` is 0."""
+    infos = list(infos)
     leaves = [r for i in infos for r in (i.rungs or (i,))]
     meta = {"lowering": "+".join(sorted({i.lowering for i in leaves})),
             "loops": "+".join(sorted(
                 {f"counted:{i.loops_counted};masked:{i.loops_masked}"
-                 for i in leaves}))}
+                 for i in leaves})),
+            "views": f"kept:{sum(i.views_kept for i in infos)};"
+                     f"built:{sum(i.views_built for i in infos)}"}
     vetoes = sorted({i.veto for i in leaves if i.veto})
     if vetoes:
         meta["veto"] = "; ".join(vetoes)
@@ -234,6 +441,11 @@ class KernelProgram:
         self._py_kernels: dict[str, PythonKernel] = {}
         self._cache: dict[tuple, tuple[Callable, Any]] = {}
         self._lock = threading.Lock()
+        # the launch-invariant views of the arrays its kernels only read,
+        # shared by its launchers, and the positions no kernel of a launch
+        # stores to (kernel names -> positions)
+        self.kept_views = _KeptViews()
+        self._frozen: dict[tuple, frozenset] = {}
         # partition-safety/flag-soundness verification (analysis/):
         # access summaries build once per kernel on first verify();
         # launch verdicts cache per (names, flag rows, window).  Both
@@ -322,6 +534,44 @@ class KernelProgram:
         if name in self._c_kernels:
             return [p.name for p in self._c_kernels[name].params if not p.is_pointer]
         return list(self._py_kernels[name].value_params)
+
+    def frozen(self, names: tuple) -> frozenset:
+        """The positions of a launch's buffer tuple that NO kernel among
+        ``names`` stores to (a C kernel's stores are its statements'; a
+        Python kernel may replace every array it takes): the arrays whose
+        views a launch of these kernels may keep."""
+        hit = self._frozen.get(names)
+        if hit is None:
+            widest = max(self.array_param_count(n) for n in names)
+            hit = self._frozen[names] = frozenset(range(widest)).difference(
+                *(self._stored(n) for n in names))
+        return hit
+
+    def _stored(self, name: str) -> tuple:
+        """The positions of the array parameters a launch of ``name`` may
+        replace: those a C kernel's statements store to, every one of a
+        Python kernel's."""
+        if name not in self._c_kernels:
+            return tuple(range(self.array_param_count(name)))
+        kdef = self._c_kernels[name]
+        stores = codegen._stored_bufs(kdef.body)
+        return tuple(i for i, p in enumerate(
+            p for p in kdef.params if p.is_pointer) if p.name in stores)
+
+    def _ladder_asks(self, rungs, frozen: frozenset):
+        """``ask(bufs) -> specs`` of a ladder (:class:`_LadderLauncher`)
+        whose kernels' launchers are ``rungs()``: ``(launcher, values)``,
+        one a kernel (a kernel's chunks ask alike)."""
+        def ask(bufs) -> tuple:
+            specs: set = set()
+            for fn, values in rungs():
+                n_arr = len(fn.info.array_params)
+                specs.update(s for s in fn.wants(
+                    bufs[:n_arr], values, fn.keys_of(values))
+                    if s.param in frozen)
+            return tuple(sorted(specs))
+
+        return ask
 
     def lowerings(self, name: str, platform: str | None) -> set[tuple]:
         """``{(lowering, veto), ...}`` over every launcher built so far
@@ -458,22 +708,23 @@ class KernelProgram:
             )
 
         static = name in self._py_kernels and self._py_kernels[name].static_values
-        # the array parameters a launch may replace: those a C kernel's
-        # statements store to, every one of a Python kernel's
-        stores = (codegen._stored_bufs(self._c_kernels[name].body)
-                  if name in self._c_kernels else info.array_params)
-        kept = tuple(i for i, p in enumerate(info.array_params) if p in stores)
+        kept = self._stored(name)
         # the value arguments that key the executable: a C kernel's pitches,
         # where the vectorized lowering built it (which can use them)
         pitches = (codegen.pitch_params(self._c_kernels[name])
                    if name in self._c_kernels and info.lowering == "xla" else ())
         # whichever lowering built it: the XLA module reads jit_<kernel>
         raw_fn.__name__ = codegen.hlo_name(name)
+        # the vectorized lowering is the one that asks for views; what it
+        # asks for, the kernel's launchers of every chunk share
+        xla = name in self._c_kernels and info.lowering == "xla"
         jitted = _KernelLauncher(
             raw_fn,
             f"{name} chunk={chunk} lr={local_size} g={global_size} "
             f"{platform}" + ("" if in_range else " beyond-range"),
-            info, static, kept, pitches)
+            info, static, kept, pitches,
+            self.kept_views if xla else None,
+            (name, local_size, global_size, platform, in_range))
         with self._lock:
             self._cache[key] = (jitted, info)
         return jitted, info
@@ -525,41 +776,56 @@ class KernelProgram:
             array_ctypes={}, stored_params=[], lowering="ladder",
         )
         rungs: dict = {}  # the rung launchers' infos, seen where traced
+        ordered = names + ((sync_kernel,) if sync_kernel else ())
+        frozen = self.frozen(ordered)
 
-        def run_names(names_seq, offset0, bufs):
+        def rung(name: str, chunk: int):
+            return self.launcher(name, chunk, local_size, global_size,
+                                 platform, in_range)
+
+        def run_names(names_seq, offset0, bufs, views):
             for name in names_seq:
                 off = offset0
                 n_arr = self.array_param_count(name)
                 for chunk in chunks:
-                    fn, rungs[name, chunk] = self.launcher(
-                        name, chunk, local_size, global_size, platform,
-                        in_range)
-                    out = fn(off, bufs[:n_arr], vals_for(name))
+                    fn, rungs[name, chunk] = rung(name, chunk)
+                    out = fn(off, bufs[:n_arr], vals_for(name),
+                             _views_of(fn, bufs[:n_arr], vals_for(name), views))
                     bufs = tuple(out) + bufs[n_arr:]
                     off = off + chunk
             info.rungs = tuple(rungs.values())
             return bufs
 
-        def raw(offset, bufs: tuple):
+        def raw(offset, bufs: tuple, held=None, views=None):
+            # ``bufs``: the buffers that move through the ladder; ``held``
+            # (position -> array) and the kept ``views`` are closed over
+            held, views = held or {}, views or {}
+            n = len(bufs) + len(held)
+
+            def run(names_seq, b):
+                return _moving(run_names(
+                    names_seq, offset, _merged(b, held, n), views), held)
+
             bufs = tuple(bufs)
             if repeats <= 1:
-                return run_names(names, offset, bufs)
+                return run(names, bufs)
             if sync_kernel:
                 def body(_, b):
-                    b = run_names(names, offset, b)
-                    return run_names((sync_kernel,), offset, b)
+                    return run((sync_kernel,), run(names, b))
 
                 bufs = lax.fori_loop(0, repeats - 1, body, bufs)
-                return run_names(names, offset, bufs)
-            return lax.fori_loop(
-                0, repeats, lambda _, b: run_names(names, offset, b), bufs
-            )
+                return run(names, bufs)
+            return lax.fori_loop(0, repeats, lambda _, b: run(names, b), bufs)
 
         raw.__name__ = "seq_" + codegen.hlo_name(*names)
-        jitted = _Launcher(
+        jitted = _LadderLauncher(
             jax.jit(raw),
             f"seq:{'+'.join(names)} x{repeats} g={global_size} {platform}",
-            info)
+            info,
+            self._ladder_asks(lambda: [(rung(n, chunks[0])[0], vals_for(n))
+                                       for n in dict.fromkeys(ordered)],
+                              frozen),
+            self.kept_views)
         with self._lock:
             self._cache[key] = (jitted, info)
         return jitted
@@ -645,22 +911,26 @@ class KernelProgram:
         )
         rungs: dict = {}  # the rung launchers' infos, seen where traced
 
-        def run_ladder(offset, units, bufs):
+        def rung(name: str, chunk: int):
+            return self.launcher(name, chunk, local_size, global_size,
+                                 platform, in_range)
+
+        def run_ladder(offset, units, bufs, held, views):
+            n = len(bufs) + len(held)
             for name in names:
                 n_arr = self.array_param_count(name)
                 va = vals_for(name)
                 off = jnp.asarray(offset, jnp.int32)
                 for k in reversed(range(nbits)):
                     chunk = step << k
-                    fn, rungs[name, chunk] = self.launcher(
-                        name, chunk, local_size, global_size, platform,
-                        in_range,
-                    )
+                    fn, rungs[name, chunk] = rung(name, chunk)
                     bit = (jnp.asarray(units, jnp.int32) >> k) & 1
 
                     def hit_branch(b, _fn=fn, _off=off, _va=va, _n=n_arr):
-                        out = _fn(_off, tuple(b)[:_n], _va)
-                        return tuple(out) + tuple(b)[_n:]
+                        b = _merged(b, held, n)
+                        out = _fn(_off, b[:_n], _va,
+                                  _views_of(_fn, b[:_n], _va, views))
+                        return _moving(tuple(out) + b[_n:], held)
 
                     bufs = lax.cond(
                         bit != 0, hit_branch, lambda b: tuple(b), tuple(bufs)
@@ -669,17 +939,26 @@ class KernelProgram:
             info.rungs = tuple(rungs.values())
             return bufs
 
-        def raw(offset, units, iters, bufs: tuple):
+        def raw(offset, units, iters, bufs: tuple, held=None, views=None):
+            # ``bufs``: the buffers that move through the ladder (donated
+            # where ``donate``); ``held`` (position -> array) and the kept
+            # ``views`` are closed over by the loop: invariants, not carried
+            held, views = held or {}, views or {}
             bufs = tuple(bufs)
             return lax.fori_loop(
-                0, iters, lambda _, b: run_ladder(offset, units, b), bufs
+                0, iters,
+                lambda _, b: run_ladder(offset, units, b, held, views), bufs
             )
 
         raw.__name__ = "fused_" + codegen.hlo_name(*names)
-        jitted = _Launcher(
+        jitted = _LadderLauncher(
             jax.jit(raw, donate_argnums=(3,) if donate else ()),
             f"fused:{'+'.join(names)} step={step} g={global_size} "
-            f"{platform}", info)
+            f"{platform}", info,
+            self._ladder_asks(lambda: [(rung(n, step)[0], vals_for(n))
+                                       for n in dict.fromkeys(names)],
+                              self.frozen(names)),
+            self.kept_views)
         with self._lock:
             self._cache[key] = (jitted, info)
         return jitted

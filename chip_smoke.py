@@ -65,6 +65,9 @@ FULL = {
     "flash_oneshot_T": 512, "qkv_T": 1024,
     "saxpy_n": 1 << 22, "backend_n": 1 << 20, "backend_nbody_n": 4096,
     "affine_n": 4096,
+    # HPCG's SpMV: the grid the smoke runs, and the grid and rung whose
+    # compiled program it only counts (the cell's: PERF.md s.4)
+    "spmv_side": 64, "spmv_count_side": 192, "spmv_count_chunk": 1 << 22,
     # stage 6
     "trace_iters": 8,
 }
@@ -823,7 +826,15 @@ def stage_kernels(devices, sizes) -> list[dict]:
     # sums in global memory, built as the registry builds it for this lane.
     # A walk that fell back to a per-lane gather or a scatter a pass would
     # still be right, and ten thousand times slower (PERF.md, PR 30)
-    from cekirdekler_tpu.kernel.registry import KernelProgram
+    from cekirdekler_tpu.kernel.registry import KernelProgram, lowering_meta
+
+    def kept_views(info, label: str) -> str:
+        """After a second call over the same arrays: the launch took its
+        views as arguments and built none (PERF.md, PR 31)."""
+        _require(info.views_kept == len(info.views) and info.views_built == 0,
+                 f"{label}: views kept:{info.views_kept};"
+                 f"built:{info.views_built} of {info.views}")
+        return lowering_meta((info,))["views"]
 
     n = sizes["affine_n"]
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -845,9 +856,65 @@ def stage_kernels(devices, sizes) -> list[dict]:
         err = float(np.abs(np.asarray(got[at]) - want).max()
                     / np.abs(want).max())
         _require(err < 1e-5, f"{name}: rel err {err}")
+        # the row walk reads the kept 2-D view of the matrix
+        _require(len(info.views) == (name == "mvt_kernel1"),
+                 f"{name}: asks for {info.views}")
         rows.append(_row(f"affine {name}", info.lowering, cold_s, run_s, err,
                          access=";".join(f"{k}:{v}"
-                                         for k, v in info.access.items())))
+                                         for k, v in info.access.items()),
+                         views=kept_views(info, name)))
+    del arrays, a, a64
+
+    # kept views on per-lane reads: HPCG's SpMV (run windows over ``col``
+    # and ``val``, row gathers of ``x``), as the registry builds it for this
+    # lane, against the grid's own product
+    import importlib.util
+
+    configs = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs")
+    spec = importlib.util.spec_from_file_location(
+        "hpcg_spmv_ref", os.path.join(configs, "hpcg_spmv_ref.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    with open(os.path.join(configs, "hpcg_spmv.cl")) as f:
+        spmv = KernelProgram(f.read())
+    side = sizes["spmv_side"]
+    cfg = {"nx": side, "ny": side, "nz": side, "alpha_cycle": [2.0]}
+    n = side ** 3
+    host, _values = ref.inputs(cfg, {"n": n}, rng)
+    arrays = tuple(jax.device_put(host[k], dev)
+                   for k in ("rowptr", "col", "val", "x", "y"))
+    fn, info = spmv.launcher("spmv", n, 256, n, platform=plat)
+    got, cold_s, run_s = first_and_repeat(
+        lambda *arrs: fn(0, arrs, (np.float32(2.0),)), *arrays)
+    want = ref.product(cfg, host["x"], 2.0)
+    err = float(np.abs(np.asarray(got[4]) - want).max() / np.abs(want).max())
+    _require(err < 1e-5, f"spmv: rel err {err}")
+    _require(len(info.views) >= 2, f"spmv: asks for {info.views}")
+    views = kept_views(info, "spmv")
+    # the compiler's count for the cell's largest rung, views as arguments
+    # against views built in the launch (the program of every launch before
+    # PR 31): what is held BETWEEN launches grows, a launch must not
+    side, chunk = sizes["spmv_count_side"], sizes["spmv_count_chunk"]
+    n, nnz = side ** 3, (3 * side - 2) ** 3
+    shapes = tuple(jax.ShapeDtypeStruct((k,), t) for k, t in (
+        (n + 1, jnp.int32), (nnz, jnp.int32), (nnz, jnp.float32),
+        (n, jnp.float32), (n, jnp.float32)))
+    alpha = (jax.ShapeDtypeStruct((), jnp.float32),)
+    big, _ = spmv.launcher("spmv", chunk, 256, n, platform=plat)
+    count = {}
+    for form, handed in (("kept", big.wants(shapes, alpha, None)),
+                         ("in_launch", ())):
+        mem = big.trace(
+            jax.ShapeDtypeStruct((), jnp.int32), shapes, alpha, None,
+            {s: jax.eval_shape(s.build, shapes[s.param]) for s in handed},
+        ).lower().compile().memory_analysis()
+        count[form] = {"arguments": int(mem.argument_size_in_bytes),
+                       "temporaries": int(mem.temp_size_in_bytes)}
+    _require(sum(count["kept"].values()) <= sum(count["in_launch"].values()),
+             f"spmv: a launch's arguments + temporaries grew: {count}")
+    rows.append(_row("views spmv", info.lowering, cold_s, run_s, err,
+                     views=views, launch_bytes=count))
 
     # the driver's own entry point (beside this script)
     import __graft_entry__ as graft

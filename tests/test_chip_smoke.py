@@ -28,7 +28,7 @@ TOY = dict(
     flash_bhd=(1, 2, 16), flash_T=(256,), flash_tiled_T=128,
     flash_dense_T=96, flash_oneshot_T=128, qkv_T=128,
     saxpy_n=1 << 10, backend_n=1 << 12, backend_nbody_n=256,
-    affine_n=256,
+    affine_n=256, spmv_side=8, spmv_count_side=12, spmv_count_chunk=1 << 10,
     trace_iters=3,
 )
 
@@ -98,7 +98,7 @@ def test_stage_serving(devs):
 
 def test_stage_kernels(devs):
     rows = chip_smoke.stage_kernels(devs, TOY)
-    _check_rows(rows, 11)
+    _check_rows(rows, 14)
     names = " | ".join(r["name"] for r in rows)
     for want in ("flash fwd+bwd T=256 highest", "flash fwd+bwd T=256 default",
                  "flash fwd T=128", "flash fwd T=96", "fused_qkv_attention",
@@ -108,6 +108,13 @@ def test_stage_kernels(devs):
     by = {r["name"]: r for r in rows}
     assert by["flash fwd T=96"]["lowering"] == "dense"
     assert by["ops.saxpy"]["max_err"] == 0.0
+    # the second call over the same arrays took its views as arguments
+    assert by["affine mvt_kernel1"]["views"] == "kept:1;built:0"
+    assert by["affine mvt_kernel2"]["views"] == "kept:0;built:0"
+    spmv = by["views spmv"]
+    assert spmv["views"] == "kept:2;built:0"  # a TPU lane keeps x's rows too
+    assert sum(spmv["launch_bytes"]["kept"].values()) <= sum(
+        spmv["launch_bytes"]["in_launch"].values())
 
 
 def test_stage_trace_degrades_to_named_absence_off_chip(devs):

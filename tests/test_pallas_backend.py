@@ -546,7 +546,8 @@ def test_a_return_switches_the_proof_off_and_spans_name_the_loops():
                         (returning, "counted:0;masked:1")):
         _fn, info = KernelProgram(text).launcher("k", 256, 64, 256,
                                                  platform="cpu")
-        assert lowering_meta((info,)) == {"lowering": "xla", "loops": loops}
+        assert lowering_meta((info,)) == {"lowering": "xla", "loops": loops,
+                                          "views": "kept:0;built:0"}
     _fn, info = KernelProgram(MANDEL).launcher("mandel", 256, 64, 256,
                                                platform="cpu")
     assert lowering_meta((info,))["loops"] == "counted:0;masked:1"

@@ -164,9 +164,11 @@ def test_a_tpu_build_is_vetoed_and_says_why():
     # rows of unequal length: lanes leave the loop on different passes
     loops = "counted:0;masked:1"
     assert lowering_meta((info,)) == {"lowering": "xla", "loops": loops,
+                                      "views": "kept:0;built:0",
                                       "veto": info.veto}
     _fn, info = prog.launcher("spmv", 4096, LOCAL_RANGE, 4096, platform="cpu")
-    assert lowering_meta((info,)) == {"lowering": "xla", "loops": loops}
+    assert lowering_meta((info,)) == {"lowering": "xla", "loops": loops,
+                                      "views": "kept:0;built:0"}
 
 
 def test_launcher_hands_back_what_the_kernel_did_not_replace():
