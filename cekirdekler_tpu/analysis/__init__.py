@@ -52,9 +52,12 @@ from .verdict import (
     ERROR_KINDS,
     VERDICT_KINDS,
     Finding,
+    FlagRow,
     LaunchVerdict,
+    Reach,
     classify,
     flag_row,
+    reach_of,
     structural_findings,
     suppressed_lines,
     verify_launch,
@@ -66,11 +69,14 @@ __all__ = [
     "ADVISORY_KINDS",
     "ERROR_KINDS",
     "Finding",
+    "FlagRow",
     "KernelSummary",
     "LaunchVerdict",
+    "Reach",
     "VERDICT_KINDS",
     "classify",
     "flag_row",
+    "reach_of",
     "structural_findings",
     "summarize_kernel",
     "suppressed_lines",

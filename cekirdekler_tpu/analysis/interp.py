@@ -16,6 +16,14 @@ with three distinguished shapes:
   a value loaded from an array, ``get_local_id``) — a gather/indirect
   index when used at an access site.
 
+An ``int`` value parameter ADDED inside an expression rides along as a
+symbolic term: ``sym = ((param, k), ...)`` stands for ``+ Σ k·param``,
+known at every launch from the compute's values.  ``u[i - width]`` is
+``AV(1, 0, 0, (("width", -1),))``: a halo whose reach a launch can
+evaluate (``verdict.Reach``), where it used to be top.  A parameter that
+MULTIPLIES a gid-dependent value stays top (that is a pitch, the code
+generator's business: ``codegen.pitch_params``).
+
 Everything is deliberately *under*-approximate toward safety: any
 operation the transfer rules above cannot model exactly produces TOP,
 never a fabricated affine form — a missed proof surfaces as an
@@ -62,6 +70,8 @@ class AV:
     coef: float | None
     lo: float
     hi: float
+    # value parameters added in: ((name, k), ...) sorted by name, k != 0
+    sym: tuple = ()
 
     @staticmethod
     def const(v) -> "AV":
@@ -69,7 +79,8 @@ class AV:
 
     @property
     def is_const(self) -> bool:
-        return self.coef == 0 and self.lo == self.hi and math.isfinite(self.lo)
+        return (self.coef == 0 and self.lo == self.hi
+                and math.isfinite(self.lo) and not self.sym)
 
 
 TOP = AV(None, -INF, INF)
@@ -77,16 +88,27 @@ UNIFORM = AV(0.0, -INF, INF)
 GID = AV(1.0, 0.0, 0.0)
 
 
+def _sym_add(a: tuple, b: tuple, k: float = 1.0) -> tuple:
+    """``a + k·b`` of two symbolic parts."""
+    if not b:
+        return a
+    terms = dict(a)
+    for name, kb in b:
+        terms[name] = terms.get(name, 0.0) + k * kb
+    return tuple(sorted((n, v) for n, v in terms.items() if v != 0))
+
+
 def _add(a: AV, b: AV) -> AV:
     if a.coef is None or b.coef is None:
         return TOP
-    return AV(a.coef + b.coef, a.lo + b.lo, a.hi + b.hi)
+    return AV(a.coef + b.coef, a.lo + b.lo, a.hi + b.hi,
+              _sym_add(a.sym, b.sym))
 
 
 def _neg(a: AV) -> AV:
     if a.coef is None:
         return TOP
-    return AV(-a.coef, -a.hi, -a.lo)
+    return AV(-a.coef, -a.hi, -a.lo, _sym_add((), a.sym, -1.0))
 
 
 def _scale(a: AV, k: float) -> AV:
@@ -95,7 +117,7 @@ def _scale(a: AV, k: float) -> AV:
     if k == 0:
         return AV.const(0)
     lo, hi = sorted((a.lo * k, a.hi * k))
-    return AV(a.coef * k, lo, hi)
+    return AV(a.coef * k, lo, hi, _sym_add((), a.sym, k))
 
 
 def _mul(a: AV, b: AV) -> AV:
@@ -119,17 +141,23 @@ def _uniform_combine(a: AV, b: AV) -> AV:
 def _join(a: AV, b: AV) -> AV:
     if a == b:
         return a
-    if a.coef is None or b.coef is None or a.coef != b.coef:
+    if a.coef is None or b.coef is None or a.coef != b.coef \
+            or a.sym != b.sym:
         if a.coef == 0 and b.coef == 0:
+            # uniform either way; the interval means something only
+            # where no parameter rides along
+            if a.sym or b.sym:
+                return UNIFORM
             return AV(0.0, min(a.lo, b.lo), max(a.hi, b.hi))
         return TOP
-    return AV(a.coef, min(a.lo, b.lo), max(a.hi, b.hi))
+    return AV(a.coef, min(a.lo, b.lo), max(a.hi, b.hi), a.sym)
 
 
 def _widen(old: AV, new: AV) -> AV:
     if old == new:
         return old
-    if old.coef is None or new.coef is None or old.coef != new.coef:
+    if old.coef is None or new.coef is None or old.coef != new.coef \
+            or old.sym != new.sym:
         if old.coef == 0 and new.coef == 0:
             return UNIFORM
         return TOP
@@ -137,6 +165,7 @@ def _widen(old: AV, new: AV) -> AV:
         old.coef,
         old.lo if new.lo >= old.lo else -INF,
         old.hi if new.hi <= old.hi else INF,
+        old.sym,
     )
 
 
@@ -182,8 +211,12 @@ class _Interp:
             p.name for p in kernel.params if p.is_pointer)
         self.value_params = tuple(
             p.name for p in kernel.params if not p.is_pointer)
+        # an integer value parameter is a symbol of its own (an index
+        # may add it: a row pitch, a halo width); any other is uniform
         self.env: dict[str, AV] = {
-            name: UNIFORM for name in self.value_params}
+            p.name: (AV(0.0, 0.0, 0.0, ((p.name, 1.0),))
+                     if p.ctype in self._INT_TYPES else UNIFORM)
+            for p in kernel.params if not p.is_pointer}
         self.priv: dict[str, AV] = {}
         self.written: dict[str, list[AV]] = {}   # must-written patterns
         self.accesses: list[Access] = []
@@ -216,7 +249,8 @@ class _Interp:
         if av.coef is None:
             return False
         for w in self.written.get(base, ()):
-            if w.coef == av.coef and w.lo <= av.lo and av.hi <= w.hi:
+            if w.coef == av.coef and w.sym == av.sym \
+                    and w.lo <= av.lo and av.hi <= w.hi:
                 return True
         return False
 
@@ -250,7 +284,7 @@ class _Interp:
             if node.ctype in self._INT_TYPES and v.coef is not None:
                 lo = math.floor(v.lo) if math.isfinite(v.lo) else v.lo
                 hi = math.ceil(v.hi) if math.isfinite(v.hi) else v.hi
-                return AV(v.coef, lo, hi)
+                return AV(v.coef, lo, hi, v.sym)
             return v
         if isinstance(node, lang.Ternary):
             self.eval(node.cond)

@@ -32,8 +32,9 @@ from .interp import AV, KernelSummary
 
 __all__ = [
     "VERDICT_KINDS", "ERROR_KINDS", "ADVISORY_KINDS",
-    "Finding", "LaunchVerdict", "FlagRow",
-    "classify", "flag_row", "structural_findings", "suppressed_lines",
+    "Finding", "LaunchVerdict", "FlagRow", "Reach",
+    "classify", "flag_row", "reach_of", "structural_findings",
+    "suppressed_lines",
     "verify_launch",
 ]
 
@@ -100,11 +101,73 @@ class Finding:
         }
 
 
+class Reach(namedtuple("Reach", ["lo", "hi", "sym"])):
+    """How far one gid-affine read leaves the item's own window: it
+    touches elements ``epw·gid + S + [lo, hi]`` with ``S = Σ k·p`` over
+    ``sym = ((p, k), ...)``, ``p`` an ``int`` value parameter of the
+    kernel — a proved quantity, known at every launch from the
+    compute's values (:meth:`elements`)."""
+
+    __slots__ = ()
+
+    def elements(self, values: dict, epw: int = 1):
+        """``(below, above)``: elements read below the window's first
+        and above its last, given the launch's values by parameter
+        name.  None where a value is missing or not a whole number."""
+        shift = 0
+        for name, k in self.sym:
+            try:
+                v = values[name]
+                if int(v) != v:
+                    return None
+            except (KeyError, TypeError, ValueError, OverflowError):
+                return None
+            shift += k * int(v)
+        return (int(max(0, -(self.lo + shift))),
+                int(max(0, self.hi + shift - (epw - 1))))
+
+    def __str__(self) -> str:
+        out = "".join(
+            ("+" if k > 0 else "-") + ("" if abs(k) == 1 else f"{abs(k):g}*")
+            + name for name, k in self.sym)
+        if self.lo == self.hi:
+            return out + (f"{self.lo:+g}" if self.lo or not out else "")
+        return f"{out}+[{self.lo:g},{self.hi:g}]"
+
+
 @dataclass(frozen=True)
 class LaunchVerdict:
-    """All findings for one (kernel sequence, flags) launch shape."""
+    """All findings for one (kernel sequence, flags) launch shape.
+
+    ``reach``: the proved reach of every read that leaves its item's
+    window, ``(kernel, position, param, epw, Reach)`` rows; ``exchanged``: the
+    positions whose reach crosses lanes from one compute of a window to
+    the next (a cyclic ``window-raw`` the runtime's halo exchange
+    answers); ``reads`` / ``writes``: the positions some kernel of the
+    sequence loads from / stores to.  All four are filled only for a
+    launch verified with ``exchange=True`` whose stores are all confined."""
 
     findings: tuple = ()
+    reach: tuple = ()
+    exchanged: tuple = ()
+    reads: tuple = ()
+    writes: tuple = ()
+
+    def reach_elements(self, values_of) -> dict:
+        """``{position: (below, above)}`` in elements for one launch:
+        the widest reach over the sequence's kernels, ``values_of(kernel)``
+        giving that kernel's values by parameter name.  A reach that
+        cannot be evaluated raises ``ValueError``."""
+        out: dict = {}
+        for kernel, pos, pname, epw, r in self.reach:
+            got = r.elements(values_of(kernel), epw)
+            if got is None:
+                raise ValueError(
+                    f"{kernel}: the reach {r} of {pname!r} needs whole-"
+                    "number values for its parameters")
+            lo, hi = out.get(pos, (0, 0))
+            out[pos] = (max(lo, got[0]), max(hi, got[1]))
+        return {pos: r for pos, r in out.items() if r != (0, 0)}
 
     @property
     def errors(self) -> tuple:
@@ -164,7 +227,8 @@ def classify(av: AV, epw: int = 1):
     - ``"confined"`` — ``epw·gid + [0, epw)``: lands inside the item's
       own elements for ANY split;
     - ``"halo"`` — gid-affine at the right stride but the offset leaves
-      the window by a bounded ``halo_width`` elements;
+      the window by a bounded ``halo_width`` elements (a :class:`Reach`
+      where ``int`` value parameters are added in: ``u[i - width]``);
     - ``"stride"`` — gid-affine at the WRONG stride (coef != epw);
     - ``"uniform"`` — identical across items (constants included):
       lane-relative position is unbounded under a split;
@@ -176,6 +240,10 @@ def classify(av: AV, epw: int = 1):
     if av.coef == 0:
         return "uniform", None
     if av.coef == float(epw):
+        if av.sym:
+            if math.isfinite(av.lo) and math.isfinite(av.hi):
+                return "halo", Reach(av.lo, av.hi, av.sym)
+            return "gather", None
         if 0 <= av.lo and av.hi <= epw - 1:
             return "confined", 0
         lo_over = max(0.0, 0 - av.lo)
@@ -185,6 +253,11 @@ def classify(av: AV, epw: int = 1):
             return "halo", int(width)
         return "gather", None
     return "stride", None
+
+
+def reach_of(av: AV) -> Reach:
+    """The :class:`Reach` of an access :func:`classify` calls a halo."""
+    return Reach(av.lo, av.hi, av.sym)
 
 
 def suppressed_lines(source: str) -> frozenset:
@@ -227,6 +300,7 @@ def verify_launch(
     flag_rows,
     window: bool = False,
     where: str = "<compute>",
+    exchange: bool = False,
 ) -> LaunchVerdict:
     """Prove or refute split-safety and flag soundness for one launch.
 
@@ -239,9 +313,23 @@ def verify_launch(
     (enqueue windows / fused ladders repeat it), so a RAW hazard from
     kernel B's read back into kernel A's write across iterations is
     reported too.
+
+    ``exchange=True`` is the caller's word that before the sequence runs
+    on a lane the runtime makes ``[offset - reach, offset + size + reach)``
+    of every array it reads current there (``Cores``: from the host in a
+    synchronous compute, from the lane that last wrote it between the
+    computes of an enqueue window).  A halo read whose reach is proved is
+    then no error, as long as EVERY store of the sequence is confined to
+    its item's own window (a lane's share then has one writer): not
+    ``partial-read-halo``, and not the cyclic ``window-raw``; the verdict
+    carries the reach instead.  A hazard inside one pass of the sequence
+    (the writer runs before the reader: no exchange between the kernels
+    of one compute) and a read the analysis cannot bound stay errors.
     """
     findings: list[Finding] = []
     seen: set = set()
+    reach: list = []
+    exchanged: set = set()
 
     def emit(kind, kernel, param, line, message, suppressed=frozenset()):
         if line in suppressed:
@@ -258,6 +346,13 @@ def verify_launch(
     names = tuple(kernel_names)
     rows = tuple(flag_rows)
     sums: list[KernelSummary | None] = [summaries.get(n) for n in names]
+    # the exchange stands on one writer an element: every store confined
+    exchange = exchange and all(
+        s is not None and all(
+            classify(acc.av, max(1, rows[pos].epw))[0] == "confined"
+            for pos, pname in enumerate(s.array_params[:len(rows)])
+            for acc in s.writes.get(pname, ()))
+        for s in sums)
     for ki, name in enumerate(names):
         s = sums[ki]
         if s is None:
@@ -313,8 +408,16 @@ def verify_launch(
                             "off-partition stores are silently dropped at "
                             "the lane's sliced readback", sup)
 
+            off_reads = _off_partition_reads(s, pname, epw)
+            if exchange:
+                reach.extend(
+                    (name, pos, pname, epw, reach_of(acc.av))
+                    for acc, klass, _w in off_reads
+                    if klass == "halo" and acc.line not in sup)
             if reads_flag and fl.partial_read:
-                for acc, klass, width in _off_partition_reads(s, pname, epw):
+                for acc, klass, width in off_reads:
+                    if klass == "halo" and exchange:
+                        continue  # the upload is widened by the reach
                     if klass == "halo":
                         emit(
                             "partial-read-halo", name, pname, acc.line,
@@ -410,6 +513,12 @@ def verify_launch(
                 ordered = ri >= wi  # same kernel: chunk-ladder order
                 if not (ordered or window):
                     continue
+                if exchange and klass == "halo" and not ordered:
+                    # the reader runs BEFORE the writer in a pass: what it
+                    # reads was written a compute ago, and fetched since
+                    if line not in sup:
+                        exchanged.add(pos)
+                    continue
                 how = ("across window iterations"
                        if window and not ordered else "within the sequence")
                 emit(
@@ -420,7 +529,16 @@ def verify_launch(
                     "device: cross-lane RAW hazard under any >1-lane "
                     "split", sup)
 
-    return LaunchVerdict(findings=tuple(findings))
+    def touched(kind: str) -> tuple:
+        return tuple(sorted({
+            pos for s in sums
+            for pos, pname in enumerate(s.array_params[:len(rows)])
+            if getattr(s, kind).get(pname)})) if exchange else ()
+
+    return LaunchVerdict(
+        findings=tuple(findings), reach=tuple(reach),
+        exchanged=tuple(sorted(exchanged)), reads=touched("reads"),
+        writes=touched("writes"))
 
 
 def structural_findings(

@@ -64,7 +64,7 @@ from .balance import (
 from .compilecache import CACHE as COMPILE_CACHE
 from .compilecache import trim_placed_jax_cache
 from .stream import TransferTuner, chunk_plan
-from .worker import Worker
+from .worker import Worker, launch_ladder
 
 __all__ = ["Cores", "PIPELINE_EVENT", "PIPELINE_DRIVER", "ComputePerf",
            "job_signature"]
@@ -120,6 +120,24 @@ class ComputePerf:
 
 
 @dataclass
+class _LanePlan:
+    """What one lane does before its launch of a compute whose kernels read
+    across lanes (``Cores._stage_exchange``)."""
+
+    # position -> [(lo, hi)]: element intervals no lane holds; the lane's
+    # phase uploads from the host what its coverage lacks of them
+    host: dict = field(default_factory=dict)
+    # uploads already staged from the host (``Worker.stage_upload``), and
+    # strips already cut from the buffers of the lanes that wrote them last
+    # (``(source lane, array, strip, offset)``: ``Worker.cut_strip``); the
+    # phase lays both into its lane's buffers
+    uploads: list = field(default_factory=list)
+    strips: list = field(default_factory=list)
+    # for the spans: what is kept current beyond the own range (``u1:128``)
+    reach: str = ""
+
+
+@dataclass
 class _FusedRun:
     """State of one ACTIVE fused-iteration window: the signature every
     deferral is matched against, plus everything needed to dispatch the
@@ -138,6 +156,52 @@ class _FusedRun:
     # coverage-epoch snapshot at engage: (worker, epoch) — ONE int compare
     # per worker per deferral detects any mid-window coverage reset
     epochs: list = field(default_factory=list)
+
+
+def _own_split(owned: Sequence[tuple], lo: int, hi: int) -> list[tuple]:
+    """``[lo, hi)`` cut along ``owned`` (sorted, disjoint ``(lo, hi,
+    lane)`` intervals): the pieces ``(lo, hi, lane)`` in ascending order,
+    ``lane`` None where no lane holds the elements."""
+    out, at = [], lo
+    for a, b, lane in owned:
+        if b <= at:
+            continue
+        if a >= hi:
+            break
+        if a > at:
+            out.append((at, a, None))
+        at = min(b, hi)
+        out.append((max(a, lo), at, lane))
+    if at < hi:
+        out.append((at, hi, None))
+    return out
+
+
+def _own_assign(owned: Sequence[tuple], lo: int, hi: int,
+                lane: int) -> list[tuple]:
+    """``owned`` with ``[lo, hi)`` given to ``lane``: whatever other
+    intervals held of it is cut away, neighbours of one lane are joined."""
+    cut = [(lo, hi, lane)]
+    for a, b, who in owned:
+        if a < min(b, lo):
+            cut.append((a, min(b, lo), who))
+        if max(a, hi) < b:
+            cut.append((max(a, hi), b, who))
+    out: list[tuple] = []
+    for a, b, who in sorted(cut):
+        if out and out[-1][2] == who and out[-1][1] == a:
+            out[-1] = (out[-1][0], b, who)
+        else:
+            out.append((a, b, who))
+    return out
+
+
+def _strip_sizes(size: int, unit: int) -> list[int]:
+    """A strip of ``size`` elements as pieces of few distinct sizes (each
+    size is a compile of the slice that cuts it): the launch ladder over
+    its whole ``unit``s, then the rest in one."""
+    whole = size - size % unit
+    return launch_ladder(whole, unit) + ([size - whole] if size > whole else [])
 
 
 class Cores:
@@ -342,6 +406,23 @@ class Cores:
         # drops, and a recycled id() would suppress a different
         # shape's one-and-only advisory forever
         self._verify_notified: set[tuple] = set()
+        # ---- who holds the current elements (reads across lanes): per
+        # array, the lane whose buffer holds the newest value of each
+        # element interval, from the ranges of every compute and the
+        # arrays its kernels store to: id(array) -> (array, sorted
+        # disjoint (lo, hi, lane)).  Kept from the first compute whose
+        # kernels read beyond their own range (the analysis' proved
+        # reach: _stage_exchange); before a lane's launch of such a
+        # compute the parts of its reach that another lane wrote last are
+        # fetched from THAT lane's buffer, device to device, and only
+        # what no lane wrote comes from the host.  Reads and writes hold
+        # the scheduler lock; a lane's upload coverage stays under its
+        # worker lock as before.
+        self._owners: dict[int, tuple] = {}
+        # compute ids whose computes exchanged in the open window: said
+        # once why they do not fuse, and left out of the barrier's feed to
+        # the balancer (their lanes retire in lock-step: see barrier)
+        self._exchanging: set[int] = set()
         # per-cid fence splitting (VERDICT r5 #8): when on, barrier()
         # fences each compute id's last output in last-dispatch order and
         # feeds the balancer MARGINAL per-cid times instead of charging
@@ -663,16 +744,33 @@ class Cores:
         # the identical shape.  Advisory by default (one flight event
         # per shape); CK_KERNEL_VERIFY=strict raises the named finding.
         verify_mode = os.environ.get("CK_KERNEL_VERIFY", "advisory")
+        # a read that leaves its item's window with a proved reach is no
+        # error where this path keeps the reach current (the plain path:
+        # not a pipelined compute, not an on-device repeat, which has no
+        # host between its passes); ``exchange`` is the verdict of such a
+        # compute on more than one lane: what _stage_exchange goes by
+        exchange = None
         if verify_mode != "off":
             verdict = self.program.verify(
                 tuple(kernel_names),
                 tuple(flag_row(p.flags) for p in params),
                 window=self.enqueue_mode or self.repeat_count > 1,
+                exchange=not pipeline and self.repeat_count == 1
+                and not self.repeat_sync_kernel,
             )
             if verdict.errors:
                 if verify_mode == "strict":
                     raise KernelVerifyError(verdict.errors[0])
                 self._note_kernel_verdict(verdict, kernel_names)
+            elif verdict.reach and self.num_devices > 1:
+                reach = self._reach_elements(verdict, kernel_names, value_args)
+                # a synchronous compute whose reads are all full and that
+                # stores to nothing it reads across lanes takes the whole
+                # arrays from the host, as ever
+                if reach and (self.enqueue_mode or any(
+                        params[pos].flags.partial_read
+                        or pos in verdict.writes for pos in reach)):
+                    exchange = (verdict, reach)
         if self.enqueue_mode:
             # under the lock: concurrent host threads may drive different
             # compute ids through one Cores, and the order list's
@@ -730,7 +828,10 @@ class Cores:
                 # would park every key in a perpetual measuring run
                 self.transfer_tuner.on_repartition()
                 self._m_stream_retunes.inc()
-        if self.enqueue_mode and old_ranges and ranges != old_ranges:
+        if self.enqueue_mode and old_ranges and ranges != old_ranges \
+                and exchange is None:
+            # (a compute that reads across lanes fetches a gained strip
+            # from the lane that held it, as it fetches its reach: below)
             # the balancer moved shares between syncs: host arrays must be
             # made current BEFORE any chip uploads its newly-acquired region
             # (the freshest data for that region is on the previous owner's
@@ -786,6 +887,9 @@ class Cores:
             # would mix into the first-join comparison and leak memory
             with self._lock:
                 self.lane_trace.pop(compute_id, None)
+        plans = self._stage_exchange(
+            exchange, params, compute_id, global_offset, ranges, refs, step,
+        ) if exchange is not None else {}
         futures = []
         for i, w in enumerate(self.workers):
             if ranges[i] <= 0:
@@ -806,6 +910,7 @@ class Cores:
                     pipeline_type,
                     value_args,
                     write_all_owner,
+                    plans.get(i),
                 )
             )
         errs = []
@@ -821,11 +926,27 @@ class Cores:
             record_crash("cores.compute", errs[0], lanes=self._lane_config())
             raise errs[0]
 
+        # the lanes' launches are out: what they stored to, they now hold
+        # ckcheck: ok racy emptiness peek — a compute's own arrays enter
+        # the map on its own thread; the writes below hold the lock
+        if exchange is not None or self._owners:
+            self._note_writers(
+                exchange, params, global_offset, ranges, refs)
         if _tt:
             TRACER.record(
                 "enqueue", _tt, cid=compute_id, tag="+".join(kernel_names),
             )
         self._record_perf(compute_id, t_start, ranges)
+        if exchange is not None and self.enqueue_mode:
+            # no fusion across an exchange: the computes of such a window
+            # cannot be deferred into one device-side loop a lane, each is
+            # its own launch with the fetch before it.  Said once a window.
+            with self._lock:
+                said = compute_id in self._exchanging
+                self._exchanging.add(compute_id)
+            if self.fused_dispatch and not said:
+                self._note_disengage("halo", compute_id)
+            return
         # fused-window engagement: a successfully dispatched enqueue call
         # whose next identical call would be a pure launch (operands
         # resident, ranges pinned) establishes the window this call's
@@ -853,6 +974,120 @@ class Cores:
             finding=f.kind, kernel=f.kernel, param=f.param, line=f.line,
             errors=len(verdict.errors),
         )
+
+    # -- reads across lanes: who holds the current elements -----------------
+    def _reach_elements(self, verdict, kernel_names, value_args) -> dict:
+        """``{position: (below, above)}``: the elements beyond a lane's
+        own range that this launch's kernels read, from the verdict's
+        proved reach and the compute's values."""
+        def values_of(kernel: str) -> dict:
+            vals = (value_args.get(kernel, ()) if isinstance(value_args, dict)
+                    else tuple(value_args))
+            return dict(zip(self.program.value_param_names(kernel), vals))
+
+        try:
+            return verdict.reach_elements(values_of)
+        except ValueError as e:
+            raise ComputeValidationError(str(e)) from None
+
+    def _stage_exchange(
+        self, exchange, params, compute_id: int, global_offset: int,
+        ranges, refs, step: int,
+    ) -> dict:
+        """Before the lanes of a compute that reads across lanes launch:
+        ``[offset - reach, offset + size + reach)`` of every array its
+        kernels read must be current on each lane.  Returns one
+        :class:`_LanePlan` a lane.
+
+        Inside an enqueue window the intervals that ANOTHER lane wrote
+        last come from that lane's buffer, which its launch of the compute
+        before left, and only what no lane holds comes from the host.  All
+        strips are CUT here (``Worker.cut_strip``: a slice on the writer's
+        device, nothing waits), on the caller's thread, before any lane's
+        phase of this compute is submitted: a phase replaces its lane's
+        buffers, and a neighbour must read what the compute before left.
+        Each lane's phase then brings its strips over and lays them in
+        (``Worker.lay_strip``), the lanes side by side.  A range that moved
+        is the same fetch: the gained strip's last writer is the lane that
+        held it.
+
+        A synchronous compute takes everything from the host (which the
+        compute before made current), widened by the reach where
+        ``partial_read`` sends the slice alone; every lane's host reads
+        are staged here and joined before any phase starts, because a
+        phase ends by writing its lane's results into the same host
+        arrays: a lane that uploaded late read its neighbour's rows of
+        the NEXT step."""
+        verdict, reach = exchange
+        windowed = self.enqueue_mode
+        with self._lock:
+            owned = {pos: self._owners.get(id(params[pos]), (None, ()))[1]
+                     for pos in verdict.reads} if windowed else {}
+        tag = ";".join(f"{params[pos].name}:{max(r)}"
+                       for pos, r in sorted(reach.items()))
+        plans: dict = {}
+        staging: list = []
+        for i, w in enumerate(self.workers):
+            if ranges[i] <= 0:
+                continue
+            plan = plans[i] = _LanePlan(reach=tag)
+            off = global_offset + refs[i]
+            fetch: list = []
+            for pos in verdict.reads:
+                p = params[pos]
+                fl = p.flags
+                epw = fl.elements_per_work_item
+                below, above = reach.get(pos, (0, 0))
+                lo = max(0, off * epw - below)
+                hi = min(p.size, (off + ranges[i]) * epw + above)
+                if fl.read and not fl.write_only:
+                    whole = (lo, hi) if fl.partial_read else (0, p.size)
+                    plan.host[pos] = [
+                        (a, b) for a, b, lane in _own_split(
+                            owned.get(pos, ()), *whole) if lane is None]
+                fetch += [
+                    (p, a, b, lane, step * epw) for a, b, lane in _own_split(
+                        owned.get(pos, ()), lo, hi)
+                    if lane is not None and lane != i]
+            if not windowed:
+                def stage(w=w, plan=plan):
+                    plan.uploads = [
+                        w.stage_upload(params[pos], a, b - a, settled=True)
+                        for pos, pieces in plan.host.items()
+                        for a, b in pieces]
+
+                staging.append(self.pool.submit(TRACER.bind(stage, i)))
+            for p, a, b, lane, unit in fetch:
+                src = self.workers[lane]
+                for n in _strip_sizes(b - a, unit):
+                    with src.lock:
+                        plan.strips.append((src, p, src.cut_strip(p, a, n), a))
+                    a += n
+        for f in staging:
+            f.result()
+        return plans
+
+    def _note_writers(self, exchange, params, global_offset: int,
+                      ranges, refs) -> None:
+        """After a compute's launches are out: each lane holds the newest
+        elements of its own range of every array the kernels store to
+        (the verdict's word for a compute that reads across lanes; for any
+        other compute, every array in the map that is not ``read_only``)."""
+        with self._lock:
+            if exchange is not None:
+                stored = [params[pos] for pos in exchange[0].writes]
+            else:
+                stored = [p for p in params if id(p) in self._owners
+                          and not p.flags.read_only]
+            for p in stored:
+                epw = p.flags.elements_per_work_item
+                owned = self._owners.get(id(p), (p, ()))[1]
+                for i, size in enumerate(ranges):
+                    if size > 0:
+                        lo = (global_offset + refs[i]) * epw
+                        owned = _own_assign(
+                            owned, lo, min(p.size, lo + size * epw), i)
+                self._owners[id(p)] = (p, owned)
 
     def _record_perf(
         self, compute_id: int, t_start: float, ranges: list[int]
@@ -1596,6 +1831,7 @@ class Cores:
         pipeline_type: int,
         value_args,
         write_all_owner: dict[int, int],
+        plan=None,
     ) -> None:
         gate = self.dispatch_gate
         if gate is not None:
@@ -1613,7 +1849,7 @@ class Cores:
             self._run_worker_locked(
                 w, kernel_names, params, compute_id, offset, size,
                 local_range, global_range, pipeline, blobs, pipeline_type,
-                value_args, write_all_owner,
+                value_args, write_all_owner, plan,
             )
 
     def _run_worker_locked(
@@ -1631,6 +1867,7 @@ class Cores:
         pipeline_type: int,
         value_args,
         write_all_owner: dict[int, int],
+        plan=None,
     ) -> None:
         w.start_bench(compute_id)
         single = self.num_devices == 1
@@ -1647,11 +1884,14 @@ class Cores:
                     write_all_owner,
                 )
                 return
+            # a phase with a plan (its kernels read across lanes:
+            # _stage_exchange) never streams: a chunk's launch would read
+            # the rows of the chunk behind it before they were uploaded
             streamed, key_bytes = self._run_streamed(
                 w, kernel_names, params, compute_id, offset, size,
                 local_range, global_range, value_args, single,
                 write_all_owner,
-            )
+            ) if plan is None else (False, None)
             if streamed:
                 return  # chunked wavefront handled the phase
             t_phase0 = time.perf_counter()
@@ -1686,7 +1926,7 @@ class Cores:
             # mis-learns every lane's per-chunk overhead
             t_up = 0.0
             t_up_stream = 0.0
-            for idx, p in enumerate(params):
+            for idx, p in enumerate(params if plan is None else ()):
                 fl = p.flags
                 if fl.read and not fl.write_only:
                     epw = fl.elements_per_work_item
@@ -1703,14 +1943,33 @@ class Cores:
                         t_up_stream += dt_u
                 else:
                     w.ensure_resident(p)
+            if plan is not None:
+                t_up = self._make_current(w, params, plan, compute_id)
             # compute
             if not self.no_compute_mode:
-                w.launch(
-                    self.program, kernel_names, params, value_args,
-                    offset, size, local_range, global_range, local_range,
-                    repeats=self.repeat_count, sync_kernel=self.repeat_sync_kernel,
-                    compute_id=compute_id,
-                )
+                if plan is not None and self.enqueue_mode \
+                        and self.fused_dispatch:
+                    # a window's compute that could not be deferred rides
+                    # the ladder executable all the same, one pass of it:
+                    # ONE dispatch a lane whatever the rungs of its range,
+                    # stores written into the lane's buffers in place
+                    # (launch_fused falls back to the per-rung loop where
+                    # the values do not hash)
+                    w.launch_fused(
+                        self.program, kernel_names, params, value_args,
+                        offset, size, local_range, global_range,
+                        local_range, 1, compute_id=compute_id,
+                        reach=plan.reach,
+                    )
+                else:
+                    w.launch(
+                        self.program, kernel_names, params, value_args,
+                        offset, size, local_range, global_range, local_range,
+                        repeats=self.repeat_count,
+                        sync_kernel=self.repeat_sync_kernel,
+                        compute_id=compute_id,
+                        reach=plan.reach if plan is not None else "",
+                    )
                 if measuring:
                     w.fence()
             t_dispatched = time.perf_counter() if self.trace_lanes else 0.0
@@ -1761,6 +2020,38 @@ class Cores:
                     )
         finally:
             w.end_bench(compute_id)
+
+    def _make_current(self, w: Worker, params: Sequence[ClArray],
+                      plan: _LanePlan, compute_id: int) -> float:
+        """A lane's half of :meth:`_stage_exchange`, under its phase lock:
+        lay in what was staged for it (a synchronous compute's uploads;
+        the strips cut from the lanes that wrote them last: one ``halo``
+        span a compute that fetched any) and, inside an enqueue window,
+        upload what its coverage lacks of the intervals no lane holds.
+        Returns the seconds the uploads took."""
+        t0 = time.perf_counter()
+        for staged in plan.uploads:
+            w.commit_upload(staged)
+        for idx, p in enumerate(params):
+            if idx not in plan.host:
+                w.ensure_resident(p)
+            elif self.enqueue_mode:  # (a synchronous compute staged them)
+                for lo, hi in plan.host[idx]:
+                    if not w.upload_covers(p, lo, hi - lo):
+                        w.upload(p, lo, hi - lo, (lo, hi) == (0, p.size))
+        t_up = time.perf_counter() - t0
+        if plan.strips:
+            _th = TRACER.t0("halo")
+            how = {w.lay_strip(src, p, strip, lo)
+                   for src, p, strip, lo in plan.strips}
+            if _th:
+                TRACER.record(
+                    "halo", _th, cid=compute_id, lane=w.index,
+                    tag="+".join(sorted(how)),
+                    bytes=sum(s[2].nbytes for s in plan.strips),
+                    src="+".join(str(k) for k in sorted(
+                        {s[0].index for s in plan.strips})))
+        return t_up
 
     def _stream_key_bytes(
         self, w: Worker, params: Sequence[ClArray], offset: int, size: int,
@@ -2607,6 +2898,15 @@ class Cores:
             window_cids = set(self._enqueue_cids)
             window_cid_order = list(self._enqueue_cid_order)
             window_iters_map = dict(self._enqueue_iters)
+            # a compute id whose computes exchanged: every step of a lane
+            # waits for the rows its neighbours wrote the step before, so
+            # the lanes retire TOGETHER whatever their shares.  Their fence
+            # times say nothing about one lane's rate (read as rates they
+            # call the lane with the most items the fastest and give it
+            # more: PERF.md, PR 32): such an id keeps its benches and arms
+            # no rebalance here; a synchronous compute, whose lanes run
+            # each for itself, still moves its ranges.
+            balanced_cids = window_cids - self._exchanging
         measure = self.enqueue_mode and t0 is not None and len(self.workers) > 1
         split_order = (
             window_cid_order
@@ -2686,7 +2986,7 @@ class Cores:
                     splits = split_fence_benches(comp_at.get(w.index, ()), t0)
                     window_ms = {
                         cid: splits.get(cid, bench)
-                        for cid in window_cids
+                        for cid in balanced_cids
                         # only chips that ran this id refresh its bench;
                         # split marginals when available, whole-window
                         # fence time otherwise (the documented default)
@@ -2705,7 +3005,7 @@ class Cores:
                 # concurrent compute()'s discard must not be interleaved
                 # into it (ckcheck lockset finding)
                 with self._lock:
-                    self._enqueue_rebalance |= window_cids
+                    self._enqueue_rebalance |= balanced_cids
         finally:
             REGISTRY.histogram(
                 "ck_barrier_seconds", "barrier wall time",
@@ -2752,6 +3052,7 @@ class Cores:
             self._enqueue_cid_order.clear()
             self._enqueue_iters.clear()
             self._enqueue_t0 = None
+            self._exchanging.clear()
 
     def ranges_of(self, compute_id: int) -> list[int]:
         return list(self.global_ranges.get(compute_id, []))
@@ -2764,6 +3065,8 @@ class Cores:
         DECISIONS.maybe_spill(force=True)
         for w in self.workers:
             w.dispose()
+        with self._lock:
+            self._owners.clear()
         self.pool.shutdown(wait=False)
 
 

@@ -60,6 +60,12 @@ def _update_slice(buf, sl, off):
     return lax.dynamic_update_slice(buf, sl, (jnp.asarray(off, jnp.int32),))
 
 
+# the same update written INTO the buffer it is given: a strip of a few
+# rows laid into a lane's full-size buffer must not copy the buffer
+_update_slice_donating = jax.jit(
+    _update_slice.__wrapped__, donate_argnums=(0,))
+
+
 def _ladder(size: int, step: int) -> list[int]:
     """Decompose ``size`` (a multiple of ``step``) into descending
     ``step·2^k`` chunks — the compile-once launch ladder."""
@@ -362,6 +368,14 @@ class Worker:
         self.m_chunk_count = REGISTRY.gauge(
             "ck_stream_chunk_count", "autotuner-chosen chunk count",
             lane=index)
+        # the exchange between lanes (Cores._stage_exchange): strips this
+        # lane took from the lanes that last wrote them (lay_strip)
+        self._m_halo_bytes = REGISTRY.counter(
+            "ck_halo_bytes_total",
+            "bytes fetched from the lane that last wrote them", lane=index)
+        self._m_halo_exchanges = REGISTRY.counter(
+            "ck_halo_exchanges_total",
+            "strips fetched from another lane's buffer", lane=index)
 
     # -- benchmarks ----------------------------------------------------------
     def start_bench(self, compute_id: int) -> None:
@@ -485,20 +499,30 @@ class Worker:
                       bytes=part.nbytes)
 
     def stage_upload(self, arr: ClArray, offset_elems: int, size_elems: int,
-                     kind: str = "upload"):
+                     kind: str = "upload", settled: bool = False):
         """Start the H2D DMA for a range slice WITHOUT inserting it into the
         chip's buffer yet — the event-pipeline engine stages blob j+1's
         transfer while blob j computes (reference: the read queue of the
         3-queue event pipeline, Cores.cs:1263-1295).  Returns a handle for
         :meth:`commit_upload`.  ``kind`` names the span recorded
         (``upload-chunk`` for one ladder-aligned chunk of a streamed
-        partition upload — same split as :meth:`download_async`)."""
+        partition upload — same split as :meth:`download_async`).
+        ``settled``: return only once the slice no longer depends on the
+        host array — the caller is about to let OTHER lanes write their
+        results into that array (``Cores._stage_exchange``).  A transfer to
+        a chip may still be reading the host memory when ``device_put``
+        returns, and on a host-CPU lane ``device_put`` does not copy at
+        all: the "device" array IS the host memory."""
         _tt = TRACER.t0(kind)
         host = arr.host()
         if self.markers is not None:
             self.markers.add()
         part = host[offset_elems : offset_elems + size_elems]
-        sl = self._h2d(part, arr.flags.zero_copy)
+        if settled and self.device.platform == "cpu":
+            part = part.copy()
+        sl = self._h2d(part, arr.flags.zero_copy and not settled)
+        if settled:
+            sl.block_until_ready()
         if self.markers is not None:
             self.markers.reach_when_ready(sl)
         if _tt:
@@ -521,9 +545,44 @@ class Worker:
         """Insert a staged slice into the range buffer (the device-side
         dependency edge between the read queue and the compute queue)."""
         arr, sl, off = staged
-        buf = self._buffer_for(arr)
-        self._buffers[id(arr)] = _update_slice(buf, sl, off)
+        if off == 0 and sl.shape[0] == arr.host().size:
+            # the whole array: the staged copy IS the lane's buffer
+            self.set_buffer(arr, sl)
+        else:
+            buf = self._buffer_for(arr)
+            self._buffers[id(arr)] = _update_slice(buf, sl, off)
         self._record_upload(arr, off, sl.shape[0])
+
+    def cut_strip(self, arr: ClArray, offset_elems: int, size_elems: int):
+        """``[offset, offset + size)`` of this lane's buffer of ``arr`` as
+        it stands NOW, for another lane to take (:meth:`lay_strip`): a
+        slice on this lane's own device, ordered after the launch that
+        wrote the buffer and unmoved by whatever replaces it later.  The
+        caller holds this lane's lock."""
+        return _slice_out(self._buffers[id(arr)], offset_elems, size_elems)
+
+    def lay_strip(self, src: "Worker", arr: ClArray, strip,
+                  offset_elems: int) -> str:
+        """Bring a strip that ``src``, the lane that last wrote it, cut
+        from its buffer (:meth:`cut_strip`) onto this lane's device and lay
+        it into this lane's buffer at ``offset``: device to device where
+        both lanes sit on one platform (asynchronous: the runtime orders
+        the copy after the writer's launch and this lane's launch after
+        the copy), through host memory otherwise; in place where the lane
+        may donate (:attr:`fused_donate`).  Upload coverage is left as it
+        is: it records what came from the HOST, and who holds a strip's
+        newest elements is ``Cores``'s to know.  Under this lane's phase
+        lock.  Returns how it went: ``d2d`` or ``host``."""
+        if src.device.platform == self.device.platform:
+            moved, how = jax.device_put(strip, self.device), "d2d"
+        else:
+            moved, how = jax.device_put(np.asarray(strip), self.device), "host"
+        put = _update_slice_donating if self.fused_donate else _update_slice
+        self._buffers[id(arr)] = put(self._buffer_for(arr), moved,
+                                     offset_elems)
+        self._m_halo_exchanges.inc()
+        self._m_halo_bytes.inc(strip.nbytes)
+        return how
 
     def ensure_resident(self, arr: ClArray) -> Any:
         """Buffer for a non-read array: reuse cache or zeros (the kernel is
@@ -647,6 +706,7 @@ class Worker:
         repeats: int = 1,
         sync_kernel: str | None = None,
         compute_id: int | None = None,
+        reach: str = "",
     ) -> None:
         """Run the kernel sequence over work items [offset, offset+size) on
         this chip.  ``repeats`` reruns the sequence on-device without host
@@ -654,7 +714,9 @@ class Worker:
         Worker.cs:1051-1069); ``sync_kernel`` interleaves a synchronization
         kernel between repeats (computeRepeatedWithSyncKernel).
         ``compute_id`` tags the launch span and the per-cid completion
-        probe used by the fence split — optional, purely observability.
+        probe used by the fence split — optional, purely observability;
+        so is ``reach``, what the caller kept current beyond this range
+        (``u1:16384``), which the launch and compile spans then carry.
 
         The launch ladder (:func:`launch_ladder`) has two lowerings and
         this is where a per-call launch picks one: a host loop over the
@@ -704,6 +766,7 @@ class Worker:
                 )
                 one_args = (offset, units, 1, bufs)
             if one_fn is not None:
+                one_fn.info.reach = reach
                 bufs = tuple(one_fn(*one_args))
                 dispatched = 1
                 infos.append(one_fn.info)
@@ -737,6 +800,7 @@ class Worker:
                                     in_range=in_range,
                                 )
                                 n_arr = program.array_param_count(name)
+                                info.reach = reach
                                 out = fn(offset, bufs[:n_arr], tuple(va),
                                          frozen=frozen)
                                 bufs = tuple(out) + bufs[n_arr:]
@@ -821,6 +885,7 @@ class Worker:
         step: int,
         iters: int,
         compute_id: int | None = None,
+        reach: str = "",
     ) -> None:
         """ONE dispatch running ``iters`` repetitions of the kernel
         sequence over this chip's range — the fused-iteration ladder
@@ -841,9 +906,10 @@ class Worker:
                 self.launch(
                     program, kernel_names, params, value_args, offset,
                     size, local_range, global_size, step,
-                    compute_id=compute_id,
+                    compute_id=compute_id, reach=reach,
                 )
             return
+        fn.info.reach = reach
         _tt = TRACER.t0("launch")
         bufs = tuple(self._buffers[id(p)] for p in params)
         # device-timeline mark (trace/device.py): the fused ladder is ONE

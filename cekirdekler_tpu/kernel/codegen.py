@@ -2581,6 +2581,9 @@ class KernelBuildInfo:
     views: tuple = ()
     views_kept: int = 0
     views_built: int = 0
+    # what the newest launch kept current beyond the lane's own range, by
+    # array (``u1:16384``: core/cores.py's exchange); "" where nothing
+    reach: str = ""
 
 
 def hlo_name(*kernel_names: str) -> str:

@@ -345,8 +345,9 @@ def lowering_meta(infos) -> dict:
     where a ladder's rungs differ), ``loops`` (``counted:N;masked:M``: how
     many of the kernel's loops run on a scalar counter and how many under a
     per-lane mask; joined the same way; no comma, which would end the
-    value in a profiler annotation) and, where a TPU build was routed
-    away from Pallas, ``veto`` with the reason.  A ladder executable stands
+    value in a profiler annotation), where a TPU build was routed
+    away from Pallas, ``veto`` with the reason, and where the launch read
+    beyond its lane's own range, ``reach`` (``u1:16384``).  A ladder executable stands
     for its rungs.  ``views`` (``kept:K;built:B``) is of the DISPATCHES the
     span ran, one of ``infos`` each: the kept views they took as arguments
     (:class:`_KeptViews`) and how many of those were built on these calls;
@@ -362,6 +363,11 @@ def lowering_meta(infos) -> dict:
     vetoes = sorted({i.veto for i in leaves if i.veto})
     if vetoes:
         meta["veto"] = "; ".join(vetoes)
+    # elements beyond the lane's own range that the launch's exchange kept
+    # current, by array (``Worker.launch`` stamps the launchers it runs)
+    reach = sorted({i.reach for i in infos + leaves if i.reach})
+    if reach:
+        meta["reach"] = ";".join(reach)
     # the access sites by how they were lowered, summed over the KERNELS
     # (the rungs of one kernel are builds of the same sites: the most of
     # each kind), and the value arguments that were launcher keys
@@ -607,21 +613,25 @@ class KernelProgram:
             self._analysis_summaries = out
         return out
 
-    def verify(self, kernel_names, flag_rows, window: bool = False):
+    def verify(self, kernel_names, flag_rows, window: bool = False,
+               exchange: bool = False):
         """Cached :class:`~..analysis.LaunchVerdict` for one launch
         shape.  ``flag_rows`` is a tuple of
         :func:`~..analysis.flag_row` tuples (positional, the call's
         parameter order).  Verification runs once per distinct
-        (kernel sequence, flags, window) — every later call is one
-        dict lookup."""
-        key = (tuple(kernel_names), tuple(flag_rows), bool(window))
+        (kernel sequence, flags, window, exchange) — every later call is
+        one dict lookup.  ``exchange``: the caller keeps every proved
+        reach current before the sequence runs (``verify_launch``)."""
+        key = (tuple(kernel_names), tuple(flag_rows), bool(window),
+               bool(exchange))
         v = self._verdict_cache.get(key)
         if v is None:
             from .. import analysis
 
             try:
                 v = analysis.verify_launch(
-                    self.summaries(), key[0], key[1], window=key[2])
+                    self.summaries(), key[0], key[1], window=key[2],
+                    exchange=key[3])
             except Exception:  # noqa: BLE001 - verifier must never
                 # sink a compute; an empty verdict is "nothing proven"
                 v = analysis.LaunchVerdict(findings=())

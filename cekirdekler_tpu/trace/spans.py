@@ -96,7 +96,7 @@ SPAN_KINDS = (
     "upload", "download", "upload-chunk", "download-chunk",
     "pipeline-stage", "pool-task", "dcn-exchange",
     "fused", "driver-error",
-    "schedule", "resync", "engage", "drain", "tune", "compile",
+    "schedule", "resync", "engage", "drain", "tune", "compile", "halo",
 )
 
 #: annotation names, built once: a span site must not concatenate per span

@@ -52,6 +52,8 @@ FULL = {
     "mandel_wh": 2048, "mandel_max_iter": 256, "local_range": 256,
     "mandel_per_call": 4, "mandel_window": 32, "mandel_marker_window": 8,
     "nbody_n": 8192, "nbody_iters": 150, "nbody_window": 50,
+    # the wave membrane split by range: a row of u1 crosses lanes each step
+    "halo_wh": 1024, "halo_window": 20,
     # stage 2 — 256 MiB per array: not a cache
     "stream_n": 1 << 26, "stream_tuner_runs": 3,
     # stage 3 — the examples/wave_equation.py stage
@@ -275,6 +277,78 @@ def _mandelbrot_through_compute(devices, sizes, source, label, want, cid,
         cr.dispose()
 
 
+def _wave_window_across_lanes(lanes, sizes) -> dict:
+    """The wave step (``waveStep rotate``, one compute) split by range over
+    the lanes: one synchronous step, then two enqueue windows with the
+    balancer moving the ranges between them.  Every lane reads one row of
+    ``u1`` its neighbour wrote the step before: exact against the float64
+    scheme at every cell, the rows fetched lane to lane (``ck/halo`` spans,
+    every one ``d2d``), no window fused, no whole-array resync."""
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu import trace as cktrace
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+
+    ex = _wave_example()
+    side, lr = sizes["halo_wh"], sizes["local_range"]
+    per, cid = sizes["halo_window"], 7105
+    rng = np.random.default_rng(sizes["seed"] + 5)
+    f = rng.standard_normal((side, side)).astype(np.float32)
+    f[0, :] = f[-1, :] = 0.0
+    f[:, 0] = f[:, -1] = 0.0
+    u0 = ClArray(f.reshape(-1).copy(), name="u0", partial_read=True)
+    u1 = ClArray(f.reshape(-1).copy(), name="u1", partial_read=True)
+    frame = ClArray(side * side, np.float32, name="frame", read=False)
+    group = u0.next_param(u1, frame)
+    cr = NumberCruncher(lanes, ex.WAVE_SRC)
+    try:
+        step = lambda: group.compute(cr, cid, "waveStep rotate", side * side,
+                                     lr, values=(side, side, ex.C2))
+        _, cold_s = _timed(step)
+        with cktrace.tracing() as tr:
+            cr.enqueue_mode = True
+            t0 = time.perf_counter()
+            for _ in range(2):
+                for _ in range(per):
+                    step()
+                cr.barrier()
+            _check_placement(cr)
+            cr.enqueue_mode = False  # flush
+            run_s = time.perf_counter() - t0
+            spans = tr.snapshot()
+        a = b = f.astype(np.float64)
+        for _ in range(1 + 2 * per):
+            c = np.zeros_like(b)
+            c[1:-1, 1:-1] = (2.0 * b[1:-1, 1:-1] - a[1:-1, 1:-1] + ex.C2 * (
+                b[1:-1, :-2] + b[1:-1, 2:] + b[:-2, 1:-1] + b[2:, 1:-1]
+                - 4.0 * b[1:-1, 1:-1]))
+            a, b = b, c
+        err = max(float(np.abs(u1.host() - b.reshape(-1)).max()),
+                  float(np.abs(u0.host() - a.reshape(-1)).max()))
+        tol = 5e-6 * float(np.abs(b).max())
+        _require(err <= tol, f"wave window over {len(lanes)} lanes: max err "
+                             f"{err} > {tol:.3g}")
+        halos = [s for s in spans if s.kind == "halo"]
+        _require(len(halos) >= (2 * per - 1) * len(lanes),
+                 f"{len(halos)} halo spans in {2 * per} computes")
+        _require(all(s.tag == "d2d" for s in halos),
+                 f"exchanges not device to device: {sorted({s.tag for s in halos})}")
+        _require(not any(s.kind == "resync" and s.tag == "range-move"
+                         for s in spans), "a range move resynced whole arrays")
+        _require(cr.fused_stats["windows"] == 0
+                 and cr.fused_stats["disengaged"].get("halo") == 2,
+                 f"fused path: {cr.fused_stats}")
+        # a row up or down is a runtime shift: Pallas vetoes it (and says
+        # why), so waveStep runs the vectorized lowering on every lane
+        routed = {lo for plat in set(_lane_platforms(lanes))
+                  for lo, _veto in cr.cores.program.lowerings("waveStep", plat)}
+        _require(routed == {"xla"}, f"waveStep lowered as {sorted(routed)}")
+        return _row("wave compute() halo window", "xla", cold_s, run_s,
+                    err, lanes=len(lanes), ranges=cr.ranges_of(cid),
+                    halo_spans=len(halos))
+    finally:
+        cr.dispose()
+
+
 def stage_compute(devices, sizes) -> list[dict]:
     from cekirdekler_tpu import ClArray
     from cekirdekler_tpu.core.cruncher import NumberCruncher
@@ -348,6 +422,7 @@ def stage_compute(devices, sizes) -> list[dict]:
             fused_windows=cr.fused_stats["windows"]))
     finally:
         cr.dispose()
+    rows.append(_wave_window_across_lanes(lanes, sizes))
     return rows
 
 

@@ -13,6 +13,14 @@ no ``JAX_PLATFORMS=cpu`` it fails instead of quietly switching):
 
     python examples/wave_equation.py                     # TPU chip
     JAX_PLATFORMS=cpu python examples/wave_equation.py   # host CPU
+    python examples/wave_equation.py --lanes 4           # split by range
+
+``--lanes N`` runs the same kernels a second way, through ``compute()`` on
+ONE ``NumberCruncher`` over N devices (upstream's headline use: the range
+split by the balancer): the steps are enqueued in windows, the field stays
+on the devices, and because every lane reads a row of ``u1`` that its
+neighbour wrote the step before, the lanes exchange those rows device to
+device between two steps (docs/KERNEL_LANGUAGE.md, "Reads across lanes").
 
 The kernel uses shifted neighbor loads (``u[i-1]``, ``u[i+W]``) — outside
 the elementwise Pallas subset, so it exercises the vectorized XLA lowering
@@ -30,6 +38,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import cekirdekler_tpu as ct  # noqa: E402
 from cekirdekler_tpu import ClArray
+from cekirdekler_tpu.core.cruncher import NumberCruncher
 from cekirdekler_tpu.pipeline.device_pipeline import DevicePipeline, PipelineStage
 
 W, H = 96, 48        # membrane grid (flattened row-major)
@@ -97,7 +106,45 @@ def ascii_frame(field: np.ndarray) -> str:
     return "\n".join(rows)
 
 
+def run_split(lanes: int, u0_init: np.ndarray, u1_init: np.ndarray) -> None:
+    """The same steps through ``compute()``, the range split over ``lanes``
+    devices: ``partial_read`` sends each lane its own rows (and the one row
+    of ``u1`` beyond them that the kernel reads: the analysis proves the
+    reach), windows of 20 steps closed by a barrier."""
+    devices = ct.chip_devices().subset(lanes)
+    if len(devices) < lanes:
+        raise SystemExit(f"--lanes {lanes}: only {len(devices)} device(s) "
+                         "here (on the host CPU: XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=N)")
+    u0 = ClArray(u0_init.copy(), name="u0", partial_read=True)
+    u1 = ClArray(u1_init.copy(), name="u1", partial_read=True)
+    frame = ClArray(W * H, np.float32, name="frame", read=False)
+    cr = NumberCruncher(devices, WAVE_SRC)
+    try:
+        cr.enqueue_mode = True
+        for step in range(STEPS):
+            u0.next_param(u1, frame).compute(
+                cr, 1, "waveStep rotate", W * H, LOCAL, values=(W, H, C2))
+            if (step + 1) % 20 == 0:
+                cr.barrier()
+        cr.enqueue_mode = False  # flush: the field comes back to the host
+        ranges = cr.ranges_of(1)
+    finally:
+        cr.dispose()
+    err = float(np.abs(u1.host() - host_reference(u0_init, u1_init,
+                                                  STEPS)).max())
+    print(f"compute() over {lanes} lanes, ranges {ranges}: "
+          f"max |device - host reference| {err:.3e}")
+    assert err < 1e-3, "the split simulation diverged from the host reference"
+
+
 def main() -> None:
+    import argparse
+
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--lanes", type=int, default=0,
+                    help="also run through compute() over this many devices")
+    args = ap.parse_args()
     dev = ct.chip_devices()[0]
     print(f"wave_equation: {W}x{H} membrane, {STEPS} steps on {dev.name}")
 
@@ -133,6 +180,8 @@ def main() -> None:
     assert err < 1e-3, "device simulation diverged from the host reference"
     print(f"field energy: start {energy[0]:.4f} -> end {energy[-1]:.4f}")
     print(ascii_frame(out))
+    if args.lanes:
+        run_split(args.lanes, u0_init, u1_init)
     print("OK")
 
 

@@ -21,6 +21,7 @@ TOY = dict(
     mandel_wh=64, mandel_max_iter=32, local_range=128,
     mandel_per_call=3, mandel_window=6, mandel_marker_window=4,
     nbody_n=256, nbody_iters=6, nbody_window=3,
+    halo_wh=64, halo_window=6,
     stream_n=1 << 14, stream_tuner_runs=2,
     wave_pushes=6,
     serve_tenants=2, serve_sigs=2, serve_reqs=4,
@@ -47,8 +48,8 @@ def _check_rows(rows, n_min=1):
 
 def test_stage_compute(devs):
     rows = chip_smoke.stage_compute(devs, TOY)
-    _check_rows(rows, 4)
-    kl, hand, forced, nbody = rows
+    _check_rows(rows, 5)
+    kl, hand, forced, nbody, wave = rows
     # CPU lanes take the XLA lowering by policy; the routing assertion
     # itself only binds on TPU lanes
     assert kl["lowering"] == "xla"
@@ -66,6 +67,10 @@ def test_stage_compute(devs):
     assert kl["marker_window"]["donate"] == [False, False]
     assert kl["marker_window"]["reached"] > 0
     assert nbody["lanes"] == 2 and nbody["max_err"] <= 0.01
+    # the wave step split by range: exact, every strip lane to lane
+    assert wave["name"] == "wave compute() halo window"
+    assert wave["lanes"] == 2 and sum(wave["ranges"]) == 64 * 64
+    assert wave["halo_spans"] >= 11 * 2 and wave["max_err"] < 1e-5
 
 
 def test_stage_compute_partitions_a_single_device():

@@ -163,13 +163,20 @@ def _halo_args(n=256):
     return x, y
 
 
+# Since PR 32 the PLAIN path keeps a proved reach current (the slice a
+# ``partial_read`` uploads is widened by it: tests/test_halo_exchange.py),
+# so the halo kernel is refused only where nothing does: a pipelined
+# compute uploads blob by blob.
+_PIPELINED = dict(pipeline=True, pipeline_blobs=2)
+
+
 def test_strict_gate_raises_named_finding(devs, monkeypatch):
     monkeypatch.setenv("CK_KERNEL_VERIFY", "strict")
     cr = NumberCruncher(devs.subset(2), _HALO_SRC)
     try:
         x, y = _halo_args()
         with pytest.raises(KernelVerifyError) as ei:
-            x.next_param(y).compute(cr, 70, "sh", 256, 32)
+            x.next_param(y).compute(cr, 70, "sh", 256, 32, **_PIPELINED)
         assert ei.value.finding.kind == "partial-read-halo"
         assert ei.value.finding.line == 4
         assert "partial-read-halo" in str(ei.value)
@@ -188,7 +195,7 @@ def test_advisory_default_computes_and_flight_records(devs, monkeypatch):
     try:
         x, y = _halo_args()
         for _ in range(3):
-            x.next_param(y).compute(cr, 71, "sh", 256, 32)
+            x.next_param(y).compute(cr, 71, "sh", 256, 32, **_PIPELINED)
         evs = [e for e in FLIGHT.snapshot()
                if e.kind == "kernel-verify"
                and e.fields.get("kernels") == "sh"]
@@ -220,7 +227,7 @@ def test_real_split_anchors_the_simulator(devs):
         cr = NumberCruncher(devs.subset(lanes), _HALO_SRC)
         try:
             x, y = _halo_args(n)
-            x.next_param(y).compute(cr, 73, "sh", n, 32)
+            x.next_param(y).compute(cr, 73, "sh", n, 32, **_PIPELINED)
             results[lanes] = np.array(y, copy=True)
         finally:
             cr.dispose()
@@ -244,6 +251,24 @@ def test_real_split_anchors_the_simulator(devs):
     np.testing.assert_array_equal(safe[1], safe[2])
 
 
+def test_plain_path_widens_the_partial_upload_by_the_reach(devs, monkeypatch):
+    """The same halo kernel on the plain path passes the strict gate and
+    is bit-identical on two lanes and on one: the lane's slice is uploaded
+    with its reach (PR 32)."""
+    monkeypatch.setenv("CK_KERNEL_VERIFY", "strict")
+    n = 256
+    results = {}
+    for lanes in (1, 2):
+        cr = NumberCruncher(devs.subset(lanes), _HALO_SRC)
+        try:
+            x, y = _halo_args(n)
+            x.next_param(y).compute(cr, 76, "sh", n, 32)
+            results[lanes] = np.array(y, copy=True)
+        finally:
+            cr.dispose()
+    np.testing.assert_array_equal(results[1], results[2])
+
+
 def test_partial_read_fix_is_bit_identical(devs):
     """Satellite pin (the partial_read flag fix): the saxpy
     input under partial_read produces bit-identical results to the
@@ -262,6 +287,85 @@ def test_partial_read_fix_is_bit_identical(devs):
         finally:
             cr.dispose()
     np.testing.assert_array_equal(out["full"], out["partial"])
+
+
+_REACH_SRC = """
+__kernel void k(__global float* x, __global float* y, int width, int height,
+                float scale) {{
+    int i = get_global_id(0);
+    y[i] = x[{index}];
+}}
+"""
+
+
+@pytest.mark.parametrize("index,values,klass,want", [
+    # c + k * p, both signs of c, of k and of the value
+    ("i - width", dict(width=16), "halo", (16, 0)),
+    ("i + width", dict(width=16), "halo", (0, 16)),
+    ("i + width", dict(width=-5), "halo", (5, 0)),
+    ("i + 2 * width + 1", dict(width=8), "halo", (0, 17)),
+    ("i - 3 + width", dict(width=2), "halo", (1, 0)),
+    ("i - (width << 1)", dict(width=4), "halo", (8, 0)),
+    ("i + width - height", dict(width=4, height=6), "halo", (2, 0)),
+    ("i + width - width", dict(width=9), "confined", (0, 0)),
+    ("i + 1", {}, "halo", (0, 1)),
+    # what stays unbounded: a float added in, a parameter that multiplies
+    # the work-item id (a pitch, the code generator's), anything modular
+    ("i + (int)scale", dict(scale=2.0), "gather", None),
+    ("i * width", dict(width=4), "gather", None),
+    ("(i + width) % height", dict(width=4, height=64), "gather", None),
+])
+def test_reach_of_a_value_parameter_added_inside_an_index(
+        index, values, klass, want):
+    from cekirdekler_tpu import analysis
+    from cekirdekler_tpu.kernel import lang
+
+    (kdef,) = lang.parse_kernels(_REACH_SRC.format(index=index))
+    (acc,) = analysis.summarize_kernel(kdef).reads["x"]
+    got, width = analysis.classify(acc.av, 1)
+    assert got == klass
+    if klass == "halo":
+        assert analysis.reach_of(acc.av).elements(values, 1) == want
+        # a value the launch does not give, or not a whole number: no reach
+        if values:
+            assert analysis.reach_of(acc.av).elements({}, 1) is None
+            broken = {k: v + 0.5 for k, v in values.items()}
+            assert analysis.reach_of(acc.av).elements(broken, 1) is None
+    elif klass == "confined":
+        assert width == 0
+
+
+def test_verdict_carries_the_reach_where_the_caller_exchanges(devs):
+    """``exchange=True`` (the plain path of ``Cores.compute``): the wave
+    step's verdict holds no error and carries ``u1``'s reach; without it,
+    and for a hazard inside ONE pass, the errors stand."""
+    import re
+
+    from cekirdekler_tpu.analysis import FlagRow
+    from cekirdekler_tpu.kernel.registry import KernelProgram
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "examples",
+                           "wave_equation.py")) as f:
+        src = re.search(r'WAVE_SRC = """(.*?)"""', f.read(), re.S).group(1)
+    prog = KernelProgram(src)
+    part = FlagRow(True, True, True, False, False, False, 1)
+    out = FlagRow(False, False, True, False, False, False, 1)
+    names, rows = ("waveStep", "rotate"), (part, part, out)
+    old = prog.verify(names, rows, window=True)
+    assert [f.kind for f in old.errors] == ["partial-read-halo", "window-raw"]
+    assert not old.reach and not old.exchanged
+    new = prog.verify(names, rows, window=True, exchange=True)
+    assert new.ok and new.exchanged == (1,)
+    assert new.reads == (0, 1, 2) and new.writes == (0, 1, 2)
+    values = dict(width=16384, height=16384, c2=0.22)
+    assert new.reach_elements(lambda _k: values) == {1: (16384, 16384)}
+    assert {str(r) for _k, _pos, _p, _e, r in new.reach} == {
+        "-1", "+1", "-width", "+width"}
+    # the writer BEFORE the reader in one pass: nothing lies between the
+    # kernels of one compute, so this one stays an error
+    swapped = prog.verify(("rotate", "waveStep"), rows, window=True,
+                          exchange=True)
+    assert [f.kind for f in swapped.errors] == ["window-raw"]
 
 
 def test_program_verdict_is_cached_per_shape(devs):

@@ -211,6 +211,14 @@ def analyze_corpus(root: str | None = None):
                 row["arrays"][pname] = {
                     "reads": reads,
                     "writes": writes,
+                    # the proved reach of each halo read: offsets beside
+                    # the item's own element, value parameters by name
+                    # (``-width``: one row up); what a launch's exchange
+                    # keeps current (docs/KERNEL_LANGUAGE.md)
+                    "reach": sorted({
+                        str(analysis.reach_of(a.av))
+                        for a in summary.reads.get(pname, ())
+                        if analysis.classify(a.av, 1)[0] == "halo"}),
                     "partial_eligible": bool(reads) and
                     reads == ["confined"],
                     "read_before_write": summary.rbw.get(pname),
