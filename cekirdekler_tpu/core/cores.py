@@ -31,7 +31,7 @@ import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from contextlib import ExitStack, nullcontext
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Any, Sequence
 
 from ..analysis import flag_row
@@ -156,6 +156,18 @@ class _FusedRun:
     # coverage-epoch snapshot at engage: (worker, epoch) — ONE int compare
     # per worker per deferral detects any mid-window coverage reset
     epochs: list = field(default_factory=list)
+    # the eager sub-batch ramp: how many pending iterations the NEXT
+    # flush of _fused_defer waits for — 1 when the window opens, doubled
+    # by every such flush up to Cores.fused_batch
+    ramp: int = 1
+    # iterations this window has dispatched: a window that deferred at
+    # least one has built (or found) its ladder executable on every lane
+    dispatched: int = 0
+    # what the window left when it closed: per row, weak references to
+    # the lane's buffers of ``params`` after its last dispatch.  The next
+    # window of this signature starts on the ladder only over these very
+    # buffers (an upload or another compute's launch replaces them)
+    left: list = field(default_factory=list)
 
 
 def _own_split(owned: Sequence[tuple], lo: int, hi: int) -> list[tuple]:
@@ -302,17 +314,25 @@ class Cores:
         self._enqueue_seq = 0
         # ---- fused-iteration dispatch (the enqueue dispatch-floor
         # collapse): when an enqueue window repeats the same compute id
-        # with unchanged ranges and HBM-resident operands, calls after the
-        # first are DEFERRED (a counter increment) and dispatched in
-        # batches as ONE dynamic-iteration-count ladder executable per
-        # device (Worker.launch_fused / KernelProgram.fused_launcher),
-        # through a depth-limited per-device driver queue so device B's
-        # ladder dispatch overlaps device A's execution.  Rebalance
-        # decisions stay at window boundaries (barrier), fed per-iteration
-        # marginal times.  fused_batch bounds how many iterations one
-        # dispatch carries (the eager sub-batch: the device starts working
-        # mid-window instead of at the barrier); fused_queue_depth bounds
-        # the per-device host dispatch backlog.
+        # with unchanged ranges and HBM-resident operands, its calls are
+        # DEFERRED (a counter increment) and dispatched in batches as ONE
+        # dynamic-iteration-count ladder executable per device
+        # (Worker.launch_fused / KernelProgram.fused_launcher), through a
+        # depth-limited per-device driver queue so device B's ladder
+        # dispatch overlaps device A's execution.  A process's first
+        # window finds out per call that it repeats (call 1 seeds the
+        # candidate, call 2 engages, calls 3.. defer); a window that
+        # repeats the LAST one, over the buffers that one left, starts on
+        # the ladder at once: its first call defers like the others
+        # (_fused_start names why not, fused_stats["window_starts"]).
+        # Rebalance decisions stay at window boundaries (barrier), fed
+        # per-iteration marginal times.  The eager sub-batch RAMPS: the
+        # first deferral of a window is dispatched alone, then 2, 4, 8, ..
+        # (_FusedRun.ramp), so the device starts on the window's first
+        # iteration and each dispatch goes out while the one before it
+        # runs; fused_batch is the ramp's cap, the most iterations one
+        # eager dispatch carries; fused_queue_depth bounds the per-device
+        # host dispatch backlog.
         self.fused_dispatch = True
         self.fused_batch = 16
         self.fused_queue_depth = 2
@@ -330,6 +350,11 @@ class Cores:
         # ping-ponging A,B,A,B) pays one tuple compare per call instead
         # of an engage/break(close+drain) cycle per call
         self._fused_candidate: tuple | None = None
+        # the last window that closed after deferring at least one
+        # iteration (its ladder executables exist, ``left`` names the
+        # buffers it left): what _fused_start opens the next window from.
+        # Written by _fused_close / _fused_start under the lock.
+        self._fused_last: _FusedRun | None = None
         # True while compute_fused_batch runs a per-call iteration it
         # already lane-preflighted: stream-driver submits inside the
         # iteration skip their own fault fire (a mid-phase fire would
@@ -355,6 +380,10 @@ class Cores:
         self.fused_stats: dict[str, Any] = {
             "windows": 0, "fused_iters": 0, "deferred_iters": 0,
             "disengaged": {},
+            # how each enqueue window's first compute went: "ladder"
+            # (deferred as the window's first iteration) or the named
+            # reason it took the per-call path (_fused_start)
+            "window_starts": {},
         }
         # cached metric handles for the fused warm path (one dispatch
         # per batch; the deferral itself counts into fused_stats alone —
@@ -682,6 +711,11 @@ class Cores:
         # executable per device (see _fused_try_engage).  Every break-out
         # names its reason (fused_stats + a "fused" trace instant) so a
         # regression to per-iteration dispatch is attributable.
+        # this call opens an enqueue window (the first since a barrier):
+        # ``how`` it went per call (the reason) is counted and rides its span
+        # ckcheck: ok racy read — a window start is an observation aid
+        opens = self.enqueue_mode and self._enqueue_t0 is None
+        how = None
         if self.enqueue_mode and self._fused_sig is not None and not pipeline:
             sig = self._fused_signature(
                 kernel_names, params, compute_id, global_range,
@@ -694,15 +728,7 @@ class Cores:
                 # them per deferral, else flipping one mid-window would
                 # silently defer a call whose semantics changed (e.g.
                 # repeat_count=3 deferring as ONE iteration)
-                mode_change = (
-                    not self.fused_dispatch
-                    or self.no_compute_mode
-                    or self.repeat_count > 1
-                    or self.repeat_sync_kernel
-                    or self.dispatch_gate is not None
-                    or self.trace_lanes
-                )
-                if mode_change:
+                if self._fused_modes_off():
                     # clear the candidate so this call's tail records ONE
                     # event ("mode-change"), not a second engage-refusal
                     # under another name for the same call.  Under the
@@ -736,6 +762,23 @@ class Cores:
             # leaving enqueue mode without flush() (callers normally go
             # through the cruncher setter, which flushes)
             self._fused_break("enqueue-off")
+        elif self.enqueue_mode:
+            # no fused window is open.  One that repeats the last window,
+            # over the buffers that window left, starts on the ladder: this
+            # call is its first deferred iteration, and nothing of the
+            # per-call path below runs (_fused_start says why not)
+            how = "mode" if pipeline else self._fused_start(
+                self._fused_signature(
+                    kernel_names, params, compute_id, global_range,
+                    local_range, global_offset, value_args,
+                ), compute_id, global_offset)
+            if how is None:
+                if self._fused_defer(t_start, kernel_names, _tt,
+                                     "ladder" if opens else ""):
+                    if opens:
+                        self._note_window_start("ladder")
+                    return
+                how = "closed"  # by another thread, before the deferral
         # kernel partition-safety / flag-soundness gate (analysis/,
         # docs/STATIC_ANALYSIS.md "Kernel partition-safety"): verdicts
         # cache per launch shape in the program, so steady state pays
@@ -771,6 +814,10 @@ class Cores:
                         params[pos].flags.partial_read
                         or pos in verdict.writes for pos in reach)):
                     exchange = (verdict, reach)
+        if opens and how is not None:
+            if exchange is not None:
+                how = "halo"
+            self._note_window_start(how)
         if self.enqueue_mode:
             # under the lock: concurrent host threads may drive different
             # compute ids through one Cores, and the order list's
@@ -935,6 +982,7 @@ class Cores:
         if _tt:
             TRACER.record(
                 "enqueue", _tt, cid=compute_id, tag="+".join(kernel_names),
+                **({"start": "per-call:" + how} if opens and how else {}),
             )
         self._record_perf(compute_id, t_start, ranges)
         if exchange is not None and self.enqueue_mode:
@@ -1198,28 +1246,9 @@ class Cores:
             except TypeError:
                 reason = "unhashable-values"
         rows: list = []
-        epochs: list = []
         if reason is None:
-            single = self.num_devices == 1
-            covered = True
-            for i, w in enumerate(self.workers):
-                if ranges[i] <= 0:
-                    continue
-                off = global_offset + refs[i]
-                rows.append((w, off, ranges[i]))
-                # ckcheck: ok monotone epoch int — one GIL-atomic read
-                epochs.append((w, w.coverage_epoch))
-                for p in params:
-                    fl = p.flags
-                    if fl.read and not fl.write_only:
-                        epw = fl.elements_per_work_item
-                        full = single or not fl.partial_read
-                        covered &= w.upload_covers(
-                            p,
-                            0 if full else off * epw,
-                            p.size if full else ranges[i] * epw,
-                        )
-            if not covered:
+            rows = self._rows_of(ranges, refs, global_offset)
+            if not self._rows_covered(rows, params):
                 # this call needed a partial upload the window would have
                 # to repeat — the deferral contract (pure launch) fails
                 reason = "partial-upload"
@@ -1230,11 +1259,44 @@ class Cores:
             sig=sig, compute_id=compute_id,
             kernel_names=tuple(kernel_names), params=tuple(params),
             value_args=value_args, local_range=local_range,
-            global_range=global_range, step=step, rows=rows, epochs=epochs,
+            global_range=global_range, step=step, rows=rows,
+            # ckcheck: ok monotone epoch int — one GIL-atomic read
+            epochs=[(w, w.coverage_epoch) for w, _off, _size in rows],
         )
         with self._lock:
             self._fused_sig = sig
             self._fused_run = run
+        self._fused_engaged(run)
+
+    def _rows_of(self, ranges, refs, global_offset: int) -> list:
+        """A fused window's rows, ``(worker, global offset, range size)``
+        for every lane with a share, from a compute id's range table."""
+        return [(w, global_offset + refs[i], ranges[i])
+                for i, w in enumerate(self.workers) if ranges[i] > 0]
+
+    def _rows_covered(self, rows, params) -> bool:
+        """Whether every array the kernels read is resident on every
+        row's lane over the range a launch there reads (the enqueue-mode
+        residency test of the per-call path, ``Worker.upload_covers``):
+        the deferral contract is a pure launch."""
+        single = self.num_devices == 1
+        for w, off, size in rows:
+            for p in params:
+                fl = p.flags
+                if fl.read and not fl.write_only:
+                    epw = fl.elements_per_work_item
+                    full = single or not fl.partial_read
+                    if not w.upload_covers(
+                        p,
+                        0 if full else off * epw,
+                        p.size if full else size * epw,
+                    ):
+                        return False
+        return True
+
+    def _fused_engaged(self, run: _FusedRun) -> None:
+        """What every opened fused window records, however it opened."""
+        compute_id, rows = run.compute_id, run.rows
         FLIGHT.event("fused-engage", cid=compute_id, rows=len(rows))
         # persistent-cache seam (core/compilecache.py): an engaged
         # window's spec is what a joining process would need to warm —
@@ -1248,17 +1310,157 @@ class Cores:
             # device residency) — what signature fused, on which lanes
             DECISIONS.record("fused-engage", {
                 "cid": compute_id,
-                "kernels": list(kernel_names),
-                "global_range": global_range,
-                "local_range": local_range,
+                "kernels": list(run.kernel_names),
+                "global_range": run.global_range,
+                "local_range": run.local_range,
                 "lanes": [w.index for w, _off, _size in rows],
             }, {"engaged": True, "rows": len(rows)})
 
-    def _fused_defer(self, t_start: float, kernel_names, span=0.0) -> bool:
+    def _fused_modes_off(self) -> bool:
+        """A runtime mode toggle that no fused window may run under (they
+        are cruncher state, not part of a call's signature, so every
+        deferral and every window start re-checks them)."""
+        return bool(
+            not self.fused_dispatch
+            or self.no_compute_mode
+            or self.repeat_count > 1
+            or self.repeat_sync_kernel
+            or self.dispatch_gate is not None
+            or self.trace_lanes
+        )
+
+    def _fused_start(self, sig: tuple, compute_id: int,
+                     global_offset: int) -> str | None:
+        """A compute in enqueue mode found no fused window open: open one
+        ON THE LADDER if this call repeats the last window, so that it is
+        deferred as the window's first iteration and the per-call path
+        (verify, range table, the pool hop, a per-call launch that hands
+        its values over at run time) never runs.  Returns ``None`` when
+        the window is open, else the named reason the call goes per call
+        (``fused_stats["window_starts"]``), the first that holds of:
+
+        - ``mode``: a runtime toggle no fused window runs under;
+        - ``first-sighting`` / ``values-changed``: the signature is not
+          the last per-call or fused one (the candidate, which survives a
+          barrier); only its values differ, or more;
+        - ``range-change``: a barrier (or a drain transition) armed a
+          rebalance of this compute id, a lane is drained or on probation,
+          or the range table no longer reads what the last window ran;
+        - ``halo``: some compute of this scheduler reads across lanes
+          (who holds which elements is tracked per compute: no deferral);
+        - ``never-fused``: the last window of this signature deferred
+          nothing, so no ladder executable of this key was ever built.
+          This path only PEEKS (``fused_launcher(build=False)``): a
+          window of one compute never compiles a ladder for it;
+        - ``non-resident``: upload coverage was reset since, or a lane's
+          buffers are no longer the ones that window left (an upload, a
+          launch of another compute);
+        - ``partial-upload``: an array the kernels read is not covered;
+        - ``closed``: another host thread opened or closed a window between
+          this call's checks and its deferral.
+
+        What the per-call first compute leaves behind for later is left
+        here too: the deferred-readback records (``flush()`` and a range
+        move read them); ``_fused_defer`` does the window bookkeeping."""
+        if self._fused_modes_off():
+            return "mode"
+        # ckcheck: ok racy read — the open below revalidates under the lock
+        candidate = self._fused_candidate
+        if not self._sig_equal(sig, candidate):
+            same_but_values = (candidate is not None
+                               and candidate[:-1] == sig[:-1])
+            return "values-changed" if same_but_values else "first-sighting"
+        # ckcheck: ok one-shot arm: same contract as compute()'s reads
+        if compute_id in self._enqueue_rebalance or (
+                self.drain.enabled and (self.drain.drained_lanes()
+                                        or self.drain.probe_lanes())):
+            return "range-change"
+        # ckcheck: ok racy emptiness peek, as compute()'s
+        if self._owners:
+            return "halo"
+        # ckcheck: ok racy read — a closed run is never written again, and
+        # the open below revalidates under the lock
+        last = self._fused_last
+        if last is None or not self._sig_equal(last.sig, sig):
+            return "never-fused"
+        ranges = self.global_ranges.get(compute_id)
+        refs = self.global_references.get(compute_id)
+        if (ranges is None or refs is None
+                or self._rows_of(ranges, refs, global_offset) != last.rows):
+            return "range-change"
+        for w, off, size in last.rows:
+            if self.program.fused_launcher(
+                    last.kernel_names, last.step, last.global_range,
+                    last.local_range, last.global_range, last.value_args,
+                    platform=w.device.platform, donate=w.fused_donate,
+                    build=False,
+                    in_range=0 <= off and off + size <= last.global_range,
+            ) is None:
+                return "never-fused"
+        for (w, epoch), left in zip(last.epochs, last.left):
+            # ckcheck: ok monotone epoch int — one GIL-atomic read
+            if w.coverage_epoch != epoch or not w.still_holds(
+                    last.params, left):
+                return "non-resident"
+        if not self._rows_covered(last.rows, last.params):
+            return "partial-upload"
+        run = replace(last, ramp=1, dispatched=0, left=[])
+        active = [w.index for w, _off, _size in run.rows]
+        with self._lock:
+            if self._fused_sig is not None:
+                return "closed"  # another thread opened a window meanwhile
+            if any(w.coverage_epoch != epoch for w, epoch in run.epochs):
+                return "non-resident"
+            for idx, p in enumerate(run.params):
+                fl = p.flags
+                if not (fl.write and not fl.read_only):
+                    continue
+                for w, off, size in run.rows:
+                    # write_all: the owning lane alone defers a readback
+                    if not fl.write_all or \
+                            w.index == active[idx % len(active)]:
+                        self._defer_readback(w, p, off, size, compute_id)
+            self._fused_sig = sig
+            self._fused_run = run
+        self._fused_engaged(run)
+        return None
+
+    def _defer_readback(self, w: Worker, p: ClArray, offset: int,
+                        size: int, compute_id: int) -> None:
+        """One deferred-readback record (enqueue mode): ``flush()`` and a
+        range move read back the newest a lane and array.  Caller holds
+        the scheduler lock."""
+        self._enqueue_seq += 1
+        self._enqueued.append(
+            (self._enqueue_seq, w, p, offset, size, p.flags.write_all,
+             compute_id))
+
+    def _note_window_start(self, how: str) -> None:
+        """How an enqueue window's first compute went: ``ladder`` or the
+        reason it took the per-call path; the dict and the registry carry
+        the same counts."""
+        with self._lock:
+            d = self.fused_stats["window_starts"]
+            d[how] = d.get(how, 0) + 1
+        REGISTRY.counter(
+            "ck_fused_window_start_total",
+            "enqueue windows by how their first compute went", how=how,
+        ).inc()
+
+    def _fused_defer(self, t_start: float, kernel_names, span=0.0,
+                     start: str = "") -> bool:
         """Count this call into the active fused window.  Returns False
         when the window was concurrently closed (caller falls through to
         the per-call path).  ``span`` is the caller's open "enqueue" span
-        (falsy while the tracer is inactive)."""
+        (falsy while the tracer is inactive); ``start`` rides it where
+        this call opened its enqueue window on the ladder.
+
+        The eager sub-batch ramps: the pending iterations are dispatched
+        once they number ``run.ramp``, which starts at 1 when a window
+        opens and doubles with every such dispatch up to ``fused_batch``
+        (1, 2, 4, 8, 16, 16, ...): the device starts on the window's first
+        deferred iteration, and each dispatch goes out while the one
+        before it runs.  The same in every window: a count, not a probe."""
         with self._lock:
             run = self._fused_run
             if run is None or self._fused_sig is None:
@@ -1266,9 +1468,12 @@ class Cores:
             cid = run.compute_id
             self._note_enqueue_call(cid, t_start)
             self._fused_pending += 1
-            pending = self._fused_pending
+            cap = max(1, int(self.fused_batch))
+            due = self._fused_pending >= min(run.ramp, cap)
+            if due:
+                run.ramp = min(2 * run.ramp, cap)
             self.fused_stats["deferred_iters"] += 1
-        if pending >= max(1, int(self.fused_batch)):
+        if due:
             self._fused_flush()
         if TRACER.active():
             # guard the WHOLE call: the tag concatenation allocates per
@@ -1278,6 +1483,7 @@ class Cores:
             TRACER.record(
                 "enqueue", span, cid=cid,
                 tag="+".join(kernel_names) + " fused-defer",
+                **({"start": start} if start else {}),
             )
         if self.performance_feed:
             # the feed wants a printed row per call — keep the full
@@ -1351,6 +1557,7 @@ class Cores:
                 self._fused_candidate = None
             raise
         with self._lock:
+            run.dispatched += iters
             self.fused_stats["windows"] += 1
             self.fused_stats["fused_iters"] += iters
         self._m_fused_windows.inc()
@@ -1378,7 +1585,7 @@ class Cores:
                 "fused", _tt, cid=run.compute_id, tag=f"x{iters}",
                 **(lowering_meta(infos) if infos else {}))
 
-    # ckcheck: cold window boundary — runs once per fused_batch deferrals
+    # ckcheck: cold window boundary — runs once a sub-batch of the ramp
     def _fused_flush(self) -> None:
         """Dispatch the accumulated deferred iterations (window stays
         open).  Under _fused_mu so a concurrent close cannot drain the
@@ -1393,14 +1600,18 @@ class Cores:
     def _fused_close(self) -> None:
         """End the fused window at a sync point: stop deferrals, dispatch
         the residue, and drain the per-device drivers (host-side dispatch
-        complete — device completion is the caller's fence).  Each new
-        window re-engages through its first per-call iteration."""
+        complete — device completion is the caller's fence).  A window that
+        deferred anything is kept as ``_fused_last``: the next window
+        re-engages through its first per-call iteration, or, where it
+        repeats this one, starts on the ladder (``_fused_start``)."""
         with self._fused_mu:
             with self._lock:
                 run, k = self._fused_run, self._fused_pending
                 self._fused_pending = 0
                 self._fused_sig = None
                 self._fused_run = None
+                if run is not None:
+                    self._fused_last = None
             if run is not None and k > 0:
                 self._dispatch_fused(run, k)
         _td = TRACER.t0("drain")
@@ -1408,6 +1619,13 @@ class Cores:
             self._fused_drain()
         finally:
             TRACER.record("drain", _td)
+        if run is not None and run.dispatched:
+            # the drivers have drained: the lanes hold what the window's
+            # last dispatch left.  The next window of this signature may
+            # start on the ladder over these buffers (_fused_start)
+            run.left = [w.buffers_left(run.params) for w, _o, _s in run.rows]
+            with self._lock:
+                self._fused_last = run
 
     def _note_disengage(self, reason: str, cid: int | None) -> None:
         """The one disengage-accounting path: fused_stats dict bump +
@@ -1453,12 +1671,7 @@ class Cores:
             if (
                 run is None
                 or not self._sig_equal(self._fused_sig, sig)
-                or not self.fused_dispatch
-                or self.no_compute_mode
-                or self.repeat_count > 1
-                or self.repeat_sync_kernel
-                or self.dispatch_gate is not None
-                or self.trace_lanes
+                or self._fused_modes_off()
                 or run.compute_id in self._enqueue_rebalance
                 or any(w.coverage_epoch != ep for w, ep in run.epochs)
             ):
@@ -1547,7 +1760,19 @@ class Cores:
         try:
             while done < iters:
                 t_start = time.perf_counter()
-                if self._batch_defer(sig, iters - done, t_start):
+                # ckcheck: ok racy reads — single enqueue driver
+                opens = self._enqueue_t0 is None
+                deferred = self._batch_defer(sig, iters - done, t_start)
+                if not deferred and self._fused_sig is None:
+                    # no window open: one that repeats the last starts
+                    # on the ladder, the whole batch in it
+                    deferred = (
+                        self._fused_start(sig, compute_id, global_offset)
+                        is None
+                        and self._batch_defer(sig, iters - done, t_start))
+                    if deferred and opens:
+                        self._note_window_start("ladder")
+                if deferred:
                     ladder = iters - done
                     done = iters
                     break
@@ -1727,7 +1952,11 @@ class Cores:
                     platform=platform, donate=donate,
                 )
                 if fn is not None:
-                    out = fn(0, units, 1, bufs)
+                    # the run-time scalars as the live path hands them
+                    # over (Worker.ladder_scalars): int32 arrays on the
+                    # lane's device, the argument types of the executable
+                    out = fn(*(jax.device_put(np.int32(v), device)
+                               for v in (0, units, 1)), bufs)
                     jax.block_until_ready(out)
                     bufs = tuple(out)  # donate consumed the scratch set
                 # every per-call chunk the binary ladder can emit
@@ -1984,11 +2213,8 @@ class Cores:
                     # ownership rule as the immediate paths
                     if not fl.write_all or w.index == write_all_owner.get(idx):
                         with self._lock:
-                            self._enqueue_seq += 1
-                            self._enqueued.append(
-                                (self._enqueue_seq, w, p, offset, size,
-                                 fl.write_all, compute_id)
-                            )
+                            self._defer_readback(
+                                w, p, offset, size, compute_id)
                     continue
                 epw = fl.elements_per_work_item
                 if fl.write_all:
@@ -2370,11 +2596,8 @@ class Cores:
                 if fl.write and not fl.read_only:
                     if not fl.write_all or w.index == write_all_owner.get(idx):
                         with self._lock:
-                            self._enqueue_seq += 1
-                            self._enqueued.append(
-                                (self._enqueue_seq, w, p, offset, size,
-                                 fl.write_all, compute_id)
-                            )
+                            self._defer_readback(
+                                w, p, offset, size, compute_id)
         else:
             for idx, p in enumerate(params):
                 fl = p.flags
@@ -2460,20 +2683,12 @@ class Cores:
                 if w.index == write_all_owner.get(idx):
                     if self.enqueue_mode:
                         with self._lock:
-                            self._enqueue_seq += 1
-                            self._enqueued.append(
-                                (self._enqueue_seq, w, p, 0, p.size, True,
-                                 compute_id)
-                            )
+                            self._defer_readback(w, p, 0, p.size, compute_id)
                     else:
                         handles.append(w.download_async(p, 0, p.size, True))
             elif self.enqueue_mode:
                 with self._lock:
-                    self._enqueue_seq += 1
-                    self._enqueued.append(
-                        (self._enqueue_seq, w, p, offset, size, False,
-                         compute_id)
-                    )
+                    self._defer_readback(w, p, offset, size, compute_id)
         for h in handles:
             Worker.finish_download(h)
 

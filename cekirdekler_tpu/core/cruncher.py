@@ -134,7 +134,7 @@ class NumberCruncher:
     def fused_dispatch(self) -> bool:
         """Fused-iteration dispatch (default True): when an enqueue
         window repeats the same compute id with unchanged partition
-        ranges and HBM-resident operands, calls after the first defer and
+        ranges and HBM-resident operands, its calls defer and
         dispatch in batches as ONE dynamic-iteration-count ladder
         executable per device — collapsing the per-call dispatch floor.
         Results are bit-identical to per-iteration dispatch; disengages
@@ -151,10 +151,12 @@ class NumberCruncher:
 
     @property
     def fused_batch(self) -> int:
-        """Iterations per fused ladder dispatch (default 16): smaller
-        starts the device earlier in the window, larger amortizes the
-        dispatch floor over more iterations.  The executable is shared
-        across batch sizes (iteration count is a runtime argument)."""
+        """The most iterations one eager fused ladder dispatch carries
+        (default 16).  A window's dispatches ramp up to it, x1 x2 x4 ..,
+        so the device starts on the window's first deferred iteration
+        whatever the cap; larger amortizes the dispatch floor over more
+        iterations.  The executable is shared across batch sizes
+        (iteration count is a runtime argument)."""
         return self.cores.fused_batch
 
     @fused_batch.setter
@@ -164,7 +166,9 @@ class NumberCruncher:
     @property
     def fused_stats(self) -> dict:
         """Fused-dispatch observability: windows dispatched, iterations
-        fused/deferred, and per-reason disengage counts."""
+        fused/deferred, per-reason disengage counts, and how each enqueue
+        window started (``window_starts``: ``ladder`` or the reason its
+        first compute went per call)."""
         # ckcheck: ok racy snapshot read — reporting only
         return self.cores.fused_stats
 

@@ -24,6 +24,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
+import weakref
 from functools import partial
 from typing import Any, Callable, Sequence
 
@@ -321,6 +322,8 @@ class Worker:
         # writer), and the fence_split-off clear holds self.lock
         # ckcheck: ok single-writer driver protocol + post-drain reads
         self._cid_last_out: dict[int, Any] = {}
+        # the ladder's run-time scalars kept on the device: value -> array
+        self._ladder_scalars: dict[int, Any] = {}
         # coverage epoch: bumped by every reset_coverage().  The fused
         # dispatch path (core/cores.py) snapshots it at window engage and
         # compares one int per deferral instead of re-walking per-array
@@ -592,6 +595,28 @@ class Worker:
     def buffer(self, arr: ClArray) -> Any:
         return self._buffers[id(arr)]
 
+    def buffers_left(self, params: Sequence[ClArray]) -> tuple:
+        """Weak references to this lane's buffers of ``params`` as they
+        stand: what a fused window left when it closed (``Cores``).  Weak,
+        so that a buffer an upload replaces later is not kept alive.
+        Empty where the lane lacks one."""
+        bufs = [self._buffers.get(id(p)) for p in params]
+        if any(b is None for b in bufs):
+            return ()
+        return tuple(weakref.ref(b) for b in bufs)
+
+    def still_holds(self, params: Sequence[ClArray], left: tuple) -> bool:
+        """Whether this lane's buffers of ``params`` are the very arrays
+        :meth:`buffers_left` saw: no upload and no other launch has
+        replaced one since."""
+        if len(left) != len(params):
+            return False
+        for p, ref in zip(params, left):
+            buf = self._buffers.get(id(p))
+            if buf is None or ref() is not buf:
+                return False
+        return True
+
     def set_buffer(self, arr: ClArray, buf: Any) -> None:
         self._buffers[id(arr)] = buf
         self._buffer_owner[id(arr)] = arr
@@ -764,7 +789,7 @@ class Worker:
                     global_size, value_args, platform=self.device.platform,
                     donate=self.fused_donate, build=False, in_range=in_range,
                 )
-                one_args = (offset, units, 1, bufs)
+                one_args = self.ladder_scalars(offset, units, 1) + (bufs,)
             if one_fn is not None:
                 one_fn.info.reach = reach
                 bufs = tuple(one_fn(*one_args))
@@ -854,6 +879,24 @@ class Worker:
             self.markers.add(dispatched)
             self.markers.reach_when_ready(bufs[0], dispatched)
 
+    def ladder_scalars(self, *values: int) -> tuple:
+        """The ladder executable's run-time scalars (offset, units,
+        iterations) as ``int32`` arrays kept on this lane.  A Python
+        scalar handed to a dispatch is a host-to-device transfer of its
+        own, every time; a window's dispatches repeat the same few values
+        (the lane's offset and units, the ramp's iteration counts), so
+        each is put on the device once and kept."""
+        kept = self._ladder_scalars
+        if len(kept) > 1024:  # a balancer that never settles: start over
+            kept.clear()
+        out = []
+        for v in values:
+            s = kept.get(v)
+            if s is None:
+                s = kept[v] = jax.device_put(np.int32(v), self.device)
+            out.append(s)
+        return tuple(out)
+
     @property
     def fused_donate(self) -> bool:
         """Whether this lane's fused ladder donates its buffer tuple: on a
@@ -918,7 +961,8 @@ class Worker:
         _dm = MARKS.begin(kernel_names, compute_id, self.index) \
             if MARKS.enabled else None
         try:
-            bufs = tuple(fn(offset, size // step, iters, bufs))
+            bufs = tuple(fn(*self.ladder_scalars(offset, size // step, iters),
+                            bufs))
         finally:
             if _dm is not None:
                 MARKS.end(_dm)
@@ -1061,6 +1105,7 @@ class Worker:
         self.benchmarks.clear()
         self.transfer_benchmarks.clear()
         self._cid_last_out.clear()
+        self._ladder_scalars.clear()
         if self.markers is not None:
             self.markers.close()
             self.markers = None

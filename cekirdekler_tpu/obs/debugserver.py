@@ -218,6 +218,8 @@ class DebugServer:
                     "fused_iters": cores.fused_stats["fused_iters"],
                     "deferred_iters": cores.fused_stats["deferred_iters"],
                     "disengaged": dict(cores.fused_stats["disengaged"]),
+                    "window_starts": dict(
+                        cores.fused_stats["window_starts"]),
                 }
             lanes = []
             for w in cores.workers:

@@ -349,6 +349,109 @@ def _wave_window_across_lanes(lanes, sizes) -> dict:
         cr.dispose()
 
 
+def _ramp(computes: int, cap: int = 16) -> list[int]:
+    """The dispatches of a fused window of ``computes`` deferred iterations:
+    the eager sub-batch ramps x1 x2 x4 .. up to ``cap``, the residue last."""
+    out, k = [], 1
+    while computes >= k:
+        out.append(k)
+        computes -= k
+        k = min(2 * k, cap)
+    return out + ([computes] if computes else [])
+
+
+def _nbody_windows_start_on_the_ladder(devices, sizes) -> dict:
+    """Three enqueue windows of the n-body kernel on ONE lane (the
+    benchmark's ``nbody_8k_window``): the second and the third repeat the
+    window before them and start on the fused ladder.  The third runs under
+    a profiler session: its first ``ck/enqueue`` span reads ``start=ladder``,
+    its ``ck/fused`` tags are the ramp, every ``ck/launch`` in it is a fused
+    dispatch (no per-call launch of the kernel), and the velocities are
+    ``sha1``-equal to the same windows with ``fused_dispatch = False``."""
+    import hashlib
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+    from cekirdekler_tpu.workloads import NBODY_SRC
+
+    lane = devices.subset(1)
+    n, lr, per = sizes["nbody_n"], sizes["local_range"], sizes["nbody_window"]
+    pos = (np.random.default_rng(sizes["seed"] + 34).random(
+        (3, n), dtype=np.float32) - 0.5) * 2.0
+    root = tempfile.mkdtemp(prefix="ck_smoke_starts_")
+    digests, timing, events = {}, {}, []
+    try:
+        for fused in (False, True):
+            arrays = [ClArray(pos[i].copy(), name=c, read_only=True)
+                      for i, c in enumerate("xyz")]
+            vel = [ClArray(n, np.float32, name=f"v{c}", partial_read=True)
+                   for c in "xyz"]
+            group = arrays[0].next_param(*arrays[1:], *vel)
+            cr = NumberCruncher(lane, NBODY_SRC)
+            cr.fused_dispatch = fused
+
+            def window():
+                for _ in range(per):
+                    group.compute(cr, 7106, "nBody", n, lr, values=(n, 1e-4))
+                cr.barrier()
+
+            try:
+                cr.enqueue_mode = True
+                _, cold = _timed(window)
+                _, run = _timed(window)
+                if fused:
+                    timing = {"cold_s": cold, "run_s": run}
+                    opts = jax.profiler.ProfileOptions()
+                    opts.python_tracer_level = 0
+                    jax.profiler.start_trace(root, profiler_options=opts)
+                    try:
+                        window()
+                    finally:
+                        jax.profiler.stop_trace()
+                    starts = dict(cr.fused_stats["window_starts"])
+                else:
+                    window()
+                cr.enqueue_mode = False  # flush
+                digests[fused] = [hashlib.sha1(v.host().tobytes()).hexdigest()
+                                  for v in vel]
+            finally:
+                cr.dispose()
+        path = [os.path.join(r, f) for r, _d, fs in os.walk(root)
+                for f in fs if f.endswith(".xplane.pb")][0]
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name == "/host:CPU":
+                events += [(ev.start_ns, ev.name, dict(ev.stats))
+                           for line in plane.lines for ev in line.events
+                           if ev.name.startswith("ck/")]
+    finally:
+        shutil.rmtree(root, ignore_errors=True)
+    events.sort(key=lambda e: e[0])
+    enqueues = [st for _t, name, st in events if name == "ck/enqueue"]
+    fused_tags = [str(st.get("tag")) for _t, name, st in events
+                  if name == "ck/fused"]
+    launches = [str(st.get("tag")) for _t, name, st in events
+                if name == "ck/launch"]
+    _require(starts == {"first-sighting": 1, "ladder": 2},
+             f"window starts {starts}")
+    _require(len(enqueues) == per and enqueues[0].get("start") == "ladder"
+             and all(str(st.get("tag")).endswith("fused-defer")
+                     for st in enqueues),
+             f"third window's enqueue spans: {enqueues[:2]} of {len(enqueues)}")
+    _require(fused_tags == [f"x{k}" for k in _ramp(per)],
+             f"third window's fused dispatches {fused_tags}")
+    _require(launches and all(t.startswith("fused:") for t in launches),
+             f"a per-call launch in a window started on the ladder: {launches}")
+    _require(digests[True] == digests[False] and any(
+        v.host().any() for v in vel),
+        f"velocities differ from fused_dispatch=False: {digests}")
+    return _row("nBody windows start on the ladder", "", timing["cold_s"],
+                timing["run_s"], 0.0, window_starts=starts, ramp=fused_tags,
+                sha1=digests[True][0][:16])
+
+
 def stage_compute(devices, sizes) -> list[dict]:
     from cekirdekler_tpu import ClArray
     from cekirdekler_tpu.core.cruncher import NumberCruncher
@@ -423,6 +526,7 @@ def stage_compute(devices, sizes) -> list[dict]:
     finally:
         cr.dispose()
     rows.append(_wave_window_across_lanes(lanes, sizes))
+    rows.append(_nbody_windows_start_on_the_ladder(devices, sizes))
     return rows
 
 

@@ -48,8 +48,8 @@ def _check_rows(rows, n_min=1):
 
 def test_stage_compute(devs):
     rows = chip_smoke.stage_compute(devs, TOY)
-    _check_rows(rows, 5)
-    kl, hand, forced, nbody, wave = rows
+    _check_rows(rows, 6)
+    kl, hand, forced, nbody, wave, starts = rows
     # CPU lanes take the XLA lowering by policy; the routing assertion
     # itself only binds on TPU lanes
     assert kl["lowering"] == "xla"
@@ -71,11 +71,14 @@ def test_stage_compute(devs):
     assert wave["name"] == "wave compute() halo window"
     assert wave["lanes"] == 2 and sum(wave["ranges"]) == 64 * 64
     assert wave["halo_spans"] >= 11 * 2 and wave["max_err"] < 1e-5
+    # three windows on one lane: the second and third start on the ladder
+    assert starts["window_starts"] == {"first-sighting": 1, "ladder": 2}
+    assert starts["ramp"] == ["x1", "x2"]  # a window of three computes
 
 
 def test_stage_compute_partitions_a_single_device():
     rows = chip_smoke.stage_compute(platforms().cpus().subset(1), TOY)
-    assert rows[-1]["lanes"] == 2 and all(r > 0 for r in rows[-1]["ranges"])
+    assert rows[-2]["lanes"] == 2 and all(r > 0 for r in rows[-2]["ranges"])
 
 
 def test_stage_transfers(devs):

@@ -71,20 +71,22 @@ def test_fused_window_defers_and_is_exact(devs):
 
 
 def test_fused_batches_dispatch_eagerly(devs):
-    """Deferral dispatches every fused_batch iterations (device starts
-    working mid-window), not only at the barrier."""
+    """The eager sub-batch ramps: the first deferral of a window is
+    dispatched alone, then 2, then 4, up to fused_batch (the device starts
+    on the window's first deferred iteration), not only at the barrier."""
     cr = NumberCruncher(devs.subset(2), INC)
     cr.fused_batch = 4
     x = ClArray(np.zeros(512, np.float32), name="x")
     x.partial_read = True
     cr.enqueue_mode = True
-    # call 1 seeds, call 2 engages, calls 3..11 defer -> 2 eager batches
-    # of 4 mid-window, residue 1 at the barrier
+    # call 1 seeds, call 2 engages, calls 3..11 defer -> eager batches of
+    # 1, 2 and 4 mid-window, residue 2 at the barrier
     for _ in range(11):
         x.compute(cr, 1, "inc", 512, 64)
-    assert cr.fused_stats["windows"] == 2
-    assert cr.fused_stats["fused_iters"] == 8
-    cr.barrier()  # residue (1) dispatches at the window close
+    assert cr.fused_stats["windows"] == 3
+    assert cr.fused_stats["fused_iters"] == 7
+    cr.barrier()  # residue (2) dispatches at the window close
+    assert cr.fused_stats["windows"] == 4
     assert cr.fused_stats["fused_iters"] == 9
     cr.enqueue_mode = False
     np.testing.assert_array_equal(np.asarray(x), 11.0)
@@ -92,9 +94,10 @@ def test_fused_batches_dispatch_eagerly(devs):
 
 
 def test_fused_is_one_dispatch_per_batch(devs):
-    """Marker accounting: a 32-iteration window costs O(1) dispatches,
-    not O(iterations) — the dispatch-floor collapse made observable
-    (same methodology as test_repeat_is_one_fused_dispatch)."""
+    """Marker accounting: a 32-iteration window costs O(log fused_batch)
+    dispatches, not O(iterations) — the dispatch-floor collapse made
+    observable (same methodology as test_repeat_is_one_fused_dispatch) —
+    and a window that repeats it has no per-call launch at all."""
     cr = NumberCruncher(devs.subset(1), INC)
     cr.fine_grained_queue_control = True
     cr.fused_batch = 32
@@ -103,11 +106,21 @@ def test_fused_is_one_dispatch_per_batch(devs):
     cr.enqueue_mode = True
     for _ in range(32):
         x.compute(cr, 1, "inc", 256, 64)
-    cr.enqueue_mode = False
+    cr.barrier()
     w = cr.cores.workers[0]
-    # 1 upload + 1 per-call launch + 1 fused ladder + 1 download = 4
-    assert w.markers.added <= 5, w.markers.added
-    np.testing.assert_array_equal(np.asarray(x), 32.0)
+    # 1 upload + 2 per-call launches + the ramp's ladders x1 x2 x4 x8 and
+    # the residue x15 = 8
+    assert w.markers.added == 8, w.markers.added
+    for _ in range(32):
+        x.compute(cr, 1, "inc", 256, 64)
+    cr.barrier()
+    # the second window starts on the ladder: x1 x2 x4 x8 x16, residue x1
+    assert w.markers.added == 8 + 6, w.markers.added
+    assert cr.fused_stats["window_starts"] == {"first-sighting": 1,
+                                               "ladder": 1}
+    cr.enqueue_mode = False  # + 1 download
+    assert w.markers.added == 8 + 6 + 1, w.markers.added
+    np.testing.assert_array_equal(np.asarray(x), 64.0)
     cr.dispose()
 
 
@@ -413,6 +426,379 @@ def test_disengage_unhashable_values(devs):
     assert cr.fused_stats["fused_iters"] == 0
     cr.enqueue_mode = False
     np.testing.assert_array_equal(np.asarray(x), 6.0)
+    cr.dispose()
+
+
+# ---------------------------------------------------------------------------
+# how a window starts (PR 34): on the ladder where it repeats the last one
+# ---------------------------------------------------------------------------
+
+ACC = """
+__kernel void acc(__global float* p, __global float* v, float dt) {
+    int i = get_global_id(0);
+    v[i] = v[i] + dt * (p[i] - 0.37f * v[i]);
+}
+"""
+
+
+def _window(cr, compute, computes, lanes):
+    for _ in range(computes):
+        compute()
+    cr.barrier()
+    if lanes > 1:
+        # every barrier of more than one lane arms a rebalance; the rig
+        # stands for a balancer that has nothing to move
+        cr.cores._enqueue_rebalance.clear()
+
+
+def _fused_tags():
+    from cekirdekler_tpu.trace.spans import TRACER
+
+    return [int(s.tag[1:]) for s in TRACER.snapshot()
+            if s.kind == "fused" and (s.tag or "").startswith("x")]
+
+
+@pytest.mark.parametrize("lanes", [1, 2])
+def test_window_repeating_the_last_starts_on_the_ladder(devs, lanes):
+    """Three windows of an accumulating kernel: the second and the third
+    start on the ladder (their first compute is deferred like the others),
+    a flush() after such a window brings every iteration back, and the
+    state is bit-identical to fused_dispatch = False."""
+    n, computes = 1024, 6
+    rng = np.random.default_rng(5)
+    pos = rng.standard_normal(n).astype(np.float32)
+    left = {}
+    for fused in (False, True):
+        cr = NumberCruncher(devs.subset(lanes), ACC)
+        cr.fused_dispatch = fused
+        p = ClArray(pos.copy(), name="p", read_only=True)
+        v = ClArray(np.zeros(n, np.float32), name="v", partial_read=True)
+        g = p.next_param(v)
+
+        def compute():
+            g.compute(cr, 34, "acc", n, 64, values=(1e-2,))
+
+        cr.enqueue_mode = True
+        _window(cr, compute, computes, lanes)
+        _window(cr, compute, computes, lanes)
+        cr.flush()  # after a window that started on the ladder
+        states = [np.asarray(v).copy()]
+        _window(cr, compute, computes, lanes)
+        cr.enqueue_mode = False
+        states.append(np.asarray(v).copy())
+        left[fused] = states
+        starts = cr.fused_stats["window_starts"]
+        if fused:
+            assert starts == {"first-sighting": 1, "ladder": 2}, starts
+            # windows 2 and 3 deferred every compute, window 1 all but two
+            assert cr.fused_stats["deferred_iters"] == 3 * computes - 2
+            assert cr.fused_stats["fused_iters"] == 3 * computes - 2
+        else:
+            assert starts == {"mode": 3}, starts
+            assert cr.fused_stats["fused_iters"] == 0
+        cr.dispose()
+    assert np.abs(left[True][0]).max() > 0
+    for a, b in zip(left[True], left[False]):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("computes,fused_batch,ramp", [
+    (50, 16, [1, 2, 4, 8, 16, 16, 3]),
+    (10, 16, [1, 2, 4, 3]),
+    (20, 4, [1, 2, 4, 4, 4, 4, 1]),
+    (7, 16, [1, 2, 4]),
+])
+def test_the_eager_sub_batch_ramps(devs, computes, fused_batch, ramp):
+    """A window that starts on the ladder dispatches its computes as
+    x1 x2 x4 .. up to fused_batch, the residue at the barrier: the same
+    dispatches in every window, and they add up to its computes."""
+    from cekirdekler_tpu.trace.spans import TRACER
+
+    cr = NumberCruncher(devs.subset(1), INC)
+    cr.fused_batch = fused_batch
+    x = ClArray(np.zeros(256, np.float32), name="x", partial_read=True)
+
+    def compute():
+        x.compute(cr, 35, "inc", 256, 64)
+
+    cr.enqueue_mode = True
+    _window(cr, compute, computes, 1)
+    try:
+        for _ in range(2):
+            TRACER.enable(clear=True)
+            _window(cr, compute, computes, 1)
+            assert _fused_tags() == ramp
+            assert sum(ramp) == computes
+    finally:
+        TRACER.disable()
+    cr.enqueue_mode = False
+    np.testing.assert_array_equal(np.asarray(x), 3.0 * computes)
+    cr.dispose()
+
+
+def _spy_starts(monkeypatch) -> list:
+    """Switches the tracer on; returns the list that takes the ``(start,
+    tag)`` of every ``enqueue`` span recorded with a ``start`` field (the
+    ring's spans have no room for fields)."""
+    from cekirdekler_tpu.trace.spans import TRACER
+
+    seen: list = []
+    record = TRACER.record
+
+    def spy(kind, t0, *a, **kw):
+        if kind == "enqueue" and t0 and "start" in kw:
+            seen.append((kw["start"], kw.get("tag", "")))
+        return record(kind, t0, *a, **kw)
+
+    monkeypatch.setattr(TRACER, "record", spy)
+    TRACER.enable(clear=True)
+    return seen
+
+
+def _start_case(devs, monkeypatch, lanes=1, src=INC):
+    """A cruncher in enqueue mode, one array, and the ``start`` fields of
+    the ``enqueue`` spans recorded from now on."""
+    cr = NumberCruncher(devs.subset(lanes), src)
+    x = ClArray(np.zeros(1024, np.float32), name="x", partial_read=True)
+    seen = _spy_starts(monkeypatch)
+    cr.enqueue_mode = True
+    return cr, x, seen
+
+
+def _values_changed(cr, x, w):
+    w(lambda: x.compute(cr, 36, "axb", 1024, 64, values=(1.0,)))
+    w(lambda: x.compute(cr, 36, "axb", 1024, 64, values=(2.0,)))
+    return 3.0 * 4
+
+
+def _reupload(cr, x, w):
+    inc = lambda: x.compute(cr, 36, "inc", 1024, 64)  # noqa: E731
+    w(inc)
+    cr.enqueue_mode = False  # flushes: the host holds 4
+    x.host()[:] = 10.0
+    inc()  # a synchronous compute uploads the state anew: 11
+    cr.enqueue_mode = True
+    w(inc)
+    return 15.0
+
+
+def _armed_rebalance(cr, x, w):
+    inc = lambda: x.compute(cr, 36, "inc", 1024, 64)  # noqa: E731
+    w(inc)
+    w(inc)  # two lanes: the barrier before it armed a rebalance
+    return 8.0
+
+
+def _one_compute(cr, x, w):
+    inc = lambda: x.compute(cr, 36, "inc", 1024, 64)  # noqa: E731
+    for _ in range(3):
+        inc()
+        cr.barrier()
+    prog, lane = cr.cores.program, cr.cores.workers[0]
+    # no ladder executable was built for a window that never deferred
+    assert prog.fused_compiled_count == 0
+    assert prog.fused_launcher(
+        ("inc",), 64, 1024, 64, 1024, (), platform=lane.device.platform,
+        donate=lane.fused_donate, build=False) is None
+    return 3.0
+
+
+def _mode_toggle(cr, x, w):
+    inc = lambda: x.compute(cr, 36, "inc", 1024, 64)  # noqa: E731
+    w(inc)
+    cr.repeat_count = 2
+    w(inc)
+    cr.repeat_count = 1
+    return 4.0 + 8.0
+
+
+def _shrunk_coverage(cr, x, w):
+    inc = lambda: x.compute(cr, 36, "inc", 1024, 64)  # noqa: E731
+    w(inc)
+    lane = cr.cores.workers[0]
+    with lane.lock:
+        lane._uploaded[id(x)] = (0, 1)
+    w(inc)
+    # the per-call start uploads what the lane is said to lack from the
+    # host, which a window without a flush has not reached: the rig's
+    # doing, and what the per-call path always did with such a record
+    return 4.0
+
+
+AXB = INC + """
+__kernel void axb(__global float* a, float b) {
+    int i = get_global_id(0);
+    a[i] = a[i] + b;
+}
+"""
+
+
+@pytest.mark.parametrize("reason,lanes,drive,first", [
+    ("values-changed", 1, _values_changed, "first-sighting"),
+    ("non-resident", 1, _reupload, "first-sighting"),
+    ("range-change", 2, _armed_rebalance, "first-sighting"),
+    ("never-fused", 1, _one_compute, "first-sighting"),
+    ("mode", 1, _mode_toggle, "first-sighting"),
+    ("partial-upload", 1, _shrunk_coverage, "first-sighting"),
+])
+def test_a_per_call_window_start_is_named(devs, monkeypatch, reason, lanes,
+                                          drive, first):
+    """Every way a window can fail to start on the ladder takes the
+    per-call path as before, counted under its name and said on the
+    window's first ``enqueue`` span; no window here starts on the
+    ladder, and the results are exact."""
+    from cekirdekler_tpu.trace.spans import TRACER
+
+    cr, x, seen = _start_case(devs, monkeypatch, lanes, AXB)
+
+    def window(compute, computes=4):
+        for _ in range(computes):
+            compute()
+        cr.barrier()
+
+    try:
+        want = drive(cr, x, window)
+    finally:
+        TRACER.disable()
+    starts = dict(cr.fused_stats["window_starts"])
+    assert starts.pop(first) == 1
+    assert set(starts) == {reason} and "ladder" not in starts, starts
+    assert [s for s, _tag in seen][1:] == [f"per-call:{reason}"] * starts[reason]
+    assert not any(tag.endswith("fused-defer") for _s, tag in seen)
+    cr.enqueue_mode = False
+    np.testing.assert_array_equal(np.asarray(x), want)
+    cr.dispose()
+
+
+def test_an_exchanging_window_starts_per_call_named_halo(devs, monkeypatch):
+    """A compute that reads across lanes never fuses: its windows start
+    per call, named ``halo``."""
+    from cekirdekler_tpu.trace.spans import TRACER
+
+    src = """
+    __kernel void shift(__global float* a, __global float* b) {
+        int i = get_global_id(0);
+        b[i] = a[i + 1] + 1.0f;
+    }
+    __kernel void back(__global float* a, __global float* b) {
+        int i = get_global_id(0);
+        a[i] = b[i];
+    }"""
+    n = 1024
+    cr = NumberCruncher(devs.subset(2), src)
+    a = ClArray(np.arange(n + 1, dtype=np.float32), name="a",
+                partial_read=True)
+    b = ClArray(np.zeros(n, np.float32), name="b", read=False)
+    g = a.next_param(b)
+    seen = _spy_starts(monkeypatch)
+    try:
+        cr.enqueue_mode = True
+        for _ in range(3):
+            for _ in range(3):
+                g.compute(cr, 37, "shift back", n, 64)
+            cr.barrier()
+        cr.enqueue_mode = False
+    finally:
+        TRACER.disable()
+    assert cr.fused_stats["window_starts"] == {"halo": 3}
+    assert [start for start, _tag in seen] == ["per-call:halo"] * 3
+    assert cr.fused_stats["fused_iters"] == 0
+    # nine steps of a[i] = a[i + 1] + 1
+    want = np.arange(n + 1, dtype=np.float32)
+    want[:n - 9 + 1] = (want[9:] + 9.0)[:n - 9 + 1]
+    np.testing.assert_array_equal(np.asarray(a)[:n - 9], want[:n - 9])
+    cr.dispose()
+
+
+def test_a_ladder_start_is_said_on_the_windows_first_span(devs, monkeypatch):
+    """The first ``enqueue`` span of a window that started on the ladder
+    carries ``start=ladder`` and keeps the tag's ending ``fused-defer``
+    (what tells a deferred compute from a per-call one); the metrics
+    registry carries the same counts as fused_stats."""
+    from cekirdekler_tpu.metrics.registry import REGISTRY
+    from cekirdekler_tpu.trace.spans import TRACER
+
+    def count(how):
+        return REGISTRY.counter("ck_fused_window_start_total", how=how).value
+
+    before = {h: count(h) for h in ("ladder", "first-sighting")}
+    cr, x, seen = _start_case(devs, monkeypatch)
+    try:
+        for _ in range(3):
+            for _ in range(5):
+                x.compute(cr, 38, "inc", 1024, 64)
+            cr.barrier()
+    finally:
+        TRACER.disable()
+    assert seen == [("per-call:first-sighting", "inc"),
+                    ("ladder", "inc fused-defer"),
+                    ("ladder", "inc fused-defer")]
+    assert {h: count(h) - n for h, n in before.items()} == {
+        "ladder": 2, "first-sighting": 1}
+    cr.enqueue_mode = False
+    np.testing.assert_array_equal(np.asarray(x), 15.0)
+    cr.dispose()
+
+
+def test_the_ladders_scalars_are_put_on_the_lane_once(devs, monkeypatch):
+    """A ladder dispatch hands its run-time scalars (offset, units,
+    iterations) over as int32 arrays kept on the lane: a window's seven
+    dispatches put each distinct value on the device once, and the
+    windows after it none."""
+    import jax
+
+    cr = NumberCruncher(devs.subset(1), INC)
+    x = ClArray(np.zeros(1024, np.float32), name="x", partial_read=True)
+    puts: list = []
+    device_put = jax.device_put
+
+    def spy(value, *a, **kw):
+        if isinstance(value, np.int32):
+            puts.append(int(value))
+        return device_put(value, *a, **kw)
+
+    monkeypatch.setattr(jax, "device_put", spy)
+    cr.enqueue_mode = True
+    for _ in range(3):
+        for _ in range(50):
+            x.compute(cr, 40, "inc", 1024, 64)
+        cr.barrier()
+    lane = cr.cores.workers[0]
+    # offset 0, 16 units, the iteration counts of x1 x2 x4 x8 x16 and the
+    # residues x1 (the first window's) and x3: every value once
+    assert sorted(puts) == [0, 1, 2, 3, 4, 8, 16]
+    a, b, c = lane.ladder_scalars(0, 16, 3)
+    assert lane.ladder_scalars(0, 16, 3) == (a, b, c)  # the same arrays
+    assert all(s.dtype == np.int32 and s.devices() == {lane.device}
+               for s in (a, b, c)) and int(c) == 3
+    cr.enqueue_mode = False
+    np.testing.assert_array_equal(np.asarray(x), 150.0)
+    cr.dispose()
+    assert not lane._ladder_scalars
+
+
+def test_compute_fused_batch_is_one_dispatch_a_batch(devs):
+    """The serving tier's coalesced batch stays ONE ladder dispatch a lane
+    whatever the ramp: it counts its iterations in at once and flushes
+    itself; a batch that repeats the last window's starts on the ladder
+    whole, with no per-call iteration in front."""
+    cr = NumberCruncher(devs.subset(1), INC)
+    x = ClArray(np.zeros(512, np.float32), name="x", partial_read=True)
+    cr.enqueue_mode = True
+    first = cr.cores.compute_fused_batch(["inc"], [x], 39, 512, 64, 9)
+    assert first["per_call_iters"] == 2 and first["ladder_iters"] == 7
+    assert cr.fused_stats["windows"] == 1
+    cr.barrier()
+    for k in (8, 3):
+        w0 = cr.fused_stats["windows"]
+        out = cr.cores.compute_fused_batch(["inc"], [x], 39, 512, 64, k)
+        assert out["per_call_iters"] == 0 and out["ladder_iters"] == k
+        assert cr.fused_stats["windows"] == w0 + 1
+        cr.barrier()
+    assert cr.fused_stats["window_starts"] == {"first-sighting": 1,
+                                               "ladder": 2}
+    cr.enqueue_mode = False
+    np.testing.assert_array_equal(np.asarray(x), 20.0)
     cr.dispose()
 
 

@@ -434,6 +434,68 @@ def test_kernels_without_views_build_the_parents_programs(config, name, platform
     assert lowering_meta((info,))["views"] == "kept:0;built:0"
 
 
+# what 2a448f8 (PR 34's parent) built for the benchmark's other kernels:
+# HPCG's SpMV, PolyBench's MVT and the wave membrane, whose cells start
+# every window per call (values that change, a window that never fused, a
+# read across lanes) and must run the programs they ran.  The launchers'
+# in-launch form (a trace is handed no kept view), as above
+PARENTS_34 = {
+    "spmv cpu launcher": "960cafd0368bc7d5",
+    "spmv cpu fused": "c1a1ce6a9293cc5c",
+    "spmv tpu launcher": "5de83af6e83ccfd1",
+    "spmv tpu fused": "d3b6c07ede26d3ce",
+    "mvt_kernel1 cpu launcher": "b148f9daac8fef1f",
+    "mvt_kernel2 cpu launcher": "b023b69ea1ea3b06",
+    "mvt cpu fused": "e0956c045c3805cb",
+    "mvt_kernel1 tpu launcher": "b148f9daac8fef1f",
+    "mvt_kernel2 tpu launcher": "b023b69ea1ea3b06",
+    "mvt tpu fused": "e0956c045c3805cb",
+    "waveStep cpu launcher": "86161f39a88f9b43",
+    "rotate cpu launcher": "ea05b7936af9a625",
+    "wave cpu fused": "02a452d9e0de0415",
+    "waveStep tpu launcher": "86161f39a88f9b43",
+    "rotate tpu launcher": "b523fddfacf6d929",
+    "wave tpu fused": "d8e6f02d15a93a3a",
+}
+OTHER_CELLS = {
+    # configuration, kernels, array sizes for n items, values, n
+    "spmv": ("hpcg_spmv", ("spmv",),
+             lambda n: (n + 1, 8 * n, 8 * n, n, n), (1.5,), 2048),
+    "mvt": ("polybench_mvt", ("mvt_kernel1", "mvt_kernel2"),
+            lambda n: (n * n, n, n, n, n), (256,), 256),
+    "wave": ("wave_membrane", ("waveStep", "rotate"),
+             lambda n: (n, n, n), (64, 32, 0.22), 2048),
+}
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+@pytest.mark.parametrize("case", sorted(OTHER_CELLS))
+def test_the_other_cells_kernels_build_the_parents_programs(case, platform):
+    """SpMV, MVT and the wave step: every kernel's launcher and the
+    sequence's fused ladder trace to the jaxprs of PR 34's parent, byte for
+    byte, on both lowerings."""
+    def sha(jaxpr) -> str:
+        return hashlib.sha1(re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr)).encode()
+                            ).hexdigest()[:16]
+
+    config, names, sizes, vals, n = OTHER_CELLS[case]
+    prog = KernelProgram(_src(config))
+    got = {}
+    for name in names:
+        pointers = [p for p in prog._c_kernels[name].params if p.is_pointer]
+        arrays = tuple(
+            jnp.zeros(size, jnp.int32 if p.ctype == "int" else jnp.float32)
+            for size, p in zip(sizes(n), pointers))
+        fn, _info = prog.launcher(name, n // 2, LOCAL, n, platform=platform)
+        got[f"{name} {platform} launcher"] = sha(
+            jax.make_jaxpr(lambda o, a: fn(o, a, vals))(0, arrays))
+    fused = prog.fused_launcher(names, LOCAL, n, LOCAL, n, vals,
+                                platform=platform)
+    got[f"{case} {platform} fused"] = sha(
+        jax.make_jaxpr(lambda o, u, i, b: fused(o, u, i, b))(0, 5, 3, arrays))
+    assert got == {k: PARENTS_34[k] for k in got}
+
+
 def test_lanes_that_meet_an_array_together_build_its_view_once():
     """More threads than cores call four rung launchers over the same tables
     and over tables of their own: every (array, kind) is built exactly once

@@ -281,6 +281,10 @@ def test_cell_sequence_first_compute_of_a_window_is_one_dispatch(devs, lanes):
         assert w["built"] == (0, 0), w      # nothing compiles
     # the fused WINDOW counters count window dispatches only, as before:
     # of a window's 6 computes one goes per call and re-engages, 5 defer
-    assert stats["windows"] == 4 and stats["fused_iters"] == 4 * 5 - 1, stats
+    # (4 in the process's first window) and go out as the ramp's x1 x2 and
+    # the residue at the barrier: three dispatches a window
+    assert stats["windows"] == 4 * 3 and stats["fused_iters"] == 4 * 5 - 1, stats
+    # more than one lane: every barrier arms a rebalance, no ladder start
+    assert stats["window_starts"] == {"first-sighting": 1, "range-change": 3}
     assert stats["deferred_iters"] == stats["fused_iters"]
     assert rode.tobytes() == per_iteration.tobytes()

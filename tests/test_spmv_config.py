@@ -241,9 +241,13 @@ def test_span_carries_the_lowering(profiled, kind):
     spans = [e for e in profiled if e.name == "ck/" + kind]
     assert spans, f"no ck/{kind} span in the session"
     if kind == "fused":
-        # the first window's span closes before its executable is traced
-        assert len(spans) == 2 and "lowering" not in spans[0].stats
-        spans = spans[1:]
+        # the ramp: x1 and the residue of the first window's two deferred
+        # computes; the second window starts on the ladder: x1 x2, residue
+        assert [str(e.stats["tag"]) for e in spans] == [
+            "x1", "x1", "x1", "x2", "x1"]
+        # the first window's spans close before its executable is traced
+        assert "lowering" not in spans[0].stats
+        spans = spans[2:]
     assert all(e.stats.get("lowering") == "xla" for e in spans)
     assert all(e.stats.get("loops") == "counted:0;masked:1" for e in spans)
     if kind == "launch":
