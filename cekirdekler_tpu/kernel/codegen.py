@@ -299,6 +299,11 @@ class _Ctx:
         reduces a float tile: Mosaic reduces no bools)."""
         return jnp.any(mask)
 
+    def counted_loop(self, node, lane_vars: list, carried_bufs: list) -> None:
+        """Hook for the Pallas subclass, called as a counted loop is entered
+        (:func:`_exec_counted`): its probe counts the tiles the loop keeps
+        alive across its passes.  Nothing for the XLA lowering."""
+
     def force_computed(self, vec):
         """Hook for the Pallas subclass: rewrite a (possibly constant)
         vector so Mosaic assigns it a non-replicated layout, making it a
@@ -1828,6 +1833,7 @@ def _exec_counted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
         enter = some if enter is None else jnp.logical_and(enter, some)
     lane_vars = [k for k in carried_vars if k not in ctx.uniform_vars]
     var_ctypes = {k: ctx.env[k].ctype for k in carried_vars}
+    ctx.counted_loop(node, lane_vars, carried_bufs)
 
     def carried(k):
         """A carried local in the form the carry holds it: 0-d if proved
@@ -2584,6 +2590,13 @@ class KernelBuildInfo:
     # what the newest launch kept current beyond the lane's own range, by
     # array (``u1:16384``: core/cores.py's exchange); "" where nothing
     reach: str = ""
+    # a Pallas build's tile: the rows of ``(tile_rows, 128)`` work items a
+    # grid step runs, the grid steps a launch makes, and the most tiles a
+    # counted loop of the kernel keeps alive across its passes, which is what
+    # the rows were fitted to (pallas_backend._fit_rows); 0 on other builds
+    tile_rows: int = 0
+    tile_grid: int = 0
+    loop_live: int = 0
 
 
 def hlo_name(*kernel_names: str) -> str:

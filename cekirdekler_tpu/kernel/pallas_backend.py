@@ -66,7 +66,14 @@ __all__ = ["PallasUnsupported", "build_kernel_fn_pallas", "LANES",
            "SMEM_UNIFORM_LIMIT"]
 
 LANES = 128          # TPU lane width
-DEFAULT_ROWS = 256   # tile rows per grid step (matches ops/mandelbrot.py)
+DEFAULT_ROWS = 256   # most tile rows per grid step (matches ops/mandelbrot.py)
+# Of the 64 vector registers (one holds 8 rows x 128 lanes of a tile), those
+# the tiles that a counted loop keeps alive across its passes may take; the
+# rest is the pass's temporaries.  48 is what nBody's loop runs at in a
+# 64-row tile (six tiles x 8 registers: 55 cycles a pass, 6.9 a register);
+# at 256 rows the six are 192 registers and every pass loads and stores them
+# (301 cycles, 9.4 a register).  The sweep that set it: PERF.md, PR 36.
+LOOP_LIVE_VREGS = 48
 MAX_HALO_ROWS = 32   # largest halo: |shift| <= 32*128 = 4096 elements
 # uniform-read buffers larger than this many BYTES fall back to the XLA
 # lowering (512 KB verified to fit this chip's SMEM; headroom kept for
@@ -86,6 +93,8 @@ class _Accesses:
     shifts: dict[str, set[int]] = field(default_factory=dict)  # nonzero
     uniform: set[str] = field(default_factory=set)   # lane-uniform loads
     stored: set[str] = field(default_factory=set)
+    # the most tiles a counted loop keeps alive across its passes
+    live: int = 0
 
 
 class _PallasCtx(_Ctx):
@@ -132,6 +141,24 @@ class _PallasCtx(_Ctx):
 
     def any_lane(self, mask):
         return jnp.sum(mask.astype(jnp.float32)) > 0.0
+
+    def counted_loop(self, node, lane_vars: list, carried_bufs: list) -> None:
+        # what a counted loop holds in registers from pass to pass, in tiles:
+        # the locals it carries that differ from lane to lane, the tile-shaped
+        # locals of outside that it reads (but the work item's id plus a
+        # constant: an index, and an iota away), and the buffers whose tile
+        # it stores to or reads at the lane's own index.  The same at any
+        # rows: the probe counts it before the rows are settled (_fit_rows)
+        if self.record is None:
+            return
+        held = set(lane_vars) | {
+            k for k in codegen._vars_read(node) if k in self.env
+            and self.env[k].affine is None
+            and getattr(codegen._num(self.env[k]), "ndim", 0) > 0}
+        tiles = set(carried_bufs) | {
+            ix.base for ix in codegen._index_nodes(node)
+            if not codegen._expr_uniform(ix.index, self.uniform_vars)}
+        self.record.live = max(self.record.live, len(held) + len(tiles))
 
     # -- load/store classification ---------------------------------------
 
@@ -306,6 +333,30 @@ def _routing_veto(acc: _Accesses) -> None:
         )
 
 
+def _fit_rows(rows_total: int, cap: int, live: int = 0) -> int:
+    """Rows of a grid step's tile for a chunk of ``rows_total`` rows: at most
+    ``cap``, halved until they divide the chunk.  ``live`` is the tiles that
+    the hungriest loop of a kernel whose loops are all counted keeps alive
+    across its passes (0 for any other kernel): where they would overflow
+    :data:`LOOP_LIVE_VREGS` every pass spills them, so such a kernel gets the
+    largest power-of-two multiple of 8 rows that divides the chunk and at
+    which they fit (8 where nothing fits), and the launch more grid steps.
+    Each step runs the whole loop for its rows: no lane's operations change
+    order.  A masked loop reduces its mask once a tile a pass and leaves
+    early tile by tile, so it wants its tiles large (ops/mandelbrot.py)
+    and is not fitted."""
+    rows = min(cap, rows_total)
+    while rows_total % rows != 0:
+        rows //= 2
+    if -(-rows // 8) * live > LOOP_LIVE_VREGS and rows_total % 8 == 0:
+        fit = 8
+        while (2 * fit <= rows and rows_total % (2 * fit) == 0
+               and 2 * fit // 8 * live <= LOOP_LIVE_VREGS):
+            fit *= 2
+        rows = fit
+    return rows
+
+
 def _halo_rows(acc: _Accesses, rows: int, rows_total: int) -> int:
     """Halo depth H (rows) covering every shift; 0 when no shifts."""
     if not acc.shifts:
@@ -408,7 +459,7 @@ def build_kernel_fn_pallas(
     chunk: int,
     local_size: int,
     global_size: int,
-    block_rows: int = DEFAULT_ROWS,
+    block_rows: int | None = None,
     interpret: bool = False,
     force: bool = False,
     in_range: bool = True,
@@ -422,7 +473,9 @@ def build_kernel_fn_pallas(
     subset, the chunk doesn't tile, or the measured routing policy prefers
     the XLA lowering for this access mix (``force=True`` skips the policy
     veto — used by tests and ``chip_smoke.py`` to exercise the halo path
-    directly).  ``in_range`` is the XLA fallback's (codegen.build_kernel_fn)."""
+    directly).  ``in_range`` is the XLA fallback's (codegen.build_kernel_fn).
+    A tile's rows follow from the chunk and the kernel (:func:`_fit_rows`);
+    ``block_rows`` caps them by hand and fits nothing (tests, sweeps)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -433,10 +486,7 @@ def build_kernel_fn_pallas(
             "kernel declares 'half' types (Mosaic rejects f16 tiles)"
         )
     rows_total = chunk // LANES
-    rows = min(block_rows, rows_total)
-    while rows_total % rows != 0:
-        rows //= 2
-    rows = max(rows, 1)
+    rows = _fit_rows(rows_total, block_rows or DEFAULT_ROWS)
 
     array_params = [p for p in kernel.params if p.is_pointer]
     value_params = [p for p in kernel.params if not p.is_pointer]
@@ -446,6 +496,9 @@ def build_kernel_fn_pallas(
     stored, acc = _probe(kernel, rows, local_size, global_size, uniform_vars)
     if not force:
         _routing_veto(acc)
+    counted, masked = codegen._loop_counts(kernel, uniform_vars)
+    if block_rows is None and counted and not masked:
+        rows = _fit_rows(rows_total, rows, acc.live)
     halo_h = _halo_rows(acc, rows, rows_total)
 
     # which inputs each array needs (an array can need several).  An
@@ -466,9 +519,9 @@ def build_kernel_fn_pallas(
         stored_params=list(stored),
         lowering="pallas",
     )
-    info.loops_counted, info.loops_masked = codegen._loop_counts(
-        kernel, uniform_vars)
+    info.loops_counted, info.loops_masked = counted, masked
     grid = rows_total // rows
+    info.tile_rows, info.tile_grid, info.loop_live = rows, grid, acc.live
     scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
     tile_spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
     halo_spec = pl.BlockSpec(

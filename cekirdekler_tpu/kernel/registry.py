@@ -29,7 +29,7 @@ import numpy as np
 
 from ..errors import KernelCompileError
 from ..trace.spans import TRACER
-from . import codegen, lang
+from . import codegen, lang, pallas_backend
 
 __all__ = ["KernelProgram", "kernel", "PythonKernel", "lowering_meta"]
 
@@ -347,7 +347,11 @@ def lowering_meta(infos) -> dict:
     per-lane mask; joined the same way; no comma, which would end the
     value in a profiler annotation), where a TPU build was routed
     away from Pallas, ``veto`` with the reason, and where the launch read
-    beyond its lane's own range, ``reach`` (``u1:16384``).  A ladder executable stands
+    beyond its lane's own range, ``reach`` (``u1:16384``), and for a Pallas
+    build ``tile`` (``64x128;grid=4;live=6``: the work items of a grid step's
+    tile, the grid steps a launch makes and the tiles a counted loop of the
+    kernel keeps alive, which the rows were fitted to; joined like
+    ``loops``).  A ladder executable stands
     for its rungs.  ``views`` (``kept:K;built:B``) is of the DISPATCHES the
     span ran, one of ``infos`` each: the kept views they took as arguments
     (:class:`_KeptViews`) and how many of those were built on these calls;
@@ -360,6 +364,12 @@ def lowering_meta(infos) -> dict:
                  for i in leaves})),
             "views": f"kept:{sum(i.views_kept for i in infos)};"
                      f"built:{sum(i.views_built for i in infos)}"}
+    tiles = sorted({(i.tile_rows, i.tile_grid, i.loop_live)
+                    for i in leaves if i.lowering == "pallas"})
+    if tiles:
+        meta["tile"] = "+".join(
+            f"{rows}x{pallas_backend.LANES};grid={grid};live={live}"
+            for rows, grid, live in tiles)
     vetoes = sorted({i.veto for i in leaves if i.veto})
     if vetoes:
         meta["veto"] = "; ".join(vetoes)
@@ -674,8 +684,6 @@ class KernelProgram:
         if name in self._c_kernels:
             raw_fn = info = veto = None
             if platform == "tpu":
-                from . import pallas_backend
-
                 try:
                     raw_fn, info = pallas_backend.build_kernel_fn_pallas(
                         self._c_kernels[name], chunk, local_size, global_size,

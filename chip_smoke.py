@@ -66,6 +66,8 @@ FULL = {
     "flash_T": (4096, 8192), "flash_tiled_T": 640, "flash_dense_T": 96,
     "flash_oneshot_T": 512, "qkv_T": 1024,
     "saxpy_n": 1 << 22, "backend_n": 1 << 20, "backend_nbody_n": 4096,
+    # the n-body launch whose tile is fitted (bodies, passes of its loop)
+    "backend_nbody_tiled": (32768, 32768),
     "affine_n": 4096,
     # HPCG's SpMV: the grid the smoke runs, and the grid and rung whose
     # compiled program it only counts (the cell's: PERF.md s.4)
@@ -445,6 +447,15 @@ def _nbody_windows_start_on_the_ladder(devices, sizes) -> dict:
                   if name == "ck/fused"]
     launches = [str(st.get("tag")) for _t, name, st in events
                 if name == "ck/launch"]
+    # a TPU lane's launches name the Pallas tiles of their ladder's rungs
+    # (PR 36), the whole range's among them; a host lane takes the XLA
+    # lowering and names none
+    tiles = {str(st.get("tile")) for _t, name, st in events
+             if name == "ck/launch"}
+    rows_total = n // 128
+    tile_rows = min(64, rows_total)
+    want_tile = (f"{tile_rows}x128;grid={rows_total // tile_rows};live=6"
+                 if lane[0].jax_device.platform == "tpu" else "None")
     # the barrier's part marks and the lane's retire anchor; a window of
     # deferred computes holds no other mark
     marks = [(name, str(st.get("tag")), st.get("lane"))
@@ -461,6 +472,8 @@ def _nbody_windows_start_on_the_ladder(devices, sizes) -> dict:
              f"third window's fused dispatches {fused_tags}")
     _require(launches and all(t.startswith("fused:") for t in launches),
              f"a per-call launch in a window started on the ladder: {launches}")
+    _require(all(want_tile in t.split("+") for t in tiles),
+             f"ck/launch spans carry tile {tiles}, expected {want_tile}")
     _require(marks == [("ck/fence", "part:wait", None),
                        ("ck/fence", "retired", 0),
                        ("ck/fence", "part:close", None)],
@@ -976,7 +989,7 @@ def stage_kernels(devices, sizes) -> list[dict]:
     interp = plat != "tpu"
 
     def backend(src, name, n, arrays, values, label, want=None, tol=0.0,
-                counted=0):
+                counted=0, tile=None):
         kdef = {k.name: k for k in lang.parse_kernels(src)}[name]
         pl_fn, info = build_kernel_fn_pallas(kdef, n, 256, n,
                                              interpret=interp, force=True)
@@ -985,6 +998,15 @@ def stage_kernels(devices, sizes) -> list[dict]:
         _require(info.loops_counted >= counted,
                  f"{label}: {info.loops_counted} counted loop(s), "
                  f"{info.loops_masked} masked; expected {counted} counted")
+        # (rows, grid steps): a masked loop and a kernel without one take
+        # the tallest tile; a counted loop's is fitted to the tiles it
+        # keeps alive, or every pass spills them (PERF.md, PR 36)
+        tallest = min(256, n // 128)
+        tile = tile or (tallest, n // 128 // tallest)
+        _require((info.tile_rows, info.tile_grid) == tile,
+                 f"{label}: tile of {info.tile_rows} rows in "
+                 f"{info.tile_grid} grid step(s), live {info.loop_live}; "
+                 f"expected {tile}")
         arrays = tuple(jax.device_put(a, dev) for a in arrays)
         f = jax.jit(lambda *arrs: pl_fn(0, arrs, values))
         got, cold_s, run_s = first_and_repeat(f, *arrays)
@@ -1020,6 +1042,12 @@ def stage_kernels(devices, sizes) -> list[dict]:
     backend(NBODY_SRC, "nBody", nb, (*pos, zero, zero, zero),
             (np.int32(nb), np.float32(1e-4)), "SMEM uniform gather",
             want=(*pos, *v1), tol=0.01, counted=1)
+    nb, passes = sizes["backend_nbody_tiled"]
+    pos = (rng.random((3, nb), dtype=np.float32) - 0.5) * 2.0
+    zero = np.zeros(nb, np.float32)
+    backend(NBODY_SRC, "nBody", nb, (*pos, zero, zero, zero),
+            (np.int32(passes), np.float32(1e-4)), "fitted tile (n-body)",
+            tol=0.01, counted=1, tile=(64, 4))
 
     # affine accesses on the vectorized-XLA lowering (Pallas vetoes them):
     # PolyBench/GPU's MVT, a matrix walked by rows and by columns with the

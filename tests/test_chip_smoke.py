@@ -29,6 +29,7 @@ TOY = dict(
     flash_bhd=(1, 2, 16), flash_T=(256,), flash_tiled_T=128,
     flash_dense_T=96, flash_oneshot_T=128, qkv_T=128,
     saxpy_n=1 << 10, backend_n=1 << 12, backend_nbody_n=256,
+    backend_nbody_tiled=(32768, 24),
     affine_n=256, spmv_side=8, spmv_count_side=12, spmv_count_chunk=1 << 10,
     trace_iters=3,
 )
@@ -106,12 +107,13 @@ def test_stage_serving(devs):
 
 def test_stage_kernels(devs):
     rows = chip_smoke.stage_kernels(devs, TOY)
-    _check_rows(rows, 14)
+    _check_rows(rows, 15)
     names = " | ".join(r["name"] for r in rows)
     for want in ("flash fwd+bwd T=256 highest", "flash fwd+bwd T=256 default",
                  "flash fwd T=128", "flash fwd T=96", "fused_qkv_attention",
                  "one-shot softmax", "ops.saxpy", "elementwise", "halo",
-                 "SMEM uniform gather", "__graft_entry__"):
+                 "SMEM uniform gather", "fitted tile (n-body)",
+                 "__graft_entry__"):
         assert want in names, want
     by = {r["name"]: r for r in rows}
     assert by["flash fwd T=96"]["lowering"] == "dense"

@@ -551,3 +551,178 @@ def test_a_return_switches_the_proof_off_and_spans_name_the_loops():
     _fn, info = KernelProgram(MANDEL).launcher("mandel", 256, 64, 256,
                                                platform="cpu")
     assert lowering_meta((info,))["loops"] == "counted:0;masked:1"
+
+
+# -- a counted loop's tile is fitted to the register file (PR 36) -----------
+
+# (a sum of ``w[j]`` alone would be the same in every lane: no tile at all)
+LIVE_1 = """
+__kernel void live1(__global float* w, __global float* o, int n) {
+    float acc = 0.0f;
+    for (int j = 0; j < n; j++) {
+        acc += w[j] * (float)get_global_id(0);
+    }
+    o[get_global_id(0)] = acc;
+}
+"""
+
+LIVE_3 = """
+__kernel void live3(__global float* x, __global float* y, __global float* o, int n) {
+    int i = get_global_id(0);
+    float xi = x[i];
+    float yi = y[i];
+    float acc = 0.0f;
+    for (int j = 0; j < n; j++) {
+        float dx = x[j] - xi;
+        float dy = y[j] - yi;
+        acc += 1.0f / (dx*dx + dy*dy + 0.0001f);
+    }
+    o[i] = acc;
+}
+"""
+
+# four positions read a pass and eight sums carried; ``dt`` lives outside the
+# loop and is not read in it, ``n`` and ``j`` are the same in every lane
+LIVE_12 = """
+__kernel void live12(__global float* x, __global float* y, __global float* z,
+                     __global float* w, __global float* o0, __global float* o1,
+                     __global float* o2, __global float* o3, int n, float dt) {
+    int i = get_global_id(0);
+    float xi = x[i];
+    float yi = y[i];
+    float zi = z[i];
+    float wi = w[i];
+    float scale = dt * xi;
+    float a0 = 0.0f; float a1 = 0.0f; float a2 = 0.0f; float a3 = 0.0f;
+    float a4 = 0.0f; float a5 = 0.0f; float a6 = 0.0f; float a7 = 0.0f;
+    for (int j = 0; j < n; j++) {
+        float d0 = x[j] - xi;
+        float d1 = y[j] - yi;
+        float d2 = z[j] - zi;
+        float d3 = w[j] - wi;
+        float r2 = d0*d0 + d1*d1 + d2*d2 + d3*d3 + 0.0001f;
+        float inv = 1.0f / (r2 * sqrt(r2));
+        a0 += d0 * inv; a1 += d1 * inv; a2 += d2 * inv; a3 += d3 * inv;
+        a4 += inv; a5 += r2; a6 += d0 * d1; a7 += d2 * d3;
+    }
+    o0[i] = (a0 + a4) * scale; o1[i] = a1 + a5; o2[i] = a2 + a6; o3[i] = a3 + a7;
+}
+"""
+
+
+# the loop stores to ``o``'s tile and reads ``x``'s at the lane's own index;
+# ``i`` is an index and no tile; ``s`` is carried
+LIVE_BUFS = """
+__kernel void livebufs(__global float* w, __global float* x, __global float* o, int n) {
+    int i = get_global_id(0);
+    float s = 0.0f;
+    for (int j = 0; j < n; j++) {
+        s += w[j] * x[i];
+        o[i] = o[i] + s;
+    }
+}
+"""
+
+
+def _nbody_src():
+    from cekirdekler_tpu.workloads import NBODY_SRC
+
+    return NBODY_SRC
+
+
+# kernel -> (source, name, tiles its hungriest counted loop keeps alive, or
+# None where the rows are not fitted: a masked loop, no loop)
+TILE_KERNELS = {
+    "live1": (lambda: LIVE_1, "live1", 1),
+    "live3": (lambda: LIVE_3, "live3", 3),
+    "live3_bufs": (lambda: LIVE_BUFS, "livebufs", 3),
+    "nbody": (_nbody_src, "nBody", 6),
+    "live12": (lambda: LIVE_12, "live12", 12),
+    "masked_while": (lambda: MANDEL, "mandel", None),
+    "no_loop": (lambda: SAXPY, "saxpy", None),
+}
+# rows by (live, chunk): the largest power-of-two multiple of 8 that divides
+# chunk / 128, is at most 256, and holds ``live`` tiles in 48 registers
+TILE_ROWS = {
+    (1, 8192): 64, (1, 32768): 256, (1, 98304): 256,
+    (3, 8192): 64, (3, 32768): 128, (3, 98304): 128,
+    (6, 8192): 64, (6, 32768): 64, (6, 98304): 64,
+    (12, 8192): 32, (12, 32768): 32, (12, 98304): 32,
+}
+
+
+@pytest.mark.parametrize("chunk", [8192, 32768, 98304])
+@pytest.mark.parametrize("kernel", sorted(TILE_KERNELS))
+def test_a_counted_loops_tile_is_fitted_to_the_register_file(kernel, chunk):
+    """A kernel whose loops are all counted gets the rows at which the tiles
+    its loop keeps alive fit the budget; a masked loop and a kernel without
+    one keep ``min(256, chunk / 128)``.  ``lowering_meta`` names the tile."""
+    from cekirdekler_tpu.kernel import pallas_backend
+    from cekirdekler_tpu.kernel.registry import lowering_meta
+
+    src, name, live = TILE_KERNELS[kernel]
+    kdef = {k.name: k for k in lang.parse_kernels(src())}[name]
+    _fn, info = build_kernel_fn_pallas(kdef, chunk, 256, chunk, interpret=True)
+    rows_total = chunk // 128
+    if live is None:
+        assert info.tile_rows == min(256, rows_total)
+        assert (info.loops_counted == 0) or info.loops_masked
+    else:
+        assert (info.loops_counted, info.loops_masked) == (1, 0)
+        assert info.loop_live == live
+        assert info.tile_rows == TILE_ROWS[live, chunk]
+        assert info.tile_rows // 8 * live <= pallas_backend.LOOP_LIVE_VREGS
+        # the next power of two up would overflow, leave the chunk
+        # undivided or pass 256
+        up = 2 * info.tile_rows
+        assert (up // 8 * live > pallas_backend.LOOP_LIVE_VREGS
+                or rows_total % up or up > 256)
+    assert info.tile_rows % 8 == 0 and info.tile_rows <= 256
+    assert rows_total % info.tile_rows == 0
+    assert info.tile_grid == rows_total // info.tile_rows
+    assert lowering_meta((info,))["tile"] == (
+        f"{info.tile_rows}x128;grid={info.tile_grid};live={info.loop_live}")
+    # rows given by hand are a cap and nothing is fitted
+    _fn, by_hand = build_kernel_fn_pallas(kdef, chunk, 256, chunk,
+                                          block_rows=256, interpret=True)
+    assert by_hand.tile_rows == min(256, rows_total)
+
+
+@pytest.mark.parametrize("rows_total,live,want", [
+    (96, 6, 32),     # 96 rows are 72 registers of six tiles: 32 divides
+    (24, 6, 24),     # fits as it stands: not made a power of two
+    (12, 12, 12),    # not a multiple of 8: nothing to fit to
+    (256, 100, 8),   # nothing fits: the smallest tile
+    (300, 1, 4),     # the cap halved until it divides, as ever
+])
+def test_fitted_rows_off_the_powers_of_two(rows_total, live, want):
+    from cekirdekler_tpu.kernel.pallas_backend import DEFAULT_ROWS, _fit_rows
+
+    assert _fit_rows(rows_total, DEFAULT_ROWS, live) == want
+
+
+def test_nbody_at_fitted_rows_equals_one_tall_tile_to_the_last_bit():
+    """Two grid steps of 64 rows against one step of 128: each step
+    runs the whole loop for its rows, so no lane's operations change order.
+    The loop's passes are the ``n`` ARGUMENT's, kept short here."""
+    import jax
+    import jax.numpy as jnp
+    from cekirdekler_tpu.kernel.registry import lowering_meta
+
+    n = 16384
+    kdef = {k.name: k for k in lang.parse_kernels(_nbody_src())}["nBody"]
+    rng = np.random.default_rng(36)
+    arrays = tuple(jnp.asarray(rng.standard_normal(n).astype(np.float32))
+                   for _ in range(6))
+    values = (np.int32(77), np.float32(1e-3))
+    outs = []
+    for block_rows, tile in ((None, "64x128;grid=2;live=6"),
+                             (256, "128x128;grid=1;live=6")):
+        fn, info = build_kernel_fn_pallas(kdef, n, 256, n, interpret=True,
+                                          block_rows=block_rows)
+        outs.append(jax.jit(fn)(0, arrays, values))
+        assert lowering_meta((info,))["tile"] == tile
+        assert info.lowering == "pallas"
+    for a, b in zip(*outs):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert float(jnp.abs(outs[0][3]).max()) > 0.0
