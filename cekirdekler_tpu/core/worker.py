@@ -344,6 +344,10 @@ class Worker:
             "ck_upload_bytes_total", "H2D bytes uploaded", lane=index)
         self._m_download_bytes = REGISTRY.counter(
             "ck_download_bytes_total", "D2H bytes materialized", lane=index)
+        self._m_download_seconds = REGISTRY.histogram(
+            "ck_download_seconds",
+            "D2H issue (copy_to_host_async) to landed in host memory",
+            lane=index)
         self._m_fence_waits = REGISTRY.counter(
             "ck_fence_waits_total", "whole-lane retirement fences",
             lane=index)
@@ -988,8 +992,15 @@ class Worker:
             out.copy_to_host_async()
         except Exception:
             pass
+        t_issued = time.perf_counter()
+        # the read-back's first part mark (trace/spans.py): the copy is on
+        # its way; ``finish_download`` marks ``part:landed``
+        if TRACER.active():
+            TRACER.instant(kind, lane=self.index, tag="part:issued",
+                           bytes=out.nbytes, name=arr.name, off=off)
         return (arr, out, off, self.markers, self.index,
-                self._m_download_bytes, kind)
+                self._m_download_bytes, kind, self._m_download_seconds,
+                t_issued)
 
     def download_chunk_async(self, arr: ClArray, offset_elems: int, size_elems: int):
         """One ladder-aligned chunk of a STREAMED partition download:
@@ -1002,7 +1013,8 @@ class Worker:
 
     @staticmethod
     def finish_download(handle) -> None:
-        arr, out, off, markers, lane, byte_counter, kind = handle
+        (arr, out, off, markers, lane, byte_counter, kind, seconds,
+         t_issued) = handle
         _tt = TRACER.t0(kind)
         # capture the fault-plane state ONCE: a plane armed mid-call
         # would otherwise pair delay_s with the 0.0 sentinel t0 and
@@ -1011,6 +1023,12 @@ class Worker:
         _ft0 = time.perf_counter() if _faults else 0.0
         host = arr.host()
         data = np.asarray(out)
+        # landed: the bytes are in host memory (jax's own); from here to
+        # the span's end is the copy into the caller's array
+        seconds.observe(time.perf_counter() - t_issued)
+        if _tt:
+            TRACER.instant(kind, lane=lane, tag="part:landed",
+                           bytes=data.nbytes, name=arr.name, off=off)
         view = host[off : off + data.size]
         lib = _native_lib()
         if (

@@ -486,6 +486,83 @@ def _nbody_windows_start_on_the_ladder(devices, sizes) -> dict:
                 sha1=digests[True][0][:16])
 
 
+def _mandelbrot_frame_read_back(devices, sizes, want) -> dict:
+    """The demo as its users run it (PR 38): ONE lane, a synchronous
+    ``compute()`` a frame, the frame in the caller's array at every return.
+    The third frame runs inside a profiler session: every download of it
+    shows ``part:issued`` (the copy to the host is on its way) before
+    ``part:landed`` (inside the download's span: the bytes are in host
+    memory) before the span's end (the frame is in the caller's array), and
+    the downloads' bytes add up to the frame's."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+    from cekirdekler_tpu.workloads import MANDELBROT_SRC
+
+    wh = sizes["mandel_wh"]
+    n, lr = wh * wh, sizes["local_range"]
+    vals = (-2.0, -1.25, 2.5 / wh, 2.5 / wh, wh, sizes["mandel_max_iter"])
+    cr = NumberCruncher(devices.subset(1), MANDELBROT_SRC)
+    out = ClArray(n, np.float32, name="mandel_shown", read=False, write=True)
+    root = tempfile.mkdtemp(prefix="ck_smoke_readback_")
+    events = []
+    try:
+        call = lambda: out.compute(cr, 7107, "mandelbrot", n, lr, values=vals)
+        _, cold_s = _timed(call)
+        call()  # the transfer tuner's first choice after its measuring run
+        out.host()[:] = -1.0
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(root, profiler_options=opts)
+        try:
+            _, run_s = _timed(call)
+        finally:
+            jax.profiler.stop_trace()
+        err = float(np.abs(out.host() - want).max())
+        chunks = cr.cores.last_stream_chunks.get(0)
+        path = [os.path.join(r, f) for r, _d, fs in os.walk(root)
+                for f in fs if f.endswith(".xplane.pb")][0]
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name == "/host:CPU":
+                events += [(ev.start_ns, ev.start_ns + ev.duration_ns, li,
+                            dict(ev.stats))
+                           for li, line in enumerate(plane.lines)
+                           for ev in line.events
+                           if ev.name in ("ck/download", "ck/download-chunk")]
+    finally:
+        cr.dispose()
+        shutil.rmtree(root, ignore_errors=True)
+    tag = lambda e: str(e[3].get("tag"))
+    spans = sorted(e for e in events if not tag(e).startswith("part:"))
+    issued = {e[3].get("off"): e for e in events if tag(e) == "part:issued"}
+    landed = {e[3].get("off"): e for e in events if tag(e) == "part:landed"}
+    _require(spans and len(spans) == len(issued) == len(landed),
+             f"read-back marks: {len(spans)} downloads, {len(issued)} issued, "
+             f"{len(landed)} landed")
+    copy_ns = 0
+    for off, mark in landed.items():
+        inside = [s for s in spans if s[2] == mark[2]
+                  and s[0] <= mark[0] and mark[1] <= s[1]]
+        _require(len(inside) == 1 and off in issued
+                 and issued[off][1] <= inside[0][0]
+                 and issued[off][0] < mark[0] < inside[0][1],
+                 f"read-back at offset {off}: issued {issued.get(off)}, "
+                 f"landed {mark}, span {inside}")
+        copy_ns += inside[0][1] - mark[0]
+    nbytes = sum(int(e[3].get("bytes", 0)) for e in landed.values())
+    _require(nbytes == 4 * n == sum(int(s[3].get("bytes", 0)) for s in spans),
+             f"the frame's downloads carry {nbytes} bytes, the frame is "
+             f"{4 * n}")
+    _require(err == 0.0, f"the frame read back differs from the host by {err}")
+    first = min(e[0] for e in issued.values())
+    return _row("mandelbrot frame read back", "", cold_s, run_s, err,
+                downloads=len(spans), bytes=nbytes, stream_chunks=chunks,
+                readback_ms=round((spans[-1][1] - first) / 1e6, 3),
+                copy_ms=round(copy_ns / 1e6, 3))
+
+
 def stage_compute(devices, sizes) -> list[dict]:
     from cekirdekler_tpu import ClArray
     from cekirdekler_tpu.core.cruncher import NumberCruncher
@@ -561,6 +638,7 @@ def stage_compute(devices, sizes) -> list[dict]:
         cr.dispose()
     rows.append(_wave_window_across_lanes(lanes, sizes))
     rows.append(_nbody_windows_start_on_the_ladder(devices, sizes))
+    rows.append(_mandelbrot_frame_read_back(devices, sizes, want))
     return rows
 
 

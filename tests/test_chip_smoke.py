@@ -49,8 +49,8 @@ def _check_rows(rows, n_min=1):
 
 def test_stage_compute(devs):
     rows = chip_smoke.stage_compute(devs, TOY)
-    _check_rows(rows, 6)
-    kl, hand, forced, nbody, wave, starts = rows
+    _check_rows(rows, 7)
+    kl, hand, forced, nbody, wave, starts, shown = rows
     # CPU lanes take the XLA lowering by policy; the routing assertion
     # itself only binds on TPU lanes
     assert kl["lowering"] == "xla"
@@ -75,11 +75,15 @@ def test_stage_compute(devs):
     # three windows on one lane: the second and third start on the ladder
     assert starts["window_starts"] == {"first-sighting": 1, "ladder": 2}
     assert starts["ramp"] == ["x1", "x2"]  # a window of three computes
+    # one frame a call on one lane: issued, landed, in the caller's array
+    assert shown["name"] == "mandelbrot frame read back"
+    assert shown["bytes"] == 4 * 64 * 64 and shown["max_err"] == 0.0
+    assert shown["downloads"] == (shown["stream_chunks"] or 1)
 
 
 def test_stage_compute_partitions_a_single_device():
     rows = chip_smoke.stage_compute(platforms().cpus().subset(1), TOY)
-    assert rows[-2]["lanes"] == 2 and all(r > 0 for r in rows[-2]["ranges"])
+    assert rows[-3]["lanes"] == 2 and all(r > 0 for r in rows[-3]["ranges"])
 
 
 def test_stage_transfers(devs):

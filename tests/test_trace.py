@@ -598,6 +598,95 @@ def test_range_move_marks_the_parts_of_its_resync(profiled):
     assert down and all(join.t0 <= e.t0 and e.t1 <= reset.t0 for e in down)
 
 
+def test_range_moves_downloads_mark_issued_and_landed(profiled):
+    """Every download of the resync: ``part:issued`` (the copy to the host
+    is on its way) before the span opens, ``part:landed`` inside it, both on
+    the span's own kind with its lane, its bytes and the array's name."""
+    for kind in ("download", "download-chunk"):
+        spans, _ = _inside(profiled, "test/moved", kind)
+        marks, _ = _inside(profiled, "test/moved", kind, marks=True)
+        tags = [str(e.stats["tag"]) for e in marks]
+        assert tags.count("part:issued") == tags.count("part:landed") == len(
+            spans), (kind, tags)
+        for span in spans:
+            (landed,) = [e for e in marks if e.stats["tag"] == "part:landed"
+                         and e.line == span.line
+                         and span.t0 <= e.t0 and e.t1 <= span.t1]
+            (issued,) = [e for e in marks if e.stats["tag"] == "part:issued"
+                         and (e.stats["lane"], e.stats["off"]) == (
+                             landed.stats["lane"], landed.stats["off"])]
+            assert issued.t1 <= span.t0 and issued.t0 < landed.t0
+            assert (issued.stats["bytes"] == landed.stats["bytes"]
+                    == span.stats["bytes"])
+            assert issued.stats["name"] == landed.stats["name"] == "bridge_x"
+            assert landed.stats["lane"] == span.stats["lane"]
+            assert "queued_us" not in landed.stats
+    # the window's downloads are one kind or the other, and there are some
+    assert _inside(profiled, "test/moved", "download")[0] or _inside(
+        profiled, "test/moved", "download-chunk")[0]
+
+
+@pytest.mark.parametrize("chunks", [1, 4])
+def test_a_synchronous_computes_read_back_is_marked_and_timed(chunks):
+    """A non-windowed ``compute()`` on one lane, monolithic and in four
+    streamed chunks: with the ring on, one ``part:issued`` and one
+    ``part:landed`` a download, instants of the download's kind, the landed
+    one inside its span; ``ck_download_seconds`` counts every download
+    (issue to landed), tracer on or off; with the tracer off nothing is
+    recorded."""
+    from cekirdekler_tpu.metrics.registry import REGISTRY
+
+    def count() -> int:
+        return sum(v["count"] for k, v in REGISTRY.snapshot()[
+            "histograms"].items() if k.startswith("ck_download_seconds"))
+
+    n = 4096
+    cr = NumberCruncher(_cpus(1), INC)
+    x = ClArray(np.zeros(n, np.float32), name="marked_x", partial_read=True)
+    kind = "download" if chunks == 1 else "download-chunk"
+    try:
+        cr.stream_chunks = chunks
+        before = count()
+        x.compute(cr, 79, "inc", n, 64)  # tracer off
+        assert count() - before == chunks
+        assert TRACER.total_recorded == 0 and not TRACER.active()
+        with tracing() as tr:
+            x.compute(cr, 79, "inc", n, 64)
+        assert count() - before == 2 * chunks
+    finally:
+        cr.dispose()
+    np.testing.assert_array_equal(np.asarray(x), 2.0)
+    spans = [s for s in tr.snapshot() if s.kind == kind]
+    marks = [s for s in spans if _is_mark(s.tag)]
+    downloads = [s for s in spans if s not in marks]
+    assert len(downloads) == chunks and all(s.t0 == s.t1 for s in marks)
+    issued = [s for s in marks if s.tag == "part:issued"]
+    landed = [s for s in marks if s.tag == "part:landed"]
+    assert len(issued) == len(landed) == chunks
+    assert all(s.lane == 0 for s in marks)
+    for span, mark in zip(downloads, landed):
+        assert span.t0 <= mark.t0 <= span.t1
+    assert max(s.t0 for s in issued) <= min(s.t0 for s in landed)
+    # no other kind got a mark of the read-back's
+    assert not [s for s in tr.snapshot() if s.kind != kind
+                and s.tag in ("part:issued", "part:landed")]
+
+
+def test_the_read_backs_marks_off_cost_one_active_check():
+    """``download_async`` asks ``active()`` before it builds a mark's
+    metadata: the off path, under the budget of a span's."""
+    tr = Tracer()
+    n = 50_000
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            if tr.active():
+                raise AssertionError("off")
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 1e-6, f"active() off cost {best*1e9:.0f} ns >= 1 µs"
+
+
 def test_per_call_compute_marks_its_parts_and_a_deferred_one_nothing(
         profiled):
     """The window's per-call compute: ``submit``, ``join``, ``note`` (no
