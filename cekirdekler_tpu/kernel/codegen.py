@@ -2587,6 +2587,11 @@ class KernelBuildInfo:
     views: tuple = ()
     views_kept: int = 0
     views_built: int = 0
+    # what the launcher's newest dispatch handed over as run-time scalars
+    # (kernel/registry.py): ``(words, loose)``, the 32-bit words that crossed
+    # in one vector and the Python or numpy scalars that crossed one by one;
+    # None for a launcher that has dispatched nothing (a ladder's rung)
+    scalars: tuple | None = None
     # what the newest launch kept current beyond the lane's own range, by
     # array (``u1:16384``: core/cores.py's exchange); "" where nothing
     reach: str = ""

@@ -54,11 +54,16 @@ class _Launcher:
         self._fn, self._tag, self._warm, self.info = fn, tag, False, info
 
     def __call__(self, *args):
+        return self._run(self._fn, *args)
+
+    def _run(self, fn, *args):
+        """``fn(*args)``, ``fn`` the jitted function or, where a launcher has
+        one, its other entry: the launcher is warm once either has run."""
         if self._warm:
-            return self._fn(*args)
+            return fn(*args)
         _tt = TRACER.t0("compile")
         try:
-            out = self._fn(*args)
+            out = fn(*args)
         finally:
             TRACER.record("compile", _tt, tag=self._tag,
                           **(lowering_meta((self.info,)) if _tt else {}))
@@ -167,6 +172,61 @@ def _concrete(arrays) -> bool:
                for a in arrays)
 
 
+# -- a dispatch's run-time scalars, packed ---------------------------------
+# A Python number handed to a jitted call is a host-to-device transfer of its
+# own.  A per-call dispatch of a C kernel hands its offset and value arguments
+# over as ONE vector of 32-bit words (``_KernelLauncher._pack``): each value
+# converted on the host to what the launch makes of it, bit-cast back in the
+# executable's entry.
+
+#: a slot of a packed call's layout that holds no words: a value that rides
+#: as a run-time argument of its own, and a launcher key (static)
+LOOSE, KEYED = "", "key"
+
+
+def _plain(v) -> bool:
+    """A plain Python or numpy scalar: what a dispatch can pack."""
+    return isinstance(v, (bool, int, float, np.bool_, np.integer, np.floating))
+
+
+def _host_words(v, dtype: np.dtype) -> bytes | None:
+    """The plain scalar ``v`` as the launch sees it, as the bytes of its
+    32-bit words: first what ``jax.jit`` makes of the argument (a Python
+    number takes the default dtype of its kind, a numpy scalar keeps its own;
+    both canonical: 32 bits wide without x64, and a Python int beyond them is
+    the OverflowError it was), then ``jnp.asarray(v, dtype)``, the
+    parameter's.  numpy rounds, wraps and truncates as the device's convert
+    does; where C leaves the cast undefined (a float that is no integer of
+    the parameter's range) the two may differ: None, the value rides as it
+    did.  A 64-bit value is two words, low first; a narrower one is widened
+    by its bits."""
+    with np.errstate(over="ignore"):  # a float beyond the narrower one's: inf
+        seen = np.array(
+            v, dtype=jax.dtypes.canonicalize_dtype(np.result_type(v)))
+        if seen.dtype.kind == "f" and dtype.kind in "iu":
+            lim = np.iinfo(dtype)
+            # (as Python's exact integers: numpy compares in the float's width)
+            if not (np.isfinite(seen) and lim.min <= int(seen) <= lim.max):
+                return None
+        a = seen.astype(dtype)
+    if dtype.itemsize < 4:
+        a = (a if dtype.kind == "b" else a.view(f"u{dtype.itemsize}")).astype(
+            np.uint32)
+    return a.tobytes()
+
+
+def _from_words(words, dtype: np.dtype):
+    """The value :func:`_host_words` packed, of ``dtype`` again."""
+    if dtype.kind == "b":
+        return words[0] != 0
+    if dtype.itemsize == 8:
+        return jax.lax.bitcast_convert_type(words, dtype)
+    if dtype.itemsize == 4:
+        return jax.lax.bitcast_convert_type(words[0], dtype)
+    return jax.lax.bitcast_convert_type(
+        words[0].astype(f"uint{8 * dtype.itemsize}"), dtype)
+
+
 class _KernelLauncher(_Launcher):
     """The launcher of ONE kernel over one chunk: ``fn(offset, arrays,
     values) -> arrays``.  The executable returns only the arrays the kernel
@@ -193,14 +253,31 @@ class _KernelLauncher(_Launcher):
     alone rides as one more argument (:class:`_KeptViews`).  A call on
     device arrays looks the views up under the arrays' identity (``frozen``:
     the positions no kernel of the caller's LAUNCH stores to; left out, this
-    kernel's own); a call from inside a ladder's trace is handed them."""
+    kernel's own); a call from inside a ladder's trace is handed them.
 
-    __slots__ = ("_kept", "_pitches", "_keyed", "_raw", "_views", "_sig")
+    ``ctypes``: a C kernel's value parameters' declared types.  A dispatch of
+    such a kernel (a call on arrays that are no tracers) hands its run-time
+    scalars over as ONE array: the offset and every value that is a plain
+    Python or numpy scalar, each converted on the host to what the launch
+    makes of it (:func:`_host_words`), cross as one vector of 32-bit words,
+    and the executable's entry (``_packed``: the one a per-call dispatch
+    compiles) bit-casts them back and runs the same function.  A launcher
+    key stays static; a value that is anything else (a ``jax.Array``, a
+    tracer) rides as an argument of its own; a Python kernel's values ride as
+    they did (``ctypes`` None), and so does every call from inside another
+    function's trace, where nothing crosses.  ``.trace`` / ``.lower`` are the
+    unpacked function's, ``(offset, arrays, values[, keys[, views]])``, which
+    compiles only where it is called.  ``info.scalars`` counts what the
+    newest dispatch handed over: words in the vector, plain scalars one by
+    one."""
+
+    __slots__ = ("_kept", "_pitches", "_keyed", "_raw", "_views", "_sig",
+                 "_dtypes", "_packed", "_static")
     KEYED_BUILDS = 4
 
     def __init__(self, raw_fn, tag: str, info, static: bool, kept: tuple,
                  pitches: tuple = (), views: _KeptViews | None = None,
-                 sig: tuple = ()):
+                 sig: tuple = (), ctypes: tuple | None = None):
         self._kept, self._pitches = kept, pitches
         self._keyed: set = set()  # the key tuples that have a build
         # ``views``: the program's, for a build of the vectorized lowering
@@ -224,6 +301,60 @@ class _KernelLauncher(_Launcher):
         super().__init__(
             jax.jit(replaced, static_argnums=(2, 3) if static else (3,)),
             tag, info)
+        self._static = static
+        self._dtypes = self._packed = None
+        if ctypes is None:
+            return
+        # slot 0 is the offset (``jnp.asarray(offset, jnp.int32)`` in both
+        # lowerings), slot i the value i - 1; each dtype with its name, which
+        # numpy derives anew at every ask
+        self._dtypes = tuple((d, d.name) for d in (
+            np.dtype(np.int32),
+            *(np.dtype(codegen.ctype_to_dtype(c)) for c in ctypes)))
+
+        def packed(words, arrays: tuple, loose: tuple, layout: tuple,
+                   keys=None, views=None):
+            at, rest, slots = 0, iter(loose), []
+            keyed = dict(zip(pitches, keys or ()))
+            for i, kind in enumerate(layout):
+                if kind == KEYED:
+                    slots.append(keyed[i - 1])
+                elif kind == LOOSE:
+                    slots.append(next(rest))
+                else:
+                    dtype = np.dtype(kind)
+                    n = max(dtype.itemsize // 4, 1)
+                    slots.append(_from_words(words[at:at + n], dtype))
+                    at += n
+            return replaced(slots[0], arrays, tuple(slots[1:]), keys, views)
+
+        packed.__name__ = raw_fn.__name__
+        self._packed = jax.jit(packed, static_argnums=(3, 4))
+
+    def _cache_size(self) -> int:
+        """The builds of both entries (a test counts a call's traces by it)."""
+        return self._fn._cache_size() + (
+            self._packed._cache_size() if self._packed is not None else 0)
+
+    def _pack(self, offset, values, keys) -> tuple:
+        """``(words, loose, layout)`` of a dispatch: the vector, the values
+        that ride beside it, and per slot the dtype's name the words hold
+        (:data:`LOOSE`, :data:`KEYED` for the others)."""
+        words, loose, layout = [], [], []
+        for i, (v, (dtype, name)) in enumerate(
+                zip((offset,) + values, self._dtypes)):
+            if keys is not None and i - 1 in self._pitches:
+                layout.append(KEYED)
+                continue
+            w = _host_words(v, dtype) if _plain(v) else None
+            if w is None:
+                loose.append(v)
+                layout.append(LOOSE)
+            else:
+                words.append(w)
+                layout.append(name)
+        return (np.frombuffer(b"".join(words), np.uint32), tuple(loose),
+                tuple(layout))
 
     def keys_of(self, values) -> tuple | None:
         """The launcher keys among ``values``, where this call has any."""
@@ -268,7 +399,20 @@ class _KernelLauncher(_Launcher):
                         arrays, specs if frozen is None else
                         [s for s in specs if s.param in frozen])
                 self.info.views_kept, self.info.views_built = len(views), built
-        new = super().__call__(offset, arrays, values, keys, views)
+        values = tuple(values)
+        # inside another function's trace nothing is dispatched
+        dispatch = not any(isinstance(x, jax.core.Tracer)
+                           for x in (offset,) + arrays)
+        if dispatch and self._packed is not None:
+            words, loose, layout = self._pack(offset, values, keys)
+            self.info.scalars = (len(words), sum(map(_plain, loose)))
+            new = self._run(self._packed, words, arrays, loose, layout, keys,
+                            views)
+        else:
+            if dispatch:
+                crossing = (offset,) + (() if self._static else values)
+                self.info.scalars = (0, sum(map(_plain, crossing)))
+            new = super().__call__(offset, arrays, values, keys, views)
         out = list(arrays)
         for i, buf in zip(self._kept, new):
             out[i] = buf
@@ -355,7 +499,10 @@ def lowering_meta(infos) -> dict:
     for its rungs.  ``views`` (``kept:K;built:B``) is of the DISPATCHES the
     span ran, one of ``infos`` each: the kept views they took as arguments
     (:class:`_KeptViews`) and how many of those were built on these calls;
-    in a warm window ``built`` is 0."""
+    in a warm window ``built`` is 0.  ``scalars`` (``packed:W;loose:L``) is
+    of the dispatches too, where a kernel's launcher made them: the 32-bit
+    words of run-time scalars that crossed to the device in one vector a
+    dispatch, and the Python or numpy scalars that crossed one by one."""
     infos = list(infos)
     leaves = [r for i in infos for r in (i.rungs or (i,))]
     meta = {"lowering": "+".join(sorted({i.lowering for i in leaves})),
@@ -364,6 +511,10 @@ def lowering_meta(infos) -> dict:
                  for i in leaves})),
             "views": f"kept:{sum(i.views_kept for i in infos)};"
                      f"built:{sum(i.views_built for i in infos)}"}
+    scalars = [i.scalars for i in infos if i.scalars is not None]
+    if scalars:
+        meta["scalars"] = (f"packed:{sum(w for w, _l in scalars)};"
+                           f"loose:{sum(l for _w, l in scalars)}")
     tiles = sorted({(i.tile_rows, i.tile_grid, i.loop_live)
                     for i in leaves if i.lowering == "pallas"})
     if tiles:
@@ -742,7 +893,9 @@ class KernelProgram:
             f"{platform}" + ("" if in_range else " beyond-range"),
             info, static, kept, pitches,
             self.kept_views if xla else None,
-            (name, local_size, global_size, platform, in_range))
+            (name, local_size, global_size, platform, in_range),
+            tuple(p.ctype for p in self._c_kernels[name].params
+                  if not p.is_pointer) if name in self._c_kernels else None)
         with self._lock:
             self._cache[key] = (jitted, info)
         return jitted, info

@@ -493,7 +493,9 @@ def _mandelbrot_frame_read_back(devices, sizes, want) -> dict:
     shows ``part:issued`` (the copy to the host is on its way) before
     ``part:landed`` (inside the download's span: the bytes are in host
     memory) before the span's end (the frame is in the caller's array), and
-    the downloads' bytes add up to the frame's."""
+    the downloads' bytes add up to the frame's.  Every dispatch of the frame
+    hands its run-time scalars over in one piece (ISSUE 39): four floats, two
+    ints and the offset are seven words in one vector, none crosses alone."""
     import jax
     from jax.profiler import ProfileData
 
@@ -507,7 +509,7 @@ def _mandelbrot_frame_read_back(devices, sizes, want) -> dict:
     cr = NumberCruncher(devices.subset(1), MANDELBROT_SRC)
     out = ClArray(n, np.float32, name="mandel_shown", read=False, write=True)
     root = tempfile.mkdtemp(prefix="ck_smoke_readback_")
-    events = []
+    events, launches = [], []
     try:
         call = lambda: out.compute(cr, 7107, "mandelbrot", n, lr, values=vals)
         _, cold_s = _timed(call)
@@ -531,9 +533,17 @@ def _mandelbrot_frame_read_back(devices, sizes, want) -> dict:
                            for li, line in enumerate(plane.lines)
                            for ev in line.events
                            if ev.name in ("ck/download", "ck/download-chunk")]
+                launches += [dict(ev.stats) for line in plane.lines
+                             for ev in line.events if ev.name == "ck/launch"]
     finally:
         cr.dispose()
         shutil.rmtree(root, ignore_errors=True)
+    # a launch's tag ends in its dispatches: ``mandelbrot x2`` is two rungs
+    scalars = [(str(s.get("tag")), str(s.get("scalars"))) for s in launches]
+    _require(scalars and all(
+        field == f"packed:{7 * int(t.rpartition(' x')[2])};loose:0"
+        for t, field in scalars),
+        f"the frame's launches did not pack their scalars: {scalars}")
     tag = lambda e: str(e[3].get("tag"))
     spans = sorted(e for e in events if not tag(e).startswith("part:"))
     issued = {e[3].get("off"): e for e in events if tag(e) == "part:issued"}
@@ -559,6 +569,7 @@ def _mandelbrot_frame_read_back(devices, sizes, want) -> dict:
     first = min(e[0] for e in issued.values())
     return _row("mandelbrot frame read back", "", cold_s, run_s, err,
                 downloads=len(spans), bytes=nbytes, stream_chunks=chunks,
+                launches=len(scalars), scalars=scalars[0][1],
                 readback_ms=round((spans[-1][1] - first) / 1e6, 3),
                 copy_ms=round(copy_ns / 1e6, 3))
 

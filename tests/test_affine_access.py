@@ -138,9 +138,9 @@ def test_a_pitch_keys_one_executable_a_value():
         traces.append(fn._cache_size() - before)
         assert info.keyed == keyed or traces[-1] == 0
         np.testing.assert_allclose(np.asarray(out[1]), 129.0)  # 1 + 128 ones
-    # (a numpy integer is the same key and, as everywhere under jit, another
-    # argument type than a Python int)
-    assert traces == [1, 0, 1, 1]
+    # (a numpy integer is the same key and, since a dispatch packs its plain
+    # scalars by the parameters' types, the same entry as a Python int)
+    assert traces == [1, 0, 0, 1]
     assert prog.compiled_count == 1  # one launcher: the keys live in its jit
 
 

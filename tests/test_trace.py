@@ -813,6 +813,46 @@ def test_mark_pairs_anchor_trace_time_onto_perf_counter(profiled):
     assert len(offs) >= 4 and max(offs) - min(offs) < 5e-3
 
 
+def test_a_synchronous_computes_launch_span_counts_its_packed_scalars(
+        tmp_path):
+    """ISSUE 39: a per-call dispatch hands its run-time scalars over as one
+    vector, and the ``ck/launch`` / ``ck/compile`` spans say so: mandelbrot's
+    four floats, two ints and the offset are ``scalars=packed:7;loose:0`` on
+    the XLA half (the rig's lanes), cold and warm."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from cekirdekler_tpu.workloads import MANDELBROT_SRC
+
+    wh = 64
+    cr = NumberCruncher(_cpus(1), MANDELBROT_SRC)
+    out = ClArray(wh * wh, np.float32, name="shown", read=False, write=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for x0 in (-2.0, -1.75):  # the view moves: nothing is kept by value
+            out.compute(cr, 3901, "mandelbrot", wh * wh, 64,
+                        values=(x0, -1.25, 2.5 / wh, 2.5 / wh, wh, 32))
+    finally:
+        jax.profiler.stop_trace()
+        cr.dispose()
+    path = [os.path.join(r, f) for r, _d, fs in os.walk(str(tmp_path))
+            for f in fs if f.endswith(".xplane.pb")][0]
+    spans = [(ev.name, dict(ev.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events
+             if ev.name in ("ck/launch", "ck/compile")]
+    launches = [st for name, st in spans if name == "ck/launch"]
+    compiles = [st for name, st in spans if name == "ck/compile"]
+    assert len(launches) == 2 and len(compiles) == 1
+    for st in launches + compiles:
+        assert st["lowering"] == "xla"
+        assert st["scalars"] == "packed:7;loose:0"
+    assert [str(st["tag"]) for st in launches] == ["mandelbrot x1"] * 2
+
+
 def test_mosaic_launch_carries_the_kernels_name():
     """The device operation and the XLA module are named after the user's
     kernel: the lowering for a TPU names the Mosaic call ``inc`` and the
