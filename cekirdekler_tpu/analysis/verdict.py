@@ -301,6 +301,7 @@ def verify_launch(
     window: bool = False,
     where: str = "<compute>",
     exchange: bool = False,
+    lanes: int | None = None,
 ) -> LaunchVerdict:
     """Prove or refute split-safety and flag soundness for one launch.
 
@@ -325,6 +326,15 @@ def verify_launch(
     carries the reach instead.  A hazard inside one pass of the sequence
     (the writer runs before the reader: no exchange between the kernels
     of one compute) and a read the analysis cannot bound stay errors.
+
+    ``lanes``: on how many lanes the launch runs, where the caller knows
+    (``Cores.compute``); None: any number.  On ONE lane the partition is the
+    whole range, so a store through a gathered index cannot leave it: no
+    ``scatter-write`` there (Rodinia's BFS: ``cost[id] = ...`` with ``id``
+    read from the edge table), and an array with ``write_all`` comes back
+    whole from the lane that stored to it: no ``off-partition-write`` for
+    it either (``over[0] = true``).  On more lanes both stay the errors
+    they are.
     """
     findings: list[Finding] = []
     seen: set = set()
@@ -387,6 +397,8 @@ def verify_launch(
                                 ">1-lane split", sup)
                         continue
                     if klass == "gather":
+                        if lanes == 1:
+                            continue  # one partition: every index is inside
                         emit(
                             "scatter-write", name, pname, acc.line,
                             f"{name}: write to {pname}[…] at a gathered/"
@@ -394,6 +406,10 @@ def verify_launch(
                             "inside the caller's partition; a split lane "
                             "drops every off-partition store at readback",
                             sup)
+                    elif lanes == 1 and fl.write_all:
+                        # the one lane owns the array and writes it back
+                        # whole: a flag every item raises (``over[0] = 1``)
+                        continue
                     else:
                         detail = (
                             f"halo offset {width} outside the per-item "

@@ -37,6 +37,10 @@ class TransferFlags:
     - ``write``: device→host after the kernel; each chip writes back only
       the slice covered by its range.
     - ``write_all``: write the entire array back from the owning chip.
+      With a whole read (no ``partial_read``) such an array is never cut by
+      the work-item range, so it alone may be SHORTER than
+      ``global_range x elements_per_work_item`` (a one-element flag that
+      the kernels raise for the host); an index beyond its length clamps.
     - ``read_only`` / ``write_only``: access hints (donation / no-readback).
     - ``zero_copy``: request pinned-host staging (the TPU analogue of
       ``CL_MEM_USE_HOST_PTR``; SURVEY.md §7).
@@ -214,6 +218,11 @@ def _validate_compute(params, names, global_range, local_range, pipeline, blobs)
             )
     for p in params:
         p.flags.validate()
+        if p.flags.write_all and not p.flags.partial_read and p.size > 0:
+            # never cut by the range: it goes up whole and its owner lane
+            # writes it back whole, so it may be SHORTER than the range (a
+            # one-element flag the kernels raise for the host)
+            continue
         need = global_range * p.flags.elements_per_work_item
         if p.size < need:
             raise ComputeValidationError(
@@ -230,6 +239,13 @@ class ClArray(_ComputeMixin):
     or a :class:`FastArr` aligned native allocation; ``fast_arr`` migrates
     between them in place (reference: ClArray.fastArr C#↔native migration,
     ClArray.cs:889-958).
+
+    ``compute()`` asks every array for at least ``global_range x
+    elements_per_work_item`` elements, with one exception: an array with
+    ``write_all`` and a whole read is uploaded whole and written back whole
+    by its owner lane, whatever the range, and may be shorter than it:
+    ``ClArray(np.zeros(1, np.int8), write_all=True)`` is the stop flag a
+    host loop reads after every compute (Rodinia's ``g_over``).
     """
 
     def __init__(

@@ -796,6 +796,7 @@ class Cores:
                 window=self.enqueue_mode or self.repeat_count > 1,
                 exchange=not pipeline and self.repeat_count == 1
                 and not self.repeat_sync_kernel,
+                lanes=self.num_devices,
             )
             if verdict.errors:
                 if verify_mode == "strict":
@@ -2449,7 +2450,14 @@ class Cores:
         up_parts: list[ClArray] = []   # chunk-streamed partition uploads
         up_full: list[ClArray] = []    # whole-array uploads (up-front)
         ensure: list[ClArray] = []
-        for p in params:
+        # arrays a kernel stores to OUTSIDE the work item's own elements (a
+        # scattered store): a chunk's launch may write another chunk's
+        # elements, so they go up whole before the first launch and come
+        # back after the last, never chunk by chunk
+        roam = self.program.roaming_stores(
+            tuple(kernel_names),
+            tuple(p.flags.elements_per_work_item for p in params))
+        for idx, p in enumerate(params):
             fl = p.flags
             if fl.read and not fl.write_only:
                 epw = fl.elements_per_work_item
@@ -2463,15 +2471,20 @@ class Cores:
                 # whole array, so ranged chunks == the full upload);
                 # non-partial arrays must land whole before any launch
                 # (the kernel may read outside the lane's range)
-                (up_parts if fl.partial_read else up_full).append(p)
+                (up_parts if fl.partial_read and idx not in roam
+                 else up_full).append(p)
             else:
                 ensure.append(p)
         down_parts: list[tuple[int, ClArray]] = []
+        down_late: list[ClArray] = []  # ranged, after the last launch
         if not self.enqueue_mode:
             for idx, p in enumerate(params):
                 fl = p.flags
                 if fl.write and not fl.read_only and not fl.write_all:
-                    down_parts.append((idx, p))
+                    if idx in roam:
+                        down_late.append(p)
+                    else:
+                        down_parts.append((idx, p))
         if not up_parts and not down_parts:
             # nothing to overlap — monolithic path is exact
             return False, None
@@ -2602,6 +2615,10 @@ class Cores:
                 if fl.write and not fl.read_only and fl.write_all:
                     if w.index == write_all_owner.get(idx):
                         handles.append(w.download_async(p, 0, p.size, True))
+            for p in down_late:
+                epw = p.flags.elements_per_work_item
+                handles.append(
+                    w.download_async(p, offset * epw, size * epw, False))
         t0d = time.perf_counter()
         for h in handles:
             Worker.finish_download(h)

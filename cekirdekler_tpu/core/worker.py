@@ -344,6 +344,14 @@ class Worker:
             "ck_upload_bytes_total", "H2D bytes uploaded", lane=index)
         self._m_download_bytes = REGISTRY.counter(
             "ck_download_bytes_total", "D2H bytes materialized", lane=index)
+        # what WHOLE (unsplit) arrays moved: a full upload, a full or
+        # ``write_all`` download; the ranged and chunked transfers are the
+        # rest of the two totals above
+        self._m_whole_up, self._m_whole_down = (
+            REGISTRY.counter(
+                "ck_whole_array_bytes_total",
+                "bytes of arrays moved whole, not cut by the lane's range",
+                dir=way, lane=index) for way in ("h2d", "d2h"))
         self._m_download_seconds = REGISTRY.histogram(
             "ck_download_seconds",
             "D2H issue (copy_to_host_async) to landed in host memory",
@@ -482,6 +490,7 @@ class Worker:
             self._buffers[key] = buf
             self._buffer_owner[key] = arr
             self._uploaded[key] = (0, host.size)
+            self._m_whole_up.inc(host.nbytes)
             if self.markers is not None:
                 self.markers.add()
                 self.markers.reach_when_ready(buf)
@@ -983,6 +992,7 @@ class Worker:
         if full:
             out = buf
             off = 0
+            self._m_whole_down.inc(out.nbytes)
         else:
             out = _slice_out(buf, offset_elems, size_elems)
             off = offset_elems

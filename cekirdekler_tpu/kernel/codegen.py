@@ -282,6 +282,8 @@ class _Ctx:
         # the store) -> kind; and the (loop, buffer) pairs carried as locals
         self.access: dict[tuple[int, bool], str] = {}
         self.carried: set[tuple[int, str]] = set()
+        # the element width in bytes of every store lowered to a scatter
+        self.scattered: list[int] = []
 
     def adopt(self, kernel: KernelDef, uniform_vars: set[str]) -> None:
         """Take what a build knows of ``kernel`` before its body runs."""
@@ -853,11 +855,32 @@ _RUN_WINDOW = 32       # passes one refill of a loop's run windows serves
 _LANE_CHUNK = 1 << 18  # work items whose rows are materialized at once
 
 
+def _words_of(buf):
+    """A table of 1-byte elements (``char`` masks) as ``[rows, 128]`` 32-bit
+    WORDS for the row gathers, which fetch 4-byte elements: row ``r`` holds
+    elements ``[512 r, 512 r + 512)``, element ``512 r + 128 k + l`` in bits
+    ``[8 k, 8 k + 8)`` of lane ``l`` (put there by shifts: no byte order is
+    assumed), the last element repeated to the end of the last row.  The
+    four bytes of a word lie 128 elements apart, which is how the chip keeps
+    a byte array in memory: packing ADJACENT bytes cost four strided reads
+    of the table a launch (1.9 ms each for a million bytes) or 17-35 s of the
+    chip's compiler a launcher (PERF.md, PR 40)."""
+    n = buf.shape[0]
+    u = lax.bitcast_convert_type(jnp.pad(buf, (0, -n % (4 * _ROW)), mode="edge"),
+                                 jnp.uint8).astype(jnp.uint32).reshape(-1, 4, _ROW)
+    w = u[:, 0] | (u[:, 1] << 8) | (u[:, 2] << 16) | (u[:, 3] << 24)
+    return lax.bitcast_convert_type(w, jnp.int32)
+
+
 def _rows_of(buf):
     """``buf`` as ``[rows, 128]``.  Element ``i`` sits at ``i + 128`` of a
     padded copy: one row of the first element before it, the last element
     repeated after it, so that a read reaching over either end reads what a
-    gather's clamp reads.  The plain view is that copy cut into rows."""
+    gather's clamp reads.  The plain view is that copy cut into rows.  A
+    table of 1-byte elements is its words (:func:`_words_of`), four
+    elements a lane, with no row before the first."""
+    if buf.dtype.itemsize == 1:
+        return _words_of(buf)
     n = buf.shape[0]
     return jnp.pad(buf, (_ROW, -n % _ROW + _ROW), mode="edge").reshape(-1, _ROW)
 
@@ -953,8 +976,12 @@ def _by_lane_chunks(fn, ix, lead: tuple, dtype):
 def _take_rows(ctx: _Ctx, name: str, iv):
     """``buf[clip(iv)]`` for every lane: the element's row fetched whole,
     its lane picked by a compare and a sum (on the value's bits, so that
-    every float comes back as it was stored)."""
-    rows, n = ctx.rows_view(name), ctx.bufs[name].shape[0]
+    every float comes back as it was stored).  Of a table of 1-byte
+    elements the row of the element's WORD is fetched and the byte shifted
+    out of it (on the chip a gather of single bytes costs 10.8 ns an
+    element, the row of its word a third: PERF.md, PR 40)."""
+    buf = ctx.bufs[name]
+    rows, n, bytewise = ctx.rows_view(name), buf.shape[0], buf.dtype.itemsize == 1
     bits = lax.bitcast_convert_type(rows, jnp.int32)
 
     def pick(ic):
@@ -964,7 +991,20 @@ def _take_rows(ctx: _Ctx, name: str, iv):
         hit = lane == (ic & (_ROW - 1))[:, None]
         return jnp.sum(jnp.where(hit, g, 0), axis=1, dtype=jnp.int32)
 
-    out = _by_lane_chunks(pick, iv.astype(jnp.int32), (), jnp.int32)
+    def pick_byte(ic):
+        # a byte table's row holds 512 elements, byte k of lane l being
+        # element 128 k + l of them (_words_of)
+        ic = jnp.clip(ic, 0, n - 1)
+        g = bits.at[ic >> 9].get(mode="promise_in_bounds")
+        lane = lax.broadcasted_iota(jnp.int32, g.shape, 1)
+        hit = lane == (ic & (_ROW - 1))[:, None]
+        got = jnp.sum(jnp.where(hit, g, 0), axis=1, dtype=jnp.int32)
+        return (got >> (((ic >> 7) & 3) << 3)) & 0xFF
+
+    out = _by_lane_chunks(pick_byte if bytewise else pick,
+                          iv.astype(jnp.int32), (), jnp.int32)
+    if bytewise:
+        return lax.bitcast_convert_type(out.astype(jnp.uint8), buf.dtype)
     return lax.bitcast_convert_type(out, rows.dtype)
 
 
@@ -1208,7 +1248,8 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
     iv = _num(_as_dtype(idx, "int"))
     if not hasattr(iv, "ndim") or iv.ndim == 0:
         iv = jnp.full((ctx.B,), iv, dtype=jnp.int32)
-    if ctx.row_gathers and buf.dtype.itemsize == 4:
+    if ctx.row_gathers and (buf.dtype.itemsize == 4
+                            or buf.dtype in (jnp.int8, jnp.uint8)):
         return _loaded(_take_rows(ctx, node.base, iv), ctype)
     return _loaded(jnp.take(buf, iv, mode="clip"), ctype)
 
@@ -1222,7 +1263,8 @@ def _store(ctx: _Ctx, node: Index, val: KVal) -> None:
     buf = ctx.bufs[node.base]
     ctype = ctx.buf_ctypes[node.base]
     v = _num(_as_dtype(val, ctype))
-    if not hasattr(v, "ndim") or v.ndim == 0:
+    one_value = not hasattr(v, "ndim") or v.ndim == 0  # the same in every lane
+    if one_value:
         v = ctx.broadcast_scalar(v, ctype_to_dtype(ctype))
     if hasattr(buf, "dtype") and v.dtype != buf.dtype:
         # a store converts to the buffer's STORAGE dtype (a caller may
@@ -1270,8 +1312,26 @@ def _store(ctx: _Ctx, node: Index, val: KVal) -> None:
             updated = lax.dynamic_update_slice(padded, v, (start,))
             ctx.bufs[node.base] = lax.slice(updated, (lo,), (lo + n,))
         ctx.invalidate_padded(node.base)
+    elif one_value and _expr_uniform(node.index, ctx.uniform_vars,
+                                     frozenset(ctx.private)):
+        # every lane names the same element and stores the same value (a
+        # flag the kernel raises for the host, ``over[0] = true``): ONE
+        # element is written if any lane is active, where a scatter would
+        # send a chunk of equal indices.  An index outside the buffer
+        # stores nothing, as the scatter's ``drop`` does
+        _note(ctx, node, True, "uniform")
+        at = _lane0(_num(_as_dtype(idx, "int"))).astype(jnp.int32)
+        n = buf.shape[0]
+        hit = jnp.logical_and(at >= 0, at < n)
+        if m is not None:
+            hit = jnp.logical_and(hit, m if m.ndim == 0 else ctx.any_lane(m))
+        at = jnp.clip(at, 0, n - 1)
+        one = jnp.where(hit, _lane0(v), lax.dynamic_slice(buf, (at,), (1,)))
+        ctx.bufs[node.base] = lax.dynamic_update_slice(buf, one, (at,))
+        ctx.invalidate_padded(node.base)
     else:
         _note(ctx, node, True, "scatter")
+        ctx.scattered.append(buf.dtype.itemsize)
         iv = _num(_as_dtype(idx, "int"))
         if not hasattr(iv, "ndim") or iv.ndim == 0:
             iv = jnp.full((ctx.B,), iv, dtype=jnp.int32)
@@ -2042,6 +2102,14 @@ def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
         # and not anew at every refill
         for t in run_tables:
             ctx.rows_view(t, overlapping=True)
+        # and so is the word view of a byte table the loop only reads
+        # (``visited[id]``): a pass gathers from it, none packs it
+        if ctx.row_gathers:
+            for ix in _index_nodes([body_core, cond_expr]):
+                if (ix.base in ctx.bufs and ix.base not in ctx.private
+                        and ix.base not in carried_bufs
+                        and ctx.bufs[ix.base].dtype in (jnp.int8, jnp.uint8)):
+                    ctx.rows_view(ix.base)
 
     outer_mask = ctx.active_mask()
 
@@ -2575,6 +2643,10 @@ class KernelBuildInfo:
     # column, by ``uniform`` scalar, by per-lane ``gather``, stores by
     # ``scatter``, and ``carried``: buffers riding a loop as a local
     access: dict = field(default_factory=dict)
+    # the element widths in bytes of the stores lowered to a scatter, one a
+    # store in the order the walk met them (``(4, 1)`` for Rodinia's BFS_1:
+    # ``cost[id]`` and ``updating[id]``); filled at trace
+    scattered: tuple = ()
     # the value parameters taken as keys of the launcher cache because the
     # kernel multiplies with them inside an index (:func:`pitch_params`),
     # with the values of the newest build
@@ -2680,6 +2752,7 @@ def build_kernel_fn(
         for kind in ctx.access.values():
             info.access[kind] += 1
         info.access["carried"] = len(ctx.carried)
+        info.scattered = tuple(ctx.scattered)
         info.views = tuple(sorted(
             ViewSpec(info.array_params.index(name), kind)
             for name, kind in ctx.asked))

@@ -502,7 +502,9 @@ def lowering_meta(infos) -> dict:
     in a warm window ``built`` is 0.  ``scalars`` (``packed:W;loose:L``) is
     of the dispatches too, where a kernel's launcher made them: the 32-bit
     words of run-time scalars that crossed to the device in one vector a
-    dispatch, and the Python or numpy scalars that crossed one by one."""
+    dispatch, and the Python or numpy scalars that crossed one by one.
+    ``scatter`` (``stores:2;width:4+1``), where a kernel's build has any: the
+    stores lowered to a scatter and the bytes of one element of each."""
     infos = list(infos)
     leaves = [r for i in infos for r in (i.rungs or (i,))]
     meta = {"lowering": "+".join(sorted({i.lowering for i in leaves})),
@@ -541,6 +543,13 @@ def lowering_meta(infos) -> dict:
         meta["access"] = ";".join(
             f"{kind}:{sum(k.get(kind, 0) for k in per_kernel.values())}"
             for kind in codegen.ACCESS_KINDS)
+    # the stores that became a scatter, summed over the kernels as
+    # ``access`` is, with the bytes of one element of each (``+``-joined)
+    scattered = {i.name: i.scattered for i in leaves if i.scattered}
+    if scattered:
+        widths = [w for name in sorted(scattered) for w in scattered[name]]
+        meta["scatter"] = (f"stores:{len(widths)};"
+                           f"width:{'+'.join(str(w) for w in widths)}")
     keyed = sorted({f"{k}={v}" for i in leaves for k, v in i.keyed.items()})
     if keyed:
         meta["keys"] = ";".join(keyed)
@@ -621,6 +630,7 @@ class KernelProgram:
         # path must not grow a lock for a cache read.
         self._analysis_summaries: dict[str, Any] | None = None
         self._verdict_cache: dict[tuple, Any] = {}
+        self._roaming: dict[tuple, frozenset] = {}  # roaming_stores' answers
 
         items: list = []
         if isinstance(source, (str, PythonKernel)):
@@ -775,16 +785,18 @@ class KernelProgram:
         return out
 
     def verify(self, kernel_names, flag_rows, window: bool = False,
-               exchange: bool = False):
+               exchange: bool = False, lanes: int | None = None):
         """Cached :class:`~..analysis.LaunchVerdict` for one launch
         shape.  ``flag_rows`` is a tuple of
         :func:`~..analysis.flag_row` tuples (positional, the call's
         parameter order).  Verification runs once per distinct
-        (kernel sequence, flags, window, exchange) — every later call is
-        one dict lookup.  ``exchange``: the caller keeps every proved
-        reach current before the sequence runs (``verify_launch``)."""
+        (kernel sequence, flags, window, exchange, one lane or not) —
+        every later call is one dict lookup.  ``exchange``: the caller keeps
+        every proved reach current before the sequence runs
+        (``verify_launch``); ``lanes``: how many lanes the launch runs on
+        (one lane holds the whole range: no ``scatter-write``)."""
         key = (tuple(kernel_names), tuple(flag_rows), bool(window),
-               bool(exchange))
+               bool(exchange), lanes == 1)
         v = self._verdict_cache.get(key)
         if v is None:
             from .. import analysis
@@ -792,12 +804,42 @@ class KernelProgram:
             try:
                 v = analysis.verify_launch(
                     self.summaries(), key[0], key[1], window=key[2],
-                    exchange=key[3])
+                    exchange=key[3], lanes=1 if key[4] else None)
             except Exception:  # noqa: BLE001 - verifier must never
                 # sink a compute; an empty verdict is "nothing proven"
                 v = analysis.LaunchVerdict(findings=())
             self._verdict_cache[key] = v
         return v
+
+    def roaming_stores(self, kernel_names, epws) -> frozenset:
+        """Positions, among the call's array parameters, that some kernel
+        of the sequence stores to at an index NOT confined to the work
+        item's own elements (gathered, uniform, shifted, strided): a store
+        of one chunk's launch may land in another chunk's elements.  The
+        STREAM engine moves such an array whole, before the first launch
+        and after the last (``Cores._run_streamed``): a chunk uploaded
+        behind the launch that scattered into it would bury the store, a
+        chunk downloaded before a later launch's store would miss it.
+        ``epws``: the parameters' elements per work item, by position.
+        Cached per sequence; a kernel outside the analyzable surface
+        contributes nothing (its stores are unknown, as they were)."""
+        key = (tuple(kernel_names), tuple(epws))
+        hit = self._roaming.get(key)
+        if hit is None:
+            from .. import analysis
+
+            found = set()
+            summaries = self.summaries()
+            for name in key[0]:
+                s = summaries.get(name)
+                if s is None:
+                    continue
+                for pos, pname in enumerate(s.array_params[:len(key[1])]):
+                    if any(analysis.classify(acc.av, max(1, key[1][pos]))[0]
+                           != "confined" for acc in s.writes.get(pname, ())):
+                        found.add(pos)
+            hit = self._roaming[key] = frozenset(found)
+        return hit
 
     def launcher(
         self,

@@ -54,6 +54,8 @@ FULL = {
     "nbody_n": 8192, "nbody_iters": 150, "nbody_window": 50,
     # the wave membrane split by range: a row of u1 crosses lanes each step
     "halo_wh": 1024, "halo_window": 20,
+    # Rodinia's BFS at the suite's middle input (graph65536.txt's size)
+    "bfs_nodes": 65536,
     # stage 2 — 256 MiB per array: not a cache
     "stream_n": 1 << 26, "stream_tuner_runs": 3,
     # stage 3 — the examples/wave_equation.py stage
@@ -574,6 +576,105 @@ def _mandelbrot_frame_read_back(devices, sizes, want) -> dict:
                 copy_ms=round(copy_ns / 1e6, 3))
 
 
+def _bfs_traversal(devices, sizes) -> dict:
+    """Rodinia's breadth-first search as its host loop drives it (PR 40): ONE
+    lane, level by level a synchronous ``compute()`` of ``BFS_1 BFS_2`` and a
+    look at the one-byte flag the kernels raise, the graph and the state
+    resident in between, ``cost`` back at the end.  Exact against the
+    configuration's plain reference; the two stores through ``edges[i]``
+    built as scatters (an ``int`` and a ``char``) and the flag's store as
+    one element; the flag crossed as ONE byte each way a level and nothing
+    with ``read = false`` crossed at all."""
+    import importlib.util
+
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+    from cekirdekler_tpu.core.worker import launch_ladder
+    from cekirdekler_tpu.kernel.registry import lowering_meta
+
+    configs = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs")
+    spec = importlib.util.spec_from_file_location(
+        "rodinia_bfs_ref", os.path.join(configs, "rodinia_bfs_ref.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    with open(os.path.join(configs, "rodinia_bfs.cl")) as f:
+        source = f.read()
+    nodes, lr = sizes["bfs_nodes"], 256
+    n = (nodes // lr + 1) * lr  # a range that is not the node count
+    data, values = ref.inputs(
+        {"nodes": nodes, "graph_seed": 1, "seed_relabels": True}, {"n": n},
+        np.random.default_rng(sizes["seed"]))
+    graph = ("starting", "no_of_edges", "edges")
+    state = ("mask", "updating", "visited", "cost")
+    arr = {k: ClArray(data[k], name=k) for k in graph + state + ("over",)}
+    for k in graph:
+        arr[k].read_only = True
+    arr["over"].write_all = True
+    group = arr["starting"].next_param(*list(arr.values())[1:])
+    cr = NumberCruncher(devices.subset(1), source)
+    w = cr.cores.workers[0]
+    try:
+        def traverse(base: int) -> int:
+            start = int(data["relabel"][base])
+            for k in state[:3]:
+                arr[k].host()[:] = 0
+            arr["cost"].host()[:] = -1
+            arr["mask"][start] = arr["visited"][start] = 1
+            arr["cost"][start] = 0
+            for k in state:
+                arr[k].read, arr[k].write = True, False
+            arr["over"].read = arr["over"].write = True
+            levels = 0
+            while True:
+                arr["over"][0] = 0
+                group.compute(cr, 7108, "BFS_1 BFS_2", n, lr, values=values)
+                levels += 1
+                if levels == 1:
+                    for k in graph + state:
+                        arr[k].read = False
+                if not arr["over"][0]:
+                    break
+            arr["over"].read = arr["over"].write = False
+            arr["cost"].write = True
+            cr.no_compute_mode = True
+            try:
+                group.compute(cr, 7108, "BFS_1 BFS_2", n, lr, values=values)
+            finally:
+                cr.no_compute_mode = False
+            return levels
+
+        _, cold_s = _timed(lambda: traverse(0))
+        up0, down0 = w._m_whole_up.value, w._m_whole_down.value
+        levels, run_s = _timed(lambda: traverse(1))
+        up, down = w._m_whole_up.value - up0, w._m_whole_down.value - down0
+        want, want_levels = ref.bfs(data["starting"], data["no_of_edges"],
+                                    data["edges"], int(data["relabel"][1]))
+        differing = int((arr["cost"].host() != want).sum())
+        _require(differing == 0 and levels == want_levels,
+                 f"BFS: {differing} nodes differ, {levels} levels for "
+                 f"{want_levels}")
+        # one byte up and one back a level; the state up once, cost back
+        _require(up == 7 * n + levels and down == 4 * n + levels,
+                 f"BFS moved {up} bytes up and {down} back whole over "
+                 f"{levels} levels (state {7 * n}, cost {4 * n})")
+        # the launchers of the ladder's first rung, as the traversal built them
+        infos = [cr.cores.program.launcher(
+            name, launch_ladder(n, lr)[0], lr, n,
+            platform=w.device.platform)[1] for name in ("BFS_1", "BFS_2")]
+        meta = lowering_meta(infos)
+        _require("scatter:2" in meta["access"] and "uniform:1" in meta["access"]
+                 and meta["scatter"] == "stores:2;width:4+1",
+                 f"BFS: access {meta['access']}, scatter "
+                 f"{meta.get('scatter')}")
+        return _row("BFS traversal compute()", meta["lowering"], cold_s,
+                    run_s, float(differing), levels=levels,
+                    access=meta["access"], scatter=meta["scatter"],
+                    flag_bytes_up=up - 7 * n, flag_bytes_back=down - 4 * n)
+    finally:
+        cr.dispose()
+
+
 def stage_compute(devices, sizes) -> list[dict]:
     from cekirdekler_tpu import ClArray
     from cekirdekler_tpu.core.cruncher import NumberCruncher
@@ -650,6 +751,7 @@ def stage_compute(devices, sizes) -> list[dict]:
     rows.append(_wave_window_across_lanes(lanes, sizes))
     rows.append(_nbody_windows_start_on_the_ladder(devices, sizes))
     rows.append(_mandelbrot_frame_read_back(devices, sizes, want))
+    rows.append(_bfs_traversal(devices, sizes))
     return rows
 
 

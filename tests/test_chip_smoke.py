@@ -21,7 +21,7 @@ TOY = dict(
     mandel_wh=64, mandel_max_iter=32, local_range=128,
     mandel_per_call=3, mandel_window=6, mandel_marker_window=4,
     nbody_n=256, nbody_iters=6, nbody_window=3,
-    halo_wh=64, halo_window=6,
+    halo_wh=64, halo_window=6, bfs_nodes=1000,
     stream_n=1 << 14, stream_tuner_runs=2,
     wave_pushes=6,
     serve_tenants=2, serve_sigs=2, serve_reqs=4,
@@ -49,8 +49,8 @@ def _check_rows(rows, n_min=1):
 
 def test_stage_compute(devs):
     rows = chip_smoke.stage_compute(devs, TOY)
-    _check_rows(rows, 7)
-    kl, hand, forced, nbody, wave, starts, shown = rows
+    _check_rows(rows, 8)
+    kl, hand, forced, nbody, wave, starts, shown, bfs = rows
     # CPU lanes take the XLA lowering by policy; the routing assertion
     # itself only binds on TPU lanes
     assert kl["lowering"] == "xla"
@@ -79,11 +79,16 @@ def test_stage_compute(devs):
     assert shown["name"] == "mandelbrot frame read back"
     assert shown["bytes"] == 4 * 64 * 64 and shown["max_err"] == 0.0
     assert shown["downloads"] == (shown["stream_chunks"] or 1)
+    # Rodinia's BFS level by level: exact, two scatters, one byte a level
+    assert bfs["name"] == "BFS traversal compute()" and bfs["max_err"] == 0.0
+    assert bfs["lowering"] == "xla" and bfs["levels"] >= 4
+    assert bfs["scatter"] == "stores:2;width:4+1"
+    assert bfs["flag_bytes_up"] == bfs["flag_bytes_back"] == bfs["levels"]
 
 
 def test_stage_compute_partitions_a_single_device():
     rows = chip_smoke.stage_compute(platforms().cpus().subset(1), TOY)
-    assert rows[-3]["lanes"] == 2 and all(r > 0 for r in rows[-3]["ranges"])
+    assert rows[-4]["lanes"] == 2 and all(r > 0 for r in rows[-4]["ranges"])
 
 
 def test_stage_transfers(devs):
