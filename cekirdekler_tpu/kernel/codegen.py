@@ -284,6 +284,16 @@ class _Ctx:
         self.carried: set[tuple[int, str]] = set()
         # the element width in bytes of every store lowered to a scatter
         self.scattered: list[int] = []
+        # LANE COMPACTION (_exec_compacted): are we inside a chunk of
+        # compacted lanes (the work-item id is data there); the reads such a
+        # chunk made once, before its passes: id of the Index node -> value;
+        # how the chunks' trace lowered the access sites (``access`` keeps
+        # what the dense trace of the same site says); the loops made
+        # compactable
+        self.compacting = False
+        self.hoisted: dict[int, Any] = {}
+        self.compact_access: dict[tuple[int, bool], str] = {}
+        self.compact_loops = 0
 
     def adopt(self, kernel: KernelDef, uniform_vars: set[str]) -> None:
         """Take what a build knows of ``kernel`` before its body runs."""
@@ -1187,7 +1197,12 @@ def _strided_window(ctx: _Ctx, site, c, width: int):
 
 
 def _note(ctx: _Ctx, node: Index, store: bool, kind: str) -> None:
-    ctx.access[id(node), store] = kind
+    """Record how an access site was lowered.  A chunk of compacted lanes
+    traces the sites of its loop a second time: that goes to a record of its
+    own, and ``access`` keeps the kinds the dense path gives them."""
+    (ctx.compact_access if ctx.compacting else ctx.access)[id(node), store] = kind
+    if kind == "scatter" and not ctx.compacting:
+        ctx.scattered.append(ctx.bufs[node.base].dtype.itemsize)
 
 
 def _load(ctx: _Ctx, node: Index) -> KVal:
@@ -1203,6 +1218,8 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
     if ctx.pallas:
         kv = ctx.pallas_load(node, buf, ctype, idx)  # type: ignore[attr-defined]
         return _loaded(kv.value, ctype)
+    if id(node) in ctx.hoisted:
+        return _loaded(ctx.hoisted[id(node)], ctype)  # read once a chunk
     run = ctx.runs.get(node.base)
     if run is not None and isinstance(node.index, Var) and node.index.name == run[0]:
         _note(ctx, node, False, "gather")  # a row gather a refill
@@ -1331,7 +1348,6 @@ def _store(ctx: _Ctx, node: Index, val: KVal) -> None:
         ctx.invalidate_padded(node.base)
     else:
         _note(ctx, node, True, "scatter")
-        ctx.scattered.append(buf.dtype.itemsize)
         iv = _num(_as_dtype(idx, "int"))
         if not hasattr(iv, "ndim") or iv.ndim == 0:
             iv = jnp.full((ctx.B,), iv, dtype=jnp.int32)
@@ -2081,6 +2097,9 @@ def _exec_loop(ctx: _Ctx, node) -> None:
     if _loop_counted(ctx, node):
         _exec_counted(ctx, node, cond_expr, body_core, step_stmt,
                       carried_vars, carried_bufs)
+    elif _compactable(ctx, body, cond_expr):
+        _exec_compacted(ctx, node, cond_expr, body_core, step_stmt,
+                        carried_vars, carried_bufs)
     else:
         _exec_masked(ctx, node, cond_expr, body_core, step_stmt,
                      carried_vars, carried_bufs)
@@ -2091,26 +2110,57 @@ def _exec_loop(ctx: _Ctx, node) -> None:
         ctx.stored.add(k)
 
 
+def _live_after(ctx: _Ctx, carried_vars: list) -> set[str]:
+    """The carried locals of a loop inside no other that something may read
+    AFTER it: those the statements still to run name (the remainder stack),
+    and the buffers riding the loop as locals, which are stored behind it."""
+    read_later: set[str] = set()
+    for rest in ctx._after_stack:
+        _vars_read(rest, read_later)
+    read_later |= {local for local, _ in ctx.own.values()}
+    return read_later & set(carried_vars)
+
+
+def _carried_at_shape(ctx: _Ctx, carried_vars: list) -> None:
+    """Broadcast a loop's carried locals to the work-item shape so that
+    loop-carry shapes are stable (broadcast_scalar: the Pallas subclass
+    forces a computed Mosaic layout — a jnp.full constant gets a replicated
+    layout the body's computed carries cannot be relaid out to)."""
+    for name in carried_vars:
+        v = ctx.env[name]
+        val = _num(v)
+        if not hasattr(val, "ndim") or val.ndim == 0:
+            val = ctx.broadcast_scalar(val, ctype_to_dtype(v.ctype))
+        ctx.env[name] = KVal(val, v.ctype, None)
+
+
+def _loop_views(ctx: _Ctx, node, cond_expr, body_core: list,
+                carried_bufs: list) -> tuple:
+    """``(j, tables)`` of a masked loop's run windows (:func:`_run_reads`),
+    with the views the loop's passes gather from made HERE, where the buffers
+    are defined, and not anew at every refill or pass."""
+    if ctx.pallas:
+        return None, []
+    run_var, run_tables = _run_reads(ctx, node, cond_expr, carried_bufs)
+    for t in run_tables:
+        ctx.rows_view(t, overlapping=True)
+    # and so is the word view of a byte table the loop only reads
+    # (``visited[id]``): a pass gathers from it, none packs it
+    if ctx.row_gathers:
+        for ix in _index_nodes([body_core, cond_expr]):
+            if (ix.base in ctx.bufs and ix.base not in ctx.private
+                    and ix.base not in carried_bufs
+                    and ctx.bufs[ix.base].dtype in (jnp.int8, jnp.uint8)):
+                ctx.rows_view(ix.base)
+    return run_var, run_tables
+
+
 def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
                  carried_vars: list, carried_bufs: list) -> None:
     """A loop that lanes may leave on different passes: a vectorized
     lax.while_loop with a per-item active mask (see module docstring)."""
-    run_var, run_tables = None, []
-    if not ctx.pallas:
-        run_var, run_tables = _run_reads(ctx, node, cond_expr, carried_bufs)
-        # their row views are made here, where the buffers are defined,
-        # and not anew at every refill
-        for t in run_tables:
-            ctx.rows_view(t, overlapping=True)
-        # and so is the word view of a byte table the loop only reads
-        # (``visited[id]``): a pass gathers from it, none packs it
-        if ctx.row_gathers:
-            for ix in _index_nodes([body_core, cond_expr]):
-                if (ix.base in ctx.bufs and ix.base not in ctx.private
-                        and ix.base not in carried_bufs
-                        and ctx.bufs[ix.base].dtype in (jnp.int8, jnp.uint8)):
-                    ctx.rows_view(ix.base)
-
+    run_var, run_tables = _loop_views(ctx, node, cond_expr, body_core,
+                                      carried_bufs)
     outer_mask = ctx.active_mask()
 
     # Free-run predication elimination: a carried variable that is never
@@ -2125,22 +2175,8 @@ def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
     # so "after" cannot be derived from the remainder stack alone.
     freerun: set[str] = set()
     if not ctx.info.get("in_loop", 0):
-        read_later: set[str] = set()
-        for rest in ctx._after_stack:
-            _vars_read(rest, read_later)
-        freerun = {v for v in carried_vars if v not in read_later}
-        freerun -= {local for local, _ in ctx.own.values()}  # stored behind
-
-    # broadcast carried locals to the work-item shape so loop-carry shapes
-    # are stable (broadcast_scalar: the Pallas subclass forces a computed
-    # Mosaic layout — a jnp.full constant gets a replicated layout the
-    # body's computed carries cannot be relaid out to)
-    for name in carried_vars:
-        v = ctx.env[name]
-        val = _num(v)
-        if not hasattr(val, "ndim") or val.ndim == 0:
-            val = ctx.broadcast_scalar(val, ctype_to_dtype(v.ctype))
-        ctx.env[name] = KVal(val, v.ctype, None)
+        freerun = set(carried_vars) - _live_after(ctx, carried_vars)
+    _carried_at_shape(ctx, carried_vars)
 
     var_ctypes = {k: ctx.env[k].ctype for k in carried_vars}
 
@@ -2276,6 +2312,201 @@ def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
     for k in carried_bufs:
         ctx.bufs[k] = bufs_f[k]
         ctx.stored.add(k)
+
+
+# ---------------------------------------------------------------------------
+# lane compaction.  A masked loop runs every pass over all the lanes of its
+# launch, whatever the mask it was entered under: a BFS level whose frontier
+# is a thousandth of the range pays for the range, pass after pass, in every
+# gather and scatter of the body.  Where few lanes enter, the loop runs over
+# THOSE lanes: their numbers are put in order once (active first) and the
+# loop walks them in chunks of ``_COMPACT_WIDTH``, each chunk the masked loop
+# at that width with the work-item id as data.  Where most lanes enter, the
+# loop runs as it always did; the count of entering lanes decides, at run
+# time, between the two (both are compiled into the launcher).
+# ---------------------------------------------------------------------------
+
+# lanes of a chunk, and the share of a launch's lanes from which on the loop
+# runs over all of them: read off sweeps on the chip (PERF.md, PR 41)
+_COMPACT_WIDTH = 8192
+_COMPACT_DENSE_SHARE = 0.75
+
+
+def _compactable(ctx: _Ctx, body: list, cond_expr) -> bool:
+    """May this masked loop run over its entering lanes alone
+    (:func:`_exec_compacted`)?  Decided from the build alone: the XLA
+    lowering, a loop inside no other, entered under a mask that differs from
+    lane to lane, over more lanes than one chunk holds, whose body or
+    condition reads or writes a buffer at an index that is neither the same
+    in every lane nor affine in the work-item id (a gather or a scatter a
+    pass: a loop of arithmetic and own-element accesses runs at the vector
+    unit's speed over all lanes and gains nothing)."""
+    if (ctx.pallas or ctx.info.get("in_loop", 0) or ctx.masks()[0] is None
+            or ctx.B <= _COMPACT_WIDTH):
+        return False
+    changed, private = _assigned_vars(body), frozenset(ctx.private)
+    for ix in _index_nodes([body, cond_expr]):
+        if ix.base not in ctx.bufs or ix.base in private:
+            continue
+        names = _vars_read(ix.index)
+        if not (_expr_uniform(ix.index, ctx.uniform_vars, private)
+                or (_affine_expr(ix.index) and not names & changed
+                    and all(ctx.env[v].affine is not None
+                            for v in names if v in ctx.env))):
+            return True
+    return False
+
+
+def _prefix_counts(x):
+    """Inclusive prefix sums of ``int32[n]`` (``n`` a multiple of 128): a
+    row of 128 at a time by a product with a triangle of ones, the rows'
+    totals the same way one level up.  Exact in float32 up to 2**24; the
+    chip's compiler takes half a second over it where ``jnp.cumsum`` of
+    524 288 elements took it 8.6 s (PERF.md, PR 41)."""
+    rows = x.reshape(-1, _ROW).astype(jnp.float32)
+    upto = (lax.broadcasted_iota(jnp.int32, (_ROW, _ROW), 0)
+            <= lax.broadcasted_iota(jnp.int32, (_ROW, _ROW), 1))
+    inc = jnp.dot(rows, upto.astype(jnp.float32),
+                  precision=lax.Precision.HIGHEST).astype(jnp.int32)
+    if inc.shape[0] == 1:
+        return inc.reshape(-1)
+    total = inc[:, -1]
+    m = total.shape[0]
+    before = _prefix_counts(jnp.pad(total, (0, -m % _ROW)))[:m] - total
+    return (inc + before[:, None]).reshape(-1)
+
+
+def _chunk_lanes(entered, width: int) -> Callable:
+    """``c -> int32[width]``: the numbers of the lanes of chunk ``c`` of the
+    lanes set in ``entered``, in rising order; beyond the last of them, lane
+    0 (the caller masks those slots off).  The order is built once: every
+    entering lane's rank among them is a prefix count, and its number goes
+    to that place of the list."""
+    b = entered.shape[0]
+    rank = _prefix_counts(jnp.pad(entered, (0, -b % _ROW)).astype(jnp.int32))[:b]
+    order = jnp.zeros(b + -b % width, jnp.int32).at[
+        jnp.where(entered, rank - 1, -1)].set(
+            jnp.arange(b, dtype=jnp.int32), mode="drop", unique_indices=True)
+    return lambda c: lax.dynamic_slice(order, (c * width,), (width,))
+
+
+def _at_lanes(ctx: _Ctx, v: KVal, lanes) -> KVal:
+    """A local of the launch's lanes at the lanes of a chunk (``ctx.gid`` is
+    the chunk's already): a value affine in the work-item id is computed
+    from it, any other vector picked out lane by lane (a private array along
+    its last axis); what is the same in every lane stays as it is."""
+    if not v.is_vector:
+        return v
+    if (v.affine is not None and type(v.affine[0]) is int
+            and v.value.dtype == jnp.int32):
+        return KVal(_num(ctx.gid) * jnp.int32(v.affine[0]) + v.affine[1], v.ctype)
+    return KVal(v.value.at[..., lanes].get(mode="promise_in_bounds"), v.ctype)
+
+
+def _exec_compacted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
+                    carried_vars: list, carried_bufs: list) -> None:
+    """A masked loop over the lanes that ENTER it (:func:`_compactable` says
+    which loops).  With ``k`` of the launch's ``B`` lanes entering: none,
+    and the loop makes no pass; more than ``_COMPACT_DENSE_SHARE`` of them,
+    and it runs over all lanes (:func:`_exec_masked`, as every other loop
+    does); else over ``ceil(k / W)`` chunks of ``W`` entering lanes, each
+    chunk :func:`_exec_masked` at shape ``(W,)``:
+
+    - the work-item id is data (no affine form): a read ``x[tid]`` is a
+      gather, a store there a scatter of distinct indices; such a read of a
+      buffer the loop does not store to is made ONCE a chunk, before its
+      passes (``ctx.hoisted``);
+    - the locals the loop reads are picked out at the chunk's lanes on the
+      way in; of those it assigns, the ones something reads after the loop
+      (and the buffers riding it as locals: ``ctx.own``) are put back into
+      their ``B``-shaped values on the way out, the others never are;
+    - run windows, kept views, the word view of a byte table and the
+      scatter's ``drop`` work at ``W`` as at ``B``; the slots of the last
+      chunk beyond ``k`` are masked off like any lane that did not enter;
+    - the buffers the loop stores to ride the chunks as they ride the passes.
+
+    The order in which the lanes of a launch run against each other is
+    unspecified (docs/KERNEL_LANGUAGE.md); chunks add nothing a kernel may
+    rely on."""
+    B, W = ctx.B, _COMPACT_WIDTH
+    entered = jnp.broadcast_to(ctx.active_mask(), ctx.shape)
+    k = jnp.sum(entered, dtype=jnp.int32)
+    loop = [body_core, cond_expr, step_stmt]
+    _loop_views(ctx, node, cond_expr, body_core, carried_bufs)
+    ctx.compact_loops += 1
+
+    live = sorted(_live_after(ctx, carried_vars))
+    _carried_at_shape(ctx, carried_vars)
+    ctypes = {v: ctx.env[v].ctype for v in live}
+    needed = sorted((_vars_read(loop) | set(carried_vars)) & set(ctx.env))
+    changed = _assigned_vars(body_core + [step_stmt])
+    hoist = [ix for ix in _index_nodes(loop)
+             if ix.base in ctx.bufs and ix.base not in ctx.private
+             and ix.base not in carried_bufs and ix.base not in ctx.own
+             and _affine_expr(ix.index) and not _vars_read(ix.index) & changed]
+    if ctx.row_gathers:  # their row views too are made once, out here
+        for ix in hoist:
+            if ctx.bufs[ix.base].dtype.itemsize == 4:
+                ctx.rows_view(ix.base)
+
+    def run(state, fn, **at):
+        """``fn()`` with the carried ``state`` (the live locals, the stored
+        buffers) in the context's place and ``at`` replacing fields of the
+        context.  Nothing a branch traced stays behind but what it
+        recorded."""
+        fields = ("B", "shape", "gid", "env", "bufs", "mask", "umask",
+                  "return_mask", "compacting", "hoisted", "_rows_cache")
+        saved = {f: getattr(ctx, f) for f in fields}
+        ctx.env, ctx.bufs = dict(ctx.env), {**ctx.bufs, **state[1]}
+        ctx._rows_cache = dict(ctx._rows_cache)
+        for v in live:
+            ctx.env[v] = KVal(state[0][v], ctypes[v], None)
+        for f, val in at.items():
+            setattr(ctx, f, val)
+        ctx._pad_cache.clear()
+        try:
+            return fn()
+        finally:
+            for f, val in saved.items():
+                setattr(ctx, f, val)
+            ctx._pad_cache.clear()
+
+    def masked():
+        _exec_masked(ctx, node, cond_expr, body_core, step_stmt,
+                     carried_vars, carried_bufs)
+        return ({v: ctx.env[v].value for v in live},
+                {b: ctx.bufs[b] for b in carried_bufs})
+
+    def chunk(lanes, valid, before):
+        ctx.env = {name: _at_lanes(ctx, ctx.env[name], lanes) for name in needed}
+        ctx.hoisted = {id(ix): _load(ctx, ix).value for ix in hoist}
+        into = jnp.where(valid, lanes, B)  # a slot beyond the last: dropped
+        out, bufs = masked()
+        return ({v: before[v].at[..., into].set(out[v], mode="drop")
+                 for v in live}, bufs)
+
+    def compacted(state):
+        lanes_of = _chunk_lanes(entered, W)
+        slot = jnp.arange(W, dtype=jnp.int32)
+
+        def one(c, st):
+            lanes, valid = lanes_of(c), c * W + slot < k
+            return run(st, lambda: chunk(lanes, valid, st[0]), B=W, shape=(W,),
+                       gid=KVal(ctx.offset + lanes, "int"), mask=valid,
+                       umask=None, return_mask=None, compacting=True)
+
+        return lax.fori_loop(0, lax.div(k + (W - 1), jnp.int32(W)), one, state)
+
+    state = ({v: ctx.env[v].value for v in live},
+             {b: ctx.bufs[b] for b in carried_bufs})
+    which = jnp.where(k == 0, 0, jnp.where(k > _COMPACT_DENSE_SHARE * B, 1, 2))
+    env_f, bufs_f = lax.switch(
+        which, [lambda st: st, lambda st: run(st, masked), compacted], state)
+    for v in live:
+        ctx.env[v] = KVal(env_f[v], ctypes[v], None)
+    for b in carried_bufs:
+        ctx.bufs[b] = bufs_f[b]
+        ctx.stored.add(b)
 
 
 # ---------------------------------------------------------------------------
@@ -2674,6 +2905,12 @@ class KernelBuildInfo:
     tile_rows: int = 0
     tile_grid: int = 0
     loop_live: int = 0
+    # lane compaction (:func:`_exec_compacted`), filled at trace: ``(loops,
+    # width, gathered, scattered)``, the masked loops made compactable, the
+    # lanes of a chunk, and the reads and stores at the lane's own element
+    # (a slice on the dense path) that a chunk lowers as a gather and as a
+    # scatter; ``()`` where no loop of the build was made compactable
+    compact: tuple = ()
 
 
 def hlo_name(*kernel_names: str) -> str:
@@ -2753,6 +2990,10 @@ def build_kernel_fn(
             info.access[kind] += 1
         info.access["carried"] = len(ctx.carried)
         info.scattered = tuple(ctx.scattered)
+        own = [kind for site, kind in ctx.compact_access.items()
+               if ctx.access.get(site) != kind]
+        info.compact = (ctx.compact_loops, _COMPACT_WIDTH, own.count("gather"),
+                        own.count("scatter")) if ctx.compact_loops else ()
         info.views = tuple(sorted(
             ViewSpec(info.array_params.index(name), kind)
             for name, kind in ctx.asked))

@@ -504,7 +504,11 @@ def lowering_meta(infos) -> dict:
     words of run-time scalars that crossed to the device in one vector a
     dispatch, and the Python or numpy scalars that crossed one by one.
     ``scatter`` (``stores:2;width:4+1``), where a kernel's build has any: the
-    stores lowered to a scatter and the bytes of one element of each."""
+    stores lowered to a scatter and the bytes of one element of each.
+    ``compact`` (``loops:1;width:8192;gathered:3;scattered:0``), where a
+    build made a masked loop compactable (``codegen._exec_compacted``): the
+    loops, the lanes of a chunk, and the reads and stores at the lane's own
+    element that a chunk lowers as gathers and scatters."""
     infos = list(infos)
     leaves = [r for i in infos for r in (i.rungs or (i,))]
     meta = {"lowering": "+".join(sorted({i.lowering for i in leaves})),
@@ -550,6 +554,18 @@ def lowering_meta(infos) -> dict:
         widths = [w for name in sorted(scattered) for w in scattered[name]]
         meta["scatter"] = (f"stores:{len(widths)};"
                            f"width:{'+'.join(str(w) for w in widths)}")
+    # the loops made compactable: of a kernel's rungs the most (a rung no
+    # wider than a chunk compacts nothing), summed over the kernels
+    compact: dict = {}
+    for i in leaves:
+        if i.compact:
+            compact[i.name] = max(compact.get(i.name, ()), i.compact)
+    if compact:
+        loops, widths, gathered, scattered = zip(*compact.values())
+        meta["compact"] = (
+            f"loops:{sum(loops)};"
+            f"width:{'+'.join(str(w) for w in sorted(set(widths)))};"
+            f"gathered:{sum(gathered)};scattered:{sum(scattered)}")
     keyed = sorted({f"{k}={v}" for i in leaves for k, v in i.keyed.items()})
     if keyed:
         meta["keys"] = ";".join(keyed)

@@ -667,9 +667,24 @@ def _bfs_traversal(devices, sizes) -> dict:
                  and meta["scatter"] == "stores:2;width:4+1",
                  f"BFS: access {meta['access']}, scatter "
                  f"{meta.get('scatter')}")
+        # a first rung wider than a chunk builds BFS_1's adjacency loop
+        # compactable (PR 41): the reads at tid are the chunks' gathers
+        from cekirdekler_tpu.kernel import codegen
+
+        width = codegen._COMPACT_WIDTH
+        want_field = (f"loops:1;width:{width};gathered:3;scattered:0"
+                      if launch_ladder(n, lr)[0] > width else None)
+        _require(meta.get("compact") == want_field,
+                 f"BFS: compact {meta.get('compact')}, expected {want_field}")
+        # a level's wall (one synchronous compute: the device's time and a
+        # byte each way) beside what the parent of PR 41 took at this size:
+        # 10 levels in 0.141 s at 65 536 nodes (my chip run, PR 40)
         return _row("BFS traversal compute()", meta["lowering"], cold_s,
                     run_s, float(differing), levels=levels,
                     access=meta["access"], scatter=meta["scatter"],
+                    compact=meta.get("compact", ""),
+                    level_ms=round(1e3 * run_s / levels, 3),
+                    level_ms_pr40_at_65536=14.1,
                     flag_bytes_up=up - 7 * n, flag_bytes_back=down - 4 * n)
     finally:
         cr.dispose()

@@ -200,6 +200,42 @@ def test_the_access_field_counts_the_scatters_and_the_row_loop_is_a_run():
     assert "scatter:2" in meta["access"]
     assert meta["scatter"] == "stores:2;width:4+1"
     assert "scatter" not in lowering_meta([two])
+    # a launch of 4096 lanes is no wider than a chunk: nothing to compact
+    assert one.compact == two.compact == () and "compact" not in meta
+
+
+def test_the_compact_field_names_the_loop_and_leaves_the_access_field():
+    """ISSUE 41: a launch wider than a chunk builds ``BFS_1``'s adjacency
+    loop compactable.  The ``access`` field keeps counting the kernel's
+    SITES by the kinds the dense path gives them (the same two scatters,
+    the same two gathers); what a chunk of compacted lanes turns into
+    gathers of its own, the reads at ``tid`` (``g_no_of_edges[tid]``,
+    ``g_starting[tid]``, ``g_cost[tid]``), is the ``compact`` field's."""
+    from cekirdekler_tpu.kernel import codegen
+
+    width = codegen._COMPACT_WIDTH
+    n = 4 * width
+    nodes = n - 192
+    data = graph(nodes)
+    assert data["mask"].size == n
+    arrays = tuple(jax.numpy.asarray(data[k]) for k in NAMES)
+    prog = KernelProgram(SRC)
+    small, big = {}, {}
+    for name in ("BFS_1", "BFS_2"):
+        for infos, chunk in ((small, width), (big, n)):
+            fn, infos[name] = prog.launcher(name, chunk, 256, n, platform="cpu")
+            fn(0, arrays, (nodes,))
+    assert big["BFS_1"].compact == (1, width, 3, 0)
+    assert small["BFS_1"].compact == big["BFS_2"].compact == ()
+    assert big["BFS_1"].access == small["BFS_1"].access
+    assert big["BFS_1"].scattered == (4, 1)
+    # a span over the ladder's rungs of both kernels
+    meta = lowering_meta(list(small.values()) + list(big.values()))
+    assert meta["compact"] == f"loops:1;width:{width};gathered:3;scattered:0"
+    assert "," not in meta["compact"]
+    for kind in ("scatter:2", "gather:2", "uniform:1"):
+        assert kind in meta["access"].split(";")
+    assert "compact" not in lowering_meta([big["BFS_2"], small["BFS_1"]])
 
 
 @pytest.mark.parametrize("dtype", [np.int8, np.uint8])
@@ -319,6 +355,8 @@ def test_both_kernels_compile_for_the_chip_at_the_cells_size(one_chip):
         compiled = lowered.compile()
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
         assert info.lowering == "xla" and info.veto
+        # the rung is wider than a chunk: BFS_1's loop compiled both ways
+        assert bool(info.compact) == (name == "BFS_1")
 
 
 UNIFORM = """

@@ -83,6 +83,8 @@ def test_stage_compute(devs):
     assert bfs["name"] == "BFS traversal compute()" and bfs["max_err"] == 0.0
     assert bfs["lowering"] == "xla" and bfs["levels"] >= 4
     assert bfs["scatter"] == "stores:2;width:4+1"
+    # the toy's launch is no wider than a chunk: nothing compactable
+    assert bfs["compact"] == "" and bfs["level_ms"] > 0
     assert bfs["flag_bytes_up"] == bfs["flag_bytes_back"] == bfs["levels"]
 
 

@@ -853,6 +853,73 @@ def test_a_synchronous_computes_launch_span_counts_its_packed_scalars(
     assert [str(st["tag"]) for st in launches] == ["mandelbrot x1"] * 2
 
 
+def test_a_compactable_loops_spans_carry_the_compact_field(tmp_path):
+    """ISSUE 41: Rodinia's ``BFS_1`` over a launch wider than a chunk builds
+    its adjacency loop both ways, and the ``ck/launch`` / ``ck/compile``
+    spans that ran the build say so (``compact=loops:1;width:W;gathered:3;
+    scattered:0``); ``BFS_2`` has no loop and its spans no such field.  The
+    ``access`` field stays as PR 40 left it."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from cekirdekler_tpu.kernel import codegen
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(os.path.dirname(here), "benchmark", "configs",
+                           "rodinia_bfs.cl"), encoding="utf-8") as f:
+        src = f.read()
+    width = codegen._COMPACT_WIDTH
+    n = 2 * width
+    rng = np.random.default_rng(7)
+    host = {"starting": 2 * np.arange(n, dtype=np.int32),
+            "no_of_edges": np.full(n, 2, np.int32),
+            "edges": rng.integers(0, n, 2 * n).astype(np.int32),
+            "mask": (rng.random(n) < 0.05).astype(np.int8),
+            "updating": np.zeros(n, np.int8),
+            "visited": (rng.random(n) < 0.5).astype(np.int8),
+            "cost": np.zeros(n, np.int32), "over": np.zeros(1, np.int8)}
+    arr = {k: ClArray(v, name=k) for k, v in host.items()}
+    arr["over"].write_all = True
+    first, *rest = arr.values()
+    group = first.next_param(*rest)
+    cr = NumberCruncher(_cpus(1), src)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for kernels in ("BFS_1 BFS_2", "BFS_1 BFS_2", "BFS_2"):
+            group.compute(cr, 4101, kernels, n, 256, values=(n,))
+    finally:
+        jax.profiler.stop_trace()
+        cr.dispose()
+    path = [os.path.join(r, f) for r, _d, fs in os.walk(str(tmp_path))
+            for f in fs if f.endswith(".xplane.pb")][0]
+    spans = [(ev.name, dict(ev.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events
+             if ev.name in ("ck/launch", "ck/compile")]
+    field = f"loops:1;width:{width};gathered:3;scattered:0"
+    launches = [st for name, st in spans if name == "ck/launch"]
+    compiles = [st for name, st in spans if name == "ck/compile"]
+    with_loop = [st for st in launches if "BFS_1" in str(st["tag"])]
+    without = [st for st in launches if "BFS_1" not in str(st["tag"])]
+    assert with_loop and without
+    assert {st.get("compact") for st in with_loop} == {field}
+    assert all("compact" not in st for st in without)
+    for st in with_loop:
+        kinds = str(st["access"]).split(";")
+        assert {"scatter:2", "gather:2"} <= set(kinds)
+        assert st["scatter"] == "stores:2;width:4+1"
+    assert any("uniform:1" in str(st["access"]).split(";") for st in without)
+    built = {str(st["tag"]).split()[0]: st for st in compiles
+             if f"chunk={n} " in str(st["tag"])}
+    assert set(built) == {"BFS_1", "BFS_2"}
+    assert built["BFS_1"]["compact"] == field
+    assert all("compact" not in st for st in compiles
+               if str(st["tag"]).startswith("BFS_2"))
+
+
 def test_mosaic_launch_carries_the_kernels_name():
     """The device operation and the XLA module are named after the user's
     kernel: the lowering for a TPU names the Mosaic call ``inc`` and the
