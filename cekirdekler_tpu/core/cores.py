@@ -2870,51 +2870,54 @@ class Cores:
 
     def _start_deferred_downloads(self, pending, lock_each: bool) -> list:
         """Start async downloads for the newest record per (worker,
-        array) in chronological order — ONE code path for flush() (which
-        takes each worker's phase lock per record: another host thread's
-        lane may be mid-phase replacing buffer entries) and the atomic
+        array) — ONE code path for flush() (which takes each worker's
+        phase lock around that lane's issue: another host thread's lane
+        may be mid-phase replacing buffer entries) and the atomic
         rebalance flush (whose caller already holds every worker
-        lock).  Returns ``(handle, worker, cid)`` entries for
-        :meth:`_finish_deferred`."""
-        handles = []
-
-        def add(h, w, cid):
-            handles.append((h, w, cid))
-
-        for _, w, p, offset, size, write_all, cid in self._latest_records(
-            pending
-        ):
-            epw = p.flags.elements_per_work_item
+        lock).  Every slice is known before the first is issued, so a
+        lane's share goes out at once: ONE dispatch a lane cuts all its
+        records into pieces of one fixed length
+        (``Worker.download_slices_async``), whatever the split — no
+        executable is keyed on a range the balancer chose.  The pieces
+        are the streamed drain's chunks: a piece's host memcpy
+        (finish_download) overlaps the NEXT pieces' still-in-flight D2H
+        instead of the whole fence draining at once.  The lanes go one
+        after another on the caller's thread (from the lanes' own
+        threads the four issues overlap and the join waits that much
+        longer for the same bytes: PERF.md s.6, PR 42).  Returns
+        ``(handle, worker, cid)`` entries for :meth:`_finish_deferred`
+        in the records' CHRONOLOGICAL order, whatever the order of
+        issue: finish order is the order of the host writes."""
+        records = self._latest_records(pending)
+        by_lane: dict[Worker, list[int]] = {}
+        for at, rec in enumerate(records):
+            by_lane.setdefault(rec[1], []).append(at)
+        handles: list[list] = [[] for _ in records]
+        marks = []
+        for w, mine in by_lane.items():
+            t0 = time.perf_counter()
             with (w.lock if lock_each else nullcontext()):
-                if write_all:
-                    add(w.download_async(p, 0, p.size, True), w, cid)
-                    continue
-                # streamed drain: a large deferred record splits into
-                # chunks so a chunk's host memcpy (finish_download)
-                # overlaps the NEXT chunks' still-in-flight D2H instead
-                # of the whole fence draining at once.  finish order is
-                # issue order, so host writes stay chronological.
-                chunks = 1
-                if self.streamed_transfers and size > 1:
-                    nbytes = size * epw * p.host().dtype.itemsize
-                    chunks = self.stream_chunks or self.transfer_tuner.choose(
-                        w.index, "flush-d2h", nbytes, size,
-                        has_compute=False,
-                    )
-                if chunks > 1:
-                    for coff, csz in chunk_plan(size, 1, chunks):
-                        add(
-                            w.download_chunk_async(
-                                p, (offset + coff) * epw, csz * epw
-                            ),
-                            w, cid,
-                        )
-                else:
-                    add(
-                        w.download_async(p, offset * epw, size * epw, False),
-                        w, cid,
-                    )
-        return handles
+                got, dispatches = w.download_slices_async([
+                    (p, offset * p.flags.elements_per_work_item,
+                     size * p.flags.elements_per_work_item, write_all)
+                    for _, _, p, offset, size, write_all, _ in (
+                        records[at] for at in mine)])
+            for at, hs in zip(mine, got):
+                handles[at] = hs
+            marks.append((w.index, dispatches, [h for hs in got for h in hs],
+                          time.perf_counter() - t0))
+        if TRACER.active():
+            # one mark a lane, where the issue ends (just before
+            # ``part:join``, so that ``issue`` stays one stretch of the
+            # caller's thread): what the lane was handed
+            for lane, dispatches, issued, issue_s in sorted(
+                    marks, key=lambda m: m[0]):
+                TRACER.instant("resync", lane=lane, tag="part:lane",
+                               dispatches=dispatches, pieces=len(issued),
+                               bytes=sum(h[1].nbytes for h in issued),
+                               issue_us=round(issue_s * 1e6, 1))
+        return [(h, rec[1], rec[6])
+                for rec, hs in zip(records, handles) for h in hs]
 
     def _finish_deferred(self, entries, iters: dict[int, int]) -> None:
         """Join the flush's D2H handles in issue order, timing each

@@ -60,8 +60,10 @@ Two kinds of keys, two first-contact rules:
   :meth:`on_repartition` — and every model flip back to 1 chunk —
   re-measures.)
 
-* **No-compute keys** (``has_compute=False`` — the flush drain's pure
-  D2H records): nothing to measure serially, so the duplex-probe seed
+* **No-compute keys** (``has_compute=False`` — a pure transfer with no
+  kernel behind it; the flush drain asked this way until it took pieces
+  of one fixed length, ``Worker.download_slices_async``): nothing to
+  measure serially, so the duplex-probe seed
   (:meth:`seed_link`, ms/MiB each direction) drives the model directly;
   with no seed either, transfers of at least :data:`BOOTSTRAP_BYTES` get
   :data:`BOOTSTRAP_CHUNKS` chunks and smaller ones stay monolithic.

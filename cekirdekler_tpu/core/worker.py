@@ -56,6 +56,32 @@ def _slice_out(buf, off, size: int):
     return lax.dynamic_slice(buf, (jnp.asarray(off, jnp.int32),), (size,))
 
 
+#: bytes a piece of the flush's batched read-back holds: the pieces are the
+#: chunked drain's chunks (a piece's host copy overlaps the next pieces'
+#: crossing), and ONE length for every split is what lets the executables a
+#: warm process holds serve whatever the balancer chooses.  4 MiB: a piece
+#: costs the link ~0.45 ms before its first byte, so four lanes' 16 MiB are
+#: on the host in 4.5 ms in pieces of 4 MiB against 6.1 in pieces of 1 MiB
+#: (PERF.md s.6, PR 42), and a whole piece takes finish_download's native
+#: parallel copy
+PIECE_BYTES = 4 << 20
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _slice_pieces(bufs, offs, counts: tuple, lengths: tuple):
+    """Every piece of a lane's flush in ONE program: ``counts[j]`` pieces
+    of ``lengths[j]`` elements each out of ``bufs[j]``, at the run-time
+    offsets ``offs`` (one ``int32`` vector for all of them, numpy handed
+    to the call: no Python scalar crosses).  Only the buffers' shapes, the
+    counts and the lengths key the executable; a split never does."""
+    out, at = [], 0
+    for buf, k, c in zip(bufs, counts, lengths):
+        out.append(tuple(
+            lax.dynamic_slice(buf, (offs[at + i],), (c,)) for i in range(k)))
+        at += k
+    return tuple(out)
+
+
 @jax.jit
 def _update_slice(buf, sl, off):
     return lax.dynamic_update_slice(buf, sl, (jnp.asarray(off, jnp.int32),))
@@ -320,6 +346,11 @@ class Worker:
         self._cid_last_out: dict[int, Any] = {}
         # the ladder's run-time scalars kept on the device: value -> array
         self._ladder_scalars: dict[int, Any] = {}
+        # array-object -> pieces the batched read-back cuts its share into
+        # (download_slices_async): a power of two that only grows, so a
+        # lane compiles log2 programs an array at most, in warm-up
+        # ckcheck: ok single-writer: the flush issues under self.lock
+        self._piece_counts: dict[int, int] = {}
         # coverage epoch: bumped by every reset_coverage().  The fused
         # dispatch path (core/cores.py) snapshots it at window engage and
         # compares one int per deferral instead of re-walking per-array
@@ -634,6 +665,7 @@ class Worker:
         self._buffers.pop(id(arr), None)
         self._buffer_owner.pop(id(arr), None)
         self._uploaded.pop(id(arr), None)
+        self._piece_counts.pop(id(arr), None)
 
     def reset_coverage(self) -> None:
         """Forget what has been uploaded WITHOUT dropping device buffers:
@@ -1021,10 +1053,77 @@ class Worker:
             arr, offset_elems, size_elems, False, kind="download-chunk"
         )
 
+    def download_slices_async(self, records) -> tuple[list, int]:
+        """The flush's read-back of this lane, issued at once: ``records``
+        are ``(arr, offset_elems, size_elems, full)``, ALL known before
+        the first is issued (unlike the streamed per-call path, which
+        issues a chunk when its kernel has been dispatched).  Every sliced
+        record is cut into pieces of ONE length (:data:`PIECE_BYTES`, the
+        buffer's own length where that is shorter) by ONE dispatch of
+        :func:`_slice_pieces` for the whole lane; the pieces a record
+        needs start their copy to the host, the rest (the count is a power
+        of two that only grows) stay on the device.  A piece whose window
+        would pass the buffer's end starts earlier, and every piece's
+        handle ends with which of its elements are the record's (``skip``,
+        ``take``): :meth:`finish_download` writes exactly ``[offset,
+        offset + size)`` of each record.  A ``full`` record
+        (``write_all``) is :meth:`download_async`'s.  Returns one list of
+        handles a record (a handle's second element is the array that
+        crosses) and the dispatches made.  The caller holds this lane's
+        lock."""
+        out: list[list] = [[] for _ in records]
+        bufs, counts, lengths, starts, cuts = [], [], [], [], []
+        for at, (arr, off, size, full) in enumerate(records):
+            if full:
+                out[at].append(self.download_async(arr, 0, arr.size, True))
+            elif size > 0:
+                buf = self._buffers[id(arr)]
+                n = buf.shape[0]
+                c = min(n, max(1, PIECE_BYTES // buf.dtype.itemsize))
+                wanted = range(off, off + size, c)
+                k = max(self._piece_counts.get(id(arr), 1),
+                        1 << (len(wanted) - 1).bit_length())
+                self._piece_counts[id(arr)] = k
+                first = [min(want, n - c) for want in wanted]
+                starts += first + first[-1:] * (k - len(first))
+                cuts.append((at, arr, [
+                    (want, want - start, min(c, off + size - want))
+                    for want, start in zip(wanted, first)]))
+                bufs.append(buf)
+                counts.append(k)
+                lengths.append(c)
+        if bufs:
+            pieces = _slice_pieces(tuple(bufs), np.asarray(starts, np.int32),
+                                   tuple(counts), tuple(lengths))
+            active = TRACER.active()
+            for (at, arr, parts), got in zip(cuts, pieces):
+                chunked = len(parts) > 1
+                kind = "download-chunk" if chunked else "download"
+                for piece, (want, skip, take) in zip(got, parts):
+                    if chunked:
+                        self._m_d2h_chunks.inc()
+                    if self.markers is not None:
+                        self.markers.add()
+                    try:
+                        piece.copy_to_host_async()
+                    except Exception:
+                        pass
+                    t_issued = time.perf_counter()
+                    if active:  # the record's own bytes, as ``landed`` says
+                        TRACER.instant(
+                            kind, lane=self.index, tag="part:issued",
+                            bytes=take * piece.dtype.itemsize,
+                            name=arr.name, off=want)
+                    out[at].append((
+                        arr, piece, want, self.markers, self.index,
+                        self._m_download_bytes, kind,
+                        self._m_download_seconds, t_issued, skip, take))
+        return out, int(bool(bufs))
+
     @staticmethod
     def finish_download(handle) -> None:
         (arr, out, off, markers, lane, byte_counter, kind, seconds,
-         t_issued) = handle
+         t_issued, *cut) = handle
         _tt = TRACER.t0(kind)
         # capture the fault-plane state ONCE: a plane armed mid-call
         # would otherwise pair delay_s with the 0.0 sentinel t0 and
@@ -1033,6 +1132,8 @@ class Worker:
         _ft0 = time.perf_counter() if _faults else 0.0
         host = arr.host()
         data = np.asarray(out)
+        if cut:  # a piece of the batched read-back: the record's elements
+            data = data[cut[0] : cut[0] + cut[1]]
         # landed: the bytes are in host memory (jax's own); from here to
         # the span's end is the copy into the caller's array
         seconds.observe(time.perf_counter() - t_issued)
@@ -1133,6 +1234,7 @@ class Worker:
         self.transfer_benchmarks.clear()
         self._cid_last_out.clear()
         self._ladder_scalars.clear()
+        self._piece_counts.clear()
         if self.markers is not None:
             self.markers.close()
             self.markers = None

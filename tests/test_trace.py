@@ -591,10 +591,18 @@ def test_barrier_marks_its_parts_and_each_lane_its_retirement(profiled):
 def test_range_move_marks_the_parts_of_its_resync(profiled):
     (resync,), _ = _inside(profiled, "test/moved", "resync")
     tags, marks = _marks_in(profiled, resync, "resync")
-    assert tags == ["part:locks", "part:issue", "part:join", "part:reset"]
+    assert tags == ["part:locks", "part:issue", "part:lane", "part:lane",
+                    "part:join", "part:reset"]
+    # one mark a lane where the issue ends (ISSUE 42): what the lane was
+    # handed, by ONE dispatch whatever its share
+    lanes = marks[2:4]
+    assert [e.stats["lane"] for e in lanes] == [0, 1]
+    assert all(e.stats["dispatches"] == 1 and e.stats["pieces"] == 1
+               and e.stats["bytes"] > 0 and e.stats["issue_us"] >= 0
+               and e.line == resync.line for e in lanes)
     # the waits keep their own spans, inside ``join``
     down, _ = _inside(profiled, "test/moved", "download")
-    join, reset = marks[2], marks[3]
+    join, reset = marks[4], marks[5]
     assert down and all(join.t0 <= e.t0 and e.t1 <= reset.t0 for e in down)
 
 
