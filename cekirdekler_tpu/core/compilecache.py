@@ -58,6 +58,8 @@ import time
 from dataclasses import dataclass
 
 from ..metrics.registry import REGISTRY
+from ..obs.decisions import DECISIONS
+from ..obs.flight import FLIGHT
 from .stream import plan_signature
 from .worker import launch_ladder
 
@@ -610,6 +612,200 @@ class CompileCache:
 #: Process singleton: root re-resolves from ``CK_COMPILE_CACHE`` per
 #: operation, so tests and operators arm/disarm via the environment.
 CACHE = CompileCache()
+
+
+def warm_targets(workers) -> list:
+    """Distinct (platform, donate, device_kind, device) combinations
+    across a scheduler's lanes — the set of fused-launcher key variants
+    the live path can request.  ``donate`` is the lane's own
+    ``Worker.fused_donate``: a warmed key that differs in any component
+    is a silent no-op."""
+    seen: dict = {}
+    for w in workers:
+        platform = w.device.platform
+        donate = w.fused_donate
+        kind = str(getattr(w.device, "device_kind", platform))
+        seen.setdefault((platform, donate, kind), w.device)
+    return [(p, d, k, dev) for (p, d, k), dev in seen.items()]
+
+
+def warmup(program, workers, plan) -> dict:
+    """AOT-precompile a workload plan's full predicated launch ladders
+    BEFORE traffic arrives (``Cores.warmup``: ``ServeFrontend.warmup``,
+    the fabric's warm-on-join, and the elastic rejoin all route here).
+
+    ``plan`` is an iterable of :class:`WarmupSpec` (or anything with the
+    job surface ``kernels/params/global_range/local_range/values`` — e.g.
+    ``serve.ServeJob``; live params are read for size/dtype only, NEVER
+    executed against).  Per distinct spec, per distinct lane (platform,
+    donate) variant, this builds and EXECUTES on scratch buffers:
+
+    - the fused predicated-ladder executable under the EXACT key the
+      live fused window requests (``KernelProgram.fused_launcher``
+      9-tuple — executing it also fills jax's in-process dispatch
+      cache, so the first live call is a cache hit end to end), and
+    - every per-call chunk launcher ``step·2^k`` up to the global
+      range (any balancer split's per-lane ladder is a subset).
+
+    With ``CK_COMPILE_CACHE`` armed, each spec's ladder key is looked up
+    in the on-disk manifest (hit/miss counted + ``ck_compile_cache_*``
+    metrics), misses are persisted for other processes, and the XLA
+    compiles triggered here are served from / written to JAX's
+    persistent compilation cache — a joining shard warms from disk
+    instead of recompiling.  Unarmed, the disk layer is skipped entirely
+    and results stay bit-identical.
+
+    Emits one ``cache-warmup`` flight event + context decision per plan
+    (key set, hit/miss split, wall).  Returns ``{"warmed", "hits",
+    "misses", "skipped", "wall_s", "kinds"}``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    t0 = time.perf_counter()
+    if CACHE.enabled:
+        CACHE.arm()
+    specs: list = []
+    seen_specs: set = set()
+    skipped = 0
+    for item in plan:
+        if isinstance(item, WarmupSpec):
+            spec = item
+        else:
+            try:
+                spec = WarmupSpec.from_job(
+                    item.kernels, item.params,
+                    getattr(item, "compute_id", 0), item.global_range,
+                    item.local_range,
+                    getattr(item, "global_offset", 0),
+                    getattr(item, "values", ()),
+                )
+            except Exception:  # noqa: BLE001 - unwarmable job shape
+                skipped += 1
+                continue
+        ident = (spec.kernels, spec.params, spec.global_range,
+                 spec.local_range, spec.values)
+        if ident in seen_specs:
+            continue
+        seen_specs.add(ident)
+        if (spec.local_range <= 0
+                or spec.global_range % spec.local_range != 0
+                or not all(n in program for n in spec.kernels)):
+            skipped += 1
+            continue
+        specs.append(spec)
+
+    hits = misses = 0
+    keys: list[str] = []
+    # per-device-kind ladder count: the mixed-fleet warmup proof —
+    # every kind present in the lane set gets its own AOT pass
+    kinds: dict[str, int] = {}
+    for spec in specs:
+        step = spec.local_range
+        units = spec.global_range // step
+        vals = spec.value_args()
+
+        def vals_for(name, _v=vals):
+            if isinstance(_v, dict):
+                return tuple(_v.get(name, ()))
+            return tuple(_v)
+
+        for platform, donate, device_kind, device in warm_targets(workers):
+            kinds[device_kind] = kinds.get(device_kind, 0) + 1
+            key = None
+            hit = False
+            if CACHE.enabled:
+                key = CACHE.ladder_key(
+                    program, spec, platform, donate, device_kind)
+                keys.append(key)
+                hit = CACHE.lookup(key)
+            bufs = tuple(
+                jnp.zeros(n, dtype=np.dtype(d), device=device)
+                for n, d in spec.params
+            )
+            # the fused predicated ladder, under the live path's key
+            fn = program.fused_launcher(
+                tuple(spec.kernels), step, spec.global_range,
+                spec.local_range, spec.global_range, vals,
+                platform=platform, donate=donate,
+            )
+            if fn is not None:
+                # the run-time scalars as the live path hands them
+                # over (Worker.ladder_scalars): int32 arrays on the
+                # lane's device, the argument types of the executable
+                out = fn(*(jax.device_put(np.int32(v), device)
+                           for v in (0, units, 1)), bufs)
+                jax.block_until_ready(out)
+                bufs = tuple(out)  # donate consumed the scratch set
+            # every per-call chunk the binary ladder can emit
+            nbits = max(1, units.bit_length())
+            for name in dict.fromkeys(spec.kernels):
+                n_arr = program.array_param_count(name)
+                va = vals_for(name)
+                for k in range(nbits):
+                    chunk = step << k
+                    if chunk > spec.global_range:
+                        break
+                    try:
+                        f2, _info = program.launcher(
+                            name, chunk, spec.local_range,
+                            spec.global_range, platform)
+                        jax.block_until_ready(
+                            f2(0, bufs[:n_arr], va))
+                    except TypeError:
+                        break  # unhashable static values: skip name
+            if CACHE.enabled:
+                if hit:
+                    hits += 1
+                else:
+                    misses += 1
+                    CACHE.record(key, spec, platform, donate,
+                                 device_kind)
+    wall_s = time.perf_counter() - t0
+    FLIGHT.event(
+        "cache-warmup", warmed=len(specs), hits=hits, misses=misses,
+        skipped=skipped, wall_ms=round(wall_s * 1e3, 3),
+        cache=CACHE.enabled, kinds=dict(kinds),
+    )
+    if DECISIONS.enabled:
+        # context record (reads the filesystem: provenance, not
+        # oracle) — which keys this plan warmed, from which split
+        DECISIONS.record("cache-warmup", {
+            "specs": [s.to_payload() for s in specs],
+            "cache_enabled": CACHE.enabled,
+            "cache_root": CACHE.root,
+        }, {
+            "warmed": len(specs), "hits": hits, "misses": misses,
+            "skipped": skipped, "keys": keys,
+            "wall_ms": round(wall_s * 1e3, 3),
+            "kinds": dict(kinds),
+        })
+    return {"warmed": len(specs), "hits": hits, "misses": misses,
+            "skipped": skipped, "wall_s": wall_s,
+            "kinds": dict(kinds)}
+
+
+def record_engaged(program, workers, run) -> None:
+    """Persist an engaged fused window's ladder spec so OTHER processes
+    can warm it from disk (the fleet's live signature mix IS the cache's
+    content).  ``run`` carries ``kernel_names``, ``params``,
+    ``compute_id``, ``global_range``, ``local_range``, ``value_args``.
+    Cold path — once per distinct key per process (the ``_seen`` set
+    bounds disk probes); best-effort and torn-tolerant like every cache
+    write."""
+    try:
+        spec = WarmupSpec.from_job(
+            run.kernel_names, run.params, run.compute_id,
+            run.global_range, run.local_range, 0, run.value_args)
+        for platform, donate, device_kind, _dev in warm_targets(workers):
+            key = CACHE.ladder_key(
+                program, spec, platform, donate, device_kind)
+            if key in CACHE._seen:
+                continue
+            if not CACHE.lookup(key, count=False):
+                CACHE.record(key, spec, platform, donate, device_kind)
+    except Exception:  # noqa: BLE001 - cache is never load-bearing
+        pass
 
 
 def warm_from_disk(cores, cache: CompileCache | None = None) -> dict:

@@ -146,7 +146,7 @@ class NumberCruncher:
     def fused_dispatch(self, v: bool) -> None:
         if not v and self.cores.fused_dispatch:
             # an open window must not outlive the toggle
-            self.cores._fused_close()
+            self.cores._window.close()
         self.cores.fused_dispatch = bool(v)
 
     @property

@@ -8,7 +8,7 @@ one compute id's partition the upload was a single monolithic
 ``jax.device_put`` that had to fully land before the first ladder chunk
 launched, and the download drained everything at once.  This module is
 the planning half of the fix (the execution half is
-``Cores._run_streamed`` + the Worker chunk primitives):
+``Phases._streamed`` + the Worker chunk primitives):
 
 - :func:`chunk_plan` cuts a lane's range into ``step·2^k`` chunks —
   the SAME geometry the compile-once launch ladder uses, so every

@@ -157,7 +157,7 @@ class _DriverQueue:
         the armed ``driver-submit`` fault point and any pending closure
         error both raise HERE.  The fused batch dispatch preflights
         EVERY lane before queuing ANY lane's closure
-        (``Cores._dispatch_fused``), so a fault fired at this stage
+        (``Window._dispatch``), so a fault fired at this stage
         leaves device iteration counts undiverged — the serving tier's
         blast-radius containment can re-dispatch the residue bit-exactly
         (``FusedBatchError.clean``).
@@ -316,7 +316,7 @@ class Worker:
         # phase wall: per-phase H2D staging + D2H materialization in the
         # immediate paths (telemetry — a subset of the same wall the
         # compute bench carries), and the lane's share of the enqueue
-        # FLUSH drain (Cores._finish_deferred — where the balancer's
+        # FLUSH drain (Sync.finish_deferred — where the balancer's
         # transfer floor genuinely binds: steady-state enqueue benches
         # exclude transfers entirely).  Fed into
         # core/balance.load_balance(transfer_ms=...) so lanes with
@@ -362,7 +362,7 @@ class Worker:
         # depth-limited per-device dispatch driver (fused path); lazy —
         # workers outside the fused path never start the thread
         self._driver: _DriverQueue | None = None
-        # SECOND driver for the streamed-transfer path (Cores._run_streamed):
+        # SECOND driver for the streamed-transfer path (Phases._streamed):
         # its closures run while the submitting thread HOLDS this worker's
         # phase lock, so they must never take worker locks — sharing the
         # fused driver would let a fused closure (which does take w.lock)

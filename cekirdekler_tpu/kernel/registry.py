@@ -833,7 +833,7 @@ class KernelProgram:
         item's own elements (gathered, uniform, shifted, strided): a store
         of one chunk's launch may land in another chunk's elements.  The
         STREAM engine moves such an array whole, before the first launch
-        and after the last (``Cores._run_streamed``): a chunk uploaded
+        and after the last (``Phases._streamed``): a chunk uploaded
         behind the launch that scattered into it would bury the store, a
         chunk downloaded before a later launch's store would miss it.
         ``epws``: the parameters' elements per work item, by position.

@@ -197,30 +197,9 @@ class DebugServer:
         }
         cores = self.cores
         if cores is not None:
-            with cores._lock:
-                enq = {
-                    "enqueue_mode": cores.enqueue_mode,
-                    "active_cids": sorted(cores._enqueue_cids),
-                    "cid_order": list(cores._enqueue_cid_order),
-                    "iters": dict(cores._enqueue_iters),
-                    "window_age_s": (
-                        round(time.perf_counter() - cores._enqueue_t0, 6)
-                        if cores._enqueue_t0 is not None else None
-                    ),
-                    "fused_window_open": cores._fused_sig is not None,
-                    "fused_pending": cores._fused_pending,
-                }
-                shares = {
-                    cid: list(r) for cid, r in cores.global_ranges.items()
-                }
-                fused = {
-                    "windows": cores.fused_stats["windows"],
-                    "fused_iters": cores.fused_stats["fused_iters"],
-                    "deferred_iters": cores.fused_stats["deferred_iters"],
-                    "disengaged": dict(cores.fused_stats["disengaged"]),
-                    "window_starts": dict(
-                        cores.fused_stats["window_starts"]),
-                }
+            # one consistent copy of the enqueue window, its shares and
+            # the fused counters, taken under the scheduler lock
+            snap = cores._window.snapshot()
             lanes = []
             for w in cores.workers:
                 lanes.append({
@@ -241,9 +220,7 @@ class DebugServer:
             doc.update({
                 "devices": cores.device_names(),
                 "lanes": lanes,
-                "shares": {str(c): r for c, r in shares.items()},
-                "enqueue_window": enq,
-                "fused": fused,
+                **snap,
                 "stream_tuner": {
                     "retunes": cores.transfer_tuner.retunes,
                     "lane_overhead_ms": {

@@ -38,7 +38,7 @@ Detector math (pinned by ``tests/test_obs.py``):
   ``ck_lane_health{lane}`` gauge on every window close.
 
 Integration (core/cores.py): ``Cores`` owns one monitor; the barrier
-feeds per-lane fence walls, ``_note_transfer``/``_finish_deferred`` feed
+feeds per-lane fence walls, ``Phases._note_transfer``/``Sync.finish_deferred`` feed
 transfer walls, and the streamed path feeds stream-driver backpressure
 stalls.  ``Cores.health_report()`` returns :meth:`HealthMonitor.report`;
 ``trace/aggregate.gather_cluster`` ships the report so the DCN tier sees

@@ -409,7 +409,7 @@ class ClPipeline:
         """Streamed partition transfers inside multi-chip stages: each
         such stage runs its kernels through a stage-local ``Cores``,
         which chunk-streams its per-lane H2D/D2H exactly like the main
-        scheduler (core/cores._run_streamed) — stage feeds stop paying
+        scheduler (core/phase.Phases._streamed) — stage feeds stop paying
         the monolithic upload-before-first-launch fence.  True iff every
         multi-chip stage has it on (single-chip stages keep values
         device-resident and have no partition transfers to stream)."""

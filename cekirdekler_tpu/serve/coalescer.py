@@ -3,7 +3,7 @@ what order.
 
 Requests coalesce by **job signature** (kernels + param identity +
 ranges + values — the same identity the fused-dispatch window keys on,
-``Cores._fused_signature``): a group of same-signature requests
+``window.job_signature``): a group of same-signature requests
 dispatches as ONE fused ladder per device
 (``Cores.compute_fused_batch``), so the coalescing plan is literally
 the batching plan.

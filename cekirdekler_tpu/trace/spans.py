@@ -59,7 +59,7 @@ Span kinds used by the built-in instrumentation (callers may add more):
 ``fence`` (retirement wait), ``upload`` (H2D), ``download`` (D2H),
 ``upload-chunk`` / ``download-chunk`` (one ladder-aligned chunk of a
 STREAMED partition transfer — the chunked double-buffered H2D/D2H path,
-``Cores._run_streamed``; the monolithic kinds above stay for whole-range
+``Phases._streamed``; the monolithic kinds above stay for whole-range
 transfers so the two paths are distinguishable in every report),
 ``pipeline-stage`` (one pipeline engine/stage body), ``pool-task``
 (device-pool task), ``dcn-exchange`` (cross-host collective), ``fused``

@@ -11,6 +11,7 @@ more through upstream's public API (flags as properties, ``compute()``,
 
 import importlib.util
 import os
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -20,6 +21,7 @@ jax = pytest.importorskip("jax")
 from cekirdekler_tpu import ClArray  # noqa: E402
 from cekirdekler_tpu.analysis import flag_row  # noqa: E402
 from cekirdekler_tpu.arrays.clarray import ComputeValidationError  # noqa: E402
+from cekirdekler_tpu.core.cores import PIPELINE_DRIVER, PIPELINE_EVENT  # noqa: E402
 from cekirdekler_tpu.core.cruncher import NumberCruncher  # noqa: E402
 from cekirdekler_tpu.hardware import platforms  # noqa: E402
 from cekirdekler_tpu.kernel.registry import KernelProgram, lowering_meta  # noqa: E402
@@ -530,7 +532,7 @@ def test_two_lanes_record_the_finding_and_one_lane_does_not(devs):
     assert two and "scatter-write" in str(two)
 
 
-# -- B.5: a scatter into a partial_read array under the STREAM engine -------
+# -- B.5: a scatter into a partial_read array under an engine that cuts ------
 
 ROAM = """
 __kernel void roam(__global int* a, __global int* b, __global int* t) {
@@ -541,12 +543,24 @@ __kernel void roam(__global int* a, __global int* b, __global int* t) {
 """
 
 
-def test_a_scattered_store_is_exact_under_the_streamed_engine(devs):
-    """Chunk 0's launch stores into the LAST chunk's elements (``t`` is the
-    reversal).  Streamed chunk by chunk, the last chunk's upload would bury
-    those stores and the first chunk's download would miss the last
-    launch's: the engine moves such an array whole (``roaming_stores``)
-    while ``b``, whose stores stay with their items, still streams."""
+ENGINES = {
+    "streamed": {},
+    "driver": dict(pipeline=True, pipeline_blobs=4,
+                   pipeline_type=PIPELINE_DRIVER),
+    "event": dict(pipeline=True, pipeline_blobs=4,
+                  pipeline_type=PIPELINE_EVENT),
+}
+
+
+@pytest.mark.parametrize("engine", list(ENGINES))
+def test_a_scattered_store_is_exact_under_an_engine_that_cuts_the_range(
+        devs, engine):
+    """Part 0's launch stores into the LAST part's elements (``t`` is the
+    reversal).  Moved part by part (chunks, blobs), the last part's upload
+    would bury those stores and the first part's download would miss the
+    last launch's: every engine that cuts the range moves such an array
+    whole (``roaming_stores``, asked by ``phase.classify`` alone) while
+    ``b``, whose stores stay with their items, still goes part by part."""
     n = 1024
     prog = KernelProgram(ROAM)
     assert prog.roaming_stores(("roam",), (1, 1, 1)) == frozenset({0})
@@ -561,14 +575,20 @@ def test_a_scattered_store_is_exact_under_the_streamed_engine(devs):
                     read_only=True)
         TRACER.enable(clear=True)
         try:
-            a.next_param(b, t).compute(cr, 47, "roam", n, 64)
+            a.next_param(b, t).compute(cr, 47, "roam", n, 64,
+                                       **ENGINES[engine])
         finally:
             TRACER.disable()
-        assert cr.cores.last_stream_chunks == {0: 4}  # it did stream
-        chunked = {s.tag.partition("@")[0] for s in TRACER.snapshot()
-                   if s.kind in ("upload-chunk", "download-chunk")
-                   and not s.tag.startswith("part:")}
-        assert chunked == {"b"}
+        if engine == "streamed":
+            assert cr.cores.last_stream_chunks == {0: 4}  # it did stream
+        moved = Counter(
+            s.tag.removeprefix("stage:").partition("@")[0]
+            for s in TRACER.snapshot()
+            if s.kind in ("upload", "upload-chunk", "download",
+                          "download-chunk")
+            and not s.tag.startswith("part:"))
+        # once up and once back, against four parts each way
+        assert moved == {"a": 2, "b": 8, "t": 1}
         want = np.empty(n, np.int32)
         want[n - 1 - np.arange(n)] = np.arange(n) + 101
         np.testing.assert_array_equal(a.host(), want)

@@ -77,11 +77,11 @@ def test_live_tree_is_clean_against_baseline(capsys):
 
 def test_live_lock_order_graph_is_nonempty():
     # a graph that silently resolved nothing would make the deadlock
-    # pass vacuous — the known Worker.lock -> Cores._lock edge must be
-    # present (the _run_worker phase takes the scheduler lock inside)
+    # pass vacuous — the known Worker.lock -> scheduler lock edge must be
+    # present (a lane's phase leaves its records in the window inside)
     pkg = scan_package(os.path.join(ROOT, "cekirdekler_tpu"))
     edges = set(lock_order_edges(pkg))
-    assert ("core.worker.Worker.lock", "core.cores.Cores._lock") in edges
+    assert ("core.worker.Worker.lock", "core.window.Window.lock") in edges
     assert len(edges) >= 3
 
 

@@ -1,5 +1,5 @@
 """The flush's batched read-back (``Worker.download_slices_async``, issued by
-``Cores._start_deferred_downloads``): ONE dispatch a lane cuts every deferred
+``Sync.start_deferred_downloads`` through ``Cores``' entry of the name): ONE dispatch a lane cuts every deferred
 record of the lane into pieces of one fixed length, whatever the split.
 
 Held here, on the CPU rig: the host arrays come out bit-identical to the
@@ -22,6 +22,7 @@ from cekirdekler_tpu.core import NumberCruncher
 from cekirdekler_tpu.core import worker as worker_mod
 from cekirdekler_tpu.core.cores import Cores
 from cekirdekler_tpu.core.stream import chunk_plan
+from cekirdekler_tpu.core.sync import latest_records
 from cekirdekler_tpu.hardware import platforms
 from cekirdekler_tpu.trace.spans import TRACER
 
@@ -46,7 +47,7 @@ def per_record_start(chunks: int):
 
     def start(self, pending, lock_each):
         handles = []
-        for _, w, p, offset, size, write_all, cid in self._latest_records(
+        for _, w, p, offset, size, write_all, cid in latest_records(
                 pending):
             epw = p.flags.elements_per_work_item
             if write_all:
@@ -112,7 +113,7 @@ class Rig:
             arr.host()[:] = POISON
         entries = self.cores._start_deferred_downloads(
             self.pending(rows), lock_each)
-        self.cores._finish_deferred(entries, {})
+        self.cores._sync.finish_deferred(entries, {})
         return {k: a.host().copy() for k, a in self.arrays.items()}
 
 

@@ -19,6 +19,7 @@ import pytest
 
 from cekirdekler_tpu import ClArray
 from cekirdekler_tpu.core import NumberCruncher
+from cekirdekler_tpu.core.window import job_signature
 from cekirdekler_tpu.hardware import platforms
 
 INC = """
@@ -297,7 +298,7 @@ def test_disengage_range_change_is_named(devs):
         # an active fused sig.  Drive one engage + one armed break:
         x.compute(cr, 61, "inc", 4096, 64)  # armed rebalance consumed here
         x.compute(cr, 61, "inc", 4096, 64)  # defers
-        cr.cores._enqueue_rebalance.add(61)  # re-arm mid-window (as a
+        cr.cores._window.rebalance.add(61)  # re-arm mid-window (as a
         # concurrent thread's barrier would)
         x.compute(cr, 61, "inc", 4096, 64)  # breaks: range-change
         assert cr.fused_stats["disengaged"].get("range-change", 0) == 1
@@ -319,7 +320,7 @@ def test_disengage_non_resident_is_named(devs):
     cr.enqueue_mode = True
     for _ in range(3):
         x.compute(cr, 62, "inc", 1024, 64)
-    assert cr.cores._fused_sig is not None
+    assert cr.cores._window.sig is not None
     for w in cr.cores.workers:
         w.coverage_epoch += 1  # the observable effect of reset_coverage()
     x.compute(cr, 62, "inc", 1024, 64)
@@ -388,17 +389,18 @@ def test_disengage_partial_upload_guard(devs):
     cr.enqueue_mode = True
     x.compute(cr, 64, "inc", 1024, 64)  # seeds the candidate
     x.compute(cr, 64, "inc", 1024, 64)  # consecutive repeat -> engages
-    assert cores._fused_sig is not None
-    cores._fused_close()
+    assert cores._window.sig is not None
+    cores._window.close()
     w = cores.workers[0]
     with w.lock:
         off, _ = w._uploaded[id(x)]
         w._uploaded[id(x)] = (off, 1)
-    cores._fused_try_engage(
+    cores._window.try_engage(
+        job_signature(["inc"], [x], 64, 1024, 64, 0, ()),
         ["inc"], [x], 64, 1024, 64, 0, (),
         cores.global_ranges[64], cores.global_references[64], 64,
     )
-    assert cores._fused_sig is None
+    assert cores._window.sig is None
     assert cr.fused_stats["disengaged"].get("partial-upload", 0) == 1
     cr.enqueue_mode = False
     cr.dispose()
@@ -448,7 +450,7 @@ def _window(cr, compute, computes, lanes):
     if lanes > 1:
         # every barrier of more than one lane arms a rebalance; the rig
         # stands for a balancer that has nothing to move
-        cr.cores._enqueue_rebalance.clear()
+        cr.cores._window.rebalance.clear()
 
 
 def _fused_tags():
@@ -960,3 +962,70 @@ def test_threaded_enqueue_windows_no_lost_updates(devs, fused):
     np.testing.assert_array_equal(np.asarray(x), float(b_iters))
     np.testing.assert_array_equal(np.asarray(y), float(phases * per_phase_a))
     cr.dispose()
+
+
+@pytest.mark.parametrize("sync", ["range-move", "flush"])
+def test_no_window_opens_inside_another_threads_read_back(devs, sync,
+                                                          monkeypatch):
+    """The threaded test above in a form without a clock (it read 352 of
+    400 once).  Thread A's read-back of the deferred results closes
+    thread B's fused window first; B's next compute repeats that window
+    and used to reopen it on the ladder at once, INSIDE A's read-back: the
+    new window's records went to A, its launches came after A's copy to
+    the host.  After a range move the coverage reset then sent B's next
+    per-call compute to upload the host's older copy over them (11 of 13);
+    after a ``flush()`` nothing recorded them for the window's own flush
+    (8 of 13).  No window opens from the close to the end of the read-back
+    (``Window.held``): B's computes go per call, named ``resync``."""
+    from cekirdekler_tpu.core.cores import Cores
+    from cekirdekler_tpu.core.window import Window
+
+    cr = NumberCruncher(devs.subset(1), INC)
+    x = ClArray(np.zeros(1024, np.float32), name="x", partial_read=True)
+    cr.enqueue_mode = True
+
+    def inc(k):
+        for _ in range(k):
+            x.compute(cr, 73, "inc", 1024, 64)
+
+    inc(4)
+    cr.barrier()
+    inc(4)  # B's window is open: it repeats the last one
+    inside, go_on = threading.Event(), threading.Event()
+
+    def pause(real):
+        def paused(self, *args, **kwargs):
+            inside.set()
+            assert go_on.wait(30.0)
+            return real(self, *args, **kwargs)
+        return paused
+
+    try:
+        if sync == "range-move":
+            # inside the block: every worker lock is held, nothing reset yet
+            monkeypatch.setattr(Cores, "_start_deferred_downloads",
+                                pause(Cores._start_deferred_downloads))
+            a = threading.Thread(target=cr.cores._flush_and_reset_coverage)
+        else:
+            # between the close of the window and the take of the records
+            monkeypatch.setattr(Window, "take_deferred",
+                                pause(Window.take_deferred))
+            a = threading.Thread(target=cr.cores.flush)
+        a.start()
+        assert inside.wait(30.0)
+        b = threading.Thread(target=inc, args=(2,))
+        b.start()
+        # (after a range move B's per-call phase waits for its lane's lock)
+        b.join(timeout=0.5 if sync == "range-move" else 30.0)
+        go_on.set()
+        a.join(timeout=30.0)
+        b.join(timeout=30.0)
+        assert not a.is_alive() and not b.is_alive()
+        monkeypatch.undo()
+        assert cr.cores._window.held == 0
+        inc(3)
+        cr.enqueue_mode = False
+        np.testing.assert_array_equal(x.host(), 13.0)
+    finally:
+        go_on.set()
+        cr.dispose()

@@ -2,7 +2,7 @@
 neighbours wrote (a stencil stepped in time) through ``compute()`` on more
 than one lane — the analysis' proved reach, the widened ``partial_read``
 upload, and inside an enqueue window the lane-to-lane exchange
-(``Cores._stage_exchange``): exact against a float64 reference at every
+(``Exchange.stage``): exact against a float64 reference at every
 cell, lane boundaries included, whatever the split.
 """
 
@@ -17,7 +17,7 @@ import pytest
 
 import cekirdekler_tpu as ct
 from cekirdekler_tpu import ClArray
-from cekirdekler_tpu.core import cores as ck_cores
+from cekirdekler_tpu.core import exchange as ck_exchange
 from cekirdekler_tpu.core.cruncher import NumberCruncher
 from cekirdekler_tpu.core.worker import Worker
 from cekirdekler_tpu.errors import KernelVerifyError
@@ -337,7 +337,7 @@ def test_a_late_lane_does_not_upload_its_neighbours_next_step(monkeypatch):
     core) uploaded neighbour rows that were already one step ahead: 7.7e-3
     to 7.6e-2 wrong within a few rows of a lane boundary, now and then.
     Here lane 1 IS late, every call.  The host reads of such a compute are
-    staged before any phase starts (``Cores._stage_exchange``)."""
+    staged before any phase starts (``Exchange.stage``)."""
     data = np.random.default_rng(5).standard_normal(N).astype(np.float32)
     a = ClArray(data.copy(), name="a")
     b = ClArray(N, np.float32, name="b")
@@ -472,7 +472,7 @@ def test_the_exchange_is_counted_by_lane(traced_window):
 
 
 def test_owner_intervals():
-    split, assign = ck_cores._own_split, ck_cores._own_assign
+    split, assign = ck_exchange._own_split, ck_exchange._own_assign
     owned = assign(assign((), 0, 100, 0), 100, 200, 1)
     assert owned == [(0, 100, 0), (100, 200, 1)]
     assert split(owned, 90, 210) == [(90, 100, 0), (100, 200, 1),
@@ -482,5 +482,5 @@ def test_owner_intervals():
     assert assign(owned, 80, 120, 0)[0] == (0, 120, 0)
     assert split((), 5, 9) == [(5, 9, None)]
     assert split(owned, 300, 310) == [(300, 310, None)]
-    assert ck_cores._strip_sizes(64 * 13 + 5, 64) == [512, 256, 64, 5]
-    assert ck_cores._strip_sizes(3, 64) == [3]
+    assert ck_exchange._strip_sizes(64 * 13 + 5, 64) == [512, 256, 64, 5]
+    assert ck_exchange._strip_sizes(3, 64) == [3]

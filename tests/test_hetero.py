@@ -20,7 +20,7 @@ import pytest
 
 from cekirdekler_tpu import ClArray
 from cekirdekler_tpu import hardware as hw
-from cekirdekler_tpu.core import NumberCruncher
+from cekirdekler_tpu.core import NumberCruncher, compilecache
 from cekirdekler_tpu.core.balance import equal_split, prior_split
 from cekirdekler_tpu.core.compilecache import CACHE, WarmupSpec
 from cekirdekler_tpu.hardware import device_rank, platforms, rate_prior
@@ -198,7 +198,7 @@ def test_cores_lane_kind_state_and_prior_gauges(devs):
         cr.dispose()
 
 
-def test_warmup_rolls_up_ladders_per_device_kind(devs):
+def test_warmup_rolls_up_ladders_per_device_kind(devs, monkeypatch):
     """Mixed-fleet AOT warmup proof: the warmup report counts ladders
     per DEVICE KIND, so a fleet with a cold kind is visible before
     traffic arrives.  Kind variants are emulated by widening the warm
@@ -213,12 +213,12 @@ def test_warmup_rolls_up_ladders_per_device_kind(devs):
         assert out["warmed"] == 1 and out["skipped"] == 0
         # homogeneous fleet: one kind, one AOT pass
         assert sum(out["kinds"].values()) == 1
-        real = cores._warm_targets()
+        real = compilecache.warm_targets(cores.workers)
         (platform, donate, kind, device) = real[0]
-        cores._warm_targets = lambda: [
+        monkeypatch.setattr(compilecache, "warm_targets", lambda workers: [
             (platform, donate, kind, device),
             (platform, donate, "tpu-emu", device),
-        ]
+        ])
         out2 = cores.warmup(
             [WarmupSpec(kernels=("inc",), params=((n, "float32"),),
                         global_range=n, local_range=lr, values=())])

@@ -621,7 +621,7 @@ def test_postmortem_on_injected_driver_failure(devs, tmp_path, monkeypatch):
         with pytest.raises(RuntimeError, match="injected driver-queue"):
             cr.barrier()
     finally:
-        cr.cores._enqueued.clear()  # poisoned run: skip the flush drain
+        cr.cores._window.enqueued.clear()  # poisoned run: skip the flush drain
         cr.cores.enqueue_mode = False
         if not was_tracing:
             TRACER.disable()

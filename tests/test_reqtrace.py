@@ -152,9 +152,9 @@ def test_fused_defer_hot_path_has_zero_reqtrace_code():
     """The deepest hot path stays untouched: request-lifecycle stamps
     live at the SERVE layer (submit/coalesce/dispatch), never inside
     the fused deferral fast path."""
-    from cekirdekler_tpu.core.cores import Cores
+    from cekirdekler_tpu.core.window import Window
 
-    src = inspect.getsource(Cores._fused_defer)
+    src = inspect.getsource(Window.defer) + inspect.getsource(Window.route)
     assert "reqtrace" not in src.lower()
     assert "REQTRACE" not in src
 

@@ -11,6 +11,7 @@ import pytest
 
 from cekirdekler_tpu import ClArray
 from cekirdekler_tpu.core import NumberCruncher
+from cekirdekler_tpu.core.phase import classify, tuner_kernel_key
 from cekirdekler_tpu.core.stream import (
     BOOTSTRAP_BYTES,
     BOOTSTRAP_CHUNKS,
@@ -388,7 +389,7 @@ def test_streamed_autotune_defaults_to_measuring_run_then_engages(devs):
 
 def test_tuner_key_matches_between_choose_and_observe(devs):
     """Regression: choose() and observe() must key the SAME byte count
-    for one phase (Cores._stream_key_bytes is the one formula).  A
+    for one phase (phase.classify's key_bytes is the one formula).  A
     read+write partition array rides both the upload and the download
     wavefront (counted twice); a second formula that counted it once
     landed the measuring run's observation in a different power-of-two
@@ -405,20 +406,61 @@ def test_tuner_key_matches_between_choose_and_observe(devs):
     a = ClArray(np.zeros(n, np.float32), name="rw", partial_read=True)
     a.compute(cr, 85, "bump", n, 64)  # the monolithic measuring run
     w = cr.cores.workers[0]
-    expect = cr.cores._stream_key_bytes(w, [a], 0, n, True)
+    expect = classify(cr.program, ("bump",), [a], w, 0, n, cut=True,
+                      single=True, enqueue=False, owners={}).key_bytes
     assert expect == 2 * n * 4  # read AND write wavefronts
-    kk = cr.cores._tuner_kernel_key(("bump",), ())
+    kk = tuner_kernel_key(("bump",), ())
     assert list(t._obs) == [(0, kk, t.bytes_bucket(expect))]
     # dict-shaped value args key on sorted ITEMS — tuple(dict) keeps
     # only the names and would collapse a 100x value change (stale C
     # estimate, no re-measure) into one key
-    k1 = cr.cores._tuner_kernel_key(("bump",), {"bump": (1000,)})
-    k2 = cr.cores._tuner_kernel_key(("bump",), {"bump": (10,)})
+    k1 = tuner_kernel_key(("bump",), {"bump": (1000,)})
+    k2 = tuner_kernel_key(("bump",), {"bump": (10,)})
     assert k1 != k2
-    assert cr.cores._tuner_kernel_key(
+    assert tuner_kernel_key(
         ("bump",), {"bump": np.zeros(4)}) == (("bump",), None)
     np.testing.assert_array_equal(np.asarray(a), 1.0)
     cr.dispose()
+
+
+@pytest.mark.parametrize("engine", ["monolithic", "streamed", "driver",
+                                    "event"])
+def test_a_window_over_part_of_an_array_keeps_its_updates(devs, engine):
+    """Residency is asked over the work items' own elements of a
+    ``partial_read`` array, the same under every engine
+    (``core/window.holds``).  One lane computing over a PART of an array
+    in enqueue mode: asked over the whole array (as the streamed engine
+    once did on one lane, where the monolithic one uploads whole), what
+    the chunks covered never sufficed, every call uploaded the host's
+    stale copy over the device's, and a window of three increments read
+    back 1."""
+    from cekirdekler_tpu.core.cores import PIPELINE_DRIVER, PIPELINE_EVENT
+
+    src = """
+    __kernel void inc(__global float* a) {
+        int i = get_global_id(0);
+        a[i] = a[i] + 1.0f;
+    }"""
+    how = {"driver": dict(pipeline=True, pipeline_blobs=4,
+                          pipeline_type=PIPELINE_DRIVER),
+           "event": dict(pipeline=True, pipeline_blobs=4,
+                         pipeline_type=PIPELINE_EVENT)}.get(engine, {})
+    cr = NumberCruncher(devs.subset(1), src)
+    try:
+        cr.stream_chunks = 4 if engine == "streamed" else 1
+        cr.fused_dispatch = False  # every call takes the per-call path
+        x = ClArray(np.zeros(2048, np.float32), name="px", partial_read=True)
+        cr.enqueue_mode = True
+        for _ in range(3):
+            x.compute(cr, 87, "inc", 1024, 64, global_offset=512, **how)
+        cr.enqueue_mode = False
+        want = np.zeros(2048, np.float32)
+        want[512:1536] = 3.0
+        np.testing.assert_array_equal(x.host(), want)
+        if engine == "streamed":
+            assert cr.cores.last_stream_chunks == {0: 4}
+    finally:
+        cr.dispose()
 
 
 def test_flush_drain_feeds_transfer_benchmarks(devs):
@@ -440,9 +482,9 @@ def test_flush_drain_feeds_transfer_benchmarks(devs):
     # the drain normalizes by iterations since the last flush (the
     # enqueue benches it floors against are per-ITERATION) — the
     # counter must hold the window series' count here and clear after
-    assert cr.cores._flush_iters.get(86) == 3
+    assert cr.cores._window.flush_iters.get(86) == 3
     cr.enqueue_mode = False  # flush: the drain runs here
-    assert cr.cores._flush_iters == {}
+    assert cr.cores._window.flush_iters == {}
     for w in cr.cores.workers[:2]:
         assert w.transfer_benchmarks.get(86, 0.0) > 0.0, (
             w.index, w.transfer_benchmarks)

@@ -296,7 +296,7 @@ def test_fence_split_correct_results_and_rebalance_arming():
         for _ in range(4):
             x.next_param(y).compute(cr, 41, "saxpy", n, 64, values=(1.0,))
         cr.barrier()
-        assert 41 in cr.cores._enqueue_rebalance
+        assert 41 in cr.cores._window.rebalance
         x.next_param(y).compute(cr, 41, "saxpy", n, 64, values=(1.0,))
         cr.enqueue_mode = False
         np.testing.assert_allclose(

@@ -114,7 +114,7 @@ def test_enqueue_write_all_single_owner_readback():
     try:
         cr.enqueue_mode = True
         out.compute(cr, 3, "f", n, 64)
-        assert len(cr.cores._enqueued) == 1  # one owner, one deferred record
+        assert len(cr.cores._window.enqueued) == 1  # one owner, one deferred record
         cr.enqueue_mode = False
     finally:
         cr.dispose()

@@ -47,7 +47,7 @@ DEFAULT_BASELINE = os.path.join(
 #: window boundaries) must obey the cached-handle / allowlisted-lock /
 #: no-alloc-telemetry discipline.
 HOT_ROOTS = (
-    "core.cores.Cores._fused_defer",
+    "core.window.Window.defer",
     "core.worker._DriverQueue.submit",
     "core.worker.Worker.dispatch_async",
     "core.worker.Worker.stream_dispatch_async",
@@ -101,8 +101,8 @@ HOT_ROOTS = (
 #: the per-metric update lock (exact counters are the registry's
 #: design point 2).
 HOT_LOCK_ALLOW = (
-    "core.cores.Cores._lock",
-    "core.cores.Cores._fused_mu",
+    "core.window.Window.lock",
+    "core.window.Window._mu",
     "core.worker._DriverQueue._cond",
     "metrics.registry._Metric._lock",
     # serving submit path: ONE frontend condition guards the whole
@@ -129,6 +129,11 @@ HOT_LOCK_ALLOW = (
     # lock — neither is held across a shard submit or any recorder
     "serve.fabric.ShardRouter._mu",
     "serve.fabric.ServeFabric._mu",
+    # the roster snapshot itself: ShardRouter.route copies {epoch,
+    # members} under the membership's own lock, a dict copy a request
+    # (seen since the analyzer follows collaborators handed to a
+    # constructor through an annotated parameter)
+    "cluster.elastic.Membership._mu",
     # retry budgets (reached from the fabric re-route path): a couple
     # of dict reads/writes per preempted request under one small-state
     # lock — preemption recovery, not the steady-state submit path

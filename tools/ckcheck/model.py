@@ -551,14 +551,35 @@ def _inventory_self_assigns(pkg: Package, mod: Module, ci: ClassInfo,
 
 def _inventory_attr_types(pkg: Package, mod: Module, ci: ClassInfo,
                           fn: ast.AST) -> None:
-    """Phase B: ``self.X = ClassName(...)`` / ``[ClassName(...)]``
-    receiver types, resolved against the COMPLETE class inventory."""
+    """Phase B: ``self.X = ClassName(...)`` / ``[ClassName(...)]`` /
+    an annotated parameter: receiver types, resolved against the COMPLETE
+    class inventory."""
+    params = {a.arg: a.annotation for a in getattr(fn.args, "args", ())
+              if a.annotation is not None}
     for attr, value, _lineno in _self_attr_assigns(fn):
         if value is None or attr in ci.locks:
             continue
         cls = _constructed_class(mod, pkg, value)
+        if cls is None and isinstance(value, ast.Name):
+            # ``self.X = x`` where ``x`` is a parameter annotated with a
+            # package class: a collaborator handed in at construction
+            cls = _annotated_class(mod, pkg, params.get(value.id))
         if cls:
             ci.attr_types.setdefault(attr, cls)
+
+
+def _annotated_class(mod: Module, pkg: Package, ann):
+    """("inst"|"list", qualname) for a parameter annotation ``ClassName``
+    / ``list[ClassName]`` naming a class of the package."""
+    kind = "inst"
+    if isinstance(ann, ast.Subscript) and isinstance(ann.value, ast.Name) \
+            and ann.value.id in ("list", "List", "Sequence"):
+        kind, ann = "list", ann.slice
+    if isinstance(ann, ast.Name):
+        qual = mod.imports.get(ann.id) or f"{mod.modname}.{ann.id}"
+        if qual in pkg.classes:
+            return (kind, qual)
+    return None
 
 
 def _constructed_class(mod: Module, pkg: Package, value: ast.expr):

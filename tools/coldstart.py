@@ -12,7 +12,7 @@ Three SUBPROCESS incarnations per workload, each a fresh interpreter
   steady-state batch.
 - **populate** — same run with the manifest armed and jax's cache placed
   from outside (``JAX_COMPILATION_CACHE_DIR=<root>/xla``): the
-  engage-time recorder (``core/cores._cache_record_engaged``) persists
+  engage-time recorder (``core/compilecache.record_engaged``) persists
   the window spec and jax's persistent cache captures the XLA
   executables.  This is the PRODUCTION population flow, not a synthetic
   writer.
