@@ -361,6 +361,14 @@ class _Interp:
             self._record(target.base, idx, target.line, write=True)
 
     def exec_stmt(self, s) -> None:
+        if isinstance(s, lang.Barrier):
+            return  # orders the group's accesses; moves no index
+        if isinstance(s, lang.LocalDecl):
+            # a work-group's scratch: no buffer, so no transfer surface and
+            # no flag to prove; what a work item reads there another may
+            # have written, so a loaded value is data (TOP)
+            self.priv[s.name] = TOP
+            return
         if isinstance(s, lang.Decl):
             for name, init in s.names:
                 if name in s.arrays:

@@ -456,6 +456,12 @@ class Cores:
             raise ComputeValidationError(
                 f"global_range ({global_range}) must be divisible by step ({step})"
             )
+        if global_offset % local_range and any(
+                self.program.cooperates(n) for n in kernel_names):
+            raise ComputeValidationError(
+                f"global_offset ({global_offset}) must be a multiple of the "
+                f"local range ({local_range}) for a kernel with a __local "
+                "array or a barrier: a launch covers whole work-groups")
         t_start = time.perf_counter()
         s = self._settings
         win = self._window

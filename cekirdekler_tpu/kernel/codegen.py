@@ -54,6 +54,14 @@ Key mechanisms:
   (:class:`ViewSpec`); the launcher builds each once an upload and keeps it
   (kernel/registry.py).  Whatever a launch is not handed it builds itself.
 
+- **Work-group cooperation** — a launch covers WHOLE work-groups; a
+  ``__local T a[K];`` array is a value ``[groups of the launch, K]`` beside
+  the lane vectors, read and written by a shift along ``K``, a broadcast, or
+  a gather / scatter inside the row (:func:`_local_load`), and a
+  ``barrier()`` lowers to nothing: every statement has run for all lanes of
+  the launch before the next one starts.  The build refuses a barrier that
+  the work items of a group do not all reach (:func:`_check_barriers`).
+
 The launch boundary: ``build_kernel_fn`` returns ``fn(offset, *buffers,
 value_args) -> updated buffers``, where ``offset`` is a *runtime* scalar —
 the load balancer can re-partition the global range every call without
@@ -77,6 +85,7 @@ from ..errors import KernelCompileError, KernelLanguageError
 from . import lang
 from .lang import (
     Assign,
+    Barrier,
     BinOp,
     Break,
     Call,
@@ -89,6 +98,7 @@ from .lang import (
     If,
     Index,
     KernelDef,
+    LocalDecl,
     Num,
     Return,
     Ternary,
@@ -239,6 +249,17 @@ class _Ctx:
         # private fixed-size arrays (``float acc[4];``): name -> length;
         # the env value is a (length, *shape) vector-per-element stack
         self.private: dict[str, int] = {}
+        # WORK-GROUP COOPERATION.  ``__local`` arrays: name -> length; the env
+        # value is ``[groups of the launch, length]``.  What the build proved
+        # the same in every lane of a GROUP, and the locals that are
+        # ``get_local_id(0)`` (adopt() takes both); how each access site of a
+        # local array was lowered: (id of the Index node, is it the store) ->
+        # ``shift`` / ``uniform`` / ``row``
+        self.local: dict[str, int] = {}
+        self.group_uniform: set[str] = set()
+        self.tid_vars: frozenset[str] = frozenset()
+        self.local_access: dict[tuple[int, bool], str] = {}
+        self.cooperative = False  # the kernel has a __local array or a barrier
         # per-innermost-loop masks: lanes that executed `break` (persist
         # for the loop's remaining iterations) / `continue` (reset per
         # iteration) — saved and restored by _exec_loop.  In a counted
@@ -295,11 +316,20 @@ class _Ctx:
         self.compact_access: dict[tuple[int, bool], str] = {}
         self.compact_loops = 0
 
-    def adopt(self, kernel: KernelDef, uniform_vars: set[str]) -> None:
+    def adopt(self, kernel: KernelDef, uniform_vars: set[str],
+              coop: "_Coop | None" = None) -> None:
         """Take what a build knows of ``kernel`` before its body runs."""
         self.uniform_vars = uniform_vars
+        if coop is not None:
+            self.group_uniform, self.tid_vars = coop.group_uniform, coop.tid_vars
+            self.cooperative = True
         self.returns = _contains_return(kernel.body)
         self.helpers = getattr(kernel, "helpers", {}) or {}
+
+    def lane_arrays(self) -> frozenset:
+        """The arrays whose elements are never the same over a launch
+        whatever the index: a lane's private ones, a group's ``__local``."""
+        return frozenset(self.private) | frozenset(self.local)
 
     def broadcast_scalar(self, val, dtype):
         """Materialize a scalar as a full work-item vector of this ctx's
@@ -431,9 +461,10 @@ def _eval(ctx: _Ctx, node) -> KVal:
             return KVal(node.value, node.ctype, (0, node.value), (node.value,) * 2)
         return KVal(node.value, node.ctype)
     if isinstance(node, Var):
-        if node.name in ctx.private:
+        if node.name in ctx.private or node.name in ctx.local:
             raise KernelLanguageError(
-                f"private array {node.name!r} used without an index",
+                f"{'private' if node.name in ctx.private else '__local'} "
+                f"array {node.name!r} used without an index",
                 line=node.line,
             )
         if node.name in ctx.env:
@@ -616,10 +647,12 @@ _BINARY_FLOAT = {
 }
 
 _UNSUPPORTED_CALLS = {
-    "barrier": "work-group barriers (no shared memory in the vectorized TPU contract; "
-               "use separate kernels — the reference's pipelines exist for exactly this)",
-    "mem_fence": "memory fences (XLA orders operations by data flow)",
-    "work_group_barrier": "work-group barriers",
+    "mem_fence": "memory fences order one work item's accesses and meet no "
+                 "other work item; use barrier(), which the group reaches together",
+    "read_mem_fence": "memory fences (use barrier())",
+    "write_mem_fence": "memory fences (use barrier())",
+    "barrier": "a barrier is a statement of its own, `barrier(CLK_LOCAL_MEM_FENCE);`",
+    "work_group_barrier": "a barrier is a statement of its own",
 }
 
 
@@ -642,13 +675,14 @@ def _inline_helper(ctx: _Ctx, fdef, arg_nodes, call_line: int) -> KVal:
             line=call_line,
         )
     vals = [_eval(ctx, a) for a in arg_nodes]
-    saved_env, saved_priv = ctx.env, ctx.private
+    saved_env, saved_priv, saved_local = ctx.env, ctx.private, ctx.local
     saved_bufs, saved_bct = ctx.bufs, ctx.buf_ctypes
     saved_uniform = ctx.uniform_vars
     ctx.env = {
         p.name: _as_dtype(v, p.ctype) for p, v in zip(fdef.params, vals)
     }
     ctx.private = {}
+    ctx.local = {}
     ctx.bufs = {}
     ctx.buf_ctypes = {}
     ctx.uniform_vars = set()
@@ -666,7 +700,7 @@ def _inline_helper(ctx: _Ctx, fdef, arg_nodes, call_line: int) -> KVal:
         ctx._after_stack.pop()
         stack.pop()
         ctx.env = saved_env
-        ctx.private = saved_priv
+        ctx.private, ctx.local = saved_priv, saved_local
         ctx.bufs = saved_bufs
         ctx.buf_ctypes = saved_bct
         ctx.uniform_vars = saved_uniform
@@ -680,8 +714,9 @@ def _call(ctx: _Ctx, node: Call) -> KVal:
         name = name.split("_", 1)[1]
     if name.startswith("atomic_") or name.startswith("atom_"):
         raise KernelLanguageError(
-            f"{node.name}: atomics are not supported in the vectorized TPU contract; "
-            "express reductions as separate reduction kernels",
+            f"{node.name}: atomics are not supported (the order in which the "
+            "lanes of a launch run against each other is unspecified); reduce "
+            "through a __local array and barrier(), one partial a work-group",
             line=node.line,
         )
     if name in _UNSUPPORTED_CALLS:
@@ -833,6 +868,176 @@ def _scatter_lanes(stack, ix, vals):
     """stack[(ix[lane], lane)] = vals[lane] for every lane position."""
     lanes = jnp.indices(stack.shape[1:])
     return stack.at[(ix,) + tuple(lanes)].set(vals)
+
+
+# ---------------------------------------------------------------------------
+# work-group cooperation: ``__local`` arrays.  A launch covers ``G`` whole
+# work-groups of ``L`` work items; lane ``b`` of it is work item ``t = b % L``
+# of group ``g = b // L`` (the launch's offset is a multiple of ``L``, so
+# ``get_local_id(0)`` is ``t``).  ``__local T a[K];`` is a value ``A[G, K]``:
+# a load ``a[e]`` gives lane ``(g, t)`` the element ``A[g, e(g, t)]``, a store
+# under the lanes' mask ``m`` sets ``A[g, e(g, t)] = v(g, t)`` where
+# ``m(g, t)``; a statement's loads see the tile as it was before its stores.
+# Three lowerings, chosen from the syntax alone (:func:`_local_kind`):
+# ``shift`` (``e = tid + u`` with ``u`` the same in every lane of a group: the
+# lanes' elements are a window of the row), ``uniform`` (``e = u``: one
+# element a group), ``row`` (anything else: a gather / scatter inside the
+# row, always right).  An index outside ``[0, K)`` is undefined in OpenCL:
+# here a load reads the nearest element and a store is dropped.
+# ---------------------------------------------------------------------------
+
+
+def _under_int_casts(node):
+    while isinstance(node, Cast) and node.ctype in _INT_TYPES:
+        node = node.operand
+    return node
+
+
+def _is_local_id(node) -> bool:
+    """Is ``node`` ``get_local_id(0)``, under integer casts?"""
+    node = _under_int_casts(node)
+    return (isinstance(node, Call) and node.name == "get_local_id"
+            and all(isinstance(a, Num) and a.value == 0 for a in node.args))
+
+
+def _is_tid(ctx: _Ctx, node) -> bool:
+    """Is ``node`` the work item's local id: ``get_local_id(0)``, or a local
+    that is nothing else (:func:`_tid_vars`), under integer casts?"""
+    inner = _under_int_casts(node)
+    if isinstance(inner, Var):
+        return inner.name in ctx.tid_vars
+    return _is_local_id(inner)
+
+
+def _local_kind(ctx: _Ctx, index) -> tuple:
+    """``(kind, rest)`` of a ``__local`` access at ``index``: ``rest`` are the
+    ``(sign, term)`` leaves of ``u`` where the kind is ``shift``."""
+    private = frozenset(ctx.private)
+    if _expr_uniform(index, ctx.group_uniform, private, group=True):
+        return "uniform", ()
+    terms = _terms(index, 1, [])
+    rest = [(sg, t) for sg, t in terms if not _is_tid(ctx, t)]
+    if ([sg for sg, t in terms if _is_tid(ctx, t)] == [1]
+            and all(_expr_uniform(t, ctx.group_uniform, private, group=True)
+                    for _, t in rest)):
+        return "shift", tuple(rest)
+    return "row", ()
+
+
+def _group_value(ctx: _Ctx, v: KVal, launch_uniform: bool):
+    """An integer proved the same in every lane of a group as a Python int,
+    a 0-d ``int32`` (the same over the launch) or ``int32[G]``."""
+    c = _const_int(v)
+    if c is not None:
+        return c
+    iv = jnp.asarray(_num(_as_dtype(v, "int")), jnp.int32)
+    if iv.ndim == 0 or launch_uniform:
+        return _lane0(iv)
+    return iv.reshape(-1, ctx.local_size)[:, 0]
+
+
+def _local_index(ctx: _Ctx, node: Index):
+    """``(kind, u)`` of the access site ``node``, ``u`` the group's part of
+    the index (:func:`_group_value`; the whole index as ``int32[G, L]`` where
+    the kind is ``row``)."""
+    kind, rest = _local_kind(ctx, node.index)
+    if kind == "shift":
+        u, parts = _int_const(0), [t for _, t in rest]
+        for sign, term in rest:
+            u = _binop(ctx, BinOp(op="+" if sign > 0 else "-", left=_Lit(u),
+                                  right=_Lit(_eval(ctx, term)), line=node.line))
+    else:
+        u, parts = _eval(ctx, node.index), [node.index]
+    if u.ctype not in _INT_TYPES:
+        raise KernelLanguageError("array index must be an integer", line=node.line)
+    if kind == "row":
+        iv = jnp.asarray(_num(_as_dtype(u, "int")), jnp.int32)
+        return kind, jnp.broadcast_to(iv, ctx.shape).reshape(-1, ctx.local_size)
+    arrays = ctx.lane_arrays()
+    return kind, _group_value(ctx, u, all(
+        _expr_uniform(t, ctx.uniform_vars, arrays) for t in parts))
+
+
+def _shift_rows(x, d, width: int, lo, hi):
+    """``out[g, k] = x[g, k + d_g]`` for ``k`` in ``[0, width)`` where that
+    exists, ``lo`` before the row and ``hi`` behind it; ``d`` a Python int, a
+    0-d value (one slice) or ``int32[G]`` (a slice a row)."""
+    g, w = x.shape
+    if type(d) is int and 0 <= d and d + width <= w:
+        return x if (d, width) == (0, w) else lax.slice_in_dim(x, d, d + width, axis=1)
+    fill = (g, width)
+    ext = jnp.concatenate([jnp.broadcast_to(jnp.asarray(lo, x.dtype), fill), x,
+                           jnp.broadcast_to(jnp.asarray(hi, x.dtype), fill)], axis=1)
+    start = width + jnp.clip(jnp.asarray(d, jnp.int32), -width, w)
+    if start.ndim == 0:
+        return lax.dynamic_slice_in_dim(ext, start, width, axis=1)
+    return jax.vmap(lambda row, s0: lax.dynamic_slice(row, (s0,), (width,)))(ext, start)
+
+
+def _group_mask(ctx: _Ctx):
+    """The active mask as ``bool[G, L]``, None when every lane is active."""
+    m = ctx.active_mask()
+    if m is None:
+        return None
+    return jnp.broadcast_to(m, ctx.shape).reshape(-1, ctx.local_size)
+
+
+def _local_load(ctx: _Ctx, node: Index) -> KVal:
+    """``a[e]`` of a ``__local`` array (see the section's comment)."""
+    tile, k, L = ctx.env[node.base], ctx.local[node.base], ctx.local_size
+    A = tile.value
+    kind, u = _local_index(ctx, node)
+    ctx.local_access[id(node), False] = kind
+    if kind == "shift":
+        out = _shift_rows(A, u, L, A[:, :1], A[:, -1:])
+    elif kind == "uniform":
+        if type(u) is int or u.ndim == 0:
+            at = min(max(u, 0), k - 1) if type(u) is int else jnp.clip(u, 0, k - 1)
+            one = lax.dynamic_slice_in_dim(A, at, 1, axis=1)
+        else:
+            one = jnp.take_along_axis(A, jnp.clip(u, 0, k - 1)[:, None], axis=1)
+        out = jnp.broadcast_to(one, (A.shape[0], L))
+    else:
+        out = jnp.take_along_axis(A, jnp.clip(u, 0, k - 1), axis=1)
+    return KVal(out.reshape(ctx.shape), tile.ctype)
+
+
+def _local_store(ctx: _Ctx, node: Index, val: KVal) -> None:
+    """``a[e] = v`` of a ``__local`` array under the active mask."""
+    tile, k, L = ctx.env[node.base], ctx.local[node.base], ctx.local_size
+    A = tile.value
+    G = A.shape[0]
+    v = jnp.broadcast_to(jnp.asarray(_num(_as_dtype(val, tile.ctype)), A.dtype),
+                         ctx.shape).reshape(G, L)
+    kind, u = _local_index(ctx, node)
+    ctx.local_access[id(node), True] = kind
+    m = _group_mask(ctx)
+    if kind == "shift":
+        # element k of a row is written by work item k - u, where that is one
+        # and it is active
+        back = -u if type(u) is int else jnp.negative(u)
+        written = _shift_rows(jnp.ones((G, L), jnp.bool_) if m is None else m,
+                              back, k, False, False)
+        new = jnp.where(written, _shift_rows(v, back, k, 0, 0), A)
+    elif kind == "uniform":
+        # one element a group: the value of its first active work item (two
+        # that store different values there race in OpenCL too)
+        if m is None:
+            some, first = True, v[:, :1]
+        else:
+            some = jnp.any(m, axis=1, keepdims=True)
+            first = jnp.take_along_axis(
+                v, jnp.argmax(m, axis=1)[:, None].astype(jnp.int32), axis=1)
+        col = lax.broadcasted_iota(jnp.int32, (G, k), 1)
+        at = jnp.asarray(u, jnp.int32)
+        hit = jnp.logical_and(col == (at if at.ndim == 0 else at[:, None]), some)
+        new = jnp.where(hit, first, A)
+    else:
+        ix = u if m is None else jnp.where(m, u, k)
+        ix = jnp.where(ix < 0, k, ix)  # outside the row: dropped
+        rows = lax.broadcasted_iota(jnp.int32, (G, L), 0)
+        new = A.at[rows, ix].set(v, mode="drop")
+    ctx.env[node.base] = KVal(new, tile.ctype)
 
 
 def _loaded(value, ctype: str) -> KVal:
@@ -1208,6 +1413,8 @@ def _note(ctx: _Ctx, node: Index, store: bool, kind: str) -> None:
 def _load(ctx: _Ctx, node: Index) -> KVal:
     if node.base in ctx.private:
         return _private_load(ctx, node)
+    if node.base in ctx.local:
+        return _local_load(ctx, node)
     if node.base not in ctx.bufs:
         raise KernelCompileError(f"{node.base!r} is not an array parameter", line=node.line)
     buf = ctx.bufs[node.base]
@@ -1251,7 +1458,7 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
             _note(ctx, node, False, "strided")
             return _loaded(col, ctype)
     if ctx.uniform_vars and _expr_uniform(
-        node.index, ctx.uniform_vars, frozenset(ctx.private)
+        node.index, ctx.uniform_vars, ctx.lane_arrays()
     ):
         _note(ctx, node, False, "uniform")
         # lane-uniform index (the n-body ``x[j]`` pattern): ONE element
@@ -1274,6 +1481,9 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
 def _store(ctx: _Ctx, node: Index, val: KVal) -> None:
     if node.base in ctx.private:
         _private_store(ctx, node, val)
+        return
+    if node.base in ctx.local:
+        _local_store(ctx, node, val)
         return
     if node.base not in ctx.bufs:
         raise KernelCompileError(f"{node.base!r} is not an array parameter", line=node.line)
@@ -1330,7 +1540,7 @@ def _store(ctx: _Ctx, node: Index, val: KVal) -> None:
             ctx.bufs[node.base] = lax.slice(updated, (lo,), (lo + n,))
         ctx.invalidate_padded(node.base)
     elif one_value and _expr_uniform(node.index, ctx.uniform_vars,
-                                     frozenset(ctx.private)):
+                                     ctx.lane_arrays()):
         # every lane names the same element and stores the same value (a
         # flag the kernel raises for the host, ``over[0] = true``): ONE
         # element is written if any lane is active, where a scatter would
@@ -1380,6 +1590,23 @@ def _exec_block(ctx: _Ctx, stmts: list) -> None:
 
 
 def _exec(ctx: _Ctx, node) -> None:
+    if isinstance(node, Barrier):
+        # every statement before it has run for all lanes of the launch, and
+        # a launch holds whole groups: nothing to do (_check_barriers has
+        # proved that the group reaches it together)
+        if ctx.info.get("inline_stack"):
+            raise KernelLanguageError(
+                "a barrier inside a helper function is not supported; "
+                "call barrier() from the kernel", line=node.line)
+        return
+    if isinstance(node, LocalDecl):
+        # at kernel scope (the parser's rule), in a launch of whole groups
+        # (build_kernel_fn's): zero at its start (OpenCL leaves it undefined)
+        ctx.local[node.name] = node.size
+        ctx.env[node.name] = KVal(
+            jnp.zeros((ctx.B // ctx.local_size, node.size),
+                      ctype_to_dtype(node.ctype)), node.ctype)
+        return
     if isinstance(node, Decl):
         for name, init in node.names:
             if name in node.arrays:
@@ -1497,9 +1724,9 @@ def _assign(ctx: _Ctx, target, op: str, value_expr) -> None:
         rhs = _binop(ctx, BinOp(op=base_op, left=_Lit(cur), right=_Lit(rhs), line=getattr(target, "line", 0)))
     if isinstance(target, Var):
         name = target.name
-        if name in ctx.private:
+        if name in ctx.private or name in ctx.local:
             raise KernelLanguageError(
-                f"cannot assign to private array {name!r} as a whole; "
+                f"cannot assign to array {name!r} as a whole; "
                 "assign elements", line=getattr(target, "line", 0),
             )
         if name in ctx.env:
@@ -1563,7 +1790,7 @@ def _exec_if(ctx: _Ctx, node: If) -> None:
     # a condition proved the same in every lane joins the uniform part of
     # the mask as a 0-d value, any other the lane mask
     part = "mask"
-    if _expr_uniform(node.cond, ctx.uniform_vars, frozenset(ctx.private)):
+    if _expr_uniform(node.cond, ctx.uniform_vars, ctx.lane_arrays()):
         part, cvec = "umask", _lane0(cond)
     elif not hasattr(cond, "ndim") or cond.ndim == 0:
         cvec = jnp.broadcast_to(cond, ctx.shape)
@@ -1820,7 +2047,7 @@ def _loop_counted(ctx: _Ctx, node) -> bool:
     (:func:`_exec_counted`)?  The same test that :func:`_uniform_vars`
     made of it, on the set it ended with."""
     return not ctx.returns and not _loop_diverges(
-        node, ctx.uniform_vars, frozenset(ctx.private))
+        node, ctx.uniform_vars, ctx.lane_arrays())
 
 
 class _Trips(NamedTuple):
@@ -2057,7 +2284,8 @@ def _exec_counted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
     ctx._pad_cache.clear()
     for k in carried_vars:
         val = env_f[k]
-        if lane0 is not None and k in lane_vars:
+        if lane0 is not None and k in lane_vars and k not in ctx.local:
+            # (a __local array's stores kept their own mask: _local_store)
             val = jnp.where(lane0, val, init_env[k])
         ctx.env[k] = KVal(val, var_ctypes[k], None)
     for k in carried_bufs:
@@ -2342,9 +2570,9 @@ def _compactable(ctx: _Ctx, body: list, cond_expr) -> bool:
     pass: a loop of arithmetic and own-element accesses runs at the vector
     unit's speed over all lanes and gains nothing)."""
     if (ctx.pallas or ctx.info.get("in_loop", 0) or ctx.masks()[0] is None
-            or ctx.B <= _COMPACT_WIDTH):
-        return False
-    changed, private = _assigned_vars(body), frozenset(ctx.private)
+            or ctx.B <= _COMPACT_WIDTH or ctx.cooperative):
+        return False  # (chunks of entering lanes would tear groups apart)
+    changed, private = _assigned_vars(body), ctx.lane_arrays()
     for ix in _index_nodes([body, cond_expr]):
         if ix.base not in ctx.bufs or ix.base in private:
             continue
@@ -2529,8 +2757,12 @@ _PURE_BUILTINS = (
 )
 
 
-def _expr_uniform(node, uset: set[str], private: set[str] = frozenset()) -> bool:
-    """True iff ``node`` provably evaluates identically in every lane."""
+def _expr_uniform(node, uset: set[str], private: set[str] = frozenset(),
+                  group: bool = False) -> bool:
+    """True iff ``node`` provably evaluates identically in every lane of the
+    launch; with ``group``, in every lane of a work-GROUP (``uset`` is then
+    :func:`_uniform_vars`'s group set, ``private`` the private arrays alone:
+    a ``__local`` array at such an index is the group's one element)."""
     if isinstance(node, Num):
         return True
     if isinstance(node, Var):
@@ -2538,26 +2770,30 @@ def _expr_uniform(node, uset: set[str], private: set[str] = frozenset()) -> bool
     if isinstance(node, Index):
         # a BUFFER load at a uniform index yields the same element in every
         # lane; a PRIVATE array's rows are per-lane, so its loads never are
+        # (nor, over a launch, those of a group's __local array: the callers
+        # name both in ``private``)
         if node.base in private:
             return False
-        return _expr_uniform(node.index, uset, private)
+        return _expr_uniform(node.index, uset, private, group)
     if isinstance(node, BinOp):
-        return (_expr_uniform(node.left, uset, private)
-                and _expr_uniform(node.right, uset, private))
+        return (_expr_uniform(node.left, uset, private, group)
+                and _expr_uniform(node.right, uset, private, group))
     if isinstance(node, UnOp):
-        return _expr_uniform(node.operand, uset, private)
+        return _expr_uniform(node.operand, uset, private, group)
     if isinstance(node, Cast):
-        return _expr_uniform(node.operand, uset, private)
+        return _expr_uniform(node.operand, uset, private, group)
     if isinstance(node, Ternary):
         return (
-            _expr_uniform(node.cond, uset, private)
-            and _expr_uniform(node.then, uset, private)
-            and _expr_uniform(node.other, uset, private)
+            _expr_uniform(node.cond, uset, private, group)
+            and _expr_uniform(node.then, uset, private, group)
+            and _expr_uniform(node.other, uset, private, group)
         )
     if isinstance(node, Call):
         name = node.name
         if name.startswith(("native_", "half_")):
             name = name.split("_", 1)[1]
+        if group and name == "get_group_id":
+            return True
         if name in _LANE_CALLS:
             return False
         if name in _UNIFORM_CALLS:
@@ -2565,11 +2801,12 @@ def _expr_uniform(node, uset: set[str], private: set[str] = frozenset()) -> bool
         if name not in _PURE_BUILTINS:
             # user helpers (and anything unrecognized) may read lane state
             return False
-        return all(_expr_uniform(a, uset, private) for a in node.args)
+        return all(_expr_uniform(a, uset, private, group) for a in node.args)
     return False  # unknown node kind: be conservative
 
 
-def _has_divergent_exit(stmts: list, divergent: bool, uset, private) -> bool:
+def _has_divergent_exit(stmts: list, divergent: bool, uset, private,
+                        group: bool = False) -> bool:
     """True if a break/continue can execute under a lane-divergent
     condition anywhere in THIS loop's body (nested loops scope their own
     break/continue and are checked when their own walk runs)."""
@@ -2577,10 +2814,10 @@ def _has_divergent_exit(stmts: list, divergent: bool, uset, private) -> bool:
         if isinstance(s, (Break, Continue)) and divergent:
             return True
         if isinstance(s, If):
-            d = divergent or not _expr_uniform(s.cond, uset, private)
-            if _has_divergent_exit(s.then, d, uset, private):
+            d = divergent or not _expr_uniform(s.cond, uset, private, group)
+            if _has_divergent_exit(s.then, d, uset, private, group):
                 return True
-            if _has_divergent_exit(s.other, d, uset, private):
+            if _has_divergent_exit(s.other, d, uset, private, group):
                 return True
     return False
 
@@ -2593,7 +2830,7 @@ def _has_break(stmts: list) -> bool:
                for s in stmts)
 
 
-def _loop_diverges(node, uset, private) -> bool:
+def _loop_diverges(node, uset, private, group: bool = False) -> bool:
     """False iff every lane that enters this loop (For, While, DoWhile)
     provably leaves it on the same pass: its condition is lane-uniform and
     reads no buffer the loop stores to (a masked store is per lane), and no
@@ -2602,10 +2839,10 @@ def _loop_diverges(node, uset, private) -> bool:
     adds no divergence to what surrounds it."""
     body = node.body + ([node.step] if getattr(node, "step", None) is not None else [])
     if node.cond is not None and (
-            not _expr_uniform(node.cond, uset, private)
+            not _expr_uniform(node.cond, uset, private, group)
             or _vars_read(node.cond) & _stored_bufs(body)):
         return True
-    return _has_divergent_exit(node.body, False, uset, private)
+    return _has_divergent_exit(node.body, False, uset, private, group)
 
 
 def _contains_return(stmts: list) -> bool:
@@ -2626,10 +2863,14 @@ def _contains_return(stmts: list) -> bool:
 
 
 def _private_array_names(stmts: list, out: set[str] | None = None) -> set[str]:
+    """The private arrays declared under ``stmts``, and the ``__local`` ones
+    (kernel scope only): see :func:`_local_arrays` for those alone."""
     if out is None:
         out = set()
     for s in stmts:
-        if isinstance(s, Decl):
+        if isinstance(s, LocalDecl):
+            out.add(s.name)
+        elif isinstance(s, Decl):
             out.update(s.arrays)
         elif isinstance(s, If):
             _private_array_names(s.then, out)
@@ -2643,9 +2884,12 @@ def _private_array_names(stmts: list, out: set[str] | None = None) -> set[str]:
     return out
 
 
-def _uniform_vars(body: list, value_params: set[str]) -> set[str]:
+def _uniform_vars(body: list, value_params: set[str],
+                  group: bool = False) -> set[str]:
     """The locals that provably hold the SAME value in every lane that can
-    observe them.  Monotone-poisoning fixed point: start assuming every
+    observe them (with ``group``: in every such lane of a work-group, where
+    ``get_group_id(0)`` and a ``__local`` element at such an index are the
+    same too).  Monotone-poisoning fixed point: start assuming every
     local is; poison any variable assigned a non-uniform value, or assigned
     in another REGION than the one it was declared in; repeat until stable.
 
@@ -2666,8 +2910,9 @@ def _uniform_vars(body: list, value_params: set[str]) -> set[str]:
     # here)
     if _contains_return(body):
         return set()
-    private = _private_array_names(body)
-    uset: set[str] = (set(value_params) | set(_assigned_vars(body))) - private
+    arrays = _private_array_names(body)
+    uset: set[str] = (set(value_params) | set(_assigned_vars(body))) - arrays
+    private = arrays - set(_local_arrays(body)) if group else arrays
     # declared-but-unassigned names also start uniform (zero-init)
 
     changed = True
@@ -2690,15 +2935,16 @@ def _uniform_vars(body: list, value_params: set[str]) -> set[str]:
                             poison(name)  # one name, two regions' lanes
                         if name in s.arrays:
                             poison(name)  # per-lane stores make stacks diverge
-                        elif init is not None and not _expr_uniform(init, uset, private):
+                        elif init is not None and not _expr_uniform(
+                                init, uset, private, group):
                             poison(name)
                 elif isinstance(s, (Assign, CrementStmt)) and isinstance(s.target, Var):
                     if home.get(s.target.name) != region or not (
                             isinstance(s, CrementStmt)
-                            or _expr_uniform(s.value, uset, private)):
+                            or _expr_uniform(s.value, uset, private, group)):
                         poison(s.target.name)
                 elif isinstance(s, If):
-                    if _expr_uniform(s.cond, uset, private):
+                    if _expr_uniform(s.cond, uset, private, group):
                         walk(s.then, region)
                         walk(s.other, region)
                     else:
@@ -2713,10 +2959,122 @@ def _uniform_vars(body: list, value_params: set[str]) -> set[str]:
                     # lanes that leave on different passes make every
                     # assignment to an outer local in the loop diverge
                     walk(inner, new_region()
-                         if _loop_diverges(s, uset, private) else region)
+                         if _loop_diverges(s, uset, private, group) else region)
 
         walk(body, 0)
     return uset
+
+
+def _local_arrays(body: list) -> dict:
+    """``{name: LocalDecl}`` of a kernel's ``__local`` arrays (kernel scope is
+    the only place the parser lets them stand)."""
+    return {s.name: s for s in body if isinstance(s, LocalDecl)}
+
+
+def _tid_vars(body: list) -> frozenset:
+    """The locals that ARE the work item's local id: declared at kernel scope
+    as ``get_local_id(0)`` (under integer casts) and assigned nowhere else."""
+    assigned: dict[str, int] = {}
+    for node in _walk(body):
+        if isinstance(node, Decl):
+            for name, _init in node.names:
+                assigned[name] = assigned.get(name, 0) + 1
+        elif isinstance(node, (Assign, CrementStmt)) and isinstance(
+                node.target, Var):
+            assigned[node.target.name] = assigned.get(node.target.name, 0) + 1
+    out = set()
+    for s in body:
+        if not isinstance(s, Decl) or s.ctype not in _INT_TYPES:
+            continue
+        for name, init in s.names:
+            if (_is_local_id(init) and assigned.get(name) == 1
+                    and name not in s.arrays):
+                out.add(name)
+    return frozenset(out)
+
+
+class _Coop(NamedTuple):
+    """What a build knows of a kernel whose work items cooperate
+    (:func:`_cooperation`)."""
+
+    arrays: dict            # name -> LocalDecl
+    barriers: int           # barrier statements in the kernel's body
+    group_uniform: set      # locals the same in every lane of a group
+    tid_vars: frozenset     # locals that are get_local_id(0)
+
+    @property
+    def nbytes(self) -> int:
+        """The bytes of local memory ONE work-group holds."""
+        return sum(d.size * ctype_to_dtype(d.ctype).itemsize
+                   for d in self.arrays.values())
+
+
+def cooperates(kernel: KernelDef) -> bool:
+    """Do the kernel's work items cooperate inside their group: has it a
+    ``__local`` array or a barrier?"""
+    return any(isinstance(n, (LocalDecl, Barrier)) for n in _walk(kernel.body))
+
+
+def _cooperation(kernel: KernelDef) -> Optional[_Coop]:
+    """None for a kernel with neither a ``__local`` array nor a barrier (the
+    build then does nothing it did not do before); else what the lowering
+    needs, with every barrier PROVED to be reached by all work items of a
+    group together (:func:`_check_barriers` raises where one is not)."""
+    if not cooperates(kernel):
+        return None
+    arrays = _local_arrays(kernel.body)
+    barriers = sum(isinstance(n, Barrier) for n in _walk(kernel.body))
+    values = {p.name for p in kernel.params if not p.is_pointer}
+    gset = _uniform_vars(kernel.body, values, group=True)
+    if barriers:
+        _check_barriers(kernel, gset)
+    return _Coop(arrays, barriers, gset, _tid_vars(kernel.body))
+
+
+def _check_barriers(kernel: KernelDef, gset: set[str]) -> None:
+    """A barrier is legal only where every work item of a group reaches it:
+    at kernel scope, or inside ``if`` / ``for`` / ``while`` whose conditions
+    are the same in every lane of a GROUP (``gset``: :func:`_uniform_vars`
+    with ``group``), in a loop no ``break`` / ``continue`` leaves under any
+    other condition, in a kernel with no early ``return``.  Anywhere else:
+    ``KernelLanguageError`` naming ``barrier-divergent`` and the line."""
+    private = frozenset(_private_array_names(kernel.body)) - set(
+        _local_arrays(kernel.body))
+
+    def refuse(node, why: str):
+        raise KernelLanguageError(
+            f"barrier-divergent: this barrier is not reached by every work "
+            f"item of a group together: {why}.  A barrier may stand at kernel "
+            "scope or under conditions built from literals, value parameters, "
+            "get_local_size / get_num_groups / get_global_size / get_group_id "
+            "and locals assigned only from such", line=node.line)
+
+    def walk(stmts, why) -> None:
+        for s in stmts:
+            if isinstance(s, Barrier):
+                if why:
+                    refuse(s, why)
+            elif isinstance(s, If):
+                inner = why or (None if _expr_uniform(
+                    s.cond, gset, private, group=True) else
+                    f"the condition of the `if` on line {s.line} differs "
+                    "between the work items of a group")
+                walk(s.then, inner)
+                walk(s.other, inner)
+            elif isinstance(s, (For, While, DoWhile)):
+                body = s.body + ([s.step] if getattr(s, "step", None) else [])
+                inner = why or (None if not _loop_diverges(
+                    s, gset, private, group=True) else
+                    f"the work items of a group leave the loop on line "
+                    f"{s.line} on different passes (its condition, or a break "
+                    "/ continue under a condition that differs between them)")
+                walk(body, inner)
+
+    if _contains_return(kernel.body):
+        first = next(n for n in _walk(kernel.body) if isinstance(n, Barrier))
+        refuse(first, "the kernel has an early `return`, which takes work "
+                      "items out of every barrier behind it")
+    walk(kernel.body, None)
 
 
 def _loop_counts(kernel: KernelDef, uset: set[str]) -> tuple[int, int]:
@@ -2911,6 +3269,17 @@ class KernelBuildInfo:
     # (a slice on the dense path) that a chunk lowers as a gather and as a
     # scatter; ``()`` where no loop of the build was made compactable
     compact: tuple = ()
+    # work-group cooperation: ``(arrays, bytes, barriers)``, the kernel's
+    # ``__local`` arrays, the bytes of them one work-group holds and its
+    # barrier statements, from the syntax; and, filled at trace, the access
+    # sites of those arrays by lowering, ``{"shift": x, "uniform": y, "row":
+    # z}`` (``row`` is the fallback, a gather / scatter inside the group's
+    # row).  ``()`` / ``{}`` for a kernel with neither array nor barrier
+    local: tuple = ()
+    local_sites: dict = field(default_factory=dict)
+
+
+LOCAL_KINDS = ("shift", "uniform", "row")
 
 
 def hlo_name(*kernel_names: str) -> str:
@@ -2961,12 +3330,20 @@ def build_kernel_fn(
     info.loops_counted, info.loops_masked = _loop_counts(kernel, uniform)
     pitches = pitch_params(kernel)
     readonly = frozenset(info.array_params) - _stored_bufs(kernel.body)
+    coop = _cooperation(kernel)  # raises on a barrier a group does not reach
+    if coop is not None:
+        info.local = (len(coop.arrays), coop.nbytes, coop.barriers)
+        if coop.arrays and chunk % local_size:
+            raise KernelLanguageError(
+                f"kernel {kernel.name!r} has a __local array: a launch covers "
+                f"whole work-groups, and {chunk} work items are no multiple of "
+                f"the local range {local_size}", line=kernel.line)
 
     def fn(offset, arrays: tuple, values: tuple = (), keys: tuple | None = None,
            views: dict | None = None):
         ctx = _Ctx(chunk, jnp.asarray(offset, jnp.int32), global_size, local_size, {},
                    in_range)
-        ctx.adopt(kernel, uniform)
+        ctx.adopt(kernel, uniform, coop)
         ctx.row_gathers = platform == "tpu"
         ctx.readonly = readonly
         ctx.kept = {(info.array_params[p], kind): v
@@ -2989,6 +3366,9 @@ def build_kernel_fn(
         for kind in ctx.access.values():
             info.access[kind] += 1
         info.access["carried"] = len(ctx.carried)
+        if coop is not None:
+            kinds = list(ctx.local_access.values())
+            info.local_sites = {k: kinds.count(k) for k in LOCAL_KINDS}
         info.scattered = tuple(ctx.scattered)
         own = [kind for site, kind in ctx.compact_access.items()
                if ctx.access.get(site) != kind]

@@ -7,9 +7,11 @@ ClNumberCruncher.cs:219-228).  TPUs cannot execute C, so we define the
 C-like subset — ``__kernel void name(__global float* a, ...)`` functions with
 scalar locals, arithmetic, comparisons, ``if``/``for``/``while`` with
 ``break``/``continue``, and the common math builtins — which the codegen (codegen.py) vectorizes over work
-items and lowers to JAX/XLA.  Unsupported constructs (local memory, barriers,
-atomics, vector types, pointers beyond parameters) raise
-:class:`KernelLanguageError` with the offending line.
+items and lowers to JAX/XLA.  A work-group's items cooperate through
+``__local T name[K];`` arrays declared at kernel scope and ``barrier()``
+statements (docs/KERNEL_LANGUAGE.md, *Work-group cooperation*).  Unsupported
+constructs (``__local`` parameters, atomics, vector types, pointers beyond
+parameters) raise :class:`KernelLanguageError` with the offending line.
 
 This module is the front end only: source → list of :class:`KernelDef` ASTs.
 """
@@ -189,6 +191,24 @@ class Decl(Node):
     # (``float acc[4];`` — OpenCL __private memory, ClArray.cs kernels use
     # these for per-work-item scratch)
     arrays: dict = field(default_factory=dict)
+
+
+@dataclass
+class LocalDecl(Node):
+    """``__local T name[K];`` at kernel scope: one array of ``K`` elements a
+    work-group, shared by its work items (OpenCL __local memory)."""
+
+    ctype: str
+    name: str
+    size: int
+
+
+@dataclass
+class Barrier(Node):
+    """``barrier(flags);`` / ``work_group_barrier(flags);`` — a statement;
+    ``flags`` are the fence names it was given (``CLK_LOCAL_MEM_FENCE``)."""
+
+    flags: tuple = ()
 
 
 @dataclass
@@ -448,6 +468,7 @@ class _Parser:
             self.expect("{")
             body = self.parse_block_items()
             self.expect("}")
+            _local_decls_at_kernel_scope(body)
             kernels.append(
                 KernelDef(name=name_tok.text, params=params, body=body,
                           source=self.source, helpers=helpers, line=start.line)
@@ -476,8 +497,9 @@ class _Parser:
                     space = "constant"
                 elif t in ("__local", "local"):
                     raise KernelLanguageError(
-                        "__local memory parameters are not supported on TPU "
-                        "(no work-group shared memory in the vectorized contract)",
+                        "__local parameters are not supported (every pointer "
+                        "parameter binds to an array of the caller's): declare "
+                        "it inside the kernel, `__local float tile[256];`",
                         line=line,
                     )
                 elif t == "const":
@@ -540,6 +562,8 @@ class _Parser:
                 self.advance()
                 self.expect(";")
                 return (Break if t.text == "break" else Continue)(line=t.line)
+            if t.text in ("__local", "local"):
+                return self.parse_local_decl()
             if t.text in _TYPE_KWS or t.text == "const":
                 return self.parse_decl()
         stmt = self.parse_expr_statement()
@@ -587,6 +611,42 @@ class _Parser:
             self.expect(",")
         return Decl(ctype=ctype, names=names, arrays=arrays, line=line)
 
+    def parse_local_decl(self) -> LocalDecl:
+        """``__local T name[K];`` (``K`` a literal, or a ``#define``, which
+        is one by now): kernel scope is checked once the body is whole."""
+        line = self.advance().line
+        if self._in_helper:
+            raise KernelLanguageError(
+                "__local arrays belong to a kernel, not a helper function",
+                line=line)
+        while self.accept("const") or self.accept("volatile"):
+            pass
+        ctype = self.parse_type()
+        if self.cur.text == "*":
+            raise KernelLanguageError(
+                "a __local array indexed through a pointer is not supported; "
+                "index the array by its name", line=line)
+        name_tok = self.advance()
+        if name_tok.kind != "id":
+            raise self.err(f"expected array name, found {name_tok.text!r}", name_tok.line)
+        if not self.accept("["):
+            raise KernelLanguageError(
+                f"__local {name_tok.text!r} must be an array, "
+                "`__local float tile[256];`", line=line)
+        size_tok = self.advance()
+        if size_tok.kind != "num" or not size_tok.text.isdigit() \
+                or int(size_tok.text) <= 0:
+            raise KernelLanguageError(
+                "__local array size must be a positive integer literal "
+                "(or a #define of one)", line=size_tok.line)
+        self.expect("]")
+        if self.cur.text == "[":
+            raise KernelLanguageError(
+                "__local arrays have one dimension", line=line)
+        self.expect(";")
+        return LocalDecl(ctype=ctype, name=name_tok.text,
+                         size=int(size_tok.text), line=line)
+
     def parse_expr_statement(self):
         """assignment / compound assignment / ++ / -- / bare call"""
         line = self.cur.line
@@ -605,6 +665,8 @@ class _Parser:
             return CrementStmt(target=lhs, op=t, line=line)
         # bare expression statement (e.g. a call) — only calls are meaningful
         if isinstance(lhs, Call):
+            if lhs.name in BARRIER_CALLS:
+                return Barrier(flags=_fence_flags(lhs), line=line)
             return Assign(target=None, op="expr", value=lhs, line=line)
         raise self.err(f"expression statement has no effect (near {t!r})", line)
 
@@ -786,6 +848,55 @@ class _Parser:
             self.expect(")")
             return e
         raise self.err(f"unexpected token {t.text!r}")
+
+
+BARRIER_CALLS = ("barrier", "work_group_barrier")
+_FENCE_FLAGS = ("CLK_LOCAL_MEM_FENCE", "CLK_GLOBAL_MEM_FENCE")
+
+
+def _fence_flags(call: Call) -> tuple:
+    """The fence names of a ``barrier(...)`` call: ``CLK_LOCAL_MEM_FENCE``,
+    ``CLK_GLOBAL_MEM_FENCE``, both joined by ``|``, or a literal (``0``)."""
+    if len(call.args) != 1:
+        raise KernelLanguageError(
+            f"{call.name} takes one argument, the fence flags", line=call.line)
+    flags: list = []
+
+    def walk(e) -> None:
+        if isinstance(e, BinOp) and e.op == "|":
+            walk(e.left)
+            walk(e.right)
+        elif isinstance(e, Var) and e.name in _FENCE_FLAGS:
+            flags.append(e.name)
+        elif not isinstance(e, Num):
+            raise KernelLanguageError(
+                f"{call.name}: the flags are CLK_LOCAL_MEM_FENCE and / or "
+                "CLK_GLOBAL_MEM_FENCE", line=call.line)
+
+    walk(call.args[0])
+    return tuple(flags)
+
+
+def _local_decls_at_kernel_scope(body: list) -> None:
+    """A ``__local`` array is the work-group's for the whole kernel: declared
+    in a branch or a loop it would be another array a pass."""
+    def nested(stmts) -> None:
+        for s in stmts:
+            if isinstance(s, LocalDecl):
+                raise KernelLanguageError(
+                    f"__local array {s.name!r} must be declared at kernel "
+                    "scope, not inside a branch or a loop", line=s.line)
+            inner(s)
+
+    def inner(s) -> None:
+        if isinstance(s, If):
+            nested(s.then)
+            nested(s.other)
+        elif isinstance(s, (For, While, DoWhile)):
+            nested(s.body)
+
+    for s in body:
+        inner(s)
 
 
 def _parse_num(t: Token) -> Num:

@@ -479,6 +479,12 @@ def build_kernel_fn_pallas(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if codegen.cooperates(kernel):
+        # a tile is one grid step's work items, not a launch's work-groups:
+        # the XLA half holds the group's row beside the lane vectors
+        raise PallasUnsupported(
+            "local-memory: the work items of a group cooperate (__local array "
+            "or barrier); the vectorized lowering runs it")
     if chunk % LANES != 0:
         raise PallasUnsupported(f"chunk {chunk} not a multiple of {LANES}")
     if not interpret and _mentions_half(kernel):

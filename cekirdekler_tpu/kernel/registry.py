@@ -508,7 +508,12 @@ def lowering_meta(infos) -> dict:
     ``compact`` (``loops:1;width:8192;gathered:3;scattered:0``), where a
     build made a masked loop compactable (``codegen._exec_compacted``): the
     loops, the lanes of a chunk, and the reads and stores at the lane's own
-    element that a chunk lowers as gathers and scatters."""
+    element that a chunk lowers as gathers and scatters.
+    ``local`` (``arrays:1;bytes:1024;barriers:2;sites:shift:6,uniform:1,row:0``),
+    where a kernel's work items cooperate inside their group: its ``__local``
+    arrays, the bytes of them one work-group holds, its barrier statements,
+    and the arrays' access sites by lowering (``codegen._local_load``);
+    ``access`` then ends in ``;local:N``, the sites' total."""
     infos = list(infos)
     leaves = [r for i in infos for r in (i.rungs or (i,))]
     meta = {"lowering": "+".join(sorted({i.lowering for i in leaves})),
@@ -543,10 +548,24 @@ def lowering_meta(infos) -> dict:
         mine = per_kernel.setdefault(i.name, {})
         for kind, n in i.access.items():
             mine[kind] = max(mine.get(kind, 0), n)
+    # work-group cooperation, summed over the kernels like ``access``: the
+    # __local arrays, the bytes one group holds, the barrier statements, and
+    # the arrays' access sites by lowering (``row`` is the fallback)
+    coop = {i.name: i for i in leaves if i.local}
+    if coop:
+        arrays, nbytes, barriers = (sum(x) for x in zip(
+            *(i.local for i in coop.values())))
+        sites = {kind: sum(max([r.local_sites.get(kind, 0) for r in leaves
+                                if r.name == name]) for name in coop)
+                 for kind in codegen.LOCAL_KINDS}
+        meta["local"] = (f"arrays:{arrays};bytes:{nbytes};barriers:{barriers};"
+                         "sites:" + ",".join(f"{k}:{n}" for k, n in sites.items()))
     if any(per_kernel.values()):
         meta["access"] = ";".join(
             f"{kind}:{sum(k.get(kind, 0) for k in per_kernel.values())}"
             for kind in codegen.ACCESS_KINDS)
+        if coop:
+            meta["access"] += f";local:{sum(sites.values())}"
     # the stores that became a scatter, summed over the kernels as
     # ``access`` is, with the bytes of one element of each (``+``-joined)
     scattered = {i.name: i.scattered for i in leaves if i.scattered}
@@ -638,6 +657,7 @@ class KernelProgram:
         # stores to (kernel names -> positions)
         self.kept_views = _KeptViews()
         self._frozen: dict[tuple, frozenset] = {}
+        self._cooperates: dict[str, bool] = {}
         # partition-safety/flag-soundness verification (analysis/):
         # access summaries build once per kernel on first verify();
         # launch verdicts cache per (names, flag rows, window).  Both
@@ -727,6 +747,17 @@ class KernelProgram:
         if name in self._c_kernels:
             return [p.name for p in self._c_kernels[name].params if not p.is_pointer]
         return list(self._py_kernels[name].value_params)
+
+    def cooperates(self, name: str) -> bool:
+        """Do the work items of kernel ``name`` cooperate inside their group
+        (a ``__local`` array or a barrier)?  Its launches then cover whole
+        work-groups (docs/KERNEL_LANGUAGE.md, *Work-group cooperation*)."""
+        hit = self._cooperates.get(name)
+        if hit is None:
+            kdef = self._c_kernels.get(name)
+            hit = self._cooperates[name] = (kdef is not None
+                                            and codegen.cooperates(kdef))
+        return hit
 
     def frozen(self, names: tuple) -> frozenset:
         """The positions of a launch's buffer tuple that NO kernel among

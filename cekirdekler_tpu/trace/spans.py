@@ -117,6 +117,7 @@ compute has ended.
 from __future__ import annotations
 
 import itertools
+import re
 import sys
 import threading
 import time
@@ -148,6 +149,9 @@ class _Context(threading.local):
 
 
 _CTX = _Context()
+
+# characters that may not stand in a value of a TraceMe annotation's metadata
+_UNSAFE_IN_META = re.compile(r"[,#'\"]")
 
 
 class Span(NamedTuple):
@@ -244,9 +248,11 @@ class Tracer:
             meta["tag"] = tag
         for k, v in meta.items():
             # '#' ends the metadata block of a TraceMe name and ',' a
-            # value in it: a tag ``[2048, 2048]`` would arrive as ``[2048``
-            if v.__class__ is str and ("," in v or "#" in v):
-                meta[k] = v.replace("#", "").replace(",", ";")
+            # value in it: a tag ``[2048, 2048]`` would arrive as ``[2048``;
+            # a quote opens a quoted value that swallows every field behind
+            # it (a veto that read "the kernel's ..." took ``lane`` with it)
+            if v.__class__ is str and _UNSAFE_IN_META.search(v):
+                meta[k] = _UNSAFE_IN_META.sub("", v.replace(",", ";"))
         if ctx:
             win, queued_us = ctx.get("win"), ctx.get("queued_us")
             if win is not None:

@@ -56,6 +56,8 @@ FULL = {
     "halo_wh": 1024, "halo_window": 20,
     # Rodinia's BFS at the suite's middle input (graph65536.txt's size)
     "bfs_nodes": 65536,
+    # SHOC's Reduction at 2^20 elements (4 MB, between its two smallest classes)
+    "reduce_elements": 1 << 20,
     # stage 2 — 256 MiB per array: not a cache
     "stream_n": 1 << 26, "stream_tuner_runs": 3,
     # stage 3 — the examples/wave_equation.py stage
@@ -690,6 +692,70 @@ def _bfs_traversal(devices, sizes) -> dict:
         cr.dispose()
 
 
+def _group_reduction(devices, sizes) -> dict:
+    """SHOC's ``reduce`` (PR 45): work items of a group cooperate through a
+    ``__local`` tile and barriers.  ONE lane, 64 groups of 256, one
+    synchronous ``compute()``; the 64 partials back in the caller's array
+    equal the configuration's plain reference partial by partial, the host's
+    sum is the array's, and the launch's span fields say how the tile was
+    lowered: two barriers, six shifts, one broadcast, no row fallback, on
+    the XLA half with the ``local-memory`` veto."""
+    import importlib.util
+
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+    from cekirdekler_tpu.kernel.registry import lowering_meta
+
+    configs = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs")
+    spec = importlib.util.spec_from_file_location(
+        "shoc_reduction_ref", os.path.join(configs, "shoc_reduction_ref.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    with open(os.path.join(configs, "shoc_reduction.cl")) as f:
+        source = f.read()
+    elements, groups, lr = sizes["reduce_elements"], 64, 256
+    n = groups * lr
+    data, _values = ref.inputs({"elements": elements, "local_range": lr},
+                               {"n": n}, np.random.default_rng(sizes["seed"]))
+    x = ClArray(data["g_idata"], name="g_idata", read_only=True)
+    out = ClArray(data["g_odata"], name="g_odata", read=False, write=True,
+                  write_all=True)
+    group = x.next_param(out)
+    cr = NumberCruncher(devices.subset(1), source)
+    w = cr.cores.workers[0]
+    try:
+        def reduce_to(upto: int) -> float:
+            group.compute(cr, 7109, "reduce", n, lr, values=(upto,))
+            return float(np.sum(out.host(), dtype=np.float64))
+
+        _, cold_s = _timed(lambda: reduce_to(elements))
+        upto = elements - 2 * n  # another prefix: a stale partial would show
+        total, run_s = _timed(lambda: reduce_to(upto))
+        want = ref.partials(data["g_idata"], upto, groups, lr)
+        differing = int((out.host().astype(np.float64) != want).sum())
+        _require(differing == 0 and total == float(want.sum()),
+                 f"reduce: {differing} partials differ, sum {total} for "
+                 f"{float(want.sum())}")
+        info = cr.cores.program.launcher(
+            "reduce", n, lr, n, platform=w.device.platform)[1]
+        meta = lowering_meta([info])
+        _require(meta.get("local") == "arrays:1;bytes:1024;barriers:2;"
+                 "sites:shift:6,uniform:1,row:0"
+                 and meta["access"].endswith(";local:7"),
+                 f"reduce: local {meta.get('local')}, access {meta['access']}")
+        if w.device.platform == "tpu":
+            _require((info.lowering, (info.veto or "")[:12])
+                     == ("xla", "local-memory"),
+                     f"reduce: lowering {info.lowering}, veto {info.veto}")
+        return _row("group reduction compute()", meta["lowering"], cold_s,
+                    run_s, float(differing), local=meta["local"],
+                    access=meta["access"], loops=meta["loops"],
+                    call_ms=round(1e3 * run_s, 3), sum=total)
+    finally:
+        cr.dispose()
+
+
 def stage_compute(devices, sizes) -> list[dict]:
     from cekirdekler_tpu import ClArray
     from cekirdekler_tpu.core.cruncher import NumberCruncher
@@ -767,6 +833,7 @@ def stage_compute(devices, sizes) -> list[dict]:
     rows.append(_nbody_windows_start_on_the_ladder(devices, sizes))
     rows.append(_mandelbrot_frame_read_back(devices, sizes, want))
     rows.append(_bfs_traversal(devices, sizes))
+    rows.append(_group_reduction(devices, sizes))
     return rows
 
 

@@ -21,7 +21,7 @@ TOY = dict(
     mandel_wh=64, mandel_max_iter=32, local_range=128,
     mandel_per_call=3, mandel_window=6, mandel_marker_window=4,
     nbody_n=256, nbody_iters=6, nbody_window=3,
-    halo_wh=64, halo_window=6, bfs_nodes=1000,
+    halo_wh=64, halo_window=6, bfs_nodes=1000, reduce_elements=1 << 17,
     stream_n=1 << 14, stream_tuner_runs=2,
     wave_pushes=6,
     serve_tenants=2, serve_sigs=2, serve_reqs=4,
@@ -49,8 +49,8 @@ def _check_rows(rows, n_min=1):
 
 def test_stage_compute(devs):
     rows = chip_smoke.stage_compute(devs, TOY)
-    _check_rows(rows, 8)
-    kl, hand, forced, nbody, wave, starts, shown, bfs = rows
+    _check_rows(rows, 9)
+    kl, hand, forced, nbody, wave, starts, shown, bfs, reduction = rows
     # CPU lanes take the XLA lowering by policy; the routing assertion
     # itself only binds on TPU lanes
     assert kl["lowering"] == "xla"
@@ -86,11 +86,17 @@ def test_stage_compute(devs):
     # the toy's launch is no wider than a chunk: nothing compactable
     assert bfs["compact"] == "" and bfs["level_ms"] > 0
     assert bfs["flag_bytes_up"] == bfs["flag_bytes_back"] == bfs["levels"]
+    # SHOC's reduce: the group's tile by shifts and one broadcast, exact
+    assert reduction["name"] == "group reduction compute()"
+    assert reduction["max_err"] == 0.0 and reduction["lowering"] == "xla"
+    assert reduction["local"] == ("arrays:1;bytes:1024;barriers:2;"
+                                  "sites:shift:6,uniform:1,row:0")
+    assert reduction["loops"] == "counted:1;masked:1"
 
 
 def test_stage_compute_partitions_a_single_device():
     rows = chip_smoke.stage_compute(platforms().cpus().subset(1), TOY)
-    assert rows[-4]["lanes"] == 2 and all(r > 0 for r in rows[-4]["ranges"])
+    assert rows[-5]["lanes"] == 2 and all(r > 0 for r in rows[-5]["ranges"])
 
 
 def test_stage_transfers(devs):

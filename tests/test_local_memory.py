@@ -1,0 +1,507 @@
+"""Work-group cooperation through ``compute()`` on the CPU rig: ``__local``
+arrays, ``barrier()`` in group-uniform flow, a tree / a scan / a reversal in
+the tile (ISSUE 45).  The system's normal path (``KernelProgram`` /
+``compute()``) against plain numpy on seeded data; small integers as float32,
+so every comparison is exact.  Nothing here yields a device number.
+
+SHOC's ``reduce`` is the benchmark's own file (``benchmark/configs/
+shoc_reduction.cl``) and is held to the configuration's plain reference
+(``shoc_reduction_ref.partials``), which imports nothing of the program.
+"""
+
+import hashlib
+import importlib.util
+import os
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+jnp = jax.numpy
+
+from cekirdekler_tpu import ClArray  # noqa: E402
+from cekirdekler_tpu.analysis import flag_row, summarize_kernel  # noqa: E402
+from cekirdekler_tpu.arrays.clarray import ComputeValidationError  # noqa: E402
+from cekirdekler_tpu.core.cruncher import NumberCruncher  # noqa: E402
+from cekirdekler_tpu.core.worker import launch_ladder  # noqa: E402
+from cekirdekler_tpu.errors import KernelLanguageError  # noqa: E402
+from cekirdekler_tpu.hardware import platforms  # noqa: E402
+from cekirdekler_tpu.kernel import codegen, lang  # noqa: E402
+from cekirdekler_tpu.kernel.registry import KernelProgram, lowering_meta  # noqa: E402
+from cekirdekler_tpu.trace.spans import TRACER  # noqa: E402
+
+CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "configs")
+
+
+def source(name: str) -> str:
+    with open(os.path.join(CONFIGS, name), encoding="utf-8") as f:
+        return f.read()
+
+
+REDUCE = source("shoc_reduction.cl")
+_spec = importlib.util.spec_from_file_location(
+    "shoc_reduction_ref", os.path.join(CONFIGS, "shoc_reduction_ref.py"))
+ref = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(ref)
+
+# reverse inside the group: the row fallback (the index runs against tid)
+REVERSE = """
+__kernel void reverse(__global const float* a, __global float* b) {
+    __local float t[256];
+    int tid = get_local_id(0);
+    int gid = get_global_id(0);
+    t[tid] = a[gid];
+    barrier(CLK_LOCAL_MEM_FENCE);
+    b[gid] = t[get_local_size(0) - 1 - tid];
+}"""
+
+# a Hillis-Steele scan in the tile: a uniform loop with two barriers a pass
+SCAN = """
+#define TILE 256
+__kernel void scan(__global const float* a, __global float* b) {
+    __local float t[TILE];
+    int tid = get_local_id(0);
+    int gid = get_global_id(0);
+    t[tid] = a[gid];
+    barrier(CLK_LOCAL_MEM_FENCE);
+    for (int d = 1; d < get_local_size(0); d <<= 1) {
+        float v = t[tid];
+        if (tid >= d) { v += t[tid - d]; }
+        barrier(CLK_LOCAL_MEM_FENCE);
+        t[tid] = v;
+        barrier(CLK_LOCAL_MEM_FENCE);
+    }
+    b[gid] = t[tid];
+}"""
+
+# a group's own offset into the tile, a broadcast of one element a group, a
+# store that one work item a group passes, a barrier under a group-uniform if
+GROUPWISE = """
+__kernel void groupwise(__global const float* a, __global float* b, int n) {
+    __local float t[64];
+    __local float top[2];
+    int tid = get_local_id(0);
+    int grp = get_group_id(0);
+    int gid = get_global_id(0);
+    t[tid] = a[gid];
+    if (grp < n) {
+        barrier(CLK_LOCAL_MEM_FENCE | CLK_GLOBAL_MEM_FENCE);
+        if (tid == 3) { top[1] = t[tid + grp]; }
+        work_group_barrier(CLK_LOCAL_MEM_FENCE);
+    }
+    b[gid] = top[1] + t[grp];
+}"""
+
+
+@pytest.fixture(scope="module")
+def devs():
+    return platforms().cpus()
+
+
+def small_ints(n: int, seed: int) -> np.ndarray:
+    return np.random.default_rng(seed).integers(0, 3, n).astype(np.float32)
+
+
+def run(devs, lanes: int, src: str, kernel: str, arrays: list, n: int,
+        local: int, values=(), flags=()):
+    """One synchronous ``compute()``; returns the host arrays."""
+    bound = [ClArray(a, name=f"p{k}", **(flags[k] if flags else {}))
+             for k, a in enumerate(arrays)]
+    cr = NumberCruncher(devs.subset(lanes), src)
+    try:
+        first, *rest = bound
+        first.next_param(*rest).compute(cr, 45, kernel, n, local,
+                                        values=tuple(values))
+        assert cr.number_of_errors_happened == 0
+        return [np.array(b.host()) for b in bound]
+    finally:
+        cr.dispose()
+
+
+# -- SHOC's reduce ----------------------------------------------------------
+
+@pytest.mark.parametrize("groups", [1, 3, 64])
+@pytest.mark.parametrize("local", [64, 128, 256])
+def test_shoc_reduce_partials_are_the_references(devs, local, groups):
+    grid = 2 * local * groups
+    x = small_ints(5 * grid, seed=local + groups)
+    flags = ({"read_only": True},
+             {"read": False, "write": True, "write_all": True})
+    for n in (5 * grid, 3 * grid, 4 * grid - 2 * local - 7, 1):
+        got = run(devs, 1, REDUCE, "reduce",
+                  [x, np.full(groups, -1, np.float32)], groups * local, local,
+                  values=(n,), flags=flags)[1]
+        want = ref.partials(x, n, groups, local)
+        np.testing.assert_array_equal(got.astype(np.float64), want)
+        assert want.sum() == x[:n].sum(dtype=np.float64) or n % (2 * local)
+
+
+def test_the_reference_names_each_groups_elements():
+    """Group ``g`` takes elements ``[2 L g, 2 L (g + 1))`` of every pass of
+    the grid; a pass cut by ``n`` counts ``x[i] + x[i + L]`` while ``i < n``."""
+    x = np.arange(48, dtype=np.float32)
+    np.testing.assert_array_equal(ref.partials(x, 48, 3, 4), [
+        x[0:8].sum() + x[24:32].sum(), x[8:16].sum() + x[32:40].sum(),
+        x[16:24].sum() + x[40:48].sum()])
+    cut = ref.partials(x, 26, 3, 4)  # the second pass: i = 24, 25 alone
+    np.testing.assert_array_equal(cut, [
+        x[0:8].sum() + x[24] + x[28] + x[25] + x[29], x[8:16].sum(),
+        x[16:24].sum()])
+    np.testing.assert_array_equal(
+        ref.partials(x, 48, 3, 4, first_pass=1),
+        ref.partials(x, 48, 3, 4) - ref.partials(x, 24, 3, 4))
+
+
+def test_reduce_lowers_by_shifts_and_one_broadcast():
+    prog = KernelProgram(REDUCE)
+    fn, info = prog.launcher("reduce", 4 * 256, 256, 4 * 256)
+    x = small_ints(8 * 256 * 3, 5)
+    out = fn(0, (jnp.asarray(x), jnp.full(4, -1, jnp.float32)), (x.size,))
+    np.testing.assert_array_equal(np.asarray(out[1], np.float64),
+                                  ref.partials(x, x.size, 4, 256))
+    assert info.local == (1, 1024, 2)
+    assert info.local_sites == {"shift": 6, "uniform": 1, "row": 0}
+    assert (info.loops_counted, info.loops_masked) == (1, 1)
+    meta = lowering_meta([info])
+    assert meta["local"] == ("arrays:1;bytes:1024;barriers:2;"
+                             "sites:shift:6,uniform:1,row:0")
+    assert meta["access"].endswith(";carried:0;local:7")
+    assert info.compact == ()  # chunks of lanes would tear groups apart
+
+
+# -- the row fallback, a scan, group-uniform offsets ------------------------
+
+@pytest.mark.parametrize("local", [64, 256])
+def test_reverse_inside_the_group_is_the_row_fallback(devs, local):
+    a = small_ints(6 * local, 11) + np.arange(6 * local, dtype=np.float32)
+    got = run(devs, 1, REVERSE, "reverse", [a, np.zeros_like(a)],
+              a.size, local)[1]
+    np.testing.assert_array_equal(got, a.reshape(-1, local)[:, ::-1].ravel())
+    _fn, info = KernelProgram(REVERSE).launcher("reverse", local, local, local)
+    _fn(0, (jnp.asarray(a), jnp.asarray(a)))
+    assert info.local_sites == {"shift": 1, "uniform": 0, "row": 1}
+
+
+@pytest.mark.parametrize("local", [32, 256])
+def test_hillis_steele_scan_in_the_tile(devs, local):
+    a = small_ints(5 * local, 13)
+    got = run(devs, 1, SCAN, "scan", [a, np.zeros_like(a)], a.size, local)[1]
+    np.testing.assert_array_equal(
+        got, np.cumsum(a.reshape(-1, local), axis=1).ravel())
+    _fn, info = KernelProgram(SCAN).launcher("scan", local, local, local)
+    assert info.local == (1, 1024, 3)
+    assert (info.loops_counted, info.loops_masked) == (1, 0)
+
+
+@pytest.mark.parametrize("n", [0, 2, 5])
+def test_group_uniform_offsets_and_a_barrier_under_a_group_uniform_if(devs, n):
+    local, groups = 32, 5
+    a = small_ints(groups * local, 17) + np.arange(groups * local,
+                                                   dtype=np.float32)
+    got = run(devs, 1, GROUPWISE, "groupwise", [a, np.zeros_like(a)], a.size,
+              local, values=(n,))[1]
+    rows = a.reshape(groups, local)
+    grp = np.arange(groups)
+    top = np.where(grp < n, rows[grp, 3 + grp], 0.0)  # the tile starts at zero
+    want = (top + rows[grp, grp])[:, None] * np.ones((1, local), np.float32)
+    np.testing.assert_array_equal(got, want.ravel())
+
+
+def test_a_tile_shorter_and_longer_than_the_group():
+    """``K`` is the declaration's, whatever the local range: a store beyond
+    the row is dropped, a load beyond it reads the nearest element."""
+    src = """
+    __kernel void k(__global const float* a, __global float* b) {
+        __local float t[8];
+        int tid = get_local_id(0);
+        t[tid] = a[get_global_id(0)];
+        barrier(CLK_LOCAL_MEM_FENCE);
+        b[get_global_id(0)] = t[tid + 1];
+    }"""
+    a = np.arange(32, dtype=np.float32)
+    fn, _ = KernelProgram(src).launcher("k", 32, 16, 32)  # L = 16 > K = 8
+    got = np.asarray(fn(0, (jnp.asarray(a), jnp.zeros(32)))[1]).reshape(2, 16)
+    rows = a.reshape(2, 16)
+    np.testing.assert_array_equal(got[:, :7], rows[:, 1:8])
+    np.testing.assert_array_equal(got[:, 7:], np.repeat(rows[:, 7:8], 9, 1))
+    fn, _ = KernelProgram(src).launcher("k", 32, 4, 32)   # L = 4 < K = 8
+    got = np.asarray(fn(0, (jnp.asarray(a), jnp.zeros(32)))[1]).reshape(8, 4)
+    rows = a.reshape(8, 4)
+    np.testing.assert_array_equal(got[:, :3], rows[:, 1:])
+    assert (got[:, 3] == 0).all()  # element 4 of the tile: still zero
+
+
+# -- groups stay whole: one launch, ladder rungs, two lanes -----------------
+
+@pytest.mark.parametrize("src,kernel", [(REVERSE, "reverse"), (SCAN, "scan")])
+def test_whole_as_rungs_and_over_two_lanes_give_the_same_bytes(
+        devs, src, kernel):
+    local, groups = 64, 7  # a ladder of 4 + 2 + 1 groups on one lane
+    a = small_ints(groups * local, 19) + np.arange(groups * local,
+                                                   dtype=np.float32)
+    assert launch_ladder(groups * local, local) == [4 * local, 2 * local, local]
+    fn, _ = KernelProgram(src).launcher(kernel, a.size, local, a.size)
+    whole = np.asarray(fn(0, (jnp.asarray(a), jnp.zeros_like(a)))[1])
+    rungs = run(devs, 1, src, kernel, [a, np.zeros_like(a)], a.size, local)[1]
+    flags = ({"partial_read": True}, {"read": False, "write": True})
+    lanes = run(devs, 2, src, kernel, [a, np.zeros_like(a)], a.size, local,
+                flags=flags)[1]
+    assert whole.tobytes() == rungs.tobytes() == lanes.tobytes()
+
+
+@pytest.mark.parametrize("size,step", [(7 * 256, 256), (64 * 256, 256),
+                                       (1000 * 64, 64), (13 * 512, 512)])
+def test_every_rung_of_the_ladder_is_whole_units_of_the_step(size, step):
+    rungs = launch_ladder(size, step)
+    assert sum(rungs) == size and all(r % step == 0 for r in rungs)
+
+
+def test_a_launch_that_cuts_a_group_is_refused():
+    prog = KernelProgram(REVERSE)
+    with pytest.raises(KernelLanguageError, match="whole work-groups"):
+        prog.launcher("reverse", 96, 64, 192)
+
+
+def test_a_global_offset_inside_a_group_is_refused(devs):
+    a = small_ints(256, 3)
+    arrays = [ClArray(a, name="a"), ClArray(np.zeros_like(a), name="b")]
+    cr = NumberCruncher(devs.subset(1), REVERSE)
+    try:
+        group = arrays[0].next_param(arrays[1])
+        with pytest.raises(ComputeValidationError, match="whole work-groups"):
+            group.compute(cr, 7, "reverse", 128, 64, global_offset=32)
+        cr.reset_errors()
+        group.compute(cr, 7, "reverse", 128, 64, global_offset=64)
+        np.testing.assert_array_equal(
+            arrays[1].host()[64:192], a[64:192].reshape(2, 64)[:, ::-1].ravel())
+    finally:
+        cr.dispose()
+
+
+# -- spans, veto, verdict ----------------------------------------------------
+
+def test_the_launch_and_compile_spans_carry_the_local_field(devs, monkeypatch):
+    """The fields ride the spans' metadata (the profiler annotation): spied
+    on where the tracer records them."""
+    seen = []
+    real = TRACER.record
+
+    def record(kind, t0, *args, **meta):
+        if kind in ("launch", "compile"):
+            seen.append((kind, meta))
+        return real(kind, t0, *args, **meta)
+
+    monkeypatch.setattr(TRACER, "record", record)
+    x = small_ints(2 * 64 * 4 * 2, 23)
+    TRACER.enable(clear=True)
+    try:
+        run(devs, 1, REDUCE, "reduce", [x, np.full(4, -1, np.float32)],
+            4 * 64, 64, values=(x.size,),
+            flags=({"read_only": True},
+                   {"read": False, "write": True, "write_all": True}))
+    finally:
+        TRACER.disable()
+    assert {kind for kind, _meta in seen} == {"launch", "compile"}
+    for _kind, meta in seen:
+        assert meta["local"] == ("arrays:1;bytes:1024;barriers:2;"
+                                 "sites:shift:6,uniform:1,row:0")
+        assert meta["access"] == ("slice:0;strided:0;uniform:0;gather:2;"
+                                  "scatter:1;carried:0;local:7")
+
+
+def test_a_tpu_lane_takes_the_xla_half_with_a_named_veto():
+    prog = KernelProgram(REDUCE)
+    _fn, info = prog.launcher("reduce", 256, 256, 256, platform="tpu")
+    assert info.lowering == "xla" and info.veto.startswith("local-memory")
+    assert prog.lowerings("reduce", "tpu") == {("xla", info.veto)}
+    assert prog.cooperates("reduce")
+
+
+def test_a_local_array_is_no_buffer_to_the_verifier():
+    kdef, = lang.parse_kernels(REDUCE)
+    summary = summarize_kernel(kdef)
+    assert "sdata" not in summary.reads and "sdata" not in summary.writes
+    assert set(summary.reads) == {"g_idata"}
+    (write,) = summary.writes["g_odata"]
+    assert write.av.coef is None  # at get_group_id(0): not affine in the id
+    prog = KernelProgram(REDUCE)
+    rows = (flag_row(ClArray(np.zeros(8, np.float32), read_only=True)),
+            flag_row(ClArray(np.zeros(1, np.float32), read=False, write=True,
+                             write_all=True)))
+    assert not prog.verify(("reduce",), rows, lanes=1).errors
+    kinds = {f.kind for f in prog.verify(("reduce",), rows).errors}
+    assert "scatter-write" in kinds  # on more lanes: ROADMAP M12
+
+
+# -- the control: a kernel without tile or barrier builds what it built -----
+
+def _hlo(src_file: str, kernel: str, arrays: tuple, values: tuple = ()):
+    prog = KernelProgram(source(src_file))
+    fn, info = prog.launcher(kernel, 1024, 256, 4096)
+    text = fn.trace(0, arrays, values).lower().as_text()
+    return hashlib.sha256(text.encode()).hexdigest(), info
+
+
+def test_a_kernel_with_no_tile_builds_the_hlo_it_built_before():
+    """The hashes were taken on the parent commit (a5e08b9) with this very
+    function: a kernel with neither a ``__local`` array nor a barrier lowers
+    to the last operation as it did."""
+    f32 = jax.ShapeDtypeStruct((4096,), jnp.float32)
+    i32 = jax.ShapeDtypeStruct((4096,), jnp.int32)
+    sha, info = _hlo("hpcg_spmv.cl", "spmv", (i32, i32, f32, f32, f32),
+                     (np.float32(1.5),))
+    assert info.local == () and info.local_sites == {}
+    assert "local" not in lowering_meta([info])
+    assert sha == SPMV_SHA
+
+
+SPMV_SHA = "8548704244454052be315cdd13728139443dbb4d92d7997ff4f9187006ac1d5d"
+
+
+@pytest.mark.parametrize("name", ["nbody_direct.cl", "hpcg_spmv.cl",
+                                  "rodinia_bfs.cl", "polybench_mvt.cl"])
+def test_the_accepted_kernels_do_not_cooperate(name):
+    for kdef in lang.parse_kernels(source(name)):
+        assert codegen._cooperation(kdef) is None
+        assert not KernelProgram(source(name)).cooperates(kdef.name)
+
+
+# -- refusals ----------------------------------------------------------------
+
+def _kernel(body: str, params: str = "__global float* x") -> str:
+    return f"__kernel void k({params}) {{\n{body}\n}}"
+
+
+REFUSED = {
+    "a barrier under a per-lane if": (_kernel("""
+        __local float t[64];
+        int tid = get_local_id(0);
+        if (tid < 3) {
+            barrier(CLK_LOCAL_MEM_FENCE);
+        }
+        x[get_global_id(0)] = t[tid];"""), "^line 6: barrier-divergent.*`if` on line 5"),
+    "a barrier in a loop with a per-lane bound": (_kernel("""
+        int tid = get_local_id(0);
+        for (int j = 0; j < tid; j++) {
+            x[get_global_id(0)] += 1.0f;
+            barrier(CLK_GLOBAL_MEM_FENCE);
+        }"""), "^line 6: barrier-divergent.*loop on line 4"),
+    "a barrier behind a per-lane break": (_kernel("""
+        int tid = get_local_id(0);
+        for (int j = 0; j < 4; j++) {
+            if (x[get_global_id(0)] > 1.0f) break;
+            barrier(CLK_LOCAL_MEM_FENCE);
+        }"""), "^line 6: barrier-divergent.*loop on line 4"),
+    "a barrier under a local assigned from the local id": (_kernel("""
+        int pair = get_local_id(0) / 2;
+        if (pair == 0) { barrier(CLK_LOCAL_MEM_FENCE); }"""),
+        "^line 4: barrier-divergent.*`if` on line 4"),
+    "a barrier in a kernel with an early return": (_kernel("""
+        if (get_global_id(0) > 5) return;
+        barrier(CLK_LOCAL_MEM_FENCE);"""), "^line 4: barrier-divergent.*early `return`"),
+    "a __local parameter": (_kernel(
+        "x[0] = t[0];", "__global float* x, __local float* t"),
+        "declare it inside the kernel"),
+    "an atomic": (_kernel("atomic_add(x, 1);"), "atomics are not supported"),
+    "a memory fence": (_kernel("mem_fence(CLK_LOCAL_MEM_FENCE);"),
+                       "mem_fence"),
+    "a barrier as a value": (_kernel("int b = barrier(0);"),
+                             "statement of its own"),
+    "a __local pointer": (_kernel("__local float* p;"), "through a pointer"),
+    "a __local scalar": (_kernel("__local float s;"), "must be an array"),
+    "a __local array in a branch": (_kernel("""
+        if (get_group_id(0) == 0) { __local float t[4]; }"""),
+        "kernel scope"),
+    "a __local array sized at run time": (_kernel(
+        "__local float t[n];", "__global float* x, int n"),
+        "integer literal"),
+    "a __local array as a whole": (_kernel("""
+        __local float t[4];
+        t = 1.0f;"""), "as a whole"),
+    "dimension 1": (_kernel("""
+        __local float t[4];
+        t[get_local_id(1)] = 1.0f;"""), "only dimension 0"),
+    "a barrier with no flags": (_kernel("barrier();"), "one argument"),
+    "unknown fence flags": (_kernel("barrier(SOME_FENCE);"),
+                            "CLK_LOCAL_MEM_FENCE"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFUSED))
+def test_refused(case):
+    src, message = REFUSED[case]
+    with pytest.raises(KernelLanguageError, match=message):
+        fn, _ = KernelProgram(src).launcher("k", 64, 64, 64)
+        fn(0, (jnp.zeros(64, jnp.float32),) + (
+            (jnp.zeros(4, jnp.float32),) if "__local float* t" in src else ())
+           + ((4,) if "int n" in src else ()))
+
+
+def test_a_barrier_in_a_helper_function_is_refused():
+    src = """
+    float meet(float v) { barrier(CLK_LOCAL_MEM_FENCE); return v; }
+    __kernel void k(__global float* x) { x[get_global_id(0)] = meet(1.0f); }"""
+    with pytest.raises(KernelLanguageError, match="helper function"):
+        fn, _ = KernelProgram(src).launcher("k", 64, 64, 64)
+        fn(0, (jnp.zeros(64, jnp.float32),))
+
+
+ACCEPTED = {
+    "at kernel scope, no tile": "barrier(CLK_GLOBAL_MEM_FENCE);",
+    "under a value parameter": "if (n > 2) { barrier(CLK_LOCAL_MEM_FENCE); }",
+    "under the group id": """
+        if (get_group_id(0) % 2 == 0) { barrier(CLK_LOCAL_MEM_FENCE); }""",
+    "in a loop over the number of groups": """
+        for (int j = 0; j < get_num_groups(0); j++) {
+            barrier(CLK_LOCAL_MEM_FENCE);
+        }""",
+    "in a loop whose trip count is the group's": """
+        int g = get_group_id(0);
+        for (int j = 0; j < g; j++) {
+            x[get_global_id(0)] += 1.0f;
+            barrier(CLK_GLOBAL_MEM_FENCE);
+        }""",
+    "a literal for the flags": "barrier(0);",
+}
+
+
+@pytest.mark.parametrize("case", sorted(ACCEPTED))
+def test_accepted_barriers(case):
+    src = _kernel(ACCEPTED[case] + "\nx[get_global_id(0)] += 1.0f;",
+                  "__global float* x, int n")
+    fn, info = KernelProgram(src).launcher("k", 128, 32, 128)
+    out = np.asarray(fn(0, (jnp.zeros(128, jnp.float32),), (3,))[0])
+    want = np.ones(128, np.float32)
+    if "trip count" in case:
+        want += np.repeat(np.arange(4, dtype=np.float32), 32)
+    np.testing.assert_array_equal(out, want)
+    assert info.local[2] == 1 and info.local[0] == 0
+
+
+def test_a_quote_in_a_span_field_does_not_swallow_the_fields_behind_it(tmp_path):
+    """A profiler annotation's metadata is ``key=value,key=value``: a quote in
+    a value opened a quoted string that took ``lane`` and ``tag`` with it (the
+    first ``local-memory`` veto read "the kernel's ..."; the cell's span
+    readers found no launch of their lane)."""
+    import sys
+
+    bench = os.path.join(os.path.dirname(CONFIGS))
+    sys.path[:0] = [p for p in (bench,) if p not in sys.path]
+    import host_phases
+    import xplane
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t = TRACER.t0("launch")
+        TRACER.record("launch", t, cid=1, lane=3, tag="quoted",
+                      veto="the kernel's \"tile\", #1")
+    finally:
+        jax.profiler.stop_trace()
+    spans = [s for line in host_phases.host_lines(
+        xplane._profile(xplane.find_xplane(str(tmp_path)))) for s in line
+        if s.name == "ck/launch"]
+    (stats,) = [s.stats for s in spans]
+    stats.pop("win", None)  # the thread's window, where earlier tests left one
+    assert stats == {"veto": "the kernels tile; 1", "cid": 1, "lane": 3,
+                     "tag": "quoted"}
