@@ -258,6 +258,9 @@ class _Ctx:
         self.local: dict[str, int] = {}
         self.group_uniform: set[str] = set()
         self.tid_vars: frozenset[str] = frozenset()
+        # the reads at ``local id + (the same in a group)``: id of the Index
+        # node -> pitch (_group_sites; _group_slice serves them)
+        self.group_sites: dict[int, int] = {}
         self.local_access: dict[tuple[int, bool], str] = {}
         self.cooperative = False  # the kernel has a __local array or a barrier
         # per-innermost-loop masks: lanes that executed `break` (persist
@@ -322,6 +325,7 @@ class _Ctx:
         self.uniform_vars = uniform_vars
         if coop is not None:
             self.group_uniform, self.tid_vars = coop.group_uniform, coop.tid_vars
+            self.group_sites = coop.group_sites
             self.cooperative = True
         self.returns = _contains_return(kernel.body)
         self.helpers = getattr(kernel, "helpers", {}) or {}
@@ -893,11 +897,17 @@ def _under_int_casts(node):
     return node
 
 
+def _is_id_call(node, name: str) -> bool:
+    """Is ``node`` the call ``name(0)`` (``get_local_id``, ``get_group_id``),
+    under integer casts?"""
+    node = _under_int_casts(node)
+    return (isinstance(node, Call) and node.name == name
+            and all(isinstance(a, Num) and a.value == 0 for a in node.args))
+
+
 def _is_local_id(node) -> bool:
     """Is ``node`` ``get_local_id(0)``, under integer casts?"""
-    node = _under_int_casts(node)
-    return (isinstance(node, Call) and node.name == "get_local_id"
-            and all(isinstance(a, Num) and a.value == 0 for a in node.args))
+    return _is_id_call(node, "get_local_id")
 
 
 def _is_tid(ctx: _Ctx, node) -> bool:
@@ -1401,6 +1411,77 @@ def _strided_window(ctx: _Ctx, site, c, width: int):
     return win, d
 
 
+def _group_slice(ctx: _Ctx, name: str, idx: KVal, pitch: int = 0):
+    """``buf[clip(idx)]`` of buffer ``name`` where ``idx`` is ``local id + u``
+    with ``u`` the same in every ACTIVE work item of a group
+    (:func:`_group_sites`): the ``L`` work items of a group read ``L``
+    neighbouring elements, so a group fetches ONE window ``buf[u : u + L]``
+    where a gather fetched a row of 128 a lane.  ``u`` is the index less the
+    local id in the group's ACTIVE lanes (a lane that has left a masked loop
+    may hold anything: its value is masked away, as a gather's is, and so is
+    all that a group with no active lane reads).
+
+    Two fetches, picked at run time from the ``u`` of the pass (both are
+    compiled into the launcher).  Where the windows of the active groups lie
+    ``pitch`` apart (the build's hint, whole rows of 128), start on a row and
+    stay inside the buffer and inside their ``pitch`` elements, they are ONE
+    slice ``[G, L / 128, 128]`` of the buffer seen as ``[n / pitch, pitch /
+    128, 128]``, which on the chip is how the buffer lies in memory
+    (:func:`_strided_rows`).  Anywhere else a window a group, element for
+    element what the gather's clamp reads: taken at the nearest start inside
+    the buffer and, where that is not where it was asked for, moved out again
+    with the first or last element beyond the ends (:func:`_shift_rows`).
+    The chip's compiler makes a loop over the groups of the windows' fetch,
+    which is why the one slice is worth its check (PERF.md s.6, PR 46, has
+    both timed)."""
+    buf, L = ctx.bufs[name], ctx.local_size
+    n, G = buf.shape[0], ctx.B // L
+    iv = jnp.broadcast_to(jnp.asarray(_num(_as_dtype(idx, "int")), jnp.int32),
+                          ctx.shape).reshape(G, L)
+    m, lowest = _group_mask(ctx), jnp.iinfo(jnp.int32).min
+    if m is None:
+        u, some = iv[:, 0], jnp.ones(G, jnp.bool_)
+    else:
+        # the same in every active lane: their largest (a reduction; picking
+        # one lane's would be a gather)
+        tid = lax.broadcasted_iota(jnp.int32, iv.shape, 1)
+        some = jnp.any(m, axis=1)
+        u = jnp.where(some, jnp.max(jnp.where(m, iv - tid, lowest), axis=1), 0)
+
+    def windows():
+        first, last = buf[:1], buf[-1:]
+        wide = buf if n >= L else jnp.pad(buf, (0, L - n), mode="edge")
+        start = jnp.clip(u, 0, wide.shape[0] - L)
+        rows = lax.gather(
+            wide, start[:, None], lax.GatherDimensionNumbers(
+                offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,)),
+            slice_sizes=(L,), mode="promise_in_bounds")
+        moved = u - start
+        return lax.cond(jnp.any(moved != 0),
+                        lambda: _shift_rows(rows, moved, L, first, last),
+                        lambda: rows).reshape(ctx.shape)
+
+    if (pitch <= 0 or pitch % _ROW or L % _ROW or L > pitch or n % pitch
+            or n // pitch < G):
+        return windows()
+    # group 0's start as the active groups have it, and do they all agree
+    base = u - pitch * jnp.arange(G, dtype=jnp.int32)
+    u0 = jnp.where(jnp.any(some), jnp.max(jnp.where(some, base, lowest)), 0)
+    at = lax.rem(u0, jnp.int32(pitch))
+    fits = (jnp.all((base == u0) | ~some) & (u0 >= 0)
+            & (u0 <= n - pitch * (G - 1) - L)
+            & ((u0 & (_ROW - 1)) == 0) & (at <= pitch - L))
+
+    def one_slice():
+        view = buf.reshape(n // pitch, pitch // _ROW, _ROW)
+        return lax.dynamic_slice(
+            view, (lax.div(u0, jnp.int32(pitch)), lax.div(at, jnp.int32(_ROW)),
+                   jnp.int32(0)),
+            (G, L // _ROW, _ROW)).reshape(ctx.shape)
+
+    return lax.cond(fits, one_slice, windows)
+
+
 def _note(ctx: _Ctx, node: Index, store: bool, kind: str) -> None:
     """Record how an access site was lowered.  A chunk of compacted lanes
     traces the sites of its loop a second time: that goes to a record of its
@@ -1468,6 +1549,10 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
         sidx = iv if (not hasattr(iv, "ndim") or iv.ndim == 0) else iv.reshape(-1)[0]
         sidx = jnp.clip(jnp.asarray(sidx, jnp.int32), 0, buf.shape[0] - 1)
         return _loaded(lax.dynamic_slice(buf, (sidx,), (1,))[0], ctype)
+    if id(node) in ctx.group_sites:
+        _note(ctx, node, False, "group")
+        return _loaded(_group_slice(ctx, node.base, idx,
+                                    ctx.group_sites[id(node)]), ctype)
     _note(ctx, node, False, "gather")
     iv = _num(_as_dtype(idx, "int"))
     if not hasattr(iv, "ndim") or iv.ndim == 0:
@@ -2822,12 +2907,18 @@ def _has_divergent_exit(stmts: list, divergent: bool, uset, private,
     return False
 
 
+def _has_exit(stmts: list, kind) -> bool:
+    """True if an exit of ``kind`` (``Break``, ``Continue``) of THIS loop is
+    anywhere in its body."""
+    return any(isinstance(s, kind)
+               or (isinstance(s, If) and (_has_exit(s.then, kind)
+                                          or _has_exit(s.other, kind)))
+               for s in stmts)
+
+
 def _has_break(stmts: list) -> bool:
     """True if a ``break`` of THIS loop is anywhere in its body."""
-    return any(isinstance(s, Break)
-               or (isinstance(s, If) and (_has_break(s.then)
-                                          or _has_break(s.other)))
-               for s in stmts)
+    return _has_exit(stmts, Break)
 
 
 def _loop_diverges(node, uset, private, group: bool = False) -> bool:
@@ -2884,6 +2975,55 @@ def _private_array_names(stmts: list, out: set[str] | None = None) -> set[str]:
     return out
 
 
+def _regions(body: list, uset: set[str], private, group: bool = False):
+    """Yield ``(stmt, path, steady)`` for every statement under ``body``, an
+    ``if`` or a loop ahead of what stands inside it (its condition is
+    evaluated where the statement stands; a ``for``'s init too, its step with
+    the body).
+
+    ``path`` names the REGION the statement stands in, outermost first: a
+    region is a stretch of the kernel that the same lanes execute.  The top
+    level is ``()``; one more opens for each branch of an ``if`` under a
+    condition that is not uniform by ``uset`` (:func:`_expr_uniform`; with
+    ``group``, over a work-group) and for the body of a loop that lanes leave
+    on different passes (:func:`_loop_diverges`), and in such a loop one more
+    for the part of a pass that a ``continue`` can skip (the body; the step
+    runs for every lane still in the loop).  ``uset`` is read as it is when a
+    statement is reached, so a caller may shrink it while it walks.
+
+    ``steady``: do the lanes that enter the statement's region enter it ONCE?
+    False in a region opened inside any loop, and in the skippable part of a
+    pass: the lanes there are chosen anew pass by pass."""
+    new_region = itertools.count(1).__next__
+
+    def walk(stmts, path: tuple, steady: bool, loops: int):
+        def opened(diverges: bool) -> tuple:
+            if not diverges:
+                return path, steady
+            return path + (new_region(),), steady and loops == 0
+
+        for s in stmts:
+            yield s, path, steady
+            if isinstance(s, If):
+                diverges = not _expr_uniform(s.cond, uset, private, group)
+                for branch in (s.then, s.other):
+                    yield from walk(branch, *opened(diverges), loops)
+            elif isinstance(s, (For, While, DoWhile)):
+                if isinstance(s, For) and s.init is not None:
+                    yield from walk([s.init], path, steady, loops)
+                diverges = _loop_diverges(s, uset, private, group)
+                mine, still = opened(diverges)
+                if diverges and _has_exit(s.body, Continue):
+                    yield from walk(s.body, mine + (new_region(),), False,
+                                    loops + 1)
+                else:
+                    yield from walk(s.body, mine, still, loops + 1)
+                if getattr(s, "step", None) is not None:
+                    yield from walk([s.step], mine, still, loops + 1)
+
+    return walk(body, (), True, 0)
+
+
 def _uniform_vars(body: list, value_params: set[str],
                   group: bool = False) -> set[str]:
     """The locals that provably hold the SAME value in every lane that can
@@ -2893,14 +3033,14 @@ def _uniform_vars(body: list, value_params: set[str],
     local is; poison any variable assigned a non-uniform value, or assigned
     in another REGION than the one it was declared in; repeat until stable.
 
-    A region is a stretch of the kernel that the same lanes execute: the
-    top level, and one more for each branch of an ``if`` under a divergent
-    condition and for the body of a loop that lanes leave on different
-    passes (:func:`_loop_diverges`).  A local declared in a region lives and
-    dies in it (a loop's pass drops its declarations), so only that
-    region's lanes ever read it: assigned there alone, from uniform values,
-    it is the same in all of them, whatever the lanes outside would have
-    made of it.  The lowering relies on exactly this: such a local stays a
+    A region is a stretch of the kernel that the same lanes execute
+    (:func:`_regions` walks them): the top level, and one more for each
+    branch of an ``if`` under a divergent condition and for the body of a
+    loop that lanes leave on different passes.  A local declared in a region
+    lives and dies in it (a loop's pass drops its declarations), so only
+    that region's lanes ever read it: assigned there alone, from uniform
+    values, it is the same in all of them, whatever the lanes outside would
+    have made of it.  The lowering relies on exactly this: such a local stays a
     0-d value that no lane mask is merged into (:func:`_assign`)."""
     # an early `return` folds into a persistent per-lane return-mask that
     # divergently suppresses EVERY later assignment — modeling which
@@ -2918,8 +3058,7 @@ def _uniform_vars(body: list, value_params: set[str],
     changed = True
     while changed:
         changed = False
-        home = dict.fromkeys(value_params, 0)  # name -> region declared in
-        new_region = itertools.count(1).__next__
+        home = dict.fromkeys(value_params, ())  # name -> region declared in
 
         def poison(name: str) -> None:
             nonlocal changed
@@ -2927,41 +3066,23 @@ def _uniform_vars(body: list, value_params: set[str],
                 uset.discard(name)
                 changed = True
 
-        def walk(stmts, region: int) -> None:
-            for s in stmts:
-                if isinstance(s, Decl):
-                    for name, init in s.names:
-                        if home.setdefault(name, region) != region:
-                            poison(name)  # one name, two regions' lanes
-                        if name in s.arrays:
-                            poison(name)  # per-lane stores make stacks diverge
-                        elif init is not None and not _expr_uniform(
-                                init, uset, private, group):
-                            poison(name)
-                elif isinstance(s, (Assign, CrementStmt)) and isinstance(s.target, Var):
-                    if home.get(s.target.name) != region or not (
-                            isinstance(s, CrementStmt)
-                            or _expr_uniform(s.value, uset, private, group)):
-                        poison(s.target.name)
-                elif isinstance(s, If):
-                    if _expr_uniform(s.cond, uset, private, group):
-                        walk(s.then, region)
-                        walk(s.other, region)
-                    else:
-                        walk(s.then, new_region())
-                        walk(s.other, new_region())
-                elif isinstance(s, (For, While, DoWhile)):
-                    inner = s.body
-                    if isinstance(s, For):
-                        if s.init is not None:
-                            walk([s.init], region)
-                        inner = s.body + ([s.step] if s.step is not None else [])
-                    # lanes that leave on different passes make every
-                    # assignment to an outer local in the loop diverge
-                    walk(inner, new_region()
-                         if _loop_diverges(s, uset, private, group) else region)
-
-        walk(body, 0)
+        # lanes that leave a loop on different passes make every assignment
+        # to an outer local in it diverge: its body is a region of its own
+        for s, region, _steady in _regions(body, uset, private, group):
+            if isinstance(s, Decl):
+                for name, init in s.names:
+                    if home.setdefault(name, region) != region:
+                        poison(name)  # one name, two regions' lanes
+                    if name in s.arrays:
+                        poison(name)  # per-lane stores make stacks diverge
+                    elif init is not None and not _expr_uniform(
+                            init, uset, private, group):
+                        poison(name)
+            elif isinstance(s, (Assign, CrementStmt)) and isinstance(s.target, Var):
+                if home.get(s.target.name) != region or not (
+                        isinstance(s, CrementStmt)
+                        or _expr_uniform(s.value, uset, private, group)):
+                    poison(s.target.name)
     return uset
 
 
@@ -2993,6 +3114,186 @@ def _tid_vars(body: list) -> frozenset:
     return frozenset(out)
 
 
+def _build_int(node, sizes: dict) -> Optional[int]:
+    """``node`` as an integer known when a launcher is built: literals and
+    the calls ``sizes`` names (``get_local_size`` ..) under ``+ - *`` and
+    integer casts; None for anything else."""
+    node = _under_int_casts(node)
+    if isinstance(node, Num):
+        return int(node.value) if float(node.value).is_integer() else None
+    if isinstance(node, Call):
+        return sizes.get(node.name)
+    if isinstance(node, BinOp) and node.op in ("+", "-", "*"):
+        a, b = _build_int(node.left, sizes), _build_int(node.right, sizes)
+        if a is None or b is None:
+            return None
+        return {"+": a + b, "-": a - b, "*": a * b}[node.op]
+    return None
+
+
+def _int_typed(node, ints: set[str]) -> bool:
+    """Is ``node`` an integer that no float took part in below its casts:
+    its ``+`` and ``-`` are then exact, modulo 2^32 as an index's own cast
+    is.  ``ints``: the parameters, buffers and locals of integer type.
+    Anything not known (a helper's call, ``min``) is not."""
+    if isinstance(node, Num):
+        return node.ctype in _INT_TYPES
+    if isinstance(node, Var):
+        return node.name in ints
+    if isinstance(node, Index):
+        return node.base in ints
+    if isinstance(node, Cast):
+        return node.ctype in _INT_TYPES  # a whole number, whatever it was
+    if isinstance(node, BinOp):
+        return _int_typed(node.left, ints) and _int_typed(node.right, ints)
+    if isinstance(node, UnOp):
+        return _int_typed(node.operand, ints)
+    if isinstance(node, Ternary):
+        return _int_typed(node.then, ints) and _int_typed(node.other, ints)
+    if isinstance(node, Call):
+        return node.name in _UNIFORM_CALLS or node.name in _LANE_CALLS
+    return False
+
+
+def _group_sites(body: list, params: list, gset: set[str],
+                 tid_vars: frozenset, every: frozenset,
+                 sizes: dict | None = None) -> dict:
+    """``{id: pitch}`` of the ``Index`` nodes whose index is ``local id + u``
+    with ``u`` the same in every work item of a group THAT IS ACTIVE THERE:
+    the reads :func:`_group_slice` serves.  An index qualifies when its terms
+    (:func:`_terms`) are ONE of ``get_local_id(0)``, a :func:`_tid_vars` local
+    or a WALKER, with sign +1, and a group-uniform rest (``i + blockSize``).
+
+    A walker is a 32- or 64-bit integer local DECLARED in the body (a value
+    parameter of ``params`` starts from what the caller gave it, which no
+    assignment here shows: never one), every assignment of which keeps
+    "local id plus a group-uniform value": a declaration or ``=`` of that
+    very form (``get_group_id(0) * (get_local_size(0) * 2) + tid``, ``i +
+    gridSize``) and updates by a group-uniform amount (``i += gridSize``,
+    ``i -= ..``, ``i++``), all of it in integers (:func:`_int_typed`: a float
+    on the way rounds lane by lane, ``tid - 3.5f`` is 0 in work items 3 AND
+    4).  The form alone does not make the lanes of a group agree: one that
+    skipped an update holds another ``u``.  So every assignment of the
+    walker must have run for ALL the lanes that read it, or for none of
+    them.  Regions say so (:func:`_regions`, the very walk that
+    :func:`_uniform_vars` makes, by group).  The lanes of a region only get
+    fewer as it nests, and a loop's lanes only get fewer pass by pass, so a
+    read sees a walker whole when each of its assignments stands in the
+    read's region or in one around it, and in none that the lanes enter anew
+    pass by pass (``steady``: a divergent ``if`` inside a loop, the part of
+    a body that a ``continue`` can skip).  The condition of a divergent loop
+    is evaluated for the lanes that have left it too (:func:`_exec_masked`):
+    it belongs to the region around the loop.  ``2 * tid + u``, ``u - tid``,
+    ``tid + x[gid]``, a walker also assigned anything else, a walker read
+    behind the loop that moved it: not this form, they keep the gather.
+
+    ``pitch`` is a HINT, proving nothing: the build-time factor of
+    ``get_group_id(0)`` in the index and in the declarations of the walkers
+    it names (``sizes``: what the launcher knows of the ranges), 0 where
+    there is none.  With it the groups' windows may lie ``pitch`` apart, one
+    2-D slice of the buffer; the launch checks that they do."""
+    if _contains_return(body):
+        return {}
+    wide = {"int", "uint", "long", "ulong"}
+    private = every - set(_local_arrays(body))  # a tile's element is a group's
+    assigns: dict[str, list] = {}   # local -> [(path, steady, op, value)]
+    declared: dict[str, object] = {}  # local -> its first declaration's value
+    reads: list = []                # (Index node, path)
+    values = {p.name for p in params if not p.is_pointer}
+    ints = {p.name for p in params if p.ctype in _INT_TYPES}
+    floats: set[str] = set()        # names declared as anything else
+
+    for s, path, steady in _regions(body, gset, private, group=True):
+        if isinstance(s, LocalDecl):
+            (ints if s.ctype in _INT_TYPES else floats).add(s.name)
+        if isinstance(s, Decl):
+            (ints if s.ctype in _INT_TYPES else floats).update(
+                name for name, _init in s.names)
+            for name, init in s.names:
+                ok = s.ctype in wide and name not in s.arrays
+                if name not in assigns:
+                    declared[name] = init
+                assigns.setdefault(name, []).append(
+                    (path, steady and ok, "=", init))
+            exprs = [init for _name, init in s.names]
+        elif isinstance(s, (Assign, CrementStmt)):
+            op, value = ((s.op, s.value) if isinstance(s, Assign)
+                         else ("+=", Num(value=1, ctype="int", line=s.line)))
+            if isinstance(s.target, Var):
+                assigns.setdefault(s.target.name, []).append(
+                    (path, steady, op, value))
+            exprs = [s.target, value]
+        elif isinstance(s, (If, For, While, DoWhile)):
+            exprs = [s.cond]  # what stands inside comes by itself
+        else:
+            continue
+        reads.extend((ix, path) for ix in _index_nodes(exprs))
+
+    ints -= floats
+
+    def uniform(node) -> bool:
+        """The same in every lane of a group, and a whole number all along."""
+        return (_expr_uniform(node, gset, private, group=True)
+                and _int_typed(node, ints))
+
+    def whole(name: str, path: tuple) -> bool:
+        """Has every assignment of ``name`` run for all the lanes at
+        ``path``, or for none?"""
+        return all(steady and path[:len(at)] == at
+                   for at, steady, _op, _value in assigns[name])
+
+    def kept(node, path: tuple) -> bool:
+        """Is ``node`` ``local id + (group-uniform)`` for the lanes at
+        ``path``, with the walkers that still stand?"""
+        if node is None:
+            return False
+        ids, rest = [], []
+        for sign, term in _terms(node, 1, []):
+            leaf = term
+            while isinstance(leaf, Cast) and leaf.ctype in wide:
+                leaf = leaf.operand  # (a narrower cast wraps the local id)
+            if (isinstance(leaf, Call) and _is_local_id(leaf)
+                    or isinstance(leaf, Var)
+                    and (leaf.name in tid_vars or leaf.name in walkers)
+                    and whole(leaf.name, path)):
+                ids.append(sign)
+            else:
+                rest.append(term)
+        return ids == [1] and all(uniform(t) for t in rest)
+
+    # a value parameter's first value is the caller's, as is that of any
+    # name first met in an assignment: nothing here has seen it
+    walkers = set(declared) - set(values) - set(gset) - set(tid_vars)
+    while True:
+        lost = {name for name in walkers
+                if not all(kept(value, at) if op == "=" else
+                           op in ("+=", "-=") and uniform(value)
+                           for at, _steady, op, value in assigns[name])}
+        if not lost:
+            break
+        walkers -= lost
+
+    def pitch(node, seen: tuple = ()) -> int:
+        total = 0
+        for sign, term in _terms(node, 1, []):
+            leaf = _under_int_casts(term)
+            if isinstance(leaf, Var) and leaf.name in walkers:
+                if leaf.name not in seen:  # as it was declared
+                    total += sign * pitch(declared[leaf.name],
+                                          seen + (leaf.name,))
+            elif _is_id_call(leaf, "get_group_id"):
+                total += sign
+            elif isinstance(leaf, BinOp) and leaf.op == "*":
+                for grp, factor in ((leaf.left, leaf.right),
+                                    (leaf.right, leaf.left)):
+                    if _is_id_call(grp, "get_group_id"):
+                        total += sign * (_build_int(factor, sizes or {}) or 0)
+        return total
+
+    return {id(ix): pitch(ix.index) for ix, path in reads
+            if ix.base not in every and kept(ix.index, path)}
+
+
 class _Coop(NamedTuple):
     """What a build knows of a kernel whose work items cooperate
     (:func:`_cooperation`)."""
@@ -3001,6 +3302,7 @@ class _Coop(NamedTuple):
     barriers: int           # barrier statements in the kernel's body
     group_uniform: set      # locals the same in every lane of a group
     tid_vars: frozenset     # locals that are get_local_id(0)
+    group_sites: dict       # the reads at ``local id + u``: id -> pitch (_group_sites)
 
     @property
     def nbytes(self) -> int:
@@ -3015,11 +3317,13 @@ def cooperates(kernel: KernelDef) -> bool:
     return any(isinstance(n, (LocalDecl, Barrier)) for n in _walk(kernel.body))
 
 
-def _cooperation(kernel: KernelDef) -> Optional[_Coop]:
+def _cooperation(kernel: KernelDef, sizes: dict | None = None) -> Optional[_Coop]:
     """None for a kernel with neither a ``__local`` array nor a barrier (the
     build then does nothing it did not do before); else what the lowering
     needs, with every barrier PROVED to be reached by all work items of a
-    group together (:func:`_check_barriers` raises where one is not)."""
+    group together (:func:`_check_barriers` raises where one is not).
+    ``sizes``: the ranges a launcher is built for, by the call that gives
+    them (``get_local_size`` ..)."""
     if not cooperates(kernel):
         return None
     arrays = _local_arrays(kernel.body)
@@ -3028,7 +3332,13 @@ def _cooperation(kernel: KernelDef) -> Optional[_Coop]:
     gset = _uniform_vars(kernel.body, values, group=True)
     if barriers:
         _check_barriers(kernel, gset)
-    return _Coop(arrays, barriers, gset, _tid_vars(kernel.body))
+    tids = _tid_vars(kernel.body)
+    # only a kernel with a tile is promised launches of whole groups
+    # (build_kernel_fn refuses a chunk that cuts one)
+    every = frozenset(_private_array_names(kernel.body))
+    sites = (_group_sites(kernel.body, kernel.params, gset, tids, every, sizes)
+             if arrays else {})
+    return _Coop(arrays, barriers, gset, tids, sites)
 
 
 def _check_barriers(kernel: KernelDef, gset: set[str]) -> None:
@@ -3230,7 +3540,9 @@ class KernelBuildInfo:
     # access sites by the walk that lowers them (filled at trace): loads
     # and stores by ``slice`` (contiguous), loads by ``strided`` window or
     # column, by ``uniform`` scalar, by per-lane ``gather``, stores by
-    # ``scatter``, and ``carried``: buffers riding a loop as a local
+    # ``scatter``, and ``carried``: buffers riding a loop as a local; in a
+    # kernel whose work items cooperate also ``group``: loads of one window a
+    # work-group (:func:`_group_slice`)
     access: dict = field(default_factory=dict)
     # the element widths in bytes of the stores lowered to a scatter, one a
     # store in the order the walk met them (``(4, 1)`` for Rodinia's BFS_1:
@@ -3330,7 +3642,12 @@ def build_kernel_fn(
     info.loops_counted, info.loops_masked = _loop_counts(kernel, uniform)
     pitches = pitch_params(kernel)
     readonly = frozenset(info.array_params) - _stored_bufs(kernel.body)
-    coop = _cooperation(kernel)  # raises on a barrier a group does not reach
+    sizes = {"get_local_size": local_size}
+    if isinstance(global_size, int):
+        sizes.update(get_global_size=global_size,
+                     get_num_groups=global_size // local_size)
+    # (raises on a barrier a group does not reach)
+    coop = _cooperation(kernel, sizes)
     if coop is not None:
         info.local = (len(coop.arrays), coop.nbytes, coop.barriers)
         if coop.arrays and chunk % local_size:
@@ -3362,7 +3679,8 @@ def build_kernel_fn(
             ctx.env[name] = _int_const(v, ctx.env[name].ctype)
         _exec_block(ctx, kernel.body)
         info.stored_params = [n for n in info.array_params if n in ctx.stored]
-        info.access = dict.fromkeys(ACCESS_KINDS, 0)
+        info.access = dict.fromkeys(
+            ACCESS_KINDS + (("group",) if coop is not None else ()), 0)
         for kind in ctx.access.values():
             info.access[kind] += 1
         info.access["carried"] = len(ctx.carried)

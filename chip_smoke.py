@@ -742,7 +742,8 @@ def _group_reduction(devices, sizes) -> dict:
         meta = lowering_meta([info])
         _require(meta.get("local") == "arrays:1;bytes:1024;barriers:2;"
                  "sites:shift:6,uniform:1,row:0"
-                 and meta["access"].endswith(";local:7"),
+                 and meta["access"].endswith(
+                     ";gather:0;scatter:1;carried:0;local:7;group:2"),
                  f"reduce: local {meta.get('local')}, access {meta['access']}")
         if w.device.platform == "tpu":
             _require((info.lowering, (info.veto or "")[:12])

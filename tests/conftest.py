@@ -126,6 +126,15 @@ _KNOWN_FAILURES = {
         "test_mvt_cell.py's and test_ladder_dispatches.py's, which a benchmark "
         "PR relaxes together (checks/test_window_edge.py holds what it held, "
         "by name)",
+    "benchmark/checks/test_reduction_cell.py"
+    "::test_the_configuration_the_cell_and_its_metrics_are_in_the_manifest":
+        "holds the reduction cell's per-layer list with ==: that one line "
+        "fails since PR 46 appended reduce_gathered_accesses and "
+        "group_slice_accesses behind it, as ISSUE 46 asked; the same kind of "
+        "pin, relaxed by the same benchmark PR.  Every other assertion of it "
+        "(the configuration, the sizes, the bound, the kernel's text, the "
+        "reference) stands, copied, in checks/test_group_slice_readers.py::"
+        "test_the_cell_is_what_its_pinned_check_held_it_to",
 }
 
 

@@ -21,6 +21,7 @@ import numpy as np
 
 from cekirdekler_tpu.kernel.lang import (
     Assign,
+    Barrier,
     BinOp,
     Break,
     Call,
@@ -33,6 +34,7 @@ from cekirdekler_tpu.kernel.lang import (
     If,
     Index,
     KernelDef,
+    LocalDecl,
     Num,
     Return,
     ReturnValue,
@@ -97,23 +99,53 @@ class Oracle:
 
     def run(self, arrays: dict[str, np.ndarray], values: dict[str, float],
             global_size: int, offset: int = 0) -> None:
-        for i in range(offset, offset + global_size):
-            self._run_item(i, arrays, values, global_size)
+        body = self.kernel.body
+        if not any(isinstance(s, (LocalDecl, Barrier)) for s in body):
+            for i in range(offset, offset + global_size):
+                self._run_item(i, arrays, values, global_size)
+            return
+        # WORK-GROUP COOPERATION: a ``__local`` array is one array a group,
+        # zero at its start (as the lowering has it), and a barrier AT KERNEL
+        # SCOPE cuts the body into phases: every work item of the group runs
+        # a phase to its end before any runs the next.  A barrier inside
+        # control flow is not modelled (the statement walk refuses it).
+        phases: list[list] = [[]]
+        for s in body:
+            if isinstance(s, Barrier):
+                phases.append([])
+            elif not isinstance(s, LocalDecl):
+                phases[-1].append(s)
+        L = self.local_size
+        assert offset % L == 0 and global_size % L == 0, "whole groups a launch"
+        for g0 in range(offset, offset + global_size, L):
+            tiles = {s.name: np.zeros(s.size, _NPT[s.ctype])
+                     for s in body if isinstance(s, LocalDecl)}
+            items = [self._item(i, arrays, values, global_size, tiles)
+                     for i in range(g0, g0 + L)]
+            for phase in phases:
+                items = [st for st in items if self._run_phase(phase, st)]
 
     # -- one work item -------------------------------------------------------
     def _run_item(self, gid, arrays, values, gsize) -> None:
+        self._run_phase(self.kernel.body, self._item(gid, arrays, values, gsize))
+
+    def _item(self, gid, arrays, values, gsize, tiles=None) -> tuple:
         env: dict = {}
-        priv: dict[str, np.ndarray] = {}
+        priv: dict[str, np.ndarray] = dict(tiles or {})  # shared by the group
         ctypes: dict[str, str] = {}
         for p in self.kernel.params:
             if not p.is_pointer:
                 env[p.name] = _NPT[p.ctype](values[p.name])
                 ctypes[p.name] = p.ctype
-        state = (env, priv, ctypes, arrays, gid, gsize)
+        return (env, priv, ctypes, arrays, gid, gsize)
+
+    def _run_phase(self, stmts, state) -> bool:
+        """False once the work item has returned."""
         try:
-            self._block(self.kernel.body, state)
+            self._block(stmts, state)
         except _Return:
-            pass
+            return False
+        return True
 
     def _block(self, stmts, state) -> None:
         for s in stmts:

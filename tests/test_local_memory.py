@@ -166,8 +166,239 @@ def test_reduce_lowers_by_shifts_and_one_broadcast():
     meta = lowering_meta([info])
     assert meta["local"] == ("arrays:1;bytes:1024;barriers:2;"
                              "sites:shift:6,uniform:1,row:0")
-    assert meta["access"].endswith(";carried:0;local:7")
-    assert info.compact == ()  # chunks of lanes would tear groups apart
+    assert meta["access"].endswith(";gather:0;scatter:1;carried:0;local:7;group:2")
+    # the groups' windows lie 512 apart: the launcher holds the one-slice read
+    text = str(fn.trace(0, (jnp.asarray(x), jnp.zeros(4, jnp.float32)),
+                        (x.size,)).jaxpr)
+    assert "f32[12,4,128]" in text and "f32[4,2,128]" in text  # chunks of lanes would tear groups apart
+
+
+# -- the group slice: a read at ``local id + (the same in a group)`` ---------
+# (ISSUE 46)  Every case runs three ways: the build (which must count the
+# reads it names as ``group``), the same kernel built with the form switched
+# off (the gather it replaces: byte for byte), and the scalar oracle.
+
+def _tiled(body: str) -> str:
+    """A kernel with a tile (so its launches are whole groups) around
+    ``body``, which leaves ``float v``."""
+    return """
+    __kernel void k(__global const float* x, __global const int* offs,
+                    __global float* out, int n, int c) {
+        __local float t[256];
+        int tid = get_local_id(0);
+        int gid = get_global_id(0);
+        t[tid] = 1.0f;
+        float v = 0.0f;
+        %s
+        out[gid] = v + t[tid];
+    }""" % body
+
+
+WALK = """
+        int i = get_group_id(0) * (get_local_size(0) * 2) + tid;
+        int grid = get_local_size(0) * 2 * get_num_groups(0);
+        int bs = get_local_size(0);
+        while (i < n) { v += x[i] + x[i + bs]; i += grid; }"""
+
+# name -> (body, groups, local, elements of x, n, c, reads lowered as group)
+GROUP_SLICE = {
+    "a walk, n whole passes": (WALK, 3, 64, 3 * 128 * 4, 3 * 128 * 4, 0, 2),
+    "a walk, n cuts a group in the middle": (WALK, 3, 64, 2000, 3 * 128 + 91, 0, 2),
+    "a walk, n 0": (WALK, 3, 64, 512, 0, 0, 2),
+    "a walk, n 1": (WALK, 3, 64, 512, 1, 0, 2),
+    "a walk, n past the buffer's end": (WALK, 3, 64, 3 * 128 + 70, 1000, 0, 2),
+    "a walk, a buffer shorter than a group": (WALK, 1, 64, 40, 100, 0, 2),
+    "a walk, 1 group of 256": (WALK, 1, 256, 3000, 2777, 0, 2),
+    "a walk, 64 groups of 128": (WALK, 64, 128, 64 * 256 * 2 + 300,
+                                 64 * 256 * 2 + 130, 0, 2),
+    "a walk, 3 groups of 256": (WALK, 3, 256, 5000, 4321, 0, 2),
+    # a pitch of whole rows, a buffer of whole pitches: the windows are one
+    # 2-D slice where the pass's starts allow it, and a window a group where
+    # they do not (a start off the rows, behind the end, apart by another
+    # distance than the hint's)
+    "one slice a pass": (WALK, 3, 256, 8 * 512 * 3, 8 * 512 * 3 - 300, 0, 2),
+    "one slice a pass, 64 groups of 128": (WALK, 64, 128, 64 * 256 * 3,
+                                           64 * 256 * 3, 0, 2),
+    "one slice, then passes behind the end": (WALK, 3, 128, 256 * 3 * 2,
+                                              256 * 3 * 2 + 900, 0, 2),
+    "a pitch, starts off the rows": (
+        WALK.replace("+ tid;", "+ tid + c;"), 3, 128, 256 * 3 * 4, 2500, 5, 2),
+    "a pitch, starts before the buffer": (
+        WALK.replace("+ tid;", "+ tid - c;"), 3, 128, 256 * 3 * 4, 2500, 256, 2),
+    "a pitch that one group leaves": (
+        WALK.replace("int grid", "if (get_group_id(0) == 1) { i += 128; }\n"
+                     "        int grid"), 3, 128, 256 * 3 * 4, 3000, 0, 2),
+    "a pitch, groups that sit out": (
+        "if (get_group_id(0) != 0) {" + WALK + "}", 3, 128, 256 * 3 * 4,
+        3000, 0, 2),
+    "a pitch under a per-lane if": (
+        "if (tid % 5 != 0) {" + WALK + "}", 3, 128, 256 * 3 * 4, 2900, 0, 2),
+    "a negative group offset": (
+        "v = x[tid + get_group_id(0) * 64 - c];", 4, 64, 300, 0, 100, 1),
+    "u from a buffer at the group's id": (
+        "v = x[offs[get_group_id(0)] + tid];", 5, 64, 700, 0, 0, 1),
+    "a launch-uniform runtime term": ("v = x[tid + c] + x[c + n + tid];",
+                                      3, 128, 500, 77, 13, 2),
+    "tid under integer casts": (
+        "v = x[(int)get_local_id(0) + (int)(get_group_id(0)) * 7]"
+        " + x[(unsigned int)tid + c];", 3, 64, 300, 0, 5, 2),
+    "updates by = + and -= and ++": ("""
+        int i = tid + c;
+        for (int k = 0; k < 3; k++) { v += x[i]; i = i + 5; }
+        i -= 40; v += x[i]; i++; v += x[i - get_group_id(0)];""",
+                                     3, 64, 256, 0, 9, 3),
+    "a walker off another walker": ("""
+        int i = get_group_id(0) * 32 + tid;
+        int j = i + c;
+        v = x[j] + x[j + n];""", 3, 64, 256, 11, 3, 2),
+    "under a group-uniform if": (
+        "if (get_group_id(0) % 2 == 0) { v = x[tid + c]; }", 4, 64, 256, 0, 3, 1),
+    "under a per-lane if": (
+        "if (tid >= c) { v = x[tid + get_group_id(0) * 3]; }", 4, 64, 256, 0, 17, 1),
+    "a walk under a per-lane if": ("""
+        if (tid % 3 != 1) {
+            int i = tid + get_group_id(0) * c;
+            while (i < n) { v += x[i]; i += 100; }
+        }""", 3, 64, 700, 520, 70, 1),
+    "a walk whose step stands in a for": ("""
+        for (int i = tid + c; i < n + get_group_id(0); i += 64) { v += x[i + 1]; }
+        """, 3, 64, 400, 333, 2, 1),
+}
+
+# what must KEEP the gather: name -> (body, gathers at least)
+KEEP_GATHER = {
+    "2 * tid + u": ("v = x[2 * tid + c];", 1),
+    "u - tid": ("v = x[c + 200 - tid];", 1),
+    "tid + a lane-varying term": ("v = x[tid + offs[gid]];", 1),
+    "two local ids": ("v = x[tid + tid + c];", 1),
+    "a walker also assigned a lane-varying value": ("""
+        int i = tid + c;
+        if (tid == 3) { i = offs[gid]; }
+        v = x[i];""", 1),
+    "a walker multiplied": ("int i = tid + c; i *= 2; v = x[i];", 1),
+    "a walker read behind the loop that moved it": ("""
+        int i = tid + c;
+        while (i < n + 9 * (tid % 4)) { i += 64; }
+        v = x[i];""", 1),
+    "a walker moved under a per-lane if": ("""
+        int i = tid + c;
+        if (tid % 2 == 0) { i += 7; }
+        v = x[i];""", 1),
+    "a walker moved under a per-lane if in a loop": ("""
+        int i = tid + c;
+        for (int k = 0; k < 4; k++) {
+            if ((tid + k) % 3 == 0) { i += 5; v += x[i]; }
+        }""", 1),
+    "a walker moved behind a continue": ("""
+        int i = tid + c;
+        int k = 0;
+        while (k < 4 + tid % 2) {
+            k++;
+            if ((tid + k) % 3 == 0) { continue; }
+            i += 5; v += x[i];
+        }""", 1),
+    "a walker of an inner loop the lanes leave apart": ("""
+        for (int r = 0; r < 2; r++) {
+            int i = tid + c;
+            int k = 0;
+            while (k < tid % 3) { k++; i += 4; }
+            while (k < 5) { k++; v += x[i]; i += 4; }
+        }""", 1),
+    "a walker read by the condition of its loop": ("""
+        int i = tid + c;
+        while (x[i] < 40.0f + tid) { i += 64; v += 1.0f; }""", 1),
+    # a value parameter starts from what the caller gave it: a move by a
+    # group-uniform amount is no declaration of ``local id + u``
+    "a value parameter moved under a per-lane if": (
+        "if (tid % 2 == 1) { c += 5; v = x[c]; }", 1),
+    "a value parameter moved in a loop the lanes leave apart": (
+        "while (c < n + tid) { v += x[c]; c += 5; }", 1),
+    "a value parameter moved and read behind the loop": ("""
+        for (int k = 0; k < 2 + tid % 3; k++) { c = c + 7; }
+        v = x[c] + x[c + tid];""", 2),
+    "a value parameter set to the form under a per-lane if": (
+        "if (tid % 2 == 1) { c = tid + 9; } v = x[c];", 1),
+    # a float between the local id and the index rounds lane by lane:
+    # work items 3 and 4 of ``tid - 3.5f`` both land on 0
+    "a walker declared from a float": ("int i = tid - 3.5f; v = x[i + 40];", 1),
+    "a walker moved by a float": (
+        "int i = tid - 7; i += 3.5f; v = x[i + 40];", 1),
+    "a walker off a float local": (
+        "float f = c + 0.5f; int i = tid - f - 3; v = x[i + 40];", 1),
+    "a short walker": ("short i = tid + c; v = x[i];", 1),
+    "the local id under a narrow cast": ("v = x[(char)tid + c];", 1),
+    "a local id kept in a char": (
+        "char t8 = get_local_id(0); v = x[t8 + c];", 1),
+}
+
+
+def _group_case(src: str, groups: int, local: int, elems: int, n: int, c: int,
+                seed: int, monkeypatch):
+    """``(info, out)`` of the build, with the gather's and the oracle's
+    outputs held equal to it."""
+    from tests.kernel_oracle import Oracle
+
+    rng = np.random.default_rng(seed)
+    x = (rng.integers(0, 1000, elems) + np.arange(elems) * 1024).astype(np.float32)
+    offs = rng.integers(-80, elems + 80, max(groups * local, 8)).astype(np.int32)
+    size = groups * local
+    arrays = (jnp.asarray(x), jnp.asarray(offs), jnp.zeros(size, jnp.float32))
+    fn, info = KernelProgram(src).launcher("k", size, local, size)
+    out = np.asarray(fn(0, arrays, (n, c))[2])
+    with monkeypatch.context() as mp:
+        mp.setattr(codegen, "_group_sites", lambda *a: {})
+        ref_fn, ref_info = KernelProgram(src).launcher("k", size, local, size)
+        want = np.asarray(ref_fn(0, arrays, (n, c))[2])
+    assert ref_info.access["group"] == 0
+    assert out.tobytes() == want.tobytes()
+    host = {"x": x.copy(), "offs": offs.copy(), "out": np.zeros(size, np.float32)}
+    kdef, = lang.parse_kernels(src)
+    Oracle(kdef, local_size=local).run(host, {"n": n, "c": c}, size)
+    np.testing.assert_array_equal(out, host["out"])
+    return info, ref_info
+
+
+@pytest.mark.parametrize("case", sorted(GROUP_SLICE))
+def test_a_read_at_local_id_plus_a_group_value_is_one_window_a_group(
+        case, monkeypatch):
+    body, groups, local, elems, n, c, sites = GROUP_SLICE[case]
+    info, ref_info = _group_case(_tiled(body), groups, local, elems, n, c,
+                                 len(case), monkeypatch)
+    # (the read of ``offs`` at the group's id is itself a gather, and stays)
+    inner = int("offs[" in body)
+    assert info.access["group"] == sites and info.access["gather"] == inner
+    assert ref_info.access["gather"] == sites + inner
+    assert lowering_meta([info])["access"].endswith(f";local:2;group:{sites}")
+
+
+@pytest.mark.parametrize("case", sorted(KEEP_GATHER))
+def test_what_is_not_that_form_keeps_the_gather(case, monkeypatch):
+    body, gathers = KEEP_GATHER[case]
+    info, _ = _group_case(_tiled(body), 3, 64, 400, 300, 21, len(case),
+                          monkeypatch)
+    assert info.access["group"] == 0 and info.access["gather"] >= gathers
+
+
+@pytest.mark.parametrize("tile", ["none", "a barrier alone"])
+def test_a_kernel_without_a_tile_keeps_the_gather(tile):
+    """Only a kernel with a ``__local`` array is promised launches of whole
+    groups: without one the walk is the gather it was."""
+    src = """
+    __kernel void k(__global const float* x, __global float* out, int n) {
+        int tid = get_local_id(0);
+        int i = get_group_id(0) * 64 + tid;
+        float v = 0.0f;
+        %s
+        while (i < n) { v += x[i]; i += 192; }
+        out[get_global_id(0)] = v;
+    }""" % ("barrier(CLK_GLOBAL_MEM_FENCE);" if tile != "none" else "")
+    x = small_ints(500, 3) + np.arange(500, dtype=np.float32)
+    fn, info = KernelProgram(src).launcher("k", 192, 64, 192)
+    out = np.asarray(fn(0, (jnp.asarray(x), jnp.zeros(192, jnp.float32)), (500,))[1])
+    np.testing.assert_array_equal(
+        out, [x[i::192].sum(dtype=np.float32) for i in range(192)])
+    assert info.access["gather"] == 1 and info.access.get("group", 0) == 0
+    assert "group" not in lowering_meta([info])["access"] or tile != "none"
 
 
 # -- the row fallback, a scan, group-uniform offsets ------------------------
@@ -306,8 +537,8 @@ def test_the_launch_and_compile_spans_carry_the_local_field(devs, monkeypatch):
     for _kind, meta in seen:
         assert meta["local"] == ("arrays:1;bytes:1024;barriers:2;"
                                  "sites:shift:6,uniform:1,row:0")
-        assert meta["access"] == ("slice:0;strided:0;uniform:0;gather:2;"
-                                  "scatter:1;carried:0;local:7")
+        assert meta["access"] == ("slice:0;strided:0;uniform:0;gather:0;"
+                                  "scatter:1;carried:0;local:7;group:2")
 
 
 def test_a_tpu_lane_takes_the_xla_half_with_a_named_veto():

@@ -211,6 +211,198 @@ def test_oracle_random_gather_kernels(seed):
     }, {})
 
 
+@pytest.mark.parametrize("seed", range(12))
+def test_oracle_random_group_walkers(seed, monkeypatch):
+    """Randomized walks at ``local id + (the same in a group)`` in a kernel
+    with a tile (ISSUE 46): the group's part from the group id, a runtime
+    argument and a table at the group's id; the step as ``+=``, ``= i +`` or
+    a ``for``'s; a second read a group-uniform distance on; a bound that cuts
+    groups and can push the walk past either end of the buffer; odd seeds
+    under a per-lane ``if``.  Three ways: the oracle, the build (whose walks
+    must be ``group`` reads) and the same kernel with the form switched off
+    (the gather it replaces, byte for byte)."""
+    rng = np.random.default_rng(900 + seed)
+    start = str(rng.choice([
+        "get_group_id(0) * {k} + tid + {c}", "tid + (get_group_id(0) * {k} - c)",
+        "offs[get_group_id(0)] + (int)get_local_id(0)", "c + tid"])).format(
+            k=int(rng.choice([17, 64, 128])), c=int(rng.integers(-70, 70)))
+    far = str(rng.choice(["get_local_size(0)", "c", "get_group_id(0) * 3", "33"]))
+    grid = int(rng.choice([64, 100, 128]))
+    walk = [f"int i = {start}; while (i < n) {{ BODY i += {grid}; }}",
+            f"int i = {start}; while (i < n) {{ BODY i = i + {grid}; }}",
+            f"for (int i = {start}; i < n; i += {grid}) {{ BODY }}"][seed % 3]
+    walk = walk.replace("BODY", f"acc += x[i] - x[i + {far}] * 0.5f;")
+    if seed % 2:
+        walk = f"if (tid % {int(rng.integers(2, 5))} != 0) {{ {walk} }}"
+    src = f"""
+    __kernel void k(__global float* x, __global int* offs, __global float* out,
+                    int n, int c) {{
+        __local float t[64];
+        int tid = get_local_id(0);
+        t[tid] = 2.0f;
+        float acc = 0.0f;
+        {walk}
+        out[get_global_id(0)] = acc * t[tid];
+    }}"""
+    size = int(rng.choice([N + 50, 3 * N, 40]))
+    arrays = {
+        "x": rng.integers(-8, 9, size).astype(np.float32),
+        "offs": rng.integers(-70, size, N).astype(np.int32),
+        "out": np.zeros(N, np.float32),
+    }
+    values = {"n": int(rng.integers(0, size + 100)), "c": int(rng.integers(-9, 40))}
+    _run_both(src, arrays, values, atol=0)
+    kdef = lang.parse_kernels(src)[0]
+    order = [arrays[k] for k in ("x", "offs", "out")]
+    vals = (values["n"], values["c"])
+    fn, info = codegen.build_kernel_fn(kdef, N, 64, N)
+    out = np.asarray(fn(0, tuple(map(jnp.asarray, order)), vals)[2])
+    inner = int("offs[" in src)
+    assert (info.access["group"], info.access["gather"]) == (2, inner), src
+    monkeypatch.setattr(codegen, "_group_sites", lambda *a: {})
+    ref_fn, ref_info = codegen.build_kernel_fn(kdef, N, 64, N)
+    want = np.asarray(ref_fn(0, tuple(map(jnp.asarray, order)), vals)[2])
+    assert ref_info.access["gather"] == 2 + inner
+    assert out.tobytes() == want.tobytes(), src
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_oracle_random_moved_value_parameters(seed, monkeypatch):
+    """A value parameter moved by a group-uniform amount where the lanes of a
+    group part ways (a per-lane ``if``, a loop they leave on different passes)
+    is neither group-uniform nor ``local id + u``: what it held first is the
+    caller's.  Every read that names it keeps the gather, in a kernel with a
+    tile too; the oracle, the build and the build with the form switched off
+    agree byte for byte."""
+    rng = np.random.default_rng(1300 + seed)
+    m, d = int(rng.integers(2, 5)), int(rng.integers(1, 9))
+    far = str(rng.choice(["tid", "get_local_size(0)", "get_group_id(0) * 3", "33"]))
+    move = [f"if (tid % {m} != 0) {{ c += {d}; acc += x[c] + x[c + {far}]; }}",
+            f"while (c < n + tid % {m}) {{ acc += x[c] - x[c + {far}]; c += {d}; }}",
+            f"for (int k = 0; k < 2 + tid % {m}; k++) {{ c = c + {d}; "
+            f"acc += x[c + {far}]; }}",
+            f"if (tid % {m} == 0) {{ c = tid + {d}; }} else {{ c -= {d}; }}"
+            ][seed % 4]
+    src = f"""
+    __kernel void k(__global float* x, __global float* out, int n, int c) {{
+        __local float t[64];
+        int tid = get_local_id(0);
+        t[tid] = 2.0f;
+        float acc = 0.0f;
+        {move}
+        acc += x[c] + x[c + tid];
+        out[get_global_id(0)] = acc * t[tid];
+    }}"""
+    size = int(rng.choice([N + 50, 3 * N, 40]))
+    arrays = {"x": rng.integers(-8, 9, size).astype(np.float32),
+              "out": np.zeros(N, np.float32)}
+    values = {"n": int(rng.integers(0, size + 100)), "c": int(rng.integers(-9, 40))}
+    _run_both(src, arrays, values, atol=0)
+    kdef = lang.parse_kernels(src)[0]
+    order = tuple(jnp.asarray(arrays[k]) for k in ("x", "out"))
+    vals = (values["n"], values["c"])
+    fn, info = codegen.build_kernel_fn(kdef, N, 64, N)
+    out = np.asarray(fn(0, order, vals)[1])
+    assert info.access["group"] == 0 and info.access["gather"] >= 2, src
+    monkeypatch.setattr(codegen, "_group_sites", lambda *a: {})
+    ref_fn, _ = codegen.build_kernel_fn(kdef, N, 64, N)
+    assert out.tobytes() == np.asarray(ref_fn(0, order, vals)[1]).tobytes(), src
+
+
+# -- random nested bodies around walkers (REVIEW 46) --------------------------
+# What the group form's analysis must get right is WHERE an assignment stands:
+# ifs and loops the lanes of a group walk together or apart, break / continue,
+# walkers off walkers, amounts that are group-uniform, lane-varying or float.
+# The build is held to the same kernel built with the form switched off.
+
+_UNI = ["c", "5", "get_group_id(0) * 3", "n", "offs[get_group_id(0)]",
+        "(int)get_local_size(0)", "(int)(f * 2.0f)"]
+_LANE = ["tid % 3", "gid % 2", "offs[gid]"]
+_FLOAT = ["f", "2.5f"]
+
+
+def _nested_stmt(r, depth: int, walkers: list, inloop: bool, fixed=()) -> str:
+    """One random statement; ``fixed``: the walkers a ``while`` around it
+    steps (never assigned inside it: the loop must end)."""
+    k, w = r.random(), r.choice(walkers)
+    inner = lambda ws=walkers, loop=inloop, fx=fixed: " ".join(  # noqa: E731
+        _nested_stmt(r, depth + 1, ws, loop, fx) for _ in range(r.randint(1, 3)))
+    apart = r.choice([f"tid % {r.randint(2, 4)} == {r.randint(0, 1)}",
+                      f"(tid + {r.randint(0, 3)}) % 3 == 0"])
+    together = r.choice(["get_group_id(0) % 2 == 0", "c > 3", "n < 100"])
+    if k < 0.25 or (k < 0.50 and w in fixed):
+        return f"v += x[{w} + {r.choice(_UNI + ['0'])}];"
+    if k < 0.40:
+        return f"{w} {r.choice(['+=', '-='])} {r.choice(_UNI * 2 + _FLOAT + _LANE)};"
+    if k < 0.45:
+        return f"{w} = {r.choice(walkers + ['tid'])} + {r.choice(_UNI + _LANE)};"
+    if k < 0.50:
+        return f"{w}++;"
+    if k < 0.53 and inloop:
+        return (f"if ({r.choice([apart, together])}) "
+                f"{{ {r.choice(['continue;', 'break;'])} }}")
+    if depth >= 3:
+        return f"v += x[{w}];"
+    if k < 0.68:
+        other = inner() if r.random() < 0.4 else ""
+        return f"if ({apart}) {{ {inner()} }} else {{ {other} }}"
+    if k < 0.76:
+        return f"if ({together}) {{ {inner()} }}"
+    if k < 0.86:
+        kk = f"k{r.randint(0, 999)}"
+        bound = r.choice(["3", "2 + tid % 3", "2 + get_group_id(0) % 2"])
+        return (f"for (int {kk} = 0; {kk} < {bound}; {kk}++) "
+                f"{{ {inner(loop=True)} }}")
+    if k < 0.93:
+        nw = f"w{r.randint(0, 999)}"
+        return (f"int {nw} = {r.choice(walkers + ['tid'])} + {r.choice(_UNI)}; "
+                f"while ({nw} < n + {r.choice(['0', 'tid % 2', 'get_group_id(0)'])}) "
+                f"{{ {nw} += {r.choice(['64', '100', 'get_local_size(0)'])}; "
+                f"{inner(walkers + [nw], True, tuple(fixed) + (nw,))} }}")
+    nw = f"d{r.randint(0, 999)}"
+    return (f"int {nw} = {r.choice(walkers + ['tid', 'tid'])} + "
+            f"{r.choice(_UNI + _FLOAT + _LANE)}; v += x[{nw}];")
+
+
+@pytest.mark.parametrize("seed", range(24))
+def test_random_nested_bodies_read_what_the_gather_reads(seed, monkeypatch):
+    """(The tree REVIEW 46 read fails seed 2: a ``long`` walker moved by
+    ``2.5f`` under a per-lane ``if``.)"""
+    import random
+
+    r = random.Random(seed)
+    body = (f"int i = tid + {r.choice(_UNI)}; "
+            "long j = get_group_id(0) * 64 + tid;\n" + "\n".join(
+                _nested_stmt(r, 0, ["i", "j"], False)
+                for _ in range(r.randint(2, 5))))
+    src = f"""
+    __kernel void k(__global const float* x, __global const int* offs,
+                    __global float* out, int n, int c, float f) {{
+        __local float t[64];
+        int tid = get_local_id(0);
+        int gid = get_global_id(0);
+        t[tid] = 1.0f;
+        float v = 0.0f;
+        {body}
+        out[gid] = v + t[tid];
+    }}"""
+    rng = np.random.default_rng(seed)
+    size = 3 * 64
+    elems = int(rng.choice([400, 1000, 200]))
+    x = (rng.integers(0, 1000, elems) + np.arange(elems) * 1024).astype(np.float32)
+    offs = rng.integers(-80, elems + 80, size).astype(np.int32)
+    arrays = (jnp.asarray(x), jnp.asarray(offs), jnp.zeros(size, jnp.float32))
+    vals = (int(rng.integers(0, elems + 50)), int(rng.integers(-9, 40)),
+            float(rng.choice([1.5, -3.5, 2.25])))
+    kdef = lang.parse_kernels(src)[0]
+    fn, _info = codegen.build_kernel_fn(kdef, size, 64, size)
+    out = np.asarray(fn(0, arrays, vals)[2])
+    monkeypatch.setattr(codegen, "_group_sites", lambda *a: {})
+    ref_fn, ref_info = codegen.build_kernel_fn(kdef, size, 64, size)
+    assert not ref_info.access.get("group")
+    assert out.tobytes() == np.asarray(ref_fn(0, arrays, vals)[2]).tobytes(), src
+
+
 AFFINE_FORMS = {
     # the column walk, the row walk, an array of structures' field, a
     # structure of arrays' field: ``s * gid + u`` with ``u`` lane-uniform
