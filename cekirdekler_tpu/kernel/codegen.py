@@ -116,6 +116,7 @@ __all__ = ["build_kernel_fn", "KernelBuildInfo", "ViewSpec", "ctype_to_dtype",
 # ---------------------------------------------------------------------------
 
 _INT_TYPES = {"char", "uchar", "short", "ushort", "int", "uint", "long", "ulong", "bool"}
+_WIDE_INTS = {"int", "uint", "long", "ulong"}  # hold an index whole
 _FLOAT_TYPES = {"float", "double", "half"}
 _RANK = {
     "bool": 0, "char": 1, "uchar": 1, "short": 2, "ushort": 2,
@@ -261,6 +262,13 @@ class _Ctx:
         # the reads at ``local id + (the same in a group)``: id of the Index
         # node -> pitch (_group_sites; _group_slice serves them)
         self.group_sites: dict[int, int] = {}
+        # of them, those whose walk the build followed through their loop,
+        # by the loop's id (_settled_walks); inside the passes that loop makes
+        # with their windows settled, id of the Index node -> (block, row) of
+        # this pass's slice; and the reads so served (_exec_masked)
+        self.group_walks: dict[int, list] = {}
+        self.settled: dict[int, tuple] = {}
+        self.settled_sites: set[int] = set()
         self.local_access: dict[tuple[int, bool], str] = {}
         self.cooperative = False  # the kernel has a __local array or a barrier
         # per-innermost-loop masks: lanes that executed `break` (persist
@@ -326,6 +334,7 @@ class _Ctx:
         if coop is not None:
             self.group_uniform, self.tid_vars = coop.group_uniform, coop.tid_vars
             self.group_sites = coop.group_sites
+            self.group_walks = coop.group_walks
             self.cooperative = True
         self.returns = _contains_return(kernel.body)
         self.helpers = getattr(kernel, "helpers", {}) or {}
@@ -1411,6 +1420,56 @@ def _strided_window(ctx: _Ctx, site, c, width: int):
     return win, d
 
 
+def _group_index(ctx: _Ctx, idx) -> Any:
+    """An index's value as ``int32[G, L]``."""
+    return jnp.broadcast_to(jnp.asarray(_num(_as_dtype(idx, "int")), jnp.int32),
+                            ctx.shape).reshape(-1, ctx.local_size)
+
+
+def _group_starts(iv, m):
+    """``(u, some)`` of an index ``local id + u`` (``iv``:
+    :func:`_group_index`) under the group mask ``m`` (:func:`_group_mask`):
+    ``u`` a group, 0 where it has no active lane (``some``).  ``u`` is the
+    same in every active lane: their largest (a reduction; picking one lane's
+    would be a gather)."""
+    if m is None:
+        return iv[:, 0], jnp.ones(iv.shape[0], jnp.bool_)
+    tid = lax.broadcasted_iota(jnp.int32, iv.shape, 1)
+    some, lowest = jnp.any(m, axis=1), jnp.iinfo(jnp.int32).min
+    return jnp.where(some, jnp.max(jnp.where(m, iv - tid, lowest), axis=1), 0), some
+
+
+def _group_pitched(ctx: _Ctx, name: str, pitch: int) -> bool:
+    """Can the windows of a launch's groups be ONE slice of buffer ``name``
+    seen as ``[n / pitch, pitch / 128, 128]``: a pitch of whole rows that
+    holds a window, a buffer of whole pitches, one a group at least."""
+    n, L = ctx.bufs[name].shape[0], ctx.local_size
+    return not (pitch <= 0 or pitch % _ROW or L % _ROW or L > pitch
+                or n % pitch or n // pitch < ctx.B // L)
+
+
+def _group_first(u, some, pitch: int):
+    """``(u0, base)``: group 0's start as each group has it (its own start
+    less its place times ``pitch``), and as the active groups have it; their
+    starts lie ``pitch`` apart where ``base == u0`` in all of them."""
+    base = u - pitch * jnp.arange(u.shape[0], dtype=jnp.int32)
+    lowest = jnp.iinfo(jnp.int32).min
+    return jnp.where(jnp.any(some), jnp.max(jnp.where(some, base, lowest)), 0), base
+
+
+def _group_block(ctx: _Ctx, name: str, pitch: int, start: Callable):
+    """The windows of a launch's groups as one slice: ``L`` elements from row
+    ``row`` of each of the blocks ``block ..`` of the buffer seen as blocks of
+    ``pitch`` (which on the chip is how it lies in memory:
+    :func:`_strided_rows`); ``start()`` gives ``(block, row)``, computed
+    behind the view as PR 46's launcher did (its text is pinned:
+    tests/test_local_memory.py)."""
+    buf, L = ctx.bufs[name], ctx.local_size
+    view = buf.reshape(buf.shape[0] // pitch, pitch // _ROW, _ROW)
+    return lax.dynamic_slice(view, (*start(), jnp.int32(0)),
+                             (ctx.B // L, L // _ROW, _ROW)).reshape(ctx.shape)
+
+
 def _group_slice(ctx: _Ctx, name: str, idx: KVal, pitch: int = 0):
     """``buf[clip(idx)]`` of buffer ``name`` where ``idx`` is ``local id + u``
     with ``u`` the same in every ACTIVE work item of a group
@@ -1426,27 +1485,18 @@ def _group_slice(ctx: _Ctx, name: str, idx: KVal, pitch: int = 0):
     ``pitch`` apart (the build's hint, whole rows of 128), start on a row and
     stay inside the buffer and inside their ``pitch`` elements, they are ONE
     slice ``[G, L / 128, 128]`` of the buffer seen as ``[n / pitch, pitch /
-    128, 128]``, which on the chip is how the buffer lies in memory
-    (:func:`_strided_rows`).  Anywhere else a window a group, element for
-    element what the gather's clamp reads: taken at the nearest start inside
-    the buffer and, where that is not where it was asked for, moved out again
-    with the first or last element beyond the ends (:func:`_shift_rows`).
-    The chip's compiler makes a loop over the groups of the windows' fetch,
-    which is why the one slice is worth its check (PERF.md s.6, PR 46, has
-    both timed)."""
+    128, 128]`` (:func:`_group_block`).  Anywhere else a window a group,
+    element for element what the gather's clamp reads: taken at the nearest
+    start inside the buffer and, where that is not where it was asked for,
+    moved out again with the first or last element beyond the ends
+    (:func:`_shift_rows`).  The chip's compiler makes a loop over the groups
+    of the windows' fetch, which is why the one slice is worth its check
+    (PERF.md s.6, PR 46, has both timed); a loop that moves the windows by a
+    step the build knows makes the check once, not a pass
+    (:func:`_settle`)."""
     buf, L = ctx.bufs[name], ctx.local_size
     n, G = buf.shape[0], ctx.B // L
-    iv = jnp.broadcast_to(jnp.asarray(_num(_as_dtype(idx, "int")), jnp.int32),
-                          ctx.shape).reshape(G, L)
-    m, lowest = _group_mask(ctx), jnp.iinfo(jnp.int32).min
-    if m is None:
-        u, some = iv[:, 0], jnp.ones(G, jnp.bool_)
-    else:
-        # the same in every active lane: their largest (a reduction; picking
-        # one lane's would be a gather)
-        tid = lax.broadcasted_iota(jnp.int32, iv.shape, 1)
-        some = jnp.any(m, axis=1)
-        u = jnp.where(some, jnp.max(jnp.where(m, iv - tid, lowest), axis=1), 0)
+    u, some = _group_starts(_group_index(ctx, idx), _group_mask(ctx))
 
     def windows():
         first, last = buf[:1], buf[-1:]
@@ -1461,25 +1511,62 @@ def _group_slice(ctx: _Ctx, name: str, idx: KVal, pitch: int = 0):
                         lambda: _shift_rows(rows, moved, L, first, last),
                         lambda: rows).reshape(ctx.shape)
 
-    if (pitch <= 0 or pitch % _ROW or L % _ROW or L > pitch or n % pitch
-            or n // pitch < G):
+    if not _group_pitched(ctx, name, pitch):
         return windows()
-    # group 0's start as the active groups have it, and do they all agree
-    base = u - pitch * jnp.arange(G, dtype=jnp.int32)
-    u0 = jnp.where(jnp.any(some), jnp.max(jnp.where(some, base, lowest)), 0)
+    u0, base = _group_first(u, some, pitch)
     at = lax.rem(u0, jnp.int32(pitch))
     fits = (jnp.all((base == u0) | ~some) & (u0 >= 0)
             & (u0 <= n - pitch * (G - 1) - L)
             & ((u0 & (_ROW - 1)) == 0) & (at <= pitch - L))
+    return lax.cond(fits, lambda: _group_block(ctx, name, pitch, lambda: (
+        lax.div(u0, jnp.int32(pitch)), lax.div(at, jnp.int32(_ROW)))), windows)
 
-    def one_slice():
-        view = buf.reshape(n // pitch, pitch // _ROW, _ROW)
-        return lax.dynamic_slice(
-            view, (lax.div(u0, jnp.int32(pitch)), lax.div(at, jnp.int32(_ROW)),
-                   jnp.int32(0)),
-            (G, L // _ROW, _ROW)).reshape(ctx.shape)
 
-    return lax.cond(fits, one_slice, windows)
+class _Settled(NamedTuple):
+    """One group read of a loop that settles its windows once
+    (:func:`_settle`): the slice its passes take."""
+
+    site: int    # id of the read's Index node
+    block: Any   # 0-d int32: the block of ``pitch`` group 0 reads in the first pass
+    row: Any     # 0-d int32: the row inside their blocks the windows start on
+    step: int    # blocks more a pass
+    last: int    # the last block the slice of all groups may start in
+
+
+def _settle(ctx: _Ctx, walks: list, entering) -> tuple:
+    """``(ok, [_Settled])`` for the group reads ``walks`` of a masked loop
+    (:func:`_settled_walks`) whose buffers take the one slice
+    (:func:`_group_pitched`), in the order of the walks.
+
+    What :func:`_group_slice` tests of a pass's starts, taken ONCE, from the
+    starts the FIRST pass will have (the index where the loop is entered and
+    what the pass adds ahead of the read) in the lanes ``entering`` that
+    pass: ``ok``, do the lanes of every group agree, the groups' starts lie
+    ``pitch`` apart, on a row, at ``row`` inside their ``pitch`` elements.
+    Every lane that stays moves its index by the same multiple of the pitch
+    a pass and lanes only leave, so all of this holds in every later pass
+    for the lanes still there; what moves is the block group 0 reads,
+    ``step`` more a pass, and the slice lies inside the buffer while that is
+    in ``[0, last]``: a scalar the loop carries and its condition compares."""
+    m = jnp.broadcast_to(entering, ctx.shape).reshape(-1, ctx.local_size)
+    ok, sites = jnp.bool_(True), []
+    for w in walks:
+        name, pitch = w.node.base, ctx.group_sites[id(w.node)]
+        if not _group_pitched(ctx, name, pitch):
+            continue
+        first = _group_index(ctx, _eval(ctx, w.node.index)) + jnp.int32(w.before)
+        u, some = _group_starts(first, m)
+        agree = (first - lax.broadcasted_iota(jnp.int32, m.shape, 1)
+                 == u[:, None]) | ~m
+        u0, base = _group_first(u, some, pitch)
+        block = jnp.floor_divide(u0, jnp.int32(pitch))
+        at = u0 - block * pitch
+        ok = (ok & jnp.all(agree) & jnp.all((base == u0) | ~some)
+              & ((at & (_ROW - 1)) == 0) & (at <= pitch - ctx.local_size))
+        sites.append(_Settled(
+            id(w.node), block, lax.div(at, jnp.int32(_ROW)), w.step // pitch,
+            ctx.bufs[name].shape[0] // pitch - ctx.B // ctx.local_size))
+    return ok, sites
 
 
 def _note(ctx: _Ctx, node: Index, store: bool, kind: str) -> None:
@@ -1551,8 +1638,11 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
         return _loaded(lax.dynamic_slice(buf, (sidx,), (1,))[0], ctype)
     if id(node) in ctx.group_sites:
         _note(ctx, node, False, "group")
-        return _loaded(_group_slice(ctx, node.base, idx,
-                                    ctx.group_sites[id(node)]), ctype)
+        pitch = ctx.group_sites[id(node)]
+        if id(node) in ctx.settled:
+            return _loaded(_group_block(ctx, node.base, pitch,
+                                        lambda: ctx.settled[id(node)]), ctype)
+        return _loaded(_group_slice(ctx, node.base, idx, pitch), ctype)
     _note(ctx, node, False, "gather")
     iv = _num(_as_dtype(idx, "int"))
     if not hasattr(iv, "ndim") or iv.ndim == 0:
@@ -2538,14 +2628,17 @@ def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
             return jnp.sum(prev) > 0.0
         return jnp.any(prev)
 
-    def body_fun(carry, rows=None):
+    def body_fun(carry, rows=None, slices=None):
         prev, env_vals, buf_vals = carry
         prev = from_carry_mask(prev)
         saved_env, saved_bufs, saved_mask = dict(ctx.env), dict(ctx.bufs), ctx.mask
         saved_umask, saved_counted = ctx.umask, ctx.counted
         saved_runs, saved_views = ctx.runs, dict(ctx._rows_cache)
+        saved_settled = ctx.settled
         if rows:  # this pass's rows of the loop's run windows
             ctx.runs = {**ctx.runs, **rows}
+        if slices:  # this pass's slice of each settled group read
+            ctx.settled = {**ctx.settled, **slices}
         saved_stored = set(ctx.stored)
         saved_rm = ctx.return_mask
         saved_fr = ctx._freerun
@@ -2594,8 +2687,32 @@ def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
             ctx.break_mask, ctx.continue_mask = saved_bk, saved_cn
             # row views made inside the body belong to its trace
             ctx.runs, ctx._rows_cache = saved_runs, saved_views
+            ctx.settled = saved_settled
 
     carry0 = (to_carry_mask(prev0), init_env, init_bufs)
+    walks = ctx.group_walks.get(id(node)) if run_var is None else None
+    ok, sites = _settle(ctx, walks, jnp.logical_and(
+        prev0, eval_cond(init_env, init_bufs))) if walks else (None, [])
+    if sites:
+        # GROUP WINDOWS SETTLED ONCE: the passes whose slices lie inside the
+        # buffers run with no check in them; what is left (a tail over the
+        # end, a first pass that does not fit) runs the loop below
+        ctx.settled_sites.update(s.site for s in sites)
+
+        def inside(c):
+            go = jnp.logical_and(ok, cond_fun(c[0]))
+            for s, block in zip(sites, c[1]):
+                go = go & (block >= 0) & (block <= s.last)
+            return go
+
+        def settled_pass(c):
+            slices = {s.site: (block, s.row) for s, block in zip(sites, c[1])}
+            return (body_fun(c[0], None, slices),
+                    tuple(block + jnp.int32(s.step)
+                          for s, block in zip(sites, c[1])))
+
+        blocks = tuple(s.block for s in sites)
+        carry0 = lax.while_loop(inside, settled_pass, (carry0, blocks))[0]
     if run_var is None:
         active_f, env_f, bufs_f = lax.while_loop(cond_fun, body_fun, carry0)
     else:
@@ -3092,9 +3209,8 @@ def _local_arrays(body: list) -> dict:
     return {s.name: s for s in body if isinstance(s, LocalDecl)}
 
 
-def _tid_vars(body: list) -> frozenset:
-    """The locals that ARE the work item's local id: declared at kernel scope
-    as ``get_local_id(0)`` (under integer casts) and assigned nowhere else."""
+def _assignments(body: list) -> dict:
+    """``{local: how many declarations and assignments name it}``."""
     assigned: dict[str, int] = {}
     for node in _walk(body):
         if isinstance(node, Decl):
@@ -3103,6 +3219,13 @@ def _tid_vars(body: list) -> frozenset:
         elif isinstance(node, (Assign, CrementStmt)) and isinstance(
                 node.target, Var):
             assigned[node.target.name] = assigned.get(node.target.name, 0) + 1
+    return assigned
+
+
+def _tid_vars(body: list) -> frozenset:
+    """The locals that ARE the work item's local id: declared at kernel scope
+    as ``get_local_id(0)`` (under integer casts) and assigned nowhere else."""
+    assigned = _assignments(body)
     out = set()
     for s in body:
         if not isinstance(s, Decl) or s.ctype not in _INT_TYPES:
@@ -3117,11 +3240,12 @@ def _tid_vars(body: list) -> frozenset:
 def _build_int(node, sizes: dict) -> Optional[int]:
     """``node`` as an integer known when a launcher is built: literals and
     the calls ``sizes`` names (``get_local_size`` ..) under ``+ - *`` and
-    integer casts; None for anything else."""
+    integer casts, and the locals it names (:func:`_build_locals`); None for
+    anything else."""
     node = _under_int_casts(node)
     if isinstance(node, Num):
         return int(node.value) if float(node.value).is_integer() else None
-    if isinstance(node, Call):
+    if isinstance(node, (Call, Var)):
         return sizes.get(node.name)
     if isinstance(node, BinOp) and node.op in ("+", "-", "*"):
         a, b = _build_int(node.left, sizes), _build_int(node.right, sizes)
@@ -3194,7 +3318,6 @@ def _group_sites(body: list, params: list, gset: set[str],
     2-D slice of the buffer; the launch checks that they do."""
     if _contains_return(body):
         return {}
-    wide = {"int", "uint", "long", "ulong"}
     private = every - set(_local_arrays(body))  # a tile's element is a group's
     assigns: dict[str, list] = {}   # local -> [(path, steady, op, value)]
     declared: dict[str, object] = {}  # local -> its first declaration's value
@@ -3210,7 +3333,7 @@ def _group_sites(body: list, params: list, gset: set[str],
             (ints if s.ctype in _INT_TYPES else floats).update(
                 name for name, _init in s.names)
             for name, init in s.names:
-                ok = s.ctype in wide and name not in s.arrays
+                ok = s.ctype in _WIDE_INTS and name not in s.arrays
                 if name not in assigns:
                     declared[name] = init
                 assigns.setdefault(name, []).append(
@@ -3250,7 +3373,7 @@ def _group_sites(body: list, params: list, gset: set[str],
         ids, rest = [], []
         for sign, term in _terms(node, 1, []):
             leaf = term
-            while isinstance(leaf, Cast) and leaf.ctype in wide:
+            while isinstance(leaf, Cast) and leaf.ctype in _WIDE_INTS:
                 leaf = leaf.operand  # (a narrower cast wraps the local id)
             if (isinstance(leaf, Call) and _is_local_id(leaf)
                     or isinstance(leaf, Var)
@@ -3294,6 +3417,106 @@ def _group_sites(body: list, params: list, gset: set[str],
             if ix.base not in every and kept(ix.index, path)}
 
 
+def _build_locals(body: list, sizes: dict) -> dict:
+    """``sizes`` and, by name, the integer locals that are a build-time
+    integer wherever they are read (:func:`_build_int`): declared once, 32
+    bits or wider, with such a value and assigned nowhere else (SHOC's
+    ``gridSize``)."""
+    known, assigned = dict(sizes), _assignments(body)
+    decls = [(s.ctype, name, init) for s in _walk(body) if isinstance(s, Decl)
+             for name, init in s.names if name not in s.arrays]
+    while True:
+        found = {name: v for ctype, name, init in decls
+                 if ctype in _WIDE_INTS and init is not None
+                 and name not in known and assigned[name] == 1
+                 and (v := _build_int(init, known)) is not None}
+        if not found:
+            return known
+        known.update(found)
+
+
+class _Walk(NamedTuple):
+    """A group read whose walker the build has followed through its loop
+    (:func:`_settled_walks`)."""
+
+    node: Index
+    step: int    # what a pass of the loop adds to the index
+    before: int  # of it, what the pass has added where the read stands
+
+
+def _settled_walks(body: list, sites: dict, sizes: dict) -> dict:
+    """``{id of a loop: [_Walk]}``: the group reads (``sites``,
+    :func:`_group_sites`) whose index moves, pass by pass of the loop they
+    stand in, by an amount the BUILD knows that is a whole multiple of the
+    site's pitch.  What :func:`_group_slice` tests of such a read's starts
+    can then change from pass to pass in one scalar only, and
+    :func:`_exec_masked` settles the rest once, before the loop.
+
+    The index is ``local id + (group-uniform)`` already.  Here: the loop is
+    the innermost ``for`` / ``while`` around the read, which stands in its
+    body (not in its condition, nor in a loop inside it) and which no
+    ``continue`` shortens; of the index's terms ONE may name a local the loop
+    assigns, bare and with sign +1 (the walker), and no other reads such a
+    local or memory; every assignment the loop makes to the walker is a
+    statement of the body itself or the ``for``'s step (so every lane that
+    stays makes it, once a pass), ``+=`` / ``-=`` / ``++`` / ``--`` by a
+    build-time integer (:func:`_build_locals`).  A step read from
+    ``x[get_group_id(0)]``, one that is no multiple of the pitch, a walker
+    moved under an ``if``: not here, their reads are checked pass by pass."""
+    known = _build_locals(body, sizes)
+    out: dict = {}
+
+    def shallow(stmts) -> list:
+        """The ``Index`` nodes a pass of THIS loop evaluates."""
+        found = []
+        for s in stmts:
+            if isinstance(s, If):
+                found += _index_nodes(s.cond) + shallow(s.then) + shallow(s.other)
+            elif not isinstance(s, (For, While, DoWhile)):
+                found += _index_nodes(s)
+        return found
+
+    def moves(s, name: str) -> Optional[int]:
+        """What statement ``s`` of the loop's body adds to ``name``: 0 where
+        it assigns it nowhere, None where not by a build-time integer."""
+        mine = (isinstance(s, (Assign, CrementStmt))
+                and isinstance(s.target, Var) and s.target.name == name)
+        if mine and isinstance(s, CrementStmt):
+            return 1 if s.op == "++" else -1
+        if mine and s.op in ("+=", "-="):
+            by = _build_int(s.value, known)
+            return None if by is None else by if s.op == "+=" else -by
+        return None if name in _assigned_vars([s]) else 0
+
+    for loop in _walk(body):
+        if (not isinstance(loop, (For, While))
+                or _has_exit(loop.body, Continue)):
+            continue
+        pass_stmts = loop.body + ([loop.step] if getattr(loop, "step", None) else [])
+        moved = _assigned_vars(pass_stmts)
+        for k, stmt in enumerate(loop.body):
+            for ix in shallow([stmt]):
+                pitch = sites.get(id(ix), 0)
+                if pitch <= 0 or len(_index_nodes(ix)) > 1:
+                    continue
+                moving = [(sign, _under_int_casts(t))
+                          for sign, t in _terms(ix.index, 1, [])
+                          if _vars_read(t) & moved]
+                by = [0] * len(pass_stmts)
+                if moving:
+                    (sign, leaf), *more = moving
+                    if more or sign != 1 or not isinstance(leaf, Var):
+                        continue
+                    by = [moves(s, leaf.name) for s in pass_stmts]
+                    if None in by:
+                        continue
+                step = sum(by)
+                if step % pitch == 0 and abs(step) < 1 << 31:
+                    out.setdefault(id(loop), []).append(
+                        _Walk(ix, step, sum(by[:k])))
+    return out
+
+
 class _Coop(NamedTuple):
     """What a build knows of a kernel whose work items cooperate
     (:func:`_cooperation`)."""
@@ -3303,6 +3526,7 @@ class _Coop(NamedTuple):
     group_uniform: set      # locals the same in every lane of a group
     tid_vars: frozenset     # locals that are get_local_id(0)
     group_sites: dict       # the reads at ``local id + u``: id -> pitch (_group_sites)
+    group_walks: dict       # of them, by loop, those it settles once (_settled_walks)
 
     @property
     def nbytes(self) -> int:
@@ -3338,7 +3562,8 @@ def _cooperation(kernel: KernelDef, sizes: dict | None = None) -> Optional[_Coop
     every = frozenset(_private_array_names(kernel.body))
     sites = (_group_sites(kernel.body, kernel.params, gset, tids, every, sizes)
              if arrays else {})
-    return _Coop(arrays, barriers, gset, tids, sites)
+    walks = _settled_walks(kernel.body, sites, sizes or {}) if sites else {}
+    return _Coop(arrays, barriers, gset, tids, sites, walks)
 
 
 def _check_barriers(kernel: KernelDef, gset: set[str]) -> None:
@@ -3685,6 +3910,7 @@ def build_kernel_fn(
             info.access[kind] += 1
         info.access["carried"] = len(ctx.carried)
         if coop is not None:
+            info.access["settled"] = len(ctx.settled_sites)
             kinds = list(ctx.local_access.values())
             info.local_sites = {k: kinds.count(k) for k in LOCAL_KINDS}
         info.scattered = tuple(ctx.scattered)

@@ -513,8 +513,10 @@ def lowering_meta(infos) -> dict:
     where a kernel's work items cooperate inside their group: its ``__local``
     arrays, the bytes of them one work-group holds, its barrier statements,
     and the arrays' access sites by lowering (``codegen._local_load``);
-    ``access`` then ends in ``;local:N;group:M``: the sites' total, and the
-    buffer reads lowered to one window a work-group (``codegen._group_slice``)."""
+    ``access`` then ends in ``;local:N;group:M;settled:S``: the sites' total,
+    the buffer reads lowered to one window a work-group
+    (``codegen._group_slice``) and, of those, the reads whose windows a loop
+    settles once, before its passes (``codegen._settle``)."""
     infos = list(infos)
     leaves = [r for i in infos for r in (i.rungs or (i,))]
     meta = {"lowering": "+".join(sorted({i.lowering for i in leaves})),
@@ -566,8 +568,10 @@ def lowering_meta(infos) -> dict:
             f"{kind}:{sum(k.get(kind, 0) for k in per_kernel.values())}"
             for kind in codegen.ACCESS_KINDS)
         if coop:
-            group = sum(k.get("group", 0) for k in per_kernel.values())
-            meta["access"] += f";local:{sum(sites.values())};group:{group}"
+            group, settled = (sum(k.get(kind, 0) for k in per_kernel.values())
+                              for kind in ("group", "settled"))
+            meta["access"] += (f";local:{sum(sites.values())};group:{group}"
+                               f";settled:{settled}")
     # the stores that became a scatter, summed over the kernels as
     # ``access`` is, with the bytes of one element of each (``+``-joined)
     scattered = {i.name: i.scattered for i in leaves if i.scattered}

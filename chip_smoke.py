@@ -699,7 +699,9 @@ def _group_reduction(devices, sizes) -> dict:
     equal the configuration's plain reference partial by partial, the host's
     sum is the array's, and the launch's span fields say how the tile was
     lowered: two barriers, six shifts, one broadcast, no row fallback, on
-    the XLA half with the ``local-memory`` veto."""
+    the XLA half with the ``local-memory`` veto; and how the walk reads: both
+    reads of ``g_idata`` one window a group, the windows settled once a
+    launch, not a pass (``settled:2``)."""
     import importlib.util
 
     from cekirdekler_tpu import ClArray
@@ -743,7 +745,7 @@ def _group_reduction(devices, sizes) -> dict:
         _require(meta.get("local") == "arrays:1;bytes:1024;barriers:2;"
                  "sites:shift:6,uniform:1,row:0"
                  and meta["access"].endswith(
-                     ";gather:0;scatter:1;carried:0;local:7;group:2"),
+                     ";gather:0;scatter:1;carried:0;local:7;group:2;settled:2"),
                  f"reduce: local {meta.get('local')}, access {meta['access']}")
         if w.device.platform == "tpu":
             _require((info.lowering, (info.veto or "")[:12])

@@ -92,9 +92,11 @@ def test_stage_compute(devs):
     assert reduction["local"] == ("arrays:1;bytes:1024;barriers:2;"
                                   "sites:shift:6,uniform:1,row:0")
     assert reduction["loops"] == "counted:1;masked:1"
-    # its walk reads one window a group, not a row a work item (ISSUE 46)
+    # its walk reads one window a group, not a row a work item (ISSUE 46),
+    # and settles the windows once a launch, not a pass (ISSUE 47)
     assert reduction["access"] == ("slice:0;strided:0;uniform:0;gather:0;"
-                                   "scatter:1;carried:0;local:7;group:2")
+                                   "scatter:1;carried:0;local:7;group:2;"
+                                   "settled:2")
 
 
 def test_stage_compute_partitions_a_single_device():

@@ -166,7 +166,8 @@ def test_reduce_lowers_by_shifts_and_one_broadcast():
     meta = lowering_meta([info])
     assert meta["local"] == ("arrays:1;bytes:1024;barriers:2;"
                              "sites:shift:6,uniform:1,row:0")
-    assert meta["access"].endswith(";gather:0;scatter:1;carried:0;local:7;group:2")
+    assert meta["access"].endswith(
+        ";gather:0;scatter:1;carried:0;local:7;group:2;settled:2")
     # the groups' windows lie 512 apart: the launcher holds the one-slice read
     text = str(fn.trace(0, (jnp.asarray(x), jnp.zeros(4, jnp.float32)),
                         (x.size,)).jaxpr)
@@ -332,30 +333,43 @@ KEEP_GATHER = {
 }
 
 
+def _group_arrays(size: int, elems: int, seed: int) -> tuple:
+    """``(x, offs, the launch's device arrays)`` of a group-read case."""
+    rng = np.random.default_rng(seed)
+    x = (rng.integers(0, 1000, elems) + np.arange(elems) * 1024).astype(np.float32)
+    offs = rng.integers(-80, elems + 80, max(size, 8)).astype(np.int32)
+    return x, offs, (jnp.asarray(x), jnp.asarray(offs),
+                     jnp.zeros(size, jnp.float32))
+
+
 def _group_case(src: str, groups: int, local: int, elems: int, n: int, c: int,
                 seed: int, monkeypatch):
     """``(info, out)`` of the build, with the gather's and the oracle's
     outputs held equal to it."""
     from tests.kernel_oracle import Oracle
 
-    rng = np.random.default_rng(seed)
-    x = (rng.integers(0, 1000, elems) + np.arange(elems) * 1024).astype(np.float32)
-    offs = rng.integers(-80, elems + 80, max(groups * local, 8)).astype(np.int32)
     size = groups * local
-    arrays = (jnp.asarray(x), jnp.asarray(offs), jnp.zeros(size, jnp.float32))
+    x, offs, arrays = _group_arrays(size, elems, seed)
     fn, info = KernelProgram(src).launcher("k", size, local, size)
     out = np.asarray(fn(0, arrays, (n, c))[2])
-    with monkeypatch.context() as mp:
-        mp.setattr(codegen, "_group_sites", lambda *a: {})
-        ref_fn, ref_info = KernelProgram(src).launcher("k", size, local, size)
-        want = np.asarray(ref_fn(0, arrays, (n, c))[2])
-    assert ref_info.access["group"] == 0
-    assert out.tobytes() == want.tobytes()
+    for switched_off in ("_group_sites", "_settled_walks"):
+        # the gather it replaces, and the check made pass by pass (ISSUE 47)
+        with monkeypatch.context() as mp:
+            mp.setattr(codegen, switched_off, lambda *a: {})
+            ref_fn, ref_info = KernelProgram(src).launcher("k", size, local, size)
+            want = np.asarray(ref_fn(0, arrays, (n, c))[2])
+        assert out.tobytes() == want.tobytes(), switched_off
+        if switched_off == "_settled_walks":
+            assert ref_info.access["group"] == info.access["group"]
+            assert ref_info.access["settled"] == 0
+            continue
+        gather_info = ref_info
+        assert ref_info.access["group"] == 0
     host = {"x": x.copy(), "offs": offs.copy(), "out": np.zeros(size, np.float32)}
     kdef, = lang.parse_kernels(src)
     Oracle(kdef, local_size=local).run(host, {"n": n, "c": c}, size)
     np.testing.assert_array_equal(out, host["out"])
-    return info, ref_info
+    return info, gather_info
 
 
 @pytest.mark.parametrize("case", sorted(GROUP_SLICE))
@@ -368,7 +382,7 @@ def test_a_read_at_local_id_plus_a_group_value_is_one_window_a_group(
     inner = int("offs[" in body)
     assert info.access["group"] == sites and info.access["gather"] == inner
     assert ref_info.access["gather"] == sites + inner
-    assert lowering_meta([info])["access"].endswith(f";local:2;group:{sites}")
+    assert f";local:2;group:{sites};settled:" in lowering_meta([info])["access"]
 
 
 @pytest.mark.parametrize("case", sorted(KEEP_GATHER))
@@ -377,6 +391,139 @@ def test_what_is_not_that_form_keeps_the_gather(case, monkeypatch):
     info, _ = _group_case(_tiled(body), 3, 64, 400, 300, 21, len(case),
                           monkeypatch)
     assert info.access["group"] == 0 and info.access["gather"] >= gathers
+
+
+# -- the windows settled once a loop (ISSUE 47) ------------------------------
+# A group read whose index moves, pass by pass of its loop, by a step the
+# build knows that is a whole multiple of its pitch: the starts are checked
+# once, before the loop, and the passes inside the buffer hold no check.
+# Every case runs four ways (_group_case): this build, the build that checks
+# pass by pass, the gather, the scalar oracle.  ``whole``: every pass that
+# reads anything is a settled one, shown by running the build once more with
+# the per-pass read poisoned.
+
+PITCHED = """
+        int i = get_group_id(0) * (get_local_size(0) * 2) + tid + c;
+        int grid = get_local_size(0) * 2 * get_num_groups(0);
+        int bs = get_local_size(0);
+        while (i < n) { v += x[i] + x[i + bs]; %s }"""
+SHOC = PITCHED % "i += grid;"
+FULL = 256 * 3 * 4  # four passes of three groups of 128 at SHOC's pitch
+
+# name -> (body, groups, local, elements of x, n, c, group reads, of them
+#          settled, whole)
+SETTLED = {
+    "SHOC's walk, every pass inside": (SHOC, 3, 128, FULL, FULL - 100, 0, 2, 2, True),
+    "SHOC's walk, 8 groups of 256": (SHOC, 8, 256, 512 * 8 * 3, 512 * 8 * 3, 0,
+                                     2, 2, True),
+    "a step that is no multiple of the pitch": (
+        PITCHED % "i += grid + 128;", 3, 128, FULL * 2, FULL * 2 - 77, 0, 2, 0, False),
+    "a step read at the group's id": (
+        PITCHED % "i += grid + 0 * offs[get_group_id(0)];", 3, 128, FULL,
+        FULL - 9, 0, 2, 0, False),
+    "a step the group's id has a part in": (
+        PITCHED % "i += grid; i += 256 * (get_group_id(0) / 8);", 3, 128, FULL,
+        FULL, 0, 2, 0, False),
+    "a walker moved under an if": (
+        PITCHED % "if (c < 1) { i += grid; } else { i += 2 * grid; }", 3, 128,
+        FULL, FULL, 0, 2, 0, False),
+    "a second local the loop moves": ("""
+        int k = 0;""" + PITCHED.replace("x[i + bs]", "x[i + k]")
+        % "i += grid; k += 256;", 3, 128, FULL * 2, FULL, 0, 1, 1, False),
+    "the last passes over the end": (SHOC, 4, 128, 256 * 4 * 3,
+                                     256 * 4 * 3 + 2000, 0, 2, 2, False),
+    "a buffer one pitch short of the walk": (SHOC, 3, 128, FULL - 256, FULL, 0,
+                                             2, 2, False),
+    "a first pass off a row": (SHOC, 3, 128, FULL, FULL - 300, 64, 2, 2, False),
+    "a first pass before the buffer": (SHOC, 3, 128, FULL, FULL, -512, 2, 2, False),
+    "a loop some groups enter": (
+        "if (get_group_id(0) != 1) {" + SHOC + "}", 3, 128, FULL, FULL, 0, 2, 2, True),
+    "a loop group 0 stays out of": (
+        "if (get_group_id(0) > 0 && tid % 7 != 2) {" + SHOC + "}", 4, 128,
+        256 * 4 * 3, 256 * 4 * 3 - 5, 0, 2, 2, True),
+    "a loop no lane enters": (SHOC, 3, 128, FULL, 0, 0, 2, 2, True),
+    "a step of 0": ("""
+        int i = get_group_id(0) * 256 + tid;
+        int k = 0;
+        while (k < 2 + tid % 3) { v += x[i] + x[i + c]; k++; }""",
+                    3, 128, 256 * 4, 0, 128, 2, 2, True),
+    "a negative step": ("""
+        int i = get_group_id(0) * 256 + tid + c;
+        int grid = 256 * get_num_groups(0);
+        while (i >= n) { v += x[i]; i -= grid; }""",
+                        3, 128, FULL, 40, 256 * 9, 1, 1, True),
+    "a read ahead of the step and one behind it": (
+        PITCHED.replace("+ x[i + bs]", "") % "i += grid; v += x[i - grid + bs];",
+        3, 128, FULL, FULL - 200, 0, 2, 2, True),
+    "a for whose init declares the walker": ("""
+        int grid = 256 * get_num_groups(0);
+        for (int i = get_group_id(0) * 256 + tid; i < n; i += grid) {
+            v += x[i + 128];
+        }""", 3, 128, FULL, FULL - 130, 0, 1, 1, True),
+    "lanes that break": (
+        PITCHED.replace("{ v +=", "{ if (tid == 3 && i > 900) { break; } v +=")
+        % "i += grid;", 3, 128, FULL, FULL, 0, 2, 2, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SETTLED))
+def test_a_walk_by_a_step_the_build_knows_settles_its_windows_once(
+        case, monkeypatch):
+    body, groups, local, elems, n, c, sites, settled, whole = SETTLED[case]
+    info, _ = _group_case(_tiled(body), groups, local, elems, n, c, len(case),
+                          monkeypatch)
+    assert (info.access["group"], info.access["settled"]) == (sites, settled)
+    assert lowering_meta([info])["access"].endswith(
+        f";local:2;group:{sites};settled:{settled}")
+    if not whole:
+        return
+    # no pass that reads anything takes the read that is checked pass by pass
+    size = groups * local
+    _x, _offs, arrays = _group_arrays(size, elems, len(case))
+    fn, _info = KernelProgram(_tiled(body)).launcher("k", size, local, size)
+    want = np.asarray(fn(0, arrays, (n, c))[2])
+    monkeypatch.setattr(
+        codegen, "_group_slice", lambda ctx, name, idx, pitch=0: jnp.full(
+            ctx.shape, jnp.nan, ctx.bufs[name].dtype))
+    fn, _info = KernelProgram(_tiled(body)).launcher("k", size, local, size)
+    np.testing.assert_array_equal(np.asarray(fn(0, arrays, (n, c))[2]), want)
+
+
+def _eqns(jaxpr, name: str) -> list:
+    """Every equation of primitive ``name`` under ``jaxpr``, inner ones too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        found += [eqn] * (eqn.primitive.name == name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _eqns(sub, name)
+    return found
+
+
+def test_the_passes_reduce_takes_in_its_cell_hold_no_cond():
+    """SHOC's launcher as the cell builds it (64 groups of 256 over 2^26
+    elements, by shape alone): the walk is two ``while``s, the first with the
+    two one-slice reads and no ``cond`` in its body or its condition, the
+    second the loop as it was (two ``cond``s a pass)."""
+    fn, info = KernelProgram(REDUCE).launcher("reduce", 16384, 256, 16384,
+                                              platform="tpu")
+    jaxpr = fn.trace(0, (jax.ShapeDtypeStruct((1 << 26,), jnp.float32),
+                         jax.ShapeDtypeStruct((64,), jnp.float32)),
+                     (np.uint32(1 << 26),)).jaxpr.jaxpr
+    walks = [e for e in jaxpr.eqns if e.primitive.name == "while"
+             and _eqns(e.params["body_jaxpr"].jaxpr, "dynamic_slice")
+             and any(v.aval.shape == (64, 2, 128) for s in _eqns(
+                 e.params["body_jaxpr"].jaxpr, "dynamic_slice") for v in s.outvars)]
+    assert len(walks) == 2 and info.access["settled"] == 2
+    settled, checked = (e.params["body_jaxpr"].jaxpr for e in walks)
+    assert not _eqns(settled, "cond") and not _eqns(settled, "gather")
+    assert not _eqns(walks[0].params["cond_jaxpr"].jaxpr, "cond")
+    assert len([s for s in _eqns(settled, "dynamic_slice")
+                if s.outvars[0].aval.shape == (64, 2, 128)]) == 2
+    # the loop as PR 46 built it: each read picks its fetch pass by pass
+    assert len([c for c in checked.eqns if c.primitive.name == "cond"]) == 2
+    # the settled passes reduce nothing over the lanes but the mask's `any`
+    assert [r.primitive.name for r in settled.eqns
+            if r.primitive.name.startswith("reduce")] == []
 
 
 @pytest.mark.parametrize("tile", ["none", "a barrier alone"])
@@ -538,7 +685,8 @@ def test_the_launch_and_compile_spans_carry_the_local_field(devs, monkeypatch):
         assert meta["local"] == ("arrays:1;bytes:1024;barriers:2;"
                                  "sites:shift:6,uniform:1,row:0")
         assert meta["access"] == ("slice:0;strided:0;uniform:0;gather:0;"
-                                  "scatter:1;carried:0;local:7;group:2")
+                                  "scatter:1;carried:0;local:7;group:2;"
+                                  "settled:0")  # (groups of 64: no rows)
 
 
 def test_a_tpu_lane_takes_the_xla_half_with_a_named_veto():
@@ -574,20 +722,49 @@ def _hlo(src_file: str, kernel: str, arrays: tuple, values: tuple = ()):
     return hashlib.sha256(text.encode()).hexdigest(), info
 
 
-def test_a_kernel_with_no_tile_builds_the_hlo_it_built_before():
-    """The hashes were taken on the parent commit (a5e08b9) with this very
-    function: a kernel with neither a ``__local`` array nor a barrier lowers
-    to the last operation as it did."""
-    f32 = jax.ShapeDtypeStruct((4096,), jnp.float32)
-    i32 = jax.ShapeDtypeStruct((4096,), jnp.int32)
-    sha, info = _hlo("hpcg_spmv.cl", "spmv", (i32, i32, f32, f32, f32),
-                     (np.float32(1.5),))
+_F32, _I32, _I8 = ("float32", "int32", "int8")
+_BFS = ((_I32,) * 3 + (_I8,) * 3 + (_I32, _I8), (np.int32(4000),))
+# file, kernel -> the arrays' types, the values, the hash.  SpMV's was taken
+# on a5e08b9 (PR 45's parent), the two of Rodinia's BFS on b9ce6ad (PR 47's:
+# masked loops and ifs that pass ``_exec_masked``), with ``_hlo`` itself
+UNTILED = {
+    ("hpcg_spmv.cl", "spmv"): (
+        (_I32, _I32, _F32, _F32, _F32), (np.float32(1.5),),
+        "8548704244454052be315cdd13728139443dbb4d92d7997ff4f9187006ac1d5d"),
+    ("rodinia_bfs.cl", "BFS_1"): _BFS + (
+        "e34f29fa0fa12b4f24562ee1c15a3e6b2e6c7b9c7353a6c623ee26bd7979a9ab",),
+    ("rodinia_bfs.cl", "BFS_2"): _BFS + (
+        "be81ec125325b10ce7b145b7a468092e3e07f93010fa0db71951376ac2e6ac03",),
+}
+
+
+@pytest.mark.parametrize("src_file,kernel", sorted(UNTILED))
+def test_a_kernel_with_no_tile_builds_the_hlo_it_built_before(src_file, kernel):
+    """The hashes were taken on a parent commit with this very function: a
+    kernel with neither a ``__local`` array nor a barrier lowers to the last
+    operation as it did."""
+    types, values, want = UNTILED[src_file, kernel]
+    sha, info = _hlo(src_file, kernel, tuple(
+        jax.ShapeDtypeStruct((4096,), jnp.dtype(t)) for t in types), values)
     assert info.local == () and info.local_sites == {}
     assert "local" not in lowering_meta([info])
-    assert sha == SPMV_SHA
+    assert sha == want
 
 
-SPMV_SHA = "8548704244454052be315cdd13728139443dbb4d92d7997ff4f9187006ac1d5d"
+def test_reduce_checked_pass_by_pass_is_the_launcher_it_was(monkeypatch):
+    """What is left of a walk once its settled passes are over runs "the loop
+    as PR 46 built it": with the settling switched off the launcher of SHOC's
+    ``reduce`` is that commit's (b9ce6ad) to the last operation, by ``_hlo``
+    there; with it on it is another program."""
+    arrays = (jax.ShapeDtypeStruct((4096,), jnp.float32),) * 2
+    sha, info = _hlo("shoc_reduction.cl", "reduce", arrays, (np.uint32(4000),))
+    assert sha != REDUCE_PER_PASS_SHA and info.access["settled"] == 2
+    monkeypatch.setattr(codegen, "_settled_walks", lambda *a: {})
+    sha, info = _hlo("shoc_reduction.cl", "reduce", arrays, (np.uint32(4000),))
+    assert sha == REDUCE_PER_PASS_SHA and info.access["settled"] == 0
+
+
+REDUCE_PER_PASS_SHA = "443cc4105f1b92139e9cc1a487cf00a55bf4256bbfb6ec4de9ec3b3a69144a90"
 
 
 @pytest.mark.parametrize("name", ["nbody_direct.cl", "hpcg_spmv.cl",

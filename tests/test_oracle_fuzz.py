@@ -266,6 +266,74 @@ def test_oracle_random_group_walkers(seed, monkeypatch):
     assert out.tobytes() == want.tobytes(), src
 
 
+@pytest.mark.parametrize("seed", range(16))
+def test_oracle_random_settled_walks(seed, monkeypatch):
+    """Randomized walks of whole groups of 128 whose windows may be one slice
+    (ISSUE 47): the pitch, the walk's first start (on a row, off it, before
+    the buffer, a runtime argument's), the step (a multiple of the pitch or
+    not, up or down, a literal, the ranges' or a once-assigned local's), the
+    buffer (whole pitches or not), a bound that cuts groups and can push the
+    walk over either end, under a per-lane or a group's ``if``, lanes that
+    break.  Four ways, byte for byte: the oracle, the build (which settles the
+    windows once a loop exactly where step, pitch and buffer allow it), the
+    build that checks pass by pass, the gather."""
+    rng = np.random.default_rng(4700 + seed)
+    G, L = 3, 128
+    P = int(rng.choice([128, 256, 384]))
+    k = int(rng.choice([1, 1, 2, 3]))           # the step in pitches a group
+    odd = int(rng.choice([0, 0, 0, 64, 128])) if P > 128 else 0
+    step = P * G * k + odd
+    down = bool(rng.integers(0, 4) == 0)
+    elems = P * G * int(rng.integers(2, 6)) + int(rng.choice([0, 0, 0, 70]))
+    first = str(rng.choice(["0", "0", "128", "5", f"-{P}", "c"]))
+    if down:
+        first = f"{elems - P * G} + {first}"
+    far = str(rng.choice(["128", "c", "(int)get_local_size(0)", "0"]))
+    by = str(rng.choice([str(step), "stride", f"get_num_groups(0) * {P * k} + {odd}"]))
+    move = f"i -= {by};" if down else f"i += {by};"
+    cond = "i >= n" if down else "i < n"
+    leave = ("if (tid % 5 == 1 && i > 700) { break; }"
+             if rng.integers(0, 3) == 0 else "")
+    body = f"{leave} acc += x[i] - x[i + {far}] * 0.5f;"
+    walk = [f"int i = get_group_id(0) * {P} + tid + {first};\n"
+            f"        while ({cond}) {{ {body} {move} }}",
+            f"for (int i = get_group_id(0) * {P} + tid + {first}; {cond}; "
+            f"{move[:-1]}) {{ {body} }}"][seed % 2]
+    guard = str(rng.choice(["", "", f"tid % {int(rng.integers(2, 5))} != 0",
+                            "get_group_id(0) != 1", "get_group_id(0) > 0"]))
+    if guard:
+        walk = f"if ({guard}) {{ {walk} }}"
+    src = f"""
+    __kernel void k(__global const float* x, __global float* out, int n, int c) {{
+        __local float t[128];
+        int tid = get_local_id(0);
+        const int stride = {step};
+        t[tid] = 2.0f;
+        float acc = 0.0f;
+        {walk}
+        out[get_global_id(0)] = acc * t[tid];
+    }}"""
+    x = rng.integers(-8, 9, elems).astype(np.float32)
+    n = int(rng.integers(0, elems + 300) if not down else rng.integers(-200, elems))
+    vals = (n, int(rng.choice([0, 128, 256, 7, -128])))
+    kdef = lang.parse_kernels(src)[0]
+    arrays = (jnp.asarray(x), jnp.zeros(G * L, jnp.float32))
+    fn, info = codegen.build_kernel_fn(kdef, G * L, L, G * L)
+    out = np.asarray(fn(0, arrays, vals)[1])
+    host = {"x": x.copy(), "out": np.zeros(G * L, np.float32)}
+    Oracle(kdef, local_size=L).run(host, dict(zip(("n", "c"), vals)), G * L)
+    np.testing.assert_array_equal(out, host["out"], err_msg=src)
+    once = step % P == 0 and elems % P == 0
+    assert (info.access["group"], info.access["settled"]) == (2, 2 * once), src
+    for switched_off in ("_settled_walks", "_group_sites"):
+        with monkeypatch.context() as mp:
+            mp.setattr(codegen, switched_off, lambda *a: {})
+            ref_fn, ref_info = codegen.build_kernel_fn(kdef, G * L, L, G * L)
+            want = np.asarray(ref_fn(0, arrays, vals)[1])
+        assert not ref_info.access["settled"]
+        assert out.tobytes() == want.tobytes(), (switched_off, src)
+
+
 @pytest.mark.parametrize("seed", range(8))
 def test_oracle_random_moved_value_parameters(seed, monkeypatch):
     """A value parameter moved by a group-uniform amount where the lanes of a
