@@ -46,6 +46,14 @@ def spread(values) -> float:
     return (q3 - q1) / statistics.median(values)
 
 
+def spread_without_farthest(values) -> float:
+    """The driver's measure for tightness: the spread of a set without the
+    run farthest from its median, where leaving it out narrows the spread."""
+    med = statistics.median(values)
+    rest = sorted(values, key=lambda v: abs(v - med))[:-1]
+    return min(spread(values), spread(rest)) if len(rest) >= 2 else spread(values)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -82,11 +90,14 @@ def main(argv=None) -> int:
             per_set = [v[1:] for v in per_set]
         meds = [statistics.median(v) for v in per_set]
         spreads = [spread(v) for v in per_set]
+        trimmed = [spread_without_farthest(v) for v in per_set]
         report["metrics"][name] = {
             "medians": meds, "spreads": spreads, "widest": max(spreads),
+            "spreads_without_farthest": trimmed,
             "second_vs_first_median": meds[-1] / meds[0] - 1.0}
         print(f"{name}: medians {meds} spreads "
-              f"{[round(s, 5) for s in spreads]} second/first "
+              f"{[round(s, 5) for s in spreads]} without each set's "
+              f"farthest run {[round(s, 5) for s in trimmed]} second/first "
               f"{meds[-1] / meds[0] - 1.0:+.5f}")
     if args.traced_seed is not None:
         r = run_once(command, args.workload, args.traced_seed,

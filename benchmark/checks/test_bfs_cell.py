@@ -469,7 +469,7 @@ def test_the_configuration_the_cell_and_its_metrics_are_in_the_manifest():
 def test_the_accepted_cells_report_what_they_reported():
     """By name, whatever this PR appended behind them."""
     percall = cells.load_cell("mandelbrot_percall_1chip")
-    assert [m["name"] for m in percall.end_to_end] == ["call_p50_ms",
+    assert [m["name"] for m in percall.end_to_end] == ["call_p50_ms.percall",
                                                        "setup_s"]
     assert "launch_ms_per_call" in [m["name"] for m in percall.per_layer]
     assert not [m for m in percall.per_layer if m["name"] in NEW_METRICS]

@@ -60,7 +60,9 @@ PER_LAYER = {
                "window_head_ms_per_call.frame1",
                "trace_clock_violation_us.frame1"],
 }
-MOVES = {PER_CALL: "call_p50_ms", WINDOWED: "call_p50_ms"}
+# the per-call cell reports the median under a variant with a bound of its
+# own since PR 49 (its processes lie 0.6-0.8 % apart: PERF.md section 2)
+MOVES = {PER_CALL: "call_p50_ms.percall", WINDOWED: "call_p50_ms"}
 # 32 iterations: XLA's CPU backend contracts multiply-adds, which moves the
 # chaotic orbits of a few boundary pixels at 256 (test_harness.py)
 SMALL_CFG = {"width": 64, "height": 64, "sample_blocks": 8, "local_range": 64,
@@ -99,8 +101,8 @@ def test_sound_program_is_exact_with_exactly_the_cells_metrics(name, devices):
     assert result["attempted"] >= 1
     assert set(result["metrics"]) == {
         m["name"] for m in cells.load_cell(name).end_to_end}
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]  # the numbers compared last
     assert [(c.name, c.value, c.limit) for c in compared] == [
         ("pixels_differing", 0.0, 0), ("pixels_unwritten", 0.0, 0),
         ("calls_not_tiling", 0.0, 0)]
@@ -393,12 +395,12 @@ def test_the_configuration_cells_and_entries_are_in_the_manifest_by_name():
             # what a per-layer metric moves, its cell reports
             assert name in ends[MOVES[name]]["workloads"]
         reported = {m["name"] for m in cell.end_to_end}
-        assert {"setup_s", "call_p50_ms"} <= reported
+        assert {"setup_s", MOVES[name]} <= reported
     # in neither is items_per_s listed: a 30 s rate spread by 1.40 % in the
     # windowed cell's first set (PERF.md section 2)
     for name in (PER_CALL, WINDOWED):
         assert {m["name"] for m in cells.load_cell(name).end_to_end} == {
-            "call_p50_ms", "setup_s"}
+            MOVES[name], "setup_s"}
     per_call = cells.load_cell(PER_CALL)
     assert (per_call.params["loop"], per_call.params["view_cycle"],
             per_call.params["iterations_per_call"]) == ("per_call", 4, 1)

@@ -174,5 +174,52 @@ def test_window_of_idle_calls_is_not_correct(name, devices, monkeypatch):
 def test_result_carries_only_the_contracts_keys(devices):
     result = run.run_cell(small_cell("nbody_8k_window"), seed=5, seconds=0.1,
                           trace=False, devices=devices)
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]  # the numbers compared last
+    assert result["compared"] and all(
+        set(c) == {"value", "limit"} and c["value"] <= c["limit"]
+        for c in result["compared"].values())
+    assert json.dumps(result, allow_nan=False)
+
+
+def test_a_check_without_a_number_reads_null_in_the_line(devices, monkeypatch):
+    """NaN is no JSON: a comparison that produced no number is ``null``
+    beside its limit, and the run is not correct."""
+    cell = small_cell("nbody_8k_window")
+    nan = [cells.Compared("vel_step_rel_err", float("nan"), 1e-4)]
+    monkeypatch.setattr(cell.ref, "compare", lambda *a, **k: nan)
+    result = run.run_cell(cell, seed=5, seconds=0.05, trace=False,
+                          devices=devices)
+    assert result["correct"] is False
+    assert result["compared"] == {"vel_step_rel_err": {"value": None,
+                                                       "limit": 1e-4}}
+    assert json.dumps(result, allow_nan=False)
+
+
+@pytest.mark.parametrize("env, fixed", [
+    ({}, True),
+    ({"MALLOC_TRIM_THRESHOLD_": "131072"}, False),
+    ({"GLIBC_TUNABLES": "glibc.malloc.mmap_threshold=65536"}, False),
+])
+def test_the_allocator_is_fixed_unless_the_caller_set_it(env, fixed):
+    """In a process of its own (the thresholds are the whole process's): the
+    harness fixes glibc's thresholds, and a caller's own setting stands.
+    With them fixed a block of 4 MiB freed at the heap's top is not given
+    back: allocating it again maps nothing (PERF.md section 6, PR 49)."""
+    import subprocess
+
+    code = ("import sys; sys.path.insert(0, sys.argv[1]); import run, ctypes\n"
+            "print(run.steady_allocator())\n"
+            "libc = ctypes.CDLL(None)\n"
+            "libc.malloc.restype = ctypes.c_void_p\n"
+            "libc.free.argtypes = [ctypes.c_void_p]\n"
+            "a = libc.malloc(4 << 20); libc.free(a)\n"
+            "b = libc.malloc(4 << 20); libc.free(b); print(a == b)\n")
+    clean = {k: v for k, v in os.environ.items()
+             if not (k.startswith("MALLOC_") or k == "GLIBC_TUNABLES")}
+    out = subprocess.run([sys.executable, "-c", code, os.path.dirname(HERE)],
+                         env={**clean, **env}, capture_output=True, text=True,
+                         check=True).stdout.split()
+    assert out[0] == str(fixed)
+    if fixed:
+        assert out[1] == "True"

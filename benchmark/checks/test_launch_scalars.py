@@ -126,7 +126,7 @@ def test_the_entry_and_its_reader_are_in_the_manifest_by_name(name):
     assert rows[0] == {
         "name": name, "unit": METRICS[name], "better": "lower",
         "source": "program_span", "layer": "fused dispatch",
-        "moves": "call_p50_ms", "workloads": [CELL]}
+        "moves": "call_p50_ms.percall", "workloads": [CELL]}
     # the layer is one the manifest had, letter for letter
     assert sum(m["layer"] == "fused dispatch" for m in man["per_layer"]) > 2
     # a file of its own, and the cell reports it and what it moves
@@ -135,4 +135,4 @@ def test_the_entry_and_its_reader_are_in_the_manifest_by_name(name):
     assert callable(cells.load_reader(name).read)
     cell = cells.load_cell(CELL)
     assert name in {m["name"] for m in cell.per_layer}
-    assert "call_p50_ms" in {m["name"] for m in cell.end_to_end}
+    assert "call_p50_ms.percall" in {m["name"] for m in cell.end_to_end}

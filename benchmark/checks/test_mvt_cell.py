@@ -68,8 +68,8 @@ def test_sound_program_is_correct_with_exactly_the_cells_metrics(devices):
     # call, one caller), and the whole-process pauses of the chip's host
     # move a 30 s rate by more than half its bound (PERF.md s.2, PR 30)
     assert set(result["metrics"]) == {"call_p50_ms", "setup_s"}
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]  # the numbers compared last
     # every element of both vectors, twice: the window's state, the fresh call's
     assert [c.name for c in compared] == ["x_window_rel_err",
                                           "x_fresh_rel_err"]
@@ -264,12 +264,12 @@ def test_the_cell_and_its_metrics_are_in_the_manifest():
                and listed[m]["moves"] == "call_p50_ms" for m in NEW_METRICS)
     cell = cells.load_cell(CELL)
     assert [m["name"] for m in cell.end_to_end] == ["call_p50_ms", "setup_s"]
-    assert [m["name"] for m in cell.per_layer] == NEW_METRICS
+    # by name and in their order: PR 35 appended the window's edge behind them
+    assert [m["name"] for m in cell.per_layer
+            if m["name"] in NEW_METRICS] == NEW_METRICS
     assert cell.cfg["source"] == conf["source"]
-    # the accepted cells report what they reported.  SpMV's own check pins
-    # its entries as the manifest's LAST (test_spmv_cell.py: it fails since
-    # this cell was appended behind them, as a later cell must be); what it
-    # held is held here by name, whatever a later PR appends
+    # the accepted cells report what they reported, by name, whatever a later
+    # PR appends
     spmv = cells.load_cell("spmv_hpcg256_window")
     assert [m["name"] for m in spmv.per_layer] == [
         "spmv_kernel_ms_per_iter", "spmv_roofline", "spmv_gather_share",
@@ -282,6 +282,6 @@ def test_the_cell_and_its_metrics_are_in_the_manifest():
     row = next(w for w in man["workloads"] if w["name"] == "spmv_hpcg256_window")
     assert row["chips"] == 1 and row["config"] == "hpcg_spmv"
     for name in ("nbody_8k_window", "nbody_32k_window"):
-        assert [m["name"] for m in cells.load_cell(name).per_layer] == [
+        assert [m["name"] for m in cells.load_cell(name).per_layer][:4] == [
             "window_compiles", "device_idle_share", "kernel_ms_per_iter",
-            "nbody_roofline"]
+            "nbody_roofline"]  # later PRs appended variants behind them

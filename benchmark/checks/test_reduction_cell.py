@@ -11,7 +11,11 @@ reductions made by hand.  Nothing here yields a device number.
   short (by hand, and the program itself with the kernel so cut), a tile read
   before its stores, the previous call's partials, partials right in total and
   wrong by group, a dropped pass, a read-back that never came, a window of
-  idle calls, the bfloat16 control;
+  idle calls, the bfloat16 control; and, since the array is uploaded once and
+  stays (ISSUE 49), a resident buffer lost between calls;
+- the loop turns ``read`` off after the first upload and only where the
+  configuration says so, and a run in which the array crossed inside the
+  window reads ``upload_bytes_per_call.reduce`` above 0;
 - ``kernel_cost`` and the readers on spans and operations made by hand;
 - the configuration, the cell and every new entry are in the manifest, found
   BY NAME (a later PR appends behind them).
@@ -40,6 +44,9 @@ NEW_METRICS = [
     "xla_launch_share.reduce", "launch_ms_per_call.reduce",
     "loose_scalars_per_call.reduce", "dispatch_idle_ms_per_call.reduce",
     "unnamed_idle_share.reduce", "readback_ms_per_call.reduce"]
+LATER_METRICS = [  # appended behind them: PR 46's two, PR 49's guard
+    "reduce_gathered_accesses", "group_slice_accesses",
+    "upload_bytes_per_call.reduce"]
 GROUPS, LOCAL = 4, 256
 GRID = 2 * LOCAL * GROUPS
 SMALL_CFG = {"elements": 16 * GRID, "groups": GROUPS}
@@ -192,9 +199,29 @@ def test_compare_passes_the_sound_calls_and_fails_each_named_fault():
     # the control stands in the program's place and reads not correct
     control = compare(cell, data, window, fresh, precision="bfloat16")
     assert control["partials_differing"].value == 8.0
-    assert control["sum_abs_err"].value > 8 and not control["sum_abs_err"].ok
+    assert control["sum_abs_err"].value > 4 and not control["sum_abs_err"].ok
     with pytest.raises(ValueError):
         compare(cell, data, window, fresh, precision="float16")
+
+
+def test_a_seeds_data_do_not_depend_on_the_hosts_cores(monkeypatch):
+    """The array is drawn in fixed pieces by a few threads (set-up time at
+    1 GiB): the same seed gives the same array whatever the threads."""
+    cell = small_cell()
+    ref = cell.ref
+
+    def data(seed):
+        return ref.inputs(cell.cfg, cell.params,
+                          np.random.default_rng(seed))[0]["g_idata"]
+
+    many = data(2**31 + 7)
+    monkeypatch.setattr(ref.os, "cpu_count", lambda: 1)
+    np.testing.assert_array_equal(data(2**31 + 7), many)
+    assert many.dtype == np.float32 and many.size == SMALL_CFG["elements"]
+    assert (data(2**31 + 8) != many).mean() > 0.5
+    # every piece has all three values: none was left unfilled
+    pieces = np.array_split(many, ref.PIECES)
+    assert all(set(np.unique(p)) == {0.0, 1.0, 2.0} for p in pieces)
 
 
 def test_the_control_is_the_kernel_with_a_bfloat16_tile():
@@ -244,6 +271,152 @@ def test_a_window_of_idle_calls_is_not_correct(devices, monkeypatch):
     assert by["partials_differing"].value >= 3 and by["sum_abs_err"].value > 0
 
 
+def span(kind, start, ms, lane=0, **stats) -> host_phases.HostSpan:
+    return host_phases.HostSpan(
+        kind, start, start + 1e-3 * ms, 1,
+        {"lane": lane, **stats} if kind.startswith("ck/") else {})
+
+
+# -- the array is uploaded once and stays (ISSUE 49) --------------------------
+
+def without_residency(cell: cells.Cell) -> cells.Cell:
+    """The configuration as it stood until PR 49: no ``after_first_upload``."""
+    arrays = [{k: v for k, v in spec.items() if k != "after_first_upload"}
+              for spec in cell.cfg["arrays"]]
+    return cell._replace(cfg={**cell.cfg, "arrays": arrays})
+
+
+def test_a_resident_buffer_lost_between_calls_is_not_correct(
+        devices, monkeypatch):
+    """The caller says it has not changed the array and the lane is to keep
+    what it holds: a lane that lost the buffer makes a new one of zeros
+    (``ensure_resident``), and every partial reads 0."""
+    from cekirdekler_tpu.core.worker import Worker
+
+    real_window, real_ensure = run.window, Worker.ensure_resident
+
+    def forgetful(self, arr):
+        if arr.name == "g_idata":
+            self._buffers.pop(id(arr), None)
+        return real_ensure(self, arr)
+
+    def window(ctx, seconds, compiles):
+        monkeypatch.setattr(Worker, "ensure_resident", forgetful)
+        try:
+            real_window(ctx, seconds, compiles)
+        finally:
+            monkeypatch.setattr(Worker, "ensure_resident", real_ensure)
+
+    monkeypatch.setattr(run, "window", window)
+    logs = logged(monkeypatch)
+    result, compared = run_small(devices)
+    assert result["correct"] is False
+    by = {c.name: c for c in compared}
+    assert by["partials_differing"].value >= GROUPS
+    assert by["sum_abs_err"].value > 0 and by["partials_unwritten"].ok
+    (log, calls), = logs
+    assert [s for _n, s in log[9:9 + calls]] == [0.0] * calls
+
+
+def spans_of_a_run(devices, monkeypatch, cell):
+    """The spans the upload reader goes by, of one small run on the CPU:
+    ``bench/call`` from the harness's own span sites, ``ck/upload`` (with the
+    bytes the program's span carries) and ``ck/launch`` around the lane's two
+    methods.  ``(lines, window start, window end, result, reads)``: ``reads`` is
+    ``g_idata.read`` as every upload or launch found it."""
+    import contextlib
+    import time
+
+    from cekirdekler_tpu.core.worker import Worker
+
+    caller, lane, reads = [], [], []
+
+    @contextlib.contextmanager
+    def bench_span(name):
+        t0 = time.perf_counter()
+        yield
+        caller.append(host_phases.HostSpan(name, t0, time.perf_counter(), 0,
+                                           {}))
+
+    def around(method, kind, stats):
+        real = getattr(Worker, method)
+
+        def wrapped(self, *args, **kwargs):
+            t0 = time.perf_counter()
+            try:
+                return real(self, *args, **kwargs)
+            finally:
+                reads.append((kind, arrays["g_idata"].read))
+                lane.append(host_phases.HostSpan(
+                    kind, t0, time.perf_counter(), 1,
+                    {"lane": self.index, **stats(*args)}))
+
+        monkeypatch.setattr(Worker, method, wrapped)
+
+    around("upload", "ck/upload", lambda arr, off, size, full: {
+        "tag": arr.name,
+        "bytes": arr.host().nbytes if full else size * arr.host().itemsize})
+    around("launch", "ck/launch", lambda *args: {})
+    real_build, arrays = run.build, {}
+
+    def build(*args, **kwargs):
+        ctx = real_build(*args, **kwargs)
+        ctx.span = bench_span
+        arrays.update(ctx.arrays)
+        return ctx
+
+    monkeypatch.setattr(run, "build", build)
+    result = run.run_cell(cell, seed=2**31 + 49, seconds=0.2, trace=False,
+                          devices=devices)
+    return ([caller, lane], caller[0].start, caller[-1].end, result, reads)
+
+
+def upload_bytes_per_call(lines, t0, t1):
+    r = cells.load_reader("levels_per_call").reduce(lines, t0, t1, 0)
+    return cells.load_reader("upload_bytes_per_call.reduce").read(
+        SimpleNamespace(traversals=r))
+
+
+def test_the_loop_turns_read_off_after_the_first_upload_and_nothing_crosses(
+        devices, monkeypatch):
+    cell = small_cell()
+    (spec,) = [s for s in cell.cfg["arrays"] if "after_first_upload" in s]
+    assert spec["name"] == "g_idata" and spec["after_first_upload"] == {
+        "read": False}
+    lines, t0, t1, result, reads = spans_of_a_run(devices, monkeypatch, cell)
+    assert result["correct"] is True
+    # the harness's one synchronous compute finds the flag on and uploads the
+    # array whole, once in the process; every later launch finds it off
+    assert reads[:2] == [("ck/upload", True), ("ck/launch", True)]
+    assert all(kind == "ck/launch" and read is False
+               for kind, read in reads[2:]) and len(reads) > 12
+    (upload,) = [s for s in lines[1] if s.name == "ck/upload"]
+    assert upload.stats["tag"] == "g_idata" and upload.end <= t0
+    assert upload.stats["bytes"] == 4 * SMALL_CFG["elements"]
+    assert upload_bytes_per_call(lines, t0, t1) == 0.0
+
+
+def test_an_array_that_crossed_inside_the_window_reads_its_bytes(
+        devices, monkeypatch):
+    """Only where the configuration says so: without the entry the loop
+    leaves ``read`` on, the program uploads the array at every synchronous
+    compute (the cell until PR 49), and the guard reads the bytes."""
+    cell = without_residency(small_cell())
+    lines, t0, t1, result, reads = spans_of_a_run(devices, monkeypatch, cell)
+    assert result["correct"] is True  # the same sums, by the dearer road
+    assert all(read is True for _kind, read in reads)
+    assert upload_bytes_per_call(lines, t0, t1) == 4.0 * SMALL_CFG["elements"]
+    # one upload a call would already trip it: the reader by hand
+    calls = [span("bench/call", 10.0, 40), span("bench/call", 10.1, 40)]
+    lane = [span("ck/launch", 10.01, 1), span("ck/launch", 10.11, 1),
+            span("ck/upload", 10.105, 1, tag="g_idata", bytes=2**30)]
+    assert upload_bytes_per_call([calls, lane], 10.0, 10.2) == 2.0**29
+    assert upload_bytes_per_call([calls, lane[:2]], 10.0, 10.2) == 0.0
+    # a program without the spans leaves nothing to read, not 0
+    assert cells.load_reader("upload_bytes_per_call.reduce").read(
+        SimpleNamespace(traversals=None)) is None
+
+
 # -- kernel_cost and the readers against reductions made by hand -------------
 
 def test_kernel_cost_is_the_least_traffic_of_the_work():
@@ -258,11 +431,6 @@ def test_kernel_cost_is_the_least_traffic_of_the_work():
     big = cell.ref.kernel_cost(cell.cfg, cell.params, 16384, n=2**28)["bytes"]
     assert 1e3 * big / peak == pytest.approx(1.311, abs=1e-3)
 
-
-def span(kind, start, ms, lane=0, **stats) -> host_phases.HostSpan:
-    return host_phases.HostSpan(
-        kind, start, start + 1e-3 * ms, 1,
-        {"lane": lane, **stats} if kind.startswith("ck/") else {})
 
 
 LOCAL_FIELD = "arrays:1;bytes:1024;barriers:2;sites:shift:6,uniform:1,row:0"
@@ -366,15 +534,33 @@ def test_the_configuration_the_cell_and_its_metrics_are_in_the_manifest():
     assert {listed[m]["source"] for m in NEW_METRICS[:2]} == {"device_trace"}
     cell = cells.load_cell(CELL)
     assert [m["name"] for m in cell.end_to_end] == ["call_p50_ms", "setup_s"]
-    assert sorted(m["name"] for m in cell.per_layer) == sorted(NEW_METRICS)
+    # by name: a later PR appends behind them (PR 46 did, PR 49 did)
+    mine = [m["name"] for m in cell.per_layer]
+    assert set(NEW_METRICS + LATER_METRICS) <= set(mine)
+    assert len(mine) == len(set(mine))
+    guard = listed["upload_bytes_per_call.reduce"]
+    assert guard == {
+        "name": "upload_bytes_per_call.reduce", "unit": "bytes",
+        "better": "lower", "source": "program_span", "layer": "transfers",
+        "moves": "call_p50_ms", "workloads": [CELL]}
+    assert listed["upload_bytes_per_call"]["workloads"] == [
+        "bfs_1m_traversal_1chip"]
     assert cell.cfg["source"] == conf["source"]
     assert cell.cfg["reduced"] == [] and cell.cfg["lanes"] == 1
-    assert cell.cfg["elements"] in (2**28, 2**26)  # ISSUE 45's two sizes
+    assert cell.cfg["elements"] == 2**28  # the name's 1 GiB (ISSUE 49)
     assert "elements" in cell.cfg["assumed"]
+    assert "2^28" in cell.cfg["deployment"] and "1 GiB" in row["why"]
+    # uploaded once and stays: the configuration states it, the loop applies it
+    assert [(s["name"], s.get("after_first_upload")) for s in cell.cfg[
+        "arrays"]] == [("g_idata", {"read": False}), ("g_odata", None)]
+    assert cell.cfg["fresh_call"]["upload"] is False
+    assert cell.params["sync"] == "call"
     assert cell.params["n"] == 16384 == cell.cfg["groups"] * cell.cfg[
         "local_range"] and cell.params["loop"] == "reduction"
     assert cell.params["iterations_per_call"] in (1, 4)
     assert cell.params["warmup_calls"] == 8 and cell.params["pins"] == {}
+    # 8192 passes of six operations a call: 3 s of trace are read in ~2 min
+    assert cell.params["trace_seconds"] == 3
     plan = cell.ref.call_values(cell.cfg, cell.params, (cell.cfg["elements"],))
     assert len(plan["cycle"]) == 4 and plan["apart"] not in plan["cycle"]
     assert all(n % 32768 == 0 for (n,) in plan["cycle"] + [plan["apart"]])
@@ -399,7 +585,7 @@ def test_the_configuration_the_cell_and_its_metrics_are_in_the_manifest():
 def test_the_accepted_cells_report_what_they_reported():
     """By name, whatever this PR appended behind them."""
     percall = cells.load_cell("mandelbrot_percall_1chip")
-    assert [m["name"] for m in percall.end_to_end] == ["call_p50_ms",
+    assert [m["name"] for m in percall.end_to_end] == ["call_p50_ms.percall",
                                                        "setup_s"]
     assert "launch_ms_per_call" in [m["name"] for m in percall.per_layer]
     assert not [m for m in percall.per_layer if m["name"] in NEW_METRICS]

@@ -67,8 +67,8 @@ def test_sound_program_is_correct_with_exactly_the_cells_metrics(devices):
     assert result["attempted"] >= 1
     assert set(result["metrics"]) == {"items_per_s", "call_p50_ms",
                                       "setup_s"}
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]  # the numbers compared last
     # every row, twice: the window's last call and the fresh call
     assert [c.name for c in compared] == ["y_window_rel_err",
                                           "y_fresh_rel_err"]
@@ -244,19 +244,22 @@ def test_readers_leave_the_metric_out_where_nothing_ran():
 
 
 def test_the_cell_and_its_metrics_are_appended_to_the_manifest():
+    # found BY NAME: what stood last when this cell was appended (PR 26) has
+    # later cells' entries behind it, in the order it was appended in
     man = cells.manifest()
-    assert man["workloads"][-1]["name"] == CELL
-    assert man["workloads"][-1]["chips"] == 1
-    assert man["configs"][-1]["name"] == "hpcg_spmv"
-    assert [m["name"] for m in man["per_layer"][-6:]] == NEW_METRICS
+    row = next(w for w in man["workloads"] if w["name"] == CELL)
+    assert row["chips"] == 1 and row["config"] == "hpcg_spmv"
+    assert "hpcg_spmv" in [c["name"] for c in man["configs"]]
+    mine = [m for m in man["per_layer"] if m["name"] in NEW_METRICS]
+    assert [m["name"] for m in mine] == NEW_METRICS
     assert all(m["workloads"] == [CELL] and m["moves"] == "items_per_s"
-               for m in man["per_layer"][-6:])
+               for m in mine)
     cell = cells.load_cell(CELL)
     assert [m["name"] for m in cell.end_to_end] == [
         "items_per_s", "call_p50_ms", "setup_s"]
     assert [m["name"] for m in cell.per_layer] == NEW_METRICS
     # the accepted cells report what they reported
     for name in ("nbody_8k_window", "nbody_32k_window"):
-        assert [m["name"] for m in cells.load_cell(name).per_layer] == [
+        assert [m["name"] for m in cells.load_cell(name).per_layer][:4] == [
             "window_compiles", "device_idle_share", "kernel_ms_per_iter",
-            "nbody_roofline"]
+            "nbody_roofline"]  # later PRs appended variants behind them

@@ -440,9 +440,8 @@ def test_the_ten_entries_are_listed_with_their_readers():
     assert not set(spmv) & set(new)
 
 
-#: what ``test_wave_cell.py`` pins the wave cell's list to with ``==`` (it is
-#: marked ``xfail`` from ``tests/conftest.py`` since this PR appended five
-#: behind them); held here by name, whatever a later PR appends
+#: the wave cell's list as ``test_wave_cell.py`` holds it (by name since PR 49;
+#: with ``==`` until then), held here by name too, whatever a later PR appends
 WAVE_ACCEPTED = [
     "halo_idle_ms_per_call", "halo_bytes_per_step", "halo_host_hops",
     "wave_kernel_ms_per_iter", "wave_roofline", "device_idle_share.wave",

@@ -15,7 +15,12 @@ partials and the sum of a call are no other call's.  The loop ``reduction``
 leaves ``(n, sum)`` of every call it made in ``arrays["sums"]``.
 """
 
+import os
+from concurrent.futures import ThreadPoolExecutor
+
 import numpy as np
+
+PIECES = 64  # of the input array, each drawn from a generator of its own
 
 
 def geometry(cfg, params) -> tuple[int, int]:
@@ -30,7 +35,19 @@ def inputs(cfg, params, rng):
     if elements % (2 * local):
         raise ValueError(f"elements {elements}: no whole number of the "
                          f"{2 * local} elements a group takes a pass")
-    data = rng.integers(0, 3, elements, dtype=np.uint8).astype(np.float32)
+    # 1 GiB of float32 is mostly page faults for one thread (set-up): the
+    # array is drawn in PIECES pieces, each from its own child of the seed's
+    # generator, by a few threads.  The pieces are fixed, so a seed's data
+    # are the same on any host
+    data = np.empty(elements, np.float32)
+    bounds = np.linspace(0, elements, PIECES + 1).astype(np.int64)
+
+    def fill(k: int, child) -> None:
+        lo, hi = bounds[k], bounds[k + 1]
+        data[lo:hi] = child.integers(0, 3, hi - lo, dtype=np.uint8)
+
+    with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
+        list(pool.map(fill, range(PIECES), rng.spawn(PIECES)))
     return {"g_idata": data, "g_odata": np.full(groups, -1.0, np.float32),
             # not a kernel argument: the loop's log, (n, sum) a call
             "sums": []}, (elements,)

@@ -10,7 +10,14 @@ comparison holds every call's sum to the reference.
 With ``iterations_per_call`` above 1 a call is that many computes, one after
 the other through the cycle of the calls' arguments from its start (the
 configuration's rule for a median that spreads too widely over seeds); the
-harness's own cycle then only says which call is set apart."""
+harness's own cycle then only says which call is set apart.
+
+Where the configuration gives an array ``after_first_upload`` (flags by their
+public names), ``enter`` sets them: the harness has made its one synchronous
+compute by then, so the array is on the chip, and from here on the caller tells
+the runtime what the configuration's deployment states, by upstream's own idiom
+for data that lives on the device (``read = false``: the lane keeps the buffer
+it holds and nothing crosses).  An array without the entry is left alone."""
 
 import numpy as np
 
@@ -21,6 +28,9 @@ def items_per_call(params: dict) -> int:
 
 def enter(ctx) -> None:
     ctx.cr.enqueue_mode = False
+    for spec in ctx.cfg["arrays"]:
+        for flag, value in spec.get("after_first_upload", {}).items():
+            setattr(ctx.arrays[spec["name"]], flag, value)
 
 
 def make_call(ctx):

@@ -7,9 +7,14 @@ A new process per run: data from the seed, the cell's own shapes warmed up
 (set-up), then a closed loop of calls for ``--seconds`` seconds, then the
 check of what the window produced against the configuration's plain
 reference.  The last line of standard output is one JSON object with
-``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (and, traced,
-``breakdown``).  ``--trace 0`` reports the cell's end-to-end metrics; ``--trace
-1`` runs a short window under the profiler and reports its per-layer metrics.
+``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (traced,
+``breakdown`` too) and last ``compared``, each number compared beside its
+limit, which are also the last lines of standard error.  ``--trace 0``
+reports the cell's end-to-end metrics; ``--trace 1`` runs a short window
+under the profiler and reports its per-layer metrics.
+The process fixes glibc's allocator thresholds before it allocates anything
+large (``steady_allocator``), so that what a run reads does not hang on the
+order of the process's first frees.
 
 Fails, printing no result, without the program beside it (exit 2) or without
 as many TPU chips as the cell asks for (exit 3): there is no CPU fallback.
@@ -24,8 +29,10 @@ T_PROCESS = time.perf_counter()  # set-up is counted from here
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import ctypes  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import statistics  # noqa: E402
@@ -44,6 +51,31 @@ import xplane  # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
+
+# glibc's allocator as a long-running service sets it, before anything large
+# is allocated: blocks up to 32 MiB come from the heap (M_MMAP_THRESHOLD, its
+# largest value) and the heap's top is not given back (M_TRIM_THRESHOLD,
+# M_TOP_PAD).  Left to its defaults the allocator moves both thresholds by
+# the order in which a process happened to free its first large blocks, and a
+# process whose read-back buffers (4 MiB each, allocated anew by every
+# ``copy_to_host_async``) lie at the heap's top maps and faults them in again
+# at every call, from its first window to its last: PERF.md section 2.
+MALLOPT = {-3: 32 << 20, -1: 1 << 30, -2: 64 << 20}
+
+
+def steady_allocator() -> bool:
+    """Fix glibc's thresholds (``MALLOPT``); a caller who set one through the
+    environment (``MALLOC_*_``, ``GLIBC_TUNABLES``) keeps that choice, and
+    another C library is left alone."""
+    if any(k.startswith("MALLOC_") and k.endswith("_") for k in os.environ) \
+            or "glibc.malloc" in os.environ.get("GLIBC_TUNABLES", ""):
+        return False
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return False
+    return all(mallopt(param, value) == 1 for param, value in MALLOPT.items())
+
 
 def log(msg: str) -> None:
     print(f"[bench] {msg}", flush=True)
@@ -288,8 +320,8 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
              devices, compared_out: list | None = None) -> dict:
     """Everything of a run but the look for a chip: returns the result
     object.  ``devices`` is the program's device selection to take the
-    cell's lanes from; ``compared_out`` receives the numbers compared, which
-    a run prints in its log lines and keeps out of the result."""
+    cell's lanes from; ``compared_out`` receives the numbers compared as
+    ``Compared`` tuples (the result carries them under ``compared``)."""
     import jax
 
     compiles = CompileCounter()
@@ -357,6 +389,11 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
                        for m in cell.end_to_end}
         result["metrics"] = metrics
         result["device"] = device
+        # last in the line: each number compared beside its limit (a check
+        # that produced no number reads null)
+        result["compared"] = {
+            c.name: {"value": c.value if math.isfinite(c.value) else None,
+                     "limit": c.limit} for c in compared}
         return result
     finally:
         ctx.cr.dispose()
@@ -369,6 +406,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
+    steady = steady_allocator()
 
     # jax's persistent cache at a fixed place inside the checkout (the path
     # is part of its key); the program honours the variable and sets no other
@@ -393,10 +431,14 @@ def main(argv=None) -> int:
         return 3
     log(f"{args.workload} seed {args.seed} seconds {args.seconds} trace "
         f"{args.trace} on {len(tpus)} x {tpus[0].device_kind}; compile cache "
-        f"{jax.config.jax_compilation_cache_dir}")
+        f"{jax.config.jax_compilation_cache_dir}; allocator "
+        f"{'fixed' if steady else 'as the environment or the library has it'}")
     result = run_cell(cell, args.seed, args.seconds, bool(args.trace),
                       ct.all_devices().tpus())
-    print(json.dumps(result, allow_nan=False))
+    print(json.dumps(result, allow_nan=False), flush=True)
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} limit {c['limit']}",
+              file=sys.stderr)
     return 0
 
 
