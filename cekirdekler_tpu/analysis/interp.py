@@ -197,6 +197,8 @@ class KernelSummary:
     must_writes: dict = field(default_factory=dict)
     suppressed: frozenset = frozenset()          # // ckprove: ok lines
     line: int = 0
+    # ``__global floatN*`` parameters: param -> N (accesses are in elements)
+    widths: dict = field(default_factory=dict)
 
 
 class _Interp:
@@ -211,6 +213,11 @@ class _Interp:
             p.name for p in kernel.params if p.is_pointer)
         self.value_params = tuple(
             p.name for p in kernel.params if not p.is_pointer)
+        # ``__global floatN* p``: ``p[e]`` is elements [N e, N e + N) of the
+        # caller's array, and the summary speaks of ELEMENTS
+        self.widths = {p.name: lang.vector_of(p.ctype)[1]
+                       for p in kernel.params
+                       if p.is_pointer and lang.vector_of(p.ctype)}
         # an integer value parameter is a symbol of its own (an index
         # may add it: a row pitch, a halo width); any other is uniform
         self.env: dict[str, AV] = {
@@ -233,6 +240,10 @@ class _Interp:
             return  # private scratch: not a transfer surface
         if base not in self.pointer_params or not self.recording:
             return
+        n = self.widths.get(base)
+        if n and av.coef is not None:
+            av = AV(av.coef * n, av.lo * n, av.hi * n + (n - 1),
+                    _sym_add((), av.sym, float(n)))
         cond = self.cond_depth > 0 or self.saw_return
         key = (base, av, line, write, cond)
         if key not in self._seen:
@@ -305,6 +316,13 @@ class _Interp:
             return _uniform_combine(a, b)
         if isinstance(node, lang.Call):
             return self._call(node)
+        if isinstance(node, lang.VecLit):
+            for a in node.args:
+                self.eval(a)
+            return TOP
+        if isinstance(node, lang.Member):
+            self.eval(node.operand)
+            return TOP
         return TOP
 
     def _call(self, node: lang.Call) -> AV:
@@ -332,7 +350,8 @@ class _Interp:
             return TOP
         saved_env, saved_priv = self.env, self.priv
         self.env = {p.name: v for p, v in zip(fdef.params, args)}
-        self.priv = {}
+        self.priv = {p.name: TOP for p in fdef.params
+                     if lang.vector_of(p.ctype)}
         self._helper_depth += 1
         try:
             self.exec_block(fdef.body[:-1])
@@ -351,7 +370,8 @@ class _Interp:
 
     def _store(self, target, value: AV) -> None:
         if isinstance(target, lang.Var):
-            self.env[target.name] = value
+            if target.name not in self.priv:  # (a vector local stays data)
+                self.env[target.name] = value
             return
         if isinstance(target, lang.Index):
             idx = self.eval(target.index)
@@ -371,7 +391,13 @@ class _Interp:
             return
         if isinstance(s, lang.Decl):
             for name, init in s.names:
-                if name in s.arrays:
+                if lang.vector_of(s.ctype):
+                    # N scalars a work item, as a private array is (``v.x``
+                    # is the parser's ``v[0]``): data, never an index
+                    if init is not None:
+                        self.eval(init)  # records the reads it makes
+                    self.priv[name] = TOP
+                elif name in s.arrays:
                     self.priv[name] = AV.const(0)
                 else:
                     self.env[name] = self.eval(init) if init is not None \
@@ -524,4 +550,5 @@ def summarize_kernel(kernel: lang.KernelDef) -> KernelSummary:
         must_writes={k: tuple(v) for k, v in it.written.items()},
         suppressed=_suppressed_lines(kernel.source or ""),
         line=kernel.line,
+        widths=dict(it.widths),
     )

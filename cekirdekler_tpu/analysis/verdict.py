@@ -562,14 +562,16 @@ def structural_findings(
 ) -> list:
     """Flag-independent findings for the CLI's repo-corpus scan, where
     no :class:`TransferFlags` exist statically: split-safety of the
-    write set (assuming the default one element per work item).  Read
+    write set (assuming the default one element per work item, one VECTOR of
+    a ``__global floatN*`` parameter: ``N`` elements).  Read
     classifications surface in the CLI's ``--json`` report as facts,
     not findings — whether a halo read is an error depends on flags
     only the call site knows."""
     v = verify_launch(
         {summary.name: summary}, (summary.name,),
-        (FlagRow(True, False, True, False, False, False, epw),)
-        * len(summary.array_params),
+        tuple(FlagRow(True, False, True, False, False, False,
+                      epw * summary.widths.get(pname, 1))
+              for pname in summary.array_params),
         window=False, where=where)
     keep = ("off-partition-write", "scatter-write")
     return [f for f in v.findings if f.kind in keep]

@@ -131,6 +131,33 @@ class _Shared:
         setattr(obj._settings, self.name, value)
 
 
+def _check_vector_params(kernel: str, widths: tuple, params) -> None:
+    """A ``__global floatN*`` parameter binds an array of the element type
+    with ``N`` elements a vector: the array is whole vectors, and where a
+    transfer is cut by the work-item range (``partial_read``, a write-back
+    that is not ``write_all``) a work item's share, ``elements_per_work_item``
+    elements, is whole vectors too (upstream's ``numberOfElementsPerWorkItem``:
+    4 for a ``float4`` an item)."""
+    for pos, (n, p) in enumerate(zip(widths, params)):
+        if not n:
+            continue
+        if p.size % n:
+            raise ComputeValidationError(
+                f"vector-array-length: kernel {kernel!r} takes array "
+                f"'{p.name}' (parameter {pos}) as vectors of {n}, and its "
+                f"{p.size} elements are no whole number of them")
+        f = p.flags
+        ranged = f.partial_read or (
+            f.write and not f.write_all and not f.read_only)
+        if ranged and f.elements_per_work_item % n:
+            raise ComputeValidationError(
+                f"vector-elements-per-work-item: kernel {kernel!r} takes "
+                f"array '{p.name}' (parameter {pos}) as vectors of {n}, but "
+                f"its ranged transfer moves elements_per_work_item = "
+                f"{f.elements_per_work_item} elements a work item, which "
+                f"would cut a vector; set it to {n} (or a multiple)")
+
+
 class Cores:
     """Scheduler over the selected chips."""
 
@@ -451,6 +478,9 @@ class Cores:
                     f"kernel {name!r} takes {len(need_vals)} scalar value argument(s) "
                     f"{need_vals} but {given} given — pass values=(...) to compute()"
                 )
+            widths = self.program.vector_widths(name)
+            if widths:
+                _check_vector_params(name, widths, params)
         step = local_range * (pipeline_blobs if pipeline else 1)
         if global_range % step != 0:
             raise ComputeValidationError(

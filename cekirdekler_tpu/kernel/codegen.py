@@ -99,11 +99,14 @@ from .lang import (
     Index,
     KernelDef,
     LocalDecl,
+    Member,
     Num,
     Return,
     Ternary,
     UnOp,
     Var,
+    VecLit,
+    VECTOR_TYPES,
     While,
 )
 
@@ -250,6 +253,16 @@ class _Ctx:
         # private fixed-size arrays (``float acc[4];``): name -> length;
         # the env value is a (length, *shape) vector-per-element stack
         self.private: dict[str, int] = {}
+        # VECTOR TYPES (kernel/vectors.py).  The ``__global floatN*``
+        # parameters: name -> N (``buf_ctypes`` holds the element type); the
+        # vector locals, which are private arrays of N scalars with a type:
+        # name -> ``floatN``; every access of a vector parameter the walk
+        # lowered, (id of the Index node, is it the store) -> kind; does the
+        # kernel name a vector type anywhere (build_kernel_fn sets it)
+        self.widths: dict[str, int] = {}
+        self.vectors: dict[str, str] = {}
+        self.vector_access: dict[tuple[int, bool], str] = {}
+        self.has_vectors = False
         # WORK-GROUP COOPERATION.  ``__local`` arrays: name -> length; the env
         # value is ``[groups of the launch, length]``.  What the build proved
         # the same in every lane of a GROUP, and the locals that are
@@ -443,6 +456,13 @@ class _Ctx:
 def _as_dtype(v: KVal, ctype: str) -> KVal:
     if v.ctype == ctype:
         return v
+    if v.ctype in VECTOR_TYPES or ctype in VECTOR_TYPES:
+        # (a scalar becomes a vector only where the language says so, and
+        # there vectors.splat makes it)
+        raise vectors.refused(
+            "vector-conversion", f"a {v.ctype} where a {ctype} is needed: "
+            "conversions between vectors and scalars, and between vector "
+            "types (convert_T, as_T), are not supported; name a component")
     dt = ctype_to_dtype(ctype)
     val = v.value
     if hasattr(val, "astype"):
@@ -474,6 +494,8 @@ def _eval(ctx: _Ctx, node) -> KVal:
             return KVal(node.value, node.ctype, (0, node.value), (node.value,) * 2)
         return KVal(node.value, node.ctype)
     if isinstance(node, Var):
+        if node.name in ctx.private and node.name in ctx.vectors:
+            return vectors.read_local(ctx, node.name)
         if node.name in ctx.private or node.name in ctx.local:
             raise KernelLanguageError(
                 f"{'private' if node.name in ctx.private else '__local'} "
@@ -487,10 +509,19 @@ def _eval(ctx: _Ctx, node) -> KVal:
         return _load(ctx, node)
     if isinstance(node, BinOp):
         return _binop(ctx, node)
+    if isinstance(node, VecLit):
+        return vectors.literal(ctx, node)
+    if isinstance(node, Member):
+        return vectors.member(ctx, node)
     if isinstance(node, UnOp):
         v = _eval(ctx, node.operand)
         if node.op == "+":
             return v
+        if v.ctype in VECTOR_TYPES and node.op == "-":
+            return vectors.negate(v)
+        if v.ctype in VECTOR_TYPES and node.op == "~":
+            raise vectors.refused("vector-operator", "~ on a vector is not "
+                                  "supported", node.line)
         if node.op == "-":
             aff = span = None
             if v.affine is not None:
@@ -507,6 +538,10 @@ def _eval(ctx: _Ctx, node) -> KVal:
         c = _truthy(_eval(ctx, node.cond))
         a = _eval(ctx, node.then)
         b = _eval(ctx, node.other)
+        if a.ctype in VECTOR_TYPES or b.ctype in VECTOR_TYPES:
+            raise vectors.refused(
+                "vector-select", "?: with a vector operand is not supported; "
+                "assign under an if", node.line)
         t = _promote(a.ctype, b.ctype)
         av, bv = _num(_as_dtype(a, t)), _num(_as_dtype(b, t))
         return KVal(jnp.where(c, av, bv), t)
@@ -526,6 +561,10 @@ def _num(v: KVal):
 
 
 def _truthy(v: KVal):
+    if v.ctype in VECTOR_TYPES:
+        raise vectors.refused(
+            "vector-comparison", f"a {v.ctype} as a condition: a vector is "
+            "neither true nor false; test a component")
     if v.ctype == "bool":
         return v.value if hasattr(v.value, "dtype") else jnp.asarray(v.value, jnp.bool_)
     return _num(v) != 0
@@ -541,6 +580,8 @@ def _binop(ctx: _Ctx, node: BinOp) -> KVal:
 
     a = _eval(ctx, node.left)
     b = _eval(ctx, node.right)
+    if a.ctype in VECTOR_TYPES or b.ctype in VECTOR_TYPES:
+        return vectors.binop(ctx, op, a, b, node.line)
 
     if op in ("==", "!=", "<", ">", "<=", ">="):
         t = _promote(a.ctype, b.ctype)
@@ -690,11 +731,14 @@ def _inline_helper(ctx: _Ctx, fdef, arg_nodes, call_line: int) -> KVal:
     vals = [_eval(ctx, a) for a in arg_nodes]
     saved_env, saved_priv, saved_local = ctx.env, ctx.private, ctx.local
     saved_bufs, saved_bct = ctx.bufs, ctx.buf_ctypes
-    saved_uniform = ctx.uniform_vars
-    ctx.env = {
-        p.name: _as_dtype(v, p.ctype) for p, v in zip(fdef.params, vals)
-    }
-    ctx.private = {}
+    saved_uniform, saved_vectors = ctx.uniform_vars, ctx.vectors
+    ctx.env, ctx.private, ctx.vectors = {}, {}, {}
+    for p, v in zip(fdef.params, vals):
+        if p.ctype in VECTOR_TYPES:
+            vectors.bind(ctx, p.name, p.ctype,
+                         vectors.splat(ctx, v, p.ctype, call_line).value)
+        else:
+            ctx.env[p.name] = _as_dtype(v, p.ctype)
     ctx.local = {}
     ctx.bufs = {}
     ctx.buf_ctypes = {}
@@ -708,6 +752,8 @@ def _inline_helper(ctx: _Ctx, fdef, arg_nodes, call_line: int) -> KVal:
     try:
         _exec_block(ctx, fdef.body[:-1])
         ret = _eval(ctx, fdef.body[-1].value)
+        if fdef.ret_ctype in VECTOR_TYPES:
+            return vectors.splat(ctx, ret, fdef.ret_ctype, call_line)
         return _as_dtype(ret, fdef.ret_ctype)
     finally:
         ctx._after_stack.pop()
@@ -716,7 +762,7 @@ def _inline_helper(ctx: _Ctx, fdef, arg_nodes, call_line: int) -> KVal:
         ctx.private, ctx.local = saved_priv, saved_local
         ctx.bufs = saved_bufs
         ctx.buf_ctypes = saved_bct
-        ctx.uniform_vars = saved_uniform
+        ctx.uniform_vars, ctx.vectors = saved_uniform, saved_vectors
 
 
 def _call(ctx: _Ctx, node: Call) -> KVal:
@@ -735,7 +781,13 @@ def _call(ctx: _Ctx, node: Call) -> KVal:
     if name in _UNSUPPORTED_CALLS:
         raise KernelLanguageError(f"{name}: {_UNSUPPORTED_CALLS[name]}", line=node.line)
 
+    vectors.refuse_builtin(name, node.line)
+
     args = [_eval(ctx, a) for a in node.args]
+    if any(a.ctype in VECTOR_TYPES for a in args):
+        raise vectors.refused(
+            "vector-builtin", f"{node.name} of a vector is not supported; "
+            "call it a component", node.line)
 
     if name in ("get_global_id", "get_local_id", "get_group_id", "get_global_size",
                 "get_local_size", "get_num_groups", "get_global_offset", "get_work_dim"):
@@ -1585,6 +1637,8 @@ def _load(ctx: _Ctx, node: Index) -> KVal:
         return _local_load(ctx, node)
     if node.base not in ctx.bufs:
         raise KernelCompileError(f"{node.base!r} is not an array parameter", line=node.line)
+    if node.base in ctx.widths:
+        return vectors.load(ctx, node)
     buf = ctx.bufs[node.base]
     ctype = ctx.buf_ctypes[node.base]
     idx = _eval(ctx, node.index)
@@ -1662,6 +1716,9 @@ def _store(ctx: _Ctx, node: Index, val: KVal) -> None:
         return
     if node.base not in ctx.bufs:
         raise KernelCompileError(f"{node.base!r} is not an array parameter", line=node.line)
+    if node.base in ctx.widths:
+        vectors.store(ctx, node, val)
+        return
     buf = ctx.bufs[node.base]
     ctype = ctx.buf_ctypes[node.base]
     v = _num(_as_dtype(val, ctype))
@@ -1782,8 +1839,12 @@ def _exec(ctx: _Ctx, node) -> None:
             jnp.zeros((ctx.B // ctx.local_size, node.size),
                       ctype_to_dtype(node.ctype)), node.ctype)
         return
+    if isinstance(node, Decl) and node.ctype in VECTOR_TYPES:
+        vectors.declare(ctx, node)
+        return
     if isinstance(node, Decl):
         for name, init in node.names:
+            ctx.vectors.pop(name, None)  # whatever it was in another scope
             if name in node.arrays:
                 if ctx.pallas:
                     from .pallas_backend import PallasUnsupported
@@ -1899,6 +1960,9 @@ def _assign(ctx: _Ctx, target, op: str, value_expr) -> None:
         rhs = _binop(ctx, BinOp(op=base_op, left=_Lit(cur), right=_Lit(rhs), line=getattr(target, "line", 0)))
     if isinstance(target, Var):
         name = target.name
+        if name in ctx.private and name in ctx.vectors:
+            vectors.assign_local(ctx, name, rhs, getattr(target, "line", 0))
+            return
         if name in ctx.private or name in ctx.local:
             raise KernelLanguageError(
                 f"cannot assign to array {name!r} as a whole; "
@@ -2041,7 +2105,7 @@ def _run_reads(ctx: _Ctx, node, cond_expr, carried_bufs) -> tuple:
     tables = _index_reads([node.body, cond_expr], j, set())
     return j, sorted(t for t in tables
                      if t in ctx.bufs and t not in ctx.private
-                     and t not in carried_bufs)
+                     and t not in carried_bufs and t not in ctx.widths)
 
 
 def _walk(node):
@@ -2131,7 +2195,8 @@ def _window_sites(ctx: _Ctx, node, counted: _Trips) -> tuple:
     sites = []
     for ix in _index_nodes(node.body):
         if (ix.base not in ctx.bufs or ix.base in ctx.private
-                or ix.base in changed or not _affine_expr(ix.index)):
+                or ix.base in changed or not _affine_expr(ix.index)
+                or ix.base in ctx.widths):
             continue
         terms = _terms(ix.index, 1, [])
         bare = [sg for sg, t in terms if isinstance(t, Var) and t.name == j]
@@ -2185,7 +2250,7 @@ def _own_element_bufs(ctx: _Ctx, body: list, cond_expr, stored: list) -> dict:
     for k in stored:
         mine = [ix.index for ix in sites if ix.base == k]
         buf = ctx.bufs[k]
-        if (k in ctx.private or k in ctx.own or not mine
+        if (k in ctx.private or k in ctx.own or k in ctx.widths or not mine
                 or not all(_same_expr(e, mine[0]) for e in mine)
                 or not _affine_expr(mine[0]) or _vars_read(mine[0]) & changed
                 or buf.dtype != ctype_to_dtype(ctx.buf_ctypes[k])):
@@ -2772,8 +2837,10 @@ def _compactable(ctx: _Ctx, body: list, cond_expr) -> bool:
     pass: a loop of arithmetic and own-element accesses runs at the vector
     unit's speed over all lanes and gains nothing)."""
     if (ctx.pallas or ctx.info.get("in_loop", 0) or ctx.masks()[0] is None
-            or ctx.B <= _COMPACT_WIDTH or ctx.cooperative):
-        return False  # (chunks of entering lanes would tear groups apart)
+            or ctx.B <= _COMPACT_WIDTH or ctx.cooperative or ctx.has_vectors):
+        # (chunks of entering lanes would tear groups apart; a chunk moves
+        # lane vectors to its lanes, not a vector's planes)
+        return False
     changed, private = _assigned_vars(body), ctx.lane_arrays()
     for ix in _index_nodes([body, cond_expr]):
         if ix.base not in ctx.bufs or ix.base in private:
@@ -3071,8 +3138,9 @@ def _contains_return(stmts: list) -> bool:
 
 
 def _private_array_names(stmts: list, out: set[str] | None = None) -> set[str]:
-    """The private arrays declared under ``stmts``, and the ``__local`` ones
-    (kernel scope only): see :func:`_local_arrays` for those alone."""
+    """The private arrays declared under ``stmts`` (a vector local is one),
+    and the ``__local`` ones (kernel scope only): see :func:`_local_arrays`
+    for those alone."""
     if out is None:
         out = set()
     for s in stmts:
@@ -3080,6 +3148,8 @@ def _private_array_names(stmts: list, out: set[str] | None = None) -> set[str]:
             out.add(s.name)
         elif isinstance(s, Decl):
             out.update(s.arrays)
+            if s.ctype in VECTOR_TYPES:  # N scalars a work item, as an array
+                out.update(name for name, _init in s.names)
         elif isinstance(s, If):
             _private_array_names(s.then, out)
             _private_array_names(s.other, out)
@@ -3324,6 +3394,7 @@ def _group_sites(body: list, params: list, gset: set[str],
     reads: list = []                # (Index node, path)
     values = {p.name for p in params if not p.is_pointer}
     ints = {p.name for p in params if p.ctype in _INT_TYPES}
+    wide = {p.name for p in params if p.ctype in VECTOR_TYPES}  # vectors.load's
     floats: set[str] = set()        # names declared as anything else
 
     for s, path, steady in _regions(body, gset, private, group=True):
@@ -3350,7 +3421,8 @@ def _group_sites(body: list, params: list, gset: set[str],
             exprs = [s.cond]  # what stands inside comes by itself
         else:
             continue
-        reads.extend((ix, path) for ix in _index_nodes(exprs))
+        reads.extend((ix, path) for ix in _index_nodes(exprs)
+                     if ix.base not in wide)
 
     ints -= floats
 
@@ -3814,6 +3886,13 @@ class KernelBuildInfo:
     # row).  ``()`` / ``{}`` for a kernel with neither array nor barrier
     local: tuple = ()
     local_sites: dict = field(default_factory=dict)
+    # vector types (kernel/vectors.py): ``(params, widths, loads, gathers,
+    # stores)``, the ``__global floatN*`` parameters, their ``N`` (sorted,
+    # each once) and, filled at trace, the accesses of them that were BUILT,
+    # one an access whatever its width: loads by slice, strided window or
+    # uniform element, loads by per-lane gather, stores; ``()`` for a kernel
+    # with no vector parameter
+    vector: tuple = ()
 
 
 LOCAL_KINDS = ("shift", "uniform", "row")
@@ -3863,6 +3942,9 @@ def build_kernel_fn(
         stored_params=[],
     )
 
+    widths = {p.name: VECTOR_TYPES[p.ctype][1] for p in array_params
+              if p.ctype in VECTOR_TYPES}
+    has_vectors = lang.uses_vectors(kernel)
     uniform = _uniform_vars(kernel.body, {p.name for p in value_params})
     info.loops_counted, info.loops_masked = _loop_counts(kernel, uniform)
     pitches = pitch_params(kernel)
@@ -3890,9 +3972,17 @@ def build_kernel_fn(
         ctx.readonly = readonly
         ctx.kept = {(info.array_params[p], kind): v
                     for (p, kind), v in (views or {}).items()}
+        ctx.has_vectors, ctx.widths = has_vectors, widths
         for p, arr in zip(array_params, arrays):
             ctx.bufs[p.name] = arr
             ctx.buf_ctypes[p.name] = p.ctype
+            if p.name in widths:
+                ctx.buf_ctypes[p.name] = VECTOR_TYPES[p.ctype][0]
+                if arr.shape[0] % widths[p.name]:
+                    raise KernelCompileError(
+                        f"{p.ctype}* {p.name}: an array of {arr.shape[0]} "
+                        f"elements is no whole number of {p.ctype}",
+                        line=p.line)
         for p, v in zip(value_params, values):
             v = jnp.asarray(v, ctype_to_dtype(p.ctype))
             # an integer argument is the same in every lane: stride 0
@@ -3914,6 +4004,14 @@ def build_kernel_fn(
             kinds = list(ctx.local_access.values())
             info.local_sites = {k: kinds.count(k) for k in LOCAL_KINDS}
         info.scattered = tuple(ctx.scattered)
+        if widths:
+            built = [(store, kind) for (_site, store), kind
+                     in ctx.vector_access.items()]
+            info.vector = (
+                len(widths), tuple(sorted(set(widths.values()))),
+                sum(not st and kind != "gather" for st, kind in built),
+                sum(not st and kind == "gather" for st, kind in built),
+                sum(st for st, _kind in built))
         own = [kind for site, kind in ctx.compact_access.items()
                if ctx.access.get(site) != kind]
         info.compact = (ctx.compact_loops, _COMPACT_WIDTH, own.count("gather"),
@@ -3963,3 +4061,9 @@ def pitch_params(kernel: KernelDef) -> tuple:
                 seen.add(node.name)
                 feeding.extend(sources.get(node.name, ()))
     return tuple(i for i, name in enumerate(names) if name in found)
+
+
+# the vector forms live in a module of their own, which reads this one: the
+# import stands behind everything it reads (either module may be imported
+# first)
+from . import vectors  # noqa: E402

@@ -9,8 +9,11 @@ scalar locals, arithmetic, comparisons, ``if``/``for``/``while`` with
 ``break``/``continue``, and the common math builtins — which the codegen (codegen.py) vectorizes over work
 items and lowers to JAX/XLA.  A work-group's items cooperate through
 ``__local T name[K];`` arrays declared at kernel scope and ``barrier()``
-statements (docs/KERNEL_LANGUAGE.md, *Work-group cooperation*).  Unsupported
-constructs (``__local`` parameters, atomics, vector types, pointers beyond
+statements (docs/KERNEL_LANGUAGE.md, *Work-group cooperation*).  The vector
+types ``float2 float4 int2 int4 uint2 uint4`` are types of the language
+(*Vector types* there): ``__global float4*`` parameters, locals, literals,
+``v.x``; what is left of OpenCL's vectors is refused by name.  Unsupported
+constructs (``__local`` parameters, atomics, pointers beyond
 parameters) raise :class:`KernelLanguageError` with the offending line.
 
 This module is the front end only: source → list of :class:`KernelDef` ASTs.
@@ -24,7 +27,8 @@ from typing import Any, Optional
 
 from ..errors import KernelCompileError, KernelLanguageError
 
-__all__ = ["tokenize", "parse_kernels", "KernelDef", "Param", "extract_kernel_names"]
+__all__ = ["tokenize", "parse_kernels", "KernelDef", "Param", "extract_kernel_names",
+           "VECTOR_TYPES", "vector_of", "uses_vectors", "any_node", "refused"]
 
 # ---------------------------------------------------------------------------
 # lexer
@@ -182,6 +186,27 @@ class Cast(Node):
     operand: Any
 
 
+@dataclass
+class VecLit(Node):
+    """``(float4)(a, b, c, d)``, ``(float4)(s)`` and, in a declaration, the
+    brace form ``{a, b, c, d}``: one scalar a component, or one for all."""
+
+    ctype: str
+    args: list
+
+
+@dataclass
+class Member(Node):
+    """``e.x`` of a vector-valued expression that is no plain local (an
+    element ``p[i].x``, a helper's result): one component, read only.  A
+    local's ``v.x`` is no node of its own: the parser writes it
+    ``Index(base="v", index=Num(0))``, the local being ``N`` scalars a work
+    item as a private array is (docs/KERNEL_LANGUAGE.md, *Vector types*)."""
+
+    operand: Any
+    comp: int
+
+
 # statements
 @dataclass
 class Decl(Node):
@@ -319,6 +344,77 @@ _TYPE_KWS = {
 
 _ASSIGN_OPS = {"=", "+=", "-=", "*=", "/=", "%=", "&=", "|=", "^=", "<<=", ">>="}
 
+# ---------------------------------------------------------------------------
+# vector types (docs/KERNEL_LANGUAGE.md, *Vector types*).  The core: six
+# types, as ``__global T*`` parameters, locals and helper parameters; one
+# component read or written at a time (``v.x``, ``v.s2``); everything else
+# of OpenCL's vectors is refused by NAME (the first word of the message).
+# ---------------------------------------------------------------------------
+
+#: vector type -> (element type, components)
+VECTOR_TYPES = {f"{t}{n}": (t, n) for t in ("float", "int", "uint") for n in (2, 4)}
+_VECTOR_NAME = re.compile(
+    r"^(char|uchar|short|ushort|int|uint|long|ulong|float|double|half)(2|3|4|8|16)$")
+_COMPONENTS = {"x": 0, "y": 1, "z": 2, "w": 3}
+
+
+def vector_of(ctype: str):
+    """``(element type, components)`` of a vector type, None for a scalar."""
+    return VECTOR_TYPES.get(ctype)
+
+
+def refused(name: str, what: str, line: int = 0) -> KernelLanguageError:
+    """A vector construct outside the core, refused by NAME: the message's
+    first word."""
+    return KernelLanguageError(f"{name}: {what}", line=line)
+
+
+def component_of(member: str, width: int, line: int) -> int:
+    """Which component ``.member`` names in a vector of ``width``."""
+    if member in _COMPONENTS:
+        k = _COMPONENTS[member]
+    elif re.fullmatch(r"[sS][0-9a-fA-F]", member):
+        k = int(member[1], 16)
+    elif (member in ("lo", "hi", "even", "odd")
+          or re.fullmatch(r"[xyzw]{2,4}|[sS][0-9a-fA-F]{2,}", member)):
+        raise refused(
+            "vector-swizzle", f".{member} names several components; read and "
+            "write one at a time (.x .y .z .w, .s0 .. .s3)", line)
+    else:
+        raise refused("vector-member", f"a vector has no member .{member} "
+                       "(.x .y .z .w, .s0 .. .s3)", line)
+    if k >= width:
+        raise refused("vector-member", f".{member} is component {k} and the "
+                       f"vector has {width}", line)
+    return k
+
+
+def any_node(kernel, test) -> bool:
+    """Does ``test(node)`` hold for any syntax-tree node of ``kernel``: its
+    parameters, its body, the helpers it can call?"""
+    seen: set[int] = set()
+
+    def walk(node) -> bool:
+        if node is None or isinstance(node, (str, int, float, bool)) \
+                or id(node) in seen:
+            return False
+        seen.add(id(node))
+        if isinstance(node, (list, tuple)):
+            return any(walk(x) for x in node)
+        if isinstance(node, dict):
+            return any(walk(x) for x in node.values())
+        return hasattr(node, "__dict__") and (test(node) or any(
+            walk(v) for k, v in vars(node).items() if k != "source"))
+
+    return walk(kernel.params) or walk(kernel.body) or walk(
+        getattr(kernel, "helpers", None))
+
+
+def uses_vectors(kernel) -> bool:
+    """Does ``kernel`` name a vector type anywhere?"""
+    return any_node(kernel, lambda node: isinstance(node, (VecLit, Member)) or any(
+        getattr(node, k, None) in VECTOR_TYPES for k in ("ctype", "ret_ctype")))
+
 
 class _Parser:
     def __init__(self, tokens: list[Token], source: str):
@@ -327,6 +423,9 @@ class _Parser:
         self.source = source
         self._loop_depth = 0  # break/continue outside a loop = parse error
         self._in_helper = False  # `return expr;` only valid in helpers
+        # the function being parsed: its vector-typed locals and value
+        # parameters, name -> type (``v.x`` needs the width where it stands)
+        self._vectors: dict[str, str] = {}
 
     # -- token helpers ------------------------------------------------------
     @property
@@ -361,8 +460,16 @@ class _Parser:
         return KernelCompileError(msg, source=self.source, line=line or self.cur.line)
 
     # -- types --------------------------------------------------------------
-    def at_type(self) -> bool:
-        return self.cur.kind == "kw" and self.cur.text in _TYPE_KWS
+    def at_type(self, tok: Token | None = None) -> bool:
+        t = tok or self.cur
+        return (t.kind == "kw" and t.text in _TYPE_KWS) or (
+            t.kind == "id" and _VECTOR_NAME.match(t.text) is not None)
+
+    def at_decl(self) -> bool:
+        """Does a declaration start here: ``const``, a scalar type's keyword,
+        or a vector type's name ahead of the variable's?"""
+        return self.cur.text == "const" or (self.at_type() and (
+            self.cur.kind == "kw" or self.peek().kind == "id"))
 
     def parse_type(self) -> str:
         parts = []
@@ -370,6 +477,15 @@ class _Parser:
             if self.cur.text != "const":
                 parts.append(self.cur.text)
             self.advance()
+        if not parts and self.cur.kind == "id" and _VECTOR_NAME.match(self.cur.text):
+            t = self.advance()
+            while self.accept("const"):
+                pass
+            if t.text not in VECTOR_TYPES:
+                raise refused(
+                    "vector-width", f"{t.text} is not supported; the vector "
+                    f"types are {' '.join(sorted(VECTOR_TYPES))}", t.line)
+            return t.text
         if not parts:
             raise self.err("expected a type")
         t = " ".join(parts)
@@ -382,6 +498,7 @@ class _Parser:
     def parse_helper(self, start: Token) -> FuncDef:
         """A non-kernel function: scalar params, scalar return, inlined at
         call sites.  Exactly one ``return expr;`` — the last statement."""
+        self._vectors = {}
         ret = self.parse_type()
         if ret == "void":
             raise KernelLanguageError(
@@ -464,7 +581,14 @@ class _Parser:
             name_tok = self.advance()
             if name_tok.kind != "id":
                 raise self.err(f"expected kernel name, found {name_tok.text!r}", name_tok.line)
+            self._vectors = {}
             params = self.parse_params()
+            for p in params:
+                if not p.is_pointer and p.ctype in VECTOR_TYPES:
+                    raise refused(
+                        "vector-value-parameter", f"{p.ctype} {p.name}: a "
+                        "kernel takes a vector through a __global pointer, "
+                        "or its components as scalars", p.line)
             self.expect("{")
             body = self.parse_block_items()
             self.expect("}")
@@ -513,6 +637,10 @@ class _Parser:
                 raise self.err(f"expected parameter name, found {name_tok.text!r}", name_tok.line)
             if is_pointer and space == "value":
                 space = "global"
+            if ctype in VECTOR_TYPES and not is_pointer:
+                self._vectors[name_tok.text] = ctype
+            else:
+                self._vectors.pop(name_tok.text, None)
             params.append(
                 Param(ctype=ctype, name=name_tok.text, is_pointer=is_pointer,
                       address_space=space if is_pointer else "value",
@@ -564,8 +692,8 @@ class _Parser:
                 return (Break if t.text == "break" else Continue)(line=t.line)
             if t.text in ("__local", "local"):
                 return self.parse_local_decl()
-            if t.text in _TYPE_KWS or t.text == "const":
-                return self.parse_decl()
+        if self.at_decl():
+            return self.parse_decl()
         stmt = self.parse_expr_statement()
         self.expect(";")
         return stmt
@@ -584,7 +712,18 @@ class _Parser:
             if name_tok.kind != "id":
                 raise self.err(f"expected variable name, found {name_tok.text!r}", name_tok.line)
             init = None
-            if self.accept("["):
+            self._vectors.pop(name_tok.text, None)  # a scalar of that name now
+            if ctype in VECTOR_TYPES:
+                if self.cur.text == "[":
+                    raise refused(
+                        "vector-array", f"an array of {ctype} is not "
+                        "supported; declare the vectors one by one",
+                        name_tok.line)
+                self._vectors[name_tok.text] = ctype
+                if self.accept("="):
+                    init = (self.parse_braces(ctype) if self.cur.text == "{"
+                            else self.parse_expr())
+            elif self.accept("["):
                 size_tok = self.advance()
                 if size_tok.kind != "num" or not size_tok.text.isdigit():
                     raise KernelLanguageError(
@@ -611,6 +750,27 @@ class _Parser:
             self.expect(",")
         return Decl(ctype=ctype, names=names, arrays=arrays, line=line)
 
+    def parse_braces(self, ctype: str) -> VecLit:
+        """``{a, b, c, d}`` behind ``float4 v =``."""
+        line = self.expect("{").line
+        return self._vec_lit(ctype, self._scalars_until("}"), line)
+
+    def _scalars_until(self, close: str) -> list:
+        args = [self.parse_expr()]
+        while self.accept(","):
+            args.append(self.parse_expr())
+        self.expect(close)
+        return args
+
+    def _vec_lit(self, ctype: str, args: list, line: int) -> VecLit:
+        n = VECTOR_TYPES[ctype][1]
+        if len(args) not in (1, n):
+            raise refused(
+                "vector-literal", f"a {ctype} is written from {n} scalars or "
+                f"from one for all, not {len(args)} (a vector among them is "
+                "not supported)", line)
+        return VecLit(ctype=ctype, args=args, line=line)
+
     def parse_local_decl(self) -> LocalDecl:
         """``__local T name[K];`` (``K`` a literal, or a ``#define``, which
         is one by now): kernel scope is checked once the body is whole."""
@@ -622,6 +782,10 @@ class _Parser:
         while self.accept("const") or self.accept("volatile"):
             pass
         ctype = self.parse_type()
+        if ctype in VECTOR_TYPES:
+            raise refused(
+                "vector-local-memory", f"a __local array of {ctype} is not "
+                "supported; keep a tile a component", line)
         if self.cur.text == "*":
             raise KernelLanguageError(
                 "a __local array indexed through a pointer is not supported; "
@@ -652,6 +816,13 @@ class _Parser:
         line = self.cur.line
         lhs = self.parse_unary_postfixless()
         t = self.cur.text
+        if t in _ASSIGN_OPS or t in ("++", "--"):
+            if isinstance(lhs, Member):
+                raise refused(
+                    "vector-member-store", "one component of an element in "
+                    "memory cannot be written alone; read the vector, set "
+                    "the component, store the vector (v = p[i]; v.x = ..; "
+                    "p[i] = v;)", line)
         if t in _ASSIGN_OPS:
             self.advance()
             value = self.parse_expr()
@@ -693,7 +864,7 @@ class _Parser:
         self.expect("(")
         init = None
         if not self.accept(";"):
-            if self.at_type() or self.cur.text == "const":
+            if self.at_decl():
                 init = self.parse_decl()  # consumes ';'
             else:
                 init = self.parse_expr_statement()
@@ -784,11 +955,22 @@ class _Parser:
             raise KernelLanguageError(
                 "prefix ++/-- in expressions is not supported; use a statement", line=t.line
             )
-        if t.text == "(" and self.peek().kind == "kw" and self.peek().text in _TYPE_KWS:
+        if t.text == "(" and self.at_type(self.peek()) and (
+                self.peek().kind == "kw" or self.peek(2).text == ")"):
             # cast
             self.advance()
             ctype = self.parse_type()
             self.expect(")")
+            if ctype in VECTOR_TYPES:
+                # ``(float4)(a, b, c, d)`` / ``(float4)(s)``: a literal
+                if self.cur.text != "(":
+                    raise refused(
+                        "vector-conversion", f"({ctype}) of a value is not "
+                        f"supported; write the literal ({ctype})(a, b, ..)",
+                        t.line)
+                self.advance()
+                return self._postfix_of(
+                    self._vec_lit(ctype, self._scalars_until(")"), t.line))
             return Cast(ctype=ctype, operand=self.parse_unary(), line=t.line)
         return self.parse_postfix()
 
@@ -797,7 +979,9 @@ class _Parser:
         return self.parse_unary()
 
     def parse_postfix(self):
-        expr = self.parse_primary()
+        return self._postfix_of(self.parse_primary())
+
+    def _postfix_of(self, expr):
         while True:
             t = self.cur
             if t.text == "[":
@@ -814,9 +998,27 @@ class _Parser:
                 # leave for parse_expr_statement by stopping here
                 break
             elif t.text == ".":
-                raise KernelLanguageError(
-                    "struct/vector member access is not supported", line=t.line
-                )
+                self.advance()
+                name = self.advance()
+                if name.kind != "id":
+                    raise self.err(f"expected a member name, found {name.text!r}",
+                                   name.line)
+                if isinstance(expr, Var):
+                    # a local's component is element k of its N scalars
+                    if expr.name not in self._vectors:
+                        raise refused(
+                            "vector-member", f"{expr.name!r} is no vector "
+                            "local (structs are not supported)", t.line)
+                    width = VECTOR_TYPES[self._vectors[expr.name]][1]
+                    k = component_of(name.text, width, name.line)
+                    expr = Index(base=expr.name,
+                                 index=Num(value=k, ctype="int", line=t.line),
+                                 line=t.line)
+                else:
+                    # the width is the expression's: known where it is lowered
+                    expr = Member(operand=expr,
+                                  comp=component_of(name.text, 16, name.line),
+                                  line=t.line)
             else:
                 break
         return expr

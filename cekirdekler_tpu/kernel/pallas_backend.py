@@ -291,28 +291,7 @@ def _mentions_half(kernel: lang.KernelDef) -> bool:
     at compile time, PAST the registry's build-time fallback window, so
     half-typed kernels must be vetoed here even when no caller ARRAY is
     f16 (a half local or cast creates f16 tiles internally)."""
-    seen: set[int] = set()
-
-    def walk(node) -> bool:
-        if node is None or id(node) in seen:
-            return False
-        if isinstance(node, (str, int, float, bool)):
-            return False
-        seen.add(id(node))
-        if isinstance(node, (list, tuple)):
-            return any(walk(x) for x in node)
-        if isinstance(node, dict):
-            return any(walk(x) for x in node.values())
-        ct = getattr(node, "ctype", None)
-        if isinstance(ct, str) and ct == "half":
-            return True
-        if hasattr(node, "__dict__"):
-            return any(walk(v) for v in vars(node).values())
-        return False
-
-    return walk(kernel.params) or walk(kernel.body) or walk(
-        getattr(kernel, "helpers", None)
-    )
+    return lang.any_node(kernel, lambda node: getattr(node, "ctype", None) == "half")
 
 
 def _routing_veto(acc: _Accesses) -> None:
@@ -485,6 +464,12 @@ def build_kernel_fn_pallas(
         raise PallasUnsupported(
             "local-memory: the work items of a group cooperate (__local array "
             "or barrier); the vectorized lowering runs it")
+    if lang.uses_vectors(kernel):
+        # a tile is (rows, 128) work items of ONE scalar each: a vector's
+        # planes and its N-element accesses are the XLA half's (vectors.py)
+        raise PallasUnsupported(
+            "vector-types: the kernel names a vector type (float4 ..); the "
+            "vectorized lowering runs it")
     if chunk % LANES != 0:
         raise PallasUnsupported(f"chunk {chunk} not a multiple of {LANES}")
     if not interpret and _mentions_half(kernel):

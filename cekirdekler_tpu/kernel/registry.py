@@ -509,6 +509,12 @@ def lowering_meta(infos) -> dict:
     build made a masked loop compactable (``codegen._exec_compacted``): the
     loops, the lanes of a chunk, and the reads and stores at the lane's own
     element that a chunk lowers as gathers and scatters.
+    ``vector`` (``params:2;width:4;loads:1;gathers:1;stores:1``), where a
+    kernel has ``__global floatN*`` parameters (kernel/vectors.py): how many,
+    their ``N`` and the accesses of them that were BUILT, one an access
+    whatever its width: loads by slice, strided window or uniform element,
+    loads by per-lane gather (one row fetch a vector), stores (``access``
+    counts each ONCE too, in the form it took).
     ``local`` (``arrays:1;bytes:1024;barriers:2;sites:shift:6,uniform:1,row:0``),
     where a kernel's work items cooperate inside their group: its ``__local``
     arrays, the bytes of them one work-group holds, its barrier statements,
@@ -579,6 +585,18 @@ def lowering_meta(infos) -> dict:
         widths = [w for name in sorted(scattered) for w in scattered[name]]
         meta["scatter"] = (f"stores:{len(widths)};"
                            f"width:{'+'.join(str(w) for w in widths)}")
+    # vector parameters and the accesses of them that were built, summed
+    # over the kernels as ``access`` is (of a kernel's rungs the most)
+    wide: dict = {}
+    for i in leaves:
+        if i.vector:
+            wide[i.name] = max(wide.get(i.name, ()), i.vector)
+    if wide:
+        params, widths, loads, gathers, stores = zip(*wide.values())
+        meta["vector"] = (
+            f"params:{sum(params)};"
+            f"width:{'+'.join(str(w) for w in sorted({w for ws in widths for w in ws}))};"
+            f"loads:{sum(loads)};gathers:{sum(gathers)};stores:{sum(stores)}")
     # the loops made compactable: of a kernel's rungs the most (a rung no
     # wider than a chunk compacts nothing), summed over the kernels
     compact: dict = {}
@@ -664,6 +682,7 @@ class KernelProgram:
         self.kept_views = _KeptViews()
         self._frozen: dict[tuple, frozenset] = {}
         self._cooperates: dict[str, bool] = {}
+        self._vector_widths: dict[str, tuple] = {}
         # partition-safety/flag-soundness verification (analysis/):
         # access summaries build once per kernel on first verify();
         # launch verdicts cache per (names, flag rows, window).  Both
@@ -753,6 +772,19 @@ class KernelProgram:
         if name in self._c_kernels:
             return [p.name for p in self._c_kernels[name].params if not p.is_pointer]
         return list(self._py_kernels[name].value_params)
+
+    def vector_widths(self, name: str) -> tuple:
+        """``N`` of every ``__global floatN*`` parameter of kernel ``name`` by
+        its position among the array parameters (0 for a scalar pointer);
+        ``()`` for a kernel with none (docs/KERNEL_LANGUAGE.md, *Vector
+        types*)."""
+        hit = self._vector_widths.get(name)
+        if hit is None:
+            kdef = self._c_kernels.get(name)
+            hit = tuple((lang.vector_of(p.ctype) or (None, 0))[1]
+                        for p in kdef.params if p.is_pointer) if kdef else ()
+            hit = self._vector_widths[name] = hit if any(hit) else ()
+        return hit
 
     def cooperates(self, name: str) -> bool:
         """Do the work items of kernel ``name`` cooperate inside their group

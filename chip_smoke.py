@@ -58,6 +58,8 @@ FULL = {
     "bfs_nodes": 65536,
     # SHOC's Reduction at 2^20 elements (4 MB, between its two smallest classes)
     "reduce_elements": 1 << 20,
+    # SHOC's MD on a 32^3 lattice (its middle class is 36 864 atoms), 128 neighbours
+    "md_side": 32, "md_neighbours": 128,
     # stage 2 — 256 MiB per array: not a cache
     "stream_n": 1 << 26, "stream_tuner_runs": 3,
     # stage 3 — the examples/wave_equation.py stage
@@ -759,6 +761,81 @@ def _group_reduction(devices, sizes) -> dict:
         cr.dispose()
 
 
+def _vector_kernel(devices, sizes) -> dict:
+    """SHOC's ``compute_lj_force`` (PR 50): ``float4`` positions and forces as
+    vector types of the kernel language, ``elements_per_work_item`` 4.  ONE
+    lane, one synchronous ``compute()`` a frame of positions; every atom's
+    force back in the caller's array equals the configuration's plain
+    reference, ``w`` is the 0 the kernel stores, and the launch's span fields
+    say what was built: one load, one gather (a row fetch a neighbour), one
+    store for the two ``float4`` parameters, no scatter, on the XLA half with
+    the ``vector-types`` veto."""
+    import importlib.util
+
+    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu.core.cruncher import NumberCruncher
+    from cekirdekler_tpu.kernel.registry import lowering_meta
+
+    configs = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs")
+    spec = importlib.util.spec_from_file_location(
+        "shoc_md_ref", os.path.join(configs, "shoc_md_ref.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    with open(os.path.join(configs, "shoc_md.cl")) as f:
+        source = f.read()
+    side, k, lr = sizes["md_side"], sizes["md_neighbours"], 256
+    n = side ** 3
+    cfg = {"atoms": n, "lattice": [side] * 3, "neighbours": k, "cutsq": 16.0,
+           "spacing": 0.4775, "jitter": 0.15, "displacement": 0.01}
+    data, values = ref.inputs(cfg, {"n": n},
+                              np.random.default_rng(sizes["seed"]))
+    plan = ref.call_values(cfg, {"n": n}, values)
+    force = ClArray(data["force3"], name="force3", read=False, write=True,
+                    elements_per_work_item=4)
+    position = ClArray(data["position"], name="position", read=True,
+                       write=False, elements_per_work_item=4)
+    neigh = ClArray(data["neighList"], name="neighList", read_only=True)
+    group = force.next_param(position, neigh)
+    cr = NumberCruncher(devices.subset(1), source)
+    w = cr.cores.workers[0]
+    try:
+        def step(frame: int) -> None:
+            position.host()[:] = data["frames"][frame]
+            group.compute(cr, 7110, "compute_lj_force", n, lr,
+                          values=tuple(plan["cycle"][frame]))
+
+        _, cold_s = _timed(lambda: step(0))
+        neigh.read = False  # the list crossed, and stays
+        _, run_s = _timed(lambda: step(1))  # another frame, another lj pair
+        _k, cutsq, lj1, lj2, _n = plan["cycle"][1]
+        want = ref.forces(data["frames"][1].reshape(n, 4),
+                          data["neighList"].reshape(k, n), np.arange(n),
+                          cutsq, lj1, lj2)
+        got = force.host().reshape(n, 4)
+        err = float(np.abs(got[:, :3] - want).max() / np.abs(want).max())
+        _require(err < 1e-5 and not got[:, 3].any(),
+                 f"md: force error {err} of the largest, "
+                 f"{int((got[:, 3] != 0).sum())} w not 0")
+        info = cr.cores.program.launcher(
+            "compute_lj_force", n, lr, n, platform=w.device.platform)[1]
+        meta = lowering_meta([info])
+        _require(meta.get("vector") == "params:2;width:4;loads:1;gathers:1;"
+                 "stores:1" and ";gather:1;scatter:0;" in meta["access"]
+                 and "scatter" not in meta,
+                 f"md: vector {meta.get('vector')}, access {meta['access']}, "
+                 f"scatter {meta.get('scatter')}")
+        if w.device.platform == "tpu":
+            _require((info.lowering, (info.veto or "")[:12])
+                     == ("xla", "vector-types"),
+                     f"md: lowering {info.lowering}, veto {info.veto}")
+        return _row("vector kernel compute()", meta["lowering"], cold_s, run_s,
+                    err, vector=meta["vector"], access=meta["access"],
+                    loops=meta["loops"], call_ms=round(1e3 * run_s, 3))
+    finally:
+        cr.dispose()
+
+
 def stage_compute(devices, sizes) -> list[dict]:
     from cekirdekler_tpu import ClArray
     from cekirdekler_tpu.core.cruncher import NumberCruncher
@@ -837,6 +914,7 @@ def stage_compute(devices, sizes) -> list[dict]:
     rows.append(_mandelbrot_frame_read_back(devices, sizes, want))
     rows.append(_bfs_traversal(devices, sizes))
     rows.append(_group_reduction(devices, sizes))
+    rows.append(_vector_kernel(devices, sizes))
     return rows
 
 

@@ -113,29 +113,6 @@ def cpu_devices():
 
 _HERE = pathlib.Path(__file__).resolve()
 _CHECKS = _HERE.parent.parent / "benchmark" / "checks"
-# node id -> reason: failures that can only be mended under benchmark/
-_KNOWN_FAILURES = {
-    "benchmark/checks/test_ladder_dispatches.py"
-    "::test_the_metric_is_listed_with_its_reader":
-        "pins the manifest's LAST per_layer entry: it fails since PR 26 "
-        "appended six and needs a benchmark PR to relax (PERF.md s.7)",
-    "benchmark/checks/test_wave_cell.py"
-    "::test_the_cell_and_its_entries_are_in_the_manifest_by_name":
-        "holds the wave cell's per-layer list with ==: it fails since PR 35 "
-        "appended five behind it; the same kind of pin as test_spmv_cell.py's, "
-        "test_mvt_cell.py's and test_ladder_dispatches.py's, which a benchmark "
-        "PR relaxes together (checks/test_window_edge.py holds what it held, "
-        "by name)",
-    "benchmark/checks/test_reduction_cell.py"
-    "::test_the_configuration_the_cell_and_its_metrics_are_in_the_manifest":
-        "holds the reduction cell's per-layer list with ==: that one line "
-        "fails since PR 46 appended reduce_gathered_accesses and "
-        "group_slice_accesses behind it, as ISSUE 46 asked; the same kind of "
-        "pin, relaxed by the same benchmark PR.  Every other assertion of it "
-        "(the configuration, the sizes, the bound, the kernel's text, the "
-        "reference) stands, copied, in checks/test_group_slice_readers.py::"
-        "test_the_cell_is_what_its_pinned_check_held_it_to",
-}
 
 
 class _BenchmarkChecks(pytest.Collector):
@@ -149,10 +126,3 @@ def pytest_collect_file(file_path, parent):
     if file_path == _HERE:
         return _BenchmarkChecks.from_parent(parent, name="benchmark/checks")
     return None
-
-
-def pytest_collection_modifyitems(items):
-    for item in items:
-        reason = _KNOWN_FAILURES.get(item.nodeid)
-        if reason:
-            item.add_marker(pytest.mark.xfail(reason=reason, strict=False))

@@ -8,6 +8,11 @@ bug, because per-item sequential execution IS the language's definition
 (each kernel invocation describes one work item; cross-item hazards are
 excluded by the test generators, as OpenCL leaves them undefined anyway).
 
+Vector types (docs/KERNEL_LANGUAGE.md, *Vector types*): a vector local is a
+numpy array of its N components in the work item's private arrays (``v.x`` is
+the parser's ``v[0]``), a whole value an array of N, and ``p[e]`` of a
+``__global floatN*`` parameter elements ``[N e, N e + N)`` of the flat array.
+
 Matches the lowerings' documented edge choices: C truncating integer
 division/remainder, clamped out-of-bounds loads, clamped private-array
 indices, f32 arithmetic for float locals.
@@ -35,13 +40,16 @@ from cekirdekler_tpu.kernel.lang import (
     Index,
     KernelDef,
     LocalDecl,
+    Member,
     Num,
     Return,
     ReturnValue,
     Ternary,
     UnOp,
     Var,
+    VecLit,
     While,
+    vector_of,
 )
 
 _NPT = {
@@ -96,6 +104,9 @@ class Oracle:
     def __init__(self, kernel: KernelDef, local_size: int = 64):
         self.kernel = kernel
         self.local_size = local_size
+        # ``__global floatN*`` parameters: name -> N
+        self.widths = {p.name: vector_of(p.ctype)[1] for p in kernel.params
+                       if p.is_pointer and vector_of(p.ctype)}
 
     def run(self, arrays: dict[str, np.ndarray], values: dict[str, float],
             global_size: int, offset: int = 0) -> None:
@@ -153,7 +164,13 @@ class Oracle:
 
     def _stmt(self, s, state) -> None:
         env, priv, ctypes, arrays, gid, gsize = state
-        if isinstance(s, Decl):
+        if isinstance(s, Decl) and vector_of(s.ctype):
+            elem, n = vector_of(s.ctype)
+            for name, init in s.names:
+                v = self._expr(init, state) if init is not None else 0
+                priv[name] = self._vector(v, elem, n)
+                ctypes[name] = elem
+        elif isinstance(s, Decl):
             for name, init in s.names:
                 if name in s.arrays:
                     priv[name] = np.zeros(s.arrays[name], _NPT[s.ctype])
@@ -222,11 +239,22 @@ class Oracle:
 
     def _store(self, target, val, state) -> None:
         env, priv, ctypes, arrays, gid, gsize = state
+        if isinstance(target, Var) and target.name in priv:  # a vector local
+            cur = priv[target.name]
+            priv[target.name] = self._vector(val, ctypes[target.name], cur.shape[0])
+            return
         if isinstance(target, Var):
             env[target.name] = _NPT[ctypes[target.name]](val)
             return
         assert isinstance(target, Index)
         idx = int(self._expr(target.index, state))
+        n = self.widths.get(target.base) if target.base not in priv else None
+        if n:
+            arr = arrays[target.base]
+            if 0 <= idx < arr.shape[0] // n:
+                arr[n * idx:n * idx + n] = self._vector(val, None, n)
+            return
+        assert not isinstance(val, np.ndarray) or val.ndim == 0, "vector to scalar"
         if target.base in priv:
             arr = priv[target.base]
             arr[np.clip(idx, 0, arr.shape[0] - 1)] = val
@@ -241,9 +269,22 @@ class Oracle:
         if isinstance(node, Num):
             return _NPT[node.ctype](node.value)
         if isinstance(node, Var):
+            if node.name in priv and node.name not in env:
+                return priv[node.name].copy()  # a vector local, whole
             return env[node.name]
+        if isinstance(node, VecLit):
+            elem, n = vector_of(node.ctype)
+            args = [self._expr(a, state) for a in node.args]
+            return self._vector(args[0] if len(args) == 1 else args, elem, n)
+        if isinstance(node, Member):
+            return self._expr(node.operand, state)[node.comp]
         if isinstance(node, Index):
             idx = int(self._expr(node.index, state))
+            n = self.widths.get(node.base) if node.base not in priv else None
+            if n:
+                arr = arrays[node.base]
+                at = n * int(np.clip(idx, 0, arr.shape[0] // n - 1))
+                return arr[at:at + n].copy()
             if node.base in priv:
                 arr = priv[node.base]
             else:
@@ -282,7 +323,21 @@ class Oracle:
             return self._call(node, state)
         raise AssertionError(f"oracle: unhandled expr {type(node).__name__}")
 
+    @staticmethod
+    def _vector(v, elem, n):
+        """``v`` (a scalar for all components, ``n`` scalars, or a vector) as
+        an array of ``n`` in the element type (``elem`` None: as it is)."""
+        out = np.asarray(v) if elem is None else np.asarray(v, _NPT[elem])
+        return np.broadcast_to(out, (n,)).copy()
+
     def _binval(self, op, a, b):
+        if isinstance(a, np.ndarray) or isinstance(b, np.ndarray):
+            # componentwise; a scalar operand takes the vector's element type
+            vec = a if isinstance(a, np.ndarray) else b
+            av, bv = (np.broadcast_to(np.asarray(x, vec.dtype), vec.shape)
+                      for x in (a, b))
+            return np.array([self._binval(op, x, y) for x, y in zip(av, bv)],
+                            vec.dtype)
         # promote like the lowering: float wins; ints promote to >= int32
         if isinstance(a, np.floating) or isinstance(b, np.floating):
             fa = np.float32(a) if not isinstance(a, np.float64) and not isinstance(b, np.float64) else np.float64(a)
@@ -340,14 +395,19 @@ class Oracle:
         if name in helpers:
             fdef = helpers[name]
             vals = [self._expr(a, state) for a in node.args]
-            henv = {
-                p.name: _NPT[p.ctype](v) for p, v in zip(fdef.params, vals)
-            }
-            hctypes = {p.name: p.ctype for p in fdef.params}
-            hstate = (henv, {}, hctypes, {}, gid, gsize)  # no buffer access
+            henv, hpriv, hctypes = {}, {}, {}
+            for p, v in zip(fdef.params, vals):
+                wide = vector_of(p.ctype)
+                if wide:
+                    hpriv[p.name], hctypes[p.name] = self._vector(v, *wide), wide[0]
+                else:
+                    henv[p.name], hctypes[p.name] = _NPT[p.ctype](v), p.ctype
+            hstate = (henv, hpriv, hctypes, {}, gid, gsize)  # no buffer access
             self._block(fdef.body[:-1], hstate)
             assert isinstance(fdef.body[-1], ReturnValue)
-            return _NPT[fdef.ret_ctype](self._expr(fdef.body[-1].value, hstate))
+            ret = self._expr(fdef.body[-1].value, hstate)
+            wide = vector_of(fdef.ret_ctype)
+            return self._vector(ret, *wide) if wide else _NPT[fdef.ret_ctype](ret)
         if name.startswith(("native_", "half_")):
             name = name.split("_", 1)[1]
         args = [self._expr(a, state) for a in node.args]

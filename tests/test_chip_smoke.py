@@ -22,6 +22,7 @@ TOY = dict(
     mandel_per_call=3, mandel_window=6, mandel_marker_window=4,
     nbody_n=256, nbody_iters=6, nbody_window=3,
     halo_wh=64, halo_window=6, bfs_nodes=1000, reduce_elements=1 << 17,
+    md_side=8, md_neighbours=16,
     stream_n=1 << 14, stream_tuner_runs=2,
     wave_pushes=6,
     serve_tenants=2, serve_sigs=2, serve_reqs=4,
@@ -49,8 +50,8 @@ def _check_rows(rows, n_min=1):
 
 def test_stage_compute(devs):
     rows = chip_smoke.stage_compute(devs, TOY)
-    _check_rows(rows, 9)
-    kl, hand, forced, nbody, wave, starts, shown, bfs, reduction = rows
+    _check_rows(rows, 10)
+    kl, hand, forced, nbody, wave, starts, shown, bfs, reduction, md = rows
     # CPU lanes take the XLA lowering by policy; the routing assertion
     # itself only binds on TPU lanes
     assert kl["lowering"] == "xla"
@@ -97,11 +98,17 @@ def test_stage_compute(devs):
     assert reduction["access"] == ("slice:0;strided:0;uniform:0;gather:0;"
                                    "scatter:1;carried:0;local:7;group:2;"
                                    "settled:2")
+    # SHOC's MD: float4 parameters, one access a vector, no scatter (ISSUE 50)
+    assert md["name"] == "vector kernel compute()" and md["lowering"] == "xla"
+    assert md["max_err"] < 1e-5
+    assert md["vector"] == "params:2;width:4;loads:1;gathers:1;stores:1"
+    assert md["access"] == ("slice:3;strided:0;uniform:0;gather:1;scatter:0;"
+                            "carried:0")
 
 
 def test_stage_compute_partitions_a_single_device():
     rows = chip_smoke.stage_compute(platforms().cpus().subset(1), TOY)
-    assert rows[-5]["lanes"] == 2 and all(r > 0 for r in rows[-5]["ranges"])
+    assert rows[-6]["lanes"] == 2 and all(r > 0 for r in rows[-6]["ranges"])
 
 
 def test_stage_transfers(devs):
