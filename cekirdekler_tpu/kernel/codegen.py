@@ -282,6 +282,11 @@ class _Ctx:
         self.group_walks: dict[int, list] = {}
         self.settled: dict[int, tuple] = {}
         self.settled_sites: set[int] = set()
+        # the masked loops whose common passes may run with no mask, by the
+        # loop's id (_common_walks; adopt() takes them), and those that were
+        # built so (_exec_masked)
+        self.peels: dict[int, "_Peel"] = {}
+        self.peeled: set[int] = set()
         self.local_access: dict[tuple[int, bool], str] = {}
         self.cooperative = False  # the kernel has a __local array or a barrier
         # per-innermost-loop masks: lanes that executed `break` (persist
@@ -341,9 +346,10 @@ class _Ctx:
         self.compact_loops = 0
 
     def adopt(self, kernel: KernelDef, uniform_vars: set[str],
-              coop: "_Coop | None" = None) -> None:
+              coop: "_Coop | None" = None, peels: dict | None = None) -> None:
         """Take what a build knows of ``kernel`` before its body runs."""
         self.uniform_vars = uniform_vars
+        self.peels = peels or {}
         if coop is not None:
             self.group_uniform, self.tid_vars = coop.group_uniform, coop.tid_vars
             self.group_sites = coop.group_sites
@@ -2290,6 +2296,10 @@ def _loop_counted(ctx: _Ctx, node) -> bool:
         node, ctx.uniform_vars, ctx.lane_arrays())
 
 
+# a comparison with its sides exchanged
+_FLIP = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}
+
+
 class _Trips(NamedTuple):
     """What :func:`_trip_count` reads off a loop's syntax."""
 
@@ -2319,11 +2329,10 @@ def _trip_count(ctx: _Ctx, node) -> Optional[_Trips]:
     if not isinstance(step.target, Var) or not isinstance(cond, BinOp):
         return None
     j, body = step.target.name, node.body + [step]
-    flip = {"<": ">", "<=": ">=", ">": "<", ">=": "<="}
     if isinstance(cond.left, Var) and cond.left.name == j:
         op, bound = cond.op, cond.right
     elif isinstance(cond.right, Var) and cond.right.name == j:
-        op, bound = flip.get(cond.op), cond.left
+        op, bound = _FLIP.get(cond.op), cond.left
     else:
         return None
     if op not in (("<", "<=") if c > 0 else (">", ">=")):
@@ -2623,6 +2632,31 @@ def _loop_views(ctx: _Ctx, node, cond_expr, body_core: list,
     return run_var, run_tables
 
 
+def _common_passes(ctx: _Ctx, peel: "_Peel"):
+    """The passes EVERY lane of a masked loop makes (:func:`_common_walks`
+    names the loop), as a 0-d unsigned integer, from the walker's values where
+    the loop is entered: as many as the lane that starts LAST, every other
+    lane at least as many, and no lane's walker passes the bound inside them,
+    so none wraps there.  ONE reduction over the lanes a launch.  The
+    difference is taken modulo the width and read without a sign, which is
+    exact for any two values the first of which is the larger.  None where
+    the condition does not compare integers of the walker's width."""
+    w, e = ctx.env[peel.walker], _eval(ctx, peel.bound)
+    if w.ctype not in _WIDE_INTS or e.ctype not in _INT_TYPES:
+        return None
+    t = _promote(w.ctype, e.ctype)
+    dt = ctype_to_dtype(t)
+    if dt.itemsize != ctype_to_dtype(w.ctype).itemsize:
+        return None
+    top = jnp.max(jnp.asarray(_num(_as_dtype(w, t))))
+    end = _lane0(_num(_as_dtype(e, t)))
+    u = jnp.dtype(f"uint{8 * dt.itemsize}").type
+    gap = lax.bitcast_convert_type(end - top, u)
+    enters = top < end if peel.strict else top <= end
+    return jnp.where(
+        enters, lax.div(gap - u(peel.strict), u(peel.step)) + u(1), u(0))
+
+
 def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
                  carried_vars: list, carried_bufs: list) -> None:
     """A loop that lanes may leave on different passes: a vectorized
@@ -2715,7 +2749,9 @@ def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
             for k in carried_bufs:
                 ctx.bufs[k] = buf_vals[k]
             ctx._pad_cache.clear()  # buffers swapped to loop tracers
-            active = jnp.logical_and(prev, eval_cond(env_vals, buf_vals))
+            # (a common pass carries no mask: every lane is sure to make it)
+            active = None if prev is None else jnp.logical_and(
+                prev, eval_cond(env_vals, buf_vals))
             ctx.mask, ctx.umask = active, None  # ``active`` holds both parts
             ctx.counted = False
             ctx.return_mask = None
@@ -2757,27 +2793,63 @@ def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
     carry0 = (to_carry_mask(prev0), init_env, init_bufs)
     walks = ctx.group_walks.get(id(node)) if run_var is None else None
     ok, sites = _settle(ctx, walks, jnp.logical_and(
-        prev0, eval_cond(init_env, init_bufs))) if walks else (None, [])
+        prev0, eval_cond(init_env, init_bufs))) if walks else (True, [])
+    blocks = tuple(s.block for s in sites)
+
+    def inside(go, blocks):
+        """``go``, with every settled slice inside its buffer."""
+        go = jnp.logical_and(ok, go)
+        for s, block in zip(sites, blocks):
+            go = go & (block >= 0) & (block <= s.last)
+        return go
+
+    def settled_pass(carry, blocks):
+        slices = {s.site: (block, s.row) for s, block in zip(sites, blocks)}
+        return (body_fun(carry, None, slices),
+                tuple(block + jnp.int32(s.step) for s, block in zip(sites, blocks)))
+
+    peel = ctx.peels.get(id(node)) if run_var is None and outer_mask is None else None
+    common = peel and _common_passes(ctx, peel)
+    if common is not None:
+        # THE COMMON PASSES: a loop entered by every lane, whose condition
+        # compares a walker the build has followed with a bound they share,
+        # makes ``common`` passes in EVERY lane, one scalar known here.  Those
+        # run on a counter with no mask: no condition in the lanes, no ``any``,
+        # no merge of an assignment or a store (with the group windows settled
+        # they keep that loop's conditions, which are scalars too).  What is
+        # left runs the loops below from the carry they leave: a pass that
+        # some lanes make, the rotated loop's last.  The walker does not ride
+        # them: it is where it started plus the passes made times the step
+        ctx.peeled.add(id(node))
+        noted = len(ctx.scattered)  # (the masked trace notes its stores again)
+        w0 = init_env[peel.walker]
+
+        def walked(env, k):
+            """``env`` with the walker where ``k`` passes leave it."""
+            by = lax.bitcast_convert_type(k * common.dtype.type(peel.step), w0.dtype)
+            return {**env, peel.walker: w0 + by}
+
+        def common_pass(c):
+            k, env, bufs, blocks = c
+            (_, env, bufs), blocks = settled_pass(
+                (None, walked(env, k), bufs), blocks)
+            del env[peel.walker]
+            return k + 1, env, bufs, blocks
+
+        k, env_c, bufs_c, blocks = lax.while_loop(
+            lambda c: inside(c[0] < common, c[3]), common_pass,
+            (jnp.zeros((), common.dtype),
+             {v: init_env[v] for v in carried_vars if v != peel.walker},
+             init_bufs, blocks))
+        del ctx.scattered[noted:]
+        carry0 = (carry0[0], walked(env_c, k), bufs_c)
     if sites:
         # GROUP WINDOWS SETTLED ONCE: the passes whose slices lie inside the
         # buffers run with no check in them; what is left (a tail over the
         # end, a first pass that does not fit) runs the loop below
         ctx.settled_sites.update(s.site for s in sites)
-
-        def inside(c):
-            go = jnp.logical_and(ok, cond_fun(c[0]))
-            for s, block in zip(sites, c[1]):
-                go = go & (block >= 0) & (block <= s.last)
-            return go
-
-        def settled_pass(c):
-            slices = {s.site: (block, s.row) for s, block in zip(sites, c[1])}
-            return (body_fun(c[0], None, slices),
-                    tuple(block + jnp.int32(s.step)
-                          for s, block in zip(sites, c[1])))
-
-        blocks = tuple(s.block for s in sites)
-        carry0 = lax.while_loop(inside, settled_pass, (carry0, blocks))[0]
+        carry0 = lax.while_loop(lambda c: inside(cond_fun(c[0]), c[1]),
+                                lambda c: settled_pass(*c), (carry0, blocks))[0]
     if run_var is None:
         active_f, env_f, bufs_f = lax.while_loop(cond_fun, body_fun, carry0)
     else:
@@ -3507,6 +3579,20 @@ def _build_locals(body: list, sizes: dict) -> dict:
         known.update(found)
 
 
+def _moves(s, name: str, known: dict) -> Optional[int]:
+    """What statement ``s`` of a loop's body adds to the local ``name``: 0
+    where it assigns it nowhere, None where not by a build-time integer
+    (``known``: :func:`_build_locals`)."""
+    mine = (isinstance(s, (Assign, CrementStmt))
+            and isinstance(s.target, Var) and s.target.name == name)
+    if mine and isinstance(s, CrementStmt):
+        return 1 if s.op == "++" else -1
+    if mine and s.op in ("+=", "-="):
+        by = _build_int(s.value, known)
+        return None if by is None else by if s.op == "+=" else -by
+    return None if name in _assigned_vars([s]) else 0
+
+
 class _Walk(NamedTuple):
     """A group read whose walker the build has followed through its loop
     (:func:`_settled_walks`)."""
@@ -3548,18 +3634,6 @@ def _settled_walks(body: list, sites: dict, sizes: dict) -> dict:
                 found += _index_nodes(s)
         return found
 
-    def moves(s, name: str) -> Optional[int]:
-        """What statement ``s`` of the loop's body adds to ``name``: 0 where
-        it assigns it nowhere, None where not by a build-time integer."""
-        mine = (isinstance(s, (Assign, CrementStmt))
-                and isinstance(s.target, Var) and s.target.name == name)
-        if mine and isinstance(s, CrementStmt):
-            return 1 if s.op == "++" else -1
-        if mine and s.op in ("+=", "-="):
-            by = _build_int(s.value, known)
-            return None if by is None else by if s.op == "+=" else -by
-        return None if name in _assigned_vars([s]) else 0
-
     for loop in _walk(body):
         if (not isinstance(loop, (For, While))
                 or _has_exit(loop.body, Continue)):
@@ -3579,13 +3653,71 @@ def _settled_walks(body: list, sites: dict, sizes: dict) -> dict:
                     (sign, leaf), *more = moving
                     if more or sign != 1 or not isinstance(leaf, Var):
                         continue
-                    by = [moves(s, leaf.name) for s in pass_stmts]
+                    by = [_moves(s, leaf.name, known) for s in pass_stmts]
                     if None in by:
                         continue
                 step = sum(by)
                 if step % pitch == 0 and abs(step) < 1 << 31:
                     out.setdefault(id(loop), []).append(
                         _Walk(ix, step, sum(by[:k])))
+    return out
+
+
+class _Peel(NamedTuple):
+    """A masked loop whose common passes can be counted before the first
+    (:func:`_common_walks`)."""
+
+    walker: str   # the local its condition compares
+    bound: Any    # the expression it is compared with
+    strict: bool  # ``walker < bound``; else ``<=``
+    step: int     # what a pass adds to the walker: positive
+
+
+def _common_walks(body: list, uset: set[str], private, sizes: dict) -> dict:
+    """``{id of a loop: _Peel}``: the ``for`` / ``while`` loops whose lanes,
+    where they leave on different passes, still make a number of passes
+    TOGETHER that one scalar gives before the first (:func:`_common_passes`;
+    :func:`_exec_masked` runs those with no mask).  From the syntax alone:
+
+    - the condition is ONE comparison ``w < e`` or ``w <= e`` (or the same
+      written ``e > w``, ``e >= w``) of a local ``w`` with an expression;
+    - ``w`` is moved as :func:`_settled_walks` demands of a walker: every
+      assignment a pass makes to it is a statement of the body itself or the
+      ``for``'s step, ``+=`` / ``-=`` / ``++`` / ``--`` by build-time integers
+      (:func:`_moves`; no float literal among them: ``w += 2.0f`` rounds)
+      whose sum is positive;
+    - ``e`` is the same in every lane (:func:`_expr_uniform` by ``uset``) and
+      the loop cannot change it: it names no local the loop assigns and no
+      buffer the loop stores to;
+    - the body holds no ``break`` and no ``continue`` (a ``return`` in a
+      loop is refused where the loop is built).
+
+    A bound that differs by lane, one the body assigns, a walker moved under
+    an ``if`` or by a run-time step, a walk downward: not here, such a loop is
+    masked from its first pass."""
+    known = _build_locals(body, sizes)
+    out: dict = {}
+    for loop in _walk(body):
+        if (not isinstance(loop, (For, While)) or not isinstance(loop.cond, BinOp)
+                or _has_exit(loop.body, (Break, Continue))):
+            continue
+        pass_stmts = loop.body + ([loop.step] if getattr(loop, "step", None) else [])
+        changed = _assigned_vars(pass_stmts) | _stored_bufs(pass_stmts)
+        c = loop.cond
+        for w, e, op in ((c.left, c.right, c.op),
+                         (c.right, c.left, _FLIP.get(c.op))):
+            if op not in ("<", "<=") or not isinstance(w, Var):
+                continue
+            by = [_moves(s, w.name, known) for s in pass_stmts]
+            moving = [s for s, d in zip(pass_stmts, by) if d]
+            if (None in by or not 0 < sum(by) < 1 << 31
+                    or any(isinstance(x, Num) and x.ctype not in _INT_TYPES
+                           for x in _walk(moving))
+                    or not _expr_uniform(e, uset, private)
+                    or _vars_read(e) & changed):
+                continue
+            out[id(loop)] = _Peel(w.name, e, op == "<", sum(by))
+            break
     return out
 
 
@@ -3833,6 +3965,9 @@ class KernelBuildInfo:
     # per-lane active mask
     loops_counted: int = 0
     loops_masked: int = 0
+    # of the masked ones, filled at trace: those whose common passes run on a
+    # scalar counter with no mask, ahead of the masked loop (_common_walks)
+    loops_peeled: int = 0
     # how the kernel's buffer accesses were lowered, counted over its
     # access sites by the walk that lowers them (filled at trace): loads
     # and stores by ``slice`` (contiguous), loads by ``strided`` window or
@@ -3955,6 +4090,8 @@ def build_kernel_fn(
                      get_num_groups=global_size // local_size)
     # (raises on a barrier a group does not reach)
     coop = _cooperation(kernel, sizes)
+    peels = _common_walks(kernel.body, uniform,
+                          frozenset(_private_array_names(kernel.body)), sizes)
     if coop is not None:
         info.local = (len(coop.arrays), coop.nbytes, coop.barriers)
         if coop.arrays and chunk % local_size:
@@ -3967,7 +4104,7 @@ def build_kernel_fn(
            views: dict | None = None):
         ctx = _Ctx(chunk, jnp.asarray(offset, jnp.int32), global_size, local_size, {},
                    in_range)
-        ctx.adopt(kernel, uniform, coop)
+        ctx.adopt(kernel, uniform, coop, peels)
         ctx.row_gathers = platform == "tpu"
         ctx.readonly = readonly
         ctx.kept = {(info.array_params[p], kind): v
@@ -3999,6 +4136,7 @@ def build_kernel_fn(
         for kind in ctx.access.values():
             info.access[kind] += 1
         info.access["carried"] = len(ctx.carried)
+        info.loops_peeled = len(ctx.peeled)
         if coop is not None:
             info.access["settled"] = len(ctx.settled_sites)
             kinds = list(ctx.local_access.values())

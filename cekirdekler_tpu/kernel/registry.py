@@ -488,8 +488,10 @@ def lowering_meta(infos) -> dict:
     ``lowering`` (``pallas``, ``xla``, ``python``; several joined by ``+``
     where a ladder's rungs differ), ``loops`` (``counted:N;masked:M``: how
     many of the kernel's loops run on a scalar counter and how many under a
-    per-lane mask; joined the same way; no comma, which would end the
-    value in a profiler annotation), where a TPU build was routed
+    per-lane mask, then ``;peeled:P`` where ``P > 0`` of the masked ones run
+    the passes all their lanes make with no mask first
+    (``codegen._common_walks``); joined the same way; no comma, which would
+    end the value in a profiler annotation), where a TPU build was routed
     away from Pallas, ``veto`` with the reason, and where the launch read
     beyond its lane's own range, ``reach`` (``u1:16384``), and for a Pallas
     build ``tile`` (``64x128;grid=4;live=6``: the work items of a grid step's
@@ -528,6 +530,7 @@ def lowering_meta(infos) -> dict:
     meta = {"lowering": "+".join(sorted({i.lowering for i in leaves})),
             "loops": "+".join(sorted(
                 {f"counted:{i.loops_counted};masked:{i.loops_masked}"
+                 + (f";peeled:{i.loops_peeled}" if i.loops_peeled else "")
                  for i in leaves})),
             "views": f"kept:{sum(i.views_kept for i in infos)};"
                      f"built:{sum(i.views_built for i in infos)}"}

@@ -703,7 +703,8 @@ def _group_reduction(devices, sizes) -> dict:
     lowered: two barriers, six shifts, one broadcast, no row fallback, on
     the XLA half with the ``local-memory`` veto; and how the walk reads: both
     reads of ``g_idata`` one window a group, the windows settled once a
-    launch, not a pass (``settled:2``)."""
+    launch, not a pass (``settled:2``), and the passes every lane is sure to
+    make run with no mask (``loops`` ends in ``peeled:1``)."""
     import importlib.util
 
     from cekirdekler_tpu import ClArray
@@ -747,8 +748,10 @@ def _group_reduction(devices, sizes) -> dict:
         _require(meta.get("local") == "arrays:1;bytes:1024;barriers:2;"
                  "sites:shift:6,uniform:1,row:0"
                  and meta["access"].endswith(
-                     ";gather:0;scatter:1;carried:0;local:7;group:2;settled:2"),
-                 f"reduce: local {meta.get('local')}, access {meta['access']}")
+                     ";gather:0;scatter:1;carried:0;local:7;group:2;settled:2")
+                 and meta["loops"] == "counted:1;masked:1;peeled:1",
+                 f"reduce: local {meta.get('local')}, access {meta['access']}, "
+                 f"loops {meta['loops']}")
         if w.device.platform == "tpu":
             _require((info.lowering, (info.veto or "")[:12])
                      == ("xla", "local-memory"),

@@ -361,6 +361,47 @@ def test_both_kernels_compile_for_the_chip_at_the_cells_size(one_chip):
         assert bool(info.compact) == (name == "BFS_1")
 
 
+def test_the_common_passes_of_reduce_compile_to_a_pass_with_no_mask(one_chip):
+    """SHOC's ``reduce`` as its cell launches it (64 groups of 256 over 2^28
+    floats), compiled for a described v5e (here, beside the file's other such
+    compile, so that one worker loads the TPU's compiler): the ``while`` of
+    the passes every lane makes (ISSUE 51) holds the two group slices, at most
+    two fusions, and nothing of a mask: no ``pred[16384]``, no ``reduce``, no
+    ``conditional``, in its body or its condition.  No time is read here."""
+    import os
+    import re
+
+    jnp = jax.numpy
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__))), "benchmark", "configs", "shoc_reduction.cl")) as f:
+        prog = KernelProgram(f.read())
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, info = prog.launcher("reduce", 16384, 256, 16384, platform="tpu")
+    values = (np.uint32(1 << 28),)
+    text = fn.trace(shaped((), jnp.int32), (shaped((1 << 28,), jnp.float32),
+                                             shaped((64,), jnp.float32)),
+                    values, fn.keys_of(values)).lower().compile().as_text()
+    assert info.loops_peeled == 1
+    comps = {m.group(1): m.group(2) for m in re.finditer(
+        r"\n%?([\w.\-]+) \([^\n]*\) -> [^\n]*\{\n(.*?)\n\}", text, re.S)}
+    walks = [(comps[c], comps[b]) for c, b in re.findall(
+        r"condition=%?([\w.\-]+), body=%?([\w.\-]+)", text)
+        if "f32[64,2,128]" in comps[b]]
+    # (the loop that checks its reads pass by pass holds them in the branches
+    # of its ``conditional``s: computations of their own)
+    assert len(walks) >= 2
+    # one of them is the unmasked one; the others carry the mask as they did
+    bare = [w for w in walks if "pred[16384]" not in w[0] + w[1]]
+    assert len(bare) == 1
+    cond, body = bare[0]
+    assert len(re.findall(r" fusion\(", body)) <= 2
+    for part in (cond, body):
+        assert not re.search(r" (reduce|conditional|while)\(", part)
+
+
 UNIFORM = """
 __kernel void raise(__global int* a, __global char* flag, __global int* at,
                     int lim, int where) {

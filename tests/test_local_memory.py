@@ -501,9 +501,11 @@ def _eqns(jaxpr, name: str) -> list:
 
 def test_the_passes_reduce_takes_in_its_cell_hold_no_cond():
     """SHOC's launcher as the cell builds it (64 groups of 256 over 2^26
-    elements, by shape alone): the walk is two ``while``s, the first with the
-    two one-slice reads and no ``cond`` in its body or its condition, the
-    second the loop as it was (two ``cond``s a pass)."""
+    elements, by shape alone): behind the common passes (ISSUE 51:
+    tests/test_peeled_loops.py has that ``while``) the walk is two
+    ``while``s, the first with the two one-slice reads and no ``cond`` in its
+    body or its condition, the second the loop as it was (two ``cond``s a
+    pass)."""
     fn, info = KernelProgram(REDUCE).launcher("reduce", 16384, 256, 16384,
                                               platform="tpu")
     jaxpr = fn.trace(0, (jax.ShapeDtypeStruct((1 << 26,), jnp.float32),
@@ -513,7 +515,8 @@ def test_the_passes_reduce_takes_in_its_cell_hold_no_cond():
              and _eqns(e.params["body_jaxpr"].jaxpr, "dynamic_slice")
              and any(v.aval.shape == (64, 2, 128) for s in _eqns(
                  e.params["body_jaxpr"].jaxpr, "dynamic_slice") for v in s.outvars)]
-    assert len(walks) == 2 and info.access["settled"] == 2
+    assert len(walks) == 3 and info.access["settled"] == 2
+    walks = walks[1:]
     settled, checked = (e.params["body_jaxpr"].jaxpr for e in walks)
     assert not _eqns(settled, "cond") and not _eqns(settled, "gather")
     assert not _eqns(walks[0].params["cond_jaxpr"].jaxpr, "cond")
@@ -682,6 +685,7 @@ def test_the_launch_and_compile_spans_carry_the_local_field(devs, monkeypatch):
         TRACER.disable()
     assert {kind for kind, _meta in seen} == {"launch", "compile"}
     for _kind, meta in seen:
+        assert meta["loops"] == "counted:1;masked:1;peeled:1"
         assert meta["local"] == ("arrays:1;bytes:1024;barriers:2;"
                                  "sites:shift:6,uniform:1,row:0")
         assert meta["access"] == ("slice:0;strided:0;uniform:0;gather:0;"
@@ -755,8 +759,10 @@ def test_reduce_checked_pass_by_pass_is_the_launcher_it_was(monkeypatch):
     """What is left of a walk once its settled passes are over runs "the loop
     as PR 46 built it": with the settling switched off the launcher of SHOC's
     ``reduce`` is that commit's (b9ce6ad) to the last operation, by ``_hlo``
-    there; with it on it is another program."""
+    there; with it on it is another program.  (Both without the common
+    passes, which that commit did not peel: ISSUE 51.)"""
     arrays = (jax.ShapeDtypeStruct((4096,), jnp.float32),) * 2
+    monkeypatch.setattr(codegen, "_common_walks", lambda *a: {})
     sha, info = _hlo("shoc_reduction.cl", "reduce", arrays, (np.uint32(4000),))
     assert sha != REDUCE_PER_PASS_SHA and info.access["settled"] == 2
     monkeypatch.setattr(codegen, "_settled_walks", lambda *a: {})

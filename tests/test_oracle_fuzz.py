@@ -334,6 +334,76 @@ def test_oracle_random_settled_walks(seed, monkeypatch):
         assert out.tobytes() == want.tobytes(), (switched_off, src)
 
 
+@pytest.mark.parametrize("seed", range(16))
+def test_oracle_random_common_passes(seed, monkeypatch):
+    """Randomized loops that lanes leave on different passes after a number
+    they make together (ISSUE 51): the walker's and the bound's types, where
+    the walk starts (below 0 too), the comparison and the side the bound
+    stands on, the step (a literal, the ranges', a once-assigned local's,
+    two moves a pass, ``++``), a bound of one term or several; three seeds in
+    four with nothing in the way, the others with what must keep the loop
+    masked from its first pass (a bound by lane, a walker moved under an
+    ``if``, a ``break``, a per-lane ``if`` around the loop, a run-time step,
+    a walk downward).  Three ways, byte for byte: the oracle, the build
+    (which counts the common passes exactly where the form allows), the
+    build with the analysis switched off."""
+    rng = np.random.default_rng(5100 + seed)
+    wt = str(rng.choice(["int", "int", "unsigned int", "long"]))
+    nt = wt if wt != "int" else str(rng.choice(["int", "int", "unsigned int"]))
+    # (no walker below 0 against an ``unsigned int``: C, and both builds,
+    # compare it as a large number, the oracle as the two numbers)
+    c = int(rng.integers(0, 200) if "unsigned" in (wt + nt)
+            else rng.integers(-300, 200))
+    step, move = [("64", "i += 64;"), ("get_global_size(0)", "i += get_global_size(0);"),
+                  ("stride", "i += stride;"), ("100 - 36", "i += 100; MID i -= 36;"),
+                  ("1", "i++;")][int(rng.integers(0, 5))]
+    bound = str(rng.choice(["n", "n", "2 * n - c", "n + get_local_size(0)"]))
+    op = str(rng.choice(["<", "<", "<="]))
+    cond = f"i {op} {bound}" if rng.integers(0, 3) else (
+        f"{bound} {'>' if op == '<' else '>='} i")
+    read = "acc += x[i - c + 1] + 1.0f;"
+    body = (move.replace("MID", read) if "MID" in move else f"{read} {move}")
+    loop = f"while ({cond}) {{ {body} }}"
+    kept = seed % 4 == 3
+    if kept:
+        loop = [
+            f"while (i {op} {bound} + gid % 3) {{ {body} }}",
+            f"while ({cond}) {{ {read} if (gid % 2) {{ i += 64; }} else {{ i += 32; }} }}",
+            f"while ({cond}) {{ if (acc > 3.0f + gid % 2) {{ break; }} {body} }}",
+            f"if (gid % 3 != 1) {{ {loop} }}",
+            f"while ({cond}) {{ {read} i += c + 301; }}",
+            f"i += 900; while (i > {bound}) {{ {read} i -= 64; }}",
+        ][int(rng.integers(0, 6))]
+    src = f"""
+    __kernel void k(__global const float* x, __global float* y, {nt} n, {wt} c) {{
+        int gid = get_global_id(0);
+        const int stride = get_global_size(0) / 2 + 8;
+        {wt} i = gid + c;
+        float acc = 0.0f;
+        {loop}
+        y[gid] = acc + 0.5f * i;
+    }}"""
+    np_of = {"int": np.int32, "unsigned int": np.uint32, "long": np.int64}
+    n = int(rng.integers(0, 60 if step == "1" else 900))
+    values = {"n": np_of[nt](n), "c": np_of[wt](c)}
+    arrays = {"x": rng.integers(0, 5, 700).astype(np.float32),
+              "y": np.zeros(N, np.float32)}
+    kdef = lang.parse_kernels(src)[0]
+    vals = (values["n"], values["c"])
+    dev = (jnp.asarray(arrays["x"]), jnp.asarray(arrays["y"]))
+    fn, info = codegen.build_kernel_fn(kdef, N, 64, N)
+    out = np.asarray(fn(0, dev, vals)[1])
+    assert info.loops_peeled == (not kept), src
+    with monkeypatch.context() as mp:
+        mp.setattr(codegen, "_common_walks", lambda *a: {})
+        off_fn, off_info = codegen.build_kernel_fn(kdef, N, 64, N)
+        want = np.asarray(off_fn(0, dev, vals)[1])
+    assert off_info.loops_peeled == 0
+    assert out.tobytes() == want.tobytes(), src
+    Oracle(kdef).run(arrays, values, N)
+    np.testing.assert_array_equal(out, arrays["y"], err_msg=src)
+
+
 @pytest.mark.parametrize("seed", range(8))
 def test_oracle_random_moved_value_parameters(seed, monkeypatch):
     """A value parameter moved by a group-uniform amount where the lanes of a
