@@ -23,9 +23,12 @@ def compute_path_proof(ndev: int = 8, iters: int = 49) -> dict:
     2. per-chip work accounting at the final split (max work / mean work),
     3. compile-count invariance: distinct jitted launch geometries must
        stop growing after the ladder is warm, across ~48 distinct splits,
-    4. dispatch concurrency: with lane tracing on, every active lane's
-       async dispatch returns before the FIRST lane's readback completes —
-       N chips genuinely in flight together.
+    4. dispatch concurrency: on the path users run (streaming and fused
+       windows as they are), with the tracer's ring on for the last call,
+       every active lane's last dispatch was handed over (its launches'
+       ``part:handed`` instants, trace/spans.py) before the FIRST lane's
+       readback had landed (its last ``part:landed``) — N chips genuinely
+       in flight together.
 
     Bench injection: the rig's 8 virtual devices share ONE host core, so a
     chip's wall time measures scheduler contention, not its work.  On real
@@ -39,6 +42,7 @@ def compute_path_proof(ndev: int = 8, iters: int = 49) -> dict:
     from .arrays.clarray import ClArray
     from .core.cruncher import NumberCruncher
     from .hardware import platforms
+    from .trace import TRACER
     from .workloads import MANDELBROT_SRC, _converged_at, mandelbrot_host
 
     w = h = 512
@@ -66,12 +70,36 @@ def compute_path_proof(ndev: int = 8, iters: int = 49) -> dict:
     # (a few rebalances in), and at the end — invariance = warm == final
     warm_call = min(8, iters - 1)
     checkpoints = {1, warm_call, iters}
+
+    def traced_compute() -> tuple[list, int]:
+        """One compute with the ring on and nothing else in it: ``(lane,
+        handed, landed)`` for each lane, its last ``part:handed`` and its last
+        ``part:landed``, and the lanes whose dispatches were out before the
+        first lane's read-back had landed."""
+        TRACER.enable()  # cleared: this call's marks alone
+        try:
+            out.compute(cr, cid, "mandelbrot", n, local, values=vals)
+        finally:
+            TRACER.disable()
+        handed: dict[int, float] = {}
+        landed: dict[int, float] = {}
+        for s in TRACER.snapshot():
+            if s.tag == "part:handed" and s.cid == cid:
+                handed[s.lane] = max(handed.get(s.lane, 0.0), s.t0)
+            elif s.tag == "part:landed":
+                landed[s.lane] = max(landed.get(s.lane, 0.0), s.t0)
+        tr = [(lane, handed[lane], landed[lane])
+              for lane in sorted(handed) if lane in landed]
+        first_join = min((t for (_, _, t) in tr), default=0.0)
+        return tr, sum(1 for (_, d, _) in tr if d <= first_join)
+
     t0 = _time.perf_counter()
     try:
         for k in range(iters):
             if k == iters - 1:
-                cores.trace_lanes = True
-            out.compute(cr, cid, "mandelbrot", n, local, values=vals)
+                trace, lanes_in_flight = traced_compute()
+            else:
+                out.compute(cr, cid, "mandelbrot", n, local, values=vals)
             ranges = cores.ranges_of(cid)
             traj.append(ranges)
             # deterministic bench injection (see docstring)
@@ -110,11 +138,6 @@ def compute_path_proof(ndev: int = 8, iters: int = 49) -> dict:
         works = [work_in(offs[i], offs[i + 1]) for i in range(ndev)]
         mean_w = sum(works) / ndev
 
-        def lane_concurrency() -> tuple[list, int]:
-            tr = cores.lane_trace.get(cid, [])
-            first_join = min((t for (_, _, t) in tr), default=0.0)
-            return tr, sum(1 for (_, d, _) in tr if d <= first_join)
-
         # the dispatch-concurrency invariant is a TIMING property: on a
         # host with fewer cores than lanes the 8 dispatch threads cannot
         # all be scheduled before the first lane's readback completes —
@@ -131,20 +154,18 @@ def compute_path_proof(ndev: int = 8, iters: int = 49) -> dict:
             host_cpus = _os.cpu_count() or 1
         active_lanes = sum(1 for r in final if r > 0)
         lane_rig_capable = host_cpus >= active_lanes
-        trace, lanes_in_flight = lane_concurrency()
         attempts = 1
         while (
             attempts < 3
             and not (lanes_in_flight == len(trace) == active_lanes)
         ):
-            out.compute(cr, cid, "mandelbrot", n, local, values=vals)
+            tr, lif = traced_compute()
             ranges = cores.ranges_of(cid)
             offs_r = np.concatenate([[0], np.cumsum(ranges)]).astype(int)
             for i, wk in enumerate(cores.workers):
                 if ranges[i] > 0:
                     wk.benchmarks[cid] = work_in(offs_r[i], offs_r[i + 1])
             attempts += 1
-            tr, lif = lane_concurrency()
             if lif > lanes_in_flight:
                 trace, lanes_in_flight = tr, lif
         distinct_splits = len({tuple(r) for r in traj})
@@ -174,7 +195,7 @@ def compute_path_proof(ndev: int = 8, iters: int = 49) -> dict:
             ),
             "lanes_traced": len(trace),
             "lanes_dispatched_before_first_join": lanes_in_flight,
-            "lane_trace_attempts": attempts,
+            "traced_attempts": attempts,
             # capability, not verdict: False means this host has fewer
             # schedulable cores than active lanes, so the all-in-flight
             # timing property is unobservable HERE regardless of the
@@ -204,5 +225,4 @@ def compute_path_proof(ndev: int = 8, iters: int = 49) -> dict:
             "elapsed_sec": round(elapsed, 1),
         }
     finally:
-        cores.trace_lanes = False
         cr.dispose()

@@ -113,8 +113,6 @@ class Settings:
     # Worker.cs:487-557 synchronized start): when set, every worker
     # lane blocks on the event before its compute phase
     dispatch_gate: Any = None
-    # each plain-path lane records (worker index, dispatch-done, join-done)
-    trace_lanes: bool = False
 
 
 class _Shared:
@@ -226,12 +224,6 @@ class Cores:
         # workers pinning the probe buffers), and homogeneous windows are
         # measured exactly either way
         self._fence_split = False
-        # lane tracing (``trace_lanes``): each plain-path lane records
-        # (worker index, dispatch-done, join-done) — when the async XLA
-        # launch returned to the host, when the lane's readbacks
-        # materialized.  All lanes dispatching before the first join
-        # completes is the "N chips in flight concurrently" evidence.
-        self.lane_trace: dict[int, list[tuple[int, float, float]]] = {}
         # lane health scoring (obs/health.py): rolling per-lane baselines
         # over fence walls, transfer walls, and stream-driver stalls,
         # fed at sync points / phase tails (never the deferral hot path);
@@ -259,7 +251,7 @@ class Cores:
             program, self.workers, self.pool, self._lock, owners)
         self._phase = Phases(
             self._settings, program, self._window, self.health,
-            self.lane_trace, single=len(self.workers) == 1)
+            single=len(self.workers) == 1)
         self.transfer_tuner = self._phase.transfer_tuner
         self.last_stream_chunks = self._phase.last_stream_chunks
         self._sync = Sync(
@@ -650,11 +642,6 @@ class Cores:
         job = Job(kernel_names, params, compute_id, local_range,
                   global_range, pipeline, pipeline_blobs, pipeline_type,
                   value_args, write_all_owners(params, active))
-        if s.trace_lanes:
-            # the trace describes ONE call: stale entries from earlier calls
-            # would mix into the first-join comparison and leak memory
-            with self._lock:
-                self.lane_trace.pop(compute_id, None)
         # part marks of the per-call path (trace/spans.py): where the
         # caller cuts the strips, hands out the lanes' phases, waits for
         # them, and notes what they wrote
@@ -925,6 +912,16 @@ class Cores:
             # caller fires it IS the ClUserEvent synchronized-start
             # semantic (reference: Worker.cs:487-557)
             gate.wait()
+        # where the lane's phase begins and where it has its lane (trace/
+        # spans.py, "Part marks"): the caller's ``part:submit`` to
+        # ``phase-start`` is the pool hop (``hop_us``: the closure's own
+        # count of it), ``phase-start`` to ``phase-locked`` the wait for
+        # the lane, and from there to the lane's first upload or launch
+        # ``classify``, the tuner's ``choose`` and ``ensure_resident``
+        _on = TRACER.active()
+        if _on:
+            TRACER.instant("enqueue", cid=job.compute_id, lane=w.index,
+                           tag="phase-start", **TRACER.hop_meta())
         # serialize whole phases per worker: concurrent host threads driving
         # DIFFERENT compute ids through one Cores (the reference's
         # kernelWithId concurrency contract, Worker.cs:291-316) otherwise
@@ -932,6 +929,9 @@ class Cores:
         # dicts.  The bench starts after acquisition so one id's measured
         # time never includes waiting on another id's phase.
         with w.lock:
+            if _on:
+                TRACER.instant("enqueue", cid=job.compute_id, lane=w.index,
+                               tag="phase-locked")
             self._phase.run(w, job, offset, size, plan)
         # from here on a caller still inside its join waits for another lane
         TRACER.instant("enqueue", cid=job.compute_id, lane=w.index,

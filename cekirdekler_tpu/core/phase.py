@@ -170,16 +170,14 @@ def _picked(params, hows, how) -> list:
 
 class Phases:
     """The four engines of one scheduler.  ``window`` takes the
-    deferred-readback records; ``lane_trace`` is the scheduler's (under the
-    scheduler lock)."""
+    deferred-readback records."""
 
     def __init__(self, settings, program: KernelProgram, window: Window,
-                 health: HealthMonitor, lane_trace: dict, single: bool):
+                 health: HealthMonitor, single: bool):
         self.settings = settings
         self.program = program
         self.window = window
         self.health = health
-        self.lane_trace = lane_trace
         self.single = single
         self.transfer_tuner = TransferTuner()
         # per-lane chunk count of the last streamed phase (the autotuner's
@@ -219,7 +217,7 @@ class Phases:
             step = job.local_range
             if (plan is None and s.streamed_transfers
                     and not s.no_compute_mode and s.repeat_count <= 1
-                    and not s.repeat_sync_kernel and not s.trace_lanes
+                    and not s.repeat_sync_kernel
                     and step > 0 and size // step >= 2):
                 moves = self._classify(w, job, offset, size, cut=True)
                 # else nothing to overlap — the monolithic path is exact
@@ -344,18 +342,12 @@ class Phases:
                              plan.reach if plan is not None else "")
             if measuring:
                 w.fence()
-        t_dispatched = time.perf_counter() if s.trace_lanes else 0.0
         t_down = self._read_back(w, job, offset, size, moves, [], cut=False)
         self._note_transfer(
             w, tuner_key, job.compute_id, key_bytes or 0, t_up, t_down,
             time.perf_counter() - t_phase0, fenced=measuring,
             u_tune_s=t_up_stream,
         )
-        if s.trace_lanes:
-            with self.window.lock:
-                self.lane_trace.setdefault(job.compute_id, []).append(
-                    (w.index, t_dispatched, time.perf_counter())
-                )
 
     def _note_transfer(
         self, w: Worker, tuner_key, compute_id: int, nbytes: int,

@@ -157,7 +157,7 @@ class Window:
       ``rebalance``, ``exchanging``, ``sig``, ``run``, ``candidate``,
       ``last``, ``pending``, ``held``, the counts and dicts of ``stats``,
       ``run.ramp`` / ``run.dispatched``; also the exchange's ``owners``
-      and the scheduler's ``lane_trace`` and verdict dedupe.
+      and the scheduler's verdict dedupe.
     - read WITHOUT it: ``sig`` / ``run`` (:meth:`route`'s fast path: one
       attribute read a call, revalidated under the lock in :meth:`defer` —
       the stale-read window is the design, the locked revalidation is the
@@ -450,7 +450,6 @@ class Window:
             or s.repeat_count > 1
             or s.repeat_sync_kernel
             or s.dispatch_gate is not None
-            or s.trace_lanes
         )
 
     def rows_of(self, ranges, refs, global_offset: int) -> list:
@@ -502,8 +501,6 @@ class Window:
             reason = "repeat-mode"
         elif s.dispatch_gate is not None:
             reason = "dispatch-gate"
-        elif s.trace_lanes:
-            reason = "trace-lanes"
         if reason is None:
             try:
                 hash(sig)

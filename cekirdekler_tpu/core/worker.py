@@ -758,6 +758,12 @@ class Worker:
             self._stream_driver.drain()
 
     # -- launch --------------------------------------------------------------
+    def _mark_dispatch(self, tag: str, compute_id) -> None:
+        """``part:call`` / ``part:handed`` (trace/spans.py): ONE pair a
+        ``launch`` span, around its dispatches; between them is what the
+        runtime takes to admit them.  Callers test their span's token."""
+        TRACER.instant("engage", cid=compute_id, lane=self.index, tag=tag)
+
     def launch(
         self,
         program: KernelProgram,
@@ -833,6 +839,8 @@ class Worker:
                 one_args = self.ladder_scalars(offset, units, 1) + (bufs,)
             if one_fn is not None:
                 one_fn.info.reach = reach
+                if _tt:
+                    self._mark_dispatch("part:call", compute_id)
                 bufs = tuple(one_fn(*one_args))
                 dispatched = 1
                 infos.append(one_fn.info)
@@ -867,6 +875,9 @@ class Worker:
                                 )
                                 n_arr = program.array_param_count(name)
                                 info.reach = reach
+                                if _tt and not dispatched:
+                                    self._mark_dispatch("part:call",
+                                                        compute_id)
                                 out = fn(offset, bufs[:n_arr], tuple(va),
                                          frozen=frozen)
                                 bufs = tuple(out) + bufs[n_arr:]
@@ -875,6 +886,8 @@ class Worker:
                                 if _tt:
                                     infos.append(info)
                             offset -= size  # rewind for next kernel/repeat
+            if _tt:
+                self._mark_dispatch("part:handed", compute_id)
         finally:
             if _dm is not None:  # close even on a failed dispatch
                 MARKS.end(_dm)
@@ -1002,8 +1015,12 @@ class Worker:
         _dm = MARKS.begin(kernel_names, compute_id, self.index) \
             if MARKS.enabled else None
         try:
+            if _tt:
+                self._mark_dispatch("part:call", compute_id)
             bufs = tuple(fn(*self.ladder_scalars(offset, size // step, iters),
                             bufs))
+            if _tt:
+                self._mark_dispatch("part:handed", compute_id)
         finally:
             if _dm is not None:
                 MARKS.end(_dm)

@@ -104,6 +104,34 @@ def _row(name: str, lowering: str, cold_s: float, run_s: float,
             "run_s": round(run_s, 4), "max_err": float(max_err), **extra}
 
 
+def _require_lane_edge(row: str, marks: list, launches: list) -> None:
+    """ISSUE 52's marks of ONE lane's phase of one synchronous compute, on
+    one clock: ``marks`` are ``(when, tag)`` of the lane's instants,
+    ``launches`` ``(open, end)`` of its ``launch`` spans.  ``phase-start``
+    <= ``phase-locked`` <= the first launch's open <= its ``part:call`` <=
+    its ``part:handed`` <= the first ``part:issued`` <= the last
+    ``part:landed`` <= ``phase-done``, and ONE ``part:call`` /
+    ``part:handed`` pair inside every launch."""
+    at = {tag: sorted(t for t, said in marks if said == tag)
+          for tag in ("phase-start", "phase-locked", "part:call",
+                      "part:handed", "part:issued", "part:landed",
+                      "phase-done")}
+    launches = sorted(launches)
+    _require(launches and all(at.values())
+             and len(at["part:call"]) == len(at["part:handed"])
+             == len(launches),
+             f"{row}: marks {({k: len(v) for k, v in at.items()})} for "
+             f"{len(launches)} launches")
+    chain = [at["phase-start"][0], at["phase-locked"][0], launches[0][0],
+             at["part:call"][0], at["part:handed"][0], at["part:issued"][0],
+             at["part:landed"][-1], at["phase-done"][0]]
+    _require(chain == sorted(chain),
+             f"{row}: the lane's marks out of order: {chain}")
+    _require(all(a <= c <= h <= b for (a, b), c, h in zip(
+        launches, at["part:call"], at["part:handed"])),
+        f"{row}: a launch without its part:call / part:handed inside it")
+
+
 def _lane_platforms(devices) -> list[str]:
     return [d.jax_device.platform for d in devices]
 
@@ -348,14 +376,21 @@ def _wave_window_across_lanes(lanes, sizes) -> dict:
         # each of the windows' per-call computes marks its parts, each lane
         # the end of its phase (trace/spans.py, "Part marks")
         parts = [s.tag for s in spans if s.kind == "engage"
-                 and (s.tag or "").startswith("part:")]
+                 and (s.tag or "").startswith("part:") and s.lane is None]
         _require(parts == ["part:stage", "part:submit", "part:join",
                            "part:note"] * (2 * per),
                  f"part marks of {2 * per} computes: {parts[:8]} .. "
                  f"{len(parts)}")
-        done = sum(s.kind == "enqueue" and s.tag == "phase-done"
-                   for s in spans)
-        _require(done == 2 * per * len(lanes), f"{done} phase-done instants")
+        for tag in ("phase-start", "phase-locked", "phase-done"):
+            said = sum(s.kind == "enqueue" and s.tag == tag for s in spans)
+            _require(said == 2 * per * len(lanes), f"{said} {tag} instants")
+        # (ISSUE 52) and every launch of a lane ONE part:call / part:handed
+        pairs = [s.tag for s in spans if s.kind == "engage"
+                 and s.lane is not None]
+        launched = sum(s.kind == "launch" for s in spans)
+        _require(sorted(pairs) == ["part:call"] * launched
+                 + ["part:handed"] * launched,
+                 f"{len(pairs)} launch marks for {launched} launches")
         # a row up or down is a runtime shift: Pallas vetoes it (and says
         # why), so waveStep runs the vectorized lowering on every lane
         routed = {lo for plat in set(_lane_platforms(lanes))
@@ -463,11 +498,16 @@ def _nbody_windows_start_on_the_ladder(devices, sizes) -> dict:
     want_tile = (f"{tile_rows}x128;grid={rows_total // tile_rows};live=6"
                  if lane[0].jax_device.platform == "tpu" else "None")
     # the barrier's part marks and the lane's retire anchor; a window of
-    # deferred computes holds no other mark
+    # deferred computes holds no other mark but its dispatches' own
     marks = [(name, str(st.get("tag")), st.get("lane"))
              for _t, name, st in events
              if str(st.get("tag")).startswith("part:")
-             or st.get("tag") in ("retired", "phase-done")]
+             or st.get("tag") in ("retired", "phase-start", "phase-locked",
+                                  "phase-done")]
+    # (ISSUE 52) every fused dispatch marks where the runtime takes it and
+    # where it has: ONE ``ck/engage`` pair a ``ck/launch``, with the lane
+    pairs = [m for m in marks if m[0] == "ck/engage"]
+    marks = [m for m in marks if m not in pairs]
     _require(starts == {"first-sighting": 1, "ladder": 2},
              f"window starts {starts}")
     _require(len(enqueues) == per and enqueues[0].get("start") == "ladder"
@@ -480,6 +520,10 @@ def _nbody_windows_start_on_the_ladder(devices, sizes) -> dict:
              f"a per-call launch in a window started on the ladder: {launches}")
     _require(all(want_tile in t.split("+") for t in tiles),
              f"ck/launch spans carry tile {tiles}, expected {want_tile}")
+    _require(pairs == [("ck/engage", "part:call", 0),
+                       ("ck/engage", "part:handed", 0)] * len(launches),
+             f"third window's launch marks: {pairs} for {len(launches)} "
+             "launches")
     _require(marks == [("ck/fence", "part:wait", None),
                        ("ck/fence", "retired", 0),
                        ("ck/fence", "part:close", None)],
@@ -515,7 +559,7 @@ def _mandelbrot_frame_read_back(devices, sizes, want) -> dict:
     cr = NumberCruncher(devices.subset(1), MANDELBROT_SRC)
     out = ClArray(n, np.float32, name="mandel_shown", read=False, write=True)
     root = tempfile.mkdtemp(prefix="ck_smoke_readback_")
-    events, launches = [], []
+    events, launches, edge = [], [], []
     try:
         call = lambda: out.compute(cr, 7107, "mandelbrot", n, lr, values=vals)
         _, cold_s = _timed(call)
@@ -539,8 +583,14 @@ def _mandelbrot_frame_read_back(devices, sizes, want) -> dict:
                            for li, line in enumerate(plane.lines)
                            for ev in line.events
                            if ev.name in ("ck/download", "ck/download-chunk")]
-                launches += [dict(ev.stats) for line in plane.lines
+                launches += [dict(ev.stats, t0=ev.start_ns,
+                                  t1=ev.start_ns + ev.duration_ns)
+                             for line in plane.lines
                              for ev in line.events if ev.name == "ck/launch"]
+                edge += [(ev.start_ns, dict(ev.stats))
+                         for line in plane.lines for ev in line.events
+                         if ev.name in ("ck/enqueue", "ck/engage")
+                         and dict(ev.stats).get("lane") == 0]
     finally:
         cr.dispose()
         shutil.rmtree(root, ignore_errors=True)
@@ -567,6 +617,18 @@ def _mandelbrot_frame_read_back(devices, sizes, want) -> dict:
                  f"read-back at offset {off}: issued {issued.get(off)}, "
                  f"landed {mark}, span {inside}")
         copy_ns += inside[0][1] - mark[0]
+    # the lane's half of the compute (ISSUE 52), on the profile's clock:
+    # the four new marks in order around the read-back's, and the pool hop
+    # on ``phase-start`` under a key of its own
+    _require_lane_edge(
+        "mandelbrot frame read back",
+        [(t, str(st.get("tag"))) for t, st in edge]
+        + [(e[0], tag(e)) for e in events if tag(e).startswith("part:")],
+        [(s["t0"], s["t1"]) for s in launches])
+    starts = [st for _t, st in edge if st.get("tag") == "phase-start"]
+    _require(len(starts) == 1 and float(starts[0].get("hop_us", -1.0)) >= 0.0
+             and "queued_us" not in starts[0],
+             f"phase-start without its hop: {starts}")
     nbytes = sum(int(e[3].get("bytes", 0)) for e in landed.values())
     _require(nbytes == 4 * n == sum(int(s[3].get("bytes", 0)) for s in spans),
              f"the frame's downloads carry {nbytes} bytes, the frame is "
@@ -576,6 +638,7 @@ def _mandelbrot_frame_read_back(devices, sizes, want) -> dict:
     return _row("mandelbrot frame read back", "", cold_s, run_s, err,
                 downloads=len(spans), bytes=nbytes, stream_chunks=chunks,
                 launches=len(scalars), scalars=scalars[0][1],
+                hop_us=float(starts[0]["hop_us"]),
                 readback_ms=round((spans[-1][1] - first) / 1e6, 3),
                 copy_ms=round(copy_ns / 1e6, 3))
 
@@ -707,7 +770,7 @@ def _group_reduction(devices, sizes) -> dict:
     make run with no mask (``loops`` ends in ``peeled:1``)."""
     import importlib.util
 
-    from cekirdekler_tpu import ClArray
+    from cekirdekler_tpu import ClArray, trace
     from cekirdekler_tpu.core.cruncher import NumberCruncher
     from cekirdekler_tpu.kernel.registry import lowering_meta
 
@@ -736,7 +799,14 @@ def _group_reduction(devices, sizes) -> dict:
 
         _, cold_s = _timed(lambda: reduce_to(elements))
         upto = elements - 2 * n  # another prefix: a stale partial would show
-        total, run_s = _timed(lambda: reduce_to(upto))
+        # (the ring is on for this call: the lane's marks, below)
+        with trace.tracing() as tr:
+            total, run_s = _timed(lambda: reduce_to(upto))
+        spans = tr.snapshot()
+        _require_lane_edge(
+            "group reduction compute()",
+            [(s.t0, s.tag) for s in spans if s.lane == 0 and s.t0 == s.t1],
+            [(s.t0, s.t1) for s in spans if s.kind == "launch"])
         want = ref.partials(data["g_idata"], upto, groups, lr)
         differing = int((out.host().astype(np.float64) != want).sum())
         _require(differing == 0 and total == float(want.sum()),

@@ -712,17 +712,29 @@ def test_an_exchanging_window_starts_per_call_named_halo(devs, monkeypatch):
     cr.dispose()
 
 
+_PHASE = ("phase-start", "phase-locked", "phase-done")
+
+
 def _marks(spans):
     return [s for s in spans if (s.tag or "").startswith("part:")
-            or s.tag in ("retired", "phase-done")]
+            or s.tag == "retired" or s.tag in _PHASE]
+
+
+def _launch_pairs(marks):
+    """The ``part:call`` / ``part:handed`` pairs of the lanes' launches
+    (ISSUE 52: ``engage`` instants with a lane), taken out of ``marks``."""
+    pairs = [s for s in marks if s.kind == "engage" and s.lane is not None]
+    return pairs, [s for s in marks if s not in pairs]
 
 
 @pytest.mark.parametrize("lanes", [1, 2])
 def test_a_window_on_the_ladder_marks_its_barrier_and_nothing_else(devs,
                                                                    lanes):
     """A deferred compute returns before any mark's site: a window that
-    started on the ladder holds the barrier's marks alone (on one lane:
-    ``wait``, ``retired``, ``close``: three instants a call)."""
+    started on the ladder holds the barrier's marks (on one lane: ``wait``,
+    ``retired``, ``close``: three instants a call) and, since ISSUE 52, ONE
+    ``part:call`` / ``part:handed`` pair a fused dispatch a lane, on the
+    lane's driver thread: no lane's phase ran, so no ``phase-*``."""
     from cekirdekler_tpu.trace.spans import TRACER
 
     n, computes = 1024, 6
@@ -747,7 +759,12 @@ def test_a_window_on_the_ladder_marks_its_barrier_and_nothing_else(devs,
     cr.enqueue_mode = False
     cr.dispose()
     assert sum(s.kind == "enqueue" and s.t1 > s.t0 for s in spans) == computes
-    marks = _marks(spans)
+    pairs, marks = _launch_pairs(_marks(spans))
+    fused = [s for s in spans if s.kind == "launch"]
+    assert len(pairs) == 2 * len(fused) and fused
+    for lane in range(lanes):
+        tags = [s.tag for s in pairs if s.lane == lane]
+        assert tags == ["part:call", "part:handed"] * (len(tags) // 2)
     assert {s.kind for s in marks} == {"fence"}
     assert [s.tag for s in marks] == (
         ["part:wait"] + ["retired"] * lanes + ["part:feed"] * (lanes > 1)
@@ -757,8 +774,8 @@ def test_a_window_on_the_ladder_marks_its_barrier_and_nothing_else(devs,
 def test_an_exchanging_compute_marks_where_its_strips_are_cut(devs):
     """Every compute of a window that reads across lanes goes per call:
     ``stage`` before the strips are cut, ``submit``, ``join``, ``note``,
-    and one ``phase-done`` a lane, each compute; no resync, no mark of
-    one."""
+    and a lane's ``phase-start``, ``phase-locked``, its launch's pair and
+    ``phase-done``, each compute; no resync, no mark of one."""
     from cekirdekler_tpu.trace.spans import TRACER
 
     src = """
@@ -793,12 +810,18 @@ def test_an_exchanging_compute_marks_where_its_strips_are_cut(devs):
     calls = [s for s in spans if s.kind == "enqueue" and s.t1 > s.t0]
     assert len(calls) == computes
     for call in calls:
-        inside = [s for s in marks if call.t0 <= s.t0 <= call.t1]
+        pairs, inside = _launch_pairs(
+            [s for s in marks if call.t0 <= s.t0 <= call.t1])
         assert [s.tag for s in inside if s.kind == "engage"] == [
             "part:stage", "part:submit", "part:join", "part:note"]
         done = [s for s in inside if s.tag == "phase-done"]
         assert sorted(s.lane for s in done) == [0, 1]
         assert all(s.kind == "enqueue" and s.cid == 40 for s in done)
+        for lane in (0, 1):
+            assert [s.tag for s in inside if s.tag in _PHASE
+                    and s.lane == lane] == list(_PHASE)
+            assert [s.tag for s in pairs if s.lane == lane] == [
+                "part:call", "part:handed"]
     assert not [s for s in marks if s.kind == "resync"]
 
 

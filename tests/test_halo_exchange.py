@@ -437,10 +437,15 @@ def test_the_window_says_once_why_it_does_not_fuse(traced_window):
     assert traced_window.disengaged == {"halo": 1}
     # and no compute of it was deferred into a ladder
     enq = [e for e in traced_window.events if e.name == "ck/enqueue"]
-    # (each compute's four lanes say ``phase-done``: instants of the kind)
-    done = [e for e in enq if e.stats.get("tag") == "phase-done"]
-    assert len(done) == 20 * 4 and all("lane" in e.stats for e in done)
-    enq = [e for e in enq if e not in done]
+    # (each compute's four lanes say ``phase-start``, ``phase-locked`` and
+    # ``phase-done``: instants of the kind)
+    phases = {tag: [e for e in enq if e.stats.get("tag") == tag]
+              for tag in ("phase-start", "phase-locked", "phase-done")}
+    for said in phases.values():
+        assert len(said) == 20 * 4 and all("lane" in e.stats for e in said)
+    assert all("hop_us" in e.stats and "queued_us" not in e.stats
+               for e in phases["phase-start"])
+    enq = [e for e in enq if not str(e.stats.get("tag")).startswith("phase-")]
     assert len(enq) == 20
     # every compute of it cut its strips on the caller's thread first
     stages = [e for e in traced_window.events if e.name == "ck/engage"

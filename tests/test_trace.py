@@ -490,7 +490,8 @@ def _is_mark(tag) -> bool:
     """A part mark or an anchor (trace/spans.py, "Part marks"): an instant
     inside a span, no span of its own."""
     tag = str(tag or "")
-    return tag.startswith("part:") or tag in ("retired", "phase-done")
+    return tag.startswith("part:") or tag in (
+        "retired", "phase-start", "phase-locked", "phase-done")
 
 
 def _inside(p, outer_name, kind, marks=False):
@@ -708,11 +709,19 @@ def test_per_call_compute_marks_its_parts_and_a_deferred_one_nothing(
     deferred = [e for e in caller if e not in per_call]
     assert len(per_call) == 1 and deferred
     tags, marks = _marks_in(profiled, per_call[0], "engage")
-    assert tags == ["part:submit", "part:join", "part:note"]
-    assert all(e.line == outer.line and e.stats["cid"] == 77 for e in marks)
-    done, events = _marks_in(profiled, per_call[0], "enqueue")
-    assert done == ["phase-done", "phase-done"]
-    assert {e.stats["lane"] for e in events} == {0, 1}
+    # (the lanes' launches mark ``part:call`` / ``part:handed`` on the kind
+    # too, from the pool's threads: ISSUE 52, the tests further down)
+    marks = [e for e in marks if e.line == outer.line]
+    assert [str(e.stats["tag"]) for e in marks] == [
+        "part:submit", "part:join", "part:note"]
+    assert len(tags) == len(marks) + 2 * 2  # one pair a lane's launch
+    assert all(e.stats["cid"] == 77 for e in marks)
+    phases, events = _marks_in(profiled, per_call[0], "enqueue")
+    for lane in (0, 1):
+        assert [str(e.stats["tag"]) for e in events
+                if e.stats["lane"] == lane] == [
+            "phase-start", "phase-locked", "phase-done"]
+    assert len(phases) == 6
     assert all(e.line != outer.line and "queued_us" not in e.stats
                for e in events)
     for kind in ("engage", "enqueue", "fence", "resync"):
@@ -762,9 +771,17 @@ def test_marks_leave_the_rings_per_kind_totals_alone(lanes):
     per_call = sum(s.kind == "enqueue" and s.t1 > s.t0
                    and not s.tag.endswith("fused-defer") for s in spans)
     assert per_call == 2
-    assert [s.tag for s in marks if s.kind == "engage"] == [
+    assert [s.tag for s in marks if s.kind == "engage"
+            and s.lane is None] == [
         "part:submit", "part:join", "part:note"] * per_call
-    assert sum(s.tag == "phase-done" for s in marks) == lanes * per_call
+    for tag in ("phase-start", "phase-locked", "phase-done"):
+        assert sum(s.tag == tag for s in marks) == lanes * per_call
+    # one pair a launch (the per-call computes' and the fused dispatch's)
+    pairs = [s.tag for s in marks if s.kind == "engage"
+             and s.lane is not None]
+    launches = sum(s.kind == "launch" for s in spans)
+    assert sorted(pairs) == ["part:call"] * launches + [
+        "part:handed"] * launches and launches >= lanes * per_call
     with_marks = window_report(spans, t0, t1)
     without = window_report([s for s in spans if s not in marks], t0, t1)
     assert set(with_marks.per_kind) == set(without.per_kind)
@@ -788,6 +805,151 @@ def test_a_mark_off_costs_what_a_span_off_costs():
         best = min(best, (time.perf_counter() - t0) / n)
     assert best < 1e-6, f"disabled mark cost {best*1e9:.0f} ns >= 1 µs"
     assert tr.total_recorded == 0
+
+
+_LANE_ORDER = ["phase-start", "phase-locked", "launch", "part:call",
+               "part:handed", "part:issued", "part:landed", "phase-done"]
+
+
+def _one_synchronous_compute(lanes, chunks, spy=None):
+    """The ring of ONE warm non-windowed ``compute()`` on ``lanes`` lanes,
+    ``stream_chunks`` pinned to ``chunks`` (1: the monolithic engine)."""
+    n = 4096
+    cr = NumberCruncher(_cpus(lanes), INC)
+    x = ClArray(np.zeros(n, np.float32), name="edge_x", partial_read=True)
+    try:
+        cr.stream_chunks = chunks
+        x.compute(cr, 81, "inc", n, 64)
+        with tracing() as tr:
+            if spy is not None:
+                spy()
+            x.compute(cr, 81, "inc", n, 64)
+    finally:
+        cr.dispose()
+    np.testing.assert_array_equal(np.asarray(x), 2.0)
+    return tr.snapshot()
+
+
+@pytest.mark.parametrize("lanes, chunks", [(1, 1), (1, 4), (2, 1)],
+                         ids=["monolithic", "streamed", "two-lanes"])
+def test_a_synchronous_compute_tells_its_lanes_edge_in_order(lanes, chunks):
+    """ISSUE 52: on each lane the ring of one synchronous compute holds
+    ``phase-start`` <= ``phase-locked`` <= the first ``launch`` open <=
+    ``part:call`` <= ``part:handed`` <= ``part:issued`` <= the last
+    ``part:landed`` <= ``phase-done``; ONE ``part:call`` / ``part:handed``
+    pair a ``launch`` span, inside it; the four new instants carry the
+    lane and the compute's id, on kinds that exist."""
+    spans = _one_synchronous_compute(lanes, chunks)
+    (call,) = [s for s in spans if s.kind == "enqueue" and s.t1 > s.t0]
+    submit, note = (next(s.t0 for s in spans if s.tag == tag)
+                    for tag in ("part:submit", "part:note"))
+    for lane in range(lanes):
+        mine = [s for s in spans if s.lane == lane]
+        at = {tag: [s.t0 for s in mine if s.tag == tag]
+              for tag in _LANE_ORDER}
+        launches = sorted((s for s in mine if s.kind == "launch"),
+                          key=lambda s: s.t0)
+        assert len(launches) == chunks
+        assert [len(at[t]) for t in ("phase-start", "phase-locked",
+                                     "phase-done")] == [1, 1, 1]
+        assert len(at["part:call"]) == len(at["part:handed"]) == chunks
+        assert len(at["part:issued"]) == len(at["part:landed"]) == chunks
+        for span, called, handed in zip(launches, sorted(at["part:call"]),
+                                        sorted(at["part:handed"])):
+            assert span.t0 <= called <= handed <= span.t1
+        chain = [submit, at["phase-start"][0], at["phase-locked"][0],
+                 launches[0].t0, min(at["part:call"]),
+                 min(at["part:handed"]), min(at["part:issued"]),
+                 max(at["part:landed"]), at["phase-done"][0], note]
+        assert chain == sorted(chain), chain
+        assert call.t0 <= chain[0] and chain[-1] <= call.t1
+        new = [s for s in mine if s.tag in (
+            "phase-start", "phase-locked", "part:call", "part:handed")]
+        assert all(s.cid == 81 and s.t0 == s.t1 for s in new)
+        assert {s.kind for s in new} == {"enqueue", "engage"}
+    # nothing of kind ``launch`` but the launches themselves: a reader that
+    # counts a lane's ``ck/launch`` events counts what it counted
+    assert all(s.t1 > s.t0 for s in spans if s.kind == "launch")
+
+
+def test_phase_start_carries_the_hop_under_a_key_of_its_own(monkeypatch):
+    """``hop_us`` on ``phase-start`` (the closure's wait between the
+    caller's submit and the lane's first line) and ``queued_us`` on no
+    instant: ``driver_queue_wait_ms_per_call`` sums that key over spans
+    and must read what it read."""
+    seen = []
+    instant = TRACER.instant
+
+    def spy():
+        def spied(kind, cid=None, lane=None, tag=None, **meta):
+            seen.append((kind, tag, lane, meta))
+            return instant(kind, cid=cid, lane=lane, tag=tag, **meta)
+
+        monkeypatch.setattr(TRACER, "instant", spied)
+
+    _one_synchronous_compute(2, 1, spy)
+    starts = [m for kind, tag, _lane, m in seen if tag == "phase-start"]
+    assert len(starts) == 2
+    assert all(set(m) == {"hop_us"} and m["hop_us"] >= 0.0 for m in starts)
+    assert not [m for _k, _t, _l, m in seen if "queued_us" in m]
+    assert sorted(lane for _k, tag, lane, _m in seen
+                  if tag == "phase-locked") == [0, 1]
+    # outside a bound closure there is no hop to tell
+    assert TRACER.hop_meta() == {}
+
+
+def test_the_lanes_marks_ride_the_profile_with_their_lane(profiled):
+    """In a profiler session the four instants are annotations of kinds
+    that exist: ``ck/enqueue`` ``phase-start`` (``hop_us``, no
+    ``queued_us``) and ``phase-locked`` from the pool's threads, ONE
+    ``ck/engage`` ``part:call`` / ``part:handed`` pair inside every
+    ``ck/launch``, with its lane; and ``ck/launch`` events are launches."""
+    launches = [e for e in profiled.events if e.name == "ck/launch"]
+    pairs = [e for e in profiled.events if e.name == "ck/engage"
+             and str(e.stats.get("tag")) in ("part:call", "part:handed")]
+    assert len(pairs) == 2 * len(launches)
+    for span in launches:
+        inside = sorted((e for e in pairs if e.line == span.line
+                         and span.t0 <= e.t0 and e.t1 <= span.t1),
+                        key=lambda e: e.t0)
+        assert [str(e.stats["tag"]) for e in inside] == [
+            "part:call", "part:handed"]
+        assert all(e.stats["lane"] == span.stats["lane"]
+                   and e.stats["cid"] == 77 and "queued_us" not in e.stats
+                   for e in inside)
+    starts = [e for e in profiled.events if e.name == "ck/enqueue"
+              and e.stats.get("tag") == "phase-start"]
+    locked = [e for e in profiled.events if e.name == "ck/enqueue"
+              and e.stats.get("tag") == "phase-locked"]
+    assert starts and len(starts) == len(locked)
+    assert all(e.stats["hop_us"] >= 0.0 and "queued_us" not in e.stats
+               and e.stats["lane"] in (0, 1) for e in starts)
+    assert not [e for e in launches if _is_mark(e.stats.get("tag"))]
+
+
+def test_the_lanes_marks_off_cost_one_check_a_site():
+    """The four new sites with the tracer off: ``_run_worker`` asks
+    ``active()`` once for its two, ``Worker.launch`` tests its own span's
+    token for its pair: under the budget of a span's, all four."""
+    tr = Tracer()
+    assert not tr.active()
+    token = tr.t0("launch")
+    n = 50_000
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            on = tr.active()
+            if on:
+                raise AssertionError("off")
+            if on:
+                raise AssertionError("off")
+            if token:
+                raise AssertionError("off")
+            if token:
+                raise AssertionError("off")
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 1e-6, f"four sites off cost {best*1e9:.0f} ns >= 1 µs"
 
 
 def test_first_launch_yields_compile_and_second_none(profiled):
