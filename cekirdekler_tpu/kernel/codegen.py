@@ -343,7 +343,7 @@ class _Ctx:
         self.compacting = False
         self.hoisted: dict[int, Any] = {}
         self.compact_access: dict[tuple[int, bool], str] = {}
-        self.compact_loops = 0
+        self.compact_loops = self.compact_ordered = 0
 
     def adopt(self, kernel: KernelDef, uniform_vars: set[str],
               coop: "_Coop | None" = None, peels: dict | None = None) -> None:
@@ -2632,7 +2632,7 @@ def _loop_views(ctx: _Ctx, node, cond_expr, body_core: list,
     return run_var, run_tables
 
 
-def _common_passes(ctx: _Ctx, peel: "_Peel"):
+def _common_passes(ctx: _Ctx, peel: "_Peel", each: bool = False):
     """The passes EVERY lane of a masked loop makes (:func:`_common_walks`
     names the loop), as a 0-d unsigned integer, from the walker's values where
     the loop is entered: as many as the lane that starts LAST, every other
@@ -2640,7 +2640,10 @@ def _common_passes(ctx: _Ctx, peel: "_Peel"):
     so none wraps there.  ONE reduction over the lanes a launch.  The
     difference is taken modulo the width and read without a sign, which is
     exact for any two values the first of which is the larger.  None where
-    the condition does not compare integers of the walker's width."""
+    the condition does not compare integers of the walker's width.  With
+    ``each``, the passes of EACH lane, its own walker against its own bound
+    (the key of :func:`_chunk_lanes`: a lane that leaves by a ``break`` or
+    wraps makes other passes than these, and no result rests on them)."""
     w, e = ctx.env[peel.walker], _eval(ctx, peel.bound)
     if w.ctype not in _WIDE_INTS or e.ctype not in _INT_TYPES:
         return None
@@ -2648,8 +2651,9 @@ def _common_passes(ctx: _Ctx, peel: "_Peel"):
     dt = ctype_to_dtype(t)
     if dt.itemsize != ctype_to_dtype(w.ctype).itemsize:
         return None
-    top = jnp.max(jnp.asarray(_num(_as_dtype(w, t))))
-    end = _lane0(_num(_as_dtype(e, t)))
+    top, end = jnp.asarray(_num(_as_dtype(w, t))), _num(_as_dtype(e, t))
+    if not each:
+        top, end = jnp.max(top), _lane0(end)
     u = jnp.dtype(f"uint{8 * dt.itemsize}").type
     gap = lax.bitcast_convert_type(end - top, u)
     enters = top < end if peel.strict else top <= end
@@ -2809,7 +2813,7 @@ def _exec_masked(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
                 tuple(block + jnp.int32(s.step) for s, block in zip(sites, blocks)))
 
     peel = ctx.peels.get(id(node)) if run_var is None and outer_mask is None else None
-    common = peel and _common_passes(ctx, peel)
+    common = _common_passes(ctx, peel) if peel and peel.common else None
     if common is not None:
         # THE COMMON PASSES: a loop entered by every lane, whose condition
         # compares a walker the build has followed with a bound they share,
@@ -2945,14 +2949,53 @@ def _prefix_counts(x):
     return (inc + before[:, None]).reshape(-1)
 
 
-def _chunk_lanes(entered, width: int) -> Callable:
-    """``c -> int32[width]``: the numbers of the lanes of chunk ``c`` of the
-    lanes set in ``entered``, in rising order; beyond the last of them, lane
-    0 (the caller masks those slots off).  The order is built once: every
-    entering lane's rank among them is a prefix count, and its number goes
-    to that place of the list."""
+# the classes of :func:`_chunk_lanes`' key: a count of passes under
+# ``_SHORT`` is a class of its own, a longer one shares its class with the
+# counts of as many bits; every 32-bit count has one of ``_CLASSES``
+_SHORT = 32
+_CLASSES = _SHORT + 32 - _SHORT.bit_length() + 1
+
+
+def _keyed_ranks(entered, trips, width: int):
+    """The place (from 1) of every lane set in ``entered`` when those lanes
+    stand by the CLASS of ``trips``, their passes to come, from long to
+    short, and inside a class in rising order; with no more than ``width`` of
+    them there is one class, and the places are the prefix counts.  A
+    counting order of the pieces the chip's compiler takes cheaply (PERF.md,
+    PR 41): the lanes of a row of 128 are counted by class and placed among
+    themselves by comparisons, and ONE prefix count over the rows' counts,
+    class after class, says how many stand before a row's lanes of a class."""
     b = entered.shape[0]
-    rank = _prefix_counts(jnp.pad(entered, (0, -b % _ROW)).astype(jnp.int32))[:b]
+    t = jnp.minimum(trips, trips.dtype.type(0xFFFFFFFF)).astype(jnp.uint32)
+    cls = jnp.where(t < _SHORT, t, (_CLASSES - 1) - lax.clz(t)).astype(jnp.int32)
+    keyed = jnp.sum(entered, dtype=jnp.int32) > width
+    cls = jnp.where(entered, jnp.where(keyed, _CLASSES - 1 - cls, 0), _CLASSES)
+    rows = jnp.pad(cls, (0, -b % _ROW), constant_values=_CLASSES).reshape(-1, _ROW)
+    mine = rows[:, :, None] == jnp.arange(_CLASSES, dtype=jnp.int32)
+    count = jnp.sum(mine, axis=1, dtype=jnp.int32)          # [rows, classes]
+    m = count.size
+    upto = _prefix_counts(jnp.pad(count.T.reshape(-1), (0, -m % _ROW)))[:m]
+    before = upto.reshape(count.shape[::-1]).T - count
+    ahead = (lax.broadcasted_iota(jnp.int32, (_ROW, _ROW), 1)
+             <= lax.broadcasted_iota(jnp.int32, (_ROW, _ROW), 0))
+    among = jnp.sum((rows[:, :, None] == rows[:, None, :]) & ahead, axis=2,
+                    dtype=jnp.int32)
+    return (jnp.sum(jnp.where(mine, before[:, None, :], 0), axis=2)
+            + among).reshape(-1)[:b]
+
+
+def _chunk_lanes(entered, width: int, trips=None) -> Callable:
+    """``c -> int32[width]``: the numbers of the lanes of chunk ``c`` of the
+    lanes set in ``entered``; beyond the last of them, lane 0 (the caller
+    masks those slots off).  The order is built once: every entering lane's
+    rank among them, and its number goes to that place of the list.  In
+    rising order, the rank a prefix count; with ``trips`` (the passes each
+    lane is going to make: :func:`_common_passes`) and more than one chunk,
+    by :func:`_keyed_ranks`, so that a chunk makes its own lanes' passes and
+    not those of the longest walk among ``width`` strangers."""
+    b = entered.shape[0]
+    rank = (_prefix_counts(jnp.pad(entered, (0, -b % _ROW)).astype(jnp.int32))[:b]
+            if trips is None else _keyed_ranks(entered, trips, width))
     order = jnp.zeros(b + -b % width, jnp.int32).at[
         jnp.where(entered, rank - 1, -1)].set(
             jnp.arange(b, dtype=jnp.int32), mode="drop", unique_indices=True)
@@ -2996,7 +3039,9 @@ def _exec_compacted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
 
     The order in which the lanes of a launch run against each other is
     unspecified (docs/KERNEL_LANGUAGE.md); chunks add nothing a kernel may
-    rely on."""
+    rely on, and which lanes share one is the build's to choose: those with
+    as many passes to make, where the syntax gives the count
+    (:func:`_chunk_lanes`)."""
     B, W = ctx.B, _COMPACT_WIDTH
     entered = jnp.broadcast_to(ctx.active_mask(), ctx.shape)
     k = jnp.sum(entered, dtype=jnp.int32)
@@ -3006,6 +3051,11 @@ def _exec_compacted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
 
     live = sorted(_live_after(ctx, carried_vars))
     _carried_at_shape(ctx, carried_vars)
+    # the passes each lane is going to make, where the syntax gives them
+    # (_common_walks): the key by which the entering lanes go to their chunks
+    peel = ctx.peels.get(id(node))
+    trips = _common_passes(ctx, peel, each=True) if peel else None
+    ctx.compact_ordered += trips is not None
     ctypes = {v: ctx.env[v].ctype for v in live}
     needed = sorted((_vars_read(loop) | set(carried_vars)) & set(ctx.env))
     changed = _assigned_vars(body_core + [step_stmt])
@@ -3055,7 +3105,7 @@ def _exec_compacted(ctx: _Ctx, node, cond_expr, body_core: list, step_stmt,
                  for v in live}, bufs)
 
     def compacted(state):
-        lanes_of = _chunk_lanes(entered, W)
+        lanes_of = _chunk_lanes(entered, W, trips)
         slot = jnp.arange(W, dtype=jnp.int32)
 
         def one(c, st):
@@ -3671,6 +3721,7 @@ class _Peel(NamedTuple):
     bound: Any    # the expression it is compared with
     strict: bool  # ``walker < bound``; else ``<=``
     step: int     # what a pass adds to the walker: positive
+    common: bool  # every lane compares with the same bound and leaves by it
 
 
 def _common_walks(body: list, uset: set[str], private, sizes: dict) -> dict:
@@ -3686,20 +3737,23 @@ def _common_walks(body: list, uset: set[str], private, sizes: dict) -> dict:
       ``for``'s step, ``+=`` / ``-=`` / ``++`` / ``--`` by build-time integers
       (:func:`_moves`; no float literal among them: ``w += 2.0f`` rounds)
       whose sum is positive;
-    - ``e`` is the same in every lane (:func:`_expr_uniform` by ``uset``) and
-      the loop cannot change it: it names no local the loop assigns and no
+    - the loop cannot change ``e``: it names no local the loop assigns and no
       buffer the loop stores to;
-    - the body holds no ``break`` and no ``continue`` (a ``return`` in a
+    - ``e`` is the same in every lane (:func:`_expr_uniform` by ``uset``) and
+      the body holds no ``break`` and no ``continue`` (a ``return`` in a
       loop is refused where the loop is built).
 
-    A bound that differs by lane, one the body assigns, a walker moved under
-    an ``if`` or by a run-time step, a walk downward: not here, such a loop is
-    masked from its first pass."""
+    A loop that meets all but the last is named too, ``common`` false: each
+    lane's own passes (with a ``break``, no more than those) are still known
+    where the loop is entered, which is all the ORDER of a compacted loop's
+    lanes asks (:func:`_chunk_lanes`; any order leaves the same memory).  A
+    bound the body assigns, a walker moved under an ``if`` or by a run-time
+    step, a walk downward: not here, such a loop is masked from its first
+    pass and its lanes keep their order."""
     known = _build_locals(body, sizes)
     out: dict = {}
     for loop in _walk(body):
-        if (not isinstance(loop, (For, While)) or not isinstance(loop.cond, BinOp)
-                or _has_exit(loop.body, (Break, Continue))):
+        if not isinstance(loop, (For, While)) or not isinstance(loop.cond, BinOp):
             continue
         pass_stmts = loop.body + ([loop.step] if getattr(loop, "step", None) else [])
         changed = _assigned_vars(pass_stmts) | _stored_bufs(pass_stmts)
@@ -3713,10 +3767,11 @@ def _common_walks(body: list, uset: set[str], private, sizes: dict) -> dict:
             if (None in by or not 0 < sum(by) < 1 << 31
                     or any(isinstance(x, Num) and x.ctype not in _INT_TYPES
                            for x in _walk(moving))
-                    or not _expr_uniform(e, uset, private)
                     or _vars_read(e) & changed):
                 continue
-            out[id(loop)] = _Peel(w.name, e, op == "<", sum(by))
+            out[id(loop)] = _Peel(
+                w.name, e, op == "<", sum(by), _expr_uniform(e, uset, private)
+                and not _has_exit(loop.body, (Break, Continue)))
             break
     return out
 
@@ -4008,10 +4063,12 @@ class KernelBuildInfo:
     tile_grid: int = 0
     loop_live: int = 0
     # lane compaction (:func:`_exec_compacted`), filled at trace: ``(loops,
-    # width, gathered, scattered)``, the masked loops made compactable, the
-    # lanes of a chunk, and the reads and stores at the lane's own element
-    # (a slice on the dense path) that a chunk lowers as a gather and as a
-    # scatter; ``()`` where no loop of the build was made compactable
+    # width, gathered, scattered, ordered)``, the masked loops made
+    # compactable, the lanes of a chunk, the reads and stores at the lane's
+    # own element (a slice on the dense path) that a chunk lowers as a gather
+    # and as a scatter, and the loops whose entering lanes go to their chunks
+    # by the passes they are going to make (:func:`_chunk_lanes`); ``()``
+    # where no loop of the build was made compactable
     compact: tuple = ()
     # work-group cooperation: ``(arrays, bytes, barriers)``, the kernel's
     # ``__local`` arrays, the bytes of them one work-group holds and its
@@ -4153,7 +4210,8 @@ def build_kernel_fn(
         own = [kind for site, kind in ctx.compact_access.items()
                if ctx.access.get(site) != kind]
         info.compact = (ctx.compact_loops, _COMPACT_WIDTH, own.count("gather"),
-                        own.count("scatter")) if ctx.compact_loops else ()
+                        own.count("scatter"), ctx.compact_ordered
+                        ) if ctx.compact_loops else ()
         info.views = tuple(sorted(
             ViewSpec(info.array_params.index(name), kind)
             for name, kind in ctx.asked))

@@ -507,10 +507,11 @@ def lowering_meta(infos) -> dict:
     dispatch, and the Python or numpy scalars that crossed one by one.
     ``scatter`` (``stores:2;width:4+1``), where a kernel's build has any: the
     stores lowered to a scatter and the bytes of one element of each.
-    ``compact`` (``loops:1;width:8192;gathered:3;scattered:0``), where a
-    build made a masked loop compactable (``codegen._exec_compacted``): the
-    loops, the lanes of a chunk, and the reads and stores at the lane's own
-    element that a chunk lowers as gathers and scatters.
+    ``compact`` (``loops:1;width:8192;gathered:3;scattered:0;ordered:1``),
+    where a build made a masked loop compactable
+    (``codegen._exec_compacted``): the loops, the lanes of a chunk, the reads
+    and stores at the lane's own element that a chunk lowers as gathers and
+    scatters, and the loops whose lanes go to their chunks by trip count.
     ``vector`` (``params:2;width:4;loads:1;gathers:1;stores:1``), where a
     kernel has ``__global floatN*`` parameters (kernel/vectors.py): how many,
     their ``N`` and the accesses of them that were BUILT, one an access
@@ -607,11 +608,12 @@ def lowering_meta(infos) -> dict:
         if i.compact:
             compact[i.name] = max(compact.get(i.name, ()), i.compact)
     if compact:
-        loops, widths, gathered, scattered = zip(*compact.values())
+        loops, widths, gathered, scattered, ordered = zip(*compact.values())
         meta["compact"] = (
             f"loops:{sum(loops)};"
             f"width:{'+'.join(str(w) for w in sorted(set(widths)))};"
-            f"gathered:{sum(gathered)};scattered:{sum(scattered)}")
+            f"gathered:{sum(gathered)};scattered:{sum(scattered)};"
+            f"ordered:{sum(ordered)}")
     keyed = sorted({f"{k}={v}" for i in leaves for k, v in i.keyed.items()})
     if keyed:
         meta["keys"] = ";".join(keyed)

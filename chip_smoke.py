@@ -735,11 +735,12 @@ def _bfs_traversal(devices, sizes) -> dict:
                  f"BFS: access {meta['access']}, scatter "
                  f"{meta.get('scatter')}")
         # a first rung wider than a chunk builds BFS_1's adjacency loop
-        # compactable (PR 41): the reads at tid are the chunks' gathers
+        # compactable (PR 41): the reads at tid are the chunks' gathers, and
+        # its entering lanes go to their chunks by trip count (PR 53)
         from cekirdekler_tpu.kernel import codegen
 
         width = codegen._COMPACT_WIDTH
-        want_field = (f"loops:1;width:{width};gathered:3;scattered:0"
+        want_field = (f"loops:1;width:{width};gathered:3;scattered:0;ordered:1"
                       if launch_ladder(n, lr)[0] > width else None)
         _require(meta.get("compact") == want_field,
                  f"BFS: compact {meta.get('compact')}, expected {want_field}")

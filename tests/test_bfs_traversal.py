@@ -227,13 +227,13 @@ def test_the_compact_field_names_the_loop_and_leaves_the_access_field():
         for infos, chunk in ((small, width), (big, n)):
             fn, infos[name] = prog.launcher(name, chunk, 256, n, platform="cpu")
             fn(0, arrays, (nodes,))
-    assert big["BFS_1"].compact == (1, width, 3, 0)
+    assert big["BFS_1"].compact == (1, width, 3, 0, 1)
     assert small["BFS_1"].compact == big["BFS_2"].compact == ()
     assert big["BFS_1"].access == small["BFS_1"].access
     assert big["BFS_1"].scattered == (4, 1)
     # a span over the ladder's rungs of both kernels
     meta = lowering_meta(list(small.values()) + list(big.values()))
-    assert meta["compact"] == f"loops:1;width:{width};gathered:3;scattered:0"
+    assert meta["compact"] == f"loops:1;width:{width};gathered:3;scattered:0;ordered:1"
     assert "," not in meta["compact"]
     for kind in ("scatter:2", "gather:2", "uniform:1"):
         assert kind in meta["access"].split(";")

@@ -8,6 +8,14 @@ it always did, and the count decides at run time.  Compaction is another
 route to the SAME arrays: every case here is held bit for bit to the build
 with the mechanism switched off (a chunk wider than any launch), on the CPU
 rig; nothing here yields a device number.
+
+The ORDER of the entering lanes (ISSUE 53): where the loop's syntax gives the
+passes each lane is going to make (``codegen._common_walks``), the lanes go
+to their chunks by that count (``_chunk_lanes(entered, width, trips)``), from
+long to short.  ``order`` = ``keyed`` is the build as it stands, ``rising``
+the same build with the key switched off (the rising lane order of PR 41):
+both are held to the dense loop, and the property kernels to the scalar
+oracle.
 """
 
 import importlib.util
@@ -18,8 +26,9 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from cekirdekler_tpu.kernel import codegen  # noqa: E402
+from cekirdekler_tpu.kernel import codegen, lang  # noqa: E402
 from cekirdekler_tpu.kernel.registry import KernelProgram, lowering_meta  # noqa: E402
+from tests.kernel_oracle import Oracle  # noqa: E402
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark", "configs")
@@ -46,6 +55,29 @@ def compaction(monkeypatch):
         monkeypatch.setattr(codegen, "_COMPACT_WIDTH", width)
         monkeypatch.setattr(codegen, "_COMPACT_DENSE_SHARE", share)
     return set_to
+
+
+ORDERS = ("keyed", "rising")
+
+
+def set_order(monkeypatch, order: str) -> None:
+    """``rising`` hands the list's builder, as it stands now, no key (the
+    loops are still counted as ordered: the analysis is the build's)."""
+    assert order in ORDERS
+    if order == "rising":
+        builder = codegen._chunk_lanes
+        monkeypatch.setattr(codegen, "_chunk_lanes",
+                            lambda entered, width, trips=None: builder(entered, width))
+
+
+def held_to_the_oracle(src: str, arrays, got, n: int) -> None:
+    """``got``, the arrays a launch of ``src``'s kernel over ``[0, n)`` left,
+    against the scalar oracle's."""
+    kdef = lang.parse_kernels(src)[0]
+    host = {p.name: a.copy() for p, a in zip(kdef.params, arrays)}
+    Oracle(kdef, local_size=LOCAL).run(host, {}, n)
+    for p, a in zip(kdef.params, got):
+        np.testing.assert_array_equal(a, host[p.name], err_msg=p.name)
 
 
 def launch(src: str, names, arrays, n: int, values=(), platform="cpu"):
@@ -93,18 +125,23 @@ def graph():
     return data
 
 
+@pytest.mark.parametrize("order", ORDERS)
 @pytest.mark.parametrize("platform", ["cpu", "tpu"])
 @pytest.mark.parametrize("share,lanes", [
     (0.0, 0), ("one lane", 1), (0.001, 8), (0.1, 819), (0.49, 4014),
     (1.0, NODES)])
 @pytest.mark.parametrize("dense_share", [0.5, 1.0])
-def test_a_bfs_level_is_the_same_to_the_last_bit(compaction, graph, share,
-                                                 lanes, dense_share, platform):
+def test_a_bfs_level_is_the_same_to_the_last_bit(compaction, monkeypatch, graph,
+                                                 share, lanes, dense_share,
+                                                 platform, order):
     """One level (``BFS_1`` then ``BFS_2``) from a frontier of ``lanes``
     seeded nodes of one launch: the five state arrays of the compacting
     build against the dense one's, with the count choosing the path
     (``dense_share`` 0.5: a frontier of 49 % runs compacted, one of 100 %
-    over all lanes) and with every frontier compacted (1.0)."""
+    over all lanes) and with every frontier compacted (1.0).  Chunks of 1024:
+    the frontiers fill none, part of one, four with the last part full, and
+    (8000 nodes, every frontier compacted) eight."""
+    set_order(monkeypatch, order)
     rng = np.random.default_rng(17)
     frontier = np.zeros(RANGE, bool)
     frontier[rng.choice(NODES, lanes, replace=False)] = True
@@ -118,7 +155,7 @@ def test_a_bfs_level_is_the_same_to_the_last_bit(compaction, graph, share,
                         ("BFS_1", "BFS_2"), arrays, RANGE, WIDTH, (NODES,),
                         dense_share, platform)
     one, two = infos
-    assert one.compact == (1, WIDTH, 3, 0) and two.compact == ()
+    assert one.compact == (1, WIDTH, 3, 0, 1) and two.compact == ()
     found = dense[BFS_NAMES.index("mask")].astype(bool)
     assert found.any() == (0 < lanes < NODES)  # all visited: none to find
     assert not (found & visited).any()
@@ -221,19 +258,22 @@ def csr(n: int, seed: int, share: float):
     return [lo, cnt, col, tab, on, out, aux]
 
 
+@pytest.mark.parametrize("order", ORDERS)
 @pytest.mark.parametrize("what", sorted(KERNELS))
 # the last chunk shorter than W (700 lanes of 2048 on: chunks of 512); a
 # range that is no multiple of W (1280 = 2.5 W) with a last chunk of its own
 @pytest.mark.parametrize("n,width,share", [(2048, 512, 0.34), (1280, 512, 0.6)])
 def test_a_compacted_loop_leaves_what_the_dense_one_leaves(
-        compaction, what, n, width, share):
+        compaction, monkeypatch, what, n, width, share, order):
     arrays = csr(n, 23, share)
     on = arrays[4].astype(bool)
     assert on.sum() % width and n > width
+    set_order(monkeypatch, order)
     dense, infos = both(compaction, KERNELS[what], ("k",), arrays, n, width)
     (info,) = infos
-    assert info.compact[:2] == (1, width)
-    assert "compact" in lowering_meta(infos)
+    assert info.compact == (1, width) + info.compact[2:4] + (1,)
+    assert lowering_meta(infos)["compact"].endswith(";ordered:1")
+    held_to_the_oracle(KERNELS[what], arrays, dense, n)
     # the lanes switched off keep what they had
     np.testing.assert_array_equal(dense[5][~on], arrays[5][~on])
     assert (dense[5][on] != arrays[5][on]).any()
@@ -340,3 +380,284 @@ def test_the_prefix_counts_are_exact():
         x = (rng.random(n) < share).astype(np.int32)
         got = np.asarray(codegen._prefix_counts(jax.numpy.asarray(x)))
         np.testing.assert_array_equal(got, np.cumsum(x))
+
+
+# -- the order of the entering lanes (ISSUE 53) ---------------------------------
+
+def classes_of(trips) -> np.ndarray:
+    """The class of a count of passes as ``codegen._keyed_ranks`` has it: the
+    count itself under ``_SHORT``, beyond it one class a bit length."""
+    t = np.minimum(np.asarray(trips, np.uint64), 0xFFFFFFFF)
+    bits = np.array([int(x).bit_length() for x in t])
+    return np.where(t < codegen._SHORT, t,
+                    codegen._SHORT + bits - codegen._SHORT.bit_length())
+
+
+def the_list(entered, width, trips=None) -> np.ndarray:
+    """The whole list ``_chunk_lanes`` builds, chunk after chunk."""
+    lanes_of = codegen._chunk_lanes(
+        jax.numpy.asarray(entered), width,
+        None if trips is None else jax.numpy.asarray(trips))
+    chunks = -(-len(entered) // width)
+    return np.concatenate([np.asarray(lanes_of(c)) for c in range(chunks)])
+
+
+ORDER_CASES = {
+    # several chunks, the last part full; lists as the BFS cell's
+    "lists of 2 to 18": (4096, 512, 0.4, lambda rng, n: rng.integers(2, 19, n)),
+    # zero-trip lanes enter and are placed too (behind every other)
+    "lists of 0 to 6": (2048, 512, 0.6, lambda rng, n: rng.integers(0, 7, n)),
+    # every class above ``_SHORT`` and the largest counts a walker can make
+    "geometric classes": (2048, 256, 0.7, lambda rng, n: rng.choice(
+        [1, 31, 32, 33, 63, 64, 1000, 1 << 20, (1 << 31) - 1, 1 << 31,
+         (1 << 32) - 1], n)),
+    # a range that is no multiple of 128 nor of the width
+    "a ragged range": (1000, 96, 0.5, lambda rng, n: rng.integers(1, 41, n)),
+    # one class: the rising order
+    "every lane the same count": (2048, 512, 0.5,
+                                  lambda rng, n: np.full(n, 5)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ORDER_CASES))
+def test_the_keyed_order_holds_every_entering_lane_once_by_class(case):
+    n, width, share, draw = ORDER_CASES[case]
+    rng = np.random.default_rng(53)
+    entered = rng.random(n) < share
+    trips = np.asarray(draw(rng, n)).astype(np.uint32)
+    k = int(entered.sum())
+    assert k > width
+    got = the_list(entered, width, trips)
+    # every entering lane exactly once, none that did not enter; beyond the
+    # last of them, lanes of the launch (masked off, but picked out)
+    np.testing.assert_array_equal(np.sort(got[:k]), np.flatnonzero(entered))
+    assert len(got) % width == 0 and (got >= 0).all() and (got < n).all()
+    # classes from long to short; inside a class the lanes rise
+    cls = classes_of(trips)[got[:k]]
+    assert (np.diff(cls) <= 0).all()
+    same = np.diff(cls) == 0
+    assert (np.diff(got[:k])[same] > 0).all()
+    if case == "every lane the same count":
+        np.testing.assert_array_equal(got, the_list(entered, width))
+
+
+@pytest.mark.parametrize("k", [0, 1, 200, 512])
+def test_one_chunk_of_entering_lanes_is_todays_list_to_the_element(k):
+    """With no more entering lanes than a chunk holds there is nothing to
+    order: the key is not looked at."""
+    rng = np.random.default_rng(7)
+    entered = np.zeros(4096, bool)
+    entered[rng.choice(4096, k, replace=False)] = True
+    trips = rng.integers(0, 41, 4096).astype(np.uint32)
+    np.testing.assert_array_equal(the_list(entered, 512, trips),
+                                  the_list(entered, 512))
+
+
+def spy_on_the_lists(monkeypatch):
+    """``seen``: a ``(trips, entered)`` pair a launch that built a list, and
+    ``chunks``: the lanes of every chunk a launch ran, by host callbacks."""
+    seen, chunks = [], []
+    real = codegen._chunk_lanes
+
+    def spied(entered, width, trips=None):
+        if trips is not None:
+            jax.debug.callback(
+                lambda t, e: seen.append((np.asarray(t), np.asarray(e))),
+                trips, entered)
+        lanes_of = real(entered, width, trips)
+
+        def of(c):
+            lanes = lanes_of(c)
+            jax.debug.callback(
+                lambda c, l, e: chunks.append(
+                    np.asarray(l)[:max(0, int(e.sum()) - int(c) * width)]),
+                c, lanes, entered)
+            return lanes
+        return of
+
+    monkeypatch.setattr(codegen, "_chunk_lanes", spied)
+    return seen, chunks
+
+
+def walk(cond: str, step: str, lo="int", extra="") -> str:
+    """A kernel whose lanes switched on walk ``j`` from ``lo[i]`` while
+    ``cond`` holds, stepping by ``step``, and gather a pass."""
+    return f"""
+__kernel void k(__global {lo}* lo, __global {lo}* hi, __global int* col,
+                __global int* on, __global int* out) {{
+    int i = get_global_id(0);
+    if (on[i]) {{
+        int s = 0;
+        for ({lo} j = lo[i]; {cond}; {step}) {{ s += col[(j & 1023)]; {extra} }}
+        out[i] = s;
+    }}
+}}"""
+
+
+TOP = (1 << 31) - 1
+KEYS = {
+    # name: (source, lo, hi, the passes each lane makes)
+    "a walker under, at and past its bound": (
+        walk("j < hi[i]", "j++"), [0, 5, 9, 3], [4, 5, 2, 9], [4, 0, 0, 6]),
+    "the bound reached is one pass more under <=": (
+        walk("j <= hi[i]", "j++"), [0, 5, 9, 3], [4, 5, 2, 9], [5, 1, 0, 7]),
+    "the comparison written from the other side": (
+        walk("hi[i] > j", "j++"), [0, 5, 9, 3], [4, 5, 2, 9], [4, 0, 0, 6]),
+    "a step over one": (
+        walk("j < hi[i]", "j += 3"), [0, 0, 0, 1], [9, 10, 1, 2], [3, 4, 1, 1]),
+    "a step over one under <=": (
+        walk("j <= hi[i]", "j += 3"), [0, 0, 0, 2], [9, 8, 0, 1], [4, 3, 1, 0]),
+    "a walk made of two moves": (
+        walk("j < hi[i]", "j += 3", extra="j--;"), [0, 0, 4, 1], [9, 10, 4, 2],
+        [5, 5, 0, 1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KEYS))
+def test_the_key_is_the_passes_each_lane_is_going_to_make(
+        compaction, monkeypatch, case):
+    src, lo, hi, passes = KEYS[case]
+    n, width = 2048, 512
+    seen, _chunks = spy_on_the_lists(monkeypatch)
+    compaction(width, 1.0)
+    rng = np.random.default_rng(3)
+    arrays = [np.resize(np.array(lo, np.int32), n),
+              np.resize(np.array(hi, np.int32), n),
+              rng.integers(0, 9, n).astype(np.int32), np.ones(n, np.int32),
+              np.zeros(n, np.int32)]
+    out, (info,) = launch(src, ("k",), arrays, n)
+    assert info.compact[4] == 1
+    (trips, entered), = seen
+    assert entered.all()
+    np.testing.assert_array_equal(trips, np.resize(passes, n))
+    # the kernel made those passes: the oracle's sum is the build's
+    held_to_the_oracle(src, arrays, out, n)
+
+
+def test_the_key_of_values_near_the_ends_of_int(compaction, monkeypatch):
+    """The difference is taken modulo 2^32 and read without a sign: a walk
+    from the least ``int`` to the largest is 2^32 - 1 passes, not -1.  (The
+    launch is traced to its list and stops there: nobody waits for that
+    lane.)"""
+    n, width = 2048, 512
+    seen, _chunks = spy_on_the_lists(monkeypatch)
+    compaction(width, 1.0)
+    lo = np.resize(np.array([-TOP - 1, TOP - 2, -TOP - 1, 0], np.int32), n)
+    hi = np.resize(np.array([TOP, TOP, -TOP + 1, TOP], np.int32), n)
+    want = np.resize(np.array([(1 << 32) - 1, 2, 2, TOP], np.uint32), n)
+    real = codegen._exec_masked
+
+    def no_chunk(ctx, *a, **k):  # the chunks' loop would make those passes
+        return real(ctx, *a, **k) if ctx.B == n else None
+
+    monkeypatch.setattr(codegen, "_exec_masked", no_chunk)
+    on = np.zeros(n, np.int32)
+    on[: n // 2] = 1  # (over a share of 1.0 the dense loop would run: none does)
+    launch(walk("j < hi[i]", "j++"), ("k",),
+           [lo, hi, np.zeros(n, np.int32), on, np.zeros(n, np.int32)], n)
+    (trips, entered), = seen
+    np.testing.assert_array_equal(trips[entered], want[entered])
+    got = the_list(entered, width, trips)
+    assert (np.diff(classes_of(trips)[got[:entered.sum()]]) <= 0).all()
+
+
+UNKEYED = {
+    "a bound the body assigns": HEAD + """
+    if (on[i]) {
+        int e = lo[i] + cnt[i];
+        for (int j = lo[i]; j < e; j++) { out[i] += tab[col[j]]; e -= (j & 1); }
+    }
+}""",
+    "a bound read from a buffer the loop stores to": HEAD + """
+    if (on[i]) {
+        for (int j = lo[i]; j < lo[i] + aux[i]; j++) { aux[col[j]] = 1; out[i] += 1; }
+    }
+}""",
+    "two terms in the condition": HEAD + """
+    if (on[i]) {
+        int s = 0;
+        for (int j = lo[i]; j < lo[i] + cnt[i] && s < 200; j++) { s += tab[col[j]]; }
+        out[i] = s;
+    }
+}""",
+    "a walker moved by a run-time step": HEAD + """
+    if (on[i]) {
+        for (int j = lo[i]; j < lo[i] + cnt[i]; j += aux[i]) { out[i] += tab[col[j]]; }
+    }
+}""",
+    "a walk downward": HEAD + """
+    if (on[i]) {
+        for (int j = lo[i] + cnt[i]; j > lo[i]; j--) { out[i] += tab[col[j - 1]]; }
+    }
+}""",
+}
+
+
+@pytest.mark.parametrize("what", sorted(UNKEYED))
+def test_a_loop_whose_syntax_gives_no_count_keeps_its_order(
+        compaction, monkeypatch, what):
+    """Compacted as before and in the rising order: the program is the one
+    the build makes with the analysis switched off, and the dense loop's
+    arrays come out of it."""
+    n, width = 2048, 512
+    arrays = csr(n, 37, 0.4)
+    # (a step, a bound: 1, and what the second kernel's lanes store there is
+    # 1 too, so that no lane's bound depends on who ran first)
+    arrays[6] = np.ones(n, np.int32)
+    _dense, (info,) = both(compaction, UNKEYED[what], ("k",), arrays, n, width)
+    assert info.compact[:2] + info.compact[4:] == (1, width, 0)
+    assert lowering_meta([info])["compact"].endswith(";ordered:0")
+
+    def text():
+        fn, _info = KernelProgram(UNKEYED[what]).launcher("k", n, LOCAL, n,
+                                                          platform="cpu")
+        return str(fn.trace(0, tuple(jax.numpy.asarray(a) for a in arrays),
+                            ()).jaxpr)
+
+    built = text()
+    monkeypatch.setattr(codegen, "_common_walks", lambda *a: {})
+    assert built == text()
+
+
+def test_a_loop_with_a_break_takes_the_key_as_a_bound(compaction):
+    """A lane that breaks makes fewer passes than its key says; the order is
+    free, so the key may err (``break and continue`` above is held to the
+    dense loop and the oracle in both orders)."""
+    compaction(512, 1.0)
+    _out, (info,) = launch(KERNELS["break and continue"], ("k",),
+                           csr(2048, 5, 0.6), 2048)
+    assert info.compact[4] == 1 and info.loops_peeled == 0
+
+
+def test_chunks_by_trip_count_make_fewer_passes(compaction, monkeypatch):
+    """Lists of 1 to 40 entries: a chunk's loop runs until its longest list
+    is through (and one trailing pass), so the passes of a launch are the sum
+    over its chunks of the longest list + 1, from the lanes each chunk was
+    HANDED (a host callback a chunk) and the lists' lengths.  A count of
+    work, no time."""
+    n, width = 8192, 512
+    rng = np.random.default_rng(11)
+    cnt = rng.integers(1, 41, n).astype(np.int32)
+    lo = (np.cumsum(cnt) - cnt).astype(np.int32)
+    arrays = [lo, cnt, rng.integers(0, n, int(cnt.sum())).astype(np.int32),
+              rng.integers(0, 100, n).astype(np.int32),
+              (rng.random(n) < 0.5).astype(np.int32), np.zeros(n, np.int32),
+              np.zeros(n, np.int32)]
+    on = arrays[4].astype(bool)
+    compaction(width, 1.0)
+    passes, outs = {}, {}
+    for order in ORDERS:
+        with monkeypatch.context() as mp:
+            _seen, chunks = spy_on_the_lists(mp)
+            set_order(mp, order)
+            outs[order], _ = launch(KERNELS["local read after the loop"],
+                                    ("k",), arrays, n)
+            jax.effects_barrier()
+        assert len(chunks) == -(-on.sum() // width)
+        np.testing.assert_array_equal(np.sort(np.concatenate(chunks)),
+                                      np.flatnonzero(on))
+        passes[order] = sum(int(cnt[c].max()) + 1 for c in chunks)
+    np.testing.assert_array_equal(outs["keyed"][5], outs["rising"][5])
+    # 8 chunks of 512 lanes: 8 x 41 in the rising order, about half by count
+    assert passes["rising"] == 8 * 41
+    assert passes["keyed"] < 0.65 * passes["rising"]

@@ -12,7 +12,15 @@ program fails here too.  ``shoc_reduction.cl``'s was TAKEN ANEW by PR 51, which
 means to change that lowering and no other (the passes every lane of its walk
 makes run with no mask: ``codegen._common_walks``); the other eleven rows are
 untouched, and tests/test_peeled_loops.py holds ``reduce`` with that analysis
-switched off to the hash this row had.
+switched off to the hash this row had.  ``rodinia_bfs.cl``'s ``BFS_1`` was TAKEN
+ANEW by PR 53, which means to change that lowering and no other, and found
+it the SAME: at this module's chunk (1024 lanes, under
+``codegen._COMPACT_WIDTH``) no loop is compacted, and the order of the
+compacted lanes is all PR 53 touches.  So the row stands and a second one,
+:data:`WIDE`, holds ``BFS_1`` at a chunk of 16 384 lanes, where its adjacency
+loop is built compacted and its entering lanes go to their chunks by trip
+count: PR 53's own hash, and beside it the hash of the same build with the
+key switched off, which is the parent's program (taken on 2dcc342).
 
 A configuration that brings a new ``.cl`` file ADDS its rows (with an empty
 hash first: the failing assertion shows the one built); a PR that means to
@@ -75,18 +83,29 @@ def value_of(ctype: str):
         1.5 if ctype in ("float", "double", "half") else 64)
 
 
-def build_sha(src_file: str, kernel: str) -> str:
+# ``BFS_1`` over a chunk wider than ``_COMPACT_WIDTH``: as built by PR 53,
+# and with ``_chunk_lanes`` handed no key, which is the program on 2dcc342,
+# PR 53's parent
+WIDE = 16384
+PINNED_WIDE = {
+    "keyed": "5c40ec551f8dac1e787d7860399b0cab021a7f4151f3cb97abed51d3ed9011d4",
+    "rising": "319c58ec56de73def6c87b715f87c3afcef37f9bed8f00d9318f05ed6b2d7fae"}
+
+
+def build_sha(src_file: str, kernel: str, chunk: int = CHUNK) -> str:
     with open(os.path.join(CONFIGS, src_file), encoding="utf-8") as f:
         source = f.read()
     kdef = next(k for k in lang.parse_kernels(source) if k.name == kernel)
     # a ``floatN*`` parameter binds N floats a work item
     typed = [(p, lang.vector_of(p.ctype) or (p.ctype, 1))
              for p in kdef.params if p.is_pointer]
+    elements = max(ELEMENTS, chunk)
     arrays = tuple(
-        jax.ShapeDtypeStruct((ELEMENTS * n,), codegen.ctype_to_dtype(elem))
+        jax.ShapeDtypeStruct((elements * n,), codegen.ctype_to_dtype(elem))
         for _p, (elem, n) in typed)
     values = tuple(value_of(p.ctype) for p in kdef.params if not p.is_pointer)
-    fn, _info = KernelProgram(source).launcher(kernel, CHUNK, LOCAL, RANGE)
+    fn, _info = KernelProgram(source).launcher(kernel, chunk, LOCAL,
+                                               max(RANGE, chunk))
     text = fn.trace(0, arrays, values, fn.keys_of(values)).lower().as_text()
     return hashlib.sha256(text.encode()).hexdigest()
 
@@ -95,3 +114,13 @@ def build_sha(src_file: str, kernel: str) -> str:
 def test_a_pinned_kernel_builds_the_program_it_built(src_file, kernel):
     assert build_sha(src_file, kernel) == PINNED[src_file, kernel]
 
+
+
+@pytest.mark.parametrize("order", sorted(PINNED_WIDE))
+def test_the_compacted_bfs_launcher_is_pinned_with_and_without_its_key(
+        order, monkeypatch):
+    if order == "rising":
+        real = codegen._chunk_lanes
+        monkeypatch.setattr(codegen, "_chunk_lanes",
+                            lambda entered, width, trips=None: real(entered, width))
+    assert build_sha("rodinia_bfs.cl", "BFS_1", WIDE) == PINNED_WIDE[order]

@@ -1027,7 +1027,8 @@ def test_a_compactable_loops_spans_carry_the_compact_field(tmp_path):
     """ISSUE 41: Rodinia's ``BFS_1`` over a launch wider than a chunk builds
     its adjacency loop both ways, and the ``ck/launch`` / ``ck/compile``
     spans that ran the build say so (``compact=loops:1;width:W;gathered:3;
-    scattered:0``); ``BFS_2`` has no loop and its spans no such field.  The
+    scattered:0;ordered:1``: since ISSUE 53 its entering lanes go to their
+    chunks by trip count); ``BFS_2`` has no loop and its spans no such field.  The
     ``access`` field stays as PR 40 left it."""
     import jax
     from jax.profiler import ProfileData
@@ -1069,7 +1070,7 @@ def test_a_compactable_loops_spans_carry_the_compact_field(tmp_path):
              if plane.name == "/host:CPU"
              for line in plane.lines for ev in line.events
              if ev.name in ("ck/launch", "ck/compile")]
-    field = f"loops:1;width:{width};gathered:3;scattered:0"
+    field = f"loops:1;width:{width};gathered:3;scattered:0;ordered:1"
     launches = [st for name, st in spans if name == "ck/launch"]
     compiles = [st for name, st in spans if name == "ck/compile"]
     with_loop = [st for st in launches if "BFS_1" in str(st["tag"])]
